@@ -44,7 +44,7 @@ class DonationPolicy:
     """Resolved donation verdict for one engine configuration."""
 
     donate_kv: bool
-    platform: str  # "cpu" | "tpu" | "gpu" | "unknown"
+    platform: str  # "cpu" | "tpu" | "gpu"
     overlap_active: bool
     sharded: bool
     reason: str
@@ -64,9 +64,8 @@ def kv_donation_policy(
     """Resolve the KV donation policy for (backend platform, schedule mode).
 
     ``platform`` is the PJRT platform of the devices the cache lives on
-    ("cpu", "tpu", "gpu"; unknown platforms are treated as async-dispatch
-    -capable, i.e. they donate — the TPU rule, and the safe default for any
-    accelerator backend).  ``overlap_active`` means the overlapped schedule
+    ("cpu", "tpu", "gpu"; anything that is not "cpu" gets the accelerator
+    rule and donates).  ``overlap_active`` means the overlapped schedule
     (including its speculative variant) will keep frames in flight across
     steps.  ``sharded`` only annotates the reason: GSPMD aliases per-shard,
     the verdict rides the platform.

@@ -356,7 +356,13 @@ class Engine:
             "uptime_secs": time.monotonic() - self.start_time,
             "watchdog_stalls": self.num_watchdog_stalls,
             "consecutive_step_failures": self.scheduler.consec_step_failures,
+            "step_failures": self.scheduler.num_step_failures,
+            "quarantined_requests": self.scheduler.num_quarantined,
             "draining": self.scheduler.draining,
+            # the device this box flew on (a remote worker's loads() proto
+            # carries counters only, so this is where a gateway can read it)
+            "mesh": self.runner.mesh_info(),
+            "attention": self.runner.attention_info(),
         }
         if fl.dumps:
             # the newest auto-dump rides along in full so one fetch answers
@@ -481,19 +487,10 @@ class Engine:
         with self._lock:
             if getattr(self, "_profiling", False):
                 raise RuntimeError("profiler already running")
-            kwargs = {}
-            po_cls = getattr(jax.profiler, "ProfileOptions", None)
-            if po_cls is not None:
-                opts = po_cls()
-                opts.host_tracer_level = 2 if host_tracer else 0
-                opts.python_tracer_level = 1 if python_tracer else 0
-                kwargs["profiler_options"] = opts
-            try:
-                jax.profiler.start_trace(output_dir, **kwargs)
-            except TypeError:
-                if not kwargs:  # genuine signature error, not a compat gap
-                    raise
-                jax.profiler.start_trace(output_dir)
+            opts = jax.profiler.ProfileOptions()
+            opts.host_tracer_level = 2 if host_tracer else 0
+            opts.python_tracer_level = 1 if python_tracer else 0
+            jax.profiler.start_trace(output_dir, profiler_options=opts)
             self._profiling = True
             self._profile_steps_left = num_steps if num_steps > 0 else None
         logger.info("profiler started -> %s", output_dir)
@@ -707,6 +704,14 @@ class Engine:
         return out
 
     # ---- background loop ----
+
+    def warmup(self) -> list[tuple[str, float]]:
+        """Compile and run the largest prefill and decode programs before
+        serving (``ModelRunner.warmup``): the serving commands call this
+        before they bind a port, so a server that cannot run its own
+        defaults never reports healthy."""
+        with self._lock:
+            return self.runner.warmup()
 
     def start(self) -> None:
         if self._thread is not None:

@@ -13,6 +13,7 @@ functions and inserts ICI collectives.  Single-device runs skip sharding.
 from __future__ import annotations
 
 import math
+import time
 from functools import partial
 
 import jax
@@ -28,6 +29,7 @@ from smg_tpu.engine.sampling import apply_penalties
 from smg_tpu.engine.sampling import sample_tokens as _sample_fast
 from smg_tpu.engine.sampling import sample_tokens_exact as _sample_exact
 from smg_tpu.models.registry import get_model
+from smg_tpu.ops.attention import scatter_kv_rows
 from smg_tpu.ops.rope import rope_frequencies
 from smg_tpu.parallel.mesh import build_mesh
 from smg_tpu.parallel.sharding import (
@@ -39,6 +41,12 @@ from smg_tpu.parallel.sharding import (
 from smg_tpu.utils import get_logger
 
 logger = get_logger("engine.runner")
+
+# Largest chunk the paged prefill kernel is chosen for.  The kernel keeps the
+# whole chunk's K/V lane slice in VMEM (T x 128 lanes, twice, double-buffered);
+# 4096 is the top of the default bucket ladder and the largest T that
+# tests/test_tpu_compile.py compiles for a v5e.
+PREFILL_KERNEL_MAX_T = 4096
 
 
 def _dev(x, dtype, sharding=None) -> jax.Array:
@@ -62,6 +70,12 @@ def _dev(x, dtype, sharding=None) -> jax.Array:
         return jax.device_put(np.asarray(x, dtype), sharding)
     # smglint: disable-next=SHARDDISC single-device path: mesh is None, there is no commitment target
     return jax.device_put(np.asarray(x, dtype))
+
+
+def _attn_label(family: str, impl: str) -> str:
+    """Key of ``ModelRunner.attn_launches`` for a program of ``family``
+    ("prefill" | "decode") traced with attention ``impl``."""
+    return f"pallas_{family}" if impl.startswith("pallas") else "xla"
 
 
 def _pad_rows(a: np.ndarray, G: int, fill=0) -> np.ndarray:
@@ -211,6 +225,10 @@ class ModelRunner:
             if self._device is not None:
                 self.params = jax.device_put(self.params, self._device)
 
+        # the platform the params and cache live on: the attention dispatch,
+        # the donation policy and the cache sizing all choose from it
+        self.platform = self.local_devices()[0].platform
+
         # KV cache sizing + buffers.  Sizing inputs are per-device: the
         # tightest device's free HBM and its local parameter shard bytes
         # (GSPMD shards most weights over tp/ep, so global nbytes would
@@ -221,7 +239,6 @@ class ModelRunner:
             self.model_cfg, config.cache, hbm_free, param_bytes,
             tp=config.parallel.tp,
         )
-        # bound pages so the fallback gather in tests stays small
         kv_sharding = None
         if self.mesh is not None:
             from smg_tpu.models.llama import kv_cache_logical_axes
@@ -245,16 +262,15 @@ class ModelRunner:
             config.scheduler.max_seq_len / config.cache.page_size
         )
         self.attn_impl = self._resolve_attn_impl()
-        logger.info("attention impl: %s", self.attn_impl)
+        # launches by attention implementation ("xla", "pallas_prefill",
+        # "pallas_decode"): loads()/"/scheduler" report them, so a run can
+        # show that each side of the dispatch rule really executed
+        self.attn_launches = {"xla": 0, "pallas_prefill": 0, "pallas_decode": 0}
         # per-backend / per-mode KV donation policy (engine/donation.py) —
         # resolved once against where the cache actually lives, replacing
         # PR 2's runner-internal CPU-overlap heuristic
-        try:
-            platform = self.local_devices()[0].platform
-        except Exception:
-            platform = "unknown"
         self.donation = kv_donation_policy(
-            platform,
+            self.platform,
             overlap_active=config.scheduler.overlap_schedule,
             sharded=self.mesh is not None,
         )
@@ -270,8 +286,16 @@ class ModelRunner:
         self._mesh_info = {
             "devices": self.mesh_devices,
             "shape": config.parallel.axis_sizes(),
-            "platform": self.donation.platform,
+            "platform": self.platform,
+            "device_kind": self.local_devices()[0].device_kind,
             "donate_kv": self.donation.donate_kv,
+            # what each device must hold at rest: its parameter shard and its
+            # share of the KV buffers (HBM gauges are read against these)
+            "param_bytes_per_device": param_bytes,
+            "kv_bytes_per_device": sum(
+                x.addressable_shards[0].data.nbytes
+                for x in (self.k_cache, self.v_cache)
+            ),
         }
         self._rng_key = jax.random.PRNGKey(config.seed ^ 0x5EED)
         if self._replicated is not None:
@@ -297,36 +321,34 @@ class ModelRunner:
         self._lora_rank = 0
 
     def _resolve_attn_impl(self) -> str:
-        """Resolve the configured mode against device capability.  Returns
-        "xla", "pallas", or "auto" (= capable; per-shape choice at trace
-        time in ``_attn_impl_for`` — decode page tables are trimmed per
-        batch, so the gather size is a call property, not an engine one)."""
-        import os
-
-        cfgd = self.config.attention_impl
-        if cfgd != "auto":
-            return cfgd
-        if os.environ.get("SMG_DISABLE_PALLAS") == "1":
-            return "xla"
-        kd = self.model_cfg.num_kv_heads * self.model_cfg.head_dim
-        if kd % 128 != 0:
-            return "xla"
-        # dispatch on where the cache actually lives, not the default backend
-        # (some installs register an always-on TPU plugin)
-        try:
-            dev = next(iter(self.k_cache.devices()))
-            if dev.platform != "tpu":
-                return "xla"
-        except Exception:
-            return "xla"
-        return "auto"
+        """What the configured mode can mean on this engine: "xla",
+        "pallas", or "auto" (kernels usable; ``_attn_impl_for`` and
+        ``_prefill_impl_for`` choose per program from its shapes).  Together
+        with those two this is the whole dispatch rule: it reads the config,
+        the platform, the mesh and the shapes, and nothing else."""
+        mode = self.config.attention_impl
+        why = None
+        if mode != "auto":
+            why = "configured"
+            if mode == "pallas" and self.mesh is not None:
+                raise ValueError(
+                    "attention_impl='pallas' under a mesh: the kernels are "
+                    "not wrapped in shard_map and GSPMD cannot partition them"
+                )
+        elif self.platform != "tpu":
+            mode, why = "xla", f"platform is {self.platform}"
+        elif self.mesh is not None:
+            # Mosaic kernels are not partitioned by GSPMD and the two
+            # pallas_calls are not wrapped in shard_map
+            mode, why = "xla", "the kernels are not partitioned over a mesh"
+        elif (self.model_cfg.num_kv_heads * self.model_cfg.head_dim) % 128:
+            mode, why = "xla", "kv_heads*head_dim is not a multiple of 128 lanes"
+        logger.info("attention impl: %s%s", mode, f" ({why})" if why else "")
+        return mode
 
     def invalidate_compiled(self, kind: str | None = None) -> None:
         """Drop compiled step functions (all, or those whose cache key starts
-        with ``kind``, e.g. "decode_multi").  Needed after flipping
-        ``attn_impl``: the kernel choice is baked in at trace time and is
-        deliberately NOT part of the cache key (normal operation never flips
-        it for a live shape — only benchmarks do)."""
+        with ``kind``, e.g. "decode_multi")."""
         if kind is None:
             dropped = list(self._compiled)
             self._compiled.clear()
@@ -335,6 +357,21 @@ class ModelRunner:
             for k in dropped:
                 del self._compiled[k]
         self._programs.forget(dropped)
+
+    def _register(self, k, fn, *, donate, in_shardings, attn: str):
+        """Cache jitted program ``fn`` under key ``k`` behind the auditor's
+        launch wrapper, and count its launches under the attention
+        implementation it was traced with."""
+        launch = self._programs.wrap(k, fn, donate=donate,
+                                     in_shardings=in_shardings)
+        logger.info("program %s: attention %s", k, attn)
+
+        def counted(*args):
+            self.attn_launches[attn] += 1
+            return launch(*args)
+
+        self._compiled[k] = counted
+        return counted
 
     def program_audit(self, *, check_donation: bool = True) -> dict:
         """Audit every cached compiled program from its compiled
@@ -345,39 +382,33 @@ class ModelRunner:
         return self._programs.audit(check_donation=check_donation)
 
     def _attn_impl_for(self, B: int, mp: int) -> str:
-        """Per-shape kernel choice.  Short contexts: XLA's fused
-        gather+softmax wins (fused-lane layout makes the gather
-        relayout-free); long contexts: the gather materializes B*mp*ps*KD
-        bytes per layer and the page-streaming pallas kernel wins.
-
-        PROVENANCE of the 131072-token crossover: one-off interactive
-        measurement on a v5e-1 during round-3 development (1B-class model,
-        bench.py's long-context A/B shape); NOT reproduced in any committed
-        BENCH artifact — the environment's TPU has been unreachable every
-        round (BENCH_r01..r04 ``tpu_unavailable``).  Treat as an estimate;
-        ``bench.py`` re-measures the A/B and should recalibrate this
-        threshold the first round a real TPU record lands."""
+        """Decode attention for one (batch bucket, table width) program.
+        The XLA path gathers ``B*mp*ps`` tokens of KV per layer (the
+        fused-lane layout makes the gather relayout-free); the kernel
+        streams only the pages that hold tokens.  The 131072-token crossover
+        has no measurement on record (ROADMAP S4)."""
         if self.use_pp:
             return "xla"  # pallas kernels don't run inside the pp shard_map
         if self.attn_impl != "auto":
             return self.attn_impl
         return "pallas" if B * mp * self.spec.page_size > 131072 else "xla"
 
-    def _prefill_impl_for(self, mp: int) -> str:
-        """Prefill kernel choice.  The XLA path gathers mp*ps tokens per
-        layer — the page table's WORST case, independent of the live prefix —
-        so the paged kernel wins once capacity is large even when the actual
-        prefix is short.  Explicit config wins; "auto" uses a capacity
-        threshold (small tables: the fused gather is relayout-free and
-        cheap)."""
-        if self.use_pp:
-            return "xla"
-        if self.attn_impl == "xla":
+    def _prefill_impl_for(self, T: int, mp: int) -> str:
+        """Solo-prefill attention for one (chunk bucket, table width)
+        program.  The XLA path scores every chunk token against all
+        ``mp*ps`` table slots, whatever the live prefix is; the kernel
+        streams only the prefix pages that hold tokens.  The kernel is
+        chosen only at shapes it is known to compile at: 128-lane-sliceable
+        heads and ``T <= PREFILL_KERNEL_MAX_T``.  The 2048-slot crossover
+        has no measurement on record (ROADMAP S4)."""
+        if self.use_pp or self.attn_impl == "xla":
             return "xla"
         d = self.model_cfg.head_dim
         c = max(1, 128 // d)
         if self.model_cfg.num_kv_heads % c or (c * d) % 128:
             return "xla"  # lanes not 128-sliceable for the kernel
+        if T > PREFILL_KERNEL_MAX_T:
+            return "xla"
         if self.attn_impl == "pallas":
             return "pallas"
         return "pallas" if mp * self.spec.page_size > 2048 else "xla"
@@ -407,23 +438,40 @@ class ModelRunner:
         mutating the cached snapshot."""
         return dict(self._mesh_info)
 
+    def attention_info(self) -> dict:
+        """The attention dispatch as ``loads()`` / ``/scheduler`` report it:
+        the resolved mode and launches so far per implementation."""
+        return {"mode": self.attn_impl, "launches": dict(self.attn_launches)}
+
     def _detect_hbm(self) -> int | None:
         """Free HBM on the tightest device this engine will occupy.
 
-        Non-addressable devices (other hosts' chips on a multi-host mesh) and
-        backends without memory stats are skipped; None only when NO device
-        reports stats (auto-size then falls back to configured num_pages)."""
-        devs = self.local_devices()
+        Non-addressable devices (other hosts' chips on a multi-host mesh)
+        are skipped.  None means the backend keeps no memory statistics
+        (the CPU client), and the cache then has the configured
+        ``num_pages``; on a TPU that is a start-up error, because a cache
+        sized by a default would serve without a word."""
         free = None
-        for d in devs:
-            try:
-                stats = d.memory_stats()
-            except Exception:
+        for d in self.local_devices():
+            if d.process_index != jax.process_index():
                 continue
+            stats = d.memory_stats()
             if not stats or "bytes_limit" not in stats:
                 continue
             f = stats["bytes_limit"] - stats.get("bytes_in_use", 0)
             free = f if free is None else min(free, f)
+        if free is None:
+            if self.platform == "tpu":
+                raise RuntimeError(
+                    "no TPU device of this engine reports memory_stats(); "
+                    "cannot size the KV cache"
+                )
+            if self.config.cache.auto_size:
+                logger.info(
+                    "platform %s reports no memory statistics: kv cache keeps "
+                    "the configured %d pages", self.platform,
+                    self.config.cache.num_pages,
+                )
         return free
 
     # ---- penalty slot state ----
@@ -597,7 +645,7 @@ class ModelRunner:
                     use_mask: bool = False, use_lora: bool = False,
                     use_ring: bool = False, use_embeds: bool = False,
                     use_mrope: bool = False):
-        impl = "xla" if use_ring else self._prefill_impl_for(mp)
+        impl = "xla" if use_ring else self._prefill_impl_for(T, mp)
         k = ("prefill", T, mp, impl, use_pen, use_mask, use_lora, use_ring,
              use_embeds, use_mrope)
         if k in self._compiled:
@@ -661,9 +709,8 @@ class ModelRunner:
         else:
             in_sh = None
             fn = jax.jit(step, donate_argnums=(5, 6))
-        fn = self._programs.wrap(k, fn, donate=(5, 6), in_shardings=in_sh)
-        self._compiled[k] = fn
-        return fn
+        return self._register(k, fn, donate=(5, 6), in_shardings=in_sh,
+                              attn=_attn_label("prefill", impl))
 
     def _prefill_extend_fn(self, T: int, mp: int, use_lora: bool = False,
                            use_ring: bool = False, use_embeds: bool = False,
@@ -676,7 +723,7 @@ class ModelRunner:
         while a ``PREFILLING`` request advances: the global key-fold order
         stays exactly the budgeted-sync order (prefill folds only on FINAL
         chunks, which suppress the lookahead for that step)."""
-        impl = "xla" if use_ring else self._prefill_impl_for(mp)
+        impl = "xla" if use_ring else self._prefill_impl_for(T, mp)
         k = ("prefill_extend", T, mp, impl, use_lora, use_ring, use_embeds,
              use_mrope)
         if k in self._compiled:
@@ -732,9 +779,8 @@ class ModelRunner:
         else:
             in_sh = None
             fn = jax.jit(step, donate_argnums=donate)
-        fn = self._programs.wrap(k, fn, donate=donate, in_shardings=in_sh)
-        self._compiled[k] = fn
-        return fn
+        return self._register(k, fn, donate=donate, in_shardings=in_sh,
+                              attn=_attn_label("prefill", impl))
 
     def _prefill_batched_fn(self, G: int, T: int, mp: int, no_ctx: bool = False,
                             use_pen: bool = False, use_mask: bool = False,
@@ -798,9 +844,8 @@ class ModelRunner:
         else:
             in_sh = None
             fn = jax.jit(step, donate_argnums=(5, 6))
-        fn = self._programs.wrap(k, fn, donate=(5, 6), in_shardings=in_sh)
-        self._compiled[k] = fn
-        return fn
+        return self._register(k, fn, donate=(5, 6), in_shardings=in_sh,
+                              attn="xla")
 
     def prefill_batched(
         self,
@@ -946,8 +991,9 @@ class ModelRunner:
         delta (M-RoPE decode: text axes are equal, so the offset rides the
         standard rope path)."""
         use_stop = E > 0
-        k = ("decode_multi", B, mp, N, E, use_pen, use_mask, use_lora,
-             use_mrope)
+        attn_impl = self._attn_impl_for(B, mp)
+        k = ("decode_multi", B, mp, N, E, attn_impl, use_pen, use_mask,
+             use_lora, use_mrope)
         if k in self._compiled:
             return self._compiled[k]
         cfg = self.model_cfg
@@ -955,7 +1001,6 @@ class ModelRunner:
         ps = self.spec.page_size
         KD = cfg.num_kv_heads * cfg.head_dim
         L = cfg.num_layers
-        attn_impl = self._attn_impl_for(B, mp)
         mesh, rules = self.mesh, self.rules
 
         n_slots = self.lora_slots
@@ -1060,15 +1105,9 @@ class ModelRunner:
             pos_c = jnp.minimum(pos, total - 1)
             page = jnp.take_along_axis(page_tables, pos_c // ps, axis=1)
             dest = jnp.where(valid, page * ps + pos_c % ps, 0).reshape(-1)  # [B*N]
-            kvals = hk.reshape(L, B * N, KD)
-            vvals = hv.reshape(L, B * N, KD)
-            P = kc.shape[1]
-            kc = kc.reshape(L, P * ps, KD).at[:, dest].set(
-                kvals.astype(kc.dtype)
-            ).reshape(kc.shape)
-            vc = vc.reshape(L, P * ps, KD).at[:, dest].set(
-                vvals.astype(vc.dtype)
-            ).reshape(vc.shape)
+            kc, vc = scatter_kv_rows(
+                kc, vc, hk.reshape(L, B * N, KD), hv.reshape(L, B * N, KD), dest
+            )
             if use_pen:
                 counts_buf = counts_buf.at[slot_idx].set(counts)
                 return outs, lps, steps_run, kc, vc, counts_buf
@@ -1099,9 +1138,8 @@ class ModelRunner:
         else:
             in_sh = None
             fn = jax.jit(multi, donate_argnums=donate)
-        fn = self._programs.wrap(k, fn, donate=donate, in_shardings=in_sh)
-        self._compiled[k] = fn
-        return fn
+        return self._register(k, fn, donate=donate, in_shardings=in_sh,
+                              attn=_attn_label("decode", attn_impl))
 
     def decode_multi_async(
         self,
@@ -1282,9 +1320,8 @@ class ModelRunner:
         else:
             in_sh = None
             fn = jax.jit(step, donate_argnums=(4, 5))
-        fn = self._programs.wrap(k, fn, donate=(4, 5), in_shardings=in_sh)
-        self._compiled[k] = fn
-        return fn
+        return self._register(k, fn, donate=(4, 5), in_shardings=in_sh,
+                              attn="xla")
 
     # ---- host-facing API ----
 
@@ -1424,6 +1461,58 @@ class ModelRunner:
                                      use_mrope=rope_pos is not None)
         self.k_cache, self.v_cache = fn(*(base_args + tail_args))
 
+    def warmup(self) -> list[tuple[str, float]]:
+        """Compile and run, once each, the largest solo-prefill,
+        grouped-prefill and decode programs the scheduler can launch for a
+        request with default features, so that a program which cannot
+        compile, or does not fit beside the cache, stops the process at
+        start-up instead of failing every long request later.  (Optional
+        features — penalties, masks, LoRA, embeddings, speculation — still
+        compile on first use.)
+
+        Page tables are all zero and decode positions sit past the table, so
+        every KV write lands on the garbage page; the sampling-key counter
+        is restored.  Returns ``(program, seconds)`` pairs: set-up time on
+        the host clock, compilation included."""
+        sched = self.config.scheduler
+        mp = self.max_pages_per_seq
+        slots = mp * self.config.cache.page_size
+        t = min(sched.max_prefill_tokens, sched.max_seq_len - 1)
+        ids = [0] * t
+        table = np.zeros(mp, np.int32)
+        # a full group splitting the step's budget evenly, each member behind
+        # one cached token so that the program gathers context.  (A skewed
+        # group pads to more rows x tokens than this; its attention is
+        # bounded by ops.attention.SCORE_BLOCK_BYTES all the same.)
+        G = min(sched.max_prefill_group, t)
+        group = [(ids[: t // G], 1, table)] * G
+        B = sched.decode_bucket(sched.max_batch_size)
+        N = sched.horizon_cap
+        zeros, ones = np.zeros(B, np.float32), np.ones(B, np.float32)
+        steps = {
+            "prefill_extend": lambda: self.prefill_extend(ids, 0, table),
+            "prefill": lambda: self.prefill(ids, 0, table, 0.0, -1, 1.0, 0.0),
+            "prefill_batched": lambda: self.prefill_batched(
+                group, zeros[:G], np.full(G, -1, np.int32), ones[:G], zeros[:G]
+            ),
+            "decode_multi": lambda: self.decode_multi(
+                np.zeros(B, np.int32), np.full(B, slots, np.int32),
+                np.zeros((B, mp), np.int32), zeros, np.full(B, -1, np.int32),
+                ones, zeros, num_steps=N, max_steps=N,
+            ),
+        }
+        mark = self.rng_mark()
+        took = []
+        for name, run in steps.items():
+            t0 = time.perf_counter()
+            run()
+            jax.block_until_ready((self.k_cache, self.v_cache))
+            took.append((name, time.perf_counter() - t0))
+            logger.info("warmup: largest %s program ran (%.1fs with "
+                        "compilation)", name, took[-1][1])
+        self.rng_restore(mark)
+        return took
+
     def _decode_spec_fn(self, B: int, mp: int, W: int, use_mrope: bool = False):
         """The fused speculative VERIFY megastep: score a W-token draft block
         for every lane in ONE forward, accept on device, and scatter only the
@@ -1525,15 +1614,9 @@ class ModelRunner:
             pos_c = jnp.minimum(pos, total - 1)
             page = jnp.take_along_axis(page_tables, pos_c // ps, axis=1)
             dest = jnp.where(valid, page * ps + pos_c % ps, 0).reshape(-1)
-            kvals = bk.reshape(L, B * W, KD)
-            vvals = bv.reshape(L, B * W, KD)
-            P = kc.shape[1]
-            kc = kc.reshape(L, P * ps, KD).at[:, dest].set(
-                kvals.astype(kc.dtype)
-            ).reshape(kc.shape)
-            vc = vc.reshape(L, P * ps, KD).at[:, dest].set(
-                vvals.astype(vc.dtype)
-            ).reshape(vc.shape)
+            kc, vc = scatter_kv_rows(
+                kc, vc, bk.reshape(L, B * W, KD), bv.reshape(L, B * W, KD), dest
+            )
             return emitted, n_emit, lps, kc, vc
 
         donate = (5, 6) if self.donation.donate_kv else ()
@@ -1549,9 +1632,8 @@ class ModelRunner:
         else:
             in_sh = None
             fn = jax.jit(spec, donate_argnums=donate)
-        fn = self._programs.wrap(k, fn, donate=donate, in_shardings=in_sh)
-        self._compiled[k] = fn
-        return fn
+        return self._register(k, fn, donate=donate, in_shardings=in_sh,
+                              attn="xla")
 
     def decode_spec_async(
         self,

@@ -418,6 +418,9 @@ class Scheduler:
             # split, so operators can see a TP worker's sharding from
             # /scheduler without reaching into the runner
             "mesh": self.runner.mesh_info(),
+            # which attention implementation the dispatch rule resolved to,
+            # and how many launches each one has had
+            "attention": self.runner.attention_info(),
             "dispatch_enqueue_seconds": self.dispatch_enqueue_s_total,
             "fetch_wait_seconds": self.fetch_wait_s_total,
         }

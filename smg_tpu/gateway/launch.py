@@ -16,23 +16,7 @@ from smg_tpu.utils import get_logger
 logger = get_logger("gateway.launch")
 
 
-def _maybe_force_cpu() -> None:
-    """SMG_FORCE_CPU=1 pins jax to the CPU backend even when an accelerator
-    plugin registers itself unconditionally (ignoring JAX_PLATFORMS)."""
-    import os
-
-    if os.environ.get("SMG_FORCE_CPU") == "1":
-        import jax
-
-        try:
-            jax.config.update("jax_default_device", jax.devices("cpu")[0])
-            logger.info("SMG_FORCE_CPU=1: pinned default device to CPU")
-        except RuntimeError:
-            logger.warning("SMG_FORCE_CPU=1 set but no CPU backend found")
-
-
 def build_engine_from_args(args):
-    _maybe_force_cpu()
     from smg_tpu.engine.config import (
         CacheConfig,
         EngineConfig,
@@ -158,6 +142,16 @@ def load_tokenizer(path: str | None):
 def run_command(args) -> int:
     if args.command == "worker":
         return run_worker(args)
+    if args.command == "launch":
+        # A host's chips belong to one process, its worker.  The gateway
+        # runs jax.numpy for image preprocessing (multimodal/image.py), and
+        # on a TPU host the first such call would reach for a chip the
+        # worker owns: keep this process on the CPU platform, before
+        # anything here can import jax.
+        import os
+
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        logger.info("gateway process pinned to the CPU platform")
     return asyncio.run(_run_gateway(args))
 
 
@@ -165,6 +159,7 @@ def run_worker(args) -> int:
     from smg_tpu.rpc.server import serve_worker
 
     engine = build_engine_from_args(args)
+    engine.warmup()  # before the port binds: fail here, not per request
     engine.start()
     return serve_worker(engine, port=args.grpc_port)
 
@@ -350,6 +345,10 @@ async def _run_gateway(args) -> int:
         from smg_tpu.gateway.worker_client import InProcWorkerClient
 
         engine = build_engine_from_args(args)
+        # before the port binds: a server that cannot run its own largest
+        # programs must not report healthy.  Blocking the loop is fine here,
+        # nothing is listening yet.
+        engine.warmup()
         tokenizer = load_tokenizer(args.tokenizer_path or args.model_path)
         ctx.tokenizers.register(engine.config.model_id, tokenizer, default=True)
         client = InProcWorkerClient(engine)

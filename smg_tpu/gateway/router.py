@@ -1066,47 +1066,19 @@ class Router:
             )
             return choice, last
 
-        # cancel siblings on first failure (n>1 fan-out).  TaskGroup needs
-        # Python 3.11; on 3.10 fall back to gather + explicit cancellation
-        # (the deployed interpreter here is 3.10 — without this the whole
-        # non-streaming chat path 500s)
-        if hasattr(asyncio, "TaskGroup"):
-            try:
-                async with asyncio.TaskGroup() as tg:
-                    tasks = [tg.create_task(run_one(i)) for i in range(sampling.n)]
-            except BaseExceptionGroup as eg:
-                route = next(
-                    (e for e in eg.exceptions if isinstance(e, RouteError)), None
-                )
-                raise route if route is not None else eg.exceptions[0]
-            # TaskGroup exit guarantees every task is done: result() here is
-            # a non-blocking unwrap, not a futures wait
-            # smglint: disable-next=ASYNCBLOCK tasks are done after TaskGroup exit
-            results = [t.result() for t in tasks]
-        else:
-            tasks = [asyncio.ensure_future(run_one(i)) for i in range(sampling.n)]
-            try:
-                await asyncio.wait(tasks, return_when=asyncio.FIRST_EXCEPTION)
-            except BaseException:
-                # outer cancellation (client disconnect / timeout middleware):
-                # TaskGroup would cancel siblings — match it, or the orphaned
-                # generations keep holding engine slots
-                for t in tasks:
-                    t.cancel()
-                await asyncio.gather(*tasks, return_exceptions=True)
-                raise
-            errors = [t.exception() for t in tasks
-                      if t.done() and not t.cancelled() and t.exception()]
-            if errors:
-                for t in tasks:
-                    t.cancel()  # fail-fast: siblings may still be running
-                await asyncio.gather(*tasks, return_exceptions=True)
-                route = next((e for e in errors if isinstance(e, RouteError)), None)
-                raise route if route is not None else errors[0]
-            # asyncio.wait(FIRST_EXCEPTION) returned with no errors -> every
-            # task completed; result() is a non-blocking unwrap
-            # smglint: disable-next=ASYNCBLOCK tasks are done after asyncio.wait
-            results = [t.result() for t in tasks]
+        # cancel siblings on first failure (n>1 fan-out)
+        try:
+            async with asyncio.TaskGroup() as tg:
+                tasks = [tg.create_task(run_one(i)) for i in range(sampling.n)]
+        except BaseExceptionGroup as eg:
+            route = next(
+                (e for e in eg.exceptions if isinstance(e, RouteError)), None
+            )
+            raise route if route is not None else eg.exceptions[0]
+        # TaskGroup exit guarantees every task is done: result() here is
+        # a non-blocking unwrap, not a futures wait
+        # smglint: disable-next=ASYNCBLOCK tasks are done after TaskGroup exit
+        results = [t.result() for t in tasks]
         choices = [c for c, _ in results]
         usage = UsageInfo(
             prompt_tokens=sum(last.prompt_tokens for _, last in results),

@@ -409,7 +409,6 @@ async def limits_middleware(request: web.Request, handler):
         # websocket sessions (realtime/relay) are long-lived by design —
         # the request timeout governs HTTP request/response cycles only
         try:
-            # wait_for (not asyncio.timeout): pyproject supports py3.10
             resp = await asyncio.wait_for(
                 handler(request), ctx.request_timeout_secs
             )

@@ -1,10 +1,13 @@
 """ctypes binding for the native C++ radix index (csrc/radix_index.cpp).
 
 Reference analogue: the Rust ``crates/kv_index`` backing the gateway's
-routing hot path.  Auto-builds ``libsmg_native.so`` on first use (make in
-csrc/); falls back to the pure-Python ``RadixTree`` when no toolchain is
-available.  Same interface as the Python tree so the cache_aware policy can
-swap implementations (``SMG_NATIVE_RADIX=0`` forces Python).
+routing hot path.  ``libsmg_native.so`` is a build product of
+``csrc/radix_index.cpp`` and of nothing else: it is (re)built with ``make``
+on first use whenever it is missing or older than its sources, and a library
+that cannot be brought up to date is not loaded.  Without a toolchain the
+pure-Python ``RadixTree`` serves instead; it has the same interface, so the
+cache_aware policy can swap implementations (``SMG_NATIVE_RADIX=0`` forces
+Python).
 
 Measured (benches/bench_gateway.py): at small trees the FFI boundary makes
 the implementations comparable; at 30k sequences x 64-512 tokens the native
@@ -26,24 +29,35 @@ logger = get_logger("kv_index.native")
 
 _CSRC = os.path.join(os.path.dirname(__file__), "..", "..", "csrc")
 _LIB_PATH = os.path.abspath(os.path.join(_CSRC, "libsmg_native.so"))
-_lib = None
+_SOURCES = ("radix_index.cpp", "Makefile")
+_lib = None  # the loaded CDLL; False once loading has failed in this process
 _lib_lock = threading.Lock()
+
+
+def _out_of_date() -> bool:
+    if not os.path.exists(_LIB_PATH):
+        return True
+    built = os.path.getmtime(_LIB_PATH)
+    return any(
+        os.path.getmtime(os.path.join(_CSRC, src)) > built for src in _SOURCES
+    )
 
 
 def _load_lib():
     global _lib
     with _lib_lock:
         if _lib is not None:
-            return _lib
+            return _lib or None
         if os.environ.get("SMG_NATIVE_RADIX") == "0":
             return None
-        if not os.path.exists(_LIB_PATH):
+        _lib = False
+        if _out_of_date():
             try:
                 subprocess.run(
-                    ["make", "-C", os.path.abspath(_CSRC)],
+                    ["make", "-B", "-C", os.path.abspath(_CSRC)],
                     check=True, capture_output=True, timeout=120,
                 )
-            except Exception as e:
+            except (OSError, subprocess.SubprocessError) as e:
                 logger.warning("native radix build failed (%s); using Python tree", e)
                 return None
         try:
@@ -163,13 +177,20 @@ class NativeRadixTree:
         }
 
 
+_announced = False
+
+
 def make_radix_tree(max_size: int = 2**20):
-    """Factory: native tree when available, Python tree otherwise."""
-    if native_available():
-        try:
-            return NativeRadixTree(max_size)
-        except RuntimeError:
-            pass
+    """Factory for the cache_aware policy: native tree when available,
+    Python tree otherwise; says which, once per process."""
+    global _announced
+    native = native_available()
+    if not _announced:
+        _announced = True
+        logger.info("cache_aware prefix index: %s tree",
+                    "native" if native else "python")
+    if native:
+        return NativeRadixTree(max_size)
     from smg_tpu.kv_index.radix_tree import RadixTree
 
     return RadixTree(max_size)

@@ -445,7 +445,7 @@ def forward_decode(
 
     # The full stacked cache rides the scan carry and is updated with
     # layer-indexed scatters — per-layer slice-out/stack-back would copy the
-    # whole cache every step (measured ~17 ms/step at 1B serving sizes).
+    # whole cache every step.
     def layer_body(carry, xs):
         h, k_cache, v_cache = carry
         if lora is not None:
@@ -606,7 +606,7 @@ def forward_decode_horizon(
     page_tables: jnp.ndarray,  # [B, mp]
     hk_all: jnp.ndarray,  # [L, B, N, K*D] horizon side buffers (carried)
     hv_all: jnp.ndarray,
-    attn_impl: str = "xla",
+    attn_impl: str = "xla",  # "xla" | "pallas" | "pallas_interpret" (tests)
     lora: Params | None = None,
     lora_gates: jnp.ndarray | None = None,  # [B, n_adapters] one-hot per slot
     pp_mesh=None,  # Mesh: serving pipeline parallelism over the "pp" axis
@@ -659,7 +659,7 @@ def forward_decode_horizon(
             )
             hk_l = jax.lax.dynamic_index_in_dim(hk_all, l, 0, keepdims=False)
             hv_l = jax.lax.dynamic_index_in_dim(hv_all, l, 0, keepdims=False)
-            if attn_impl == "pallas":
+            if attn_impl.startswith("pallas"):
                 from smg_tpu.ops.pallas.decode_attention import paged_attention_decode_cached
 
                 attn = paged_attention_decode_cached(
@@ -667,6 +667,7 @@ def forward_decode_horizon(
                     page_tables, entry_positions, scale,
                     softcap=cfg.attn_logit_softcap,
                     window=_layer_window(cfg, l),
+                    interpret=(attn_impl == "pallas_interpret"),
                 )
             else:
                 attn = attention_decode_cached(

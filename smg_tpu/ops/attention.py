@@ -10,10 +10,13 @@ Sequences own an ordered list of pages (``page_table``); the radix prefix cache
 shares page prefixes between sequences (``smg_tpu/engine/radix_cache.py``).
 Page 0 is reserved as a garbage page: padded/inactive tokens scatter there.
 
-Pallas TPU kernels for these two ops live in ``smg_tpu/ops/pallas/`` and are
-selected by ``smg_tpu.ops.dispatch`` on TPU backends; these XLA versions are
-the correctness reference and the CPU-test path (SURVEY.md §4 takeaway — the
-whole engine must run without TPU hardware).
+Pallas TPU kernels for solo prefill and horizon decode live in
+``smg_tpu/ops/pallas/``; ``ModelRunner._prefill_impl_for`` and
+``_attn_impl_for`` (``smg_tpu/engine/runner.py``) choose between them and
+these XLA versions per compiled program.  The XLA versions are the
+correctness reference, the only path for grouped prefill, verify blocks and
+meshes, and the CPU-test path (SURVEY.md §4 takeaway — the whole engine must
+run without TPU hardware).
 """
 
 from __future__ import annotations
@@ -22,6 +25,12 @@ import jax
 import jax.numpy as jnp
 
 NEG_INF = -1e30
+# Largest float32 score tensor one prefill attention call may materialize.
+# Past it the queries go through in blocks (``lax.map``): at the serving
+# defaults (4096-token chunk, 8192-slot table, 32 heads) the one-shot
+# ``[T, H, S]`` scores are 4 GiB, more than a 16 GB chip has left beside its
+# weights and cache.  256 MiB keeps every tier-1 shape on the one-shot path.
+SCORE_BLOCK_BYTES = 256 * 2**20
 
 
 def scatter_kv_pages(
@@ -61,6 +70,31 @@ def scatter_kv_pages_full(
     return k_flat.reshape(k_cache.shape), v_flat.reshape(v_cache.shape)
 
 
+def scatter_kv_rows(
+    k_cache: jnp.ndarray,  # [L, P, ps, KD] — FULL stacked cache
+    v_cache: jnp.ndarray,
+    k_rows: jnp.ndarray,  # [L, n, KD] side-buffer rows, every layer
+    v_rows: jnp.ndarray,
+    dest_slots: jnp.ndarray,  # [n] flat slot per row (the same in every layer)
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Land a decode horizon's (or verify block's) side-buffer rows in the
+    cache, all layers in one scatter.  The layer is part of the scatter
+    INDEX here too: written as ``cache.at[:, dest]`` (layer as a window
+    dimension) XLA:TPU moves the whole cache into a scatter-friendly layout
+    and back, two full copies a call and one buffer's worth of temporaries,
+    which at an auto-sized cache does not fit the chip."""
+    L, P, ps, KD = k_cache.shape
+    layer = jnp.arange(L)[:, None]
+    dest = dest_slots[None, :]
+    k_flat = k_cache.reshape(L, P * ps, KD).at[layer, dest].set(
+        k_rows.astype(k_cache.dtype)
+    )
+    v_flat = v_cache.reshape(L, P * ps, KD).at[layer, dest].set(
+        v_rows.astype(v_cache.dtype)
+    )
+    return k_flat.reshape(k_cache.shape), v_flat.reshape(v_cache.shape)
+
+
 def gather_seq_kv(
     k_pages: jnp.ndarray,  # [P, ps, KD]
     v_pages: jnp.ndarray,
@@ -78,6 +112,16 @@ def gather_seq_kv(
     )
 
 
+def _query_block(T: int, H: int, S: int) -> int:
+    """Queries per block: ``T`` halved until a ``[block, H, S]`` float32
+    score tensor fits ``SCORE_BLOCK_BYTES`` (or the block reaches 16 rows,
+    or stops dividing evenly).  Always divides ``T``."""
+    qb = T
+    while qb > 16 and qb % 2 == 0 and qb * H * S * 4 > SCORE_BLOCK_BYTES:
+        qb //= 2
+    return qb
+
+
 def attention_prefill(
     q: jnp.ndarray,  # [T, H, D] (new tokens, post-rope)
     k_ctx: jnp.ndarray,  # [S, K, D] contiguous KV incl. prefix and new tokens
@@ -88,26 +132,39 @@ def attention_prefill(
     softcap: float | None = None,  # tanh softcap on attention logits (Gemma-2)
     window: jnp.ndarray | None = None,  # scalar sliding window (<=0 = global)
 ) -> jnp.ndarray:
-    """Causal attention for one sequence's prefill chunk. GQA-aware."""
+    """Causal attention for one sequence's prefill chunk. GQA-aware.
+    Query blocks bound the score tensor (``SCORE_BLOCK_BYTES``)."""
     T, H, D = q.shape
     S, K, _ = k_ctx.shape
     G = H // K
-    qf = q.astype(jnp.float32).reshape(T, K, G, D)
     kf = k_ctx.astype(jnp.float32)
     vf = v_ctx.astype(jnp.float32)
-    scores = jnp.einsum("tkgd,skd->tkgs", qf, kf) * scale  # [T, K, G, S]
-    if softcap:
-        scores = softcap * jnp.tanh(scores / softcap)
     j = jnp.arange(S)
-    mask = (j[None, :] <= q_positions[:, None]) & (j[None, :] < ctx_len)  # [T, S]
-    if window is not None:
-        mask = mask & (
-            (window <= 0) | (j[None, :] > q_positions[:, None] - window)
-        )
-    scores = jnp.where(mask[:, None, None, :], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("tkgs,skd->tkgd", probs, vf)
-    return out.reshape(T, H, D).astype(q.dtype)
+
+    def attend(q_blk, pos_blk):
+        n = q_blk.shape[0]
+        qf = q_blk.astype(jnp.float32).reshape(n, K, G, D)
+        scores = jnp.einsum("tkgd,skd->tkgs", qf, kf) * scale  # [n, K, G, S]
+        if softcap:
+            scores = softcap * jnp.tanh(scores / softcap)
+        mask = (j[None, :] <= pos_blk[:, None]) & (j[None, :] < ctx_len)  # [n, S]
+        if window is not None:
+            mask = mask & (
+                (window <= 0) | (j[None, :] > pos_blk[:, None] - window)
+            )
+        scores = jnp.where(mask[:, None, None, :], scores, NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1)
+        out = jnp.einsum("tkgs,skd->tkgd", probs, vf)
+        return out.reshape(n, H, D).astype(q.dtype)
+
+    qb = _query_block(T, H, S)
+    if qb == T:
+        return attend(q, q_positions)
+    out = jax.lax.map(
+        lambda blk: attend(*blk),
+        (q.reshape(T // qb, qb, H, D), q_positions.reshape(T // qb, qb)),
+    )
+    return out.reshape(T, H, D)
 
 
 def attention_prefill_batched(
@@ -120,11 +177,19 @@ def attention_prefill_batched(
     softcap: float | None = None,
     window: jnp.ndarray | None = None,  # scalar sliding window (<=0 = global)
 ) -> jnp.ndarray:
-    """Batched multi-sequence prefill attention (one row per sequence)."""
+    """Batched multi-sequence prefill attention (one row per sequence).
+    When the ``[G, T, H, S]`` scores would pass ``SCORE_BLOCK_BYTES`` the
+    rows go one at a time through ``attention_prefill``'s query blocks."""
     G_, T, H, D = q.shape
     S = k_ctx.shape[1]
     K = k_ctx.shape[2]
     Gq = H // K
+    if G_ * T * H * S * 4 > SCORE_BLOCK_BYTES:
+        return jax.lax.map(
+            lambda row: attention_prefill(*row, scale, softcap=softcap,
+                                          window=window),
+            (q, k_ctx, v_ctx, q_positions, ctx_lens),
+        )
     qf = q.astype(jnp.float32).reshape(G_, T, K, Gq, D)
     kf = k_ctx.astype(jnp.float32)
     vf = v_ctx.astype(jnp.float32)

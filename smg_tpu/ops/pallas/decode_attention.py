@@ -6,15 +6,12 @@ TPU-native decode structure (multi-step horizon, ``runner.decode_multi``):
   step's new K/V rows accumulate in a small per-layer side buffer carried
   through the scan ([L, B, N, K*D] — a few MB).  After the scan, one
   top-level scatter lands the whole horizon into the donated cache buffers,
-  which XLA performs in place.  (Every design that updates the big cache
-  *inside* the loop — functional scatters, layer-sliced scans, aliased
-  kernel writes — measured 17-90 ms/step of pure cache copying at 1B
-  serving sizes; single-row in-kernel DMA writes violate sublane tiling.
-  PROVENANCE: one-off interactive v5e-1 measurements during round-3
-  development, not recorded in a committed BENCH artifact — the
-  environment's TPU has been unreachable every round.  The DESIGN
-  conclusion (don't copy the cache per step) holds regardless of the
-  exact constants.)
+  which XLA performs in place (``ops.attention.scatter_kv_rows``; written
+  with the layer as a scatter window it is not in place, see there).  Designs
+  that update the big cache *inside* the loop (functional scatters,
+  layer-sliced scans, aliased kernel writes) risk a copy of the whole cache
+  every step, and single-row in-kernel DMA writes violate sublane tiling; what
+  such a copy costs has not been measured (ROADMAP D4).
 
 - Attention therefore covers two ranges: cache pages (tokens < entry
   position, streamed HBM→VMEM with double-buffered DMA) and the first
@@ -41,8 +38,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-# pl.ANY replaced pltpu.ANY in newer jax; accept either
-_ANY = getattr(pl, "ANY", None) or pltpu.ANY
 
 
 def _decode_kernel(
@@ -91,13 +86,13 @@ def _decode_kernel(
     start_page = jnp.minimum(lo // ps, n_pages)
 
     def dma(i, slot):
-        page = page_tables_ref[b, i]
+        row0 = pl.multiple_of(page_tables_ref[b, i] * ps, ps)
         return (
             pltpu.make_async_copy(
-                k_hbm.at[layer, pl.ds(page * ps, ps)], k_buf.at[slot], sems.at[slot, 0]
+                k_hbm.at[layer, pl.ds(row0, ps)], k_buf.at[slot], sems.at[slot, 0]
             ),
             pltpu.make_async_copy(
-                v_hbm.at[layer, pl.ds(page * ps, ps)], v_buf.at[slot], sems.at[slot, 1]
+                v_hbm.at[layer, pl.ds(row0, ps)], v_buf.at[slot], sems.at[slot, 1]
             ),
         )
 
@@ -220,8 +215,8 @@ def paged_attention_decode_cached(
             pl.BlockSpec((1, H, KD), lambda b, *_: (b, 0, 0)),
             pl.BlockSpec((1, N, KD), lambda b, *_: (b, 0, 0)),
             pl.BlockSpec((1, N, KD), lambda b, *_: (b, 0, 0)),
-            pl.BlockSpec(memory_space=_ANY),
-            pl.BlockSpec(memory_space=_ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, H, KD), lambda b, *_: (b, 0, 0)),
         scratch_shapes=[
@@ -236,7 +231,6 @@ def paged_attention_decode_cached(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, KD), q.dtype),
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=96 * 1024 * 1024),
         interpret=interpret,
     )(
         page_tables.astype(jnp.int32),

@@ -16,12 +16,21 @@ Two attention ranges, merged in one online softmax:
   * the chunk itself: causal within the chunk (query t attends chunk cols
     j <= t, j < t_real), read directly from VMEM.
 
-GQA/head mapping: the grid is one program per group of ``C = max(1,
+GQA/head mapping: grid axis 0 is one program per group of ``C = max(1,
 128//D)`` KV heads, so each program's lane slice of the fused ``[ps, K*D]``
 cache page layout is 128-aligned even for D=64 models (Llama-3.2-1B).
 Within a program the C heads are folded block-diagonally into the queries
 (``q_bd[(t,c,g), c*D:(c+1)*D] = q[t, (c,g)]``) — one MXU matmul serves all
 of them; the caller extracts each head's diagonal D-lane band afterwards.
+
+Query tiling: grid axis 1 walks the chunk in tiles of ``TQ`` tokens
+(``RQ = TQ*C*G`` query rows).  VMEM then holds one ``[RQ, max(BT, TQ)]``
+score block whatever ``T`` is; holding all ``T*C*G`` rows and an ``[R, T]``
+score block in one program needed 149 MB of VMEM at T=2048 on a v5e (128 MB)
+and did not compile.  The price is that each tile streams the prefix again
+(``T/TQ`` passes).  The chunk's own K/V stay in VMEM for every tile (their
+block index does not change along axis 1); tile ``i`` attends chunk key
+blocks ``0..i`` and tiles wholly past ``t_real`` do nothing.
 
 Masking note: chunk tokens past the page-table capacity (``prefix_len + t >=
 mp*ps``) are still attended here, while the XLA path drops them (they never
@@ -39,8 +48,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-# pl.ANY replaced pltpu.ANY in newer jax; accept either
-_ANY = getattr(pl, "ANY", None) or pltpu.ANY
+# Query-tile width in tokens.  At the llama3.2-1b widths (C*G = 8) a tile is
+# 2048 query rows: 1 MiB each for the f32 queries and accumulator, 2 MiB of
+# statistics and 2 MiB per score block.  Not tuned: no time has been measured.
+Q_TILE = 256
 
 
 def _prefill_kernel(
@@ -48,18 +59,18 @@ def _prefill_kernel(
     page_table_ref,  # [mp] int32 (SMEM)
     meta_ref,  # [4] int32 (SMEM): [prefix_len, t_real, layer, window]
     # inputs
-    q_ref,  # [1, R, CD] VMEM — block-diagonal queries (R = T*C*G)
+    q_ref,  # [1, RQ, CD] VMEM — block-diagonal queries of this tile (RQ = TQ*C*G)
     ck_ref,  # [1, T, CD] VMEM — chunk keys (this program's lane slice)
     cv_ref,  # [1, T, CD] VMEM
     k_hbm,  # [L, P*ps, KD] HBM (read-only cache)
     v_hbm,
     # outputs
-    out_ref,  # [1, R, CD] VMEM
+    out_ref,  # [1, RQ, CD] VMEM
     # scratch
     k_buf,  # [2, BT, CD] VMEM
     v_buf,
-    acc_ref,  # [R, CD] f32
-    stat_ref,  # [R, 256] f32 (col 0 = m, col 128 = l)
+    acc_ref,  # [RQ, CD] f32
+    stat_ref,  # [RQ, 256] f32 (col 0 = m, col 128 = l)
     sems,  # DMA sems [2, PPB, 2]
     *,
     ps: int,
@@ -69,35 +80,37 @@ def _prefill_kernel(
     softcap: float,
 ):
     prog = pl.program_id(0)
+    tile = pl.program_id(1)
     R = q_ref.shape[1]
-    T = ck_ref.shape[1]
     CD = q_ref.shape[2]
+    tq = R // cg
     mp = page_table_ref.shape[0]
     bt = ppb * ps
     prefix_len = meta_ref[0]
     t_real = meta_ref[1]
     layer = meta_ref[2]
     window = meta_ref[3]
-    lane0 = prog * CD
+    lane0 = pl.multiple_of(prog * CD, CD)
+    q0 = tile * tq  # chunk index of this tile's first query
 
     n_blocks = (prefix_len + bt - 1) // bt
-    # sliding window: the EARLIEST query in the chunk sits at prefix_len, so
-    # prefix blocks wholly below ``prefix_len - window`` are skipped — the
-    # DMA loop starts at the first block any query can still see
-    lo_min = jnp.where(window > 0, jnp.maximum(prefix_len - window + 1, 0), 0)
+    # sliding window: the EARLIEST query of the tile sits at prefix_len + q0,
+    # so prefix blocks wholly below its window are skipped — the DMA loop
+    # starts at the first block any query of the tile can still see
+    lo_min = jnp.where(window > 0, jnp.maximum(prefix_len + q0 - window + 1, 0), 0)
     start_block = jnp.minimum(lo_min // bt, n_blocks)
 
     def dma(i, g, slot):
         idx = jnp.minimum(i * ppb + g, mp - 1)
-        page = page_table_ref[idx]
+        row0 = pl.multiple_of(page_table_ref[idx] * ps, ps)
         return (
             pltpu.make_async_copy(
-                k_hbm.at[layer, pl.ds(page * ps, ps), pl.ds(lane0, CD)],
+                k_hbm.at[layer, pl.ds(row0, ps), pl.ds(lane0, CD)],
                 k_buf.at[slot, pl.ds(g * ps, ps)],
                 sems.at[slot, g, 0],
             ),
             pltpu.make_async_copy(
-                v_hbm.at[layer, pl.ds(page * ps, ps), pl.ds(lane0, CD)],
+                v_hbm.at[layer, pl.ds(row0, ps), pl.ds(lane0, CD)],
                 v_buf.at[slot, pl.ds(g * ps, ps)],
                 sems.at[slot, g, 1],
             ),
@@ -112,16 +125,6 @@ def _prefill_kernel(
         for g in range(ppb):
             for c in dma(i, g, slot):
                 c.wait()
-
-    acc_ref[:] = jnp.zeros_like(acc_ref)
-    stat_ref[:, 0:128] = jnp.full((R, 128), NEG_INF, jnp.float32)
-    stat_ref[:, 128:256] = jnp.zeros((R, 128), jnp.float32)
-
-    @pl.when(n_blocks > start_block)
-    def _prologue():
-        start_dma(start_block, jax.lax.rem(start_block, 2))
-
-    q = q_ref[0].astype(jnp.float32)  # [R, CD]
 
     def cap(scores):
         if softcap:
@@ -143,49 +146,72 @@ def _prefill_kernel(
         stat_ref[:, 0:1] = m_new
         stat_ref[:, 128:129] = l_new
 
-    def body(i, _):
-        slot = jax.lax.rem(i, 2)
+    @pl.when(q0 >= t_real)
+    def _padding_tile():
+        out_ref[0] = jnp.zeros(out_ref.shape[1:], out_ref.dtype)
 
-        @pl.when(i + 1 < n_blocks)
-        def _prefetch():
-            start_dma(i + 1, jax.lax.rem(i + 1, 2))
+    @pl.when(q0 < t_real)
+    def _live_tile():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        stat_ref[:, 0:128] = jnp.full((R, 128), NEG_INF, jnp.float32)
+        stat_ref[:, 128:256] = jnp.zeros((R, 128), jnp.float32)
 
-        wait_dma(i, slot)
-        k = k_buf[slot].astype(jnp.float32)  # [BT, CD]
-        v = v_buf[slot].astype(jnp.float32)
-        scores = cap(jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale)  # [R, BT]
-        slot_pos = i * bt + jax.lax.broadcasted_iota(jnp.int32, (R, bt), 1)
-        keep = slot_pos < prefix_len
-        # per-row window cut: query row r sits at prefix_len + r//cg
-        qpos_row = prefix_len + jax.lax.broadcasted_iota(jnp.int32, (R, bt), 0) // cg
-        keep &= (window <= 0) | (slot_pos > qpos_row - window)
-        scores = jnp.where(keep, scores, NEG_INF)
-        merge(scores, v)
-        return 0
+        @pl.when(n_blocks > start_block)
+        def _prologue():
+            start_dma(start_block, jax.lax.rem(start_block, 2))
 
-    jax.lax.fori_loop(start_block, n_blocks, body, 0)
+        q = q_ref[0].astype(jnp.float32)  # [R, CD]
 
-    # the chunk itself: causal, straight from VMEM
-    ck = ck_ref[0].astype(jnp.float32)  # [T, CD]
-    cv = cv_ref[0].astype(jnp.float32)
-    s_chunk = cap(jax.lax.dot_general(
-        q, ck, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale)  # [R, T]
-    t_row = jax.lax.broadcasted_iota(jnp.int32, (R, T), 0) // cg
-    col = jax.lax.broadcasted_iota(jnp.int32, (R, T), 1)
-    keep = (col <= t_row) & (col < t_real)
-    # both query and key sit at prefix_len + {t_row, col}: offsets cancel
-    keep &= (window <= 0) | (col > t_row - window)
-    s_chunk = jnp.where(keep, s_chunk, NEG_INF)
-    merge(s_chunk, cv)
+        def score(keys):  # [S, CD] -> [R, S]
+            return cap(jax.lax.dot_general(
+                q, keys, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            ) * scale)
 
-    l = stat_ref[:, 128:129]
-    out_ref[0] = (acc_ref[:] / jnp.maximum(l, 1e-20)).astype(out_ref.dtype)
+        def prefix_body(i, _):
+            slot = jax.lax.rem(i, 2)
+
+            @pl.when(i + 1 < n_blocks)
+            def _prefetch():
+                start_dma(i + 1, jax.lax.rem(i + 1, 2))
+
+            wait_dma(i, slot)
+            scores = score(k_buf[slot].astype(jnp.float32))  # [R, BT]
+            slot_pos = i * bt + jax.lax.broadcasted_iota(jnp.int32, (R, bt), 1)
+            keep = slot_pos < prefix_len
+            # per-row window cut: query row r sits at prefix_len + q0 + r//cg
+            qpos_row = (prefix_len + q0
+                        + jax.lax.broadcasted_iota(jnp.int32, (R, bt), 0) // cg)
+            keep &= (window <= 0) | (slot_pos > qpos_row - window)
+            merge(jnp.where(keep, scores, NEG_INF), v_buf[slot].astype(jnp.float32))
+            return 0
+
+        jax.lax.fori_loop(start_block, n_blocks, prefix_body, 0)
+
+        # the chunk itself: causal, straight from VMEM, key blocks 0..tile.
+        # The diagonal block comes last and holds every valid row's own key,
+        # so a row whose earlier blocks were all masked still ends on a real
+        # maximum (alpha = 0 wipes what the masked blocks accumulated).
+        def chunk_body(j, _):
+            c0 = pl.multiple_of(j * tq, tq)
+            scores = score(ck_ref[0, pl.ds(c0, tq), :].astype(jnp.float32))  # [R, TQ]
+            t_row = q0 + jax.lax.broadcasted_iota(jnp.int32, (R, tq), 0) // cg
+            col = c0 + jax.lax.broadcasted_iota(jnp.int32, (R, tq), 1)
+            keep = (col <= t_row) & (col < t_real)
+            # both query and key sit at prefix_len + {t_row, col}: offsets cancel
+            keep &= (window <= 0) | (col > t_row - window)
+            merge(jnp.where(keep, scores, NEG_INF),
+                  cv_ref[0, pl.ds(c0, tq), :].astype(jnp.float32))
+            return 0
+
+        jax.lax.fori_loop(0, tile + 1, chunk_body, 0)
+
+        l = stat_ref[:, 128:129]
+        out_ref[0] = (acc_ref[:] / jnp.maximum(l, 1e-20)).astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "softcap", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("scale", "softcap", "interpret", "q_tile")
+)
 def paged_attention_prefill(
     q: jax.Array,  # [T, H, D] post-rope chunk queries
     chunk_k: jax.Array,  # [T, K*D] post-rope chunk keys (fused lanes)
@@ -200,9 +226,10 @@ def paged_attention_prefill(
     softcap: float | None = None,  # tanh softcap on attn logits (Gemma-2)
     window=None,  # scalar int32 sliding window (None/<=0 = global)
     interpret: bool = False,
+    q_tile: int = Q_TILE,  # tokens per query tile (tests shrink it)
 ) -> jax.Array:
     """Prefix-aware chunked-prefill attention for ONE sequence.
-    Returns [T, H, D]."""
+    Returns [T, H, D]; rows past ``t_real`` are unspecified."""
     T, H, D = q.shape
     L, P, ps, KD = k_cache.shape
     K = KD // D
@@ -217,6 +244,10 @@ def paged_attention_prefill(
     CD = C * D
     R = T * C * G
     ppb = max(1, 128 // ps)
+    tq = min(T, q_tile)
+    if T % tq:
+        raise ValueError(f"chunk length {T} is not a multiple of the query tile {tq}")
+    rq = tq * C * G
 
     # [T, H, D] -> [KC, T, C, G, D], then fold C block-diagonally into lanes
     q5 = q.reshape(T, KC, C, G, D).transpose(1, 0, 2, 3, 4)
@@ -240,20 +271,20 @@ def paged_attention_prefill(
                                scale=scale, softcap=float(softcap or 0.0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(KC,),
+        grid=(KC, T // tq),
         in_specs=[
-            pl.BlockSpec((1, R, CD), lambda p, *_: (p, 0, 0)),
-            pl.BlockSpec((1, T, CD), lambda p, *_: (p, 0, 0)),
-            pl.BlockSpec((1, T, CD), lambda p, *_: (p, 0, 0)),
-            pl.BlockSpec(memory_space=_ANY),
-            pl.BlockSpec(memory_space=_ANY),
+            pl.BlockSpec((1, rq, CD), lambda p, i, *_: (p, i, 0)),
+            pl.BlockSpec((1, T, CD), lambda p, i, *_: (p, 0, 0)),
+            pl.BlockSpec((1, T, CD), lambda p, i, *_: (p, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, R, CD), lambda p, *_: (p, 0, 0)),
+        out_specs=pl.BlockSpec((1, rq, CD), lambda p, i, *_: (p, i, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, ppb * ps, CD), k_cache.dtype),
             pltpu.VMEM((2, ppb * ps, CD), v_cache.dtype),
-            pltpu.VMEM((R, CD), jnp.float32),
-            pltpu.VMEM((R, 256), jnp.float32),
+            pltpu.VMEM((rq, CD), jnp.float32),
+            pltpu.VMEM((rq, 256), jnp.float32),
             pltpu.SemaphoreType.DMA((2, ppb, 2)),
         ],
     )

@@ -15,6 +15,7 @@ from dataclasses import dataclass
 
 import jax
 import numpy as np
+from jax.experimental import mesh_utils
 from jax.sharding import Mesh
 
 from smg_tpu.engine.config import ParallelConfig
@@ -40,8 +41,10 @@ class MeshSpec:
 def build_mesh(parallel: ParallelConfig, devices: list | None = None) -> Mesh:
     """Build a Mesh for the given parallel config.
 
-    Uses ``jax.experimental.mesh_utils`` for torus-aware placement when the
-    device count matches, otherwise a plain reshape (CPU fake meshes).
+    ``jax.experimental.mesh_utils`` places the axes on the torus.  Where it
+    refuses the shape, virtual CPU devices (which have no torus) take a
+    plain reshape; TPU devices do not, because a linear order there would
+    put ``tp`` on whatever links it happens to get and say nothing.
     """
     spec = MeshSpec(parallel)
     if devices is None:
@@ -53,12 +56,13 @@ def build_mesh(parallel: ParallelConfig, devices: list | None = None) -> Mesh:
         )
     devices = devices[:world]
     try:
-        from jax.experimental import mesh_utils
-
         dev_array = mesh_utils.create_device_mesh(spec.shape, devices=devices)
-    except (ImportError, ValueError, AssertionError) as e:
-        # CPU fake meshes and odd topologies: fall back to linear order, but
-        # say so — on real slices this costs torus-optimal ICI placement.
+    except (ValueError, AssertionError) as e:
+        if devices[0].platform == "tpu":
+            raise ValueError(
+                f"no torus placement for mesh {parallel.axis_sizes()} over "
+                f"{world} TPU devices: {e}"
+            ) from e
         logging.getLogger("smg_tpu.parallel").debug(
             "mesh_utils placement failed (%s); using linear device order", e
         )

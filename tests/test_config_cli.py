@@ -287,3 +287,29 @@ def test_mesh_shape_flag_conflict_is_error():
     # malformed string surfaces as a startup error, not a trace-time one
     assert any("mesh_shape" in i.field
                for i in _errors(_serve("--mesh-shape", "bogus=2")))
+
+
+def test_launch_pins_its_process_to_the_cpu_platform(monkeypatch):
+    """One process per host owns the chips (the worker).  `launch` runs
+    jax.numpy for image preprocessing, so it must put itself on the CPU
+    platform, whatever it inherits, before anything imports jax."""
+    import os
+
+    from smg_tpu.gateway import launch
+
+    seen = {}
+
+    async def fake_gateway(args):
+        seen["platforms"] = os.environ.get("JAX_PLATFORMS")
+        return 0
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    monkeypatch.setattr(launch, "_run_gateway", fake_gateway)
+    args = build_parser().parse_args(["launch", "--port", "0"])
+    assert launch.run_command(args) == 0
+    assert seen["platforms"] == "cpu"
+    # `serve` owns the chips itself and keeps what it was given
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    args = build_parser().parse_args(["serve", "--model-preset", "tiny"])
+    assert launch.run_command(args) == 0
+    assert seen["platforms"] == "tpu"

@@ -388,3 +388,53 @@ def test_engine_auto_size_smoke():
     assert eng.runner.spec.num_pages >= 16
     res = eng.generate(prompt_ids=list(range(5, 15)), sampling=greedy(4))
     assert len(res.token_ids) == 4
+
+
+def test_warmup_runs_largest_programs_and_leaves_no_trace():
+    """Engine.warmup (what `serve`/`worker` run before binding a port)
+    compiles and runs the largest solo-prefill, grouped-prefill and decode
+    programs; everything it writes lands on the garbage page and the
+    sampling-key counter is restored, so serving afterwards is byte-identical
+    to an engine that never warmed up."""
+    import numpy as np
+
+    prompt = list(range(5, 40))
+    sampled = SamplingParams(temperature=0.8, max_new_tokens=6, ignore_eos=True)
+    cold = make_engine(decode_horizon=4)
+    want = cold.generate(prompt_ids=prompt, sampling=sampled).token_ids
+
+    eng = make_engine(decode_horizon=4)
+    took = eng.warmup()
+    assert [name for name, _ in took] == [
+        "prefill_extend", "prefill", "prefill_batched", "decode_multi"]
+    keys = list(eng.runner._compiled)
+    assert ("prefill_extend", 64, 16, "xla") == keys[0][:4]
+    assert ("prefill_batched", 8, 16, 16, False) == keys[2][:5]  # ctx variant
+    assert ("decode_multi", 8, 16, 4) == keys[3][:4]
+    assert eng.runner._step == 0
+    assert not np.asarray(eng.runner.k_cache[:, 1:]).any()
+    assert eng.generate(prompt_ids=prompt, sampling=sampled).token_ids == want
+    # launches were counted under the implementation the rule chose
+    assert eng.loads()["attention"] == {
+        "mode": "xla",
+        "launches": {"xla": eng.runner.attn_launches["xla"],
+                     "pallas_prefill": 0, "pallas_decode": 0},
+    }
+    assert eng.runner.attn_launches["xla"] >= 4
+
+
+def test_tpu_without_memory_stats_is_a_startup_error(monkeypatch):
+    """A TPU that reports no memory statistics must not be served from the
+    default 2048-page cache; the CPU client (which has none) keeps the
+    configured page count."""
+    from smg_tpu.engine.runner import ModelRunner
+
+    r = object.__new__(ModelRunner)
+    r.config = EngineConfig(model=tiny_test_config())
+    r.mesh = None
+    r._device = None
+    r.platform = "cpu"
+    assert r._detect_hbm() is None
+    r.platform = "tpu"
+    with pytest.raises(RuntimeError, match="memory_stats"):
+        r._detect_hbm()

@@ -123,7 +123,7 @@ def test_gemma2_generates_and_differs_from_llama():
         assert "post_attn_norm" in g.runner.params["layers"]
         assert "post_mlp_norm" in g.runner.params["layers"]
         # gemma forces the XLA attention paths (kernels lack softcap)
-        assert g.runner._prefill_impl_for(8) == "xla"
+        assert g.runner._prefill_impl_for(64, 8) == "xla"
         assert g.runner._attn_impl_for(64, 512) == "xla"
     finally:
         g.stop()

@@ -193,6 +193,34 @@ def test_native_radix_parity_with_python():
         assert py.prefix_match(probe) == nat.prefix_match(probe)
 
 
+def test_native_radix_is_rebuilt_when_its_source_is_newer(tmp_path, monkeypatch):
+    """The library is a build product of csrc/radix_index.cpp: one that is
+    older than the source is rebuilt before it is loaded, never loaded as
+    found."""
+    import os
+    import shutil
+
+    from smg_tpu.kv_index import native
+
+    if shutil.which("make") is None or shutil.which(os.environ.get("CXX", "g++")) is None:
+        pytest.skip("no make / C++ compiler here")
+    csrc = tmp_path / "csrc"
+    csrc.mkdir()
+    for name in ("radix_index.cpp", "Makefile"):
+        shutil.copy(os.path.join(native._CSRC, name), csrc / name)
+    stale = csrc / "libsmg_native.so"
+    stale.write_bytes(b"not a shared object")
+    os.utime(stale, (1, 1))  # older than the source
+    monkeypatch.setattr(native, "_CSRC", str(csrc))
+    monkeypatch.setattr(native, "_LIB_PATH", str(stale))
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.delenv("SMG_NATIVE_RADIX", raising=False)
+    assert native._out_of_date()
+    assert native._load_lib() is not None  # the junk file would not have loaded
+    assert not native._out_of_date()
+    assert stale.stat().st_size > 1000
+
+
 # ---- routing decision records (gateway/route_observability.py consumes) ----
 
 

@@ -1,0 +1,203 @@
+"""The attention dispatch rule, and whether what it can choose compiles for a
+TPU v5e.
+
+libtpu compiles for a topology it does not have
+(``jax.experimental.topologies``), so Mosaic and XLA:TPU can refuse a kernel
+here, on the CPU, before it costs chip time.  This checks compilation and
+compile-time memory only; what the kernels compute on the chip is
+``chip_smoke.py`` phase b's business, and interpret-mode parity is
+``test_pallas_*.py``'s.
+"""
+
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from smg_tpu.engine.config import EngineConfig, ParallelConfig
+from smg_tpu.engine.kv_cache import KvCacheSpec
+from smg_tpu.engine.runner import PREFILL_KERNEL_MAX_T, ModelRunner
+from smg_tpu.models.config import llama32_1b_config
+from smg_tpu.models.registry import get_model
+from smg_tpu.ops.attention import SCORE_BLOCK_BYTES, attention_prefill, scatter_kv_rows
+from smg_tpu.ops.pallas.decode_attention import paged_attention_decode_cached
+from smg_tpu.ops.pallas.prefill_attention import paged_attention_prefill
+from smg_tpu.parallel.mesh import build_mesh
+from smg_tpu.parallel.sharding import ShardingRules, logical_to_sharding, tree_shardings
+
+CFG = llama32_1b_config()
+PS = 16
+KD = CFG.num_kv_heads * CFG.head_dim
+BF16 = jnp.bfloat16
+
+
+def _rule(platform, mesh=None, attention_impl="auto", model=CFG) -> ModelRunner:
+    """A runner with just the state the dispatch rule reads."""
+    r = object.__new__(ModelRunner)
+    r.config = EngineConfig(model=model, attention_impl=attention_impl)
+    r.model_cfg = model
+    r.platform = platform
+    r.mesh = mesh
+    r.use_pp = False
+    r.spec = KvCacheSpec(model.num_layers, 64, PS, model.num_kv_heads,
+                         model.head_dim, "bfloat16")
+    r.attn_impl = r._resolve_attn_impl()
+    return r
+
+
+class TestDispatchRule:
+    def test_one_tpu_chip_chooses_from_shapes(self):
+        r = _rule("tpu")
+        assert r.attn_impl == "auto"
+        # prefill: the kernel for wide tables, up to its bound and no further
+        assert r._prefill_impl_for(PREFILL_KERNEL_MAX_T, 512) == "pallas"
+        assert r._prefill_impl_for(64, 512) == "pallas"
+        assert r._prefill_impl_for(64, 128) == "xla"  # 2048 slots: gather is cheap
+        assert r._prefill_impl_for(2 * PREFILL_KERNEL_MAX_T, 1024) == "xla"
+        # decode: the kernel past 131072 gathered tokens
+        assert r._attn_impl_for(64, 256) == "pallas"
+        assert r._attn_impl_for(32, 256) == "xla"
+        assert r._attn_impl_for(8, 512) == "xla"
+
+    def test_kernel_never_above_its_bound_even_when_forced(self):
+        r = _rule("tpu", attention_impl="pallas")
+        assert r._prefill_impl_for(PREFILL_KERNEL_MAX_T, 8) == "pallas"
+        assert r._prefill_impl_for(2 * PREFILL_KERNEL_MAX_T, 1024) == "xla"
+
+    def test_mesh_answers_xla(self, cpu_devices):
+        mesh = build_mesh(ParallelConfig(tp=4), devices=cpu_devices[:4])
+        r = _rule("tpu", mesh=mesh)
+        assert r.attn_impl == "xla"
+        assert r._prefill_impl_for(4096, 512) == "xla"
+        assert r._attn_impl_for(64, 512) == "xla"
+        with pytest.raises(ValueError, match="under a mesh"):
+            _rule("tpu", mesh=mesh, attention_impl="pallas")
+
+    def test_other_platforms_and_narrow_heads_answer_xla(self, tiny_cfg):
+        assert _rule("cpu").attn_impl == "xla"
+        assert _rule("tpu", model=tiny_cfg).attn_impl == "xla"  # 32 KV lanes
+
+    def test_no_environment_variable_takes_part(self, monkeypatch):
+        """The rule reads the config, the platform, the mesh and shapes."""
+        import os
+
+        class Untouchable(dict):
+            def _read(self, *_):
+                raise AssertionError("the dispatch rule read the environment")
+
+            get = __getitem__ = __contains__ = _read
+
+        monkeypatch.setattr(os, "environ", Untouchable())
+        r = _rule("tpu")
+        assert r.attn_impl == "auto"
+        assert r._prefill_impl_for(4096, 512) == "pallas"
+        assert r._attn_impl_for(64, 256) == "pallas"
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever libtpu raises without support
+        pytest.skip(f"libtpu cannot build the v5e:2x2 topology here: {e}")
+    return list(topo.devices)
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+class TestCompilesForV5e:
+    """Each side of the rule, at the llama3.2-1b widths and serving defaults
+    (page 16, 8192-token tables, 4096-token chunks, batch 64)."""
+
+    def _sds(self, v5e):
+        one = SingleDeviceSharding(v5e[0])
+        return lambda shape, dtype=BF16: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def test_prefill_kernel_at_its_largest_chunk(self, v5e):
+        s = self._sds(v5e)
+        T, mp, L, P = PREFILL_KERNEL_MAX_T, 512, CFG.num_layers, 1024
+        _compile(
+            functools.partial(paged_attention_prefill, scale=0.125),
+            s((T, CFG.num_heads, CFG.head_dim)), s((T, KD)), s((T, KD)),
+            s((L, P, PS, KD)), s((L, P, PS, KD)), s((), jnp.int32),
+            s((mp,), jnp.int32), s((), jnp.int32), s((), jnp.int32),
+        )
+
+    def test_decode_kernel_at_batch_64(self, v5e):
+        s = self._sds(v5e)
+        B, mp, N, L, P = 64, 256, 8, CFG.num_layers, 1024
+        _compile(
+            functools.partial(paged_attention_decode_cached, scale=0.125),
+            s((B, CFG.num_heads, CFG.head_dim)), s((L, P, PS, KD)),
+            s((L, P, PS, KD)), s((B, N, KD)), s((B, N, KD)), s((), jnp.int32),
+            s((), jnp.int32), s((B, mp), jnp.int32), s((B,), jnp.int32),
+        )
+
+    def test_xla_prefill_stays_under_its_score_block(self, v5e):
+        """The other side of the switch at the largest chunk: one-shot
+        scores would be 4 GiB; blocked, the program's temporaries must stay
+        within a few score blocks."""
+        s = self._sds(v5e)
+        T, S = 4096, 8192
+        compiled = _compile(
+            functools.partial(attention_prefill, scale=0.125),
+            s((T, CFG.num_heads, CFG.head_dim)),
+            s((S, CFG.num_kv_heads, CFG.head_dim)),
+            s((S, CFG.num_kv_heads, CFG.head_dim)),
+            s((T,), jnp.int32), s((), jnp.int32),
+        )
+        assert compiled.memory_analysis().temp_size_in_bytes <= 4 * SCORE_BLOCK_BYTES
+
+    def test_decode_scatter_leaves_the_cache_in_place(self, v5e):
+        """The scatter that lands a decode horizon in the donated cache must
+        not relayout it: with the layer as a window dimension
+        (``cache.at[:, dest]``) XLA:TPU copies the whole buffer into a
+        scatter-friendly layout and back, one buffer of temporaries, and the
+        decode program no longer fits beside an auto-sized cache."""
+        s = self._sds(v5e)
+        L, P, n = CFG.num_layers, 4096, 64 * 8
+        cache, rows = s((L, P, PS, KD)), s((L, n, KD))
+        compiled = jax.jit(scatter_kv_rows, donate_argnums=(0, 1)).lower(
+            cache, cache, rows, rows, s((n,), jnp.int32)).compile()
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes == 2 * L * P * PS * KD * 2  # both buffers
+        assert mem.temp_size_in_bytes < 2**20
+
+    def test_decode_step_under_tp4(self, v5e):
+        """A tp=4 mesh takes the XLA side (the rule's mesh answer): one
+        decode step, depth cut to two layers, must partition and compile."""
+        cfg = dataclasses.replace(CFG, num_layers=2)
+        module = get_model(cfg.arch)
+        mesh = build_mesh(ParallelConfig(tp=4), devices=v5e)
+        rules = ShardingRules()
+        impl = _rule("tpu", mesh=mesh)._attn_impl_for(64, 256)
+        assert impl == "xla"
+        rep = logical_to_sharding((), mesh, rules)
+        shapes = jax.eval_shape(
+            functools.partial(module.init_params, cfg), jax.random.PRNGKey(0))
+        params = jax.tree.map(
+            lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+            shapes, tree_shardings(module.logical_axes(cfg), mesh, rules, shapes=shapes),
+        )
+        B, mp, N, L, P = 64, 256, 8, cfg.num_layers, 1024
+
+        def s(shape, dtype=BF16, axes=()):
+            sharding = logical_to_sharding(axes, mesh, rules, shape=shape) if axes else rep
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+        cache = s((L, P, PS, KD), axes=("layers", "pages", None, "kv_lanes"))
+        side = s((L, B, N, KD), axes=("layers", None, None, "kv_lanes"))
+        _compile(
+            lambda p, inv, *a: module.forward_decode_horizon(
+                p, cfg, inv, *a, attn_impl=impl),
+            params, s((cfg.head_dim // 2,), jnp.float32), s((B,), jnp.int32),
+            s((B,), jnp.int32), s((B,), jnp.int32), s((), jnp.int32), cache, cache,
+            s((B, mp), jnp.int32), side, side,
+        )
