@@ -1,0 +1,11 @@
+"""The 95th percentile of the time from when a request was due to its first streamed token, on the client's clock.  Recorded, not
+judged: under a closed loop at full load it swings with how requests happen
+to fall against the megastep in flight (PERF.md, Findings, PR 24)."""
+
+from _common import caller_latency
+
+META = {"layer": "caller", "unit": "ms", "moves": "output_tok_per_s", "source": "host_clock"}
+
+
+def read(ctx):
+    return caller_latency(ctx, 0, 0.95)

@@ -1,0 +1,601 @@
+#!/usr/bin/env python3
+"""One run of one cell of BENCHMARK.json.
+
+    python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+
+The process holds the cell's chips and does what ``smg-tpu serve`` does, by
+the program's own code: ``gateway.launch._run_gateway`` builds the engine,
+warms it, registers the tokenizer and the in-process worker client, builds
+the app and binds a port.  The cell's configuration reaches it as a model
+preset registered from ``benchmark/configs/<config>.json`` (``serve`` has
+presets for Llama only and ``--model-path`` wants safetensors), so weights
+are random, made on the device by the engine from its own seed, and the
+tokenizer is the vocab-matched mock.  The load generator is a child process
+(``loadgen.py``) that never imports JAX and talks HTTP to the port.
+
+Set-up (all of it counted in ``setup_s``): import, weights, cache, every
+program the cell's traffic can reach (``warm.py``), the program's own
+warm-up, a slice of the cell's traffic from another seed, and the
+correctness check (``reference.py``).  Then the window.
+
+The last line of standard output is the result, one JSON object.  Without a
+TPU the run prints no result and exits 3; ``--rehearsal`` is the only way
+onto the CPU (tiny widths, kernels interpreted, ``"rehearsal": true``).
+"""
+
+from __future__ import annotations
+
+import time
+
+T_START = time.monotonic()  # process start, as near as Python lets us see it
+
+import argparse  # noqa: E402
+import asyncio  # noqa: E402
+import json  # noqa: E402
+import os  # noqa: E402
+import signal  # noqa: E402
+import statistics  # noqa: E402
+import sys  # noqa: E402
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+sys.path.insert(0, HERE)
+
+import catalog  # noqa: E402
+from client_reduce import MISSED_MS, latencies, percentile, request_ok  # noqa: E402
+
+
+def log(msg: str) -> None:
+    print(f"bench[{time.monotonic() - T_START:7.1f}s]: {msg}", file=sys.stderr, flush=True)
+
+
+# --------------------------------------------------------------------------
+# client-side reduction
+
+
+#: how much of the window's end a ``--trace 1`` run records
+TRACE_SECONDS = 12.0
+
+
+def end_to_end(result: dict) -> dict:
+    """The end-to-end metrics of one window, from the client's records."""
+    reqs = result["requests"]
+    t0, t1 = result["t0"], result["t0"] + result["seconds"]
+    ttft, tpot = latencies(reqs)
+    # a rate is taken over all the work of the window: every token that
+    # reached the client inside it, whether or not its request ended there
+    tokens_in_window = sum(r["tokens_in_window"] for r in reqs)
+    tokens_completed = sum(r["output_tokens"] for r in reqs
+                           if request_ok(r) and r["done"] <= t1)
+    failed = sum(1 for r in reqs if not request_ok(r))
+    late = [(r["sent"] - r["due"]) * 1e3 for r in reqs if r["sent"] is not None]
+    good_ttft = [v for v in ttft if v is not None]
+    good_tpot = [v for v in tpot if v is not None]
+    return {
+        "attempted": len(reqs), "failed": failed,
+        "metrics": {
+            "ttft_p95_ms": min(percentile(ttft, 0.95), MISSED_MS),
+            "tpot_p95_ms": min(percentile(tpot, 0.95), MISSED_MS),
+            "output_tok_per_s": tokens_in_window / (t1 - t0),
+        },
+        "detail": {
+            "ttft_p50_ms": statistics.median(good_ttft) if good_ttft else None,
+            "tpot_p50_ms": statistics.median(good_tpot) if good_tpot else None,
+            "samples": len(good_ttft),
+            "completed_request_tok_per_s": tokens_completed / (t1 - t0),
+            "generator_late_p50_ms": statistics.median(late) if late else None,
+            "generator_late_max_ms": max(late) if late else None,
+            "prompt_tokens": sum(r["prompt_tokens"] for r in reqs),
+            "cached_tokens": sum(r["cached_tokens"] for r in reqs),
+            "output_tokens": sum(r["output_tokens"] for r in reqs),
+            "backlog_mid": backlog(reqs, t0 + result["seconds"] / 2),
+            "backlog_end": backlog(reqs, t1),
+            "live_kv_tokens_peak": live_tokens_peak(reqs),
+            "errors": sorted({r["error"] or f"finish={r['finish']}" for r in reqs
+                              if not request_ok(r)})[:5],
+        },
+    }
+
+
+def backlog(reqs: list, t: float) -> int:
+    """Requests due and unfinished at time ``t``."""
+    return sum(1 for r in reqs if r["due"] <= t and (r["done"] is None or r["done"] > t))
+
+
+def live_tokens_peak(reqs: list) -> int:
+    """The most tokens the requests in flight held in the KV cache at once
+    (prompt plus the output streamed so far), read at every send: what the
+    traffic fills of the pool that ``memory_peak_bytes`` reserves."""
+    def held(r, t):
+        if r["sent"] is None or r["done"] is None or not r["sent"] <= t < r["done"]:
+            return 0
+        if r["first"] is None or t < r["first"]:
+            return r["prompt_tokens"]
+        part = min((t - r["first"]) / max(r["last"] - r["first"], 1e-9), 1.0)
+        return r["prompt_tokens"] + int(part * r["output_tokens"])
+
+    return max((sum(held(r, q["sent"]) for r in reqs) for q in reqs
+                if q["sent"] is not None), default=0)
+
+
+# --------------------------------------------------------------------------
+# the server side
+
+
+class CompileWatch:
+    """Counts XLA compilations (``jax.monitoring``) and keeps the names JAX
+    logs for them, with the time of each, so that a run can say what
+    compiled inside its window."""
+
+    def __init__(self):
+        import logging
+
+        import jax.monitoring
+
+        self.count = 0
+        self.names: list = []  # (monotonic, message)
+        jax.monitoring.register_event_duration_secs_listener(self._on_event)
+        watch = self
+
+        class Handler(logging.Handler):
+            def emit(self, record):
+                msg = record.getMessage()
+                if msg.startswith("Compiling"):
+                    watch.names.append((time.monotonic(), msg[:160]))
+
+        for name in ("jax._src.interpreters.pxla", "jax._src.dispatch"):
+            lg = logging.getLogger(name)
+            lg.setLevel(logging.DEBUG)
+            lg.addHandler(Handler())
+            lg.propagate = False
+
+    def _on_event(self, name: str, *_a, **_kw) -> None:
+        if "backend_compile" in name:
+            self.count += 1
+
+    def since(self, t: float) -> list:
+        return [msg for at, msg in self.names if at >= t]
+
+
+class Probe:
+    """What a traced run reads from inside the server process: the engine's
+    submit and first-output stamps, the flight recorder's step ring and
+    timelines, and host spans for the profiler."""
+
+    def __init__(self, engine):
+        self.engine = engine
+        self.stamps: dict = {}  # rid -> [submit_t, first_output_t]
+        self.steps: dict = {}  # serial -> step record
+        self.timelines: dict = {}  # rid -> finished timeline
+        self.lost_steps = 0
+        self._last_serial = None
+
+    def install(self) -> None:
+        import jax.profiler
+
+        engine, stamps = self.engine, self.stamps
+        submit, step = engine.submit, engine.step
+
+        def stamped_submit(input_ids, sampling, rid=None, on_output=None, **kw):
+            rec = stamps.setdefault(rid, [time.monotonic(), None])
+
+            def first_stamped(out):
+                if rec[1] is None and out.new_token_ids:
+                    rec[1] = time.monotonic()
+                return on_output(out)
+
+            with jax.profiler.TraceAnnotation("bench.engine_submit"):
+                return submit(input_ids, sampling, rid=rid,
+                              on_output=first_stamped if on_output else None, **kw)
+
+        def spanned_step(*a, **kw):
+            with jax.profiler.TraceAnnotation("bench.engine_step"):
+                return step(*a, **kw)
+
+        engine.submit, engine.step = stamped_submit, spanned_step
+
+    def poll(self) -> None:
+        flight = self.engine.scheduler.flight
+        if flight is None:
+            return
+        snap = flight.snapshot("bench")
+        ring = snap["ring"]
+        if ring and self._last_serial is not None and ring[0]["serial"] > self._last_serial + 1:
+            self.lost_steps += ring[0]["serial"] - self._last_serial - 1
+        for rec in ring:
+            self.steps[rec["serial"]] = rec
+        if ring:
+            self._last_serial = ring[-1]["serial"]
+        for tl in snap["timelines"]["finished"]:
+            tl.pop("events", None)
+            self.timelines[tl["rid"]] = tl
+
+
+async def run_loadgen(plan: dict, out_dir: str, tag: str) -> dict:
+    plan_path = os.path.join(out_dir, f"{tag}.plan.json")
+    res_path = os.path.join(out_dir, f"{tag}.result.json")
+    with open(plan_path, "w") as f:
+        json.dump(plan, f)
+    if os.path.exists(res_path):
+        os.remove(res_path)
+    env = {k: v for k, v in os.environ.items() if k != "BENCH_RUN"}
+    proc = await asyncio.create_subprocess_exec(
+        sys.executable, os.path.join(HERE, "loadgen.py"), plan_path, res_path, env=env)
+    try:
+        rc = await proc.wait()
+    finally:
+        if proc.returncode is None:
+            proc.kill()
+            await proc.wait()
+    if rc != 0 or not os.path.exists(res_path):
+        raise RuntimeError(f"load generator ({tag}) exited {rc}")
+    with open(res_path) as f:
+        result = json.load(f)
+    os.remove(plan_path)
+    os.remove(res_path)
+    return result
+
+
+async def wait_quiet(engine, timeout: float = 60.0) -> dict:
+    deadline = time.monotonic() + timeout
+    while True:
+        loads = await asyncio.to_thread(engine.loads)
+        if loads["audit"]["quiescent"] or time.monotonic() > deadline:
+            return loads
+        await asyncio.sleep(0.1)
+
+
+def memory_peak(engine) -> int:
+    peak = 0
+    for d in engine.runner.local_devices():
+        stats = d.memory_stats() or {}
+        peak = max(peak, int(stats.get("peak_bytes_in_use", stats.get("bytes_in_use", 0))))
+    return peak
+
+
+def program_keys(loads: dict) -> set:
+    return {p["key"] for p in loads["programs"]["programs"]}
+
+
+async def serve_and_measure(args, bench: dict, cell: catalog.Cell, out_dir: str) -> dict:
+    import jax
+
+    import reference
+    import warm
+    from smg_tpu.cli import build_parser
+    from smg_tpu.config.validation import raise_on_errors, validate_cli_args
+    from smg_tpu.gateway import launch
+    from smg_tpu.models.config import PRESETS, ModelConfig
+    from smg_tpu.utils import get_logger
+    from smg_tpu.utils.logging import configure
+
+    marks = {"imports": time.monotonic() - T_START}
+    devs = jax.devices()
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind, "count": len(devs)}
+    want = "cpu" if args.rehearsal else "tpu"
+    if device["platform"] != want or len(devs) < cell.chips:
+        print(f"bench: the cell needs {cell.chips} {want} device(s); JAX found {device}. "
+              "No result.", file=sys.stderr)
+        raise SystemExit(3)
+    if not args.rehearsal:
+        import peaks
+
+        peaks.peaks_for(device["kind"])  # a device without published peaks is an error
+
+    import socket
+
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        port = s.getsockname()[1]
+    preset = cell.entry["config"]
+    argv = ["serve", "--model-preset", preset, *cell.serve_args,
+            "--host", "127.0.0.1", "--port", str(port)]
+    sargs = build_parser().parse_args(argv)
+    configure(level=sargs.log_level, json_logs=None)
+    raise_on_errors(validate_cli_args(sargs), logger=get_logger("config"))
+    hf = cell.hf_config
+    PRESETS[preset] = lambda: ModelConfig.from_hf_config(hf, dtype=sargs.dtype)
+
+    holder: dict = {}
+    build = launch.build_engine_from_args
+
+    def build_and_keep(a):
+        t = time.monotonic()
+        engine = build(a)
+        marks["weights_and_cache"] = time.monotonic() - t
+        t = time.monotonic()
+        holder["warmed"] = warm.warm_shapes(
+            engine, cell.chains(args.seed, float(args.seconds)), log)
+        marks["warm_shapes"] = time.monotonic() - t
+        holder["engine"] = engine
+        holder["t_built"] = time.monotonic()
+        return engine
+
+    launch.build_engine_from_args = build_and_keep
+    watch = CompileWatch()
+    gateway = asyncio.create_task(launch._run_gateway(sargs))
+    try:
+        # _run_gateway blocks the loop while it builds and warms the engine
+        while True:
+            await asyncio.sleep(0.05)
+            if gateway.done():
+                raise RuntimeError(f"the gateway ended during start-up: {gateway.result()}")
+            try:
+                _r, w = await asyncio.open_connection("127.0.0.1", port)
+                w.close()
+                break
+            except OSError:
+                continue
+        engine = holder["engine"]
+        marks["program_warmup"] = time.monotonic() - holder["t_built"]
+        log(f"listening on {port}; device {device}; mesh {engine.runner.mesh_info()}")
+        sched = engine.config.scheduler
+        vocab = engine.config.model.vocab_size
+        base = {"url": f"http://127.0.0.1:{port}", "vocab": vocab,
+                "drain_s": float(cell.traffic.get("drain_s", 60))}
+
+        # a slice of the cell's own traffic from another seed: the gateway,
+        # the tokenizer, the uploads; and the proof that warm.py missed nothing
+        t = time.monotonic()
+        before_slice = program_keys(await wait_quiet(engine))
+        slice_s = min(float(args.seconds), 3.0)
+        res = await run_loadgen(
+            {**base, "tag": "slice", "seconds": slice_s,
+             "chains": cell.chains(args.seed ^ 0x5EED5, slice_s)}, out_dir, "slice")
+        loads = await wait_quiet(engine)
+        grew = sorted(program_keys(loads) - before_slice)
+        if grew:
+            log(f"the traffic slice reached {len(grew)} program(s) the warm-up had "
+                f"not: {grew}")
+        holder["slice_grew"] = grew
+        log(f"slice: {len(res['requests'])} requests, "
+            f"{sum(1 for r in res['requests'] if not request_ok(r))} failed")
+        # no flush_cache here: on a chip whose cache was auto-sized it allocates
+        # the new buffers before it frees the old and dies (PERF.md, Open
+        # questions).  The slice's prompts come from another seed, so the
+        # pages it leaves behind match nothing in the window and are evictable.
+        marks["traffic_slice"] = time.monotonic() - t
+
+        t = time.monotonic()
+        check = await asyncio.to_thread(
+            reference.check_engine, engine, hf, args.seed, args.rehearsal)
+        marks["correctness_check"] = time.monotonic() - t
+        log(f"logits vs float32 reference: {json.dumps(check)}")
+
+        probe = Probe(engine)
+        trace_dir = os.path.join(out_dir, "trace")
+        if args.trace:
+            probe.install()
+            await asyncio.to_thread(probe.poll)
+        loads0 = await wait_quiet(engine)
+        compiles0 = watch.count
+
+        # ---- the window ----
+        t0 = time.monotonic() + 0.3
+        setup_s = t0 - T_START
+        plan = {**base, "tag": "w", "seconds": float(args.seconds), "t0": t0,
+                "chains": cell.chains(args.seed, float(args.seconds))}
+        load_task = asyncio.create_task(run_loadgen(plan, out_dir, "window"))
+        trace_win = None
+        polling = True
+
+        async def poll_loop():
+            while polling:
+                await asyncio.to_thread(probe.poll)
+                await asyncio.sleep(1.0)
+
+        poller = asyncio.create_task(poll_loop()) if args.trace else None
+        if args.trace:
+            import shutil
+
+            shutil.rmtree(trace_dir, ignore_errors=True)
+            # the last seconds of the window: stop_profile holds the engine's
+            # lock while it writes the trace, which stalls every step and every
+            # submit for seconds, so nothing read after it stands for the cell.
+            # Long enough for dozens of launches of each family.
+            trace_s = min(TRACE_SECONDS, 0.3 * args.seconds)
+            await asyncio.sleep(max(t0 + args.seconds - trace_s - 0.5 - time.monotonic(), 0))
+            ts = time.monotonic()
+            await asyncio.to_thread(engine.start_profile, trace_dir)
+            await asyncio.sleep(trace_s)
+            await asyncio.to_thread(engine.stop_profile)
+            trace_win = (ts, time.monotonic())
+        result = await load_task
+        loads1 = await wait_quiet(engine)
+        polling = False
+        if poller is not None:
+            await poller
+            await asyncio.to_thread(probe.poll)
+        compiles1 = watch.count
+        peak = memory_peak(engine)
+    finally:
+        if not gateway.done():
+            os.kill(os.getpid(), signal.SIGTERM)  # the program's own way down
+            try:
+                await asyncio.wait_for(gateway, 60)
+            except asyncio.TimeoutError:
+                gateway.cancel()
+        launch.build_engine_from_args = build
+
+    e2e = end_to_end(result)
+    new_programs = sorted(program_keys(loads1) - program_keys(loads0))
+    verdict = {
+        "logits": check["ok"],
+        "requests": e2e["failed"] == 0 and e2e["attempted"] > 0,
+        "step_failures": loads1["step_failures"] == loads0["step_failures"],
+        "quarantined": loads1["quarantined_requests"] == loads0["quarantined_requests"],
+        "audit_clean": bool(loads1["audit"]["quiescent"] and loads1["audit"]["clean"]),
+        "no_compile_in_window": (not new_programs and compiles1 == compiles0
+                                 and loads1["programs"]["recompiles"]
+                                 == loads0["programs"]["recompiles"]),
+    }
+    e2e["metrics"]["setup_s"] = setup_s
+    ctx = {
+        "cell": cell.name, "hf": hf, "chips": cell.chips, "device": device,
+        "rehearsal": args.rehearsal, "requests": result["requests"],
+        # a traced run's whole-window readers stop where the profiler started
+        "window": (result["t0"], trace_win[0] if trace_win
+                   else result["t0"] + result["seconds"]),
+        "loads_before": loads0, "loads_after": loads1,
+        "stamps": probe.stamps, "steps": sorted(probe.steps.values(),
+                                                key=lambda r: r["serial"]),
+        "timelines": list(probe.timelines.values()), "lost_steps": probe.lost_steps,
+        "trace_window": trace_win, "trace": None, "kv_dtype_bytes":
+            2 if engine.config.cache.dtype == "bfloat16" else 4,
+    }
+    return {"e2e": e2e, "verdict": verdict, "check": check, "marks": marks, "ctx": ctx,
+            "device": device, "memory_peak_bytes": peak, "new_programs": new_programs,
+            "compiles_in_window": compiles1 - compiles0,
+            "compiled_in_window": watch.since(t0), "warmed": holder["warmed"],
+            "slice_grew": holder["slice_grew"], "trace_dir": trace_dir,
+            "attention": loads1["attention"], "mesh": loads1["mesh"],
+            "counters": {k: loads1[k] - loads0[k] for k in (
+                "radix_evicted_pages", "preemptions", "radix_hit_pages", "radix_miss_pages",
+                "lookahead_kept", "lookahead_discarded", "wasted_decode_tokens")},
+            "total_pages": loads1["total_pages"]}
+
+
+def per_layer(bench: dict, cell: str, ctx: dict) -> dict:
+    """Every per-layer metric of the cell whose reader finds something."""
+    out = {}
+    for m in catalog.metrics_for(bench, cell, "per_layer"):
+        reader = catalog.layer_metric_reader(m["name"])
+        if reader is None:
+            continue
+        value = reader.read(ctx)
+        if value is not None:
+            out[m["name"]] = {"value": float(value), "unit": m["unit"]}
+    return out
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload")
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--seconds", type=float, default=None)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--rehearsal", action="store_true",
+                    help="tiny widths on the CPU; proves nothing about a chip")
+    ap.add_argument("--out", default=os.path.join(ROOT, "bench_out"),
+                    help="directory for the run's file")
+    ap.add_argument("--set", action="append", default=[], metavar="KEY=NUMBER",
+                    help="override one number of the traffic file (for the rate "
+                         "sweep that defines a cell; the result says so)")
+    ap.add_argument("--list", action="store_true", help="list what the harness sees")
+    args = ap.parse_args()
+    try:
+        bench = catalog.load_benchmark()
+        if args.list:
+            print(json.dumps(catalog.listing(), indent=1))
+            return 0
+        if not args.workload:
+            ap.error("--workload is required")
+        cell = catalog.Cell(bench, args.workload, rehearsal=args.rehearsal)
+    except catalog.CatalogError as e:
+        print(f"bench: {e}", file=sys.stderr)
+        return 2
+    if not os.path.isdir(os.path.join(ROOT, "smg_tpu")):
+        print("bench: no smg_tpu package beside benchmark/: nothing to measure",
+              file=sys.stderr)
+        return 2
+    for item in args.set:
+        key, _, val = item.partition("=")
+        if key not in cell.traffic or not isinstance(cell.traffic[key], (int, float)):
+            ap.error(f"--set {item}: the traffic file has no number {key!r}")
+        cell.traffic[key] = float(val)
+    if args.seconds is None:
+        args.seconds = float(bench["run_seconds"])
+    sys.path.insert(0, ROOT)
+    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", os.path.join(ROOT, ".bench_cache", "jax"))
+    if args.rehearsal:
+        os.environ.pop("JAX_COMPILATION_CACHE_DIR")  # a rehearsal caches nothing
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                                   + " --xla_force_host_platform_device_count=4").strip()
+    import jax
+
+    # every program goes to the persistent cache, however quickly it compiled
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    out_dir = os.path.join(args.out, args.workload, f"seed{args.seed}-trace{args.trace}")
+    os.makedirs(out_dir, exist_ok=True)
+
+    run = asyncio.run(serve_and_measure(args, bench, cell, out_dir))
+    e2e, ctx = run["e2e"], run.pop("ctx")
+    device = dict(run["device"], memory_peak_bytes=run["memory_peak_bytes"])
+    line = {"correct": all(run["verdict"].values()), "attempted": e2e["attempted"],
+            "failed": e2e["failed"]}
+    units = {m["name"]: m["unit"] for m in catalog.metrics_for(bench, cell.name, "end_to_end")}
+    report = {"workload": cell.name, "seed": args.seed, "seconds": args.seconds,
+              "trace": args.trace, "overrides": args.set, **run, "traffic": cell.traffic}
+    if args.trace:
+        import shutil
+
+        import trace_reduce
+
+        path = trace_reduce.find_xplane(run["trace_dir"])
+        if path is None:
+            print("bench: the profiler wrote no trace", file=sys.stderr)
+            return 4
+        ctx["trace"] = trace = trace_reduce.load_xplane(path)
+        with open(os.path.join(out_dir, "trace_cut.json"), "w") as f:
+            json.dump(trace_reduce.cut(trace, 0.25), f)  # a quarter second, to look at
+        b = trace_reduce.busy(trace)
+        if not b["busy_s"] or b["window_s"] <= 0:
+            print("bench: no operation ran on the device in the traced window",
+                  file=sys.stderr)
+            return 4
+        device["busy_s"] = sum(b["busy_s"].values()) / len(b["busy_s"])
+        device["window_s"] = b["window_s"]
+        line["metrics"] = per_layer(bench, cell.name, ctx)
+        line["breakdown"] = {"device_ops": trace_reduce.top_ops(trace),
+                             "idle_gaps": trace_reduce.idle_gaps(trace)}
+        steps = [s for s in ctx["steps"] if s["horizon"] > 0
+                 and ctx["window"][0] <= s["t"] <= ctx["window"][1]]
+        report["probe"] = {
+            "steps_read": len(ctx["steps"]), "steps_lost": ctx["lost_steps"],
+            "timelines_read": len(ctx["timelines"]),
+            "step_ms_by_kind": {
+                kind: [len(v), statistics.median(v) * 1e3, percentile(v, 0.95) * 1e3]
+                for kind in ("decode", "mixed", "prefill", "idle")
+                if (v := [s["step_s"] for s in ctx["steps"] if s["kind"] == kind
+                          and ctx["window"][0] <= s["t"] <= ctx["window"][1]])},
+            "decode_launches_at_k1_share":
+                (sum(1 for s in steps if s["horizon"] == 1) / len(steps)) if steps else None,
+            "programs": {d: {k: v[:2] for k, v in per.items()}
+                         for d, per in trace_reduce.program_times(trace).items()},
+        }
+        log(f"probe: {json.dumps(report['probe'])[:3000]}")
+        shutil.rmtree(run["trace_dir"], ignore_errors=True)
+    else:
+        line["metrics"] = {k: {"value": float(v), "unit": units[k]}
+                           for k, v in e2e["metrics"].items() if k in units}
+    line["device"] = device
+    if args.rehearsal:
+        line["rehearsal"] = True
+    if args.set:
+        line["overrides"] = args.set
+    report["line"] = line
+    # the samples behind the percentiles, and the engine's own counters
+    report["requests"] = [{k: q[k] for k in ("id", "due", "sent", "first", "last", "done",
+                                             "prompt_tokens", "cached_tokens", "output_tokens",
+                                             "tokens_in_window", "finish", "error")}
+                          for q in ctx["requests"]]
+    report["loads_before"], report["loads_after"] = ctx["loads_before"], ctx["loads_after"]
+    with open(os.path.join(out_dir, "run.json"), "w") as f:
+        json.dump(report, f, indent=1, default=str)
+    print("bench: detail " + json.dumps(
+        {"cell": cell.name, "seed": args.seed, "verdict": run["verdict"],
+         "setup": {k: round(v, 2) for k, v in run["marks"].items()},
+         **e2e["detail"], "e2e": e2e["metrics"], "attention": run["attention"], "counters": run["counters"],
+         "total_pages": run["total_pages"], "check_worst": run["check"]["worst"],
+         "control": run["check"]["control_errors"], "new_programs": run["new_programs"],
+         "compiles_in_window": run["compiles_in_window"],
+         "compiled_in_window": run["compiled_in_window"],
+         "programs_warmed": len(run["warmed"])}), flush=True)
+    print(json.dumps(line), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
