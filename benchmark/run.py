@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """One run of one cell of BENCHMARK.json.
 
-    python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+    python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1|2>
 
 The process holds the cell's chips and does what ``smg-tpu serve`` does, by
 the program's own code: ``gateway.launch._run_gateway`` builds the engine,
@@ -16,7 +16,11 @@ tokenizer is the vocab-matched mock.  The load generator is a child process
 Set-up (all of it counted in ``setup_s``): import, weights, cache, every
 program the cell's traffic can reach (``warm.py``), the program's own
 warm-up, a slice of the cell's traffic from another seed, and the
-correctness check (``reference.py``).  Then the window.
+correctness check (``reference.py``).  Then the window.  ``--trace 2`` is a
+``--trace 0`` run to the moment the window has closed and its numbers are
+taken; only then, in the same process and engine, the window's traffic is
+replayed with its last seconds traced, for the per-layer metrics
+(``trace_phase``).
 
 The last line of standard output is the result, one JSON object.  Without a
 TPU the run prints no result and exits 3; ``--rehearsal`` is the only way
@@ -53,8 +57,12 @@ def log(msg: str) -> None:
 # client-side reduction
 
 
-#: how much of the window's end a ``--trace 1`` run records
+#: how much of the window's end a ``--trace 1`` run records, and how much of
+#: the replayed window's end a ``--trace 2`` run's trace phase does
 TRACE_SECONDS = 12.0
+#: ``--trace 2``: traffic goes on this long after the traced stretch, so that
+#: steps and submits meet the profiler while it writes its trace
+TRACE_TAIL_SECONDS = 4.0
 
 
 def end_to_end(result: dict) -> dict:
@@ -211,6 +219,11 @@ class Probe:
             self.timelines[tl["rid"]] = tl
 
 
+#: ``loads()`` counters whose rise over a window goes on the detail line
+COUNTERS = ("radix_evicted_pages", "preemptions", "radix_hit_pages", "radix_miss_pages",
+            "lookahead_kept", "lookahead_discarded", "wasted_decode_tokens")
+
+
 async def run_loadgen(plan: dict, out_dir: str, tag: str) -> dict:
     plan_path = os.path.join(out_dir, f"{tag}.plan.json")
     res_path = os.path.join(out_dir, f"{tag}.result.json")
@@ -255,6 +268,139 @@ def memory_peak(engine) -> int:
 
 def program_keys(loads: dict) -> set:
     return {p["key"] for p in loads["programs"]["programs"]}
+
+
+async def trace_phase(args, cell: catalog.Cell, engine, probe: Probe, base: dict,
+                      out_dir: str, watch: CompileWatch, trace_dir: str, *,
+                      closed_window: dict) -> dict:
+    """The second half of a ``--trace 2`` run.  ``closed_window`` is the
+    measured window's result: it and the numbers taken from it exist before
+    anything here starts, and nothing here touches them.
+
+    In order: one read of the flight recorder (its ring and finished timelines
+    still hold the window, which no probe watched); one start and stop of the
+    profiler whose trace is thrown away, so that the cost of the first start
+    falls into no number; the probe; a second load generator that replays the
+    window's own chains with other words (the window's prompts are in the
+    radix cache); the profiler through the engine's own ``start_profile``
+    over the stretch a ``--trace 1`` run traces, the window's last
+    ``TRACE_SECONDS``; the stop, with traffic still running, and how long steps
+    and submits stalled meanwhile; the drain.
+
+    The replay is as long as the window because the arrangement of the
+    requests is part of the work: traced right after the callers' ramp, the
+    batch held young requests behind narrow page tables and a decode column
+    took half the time it takes at the window's end (PERF.md, Findings, PR 26)."""
+    import shutil
+
+    await asyncio.to_thread(probe.poll)
+    window_steps = sorted(probe.steps.values(), key=lambda r: r["serial"])
+    window_timelines = list(probe.timelines.values())
+
+    scrap = os.path.join(out_dir, "trace_scrap")
+    await asyncio.to_thread(engine.start_profile, scrap)
+    await asyncio.to_thread(engine.stop_profile)
+    shutil.rmtree(scrap, ignore_errors=True)
+    shutil.rmtree(trace_dir, ignore_errors=True)
+
+    probe.install()
+    loads0 = await wait_quiet(engine)
+    compiles0 = watch.count
+    traced = min(TRACE_SECONDS, 0.3 * args.seconds)
+    lead = float(args.seconds) - traced
+    seconds = float(args.seconds) + TRACE_TAIL_SECONDS
+    t0 = time.monotonic() + 0.3
+    plan = {**base, "tag": "t", "seconds": seconds, "t0": t0,
+            "drain_s": min(base["drain_s"], 30.0),
+            "chains": cell.chains(args.seed ^ 0x7ACE, seconds)}
+    load_task = asyncio.create_task(run_loadgen(plan, out_dir, "trace"))
+    polling = True
+
+    async def poll_loop():
+        while polling:
+            await asyncio.to_thread(probe.poll)
+            await asyncio.sleep(1.0)
+
+    poller = asyncio.create_task(poll_loop())
+    try:
+        await asyncio.sleep(max(t0 + lead - time.monotonic(), 0))
+        ts = time.monotonic()
+        await asyncio.to_thread(engine.start_profile, trace_dir)
+        started = time.monotonic()
+        await asyncio.sleep(traced)
+        stopping = time.monotonic()
+        await asyncio.to_thread(engine.stop_profile)
+        te = time.monotonic()
+        result = await load_task
+        loads1 = await wait_quiet(engine)
+    finally:
+        polling = False
+        await poller
+    await asyncio.to_thread(probe.poll)
+    reqs = result["requests"]
+    steps = sorted(probe.steps.values(), key=lambda r: r["serial"])
+    timelines = list(probe.timelines.values())
+
+    def gaps(lo, hi):  # between the stamps of consecutive steps, in seconds
+        ts_ = [s["t"] for s in steps if lo <= s["t"] <= hi]
+        return [b - a for a, b in zip(ts_, ts_[1:])]
+
+    def lock_waits(lo, hi):
+        return [tl["queued_t"] - tl["submit_t"] for tl in timelines
+                if lo <= tl["submit_t"] <= hi]
+
+    def engine_tok_per_s(lo, hi, recs, tls):
+        """Tokens the engine accepted between ``lo`` and ``hi``: the decode
+        tokens of the step records stamped there and one first token for
+        every timeline whose first token fell there, a second."""
+        n = sum(s["decode_tokens"] for s in recs if lo <= s["t"] <= hi)
+        n += sum(1 for tl in tls if tl["first_token_t"] is not None
+                 and lo <= tl["first_token_t"] <= hi)
+        return n / (hi - lo)
+
+    in_trace = [s for s in steps if started <= s["t"] <= stopping]
+    w1 = closed_window["t0"] + closed_window["seconds"]
+    w0 = w1 - (stopping - started)  # the stretch of the window that the trace replays
+    return {
+        "window_steps": window_steps, "window_timelines": window_timelines,
+        "attempted": len(reqs), "failed": sum(1 for r in reqs if not request_ok(r)),
+        "compiles": watch.count - compiles0,
+        "new_programs": sorted(program_keys(loads1) - program_keys(loads0)),
+        "recompiles": loads1["programs"]["recompiles"] - loads0["programs"]["recompiles"],
+        "counters": {k: loads1[k] - loads0[k] for k in COUNTERS},
+        "decode_launches": {r: n - loads0["decode_launches"][r]
+                            for r, n in loads1["decode_launches"].items()},
+        "traced_steps": {
+            "records": len(in_trace),
+            "horizon_sum": sum(s["horizon"] for s in in_trace),
+            "decode_tokens": sum(s["decode_tokens"] for s in in_trace),
+            "early_exits": sum(s["early_exits"] for s in in_trace)},
+        "seconds": {"lead": lead, "start_profile": started - ts,
+                    "traced": stopping - started, "stop_profile": te - stopping},
+        # what tracing costs while it is on (the same stretch of the same
+        # arrangement, untraced in the window and traced in the replay), and
+        # what the stop holds up
+        "engine_tok_per_s": {
+            "window_same_stretch": engine_tok_per_s(w0, w1, window_steps, window_timelines),
+            "traced": engine_tok_per_s(started, stopping, steps, timelines)},
+        "step_gap_s": {"traced_max": max(gaps(started, stopping), default=None),
+                       "stop_profile_max": max(gaps(stopping, te), default=None)},
+        "submit_lock_wait_s": {"traced_max": max(lock_waits(started, stopping), default=None),
+                               "stop_profile_max": max(lock_waits(stopping, te), default=None)},
+        "rests_on": {"window_requests": len(closed_window["requests"]),
+                     "window_steps": len(window_steps),
+                     "window_ring_reaches_back": bool(
+                         window_steps and window_steps[0]["t"] <= closed_window["t0"]),
+                     "window_timelines": len(window_timelines),
+                     "trace_phase_requests": len(reqs)},
+        "ctx": {
+            # steps go on while the profiler writes: the traced window ends
+            # where the stop was asked for, not where it returned
+            "requests": reqs, "window": (result["t0"], ts), "trace_window": (ts, stopping),
+            "loads_before": loads0, "loads_after": loads1, "stamps": probe.stamps,
+            "steps": steps, "timelines": timelines, "lost_steps": probe.lost_steps,
+        },
+    }
 
 
 async def serve_and_measure(args, bench: dict, cell: catalog.Cell, out_dir: str) -> dict:
@@ -364,7 +510,7 @@ async def serve_and_measure(args, bench: dict, cell: catalog.Cell, out_dir: str)
 
         probe = Probe(engine)
         trace_dir = os.path.join(out_dir, "trace")
-        if args.trace:
+        if args.trace == 1:
             probe.install()
             await asyncio.to_thread(probe.poll)
         loads0 = await wait_quiet(engine)
@@ -384,8 +530,8 @@ async def serve_and_measure(args, bench: dict, cell: catalog.Cell, out_dir: str)
                 await asyncio.to_thread(probe.poll)
                 await asyncio.sleep(1.0)
 
-        poller = asyncio.create_task(poll_loop()) if args.trace else None
-        if args.trace:
+        poller = asyncio.create_task(poll_loop()) if args.trace == 1 else None
+        if args.trace == 1:
             import shutil
 
             shutil.rmtree(trace_dir, ignore_errors=True)
@@ -408,6 +554,14 @@ async def serve_and_measure(args, bench: dict, cell: catalog.Cell, out_dir: str)
             await asyncio.to_thread(probe.poll)
         compiles1 = watch.count
         peak = memory_peak(engine)
+        e2e = end_to_end(result)
+        phase = None
+        if args.trace == 2:
+            # the window is closed and its numbers are taken: from here on
+            # nothing can move them
+            phase = await trace_phase(args, cell, engine, probe, base, out_dir, watch,
+                                      trace_dir, closed_window=result)
+            peak = max(peak, memory_peak(engine))
     finally:
         if not gateway.done():
             os.kill(os.getpid(), signal.SIGTERM)  # the program's own way down
@@ -417,7 +571,6 @@ async def serve_and_measure(args, bench: dict, cell: catalog.Cell, out_dir: str)
                 gateway.cancel()
         launch.build_engine_from_args = build
 
-    e2e = end_to_end(result)
     new_programs = sorted(program_keys(loads1) - program_keys(loads0))
     verdict = {
         "logits": check["ok"],
@@ -443,26 +596,32 @@ async def serve_and_measure(args, bench: dict, cell: catalog.Cell, out_dir: str)
         "trace_window": trace_win, "trace": None, "kv_dtype_bytes":
             2 if engine.config.cache.dtype == "bfloat16" else 4,
     }
+    if phase is not None:
+        # the trace phase's context: everything of ``ctx`` that does not
+        # belong to the window, and the phase's own traffic, stamps and trace
+        ctx = {**ctx, "steps": phase.pop("window_steps"),
+               "timelines": phase.pop("window_timelines")}
+        phase["ctx"] = {**ctx, **phase["ctx"]}
     return {"e2e": e2e, "verdict": verdict, "check": check, "marks": marks, "ctx": ctx,
-            "device": device, "memory_peak_bytes": peak, "new_programs": new_programs,
+            "trace_phase": phase, "device": device, "memory_peak_bytes": peak, "new_programs": new_programs,
             "compiles_in_window": compiles1 - compiles0,
             "compiled_in_window": watch.since(t0), "warmed": holder["warmed"],
             "slice_grew": holder["slice_grew"], "trace_dir": trace_dir,
             "attention": loads1["attention"], "mesh": loads1["mesh"],
-            "counters": {k: loads1[k] - loads0[k] for k in (
-                "radix_evicted_pages", "preemptions", "radix_hit_pages", "radix_miss_pages",
-                "lookahead_kept", "lookahead_discarded", "wasted_decode_tokens")},
+            "counters": {k: loads1[k] - loads0[k] for k in COUNTERS},
             "total_pages": loads1["total_pages"]}
 
 
-def per_layer(bench: dict, cell: str, ctx: dict) -> dict:
-    """Every per-layer metric of the cell whose reader finds something."""
+def per_layer(bench: dict, cell: str, *ctxs: dict) -> dict:
+    """Every per-layer metric of the cell whose reader finds something, in
+    the first of ``ctxs`` that gives it something to read (``--trace 2``: the
+    closed window's context, then the trace phase's)."""
     out = {}
     for m in catalog.metrics_for(bench, cell, "per_layer"):
         reader = catalog.layer_metric_reader(m["name"])
         if reader is None:
             continue
-        value = reader.read(ctx)
+        value = next((v for c in ctxs if (v := reader.read(c)) is not None), None)
         if value is not None:
             out[m["name"]] = {"value": float(value), "unit": m["unit"]}
     return out
@@ -473,7 +632,7 @@ def main() -> int:
     ap.add_argument("--workload")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--seconds", type=float, default=None)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     ap.add_argument("--rehearsal", action="store_true",
                     help="tiny widths on the CPU; proves nothing about a chip")
     ap.add_argument("--out", default=os.path.join(ROOT, "bench_out"),
@@ -522,10 +681,15 @@ def main() -> int:
 
     run = asyncio.run(serve_and_measure(args, bench, cell, out_dir))
     e2e, ctx = run["e2e"], run.pop("ctx")
+    # the context that holds the trace: the window's own (--trace 1), or the
+    # trace phase's, which comes after the window (--trace 2)
+    tctx = run["trace_phase"].pop("ctx") if run["trace_phase"] else ctx
     device = dict(run["device"], memory_peak_bytes=run["memory_peak_bytes"])
     line = {"correct": all(run["verdict"].values()), "attempted": e2e["attempted"],
             "failed": e2e["failed"]}
     units = {m["name"]: m["unit"] for m in catalog.metrics_for(bench, cell.name, "end_to_end")}
+    e2e_metrics = {k: {"value": float(v), "unit": units[k]}
+                   for k, v in e2e["metrics"].items() if k in units}
     report = {"workload": cell.name, "seed": args.seed, "seconds": args.seconds,
               "trace": args.trace, "overrides": args.set, **run, "traffic": cell.traffic}
     if args.trace:
@@ -537,7 +701,7 @@ def main() -> int:
         if path is None:
             print("bench: the profiler wrote no trace", file=sys.stderr)
             return 4
-        ctx["trace"] = trace = trace_reduce.load_xplane(path)
+        tctx["trace"] = trace = trace_reduce.load_xplane(path)
         with open(os.path.join(out_dir, "trace_cut.json"), "w") as f:
             json.dump(trace_reduce.cut(trace, 0.25), f)  # a quarter second, to look at
         b = trace_reduce.busy(trace)
@@ -547,14 +711,19 @@ def main() -> int:
             return 4
         device["busy_s"] = sum(b["busy_s"].values()) / len(b["busy_s"])
         device["window_s"] = b["window_s"]
-        line["metrics"] = per_layer(bench, cell.name, ctx)
-        line["breakdown"] = {"device_ops": trace_reduce.top_ops(trace),
-                             "idle_gaps": trace_reduce.idle_gaps(trace)}
+        if args.trace == 2:
+            # both kinds of metric side by side; idle gaps by the program's spans
+            line["metrics"] = {**e2e_metrics, **per_layer(bench, cell.name, ctx, tctx)}
+            gaps = trace_reduce.idle_gaps(trace, span_prefix="smg.")
+        else:
+            line["metrics"] = per_layer(bench, cell.name, ctx)
+            gaps = trace_reduce.idle_gaps(trace)
+        line["breakdown"] = {"device_ops": trace_reduce.top_ops(trace), "idle_gaps": gaps}
         steps = [s for s in ctx["steps"] if s["horizon"] > 0
                  and ctx["window"][0] <= s["t"] <= ctx["window"][1]]
         report["probe"] = {
-            "steps_read": len(ctx["steps"]), "steps_lost": ctx["lost_steps"],
-            "timelines_read": len(ctx["timelines"]),
+            "steps_read": len(tctx["steps"]), "steps_lost": tctx["lost_steps"],
+            "timelines_read": len(tctx["timelines"]),
             "step_ms_by_kind": {
                 kind: [len(v), statistics.median(v) * 1e3, percentile(v, 0.95) * 1e3]
                 for kind in ("decode", "mixed", "prefill", "idle")
@@ -568,8 +737,7 @@ def main() -> int:
         log(f"probe: {json.dumps(report['probe'])[:3000]}")
         shutil.rmtree(run["trace_dir"], ignore_errors=True)
     else:
-        line["metrics"] = {k: {"value": float(v), "unit": units[k]}
-                           for k, v in e2e["metrics"].items() if k in units}
+        line["metrics"] = e2e_metrics
     line["device"] = device
     if args.rehearsal:
         line["rehearsal"] = True
@@ -592,7 +760,8 @@ def main() -> int:
          "control": run["check"]["control_errors"], "new_programs": run["new_programs"],
          "compiles_in_window": run["compiles_in_window"],
          "compiled_in_window": run["compiled_in_window"],
-         "programs_warmed": len(run["warmed"])}), flush=True)
+         "programs_warmed": len(run["warmed"]),
+         **({"trace_phase": run["trace_phase"]} if run["trace_phase"] else {})}), flush=True)
     print(json.dumps(line), flush=True)
     return 0
 

@@ -273,7 +273,7 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 def test_benchmark_json_is_consistent_with_its_files():
     bench = catalog.load_benchmark()
     assert set(bench) == {"command", "paths", "run_seconds", "configs", "workloads",
-                          "end_to_end", "per_layer"}
+                          "end_to_end", "per_layer", "trace_in_run"}
     e2e = {m["name"] for m in bench["end_to_end"]}
     assert "setup_s" in e2e
     for m in bench["end_to_end"] + bench["per_layer"]:

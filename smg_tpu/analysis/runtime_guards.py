@@ -259,12 +259,15 @@ class ProgramAuditor:
 
     The runner routes each jit family through :meth:`wrap` at cache-fill
     time, declaring the family's intended donation positions and (in mesh
-    mode) the committed input shardings.  Unarmed, the wrapper is a single
-    attribute check per launch.  Armed (:meth:`arm`, post-warmup), each
-    launch snapshots the argument tree's shapes/dtypes/shardings BEFORE
-    dispatch (donation invalidates input buffers afterwards) and brackets
-    the call with the process compile counter — so a steady-state launch
-    that compiles gets a provenance entry naming exactly which argument's
+    mode) the committed input shardings.  Always, the wrapper counts the
+    launch and reads the process compile counter on either side of it (two
+    integer reads and an add), so ``launches``, ``recompiles`` and the
+    snapshot's ``compiles`` mean something on a server nobody armed: a
+    program that compiles on any launch after its first has been retraced.
+    Armed (:meth:`arm`, post-warmup), each launch also snapshots the
+    argument tree's shapes/dtypes/shardings BEFORE dispatch (donation
+    invalidates input buffers afterwards) — so a steady-state launch that
+    compiles gets a provenance entry naming exactly which argument's
     shape/dtype/sharding differed from the previous launch.
 
     :meth:`audit` then re-lowers each captured program from its specs and
@@ -277,6 +280,7 @@ class ProgramAuditor:
         self._mu = threading.Lock()
         self._records: dict = {}
         self.armed = False
+        _ensure_listener()  # jax is imported wherever a program is built
 
     # ---- registration / launch path ----
 
@@ -289,8 +293,15 @@ class ProgramAuditor:
 
         def launch(*args):
             if not self.armed:
-                return fn(*args)
-            _ensure_listener()
+                # launches come from the step thread alone; a compile on
+                # another thread inside the call would be miscounted here,
+                # which the armed pass (with provenance) is there to settle
+                pre = _compile_count
+                out = fn(*args)
+                if rec.launches:
+                    rec.recompiles += _compile_count - pre
+                rec.launches += 1
+                return out
             sig, entries, specs = _describe_args(args)
             pre = _compile_count
             out = fn(*args)
@@ -347,6 +358,9 @@ class ProgramAuditor:
             ]
         return {
             "armed": self.armed,
+            # every XLA compile of the process since this auditor's listener
+            # went in, the programs' first compiles included
+            "compiles": _compile_count,
             "recompiles": sum(p["recompiles"] for p in programs),
             "programs": programs,
         }

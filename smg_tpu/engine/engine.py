@@ -9,10 +9,13 @@ the reference, where the gateway's StreamingProcessor scans stop strings).
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 import uuid
 from dataclasses import dataclass, field
+
+from jax.profiler import TraceAnnotation
 
 from smg_tpu.analysis.runtime_guards import make_lock
 from smg_tpu.engine.config import EngineConfig
@@ -127,6 +130,17 @@ class Engine:
         self._thread: threading.Thread | None = None
         self._stopping = False
         self.start_time = time.monotonic()
+        # profiler state under a lock of its own: jax.profiler writes the
+        # trace inside stop_trace(), seconds on a chip, and that must never
+        # happen under the engine lock (every step and submit would wait)
+        self._profile_lock = make_lock("engine.profiler")
+        self._profiling = False  # a trace runs, or is being written
+        self._profile_stopping = False  # stop_trace() is writing it
+        self._profile_steps_left: int | None = None
+        # submit-side lock wait (smg_engine_submit_lock_wait_seconds_total):
+        # written under the engine lock, read by loads()
+        self.num_submits = 0
+        self.submit_lock_wait_s_total = 0.0
         # failure isolation: step-watchdog state.  ``_last_progress`` is a
         # bare float written by the step thread and read by the watchdog
         # WITHOUT the engine lock — the watchdog must never block on a lock
@@ -206,7 +220,7 @@ class Engine:
         req.token_filter = self._build_token_filter(sampling)
         if sampling.lora_adapter:
             req.lora_idx = self.runner.lora_index(sampling.lora_adapter)
-        with self._wakeup:
+        with self._locked_for_submit(req), TraceAnnotation("smg.submit"):
             self.scheduler.add_request(req)  # may raise QueueFullError
             # fresh work resets the watchdog clock: stall time is measured
             # from "work existed and no step completed", not from engine idle
@@ -215,6 +229,25 @@ class Engine:
                 self._callbacks[rid] = on_output
             self._wakeup.notify_all()
         return rid
+
+    @contextlib.contextmanager
+    def _locked_for_submit(self, req: EngineRequest):
+        """The engine lock for a submission, with the wait for it stamped
+        (``req.submit_t`` -> the timeline's ``submit_t``), counted and
+        spanned: ``step()`` holds the lock across the blocking fetch of the
+        frame in flight, so this wait is part of a caller's time to first
+        token that ``queued_t`` (stamped after the lock is won) cannot see."""
+        req.submit_t = time.monotonic()
+        with TraceAnnotation("smg.submit.lock_wait"):
+            self._wakeup.acquire()
+        try:
+            wait = time.monotonic() - req.submit_t
+            self.num_submits += 1
+            self.submit_lock_wait_s_total += wait
+            self.metrics.observe_submit_lock_wait(wait)
+            yield
+        finally:
+            self._wakeup.release()
 
     def _build_token_filter(self, sampling: SamplingParams):
         """Install the grammar vocab-mask filter for structured output.
@@ -302,6 +335,8 @@ class Engine:
                 out["programs"] = self.runner._programs.snapshot()
         out["healthy"] = self.healthy
         out["watchdog_stalls"] = self.num_watchdog_stalls
+        out["submits"] = self.num_submits
+        out["submit_lock_wait_seconds"] = self.submit_lock_wait_s_total
         return out
 
     def program_audit(self, *, check_donation: bool = True) -> dict:
@@ -481,17 +516,25 @@ class Engine:
     ) -> str:
         """Begin a jax.profiler trace; returns the resolved trace dir.
         ``num_steps > 0`` auto-stops the trace after that many engine steps
-        (reference StartProfileRequest.num_steps semantics)."""
+        (reference StartProfileRequest.num_steps semantics).  Neither this
+        nor ``stop_profile`` takes the engine lock: steps and submits go on
+        while the profiler starts and while it writes the trace."""
         import jax
 
-        with self._lock:
-            if getattr(self, "_profiling", False):
+        with self._profile_lock:
+            if self._profiling:
                 raise RuntimeError("profiler already running")
+            self._profiling = True  # claimed; released below if the start fails
+        try:
             opts = jax.profiler.ProfileOptions()
             opts.host_tracer_level = 2 if host_tracer else 0
             opts.python_tracer_level = 1 if python_tracer else 0
             jax.profiler.start_trace(output_dir, profiler_options=opts)
-            self._profiling = True
+        except BaseException:
+            with self._profile_lock:
+                self._profiling = False
+            raise
+        with self._profile_lock:
             self._profile_steps_left = num_steps if num_steps > 0 else None
         logger.info("profiler started -> %s", output_dir)
         return output_dir
@@ -499,17 +542,45 @@ class Engine:
     def stop_profile(self) -> None:
         import jax
 
-        with self._lock:
-            if not getattr(self, "_profiling", False):
+        with self._profile_lock:
+            if not self._profiling or self._profile_stopping:
                 raise RuntimeError("profiler not running")
-            try:
-                jax.profiler.stop_trace()
-            finally:
-                # trace serialization can fail (unwritable dir); never wedge
-                # the profiler state on it
+            self._profile_stopping = True
+            self._profile_steps_left = None
+        try:
+            jax.profiler.stop_trace()  # writes the trace: seconds, no engine lock
+        finally:
+            # trace serialization can fail (unwritable dir); never wedge
+            # the profiler state on it
+            with self._profile_lock:
                 self._profiling = False
-                self._profile_steps_left = None
+                self._profile_stopping = False
         logger.info("profiler stopped")
+
+    def _profile_step_done(self) -> None:
+        """Count one step against a ``num_steps`` trace.  At zero the stop
+        runs on a thread of its own: the caller is ``step()``, and writing
+        the trace on the step thread would stall serving just as writing it
+        under the engine lock did."""
+        with self._profile_lock:
+            if self._profile_steps_left is None:
+                return
+            self._profile_steps_left -= 1
+            if self._profile_steps_left > 0:
+                return
+            self._profile_steps_left = None
+        threading.Thread(
+            target=self._stop_profile_after_steps, name="smg-profiler-stop",
+            daemon=True,
+        ).start()
+
+    def _stop_profile_after_steps(self) -> None:
+        try:
+            self.stop_profile()
+        except RuntimeError:
+            pass  # an explicit stop_profile got there first
+        except Exception:
+            logger.exception("step-bounded profiler stop failed")
 
     # ---- PD disaggregation legs ----
 
@@ -561,7 +632,7 @@ class Engine:
         req.token_filter = self._build_token_filter(sampling)
         if sampling.lora_adapter:
             req.lora_idx = self.runner.lora_index(sampling.lora_adapter)
-        with self._wakeup:
+        with self._locked_for_submit(req), TraceAnnotation("smg.submit"):
             pages = None
             try:
                 from smg_tpu.engine.kv_connector import resolve_for_payload
@@ -601,9 +672,10 @@ class Engine:
 
     def step(self) -> list[RequestOutput]:
         """One scheduler iteration; returns per-request increments."""
-        with self._lock:
+        with self._lock, TraceAnnotation("smg.step"):
             step_outs = self.scheduler.step()
-            outputs = [self._postprocess(so) for so in step_outs]
+            with TraceAnnotation("smg.step.postprocess"):
+                outputs = [self._postprocess(so) for so in step_outs]
             self.events.flush()
             if self.config.device_metrics_interval_secs > 0:
                 # cadence-gated HBM gauges (no-op between samples; CPU
@@ -613,34 +685,25 @@ class Engine:
                 if self._metric_devices is None:
                     self._metric_devices = self.runner.local_devices()
                 self.metrics.maybe_sample_devices(self._metric_devices)
-            if getattr(self, "_profile_steps_left", None) is not None:
-                self._profile_steps_left -= 1
-                if self._profile_steps_left <= 0:
-                    try:
-                        import jax
-
-                        jax.profiler.stop_trace()
-                        logger.info("profiler stopped (step budget reached)")
-                    except Exception:
-                        logger.exception("step-bounded profiler stop failed")
-                    finally:
-                        self._profiling = False
-                        self._profile_steps_left = None
+        # smglint: disable-next=GUARDED lock-free gate; the count re-reads under the profiler's lock
+        if self._profile_steps_left is not None:
+            self._profile_step_done()
         # watchdog progress mark + stall recovery (a step completed end to
         # end, so a previously-flagged wedge has cleared)
         self._last_progress = time.monotonic()
         if self._stalled:
             self._stalled = False
             logger.warning("engine step progress resumed; stall cleared")
-        for out in outputs:
-            cb = self._callbacks.get(out.rid)
-            if cb is not None:
-                try:
-                    cb(out)
-                except Exception:
-                    logger.exception("output callback failed for %s", out.rid)
-                if out.finished:
-                    self._callbacks.pop(out.rid, None)
+        with TraceAnnotation("smg.step.callbacks"):
+            for out in outputs:
+                cb = self._callbacks.get(out.rid)
+                if cb is not None:
+                    try:
+                        cb(out)
+                    except Exception:
+                        logger.exception("output callback failed for %s", out.rid)
+                    if out.finished:
+                        self._callbacks.pop(out.rid, None)
         return outputs
 
     def _postprocess(self, so: StepOutput) -> RequestOutput:

@@ -61,8 +61,11 @@ logger = get_logger("engine.flight_recorder")
 #: decoding — per-step drafted/accepted token counts from the fused
 #: verify blocks consumed that step; v4: tensor-parallel sharded decode —
 #: the engine's mesh device count rides every step record, so rings pulled
-#: from a mixed single-device/TP fleet self-describe their topology)
-SCHEMA_VERSION = 4
+#: from a mixed single-device/TP fleet self-describe their topology; v5:
+#: ``horizon_reason`` on the step record — why the decode launch of that
+#: step ran the K it ran — and ``submit_t`` on the timeline, stamped before
+#: the submit waits for the engine lock)
+SCHEMA_VERSION = 5
 
 #: stable key set of one step record (schema contract, tested)
 STEP_RECORD_KEYS = frozenset({
@@ -70,8 +73,15 @@ STEP_RECORD_KEYS = frozenset({
     "prefill_tokens", "decode_tokens", "prefill_inflight_tokens",
     "free_pages", "admissions", "finishes", "overlap", "fetch_wait_s",
     "faults", "horizon", "early_exits", "wasted_decode_tokens",
-    "spec_drafted", "spec_accepted", "mesh",
+    "spec_drafted", "spec_accepted", "mesh", "horizon_reason",
 })
+
+#: why a decode launch ran the horizon it ran (``Scheduler._pick_horizon``);
+#: a step record of a step that launched no decode carries ""
+HORIZON_REASONS = (
+    "full", "forced_lane", "pending_admission", "adaptive", "page_headroom",
+    "cap",
+)
 
 
 class RequestTimeline:
@@ -79,7 +89,8 @@ class RequestTimeline:
     FlightRecorder (which holds its lock); this object is plain state."""
 
     __slots__ = (
-        "rid", "trace_id", "meta", "queued_t", "admitted_t", "first_token_t",
+        "rid", "trace_id", "meta", "submit_t", "queued_t", "admitted_t",
+        "first_token_t",
         "last_token_t", "finish_t", "finish_reason", "finish_message",
         "prompt_tokens", "cached_tokens", "output_tokens", "deadline_t",
         "events", "itl_samples", "itl_count", "itl_total", "itl_max",
@@ -88,10 +99,13 @@ class RequestTimeline:
     def __init__(self, rid: str, t: float, *, prompt_tokens: int = 0,
                  trace_id: str | None = None, meta: dict | None = None,
                  deadline_t: float | None = None, events_cap: int = 96,
-                 itl_cap: int = 64):
+                 itl_cap: int = 64, submit_t: float | None = None):
         self.rid = rid
         self.trace_id = trace_id
         self.meta = meta or {}
+        # before Engine.submit waited for the engine lock; a request that
+        # reached the scheduler another way was queued when it was submitted
+        self.submit_t = t if submit_t is None else submit_t
         self.queued_t = t
         self.admitted_t: float | None = None
         self.first_token_t: float | None = None
@@ -126,6 +140,7 @@ class RequestTimeline:
             "rid": self.rid,
             "trace_id": self.trace_id,
             "meta": dict(self.meta),
+            "submit_t": self.submit_t,
             "queued_t": self.queued_t,
             "admitted_t": self.admitted_t,
             "first_token_t": self.first_token_t,
@@ -196,7 +211,7 @@ class FlightRecorder:
         horizon: int = 0, early_exits: int = 0,
         wasted_decode_tokens: int = 0,
         spec_drafted: int = 0, spec_accepted: int = 0,
-        mesh: int = 1,
+        mesh: int = 1, horizon_reason: str = "",
     ) -> int:
         """Append one step record; returns the step serial.  Called once per
         scheduler step with values already in hand — no derivation here."""
@@ -241,6 +256,11 @@ class FlightRecorder:
                 # single-device; static per engine, but the ring is often
                 # read detached from the engine that produced it)
                 "mesh": mesh,
+                # why this step's decode launch ran its K (HORIZON_REASONS;
+                # "" = the step launched no decode).  ``horizon`` above is
+                # the frame CONSUMED this step, launched one step earlier
+                # under the overlapped schedule
+                "horizon_reason": horizon_reason,
             })
             return self.step_serial
 
@@ -249,11 +269,13 @@ class FlightRecorder:
     def on_queued(
         self, rid: str, *, prompt_tokens: int, trace_id: str | None = None,
         meta: dict | None = None, deadline_t: float | None = None,
+        submit_t: float | None = None,
     ) -> None:
         t = time.monotonic()
         tl = RequestTimeline(
             rid, t, prompt_tokens=prompt_tokens, trace_id=trace_id, meta=meta,
             deadline_t=deadline_t, events_cap=self.events_per_timeline,
+            submit_t=submit_t,
         )
         tl.events.append((t, "queued", {"prompt_tokens": prompt_tokens}))
         with self._lock:

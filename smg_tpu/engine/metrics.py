@@ -356,6 +356,31 @@ class EngineMetrics:
             ["phase"], registry=r,
         ))
 
+        # where a caller's time to first token goes inside the engine
+        self.submit_lock_wait = _track(Counter(
+            "smg_engine_submit_lock_wait_seconds_total",
+            "Time submissions spent waiting for the engine lock (step() "
+            "holds it across the blocking fetch of the frame in flight); "
+            "over smg_engine_submits_total it is the mean wait of a submit",
+            registry=r,
+        ))
+        self.submits = _track(Counter(
+            "smg_engine_submits_total",
+            "Submissions that took the engine lock (the count behind "
+            "smg_engine_submit_lock_wait_seconds_total)",
+            registry=r,
+        ))
+        self.decode_launches = _track(Counter(
+            "smg_engine_decode_launches_total",
+            "Decode megastep launches by why their horizon K was chosen: "
+            "full (the configured K), forced_lane (grammar or stop-string "
+            "lanes force K=1), pending_admission (a waiting or mid-prefill "
+            "request forces K=1), adaptive (the finish-gap controller or a "
+            "lane's remaining budget shrank K), page_headroom (free pages "
+            "shrank K), cap (horizon_cap is 1)",
+            ["horizon_reason"], registry=r,
+        ))
+
     # ---- registry unification ----
 
     def register_into(self, registry: CollectorRegistry) -> None:
@@ -487,6 +512,10 @@ class EngineMetrics:
         deferred-fetch block); see ``smg_engine_dispatch_seconds_total``."""
         self.dispatch_seconds.labels(phase="enqueue").inc(max(enqueue_s, 0.0))
         self.dispatch_seconds.labels(phase="fetch").inc(max(fetch_s, 0.0))
+
+    def observe_submit_lock_wait(self, seconds: float) -> None:
+        self.submits.inc()
+        self.submit_lock_wait.inc(max(seconds, 0.0))
 
     def set_mesh_devices(self, n: int) -> None:
         """One-shot topology gauge (engine construction)."""

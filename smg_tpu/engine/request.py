@@ -98,6 +98,9 @@ class EngineRequest:
     # recorded into the flight-recorder timeline so a postmortem dump links
     # back to the request's distributed trace.  None = no trace context.
     trace_id: str | None = None
+    # time.monotonic() when Engine.submit started waiting for the engine
+    # lock; the flight recorder's ``queued_t`` minus this is the lock wait
+    submit_t: float | None = None
 
     @property
     def prompt_len(self) -> int:

@@ -23,6 +23,7 @@ NEG_INF = -1e30
 K_CAP = 64  # top-k candidates examined for thresholds
 
 
+@jax.named_scope("smg.sample")
 def sample_tokens(
     logits: jnp.ndarray,  # [B, V] float32
     key: jax.Array,
@@ -100,6 +101,7 @@ def sample_tokens(
     return tokens, chosen_logit - raw_lse
 
 
+@jax.named_scope("smg.sample")
 def sample_tokens_exact(
     logits: jnp.ndarray,
     key: jax.Array,

@@ -59,6 +59,7 @@ import numpy as np
 from jax import lax
 
 from smg_tpu.engine.config import EngineConfig
+from smg_tpu.engine.flight_recorder import HORIZON_REASONS
 from smg_tpu.engine.kv_cache import PagePool
 from smg_tpu.engine.radix_cache import RadixCache
 from smg_tpu.engine.request import (
@@ -69,6 +70,7 @@ from smg_tpu.engine.request import (
     StepOutput,
 )
 from smg_tpu.engine.runner import DecodeState, ModelRunner
+from smg_tpu.engine.spans import spanned
 from smg_tpu.faults import FAULTS
 from smg_tpu.utils import get_logger
 
@@ -119,6 +121,12 @@ class InFlightFrame:
     n_emit: "object" = None  # jax.Array [B] (spec frames only)
     draft_ns: "list | None" = None  # per-lane drafted-token counts
     tiers: "list | None" = None  # per-lane drafting tier ("ngram"/"draft")
+
+
+def _launch_attrs(frame: "InFlightFrame") -> dict:
+    """Attributes of an ``smg.step.launch`` span."""
+    return {"K": frame.horizon, "lanes": frame.B_real,
+            "lookahead": int(frame.lookahead)}
 
 
 class Scheduler:
@@ -187,6 +195,11 @@ class Scheduler:
         self._cols_since_finish = 0
         # step-scoped megastep telemetry for the flight-recorder ring
         self._step_horizon = 0
+        # why _pick_horizon chose its last K; the launch that follows copies
+        # it into the step record and counts it (HORIZON_REASONS)
+        self._picked_reason = ""
+        self._step_horizon_reason = ""
+        self.num_decode_launches = dict.fromkeys(HORIZON_REASONS, 0)
         # step-scoped speculative-decoding telemetry (flight-recorder ring
         # spec fields) + the acceptance-length EMA the adaptive depth
         # controller reads (_pick_spec_depth)
@@ -265,7 +278,7 @@ class Scheduler:
             self.flight.on_queued(
                 req.rid, prompt_tokens=len(req.prompt_ids),
                 trace_id=req.trace_id, meta=self._flight_meta(req),
-                deadline_t=req.deadline,
+                deadline_t=req.deadline, submit_t=req.submit_t,
             )
 
     def _flight_meta(self, req: EngineRequest) -> dict:
@@ -404,6 +417,8 @@ class Scheduler:
             # device-side early exits (a finish ended a horizon early)
             "wasted_decode_tokens": self.num_wasted_decode_tokens,
             "megastep_early_exits": self.num_megastep_early_exits,
+            # decode launches by why their K was chosen (HORIZON_REASONS)
+            "decode_launches": dict(self.num_decode_launches),
             # failure isolation: quarantine/deadline/backpressure counters
             # the gateway's health + routing decisions key off
             "quarantined_requests": self.num_quarantined,
@@ -512,6 +527,7 @@ class Scheduler:
         self._step_fetch_s = 0.0
         self._step_dispatch_s = 0.0
         self._step_horizon = 0
+        self._step_horizon_reason = ""
         self._step_spec_drafted = 0
         self._step_spec_accepted = 0
         pf0, dc0 = self.num_prefill_tokens, self.num_decode_tokens
@@ -556,6 +572,7 @@ class Scheduler:
                     spec_drafted=self._step_spec_drafted,
                     spec_accepted=self._step_spec_accepted,
                     mesh=self._mesh_devices,
+                    horizon_reason=self._step_horizon_reason,
                 )
                 self.flush_pending_dumps()
         return outputs
@@ -1065,6 +1082,7 @@ class Scheduler:
                 return j
         return None
 
+    @spanned("smg.step.consume")
     def _consume_frame(
         self, frame: InFlightFrame, outputs: list[StepOutput]
     ) -> tuple[float, int]:
@@ -1131,6 +1149,7 @@ class Scheduler:
             self._cols_since_finish = 0
         return fetch_s, used
 
+    @spanned("smg.step.launch", _launch_attrs)
     def _launch_lookahead(self, frame: InFlightFrame) -> InFlightFrame | None:
         """Chained launch for the step AFTER ``frame``, dispatched before
         ``frame`` is consumed.  Input tokens are the frame's last sampled
@@ -1211,6 +1230,7 @@ class Scheduler:
             rope_delta=ds.rope_delta if frame.use_mrope else None,
         )
         self._note_dispatch(time.perf_counter() - t_dispatch)
+        self._count_decode_launch()
         return InFlightFrame(
             lanes=[(s, r, e + H) for s, r, e in frame.lanes],
             toks=toks, lps=lps, horizon=H2, B=frame.B, B_real=frame.B_real,
@@ -1222,6 +1242,7 @@ class Scheduler:
 
     # ---- admission / prefill (the per-step prefill phase) ----
 
+    @spanned("smg.step.admit")
     def _admit(self, outputs: list[StepOutput]) -> bool:
         """Run this step's prefill phase under the configured mix policy.
 
@@ -1966,11 +1987,13 @@ class Scheduler:
             for _, r in active
         )
         if forced or cap <= 1:
+            self._picked_reason = "forced_lane" if forced else "cap"
             return 1, 1
         if self.waiting or any(
             r is not None and r.status is RequestStatus.PREFILLING
             for r in self.slots
         ):
+            self._picked_reason = "pending_admission"
             return 1, cap
         if sched.adaptive_horizon:
             k = cap
@@ -1985,8 +2008,10 @@ class Scheduler:
                 for _, r in active
             )
             k = max(1, min(k, rem))
+            self._picked_reason = "adaptive" if k < cap else "full"
         else:
             k = min(max(sched.decode_horizon, 1), cap)
+            self._picked_reason = "full"
         # page-headroom clamp applies to the STATIC path too (parity, not
         # just politeness): growing every lane K tokens must fit the free
         # pool, else _ensure_seq_capacity would evict/preempt for a horizon
@@ -2002,7 +2027,18 @@ class Scheduler:
             if need <= self.pool.free_count:
                 break
             k //= 2
+            self._picked_reason = "page_headroom"
         return k, cap
+
+    def _count_decode_launch(self) -> None:
+        """A decode megastep was enqueued at the K ``_pick_horizon`` just
+        chose: its reason goes on the step record, into
+        ``loads()["decode_launches"]`` and
+        ``smg_engine_decode_launches_total{horizon_reason}``."""
+        reason = self._step_horizon_reason = self._picked_reason
+        self.num_decode_launches[reason] += 1
+        if self.metrics is not None:
+            self.metrics.decode_launches.labels(horizon_reason=reason).inc()
 
     def _stop_id_width(self, active: list) -> int:
         """Power-of-two width (>= 1) of the device stop-token id set: EOS
@@ -2020,6 +2056,7 @@ class Scheduler:
             e *= 2
         return e
 
+    @spanned("smg.step.launch", _launch_attrs)
     def _launch_frame(self, active: list) -> InFlightFrame | None:
         """Plan + dispatch one decode megastep for ``active`` slots; returns
         the in-flight frame (results unmaterialized) or None when capacity
@@ -2089,6 +2126,7 @@ class Scheduler:
             rope_delta=ds.rope_delta if use_mrope else None,
         )
         self._note_dispatch(time.perf_counter() - t_dispatch)
+        self._count_decode_launch()
         return InFlightFrame(
             lanes=[(i, r, r.seq_len) for i, r in active],
             toks=toks, lps=lps, horizon=horizon, B=B, B_real=B_real,
@@ -2700,7 +2738,7 @@ class Scheduler:
             # (its prefill ran on the other leg's worker)
             self.flight.on_queued(
                 req.rid, prompt_tokens=req.prompt_len, trace_id=req.trace_id,
-                meta=self._flight_meta(req),
+                meta=self._flight_meta(req), submit_t=req.submit_t,
             )
             self.flight.event(req.rid, "adopted", slot=slot)
         # first_token is accepted by the caller (stop checks + client emission)

@@ -134,6 +134,12 @@ def kv_cache_logical_axes() -> tuple[str | None, ...]:
     return ("layers", "pages", None, "kv_lanes")
 
 
+# The ``smg.*`` named scopes (here, in ops/attention.py, ops/pallas and
+# engine/sampling.py) reach the profiler's device trace as part of each HLO
+# operation's name path, so device time can be split by kernel; an operation
+# belongs to the innermost ``smg.`` scope on its path.  Metadata only: the
+# compiled code is the same with and without them.
+@jax.named_scope("smg.embed")
 def embed_tokens(params: Params, cfg: ModelConfig, tokens: jnp.ndarray) -> jnp.ndarray:
     h = params["embed"][tokens]
     if cfg.embed_scale:  # Gemma: embeddings scaled by sqrt(hidden)
@@ -141,6 +147,7 @@ def embed_tokens(params: Params, cfg: ModelConfig, tokens: jnp.ndarray) -> jnp.n
     return h
 
 
+@jax.named_scope("smg.lm_head")
 def unembed(params: Params, cfg: ModelConfig, h: jnp.ndarray) -> jnp.ndarray:
     h = _norm(h, params["final_norm"], cfg)
     if cfg.tie_word_embeddings:
@@ -175,6 +182,7 @@ def _attn_residual(h, layer, attn, cfg, lora=None, gates=None):
     return h + o
 
 
+@jax.named_scope("smg.mlp")
 def _mlp_residual(h, layer, cfg):
     """Pre-norm -> MLP -> (optional Gemma-2 post-ffn norm) -> residual."""
     o = _mlp(layer, _norm(h, layer["mlp_norm"], cfg), cfg)

@@ -95,6 +95,7 @@ def scatter_kv_rows(
     return k_flat.reshape(k_cache.shape), v_flat.reshape(v_cache.shape)
 
 
+@jax.named_scope("smg.attn.kv_read")
 def gather_seq_kv(
     k_pages: jnp.ndarray,  # [P, ps, KD]
     v_pages: jnp.ndarray,
@@ -122,6 +123,21 @@ def _query_block(T: int, H: int, S: int) -> int:
     return qb
 
 
+@jax.named_scope("smg.attn.kv_read")
+def gather_layer_pages(
+    k_cache: jnp.ndarray,  # [L, P, ps, K*D]
+    v_cache: jnp.ndarray,
+    layer,  # scalar layer index
+    page_tables: jnp.ndarray,  # [B, mp]
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """One layer's pages for every lane: ``[B, mp, ps, K*D]`` each.  The
+    slice of the layer out of the cache and the gather of the pages out of
+    that slice are the decode megastep's two largest device operations (a
+    scope of their own so the trace can say so)."""
+    return k_cache[layer][page_tables], v_cache[layer][page_tables]
+
+
+@jax.named_scope("smg.attn.prefill")
 def attention_prefill(
     q: jnp.ndarray,  # [T, H, D] (new tokens, post-rope)
     k_ctx: jnp.ndarray,  # [S, K, D] contiguous KV incl. prefix and new tokens
@@ -167,6 +183,7 @@ def attention_prefill(
     return out.reshape(T, H, D)
 
 
+@jax.named_scope("smg.attn.prefill")
 def attention_prefill_batched(
     q: jnp.ndarray,  # [G, T, H, D] (new tokens per sequence, post-rope)
     k_ctx: jnp.ndarray,  # [G, S, K, D] per-sequence contiguous KV
@@ -210,6 +227,7 @@ def attention_prefill_batched(
     return out.reshape(G_, T, H, D).astype(q.dtype)
 
 
+@jax.named_scope("smg.attn.decode")
 def attention_decode_cached(
     q: jnp.ndarray,  # [B, H, D]
     k_cache: jnp.ndarray,  # [L, P, ps, K*D] read-only cache (fused lanes)
@@ -236,8 +254,7 @@ def attention_decode_cached(
     # preferred_element_type): converting the gather to f32 doubles its HBM
     # write traffic, and decode is bandwidth-bound.
     cd = k_cache.dtype
-    kl = k_cache[layer][page_tables]  # [B, mp, ps, KD]
-    vl = v_cache[layer][page_tables]
+    kl, vl = gather_layer_pages(k_cache, v_cache, layer, page_tables)
     mp = kl.shape[1]
     S = mp * ps
     kl = kl.reshape(B, S, K, D)
@@ -273,6 +290,7 @@ def attention_decode_cached(
     return out.reshape(B, H, D).astype(q.dtype)
 
 
+@jax.named_scope("smg.attn.decode")
 def attention_verify_block(
     q: jnp.ndarray,  # [B, W, H, D] one verify block per lane (post-rope)
     k_cache: jnp.ndarray,  # [L, P, ps, K*D] read-only cache (fused lanes)
@@ -299,8 +317,7 @@ def attention_verify_block(
     K = KD // D
     G = H // K
     cd = k_cache.dtype  # cache-dtype matmuls, f32 accumulation (HBM-bound)
-    kl = k_cache[layer][page_tables]  # [B, mp, ps, KD]
-    vl = v_cache[layer][page_tables]
+    kl, vl = gather_layer_pages(k_cache, v_cache, layer, page_tables)
     mp = kl.shape[1]
     S = mp * ps
     kl = kl.reshape(B, S, K, D)
@@ -339,6 +356,7 @@ def attention_verify_block(
     return out.reshape(B, W, H, D).astype(q.dtype)
 
 
+@jax.named_scope("smg.attn.decode")
 def attention_decode(
     q: jnp.ndarray,  # [B, H, D] one new token per sequence (post-rope)
     k_pages: jnp.ndarray,  # [P, ps, KD]

@@ -169,6 +169,7 @@ def _decode_kernel(
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "softcap", "interpret"))
+@jax.named_scope("smg.attn.decode")
 def paged_attention_decode_cached(
     q: jax.Array,  # [B, H, D] post-rope queries
     k_cache: jax.Array,  # [L, P, ps, K*D] read-only cache (fused lanes)

@@ -212,6 +212,7 @@ def _prefill_kernel(
 @functools.partial(
     jax.jit, static_argnames=("scale", "softcap", "interpret", "q_tile")
 )
+@jax.named_scope("smg.attn.prefill")
 def paged_attention_prefill(
     q: jax.Array,  # [T, H, D] post-rope chunk queries
     chunk_k: jax.Array,  # [T, K*D] post-rope chunk keys (fused lanes)

@@ -162,8 +162,8 @@ def test_dump_schema_stable():
         assert set(rec) == STEP_RECORD_KEYS
     tl = dump["timelines"]["finished"][0]
     assert {
-        "rid", "trace_id", "meta", "queued_t", "admitted_t", "first_token_t",
-        "finish_t", "finish_reason", "finish_message", "deadline_t", "ttft_s",
+        "rid", "trace_id", "meta", "submit_t", "queued_t", "admitted_t",
+        "first_token_t", "finish_t", "finish_reason", "finish_message", "deadline_t", "ttft_s",
         "e2e_s", "prompt_tokens", "cached_tokens", "output_tokens", "itl",
         "events",
     } == set(tl)

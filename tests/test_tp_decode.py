@@ -224,8 +224,8 @@ def test_mesh_surfaces(cpu_devices):
     assert mesh["platform"] == "cpu"
     assert mesh["donate_kv"] is False  # overlap on a CPU mesh
     assert loads["dispatch_enqueue_seconds"] > 0.0
-    # flight ring: every step record carries the mesh device count (schema v4)
-    assert SCHEMA_VERSION == 4
+    # flight ring: every step record carries the mesh device count (since v4)
+    assert SCHEMA_VERSION >= 4
     assert "mesh" in STEP_RECORD_KEYS
     dump = eng.dump_flight("test")
     recs = dump["ring"]
