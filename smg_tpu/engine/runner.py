@@ -250,6 +250,13 @@ class ModelRunner:
         elif self._device is not None:
             kv_sharding = jax.sharding.SingleDeviceSharding(self._device)
         self.kv_sharding = kv_sharding
+        # XLA decode and verify attention adapt to where the cache's lane
+        # axis lies: whole on the device, its products run on the fused
+        # lanes; split over a mesh axis, per head (ops.attention
+        # ._attend_cache_and_side says why)
+        lane_axis = kv_sharding.spec[3] if self.mesh is not None else None
+        self.kv_lanes_sharded = (
+            lane_axis is not None and self.mesh.shape[lane_axis] > 1)
         self.k_cache, self.v_cache = create_kv_buffers(self.spec, kv_sharding)
         logger.info(
             "kv cache: %d pages x %d tokens (%.1f MiB)",
@@ -358,13 +365,16 @@ class ModelRunner:
                 del self._compiled[k]
         self._programs.forget(dropped)
 
-    def _register(self, k, fn, *, donate, in_shardings, attn: str):
+    def _register(self, k, fn, *, donate, in_shardings, attn: str,
+                  products: str | None = None):
         """Cache jitted program ``fn`` under key ``k`` behind the auditor's
         launch wrapper, and count its launches under the attention
-        implementation it was traced with."""
+        implementation it was traced with (``products``: the form of its
+        XLA decode products, where it has them)."""
         launch = self._programs.wrap(k, fn, donate=donate,
                                      in_shardings=in_shardings)
-        logger.info("program %s: attention %s", k, attn)
+        logger.info("program %s: attention %s%s", k, attn,
+                    f" ({products} products)" if products else "")
 
         def counted(*args):
             self.attn_launches[attn] += 1
@@ -383,10 +393,12 @@ class ModelRunner:
 
     def _attn_impl_for(self, B: int, mp: int) -> str:
         """Decode attention for one (batch bucket, table width) program.
-        The XLA path gathers ``B*mp*ps`` tokens of KV per layer (the
-        fused-lane layout makes the gather relayout-free); the kernel
-        streams only the pages that hold tokens.  The 131072-token crossover
-        has no measurement on record (ROADMAP S4)."""
+        The XLA path gathers ``B*mp*ps`` tokens of KV per layer, whatever
+        the lanes hold, and multiplies them where the gather left them (on
+        the fused lanes; per head under a mesh that splits them, which
+        copies the gather once more); the kernel streams only the pages
+        that hold tokens.  The 131072-token crossover has no measurement on
+        record (ROADMAP S2)."""
         if self.use_pp:
             return "xla"  # pallas kernels don't run inside the pp shard_map
         if self.attn_impl != "auto":
@@ -438,10 +450,18 @@ class ModelRunner:
         mutating the cached snapshot."""
         return dict(self._mesh_info)
 
+    @property
+    def xla_decode_products(self) -> str:
+        """The form XLA decode and verify programs are traced with."""
+        return "per_head" if self.kv_lanes_sharded else "fused_lanes"
+
     def attention_info(self) -> dict:
         """The attention dispatch as ``loads()`` / ``/scheduler`` report it:
-        the resolved mode and launches so far per implementation."""
-        return {"mode": self.attn_impl, "launches": dict(self.attn_launches)}
+        the resolved mode, the form of the XLA decode products, and launches
+        so far per implementation."""
+        return {"mode": self.attn_impl,
+                "xla_decode_products": self.xla_decode_products,
+                "launches": dict(self.attn_launches)}
 
     def _detect_hbm(self) -> int | None:
         """Free HBM on the tightest device this engine will occupy.
@@ -1060,6 +1080,7 @@ class ModelRunner:
                     lora=lora_bank, lora_gates=lora_gates,
                     pp_mesh=(self.mesh if self.use_pp else None),
                     rope_delta=rope_delta,
+                    kv_lanes_sharded=self.kv_lanes_sharded,
                 )
                 if use_pen:
                     logits = apply_penalties(logits, counts, pmask, freqs,
@@ -1139,7 +1160,9 @@ class ModelRunner:
             in_sh = None
             fn = jax.jit(multi, donate_argnums=donate)
         return self._register(k, fn, donate=donate, in_shardings=in_sh,
-                              attn=_attn_label("decode", attn_impl))
+                              attn=_attn_label("decode", attn_impl),
+                              products=(self.xla_decode_products
+                                        if attn_impl == "xla" else None))
 
     def decode_multi_async(
         self,
@@ -1557,7 +1580,7 @@ class ModelRunner:
             rope_delta = extra[0] if use_mrope else None
             logits, bk, bv = module.forward_verify_block(
                 params, cfg, inv_freq, tokens, entry_pos, kc, vc, page_tables,
-                rope_delta=rope_delta,
+                rope_delta=rope_delta, kv_lanes_sharded=self.kv_lanes_sharded,
             )  # [B, W, V], [L, B, W, KD] x2
             # same lane-sharding hint as the megastep's horizon carry: keep
             # the accepted-column scatter shard-local against the kv cache
@@ -1633,7 +1656,7 @@ class ModelRunner:
             in_sh = None
             fn = jax.jit(spec, donate_argnums=donate)
         return self._register(k, fn, donate=donate, in_shardings=in_sh,
-                              attn="xla")
+                              attn="xla", products=self.xla_decode_products)
 
     def decode_spec_async(
         self,

@@ -29,6 +29,7 @@ from smg_tpu.ops.attention import (
     attention_prefill,
     attention_prefill_batched,
     attention_verify_block,
+    gather_layer_pages,
     gather_seq_kv,
     scatter_kv_pages_full,
 )
@@ -385,7 +386,7 @@ def forward_prefill(
                 )
             else:
                 k_ctx, v_ctx = gather_seq_kv(
-                    k_cache[l], v_cache[l], page_table, cfg.num_kv_heads
+                    k_cache, v_cache, l, page_table, cfg.num_kv_heads
                 )
                 attn = attention_prefill(q, k_ctx, v_ctx, pos, ctx_len, scale,
                                          softcap=cfg.attn_logit_softcap,
@@ -563,8 +564,8 @@ def forward_prefill_batched(
                                                  softcap=cfg.attn_logit_softcap,
                                                  window=_layer_window(cfg, l))
             else:
-                kl = k_cache[l][page_tables]  # [G, mp, ps, KD]
-                vl = v_cache[l][page_tables]
+                kl, vl = gather_layer_pages(  # [G, mp, ps, KD]
+                    k_cache, v_cache, l, page_tables)
                 S = mp * ps
                 k_ctx = kl.reshape(G_, S, K, D)
                 v_ctx = vl.reshape(G_, S, K, D)
@@ -619,6 +620,7 @@ def forward_decode_horizon(
     lora_gates: jnp.ndarray | None = None,  # [B, n_adapters] one-hot per slot
     pp_mesh=None,  # Mesh: serving pipeline parallelism over the "pp" axis
     rope_delta: jnp.ndarray | None = None,  # [B] M-RoPE decode offset per slot
+    kv_lanes_sharded: bool = False,  # the cache's K*D axis is split over a mesh
 ):
     """One decode step against a frozen cache + growing side buffer.
 
@@ -683,6 +685,7 @@ def forward_decode_horizon(
                     page_tables, entry_positions, scale,
                     softcap=cfg.attn_logit_softcap,
                     window=_layer_window(cfg, l),
+                    lanes_sharded=kv_lanes_sharded,
                 )
             h = _attn_residual(h, layer, attn, cfg, lor, lora_gates)
             h = _mlp_residual(h, layer, cfg)
@@ -721,6 +724,7 @@ def forward_verify_block(
     v_cache: jnp.ndarray,
     page_tables: jnp.ndarray,  # [B, mp]
     rope_delta: jnp.ndarray | None = None,  # [B] M-RoPE decode offset per lane
+    kv_lanes_sharded: bool = False,  # the cache's K*D axis is split over a mesh
 ):
     """Speculative verify block: score W tokens per lane in ONE forward.
 
@@ -769,6 +773,7 @@ def forward_verify_block(
         attn = attention_verify_block(
             q, k_cache, v_cache, bk_l, bv_l, l, page_tables, entry_positions,
             scale, softcap=cfg.attn_logit_softcap, window=_layer_window(cfg, l),
+            lanes_sharded=kv_lanes_sharded,
         )
         h = _attn_residual(h, layer, attn, cfg)
         h = _mlp_residual(h, layer, cfg)

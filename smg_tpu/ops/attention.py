@@ -3,9 +3,13 @@
 Layout (per layer): ``k_pages, v_pages: [num_pages, page_size, kv_heads*head_dim]``
 — the kv-head and head-dim axes are FUSED into the lane dimension (>= 512
 lanes for standard configs).  This keeps the trailing dim a multiple of the
-TPU 128-lane tile for any head_dim, so page views/reshapes are bitcasts and
-the Pallas kernels DMA pages without relayout copies (head_dim 64 unfused
-would lane-pad 64->128 and every cache reshape would copy ~0.5 GB).
+TPU 128-lane tile for any head_dim, so the Pallas kernels DMA pages without
+relayout copies (head_dim 64 unfused would lane-pad 64->128).  Only views
+that KEEP the fused lane axis are bitcasts (``[B, mp, ps, K*D]`` to
+``[B, mp*ps, K*D]``: whole sublane tiles); splitting it into ``[.., K, D]``
+for a per-head product is a copy of the operand on the chip's (8, 128)
+tiles.  So XLA decode and verify attention keep K and V on the fused lanes
+between the page gather and the matmuls (``_attend_cache_and_side``).
 Sequences own an ordered list of pages (``page_table``); the radix prefix cache
 shares page prefixes between sequences (``smg_tpu/engine/radix_cache.py``).
 Page 0 is reserved as a garbage page: padded/inactive tokens scatter there.
@@ -96,15 +100,29 @@ def scatter_kv_rows(
 
 
 @jax.named_scope("smg.attn.kv_read")
+def gather_layer_pages(
+    k_cache: jnp.ndarray,  # [L, P, ps, K*D]
+    v_cache: jnp.ndarray,
+    layer,  # scalar layer index
+    page_tables: jnp.ndarray,  # [..., mp]
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """One layer's pages for every lane, ``[..., mp, ps, K*D]`` each, in ONE
+    gather: the layer is part of the gather's index, as it is of the
+    scatters' above.  Written ``k_cache[layer][page_tables]`` XLA:TPU copies
+    the whole layer out of the cache (155 MB at 4,725 pages of 1,024 lanes)
+    before it gathers from the copy, per layer, per decode column."""
+    return k_cache[layer, page_tables], v_cache[layer, page_tables]
+
+
 def gather_seq_kv(
-    k_pages: jnp.ndarray,  # [P, ps, KD]
-    v_pages: jnp.ndarray,
+    k_cache: jnp.ndarray,  # [L, P, ps, K*D]
+    v_cache: jnp.ndarray,
+    layer,  # scalar layer index
     page_table: jnp.ndarray,  # [max_pages] page ids for one sequence
     num_kv_heads: int,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Materialize one sequence's KV contiguously: [max_pages*ps, K, D]."""
-    k = k_pages[page_table]  # [max_pages, ps, KD]
-    v = v_pages[page_table]
+    k, v = gather_layer_pages(k_cache, v_cache, layer, page_table)
     mp, ps, KD = k.shape
     K = num_kv_heads
     return (
@@ -121,20 +139,6 @@ def _query_block(T: int, H: int, S: int) -> int:
     while qb > 16 and qb % 2 == 0 and qb * H * S * 4 > SCORE_BLOCK_BYTES:
         qb //= 2
     return qb
-
-
-@jax.named_scope("smg.attn.kv_read")
-def gather_layer_pages(
-    k_cache: jnp.ndarray,  # [L, P, ps, K*D]
-    v_cache: jnp.ndarray,
-    layer,  # scalar layer index
-    page_tables: jnp.ndarray,  # [B, mp]
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """One layer's pages for every lane: ``[B, mp, ps, K*D]`` each.  The
-    slice of the layer out of the cache and the gather of the pages out of
-    that slice are the decode megastep's two largest device operations (a
-    scope of their own so the trace can say so)."""
-    return k_cache[layer][page_tables], v_cache[layer][page_tables]
 
 
 @jax.named_scope("smg.attn.prefill")
@@ -227,6 +231,112 @@ def attention_prefill_batched(
     return out.reshape(G_, T, H, D).astype(q.dtype)
 
 
+def _attend_cache_and_side(
+    q: jnp.ndarray,  # [B, W, H, D] W query tokens per lane (post-rope)
+    k_cache: jnp.ndarray,  # [L, P, ps, K*D] read-only cache (fused lanes)
+    v_cache: jnp.ndarray,
+    sk: jnp.ndarray,  # [B, N, K*D] side-buffer rows (this layer)
+    sv: jnp.ndarray,
+    layer,  # scalar layer index
+    page_tables: jnp.ndarray,  # [B, mp]
+    entry_positions: jnp.ndarray,  # [B] cache token count at entry
+    q_pos: jnp.ndarray,  # [B, W] absolute position of each query
+    side_visible: jnp.ndarray,  # [W, N] bool: side row n is a key of query w
+    scale: float,
+    softcap: float | None,
+    window: jnp.ndarray | None,
+    lanes_sharded: bool,
+) -> jnp.ndarray:
+    """Attention of each lane's queries over its cache pages (slots below
+    ``entry``) and its side rows, joined in ONE softmax (one maximum, one
+    denominator).  The cache part and the side part are scored by products
+    of their own, so nothing is concatenated onto the gathered pages, and
+    between the page gather and the matmuls there is no other copy of K or V:
+
+    - lane axis whole on the device (``lanes_sharded`` false): the gathered
+      pages stay ``[B, mp*ps, K*D]`` and the query is the block-diagonal
+      ``[.., H, K*D]`` the Pallas kernel builds (head ``h`` on the lanes of
+      its KV head, zeros elsewhere).  ``K`` times the multiply-adds of the
+      per-head product on an operand read once; the zeros add exactly
+      nothing.  The product with V is ``[.., H, K*D]`` and each head keeps
+      its own ``D`` lanes.
+    - lane axis sharded over a mesh axis (tp): per-head products on
+      ``[B, S, K, D]`` views.  A contraction over the sharded lanes of a
+      block-diagonal query would have GSPMD all-reduce the scores; this form
+      contracts ``D`` inside each shard's own heads and needs no collective.
+
+    Cache dtype through both products, float32 accumulation: converting the
+    gather to float32 doubles its HBM traffic, and decode is bandwidth-bound.
+    """
+    B, W, H, D = q.shape
+    ps, KD = k_cache.shape[2:]
+    K = KD // D
+    G = H // K
+    N = sk.shape[1]
+    cd = k_cache.dtype
+    kl, vl = gather_layer_pages(k_cache, v_cache, layer, page_tables)
+    S = page_tables.shape[1] * ps
+    kl = kl.reshape(B, S, KD)
+    vl = vl.reshape(B, S, KD)
+    sk = sk.astype(cd)
+    sv = sv.astype(cd)
+    f32 = jnp.float32
+
+    if lanes_sharded:
+        qh = q.astype(cd).reshape(B, W, K, G, D)
+
+        def score(keys):  # [B, n, KD] -> [B, W, H, n]
+            n = keys.shape[1]
+            return jnp.einsum("bwkgd,bskd->bwkgs", qh, keys.reshape(B, n, K, D),
+                              preferred_element_type=f32).reshape(B, W, H, n)
+
+        def weigh(p, vals):  # [B, W, H, n], [B, n, KD] -> [B, W, H, D]
+            n = vals.shape[1]
+            return jnp.einsum("bwkgs,bskd->bwkgd", p.reshape(B, W, K, G, n),
+                              vals.reshape(B, n, K, D),
+                              preferred_element_type=f32).reshape(B, W, H, D)
+    else:
+        own = jnp.arange(H)[:, None] // G == jnp.arange(K)[None, :]  # [H, K]
+        q_bd = jnp.where(own[:, :, None], q.astype(cd)[:, :, :, None, :], 0)
+        q_bd = q_bd.reshape(B, W, H, KD)
+
+        def score(keys):
+            return jnp.einsum("bwhl,bsl->bwhs", q_bd, keys,
+                              preferred_element_type=f32)
+
+        def weigh(p, vals):
+            out = jnp.einsum("bwhs,bsl->bwhl", p, vals,
+                             preferred_element_type=f32).reshape(B, W, H, K, D)
+            return jnp.where(own[:, :, None], out, 0).sum(axis=3)
+
+    # masks broadcast against [B, W, keys]
+    j = jnp.arange(S)
+    cache_mask = j < entry_positions[:, None, None]
+    side_mask = side_visible[None]
+    if window is not None:
+        # absolute key positions: the slot index in the cache, entry + row
+        # in the side buffer
+        lo = (q_pos - window)[:, :, None]
+        side_pos = entry_positions[:, None, None] + jnp.arange(N)
+        cache_mask = cache_mask & ((window <= 0) | (j > lo))
+        side_mask = side_mask & ((window <= 0) | (side_pos > lo))
+
+    def masked_scores(keys, mask):
+        s = score(keys) * scale
+        if softcap:
+            s = softcap * jnp.tanh(s / softcap)
+        return jnp.where(mask[:, :, None, :], s, NEG_INF)
+
+    sc = masked_scores(kl, cache_mask)  # [B, W, H, S]
+    ss = masked_scores(sk, side_mask)  # [B, W, H, N]
+    m = jnp.maximum(sc.max(axis=-1), ss.max(axis=-1))[..., None]
+    ec = jnp.exp(sc - m)
+    es = jnp.exp(ss - m)
+    denom = (ec.sum(axis=-1) + es.sum(axis=-1))[..., None]
+    out = weigh((ec / denom).astype(cd), vl) + weigh((es / denom).astype(cd), sv)
+    return out.astype(q.dtype)
+
+
 @jax.named_scope("smg.attn.decode")
 def attention_decode_cached(
     q: jnp.ndarray,  # [B, H, D]
@@ -241,53 +351,20 @@ def attention_decode_cached(
     scale: float,
     softcap: float | None = None,
     window: jnp.ndarray | None = None,  # scalar sliding window (<=0 = global)
+    lanes_sharded: bool = False,  # the cache's lane axis is split over a mesh
 ) -> jnp.ndarray:
     """XLA fallback for the horizon-decode attention: cache pages (tokens <
     entry) plus the first n_extra side-buffer rows, one joint softmax.
     Mirrors ``smg_tpu/ops/pallas/decode_attention.py``."""
-    B, H, D = q.shape
-    L, P, ps, KD = k_cache.shape
-    K = KD // D
     N = hk.shape[1]
-    G = H // K
-    # Stay in the cache dtype through the matmuls (f32 ACCUMULATION via
-    # preferred_element_type): converting the gather to f32 doubles its HBM
-    # write traffic, and decode is bandwidth-bound.
-    cd = k_cache.dtype
-    kl, vl = gather_layer_pages(k_cache, v_cache, layer, page_tables)
-    mp = kl.shape[1]
-    S = mp * ps
-    kl = kl.reshape(B, S, K, D)
-    vl = vl.reshape(B, S, K, D)
-    k_all = jnp.concatenate([kl, hk.reshape(B, N, K, D).astype(cd)], axis=1)
-    v_all = jnp.concatenate([vl, hv.reshape(B, N, K, D).astype(cd)], axis=1)
-    qf = q.astype(cd).reshape(B, K, G, D)
-    scores = jnp.einsum(
-        "bkgd,bskd->bkgs", qf, k_all, preferred_element_type=jnp.float32
-    ) * scale
-    if softcap:
-        scores = softcap * jnp.tanh(scores / softcap)
-    j = jnp.arange(S + N)
-    mask = jnp.where(
-        j[None, :] < S,
-        j[None, :] < entry_positions[:, None],
-        (j[None, :] - S) < n_extra,
-    )
-    if window is not None:
-        # absolute key positions: cache slot index below S, side-buffer row
-        # entry+(j-S) above; the query sits at entry + n_extra - 1
-        key_pos = jnp.where(
-            j[None, :] < S, j[None, :], entry_positions[:, None] + (j[None, :] - S)
-        )
-        q_pos = entry_positions[:, None] + n_extra - 1
-        mask = mask & ((window <= 0) | (key_pos > q_pos - window))
-    scores = jnp.where(mask[:, None, None, :], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum(
-        "bkgs,bskd->bkgd", probs.astype(cd), v_all,
-        preferred_element_type=jnp.float32,
-    )
-    return out.reshape(B, H, D).astype(q.dtype)
+    # the query sits at entry + n_extra - 1
+    q_pos = (entry_positions + n_extra - 1)[:, None]
+    side_visible = (jnp.arange(N) < n_extra)[None, :]
+    return _attend_cache_and_side(
+        q[:, None], k_cache, v_cache, hk, hv, layer, page_tables,
+        entry_positions, q_pos, side_visible, scale, softcap, window,
+        lanes_sharded,
+    )[:, 0]
 
 
 @jax.named_scope("smg.attn.decode")
@@ -303,6 +380,7 @@ def attention_verify_block(
     scale: float,
     softcap: float | None = None,
     window: jnp.ndarray | None = None,  # scalar sliding window (<=0 = global)
+    lanes_sharded: bool = False,  # the cache's lane axis is split over a mesh
 ) -> jnp.ndarray:
     """Attention for a speculative verify block: W query tokens per lane
     (the last committed token plus the drafted columns) against the lane's
@@ -312,48 +390,13 @@ def attention_verify_block(
     acceptance decision, which is how rejected drafts' KV ends up on the
     garbage page instead of poisoning real slots.  The multi-query cousin of
     ``attention_decode_cached`` (same gather, same joint softmax)."""
-    B, W, H, D = q.shape
-    L, P, ps, KD = k_cache.shape
-    K = KD // D
-    G = H // K
-    cd = k_cache.dtype  # cache-dtype matmuls, f32 accumulation (HBM-bound)
-    kl, vl = gather_layer_pages(k_cache, v_cache, layer, page_tables)
-    mp = kl.shape[1]
-    S = mp * ps
-    kl = kl.reshape(B, S, K, D)
-    vl = vl.reshape(B, S, K, D)
-    k_all = jnp.concatenate([kl, bk.reshape(B, W, K, D).astype(cd)], axis=1)
-    v_all = jnp.concatenate([vl, bv.reshape(B, W, K, D).astype(cd)], axis=1)
-    qf = q.astype(cd).reshape(B, W, K, G, D)
-    scores = jnp.einsum(
-        "bwkgd,bskd->bwkgs", qf, k_all, preferred_element_type=jnp.float32
-    ) * scale
-    if softcap:
-        scores = softcap * jnp.tanh(scores / softcap)
-    j = jnp.arange(S + W)
-    w_idx = jnp.arange(W)
-    # cache keys: position j valid below the lane's entry; block keys: side
-    # row i visible to query column w iff i <= w (causal within the block)
-    mask = jnp.where(
-        j[None, None, :] < S,
-        j[None, None, :] < entry_positions[:, None, None],
-        (j[None, None, :] - S) <= w_idx[None, :, None],
-    )  # [B, W, S+W]
-    if window is not None:
-        key_pos = jnp.where(
-            j[None, None, :] < S,
-            j[None, None, :],
-            entry_positions[:, None, None] + (j[None, None, :] - S),
-        )
-        q_pos = entry_positions[:, None, None] + w_idx[None, :, None]
-        mask = mask & ((window <= 0) | (key_pos > q_pos - window))
-    scores = jnp.where(mask[:, :, None, None, :], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum(
-        "bwkgs,bskd->bwkgd", probs.astype(cd), v_all,
-        preferred_element_type=jnp.float32,
+    w = jnp.arange(q.shape[1])
+    # side row i is visible to query column w iff i <= w (causal in the block)
+    return _attend_cache_and_side(
+        q, k_cache, v_cache, bk, bv, layer, page_tables, entry_positions,
+        entry_positions[:, None] + w[None, :], w[None, :] <= w[:, None],
+        scale, softcap, window, lanes_sharded,
     )
-    return out.reshape(B, W, H, D).astype(q.dtype)
 
 
 @jax.named_scope("smg.attn.decode")

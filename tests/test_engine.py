@@ -417,6 +417,7 @@ def test_warmup_runs_largest_programs_and_leaves_no_trace():
     # launches were counted under the implementation the rule chose
     assert eng.loads()["attention"] == {
         "mode": "xla",
+        "xla_decode_products": "fused_lanes",
         "launches": {"xla": eng.runner.attn_launches["xla"],
                      "pallas_prefill": 0, "pallas_decode": 0},
     }

@@ -47,7 +47,7 @@ def _xla_reference(q, k_cache, v_cache, layer, page_table, prefix_len, t_real, K
                    softcap=None, window=None):
     T, H, D = q.shape
     scale = 1.0 / np.sqrt(D)
-    k_ctx, v_ctx = gather_seq_kv(k_cache[layer], v_cache[layer], page_table, K)
+    k_ctx, v_ctx = gather_seq_kv(k_cache, v_cache, layer, page_table, K)
     pos = prefix_len + jnp.arange(T)
     return attention_prefill(q, k_ctx, v_ctx, pos, jnp.int32(prefix_len + t_real),
                              scale, softcap=softcap, window=window)

@@ -9,8 +9,11 @@ compile-time memory only; what the kernels compute on the chip is
 ``test_pallas_*.py``'s.
 """
 
+import collections
 import dataclasses
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +25,12 @@ from smg_tpu.engine.kv_cache import KvCacheSpec
 from smg_tpu.engine.runner import PREFILL_KERNEL_MAX_T, ModelRunner
 from smg_tpu.models.config import llama32_1b_config
 from smg_tpu.models.registry import get_model
-from smg_tpu.ops.attention import SCORE_BLOCK_BYTES, attention_prefill, scatter_kv_rows
+from smg_tpu.ops.attention import (
+    SCORE_BLOCK_BYTES,
+    attention_decode_cached,
+    attention_prefill,
+    scatter_kv_rows,
+)
 from smg_tpu.ops.pallas.decode_attention import paged_attention_decode_cached
 from smg_tpu.ops.pallas.prefill_attention import paged_attention_prefill
 from smg_tpu.parallel.mesh import build_mesh
@@ -112,6 +120,37 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
+_HLO_OP = re.compile(r"= \w+\[([\d,]*)\]\S* (\w[\w-]*)\((.*)")
+
+
+def _relayouts(hlo: str, min_elements: int) -> list[str]:
+    """Instructions of the compiled text that move an array of at least
+    ``min_elements`` into another layout: every ``copy``, and every
+    ``transpose`` whose permutation is not the identity."""
+    found = []
+    for line in hlo.splitlines():
+        m = _HLO_OP.search(line)
+        if not m or m.group(2) not in ("copy", "transpose"):
+            continue
+        dims = [int(d) for d in m.group(1).split(",") if d]
+        if math.prod(dims) < min_elements:
+            continue
+        perm = re.search(r"dimensions=\{([\d,]*)\}", m.group(3))
+        if m.group(2) == "transpose" and perm and [
+                int(d) for d in perm.group(1).split(",")] == list(range(len(dims))):
+            continue
+        found.append(line.strip()[:160])
+    return found
+
+
+def _collectives(hlo: str) -> collections.Counter:
+    """Collective operations in the compiled text (an async pair counts
+    once, at its ``-start``)."""
+    return collections.Counter(re.findall(
+        r" (all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
+        r"(?:-start)?\(", hlo))
+
+
 class TestCompilesForV5e:
     """Each side of the rule, at the llama3.2-1b widths and serving defaults
     (page 16, 8192-token tables, 4096-token chunks, batch 64)."""
@@ -170,9 +209,45 @@ class TestCompilesForV5e:
         assert mem.alias_size_in_bytes == 2 * L * P * PS * KD * 2  # both buffers
         assert mem.temp_size_in_bytes < 2**20
 
+    def test_xla_decode_reads_the_cache_where_it_lies(self, v5e):
+        """XLA decode attention inside a scan over layers, at the shapes of
+        the ``qwen3-1.7b.eval`` cell (28 layers over an auto-sized cache of
+        4,725 pages, 16 lanes behind 256-page tables, 16/8 heads of 128):
+        between the cache and the matmuls there is the page gather and no
+        other copy of K or V.  With ``k_cache[layer][page_tables]``, per-head
+        products and one concatenated softmax this program sliced a whole
+        layer out of the cache (155 MB) and relaid the gathered pages out
+        twice (134 MB each), for K and for V, per layer: 536 MiB of
+        temporaries, 62 % of the cell's device time."""
+        s = self._sds(v5e)
+        L, P, B, mp, N, H, K, D = 28, 4725, 16, 256, 8, 16, 8, 128
+        kd = K * D
+
+        def columns(q, kc, vc, hk_all, hv_all, n_extra, tables, entry):
+            def layer_body(h, xs):
+                l, hk, hv = xs
+                return h + attention_decode_cached(
+                    q + h, kc, vc, hk, hv, n_extra, l, tables, entry, 0.088), None
+
+            return jax.lax.scan(layer_body, jnp.zeros_like(q),
+                                (jnp.arange(L), hk_all, hv_all))[0]
+
+        compiled = _compile(
+            columns, s((B, H, D)), s((L, P, PS, kd)), s((L, P, PS, kd)),
+            s((L, B, N, kd)), s((L, B, N, kd)), s((), jnp.int32),
+            s((B, mp), jnp.int32), s((B,), jnp.int32),
+        )
+        hlo = compiled.as_text()
+        assert f"[{P},{PS},{kd}]" not in hlo  # no layer sliced out of the cache
+        assert _relayouts(hlo, B * mp * PS * kd) == []
+        assert compiled.memory_analysis().temp_size_in_bytes < 192 * 2**20
+
     def test_decode_step_under_tp4(self, v5e):
         """A tp=4 mesh takes the XLA side (the rule's mesh answer): one
-        decode step, depth cut to two layers, must partition and compile."""
+        decode step, depth cut to two layers, must partition and compile,
+        with the collectives the matmuls' row-parallel halves need (two in
+        the layer body, one for the logits) and none for attention: the
+        cache's lane axis is sharded, so the products run per head."""
         cfg = dataclasses.replace(CFG, num_layers=2)
         module = get_model(cfg.arch)
         mesh = build_mesh(ParallelConfig(tp=4), devices=v5e)
@@ -194,10 +269,12 @@ class TestCompilesForV5e:
 
         cache = s((L, P, PS, KD), axes=("layers", "pages", None, "kv_lanes"))
         side = s((L, B, N, KD), axes=("layers", None, None, "kv_lanes"))
-        _compile(
+        assert cache.sharding.spec[3] == "tp"
+        compiled = _compile(
             lambda p, inv, *a: module.forward_decode_horizon(
-                p, cfg, inv, *a, attn_impl=impl),
+                p, cfg, inv, *a, attn_impl=impl, kv_lanes_sharded=True),
             params, s((cfg.head_dim // 2,), jnp.float32), s((B,), jnp.int32),
             s((B,), jnp.int32), s((B,), jnp.int32), s((), jnp.int32), cache, cache,
             s((B, mp), jnp.int32), side, side,
         )
+        assert _collectives(compiled.as_text()) == {"all-reduce": 3}
