@@ -5,13 +5,18 @@ one of them is a file of its own under ``benchmark/``:
     configs/<config>.json          the model's config.json plus serve flags
     traffic/<traffic>.json         parameters for one of generators/<kind>.py
     layer_metrics/<metric>.py      META and read(ctx) for one per-layer metric
+    architectures/<name>.py        reference, serving drive and costs of one
+                                   architecture; a configuration names it with
+                                   its ``architecture`` key (``llama`` without)
 
-so a later PR adds a cell, a configuration, a traffic mix or a metric as new
-files and a new entry, and edits nothing that exists.  Standard library only.
+so a later PR adds a cell, a configuration, a traffic mix, a metric or an
+architecture as new files and a new entry, and edits nothing that exists.
+Standard library only.
 """
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
@@ -39,9 +44,10 @@ def _load_json(path: str, what: str) -> dict:
         return json.load(f)
 
 
-def _module(path: str, name: str):
+def _module(path: str, kind: str, name: str):
     if not os.path.isfile(path):
         raise CatalogError(f"no module {path}")
+    name = f"benchmark_{kind}_" + name.replace(".", "_").replace("-", "_")
     spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
@@ -74,8 +80,14 @@ class Cell:
     @property
     def hf_config(self) -> dict:
         """The model's config.json as the program's ``from_hf_config`` reads it."""
-        own = {"assumed", "deployment", "chips", "serve_args", "rehearsal", "status"}
+        own = {"assumed", "deployment", "chips", "serve_args", "rehearsal", "status",
+               "architecture", "reduced", "published"}
         return {k: v for k, v in self.config.items() if k not in own}
+
+    @functools.cached_property
+    def architecture(self):
+        """The module ``architectures/<name>.py`` this configuration names."""
+        return architecture(self.config.get("architecture", "llama"), self.root)
 
     @property
     def serve_args(self) -> list[str]:
@@ -100,6 +112,16 @@ def load_generator(kind: str, root: str = ROOT):
     return importlib.import_module(f"generators.{kind}")
 
 
+def architecture(name: str, root: str = ROOT):
+    """``benchmark/architectures/<name>.py`` of the checkout at ``root``: the
+    plain reference, the drive of the serving forward and the costs of one
+    architecture (README, "An architecture")."""
+    path = os.path.join(root, "benchmark", "architectures", name + ".py")
+    if not os.path.isfile(path):
+        raise CatalogError(f"no architecture {name!r}: no file {path}")
+    return _module(path, "architecture", name)
+
+
 def metrics_for(bench: dict, cell: str, group: str) -> list[dict]:
     """The ``end_to_end`` or ``per_layer`` metrics that ``cell`` reports."""
     return [m for m in bench[group] if cell in m.get("workloads", [cell])]
@@ -115,7 +137,7 @@ def layer_metric_reader(name: str, root: str = ROOT):
 
     if os.path.dirname(path) not in sys.path:
         sys.path.insert(0, os.path.dirname(path))  # the readers share _common.py
-    return _module(path, "benchmark_layer_metric_" + name.replace(".", "_").replace("-", "_"))
+    return _module(path, "layer_metric", name)
 
 
 def listing(root: str = ROOT) -> dict:
@@ -134,6 +156,7 @@ def listing(root: str = ROOT) -> dict:
         "config_files": stems("configs", ".json"),
         "traffic": stems("traffic", ".json"),
         "generators": [g for g in stems("generators", ".py") if g != "common"],
+        "architectures": stems("architectures", ".py"),
         "layer_metrics": stems("layer_metrics", ".py"),
         "end_to_end": [m["name"] for m in bench["end_to_end"]],
         "per_layer": [m["name"] for m in bench["per_layer"]],

@@ -8,7 +8,9 @@ A traffic mix is a data file ``benchmark/traffic/<mix>.json`` whose
 and returns *chains*: ``{"start": s, "requests": [{"gap": g, "prefix": [seed,
 n] | None, "body": [seed, n], "max_tokens": m}, ...]}``.  The first request of
 a chain is due ``start`` seconds into the window, each later one ``gap``
-seconds after the one before it ended.  One executor (``loadgen.py``) runs
+seconds after the one before it ended.  A chain with ``"starts_over": true``
+(a caller of a closed loop) goes through its requests again with other words
+when it has sent the last (``common.again``).  One executor (``loadgen.py``) runs
 every kind: an open loop is chains of one request, a closed loop is one long
 chain per client with no gaps, a session is a chain with think times.
 

@@ -89,12 +89,30 @@ def rngs(seed: int) -> tuple[random.Random, random.Random]:
     return random.Random(ARRANGEMENT), random.Random((seed << 1) ^ 0x9E3779B9)
 
 
+#: word seeds are drawn below this, so a lap's shifted seeds meet no dealt one
+WORD_SEEDS = 1 << 30
+
+
+def again(requests: list, i: int, lap: int) -> dict:
+    """Request ``i`` of a chain on its lap ``lap`` >= 1.  A chain that has sent
+    its last dealt request starts over (``loadgen.run_chain``): the same sizes
+    and gaps in the same order, with other words, and other documents too, so
+    that the repeat finds nothing cached.  A lap's first request waits as the
+    deal's last one did (no time in a closed loop, a think time in a session).
+    The deal itself is left alone: another pool size deals another arrangement
+    (``dealt``), and that is other work (``ARRANGEMENT``)."""
+    spec = requests[i]
+    shift = lambda part: part and [part[0] + lap * WORD_SEEDS, part[1]]
+    return {**spec, "gap": spec["gap"] if i else requests[-1]["gap"],
+            "prefix": shift(spec["prefix"]), "body": shift(spec["body"])}
+
+
 def request(rng: random.Random, body_tokens: int, max_tokens: int,
             prefix: list | None = None, gap: float = 0.0) -> dict:
     """One request.  ``body_tokens`` counts the template's two tokens, so the
     server's ``prompt_tokens`` is ``prefix tokens + body_tokens``."""
     return {
         "gap": gap, "prefix": prefix,
-        "body": [rng.randrange(1 << 30), max(int(body_tokens) - TEMPLATE_TOKENS, 1)],
+        "body": [rng.randrange(WORD_SEEDS), max(int(body_tokens) - TEMPLATE_TOKENS, 1)],
         "max_tokens": int(max_tokens),
     }

@@ -8,13 +8,14 @@ think times are dealt in rounds (``common.dealt``).  Client ``c`` starts
 
 from __future__ import annotations
 
-from .common import dealt, request, rngs
+from .common import WORD_SEEDS, dealt, request, rngs
 
 
 def chains(params: dict, seed: int, seconds: float) -> list[dict]:
     rng, words_rng = rngs(seed)
     clients, turns = int(params["clients"]), int(params["turns"])
-    per = int(params["documents_per_client"])  # enough to outlast the window
+    # a reader who has been through them all starts over with other documents
+    per = int(params["documents_per_client"])
     docs = dealt(params["document_tokens"], clients, per, rng, integer=True)
     questions = dealt(params["question_tokens"], clients, per * turns, rng, integer=True)
     outputs = dealt(params["output_tokens"], clients, per * turns, rng, integer=True)
@@ -24,12 +25,12 @@ def chains(params: dict, seed: int, seconds: float) -> list[dict]:
     for c in range(clients):
         reqs = []
         for d in range(per):
-            prefix = [words_rng.randrange(1 << 30), docs[d][c]]
+            prefix = [words_rng.randrange(WORD_SEEDS), docs[d][c]]
             for k in range(turns):
                 j = d * turns + k
                 # the first question of the first document goes at once; every
                 # other request waits its think time after the answer before it
                 reqs.append(request(words_rng, questions[j][c], outputs[j][c], prefix=prefix,
                                     gap=thinks[j][c] if reqs else 0.0))
-        out.append({"start": c * ramp / clients, "requests": reqs})
+        out.append({"start": c * ramp / clients, "starts_over": True, "requests": reqs})
     return out

@@ -22,7 +22,7 @@ def decode_records(ctx, window):
 
 
 def bench_module(name):
-    """A module of benchmark/ (trace_reduce, costs, peaks)."""
+    """A module of benchmark/ (trace_reduce, peaks, client_reduce)."""
     import importlib
     import os
     import sys

@@ -8,7 +8,7 @@ holds ``prompt + output so far`` tokens meanwhile."""
 from _common import bench_module, decode_records, peak
 
 META = {"layer": "kernels", "unit": "%", "moves": "output_tok_per_s",
-        "source": "device_trace: jit_multi* device time; bytes from shapes (costs.py)"}
+        "source": "device_trace: jit_multi* device time; bytes from shapes (architectures/)"}
 
 
 def live_tokens(ctx, window) -> float:
@@ -35,7 +35,7 @@ def read(ctx):
     columns = sum(s["horizon"] for s in decode_records(ctx, ctx["trace_window"]))
     if not fam or not columns:
         return None
-    least = bench_module("costs").decode_min_seconds(
+    least = ctx["costs"].decode_min_seconds(
         ctx["hf"], columns, columns * live_tokens(ctx, ctx["trace_window"]),
         ctx["chips"], peak(ctx), ctx["kv_dtype_bytes"])
     return 100.0 * least / fam["seconds"]

@@ -8,7 +8,7 @@ the flight recorder's timelines."""
 from _common import bench_module, in_window, peak
 
 META = {"layer": "kernels", "unit": "%", "moves": "output_tok_per_s",
-        "source": "device_trace: jit_step* device time; FLOPs from shapes (costs.py)"}
+        "source": "device_trace: jit_step* device time; FLOPs from shapes (architectures/)"}
 
 
 def read(ctx):
@@ -23,6 +23,6 @@ def read(ctx):
             pairs += new * (tl["cached_tokens"] + (new + 1) / 2.0)
     if not fam or not new_tokens:
         return None
-    least = bench_module("costs").prefill_min_seconds(
+    least = ctx["costs"].prefill_min_seconds(
         ctx["hf"], new_tokens, pairs, ctx["chips"], peak(ctx))
     return 100.0 * least / fam["seconds"]

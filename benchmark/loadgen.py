@@ -12,6 +12,9 @@ streaming ``/v1/chat/completions`` call with ``ignore_eos`` and an exact
 server process shares) when each request was due, was sent, brought its first
 and its last content delta, and ended.  A chain's first request is due at
 ``t0 + start``; each later one ``gap`` seconds after the one before it ended.
+A chain marked ``starts_over`` (a caller of a closed loop) that has sent its
+last request goes through them again with other words
+(``generators.common.again``), so no caller falls silent inside a window.
 Requests due after the window's end are not sent.  After the window the
 requests in flight are given ``drain_s`` seconds to end.
 """
@@ -19,6 +22,7 @@ requests in flight are given ``drain_s`` seconds to end.
 from __future__ import annotations
 
 import asyncio
+import itertools
 import json
 import os
 import sys
@@ -27,7 +31,7 @@ import time
 import aiohttp
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from generators.common import words  # noqa: E402
+from generators.common import again, words  # noqa: E402
 
 
 async def one_request(session, plan: dict, rec: dict, spec: dict) -> None:
@@ -92,7 +96,12 @@ async def run_chain(session, plan: dict, t0: float, ci: int, chain: dict,
     end = t0 + plan["seconds"]
     due = t0 + chain["start"]
     prev_done = due
-    for k, spec in enumerate(chain["requests"]):
+    dealt = chain["requests"]
+    for k in itertools.count():
+        lap, i = divmod(k, len(dealt))
+        if lap and not chain.get("starts_over"):
+            return
+        spec = again(dealt, i, lap) if lap else dealt[i]
         if k:
             due = prev_done + spec["gap"]
         if due >= end:
@@ -101,7 +110,7 @@ async def run_chain(session, plan: dict, t0: float, ci: int, chain: dict,
         if delay > 0:
             await asyncio.sleep(delay)
         rec = {
-            "id": f"{plan['tag']}-{ci}-{k}", "chain": ci, "turn": k, "due": due,
+            "id": f"{plan['tag']}-{ci}-{k}", "chain": ci, "turn": k, "lap": lap, "due": due,
             "sent": None, "first": None, "last": None, "done": None, "chunks": 0,
             "want_prompt_tokens": (spec["prefix"][1] if spec["prefix"] else 0)
             + spec["body"][1] + 2,
@@ -113,6 +122,8 @@ async def run_chain(session, plan: dict, t0: float, ci: int, chain: dict,
         records.append(rec)
         await one_request(session, plan, rec, spec)
         prev_done = rec["done"]
+        if lap and rec["error"]:
+            return  # a server that refuses everything is not asked without end
 
 
 async def amain(plan: dict) -> dict:

@@ -113,7 +113,7 @@ def sets(a) -> int:
                  "spread": spread(vals), "range": (max(vals) - min(vals)) / statistics.median(vals),
                  "values": vals})
     if a.trace:
-        r = run_once(a.workload, seeds[0], a.seconds, 1, a.out, [])
+        r = run_once(a.workload, seeds[0], a.seconds, a.trace, a.out, [])
         if r is not None:
             print("measure: traced " + json.dumps(r["line"]), flush=True)
     for name, per_set in table.items():
@@ -140,7 +140,8 @@ def main() -> int:
     sw.add_argument("--seed", type=int, default=2024)
     st.add_argument("--seeds", required=True)
     st.add_argument("--sets", type=int, default=2)
-    st.add_argument("--trace", type=int, default=0, help="1: one traced run at the end")
+    st.add_argument("--trace", type=int, default=0, choices=(0, 1, 2),
+                    help="1 or 2: one more run at the end, traced so")
     a = ap.parse_args()
     os.makedirs(a.out, exist_ok=True)
     if a.rehearsal:

@@ -98,6 +98,9 @@ def end_to_end(result: dict) -> dict:
             "output_tokens": sum(r["output_tokens"] for r in reqs),
             "backlog_mid": backlog(reqs, t0 + result["seconds"] / 2),
             "backlog_end": backlog(reqs, t1),
+            # a chain that has sent its whole pool starts over with other words
+            # (loadgen.run_chain): how many did, so a ceiling shows before it binds
+            "chains_started_over": len({r["chain"] for r in reqs if r.get("lap", 0) > 0}),
             "live_kv_tokens_peak": live_tokens_peak(reqs),
             "errors": sorted({r["error"] or f"finish={r['finish']}" for r in reqs
                               if not request_ok(r)})[:5],
@@ -504,7 +507,7 @@ async def serve_and_measure(args, bench: dict, cell: catalog.Cell, out_dir: str)
 
         t = time.monotonic()
         check = await asyncio.to_thread(
-            reference.check_engine, engine, hf, args.seed, args.rehearsal)
+            reference.check_engine, engine, cell, args.seed, args.rehearsal)
         marks["correctness_check"] = time.monotonic() - t
         log(f"logits vs float32 reference: {json.dumps(check)}")
 
@@ -584,7 +587,8 @@ async def serve_and_measure(args, bench: dict, cell: catalog.Cell, out_dir: str)
     }
     e2e["metrics"]["setup_s"] = setup_s
     ctx = {
-        "cell": cell.name, "hf": hf, "chips": cell.chips, "device": device,
+        "cell": cell.name, "hf": hf, "costs": cell.architecture, "chips": cell.chips,
+        "device": device,
         "rehearsal": args.rehearsal, "requests": result["requests"],
         # a traced run's whole-window readers stop where the profiler started
         "window": (result["t0"], trace_win[0] if trace_win

@@ -6,7 +6,6 @@ added as new files only.  CPU, seconds:  python3 -m pytest benchmark/tests -q
 import json
 import os
 import re
-import shutil
 import statistics
 import subprocess
 import sys
@@ -14,11 +13,11 @@ import sys
 import pytest
 
 import catalog
-import costs
 import trace_reduce
-from conftest import BENCH, ROOT
+from conftest import BENCH, checkout, untouched
 
 DATA = os.path.join(BENCH, "tests", "data")
+costs = catalog.architecture("llama")  # both configurations below are of that file
 
 
 def lengths(chains, key):
@@ -95,6 +94,82 @@ def test_closed_loop_has_one_long_chain_per_client():
     assert len(chains) == params["clients"]
     assert all(c["start"] == 0.0 and len(c["requests"]) == params["pool_per_client"]
                and all(r["gap"] == 0.0 for r in c["requests"]) for c in chains)
+
+
+#: sha256 of every chain's start and requests at seed 7, as PR 27's generators
+#: dealt them: what a chain sends before it starts over may never move
+DEALT = {"eval": "b381173d4568bb58e71fb1375eb26449f48f2f353d1522a3cff39eee1ae11153",
+         "doc-qa": "0b4a6db479700a7e835b544cabbf0365aea5d2b231e8a08b71b68242cd6b019f",
+         "batch": "4abfb704042e655a74306131166268704932a95dc0e34ca505371ebc0dcfb44f"}
+
+
+@pytest.mark.parametrize("mix", sorted(DEALT))
+def test_the_deal_is_what_it_was_and_a_lap_repeats_it_with_other_words(mix):
+    import hashlib
+
+    from generators.common import again
+
+    params = json.load(open(os.path.join(BENCH, "traffic", mix + ".json")))
+    chains = catalog.load_generator(params["generator"]).chains(params, 7, 51.0)
+    dealt = json.dumps([[c["start"], c["requests"]] for c in chains], sort_keys=True)
+    assert hashlib.sha256(dealt.encode()).hexdigest() == DEALT[mix]
+    assert all(c["starts_over"] for c in chains)
+    sizes = lambda r: (r["prefix"] and r["prefix"][1], r["body"][1], r["max_tokens"])
+    seen = {tuple(p) for c in chains for r in c["requests"] for p in (r["prefix"], r["body"]) if p}
+    for c in chains:
+        reqs = c["requests"]
+        for lap in (1, 2):
+            rep = [again(reqs, i, lap) for i in range(len(reqs))]
+            assert [sizes(r) for r in rep] == [sizes(r) for r in reqs]
+            assert [r["gap"] for r in rep[1:]] == [r["gap"] for r in reqs[1:]]
+            assert rep[0]["gap"] == reqs[-1]["gap"]  # a reader thinks before the next document
+            for r, old in zip(rep, reqs):  # other words, other documents: nothing cached
+                assert tuple(r["body"]) not in seen and r["body"] != old["body"]
+                assert r["prefix"] is None or tuple(r["prefix"]) not in seen
+            if reqs[0]["prefix"]:  # the turns of one document still share it
+                assert rep[0]["prefix"] == rep[1]["prefix"]
+        assert reqs == c["requests"]  # the deal itself is untouched
+
+
+def test_a_chain_that_passes_its_pool_goes_on_and_an_open_one_ends(monkeypatch):
+    import asyncio
+    import time
+
+    import loadgen
+    from generators.common import request
+
+    async def served(session, plan, rec, spec):
+        rec["sent"] = time.monotonic()
+        await asyncio.sleep(0.01)
+        rec["done"] = time.monotonic()
+        rec["spec"] = spec
+
+    monkeypatch.setattr(loadgen, "one_request", served)
+    import random
+    rng = random.Random(1)
+    reqs = [request(rng, 40 + k, 5 + k, gap=0.0) for k in range(3)]
+
+    def run(chain):
+        records = []
+        plan = {"seconds": 0.25, "tag": "t"}
+        asyncio.run(loadgen.run_chain(None, plan, time.monotonic(), 0, chain, records))
+        return records
+
+    closed = run({"start": 0.0, "starts_over": True, "requests": reqs})
+    assert len(closed) >= 9 and max(r["lap"] for r in closed) >= 2
+    assert [r["turn"] for r in closed] == list(range(len(closed)))
+    for r in closed:  # every lap sends the dealt sizes in the dealt order
+        assert r["spec"]["max_tokens"] == reqs[r["turn"] % 3]["max_tokens"]
+        assert (r["spec"]["body"] == reqs[r["turn"] % 3]["body"]) == (r["lap"] == 0)
+    assert closed[-1]["due"] < closed[0]["due"] + 0.25  # and stops at the window's end
+    opened = run({"start": 0.0, "requests": reqs})
+    assert [r["lap"] for r in opened] == [0, 0, 0]
+    # a server that refuses everything is asked the pool and one request more
+    async def refused(session, plan, rec, spec):
+        rec["done"], rec["error"] = time.monotonic(), "HTTP 503"
+
+    monkeypatch.setattr(loadgen, "one_request", refused)
+    assert len(run({"start": 0.0, "starts_over": True, "requests": reqs})) == 4
 
 
 def test_words_are_exact_token_counts():
@@ -294,14 +369,7 @@ def test_benchmark_json_is_consistent_with_its_files():
 # ---- driven by data: new things are new files ----
 
 def test_a_cell_config_traffic_generator_and_metric_are_added_as_new_files(tmp_path):
-    root = str(tmp_path)
-    shutil.copytree(BENCH, os.path.join(root, "benchmark"),
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
-    before = {}
-    for d, _dirs, files in os.walk(os.path.join(root, "benchmark")):
-        for f in files:
-            before[os.path.join(d, f)] = open(os.path.join(d, f), "rb").read()
+    root, before = checkout(tmp_path)
     b = os.path.join(root, "benchmark")
     cfg = json.load(open(os.path.join(b, "configs", "qwen3-1.7b.json")))
     cfg["num_hidden_layers"] = 4
@@ -351,5 +419,4 @@ def test_a_cell_config_traffic_generator_and_metric_are_added_as_new_files(tmp_p
     assert "device.collective_exposed_share" not in out["new"]
     assert "scheduler.new_metric" not in out["old"]
     assert out["read"] == 2 and out["none"] is True
-    for path, content in before.items():
-        assert open(path, "rb").read() == content, f"{path} had to be edited"
+    untouched(before)
