@@ -104,6 +104,16 @@ def validate_engine_config(cfg) -> list[ValidationIssue]:
                     "parallel.ep",
                     f"ep={par.ep} does not divide num_experts={model.num_experts}",
                 ))
+    if model is not None and getattr(model, "recurrent", False):
+        # what a model with recurrent layers cannot do yet is refused here,
+        # at start, and not left to run a wrong model
+        from smg_tpu.models.registry import get_model
+
+        limits = get_model(model.arch).SERVING_LIMITS
+        if par.world_size > 1:
+            issues.append(_err("parallel", limits["mesh"]))
+        if sched.speculative or getattr(cfg, "draft_model", None) is not None:
+            issues.append(_err("scheduler.speculative", limits["speculative"]))
     if par.sp > 1:
         bad = [b for b in sched.prefill_token_buckets if b % par.sp != 0]
         if bad:

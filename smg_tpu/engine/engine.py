@@ -71,7 +71,12 @@ class Engine:
         self.config = config
         self.tokenizer = tokenizer
         self.events = KvEventPublisher()
-        self.runner = ModelRunner(config, params=params, devices=devices)
+        runner_cls = ModelRunner
+        if getattr(config.model, "recurrent", False):
+            from smg_tpu.engine.recurrent_runner import RecurrentModelRunner
+
+            runner_cls = RecurrentModelRunner
+        self.runner = runner_cls(config, params=params, devices=devices)
         # engine-deep metric set (own registry; the gateway additionally
         # registers it into its CollectorRegistry so /metrics is one scrape)
         from smg_tpu.engine.metrics import EngineMetrics

@@ -64,8 +64,10 @@ logger = get_logger("engine.flight_recorder")
 #: from a mixed single-device/TP fleet self-describe their topology; v5:
 #: ``horizon_reason`` on the step record — why the decode launch of that
 #: step ran the K it ran — and ``submit_t`` on the timeline, stamped before
-#: the submit waits for the engine lock)
-SCHEMA_VERSION = 5
+#: the submit waits for the engine lock; v6: ``state_lanes``, the lanes of
+#: the consumed frame that held a recurrent-state slot (0 for a model whose
+#: layers are all attention))
+SCHEMA_VERSION = 6
 
 #: stable key set of one step record (schema contract, tested)
 STEP_RECORD_KEYS = frozenset({
@@ -73,7 +75,7 @@ STEP_RECORD_KEYS = frozenset({
     "prefill_tokens", "decode_tokens", "prefill_inflight_tokens",
     "free_pages", "admissions", "finishes", "overlap", "fetch_wait_s",
     "faults", "horizon", "early_exits", "wasted_decode_tokens",
-    "spec_drafted", "spec_accepted", "mesh", "horizon_reason",
+    "spec_drafted", "spec_accepted", "mesh", "horizon_reason", "state_lanes",
 })
 
 #: why a decode launch ran the horizon it ran (``Scheduler._pick_horizon``);
@@ -211,7 +213,7 @@ class FlightRecorder:
         horizon: int = 0, early_exits: int = 0,
         wasted_decode_tokens: int = 0,
         spec_drafted: int = 0, spec_accepted: int = 0,
-        mesh: int = 1, horizon_reason: str = "",
+        mesh: int = 1, horizon_reason: str = "", state_lanes: int = 0,
     ) -> int:
         """Append one step record; returns the step serial.  Called once per
         scheduler step with values already in hand — no derivation here."""
@@ -261,6 +263,8 @@ class FlightRecorder:
                 # the frame CONSUMED this step, launched one step earlier
                 # under the overlapped schedule
                 "horizon_reason": horizon_reason,
+                # lanes of the consumed frame that held a recurrent-state slot
+                "state_lanes": state_lanes,
             })
             return self.step_serial
 

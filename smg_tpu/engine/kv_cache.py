@@ -12,6 +12,7 @@ reference's worker launcher (``bindings/python/src/smg/serve.py``).
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 
 import jax
@@ -51,6 +52,59 @@ class PagePool:
 
     def reset(self) -> None:
         self._free = list(range(self.num_pages - 1, 0, -1))
+
+
+class StateSlotPool:
+    """Free-list allocator of per-sequence state slots, the recurrent model's
+    companion of ``PagePool``: a sequence of a model with recurrent layers
+    holds one slot from admission to release, beside its pages.  Slot 0 is
+    the reserved garbage slot (padded rows of a batch name it)."""
+
+    def __init__(self, num_slots: int):
+        if num_slots < 2:
+            raise ValueError("need at least 2 state slots (slot 0 is reserved)")
+        self.num_slots = num_slots
+        self._free: list[int] = list(range(num_slots - 1, 0, -1))
+
+    @property
+    def free_count(self) -> int:
+        return len(self._free)
+
+    @property
+    def in_use(self) -> int:
+        return self.num_slots - 1 - len(self._free)
+
+    def alloc(self) -> int:
+        if not self._free:
+            raise OutOfPagesError("no state slot free")
+        return self._free.pop()
+
+    def free(self, slot: int) -> None:
+        if slot == 0:
+            raise ValueError("state slot 0 is reserved and never allocated")
+        self._free.append(slot)
+
+
+@dataclass
+class StateSpec:
+    """The two state pools of a model with recurrent layers (shapes as
+    ``models/olmo_hybrid.state_shapes`` gives them, the garbage slot
+    included)."""
+
+    num_slots: int  # allocatable slots + the garbage slot
+    state_shape: tuple[int, ...]  # float32
+    conv_shape: tuple[int, ...]
+    conv_dtype: str
+
+    @property
+    def slot_bytes(self) -> int:
+        """Bytes one sequence's slot holds over all recurrent layers."""
+        n = lambda shape: math.prod(shape) // self.num_slots
+        return n(self.state_shape) * 4 + n(self.conv_shape) * jnp.dtype(self.conv_dtype).itemsize
+
+    @property
+    def total_bytes(self) -> int:
+        return self.slot_bytes * self.num_slots
 
 
 @dataclass
@@ -107,6 +161,42 @@ def plan_cache(
         budget = int(hbm_bytes_free * cache.hbm_utilization) - param_bytes
         spec.num_pages = int(max(budget // per_page_device, 16))
     return spec
+
+
+def plan_recurrent_cache(
+    model: ModelConfig,
+    cache: CacheConfig,
+    state_slots: int,
+    state_shapes,
+    hbm_limit: int | None = None,
+    hbm_in_use: int = 0,
+) -> tuple[KvCacheSpec, StateSpec]:
+    """Split what the device has left between state slots and pages, for a
+    model whose layers are not all attention.  Pages exist only for the
+    layers that hold keys and values (``model.num_cache_layers``); the
+    ``state_slots`` slots (and the garbage slot) are taken first, because a
+    sequence cannot be admitted without one, and pages get the rest.
+
+    ``hbm_limit`` and ``hbm_in_use`` are the tightest device's, read **after
+    the weights are on it**: the budget is ``hbm_utilization`` of the whole
+    device less what is in use, so the weights come off once
+    (``plan_cache`` takes them off what is free after them, a second time,
+    PERF.md 7.3a; that path is left as it is)."""
+    s_shape, c_shape = state_shapes(model, state_slots + 1)
+    state = StateSpec(num_slots=state_slots + 1, state_shape=tuple(s_shape),
+                      conv_shape=tuple(c_shape), conv_dtype=model.dtype)
+    spec = KvCacheSpec(
+        num_layers=model.num_cache_layers,
+        num_pages=cache.num_pages,
+        page_size=cache.page_size,
+        num_kv_heads=model.num_kv_heads,
+        head_dim=model.head_dim,
+        dtype=cache.dtype,
+    )
+    if cache.auto_size and hbm_limit is not None:
+        budget = int(hbm_limit * cache.hbm_utilization) - hbm_in_use - state.total_bytes
+        spec.num_pages = int(max(budget // spec.bytes_per_page, 16))
+    return spec, state
 
 
 def create_kv_buffers(spec: KvCacheSpec, sharding=None) -> tuple[jax.Array, jax.Array]:

@@ -1,0 +1,458 @@
+"""The runner of a model whose layers are not all attention.
+
+``RecurrentModelRunner`` is ``ModelRunner`` with a second kind of
+per-sequence memory: beside the pages of the full-attention layers every
+sequence holds one **state slot** (``kv_cache.StateSlotPool`` hands them out;
+the two device pools are ``s_pool`` and ``c_pool``, laid out as
+``models/olmo_hybrid.state_shapes`` says).  It builds the same four program
+families under the same names and positional signatures (``prefill``,
+``prefill_extend``, ``prefill_batched``, ``decode_multi_async``); the slot of
+each row arrives as a keyword whose default is the garbage slot 0, so a caller
+that knows nothing of slots (a warm-up) compiles and runs exactly the programs
+the scheduler then launches.
+
+Prefill starts a sequence from zero state when its chunk starts at position 0
+and carries the state in its slot from chunk to chunk otherwise.  A decode
+frame advances the state of its lanes in place, column by column; a lane on
+the garbage slot does not run.  A frame launched ahead of the one before it
+(the overlapped schedule's lookahead) runs no column when that frame met a
+finish, because the scheduler then throws the lookahead away and its tokens
+must not have reached the state: ``frame_clean`` carries that from launch to
+launch on the device.
+
+What this runner refuses: everything in the module's ``SERVING_LIMITS``.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+from smg_tpu.engine.kv_cache import plan_recurrent_cache
+from smg_tpu.engine.runner import (
+    ModelRunner,
+    _attn_label,
+    _dev,
+    _pick_sampler,
+    logger,
+)
+from smg_tpu.ops.attention import scatter_kv_rows
+
+
+class RecurrentModelRunner(ModelRunner):
+    # ---- what a sequence holds ----
+
+    def _plan_cache(self, param_bytes: int):
+        """Slots first, pages from what is left (``plan_recurrent_cache``).
+        The device is read after the weights are on it."""
+        cfg, sched = self.model_cfg, self.config.scheduler
+        if self.mesh is not None:
+            raise ValueError(self.module.SERVING_LIMITS["mesh"])
+        limit = in_use = None
+        stats = self.local_devices()[0].memory_stats() or {}
+        if "bytes_limit" in stats:
+            limit, in_use = stats["bytes_limit"], stats.get("bytes_in_use", 0)
+        elif self.platform == "tpu":
+            raise RuntimeError("the TPU reports no memory_stats(); cannot size the caches")
+        spec, self.state_spec = plan_recurrent_cache(
+            cfg, self.config.cache, sched.max_batch_size + sched.max_prefill_group,
+            self.module.state_shapes, limit, in_use)
+        return spec
+
+    def _create_state_buffers(self) -> None:
+        st = self.state_spec
+        self.s_pool = jnp.zeros(st.state_shape, jnp.float32)
+        self.c_pool = jnp.zeros(st.conv_shape, jnp.dtype(st.conv_dtype))
+        if self._device is not None:
+            self.s_pool = jax.device_put(self.s_pool, self._device)
+            self.c_pool = jax.device_put(self.c_pool, self._device)
+        # whether the frame launched last met no finish (a device scalar the
+        # next launch may chain on; see the module docstring), and what a
+        # frame that chains on none is given in its place
+        self.frame_clean = None
+        self._unchained = self._scalar_up(np.bool_(True))
+        from smg_tpu.ops.pallas import linattn_decode
+
+        self.linattn_kernel_fits = linattn_decode.supported(
+            self.model_cfg.linear_num_heads, self.model_cfg.linear_key_head_dim,
+            self.model_cfg.linear_value_head_dim)
+        # the linear layers' decode step: the kernel on a TPU where its blocks
+        # fit the state's shape, the XLA form elsewhere
+        self.linattn_impl = ("pallas" if self.platform == "tpu" and self.linattn_kernel_fits
+                             and self.config.attention_impl != "xla" else "xla")
+        logger.info(
+            "state slots: %d x %.1f MiB (%d linear-attention layers), decode step %s; "
+            "pages for %d full-attention layers",
+            st.num_slots - 1, st.slot_bytes / 2**20, st.state_shape[0], self.linattn_impl,
+            self.spec.num_layers)
+
+    def state_info(self) -> dict:
+        st = self.state_spec
+        return {"slots_total": st.num_slots - 1, "slot_bytes": st.slot_bytes,
+                "linattn_decode": self.linattn_impl}
+
+    def _prefill_impl_for(self, T: int, mp: int) -> str:
+        """A chunk that continues a prompt runs on XLA attention here: the
+        paged prefill kernel takes 3.2 s for a 4,096-token chunk where the
+        XLA form takes a tenth of that (PERF.md 7.3h), and every lane waits
+        meanwhile.  ``attention_impl='pallas'`` still forces the kernel."""
+        if self.attn_impl == "pallas":
+            return super()._prefill_impl_for(T, mp)
+        return "xla"
+
+    def _chunk_bucket(self, n_tokens: int) -> int:
+        """Every second bucket of the ladder, from the top: a prompt cut by a
+        step's budget is rare, and each bucket is a program of 5 MB that takes
+        10-20 s to compile (of this model's programs the compile cache a chip
+        machine keeps, 192 MiB, holds about forty)."""
+        ladder = sorted(self.config.scheduler.prefill_token_buckets, reverse=True)[::2]
+        return min((b for b in ladder if b >= n_tokens), default=ladder[0])
+
+    def flush_cache_buffers(self) -> None:
+        super().flush_cache_buffers()
+        self._create_state_buffers()
+
+    # ---- refusals ----
+
+    def load_lora(self, name, weights):
+        raise ValueError(self.module.SERVING_LIMITS["lora"])
+
+    def embed(self, batches):
+        raise ValueError(self.module.SERVING_LIMITS["embeddings"])
+
+    @property
+    def supports_kv_transfer(self) -> bool:
+        return False
+
+    def _no_transfer(self, *_a, **_k):
+        raise ValueError(self.module.SERVING_LIMITS["kv_transfer"])
+
+    export_pages = import_pages = export_pages_device = import_pages_device = _no_transfer
+
+    def _decode_spec_fn(self, *_a, **_k):
+        raise ValueError(self.module.SERVING_LIMITS["speculative"])
+
+    def _decode_fn(self, *_a, **_k):
+        raise ValueError("olmo_hybrid decodes through decode_multi only")
+
+    # ---- programs ----
+
+    def _plain(self, what: str, **flags) -> None:
+        on = [k for k, v in flags.items() if v]
+        if on:
+            raise ValueError(f"olmo_hybrid {what} does not take {', '.join(on)}")
+
+    def _prefill_fn(self, T: int, mp: int, use_pen: bool = False,
+                    use_mask: bool = False, use_lora: bool = False,
+                    use_ring: bool = False, use_embeds: bool = False,
+                    use_mrope: bool = False):
+        self._plain("prefill", lora=use_lora, ring=use_ring, embeds=use_embeds, mrope=use_mrope)
+        impl = self._prefill_impl_for(T, mp)
+        k = ("prefill", T, mp, impl, use_pen, use_mask)
+        if k in self._compiled:
+            return self._compiled[k]
+        cfg, module = self.model_cfg, self.module
+        from smg_tpu.engine.sampling import apply_penalties
+
+        def step(params, inv_freq, tokens, prefix_len, t_real, kc, vc, page_table,
+                 sp, cp, slot, key, temp, topk, topp, minp, *extra):
+            logits, kc, vc, sp, cp = module.forward_prefill(
+                params, cfg, inv_freq, tokens, prefix_len, t_real, kc, vc, page_table,
+                sp, cp, slot, attn_impl=impl)
+            logits = logits[None]
+            i = 0
+            if use_pen:
+                logits = apply_penalties(logits, *extra[:5])
+                i = 5
+            mask = extra[i] if use_mask else None
+            toks, lps = _pick_sampler()(logits, key, temp, topk, topp, minp, mask=mask)
+            return toks[0], lps[0], kc, vc, sp, cp
+
+        donate = (5, 6, 8, 9)
+        return self._register(k, jax.jit(step, donate_argnums=donate), donate=donate,
+                              in_shardings=None, attn=_attn_label("prefill", impl))
+
+    def _prefill_batched_fn(self, G: int, T: int, mp: int, no_ctx: bool = False,
+                            use_pen: bool = False, use_mask: bool = False,
+                            use_lora: bool = False, use_embeds: bool = False,
+                            use_mrope: bool = False):
+        self._plain("prefill_batched", lora=use_lora, embeds=use_embeds, mrope=use_mrope)
+        k = ("prefill_batched", G, T, mp, no_ctx, use_pen, use_mask)
+        if k in self._compiled:
+            return self._compiled[k]
+        cfg, module = self.model_cfg, self.module
+        from smg_tpu.engine.sampling import apply_penalties
+
+        def step(params, inv_freq, tokens, prefix_lens, t_reals, kc, vc, page_tables,
+                 sp, cp, slots, key, temps, topks, topps, minps, *extra):
+            logits, kc, vc, sp, cp = module.forward_prefill_batched(
+                params, cfg, inv_freq, tokens, prefix_lens, t_reals, kc, vc, page_tables,
+                sp, cp, slots, no_ctx=no_ctx)
+            i = 0
+            if use_pen:
+                logits = apply_penalties(logits, *extra[:5])
+                i = 5
+            mask = extra[i] if use_mask else None
+            toks, lps = _pick_sampler()(logits, key, temps, topks, topps, minps, mask=mask)
+            return toks, lps, kc, vc, sp, cp
+
+        donate = (5, 6, 8, 9)
+        return self._register(k, jax.jit(step, donate_argnums=donate), donate=donate,
+                              in_shardings=None, attn="xla")
+
+    def _decode_multi_fn(self, B: int, mp: int, N: int, E: int = 0,
+                         use_pen: bool = False, use_mask: bool = False,
+                         use_lora: bool = False, use_mrope: bool = False):
+        """``ModelRunner._decode_multi_fn``'s megastep for this model: the
+        same loop, stop detection and in-loop key folds, with the state pools
+        carried through the columns beside the side buffers.  ``chain`` (a
+        device bool, true for a frame that chains on none) turns the frame
+        into no columns at all when the frame before it met a finish."""
+        self._plain("decode", lora=use_lora, mrope=use_mrope)
+        use_stop = E > 0
+        attn_impl = self._attn_impl_for(B, mp)
+        lin_impl = self.linattn_impl
+        k = ("decode_multi", B, mp, N, E, attn_impl, lin_impl, use_pen, use_mask)
+        if k in self._compiled:
+            return self._compiled[k]
+        cfg, module = self.model_cfg, self.module
+        ps = self.spec.page_size
+        KD = cfg.num_kv_heads * cfg.head_dim
+        L = cfg.num_cache_layers
+        from smg_tpu.engine.sampling import apply_penalties
+
+        def multi(params, inv_freq, tokens, entry_pos, kc, vc, page_tables, sp, cp,
+                  slots, chain, base_key, step0, n_steps, temps, topks, topps, minps,
+                  *extra):
+            i = 0
+            if use_pen:
+                counts_buf, pmask_buf, slot_idx, freqs, pres, reps = extra[:6]
+                i = 6
+            mask = None
+            if use_mask:
+                mask = extra[i]
+                i += 1
+            if use_stop:
+                stop_ids, limits, live = extra[i], extra[i + 1], extra[i + 2]
+            n_steps = jnp.where(chain, n_steps, 0)
+            runs = slots > 0
+            hk0 = jnp.zeros((L, B, N, KD), kc.dtype)
+            hv0 = jnp.zeros((L, B, N, KD), kc.dtype)
+            counts0 = counts_buf[slot_idx] if use_pen else jnp.zeros((B, 0))
+            pmask = pmask_buf[slot_idx] if use_pen else None
+            sampler = _pick_sampler()
+            done0 = (~live) if use_stop else jnp.zeros((B,), jnp.bool_)
+
+            def cond(carry):
+                j, done = carry[0], carry[7]
+                ok = j < n_steps
+                if use_stop:
+                    ok = jnp.logical_and(ok, ~jnp.any(done & live))
+                return ok
+
+            def body(carry):
+                j, cur, toks_out, lps_out, hk, hv, counts, done, sp, cp = carry
+                logits, hk, hv, sp, cp = module.forward_decode_horizon(
+                    params, cfg, inv_freq, cur, entry_pos + j, entry_pos, j,
+                    kc, vc, page_tables, hk, hv, sp, cp, slots, runs,
+                    attn_impl=attn_impl, linattn_impl=lin_impl)
+                if use_pen:
+                    logits = apply_penalties(logits, counts, pmask, freqs, pres, reps)
+                kj = jax.random.split(jax.random.fold_in(
+                    base_key, step0 + j.astype(jnp.uint32) + jnp.uint32(1)), 1)[0]
+                new, lps = sampler(logits, kj, temps, topks, topps, minps, mask=mask)
+                if use_pen:
+                    counts = counts.at[jnp.arange(B), new].add(1)
+                toks_out = lax.dynamic_update_slice(
+                    toks_out, new[:, None].astype(jnp.int32), (0, j))
+                lps_out = lax.dynamic_update_slice(
+                    lps_out, lps[:, None].astype(jnp.float32), (0, j))
+                if use_stop:
+                    tok_done = jnp.any(new[:, None] == stop_ids, axis=1)
+                    done = done | tok_done | ((entry_pos + j) >= (limits - 2))
+                return (j + 1, new, toks_out, lps_out, hk, hv, counts, done, sp, cp)
+
+            init = (jnp.int32(0), tokens, jnp.zeros((B, N), jnp.int32),
+                    jnp.zeros((B, N), jnp.float32), hk0, hv0, counts0, done0, sp, cp)
+            (steps_run, _cur, outs, lps, hk, hv, counts, done, sp, cp) = \
+                lax.while_loop(cond, body, init)
+            total = mp * ps
+            pos = entry_pos[:, None] + jnp.arange(N)[None, :]
+            valid = (pos < total) & (jnp.arange(N)[None, :] < steps_run)
+            pos_c = jnp.minimum(pos, total - 1)
+            page = jnp.take_along_axis(page_tables, pos_c // ps, axis=1)
+            dest = jnp.where(valid, page * ps + pos_c % ps, 0).reshape(-1)
+            kc, vc = scatter_kv_rows(
+                kc, vc, hk.reshape(L, B * N, KD), hv.reshape(L, B * N, KD), dest)
+            clean = chain & ~jnp.any(done & live) if use_stop else chain
+            out = (outs, lps, steps_run, kc, vc, sp, cp, clean)
+            if use_pen:
+                out += (counts_buf.at[slot_idx].set(counts),)
+            return out
+
+        donate = (4, 5, 7, 8) + ((18,) if use_pen else ())
+        if not self.donation.donate_kv:
+            donate = ()
+        return self._register(k, jax.jit(multi, donate_argnums=donate), donate=donate,
+                              in_shardings=None, attn=_attn_label("decode", attn_impl),
+                              products=(self.xla_decode_products
+                                        if attn_impl == "xla" else None))
+
+    # ---- host-facing API: ModelRunner's, with the rows' slots as keywords ----
+
+    def _state_args(self, slots) -> list:
+        return [self.s_pool, self.c_pool, _dev(slots, jnp.int32)]
+
+    def prefill(self, token_ids, prefix_len, page_table, temperature, top_k, top_p,
+                min_p, pen=None, mask=None, lora_idx=0, mm=None, rope_pos=None,
+                state_slot: int = 0):
+        """``ModelRunner.prefill`` from the state in ``state_slot`` (zero
+        state where ``prefix_len`` is 0)."""
+        T, mp, base, use_lora, use_ring, _tail = self._prefill_chunk_prep(
+            token_ids, prefix_len, page_table, lora_idx, mm, rope_pos)
+        fn = self._prefill_fn(T, mp, use_pen=pen is not None, use_mask=mask is not None,
+                              use_lora=use_lora, use_ring=use_ring,
+                              use_embeds=mm is not None, use_mrope=rope_pos is not None)
+        up = self.upload
+        args = base + self._state_args(np.int32(state_slot)) + [
+            self._next_key(), up([temperature], jnp.float32), up([top_k], jnp.int32),
+            up([top_p], jnp.float32), up([min_p], jnp.float32)]
+        if pen is not None:
+            counts, pmask, freq, pres, rep = pen
+            args += [up(counts, jnp.int32)[None], up(pmask)[None], up([freq], jnp.float32),
+                     up([pres], jnp.float32), up([rep], jnp.float32)]
+        if mask is not None:
+            args.append(up(mask)[None])
+        tok, lp, self.k_cache, self.v_cache, self.s_pool, self.c_pool = fn(*args)
+        return int(tok), float(lp)
+
+    def prefill_extend(self, token_ids, prefix_len, page_table, lora_idx=0, mm=None,
+                       rope_pos=None, state_slot: int = 0) -> None:
+        """``ModelRunner.prefill_extend``: a chunk that is not the prompt's
+        last; the state in ``state_slot`` moves on by the chunk.  It runs
+        the ``prefill`` program (one program a bucket instead of two) with the
+        unfolded key, so the key counter stands still, and fetches nothing:
+        the token it samples from one row of logits is dropped on the device."""
+        T, mp, base, use_lora, use_ring, _tail = self._prefill_chunk_prep(
+            token_ids, prefix_len, page_table, lora_idx, mm, rope_pos)
+        fn = self._prefill_fn(T, mp, use_lora=use_lora, use_ring=use_ring,
+                              use_embeds=mm is not None, use_mrope=rope_pos is not None)
+        up = self.upload
+        _tok, _lp, self.k_cache, self.v_cache, self.s_pool, self.c_pool = fn(
+            *base, *self._state_args(np.int32(state_slot)), self._rng_key,
+            up([0.0], jnp.float32), up([-1], jnp.int32), up([1.0], jnp.float32),
+            up([0.0], jnp.float32))
+
+    def prefill_batched(self, chunks, temps, topks, topps, minps, pen=None, mask=None,
+                        lora_idx=None, mm=None, rope=None, state_slots=None):
+        """``ModelRunner.prefill_batched``; ``state_slots`` [G_real] names
+        each row's slot (padded rows get the garbage slot).  A group whose
+        padded rows x tokens pass the step's token budget runs as several
+        launches of as many rows as fit it: the chunked recurrence keeps
+        float32 operands of every padded token, and a program of 8 rows x
+        4,096 tokens does not fit beside the caches."""
+        from smg_tpu.engine.runner import _pad_rows, _pad_vec
+
+        self._plain("prefill_batched", lora=lora_idx is not None and self._lora_bank is not None,
+                    embeds=mm is not None and any(m is not None for m in mm),
+                    mrope=rope is not None and any(r is not None for r in rope))
+        g_real = len(chunks)
+        T = self.config.scheduler.prefill_bucket(max(len(c[0]) for c in chunks))
+        rows = max(1, self.config.scheduler.max_prefill_tokens // T)
+        if g_real > rows:
+            toks, lps = [], []
+            for lo in range(0, g_real, rows):
+                part = slice(lo, lo + rows)
+                t, l = self.prefill_batched(
+                    chunks[part], temps[part], topks[part], topps[part], minps[part],
+                    pen=None if pen is None else tuple(x[part] for x in pen),
+                    mask=None if mask is None else mask[part],
+                    state_slots=None if state_slots is None else state_slots[part])
+                toks.append(t)
+                lps.append(l)
+            return np.concatenate(toks), np.concatenate(lps)
+        G = 1
+        while G < g_real:
+            G *= 2
+        mp = len(chunks[0][2])
+        tokens = np.zeros((G, T), np.int32)
+        prefix_lens, t_reals = np.zeros(G, np.int32), np.zeros(G, np.int32)
+        page_tables = np.zeros((G, mp), np.int32)
+        slots = np.zeros(G, np.int32)
+        for i, (ids, pfx, row) in enumerate(chunks):
+            tokens[i, : len(ids)] = ids
+            prefix_lens[i], t_reals[i], page_tables[i] = pfx, len(ids), row
+        if state_slots is not None:
+            slots[:g_real] = state_slots
+        fn = self._prefill_batched_fn(G, T, mp, all(c[1] == 0 for c in chunks),
+                                      use_pen=pen is not None, use_mask=mask is not None)
+        up = self.upload
+        args = [self.params, self.inv_freq, up(tokens), up(prefix_lens), up(t_reals),
+                self.k_cache, self.v_cache, up(page_tables), *self._state_args(slots),
+                self._next_key(),
+                up(_pad_vec(np.asarray(temps, np.float32), G, 0.0)),
+                up(_pad_vec(np.asarray(topks, np.int32), G, -1)),
+                up(_pad_vec(np.asarray(topps, np.float32), G, 1.0)),
+                up(_pad_vec(np.asarray(minps, np.float32), G, 0.0))]
+        if pen is not None:
+            counts, pmask, freqs, pres, reps = pen
+            args += [up(_pad_rows(counts, G).astype(np.int32)), up(_pad_rows(pmask, G)),
+                     up(_pad_vec(freqs, G, 0.0), jnp.float32),
+                     up(_pad_vec(pres, G, 0.0), jnp.float32),
+                     up(_pad_vec(reps, G, 1.0), jnp.float32)]
+        if mask is not None:
+            args.append(up(_pad_rows(mask, G, fill=True)))
+        toks, lps, self.k_cache, self.v_cache, self.s_pool, self.c_pool = fn(*args)
+        toks, lps = jax.device_get((toks, lps))  # intended blocking fetch
+        return toks[:g_real], lps[:g_real]
+
+    def decode_multi_async(self, tokens, positions, page_tables, temps, topks, topps,
+                           minps, num_steps, max_steps=None, stop_state=None, pen=None,
+                           mask=None, lora_idx=None, rope_delta=None, state_slots=None,
+                           chain=None):
+        """``ModelRunner.decode_multi_async``.  ``state_slots`` [B] names
+        each lane's slot (0: the lane does not run); ``chain`` is the
+        ``frame_clean`` of the frame this one was launched ahead of, None
+        for a frame that follows a consumed one.  After the call
+        ``self.frame_clean`` is this frame's."""
+        B, mp = page_tables.shape
+        N = max_steps or num_steps
+        self._plain("decode", lora=lora_idx is not None and self._lora_bank is not None,
+                    mrope=rope_delta is not None)
+        E = 0
+        if N > 1:
+            if stop_state is None:
+                raise ValueError("decode megastep with N > 1 requires stop_state")
+            E = stop_state[0].shape[1]
+        fn = self._decode_multi_fn(B, mp, N, E, pen is not None, mask is not None)
+        mark = self._consume_folds(num_steps)
+        if state_slots is None:
+            state_slots = np.zeros(B, np.int32)
+        if chain is None:
+            chain = self._unchained
+        args = [self.params, self.inv_freq, _dev(tokens, jnp.int32),
+                _dev(positions, jnp.int32), self.k_cache, self.v_cache,
+                _dev(page_tables, jnp.int32), *self._state_args(state_slots), chain,
+                self._rng_key, self._scalar_up(np.uint32(mark)),
+                self._scalar_up(np.int32(num_steps)), _dev(temps, jnp.float32),
+                _dev(topks, jnp.int32), _dev(topps, jnp.float32), _dev(minps, jnp.float32)]
+        if pen is not None:
+            self._ensure_penalty_buffers()
+            slot_idx, freqs, pres, reps = pen
+            args += [self._counts_buf, self._pmask_buf, _dev(slot_idx, jnp.int32),
+                     _dev(freqs, jnp.float32), _dev(pres, jnp.float32),
+                     _dev(reps, jnp.float32)]
+        if mask is not None:
+            args.append(_dev(mask, jnp.bool_))
+        if E:
+            stop_ids, limits, live = stop_state
+            args += [_dev(stop_ids, jnp.int32), _dev(limits, jnp.int32),
+                     _dev(live, jnp.bool_)]
+        out = fn(*args)
+        (toks, lps, steps_run, self.k_cache, self.v_cache, self.s_pool, self.c_pool,
+         self.frame_clean) = out[:8]
+        if pen is not None:
+            self._counts_buf = out[8]
+        return toks, lps, steps_run

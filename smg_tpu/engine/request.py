@@ -62,6 +62,9 @@ class EngineRequest:
     shared_pages: list[int] = field(default_factory=list)  # radix-cache pages (pinned)
     radix_node: Any = None  # locked RadixNode for the shared prefix
     slot: int | None = None  # decode slot index
+    # recurrent models: the state slot this sequence holds beside its pages
+    # (kv_cache.StateSlotPool), from admission to release or preemption
+    state_slot: int | None = None
     finish: FinishInfo | None = None
     # filled by the engine layer (detokenize/stop strings)
     detok: Any = None

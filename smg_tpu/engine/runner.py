@@ -122,7 +122,7 @@ class DecodeState:
         "lane_sig", "temps", "topks", "topps", "minps",
         "slot_idx", "freqs", "pres", "reps", "lora_idx", "rope_delta",
         "pt_sig", "page_tables",
-        "stop_ids", "limits", "live",
+        "stop_ids", "limits", "live", "state_slots",
     )
 
     def __init__(self):
@@ -142,6 +142,8 @@ class DecodeState:
         self.stop_ids = None
         self.limits = None
         self.live = None
+        # a recurrent model's state slot of every lane ([B]; 0 for padding)
+        self.state_slots = None
 
 
 class ModelRunner:
@@ -182,7 +184,10 @@ class ModelRunner:
 
         self.inv_freq = jnp.asarray(
             rope_frequencies(
-                self.model_cfg.head_dim, self.model_cfg.rope_theta, self.model_cfg.rope_scaling
+                # rope_theta 0: the model applies no rotary embedding and
+                # never reads these
+                self.model_cfg.head_dim, self.model_cfg.rope_theta or 10000.0,
+                self.model_cfg.rope_scaling
             )
         )
         if self._replicated is not None:
@@ -234,11 +239,7 @@ class ModelRunner:
         # (GSPMD shards most weights over tp/ep, so global nbytes would
         # over-subtract and under-size the cache).
         param_bytes = self._local_param_bytes()
-        hbm_free = self._detect_hbm()
-        self.spec: KvCacheSpec = plan_cache(
-            self.model_cfg, config.cache, hbm_free, param_bytes,
-            tp=config.parallel.tp,
-        )
+        self.spec: KvCacheSpec = self._plan_cache(param_bytes)
         kv_sharding = None
         if self.mesh is not None:
             from smg_tpu.models.llama import kv_cache_logical_axes
@@ -258,6 +259,7 @@ class ModelRunner:
         self.kv_lanes_sharded = (
             lane_axis is not None and self.mesh.shape[lane_axis] > 1)
         self.k_cache, self.v_cache = create_kv_buffers(self.spec, kv_sharding)
+        self._create_state_buffers()
         logger.info(
             "kv cache: %d pages x %d tokens (%.1f MiB)",
             self.spec.num_pages,
@@ -326,6 +328,18 @@ class ModelRunner:
         self._lora_bank = None
         self._lora_names: dict[str, int] = {}
         self._lora_rank = 0
+
+    def _plan_cache(self, param_bytes: int) -> KvCacheSpec:
+        """Size the paged cache from what the tightest device has free."""
+        return plan_cache(
+            self.model_cfg, self.config.cache, self._detect_hbm(), param_bytes,
+            tp=self.config.parallel.tp,
+        )
+
+    def _create_state_buffers(self) -> None:
+        """Per-sequence state beside the pages: none for a model whose layers
+        are all attention (``engine/recurrent_runner.py`` has the other kind)."""
+        self.state_spec = None
 
     def _resolve_attn_impl(self) -> str:
         """What the configured mode can mean on this engine: "xla",
@@ -1348,6 +1362,10 @@ class ModelRunner:
 
     # ---- host-facing API ----
 
+    def _chunk_bucket(self, n_tokens: int) -> int:
+        """Padded length of a solo or continuing prefill chunk."""
+        return self.config.scheduler.prefill_bucket(n_tokens)
+
     def _prefill_chunk_prep(
         self, token_ids, prefix_len, page_table, lora_idx, mm, rope_pos
     ):
@@ -1368,7 +1386,7 @@ class ModelRunner:
         ``base_args`` is the common [params..page_table] prefix and
         ``tail_args`` the lora/mm/rope suffix in extra-arg order."""
         t = len(token_ids)
-        T = self.config.scheduler.prefill_bucket(t)
+        T = self._chunk_bucket(t)
         tokens = np.zeros(T, np.int32)
         tokens[:t] = token_ids
         mp = len(page_table)

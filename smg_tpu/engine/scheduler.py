@@ -60,7 +60,7 @@ from jax import lax
 
 from smg_tpu.engine.config import EngineConfig
 from smg_tpu.engine.flight_recorder import HORIZON_REASONS
-from smg_tpu.engine.kv_cache import PagePool
+from smg_tpu.engine.kv_cache import PagePool, StateSlotPool
 from smg_tpu.engine.radix_cache import RadixCache
 from smg_tpu.engine.request import (
     EngineRequest,
@@ -121,6 +121,13 @@ class InFlightFrame:
     n_emit: "object" = None  # jax.Array [B] (spec frames only)
     draft_ns: "list | None" = None  # per-lane drafted-token counts
     tiers: "list | None" = None  # per-lane drafting tier ("ngram"/"draft")
+    # recurrent models: ``clean`` is the device's word that this frame met no
+    # finish (a frame chained on it then ran; on one that met a finish it ran
+    # no column).  ``ran`` is what the host knows of THIS frame: False once
+    # the frame it was chained on is known to have met a finish, so that
+    # discarding it has nothing to take out of the state again.
+    clean: "object" = None  # jax.Array scalar bool
+    ran: bool = True
 
 
 def _launch_attrs(frame: "InFlightFrame") -> dict:
@@ -154,6 +161,16 @@ class Scheduler:
         # installs one when config.draft_model is set
         self.draft = None
         self.slots: list[EngineRequest | None] = [None] * self.sched.max_batch_size
+        # a model with recurrent layers: every resident sequence holds a
+        # state slot beside its pages, and a prefix hit without the state at
+        # that prefix is no hit (no snapshot is kept yet, so none is)
+        self.state_pool = (
+            StateSlotPool(runner.state_spec.num_slots)
+            if getattr(runner, "state_spec", None) is not None else None
+        )
+        self.num_state_prefix_hits_declined = 0
+        self.num_state_recomputed_tokens = 0
+        self._step_state_lanes = 0
         self.page_tables = np.zeros((self.sched.max_batch_size, self.mp), np.int32)
         self.requests: dict[str, EngineRequest] = {}
         # counters for GetLoads / metrics
@@ -439,6 +456,19 @@ class Scheduler:
             "dispatch_enqueue_seconds": self.dispatch_enqueue_s_total,
             "fetch_wait_seconds": self.fetch_wait_s_total,
         }
+        if self.state_pool is not None:
+            info = self.runner.state_info()
+            out.update({
+                "state_slots_total": info["slots_total"],
+                "state_slots_in_use": self.state_pool.in_use,
+                "state_slot_bytes": info["slot_bytes"],
+                # radix matches turned down for want of a state snapshot, and
+                # tokens prefilled again because a sequence lost its state
+                # (preemption, or a discarded frame that had advanced it)
+                "state_prefix_hits_declined": self.num_state_prefix_hits_declined,
+                "state_recomputed_tokens": self.num_state_recomputed_tokens,
+                "linattn_decode": info["linattn_decode"],
+            })
         if self.metrics is not None:
             # rolling-window live signal (p50/p95 step time, tokens/s) for
             # the /scheduler endpoint, dp-aware routing, and benchmarks
@@ -477,7 +507,13 @@ class Scheduler:
             not live and not self.waiting and self.inflight is None
         )
         leaked = allocatable - free - cached - held_pages
+        # state slots: every slot in use belongs to a resident sequence
+        leaked_slots = 0
+        if self.state_pool is not None:
+            held = {r.state_slot for r in live if r.state_slot is not None}
+            leaked_slots = self.state_pool.in_use - len(held)
         return {
+            **({"leaked_state_slots": leaked_slots} if self.state_pool is not None else {}),
             "live_slots": len(live),
             "waiting_requests": len(self.waiting),
             "inflight_frames": 0 if self.inflight is None else 1,
@@ -492,7 +528,7 @@ class Scheduler:
             "quiescent": quiescent,
             # the one-bit verdict the harness asserts: no page unaccounted
             # for now, and no stray pins once nothing is running
-            "clean": leaked == 0 and (
+            "clean": leaked == 0 and leaked_slots == 0 and (
                 not quiescent
                 or (locks["locked_nodes"] == 0 and locks["lock_refcounts"] == 0)
             ),
@@ -530,6 +566,7 @@ class Scheduler:
         self._step_horizon_reason = ""
         self._step_spec_drafted = 0
         self._step_spec_accepted = 0
+        self._step_state_lanes = 0
         pf0, dc0 = self.num_prefill_tokens, self.num_decode_tokens
         we0, ee0 = self.num_wasted_decode_tokens, self.num_megastep_early_exits
         t0 = time.perf_counter()
@@ -573,6 +610,7 @@ class Scheduler:
                     spec_accepted=self._step_spec_accepted,
                     mesh=self._mesh_devices,
                     horizon_reason=self._step_horizon_reason,
+                    state_lanes=self._step_state_lanes,
                 )
                 self.flush_pending_dumps()
         return outputs
@@ -856,6 +894,8 @@ class Scheduler:
                 if self._prefill_phase_fold_free():
                     look = self._launch_lookahead(frame)
                 fetch_s, used = self._consume_frame(frame, outputs)
+                if look is not None and self.state_pool is not None:
+                    look.ran = bool(frame.clean)
             except Exception:
                 # quarantine path: rewind the NEWEST folds first (the chained
                 # lookahead launched off this frame), then stash the frame on
@@ -1015,10 +1055,28 @@ class Scheduler:
         # results are never fetched, so count the full requested width (an
         # upper bound — the device may have early-exited sooner)
         self.num_wasted_decode_tokens += frame.B_real * frame.horizon
+        if self.state_pool is not None and frame.ran:
+            self._state_lost([r for _s, r, _e in frame.lanes], "discarded frame")
         if frame.use_pen:
             for _slot, req, _expected in frame.lanes:
                 if req.sampling.has_penalties and not req.is_finished:
                     req.penalty_synced = False
+
+    def _state_lost(self, reqs: list, why: str) -> None:
+        """A frame advanced these sequences' recurrent state by columns that
+        are not accepted (a discarded frame that ran, or a host trim short of
+        what the device ran).  The state cannot be taken back and no snapshot
+        is kept, so each sequence still alive gives up its slot and pages
+        and prefills again from its first token (``state_recomputed_tokens``).
+        A lookahead chained on a frame that met a finish never gets here: it
+        ran no column (``InFlightFrame.ran``)."""
+        for req in reqs:
+            if (req.is_finished or req.slot is None
+                    or req.status is not RequestStatus.RUNNING):
+                continue
+            logger.warning("request %s lost its recurrent state (%s); prefilling again",
+                           req.rid, why)
+            self._preempt(req)
 
     def _rewind_unused_folds(self, frame: InFlightFrame, used: int) -> None:
         """A finish trimmed a consumed megastep at column ``used-1``: the
@@ -1103,9 +1161,12 @@ class Scheduler:
             rids=",".join(r.rid for _s, r, _e in frame.lanes),
         )
         t0 = time.perf_counter()
-        toks, lps, steps_run = jax.device_get(
-            (frame.toks, frame.lps, frame.steps_run)
+        toks, lps, steps_run, clean = jax.device_get(
+            (frame.toks, frame.lps, frame.steps_run, frame.clean)
         )
+        # recurrent models: what a frame chained on this one did (ran, or ran
+        # no column because this one met a finish)
+        frame.clean = clean
         fetch_s = time.perf_counter() - t0
         self.fetch_wait_s_total += fetch_s
         if frame.lookahead:
@@ -1114,6 +1175,8 @@ class Scheduler:
         # host-side trim: earliest finish column across all lanes (scanning
         # only device-computed columns — later ones hold unset zeros)
         used = min(frame.horizon, sr) if sr > 0 else frame.horizon
+        if self.state_pool is not None:
+            used = min(frame.horizon, sr)  # a frame that ran no column gives no token
         finished_any = False
         for idx, (_slot, req, _expected) in enumerate(frame.lanes):
             col = self._host_finish_col(req, toks[idx], used)
@@ -1129,6 +1192,8 @@ class Scheduler:
             # the device done rules lag the host's) — pure waste, normally 0
             self.num_wasted_decode_tokens += (sr - used) * frame.B_real
         self.num_decode_tokens += frame.B_real * used
+        self._step_state_lanes = sum(
+            1 for _s, r, _e in frame.lanes if r.state_slot is not None)
         for idx, (_slot, req, _expected) in enumerate(frame.lanes):
             self._accept_tokens(
                 req,
@@ -1137,6 +1202,10 @@ class Scheduler:
                 outputs,
                 advance_seq=True,
             )
+        if self.state_pool is not None and sr > used:
+            # the device ran past what the host accepts: the state holds
+            # tokens that were never emitted
+            self._state_lost([r for _s, r, _e in frame.lanes], "host trim short of device")
         # adaptive-horizon controller signal: EMA of decode columns between
         # finishes — the expected uninterrupted run length K should track
         self._cols_since_finish += used
@@ -1180,6 +1249,11 @@ class Scheduler:
         H2, max_steps = self._pick_horizon(
             [(s, r) for s, r, _ in frame.lanes]
         )
+        if self.state_pool is not None and (max_steps == 1 or frame.clean is None):
+            # without the device's stop state a frame cannot tell the one
+            # chained on it that it met a finish, and a lookahead that ran and
+            # is then discarded costs its lanes their state
+            return None
         ps = self.ps
         max_seq = self.sched.max_seq_len
         need = 0
@@ -1194,10 +1268,11 @@ class Scheduler:
             limit = min(expected + H + H2, max_seq)
             have = len(req.shared_pages) + len(req.owned_pages)
             need += max(0, math.ceil(limit / ps) - have)
-        if need > self.pool.free_count:
+        if need > self._headroom_pages():
             return None
         for _slot, req, _expected in frame.lanes:
-            # precheck guarantees allocation without eviction or preemption
+            # precheck guarantees allocation without preemption (and, but for
+            # a recurrent model's never-pinned cache, without eviction)
             if not self._ensure_seq_capacity(req, H + H2):
                 return None  # defensive; unreachable after the precheck
         mp_b = self._mp_bucket(max(
@@ -1228,10 +1303,12 @@ class Scheduler:
             if frame.use_pen else None,
             lora_idx=ds.lora_idx if frame.use_lora else None,
             rope_delta=ds.rope_delta if frame.use_mrope else None,
+            **self._state_kw(ds, chain=frame.clean),
         )
         self._note_dispatch(time.perf_counter() - t_dispatch)
         self._count_decode_launch()
         return InFlightFrame(
+            clean=getattr(self.runner, "frame_clean", None),
             lanes=[(s, r, e + H) for s, r, e in frame.lanes],
             toks=toks, lps=lps, horizon=H2, B=frame.B, B_real=frame.B_real,
             mp_b=mp_b, positions=positions, lane_sig=frame.lane_sig,
@@ -1448,6 +1525,14 @@ class Scheduler:
                 prompt[:-1],
                 extra_keys=self._mm_extra_keys(req, len(prompt)),
             )
+        if self.state_pool is not None:
+            if self.state_pool.free_count == 0:
+                return None  # every state slot is held: wait for a release
+            if shared_pages:
+                # the pages of the prefix are here and the recurrent state at
+                # its end is not: the prompt prefills from its first token
+                self.num_state_prefix_hits_declined += 1
+                shared_pages, node = [], None
         matched_tokens = len(shared_pages) * self.ps
         remaining = len(prompt) - matched_tokens
         if (
@@ -1483,6 +1568,13 @@ class Scheduler:
         req.shared_pages = shared_pages
         req.cached_tokens = matched_tokens
         req.owned_pages = self.pool.alloc(need)
+        if self.state_pool is not None:
+            # a chunk at position 0 starts from zero state whatever the slot
+            # held before (models/olmo_hybrid.py), so binding is all it takes
+            req.state_slot = self.state_pool.alloc()
+            if req.status is RequestStatus.PREEMPTED:
+                # it had a state and lost it: everything it held is computed again
+                self.num_state_recomputed_tokens += len(prompt) - 1
         req.status = RequestStatus.PREFILLING
         req.prefill_pos = matched_tokens
         req.seq_len = matched_tokens
@@ -1517,6 +1609,7 @@ class Scheduler:
             lora_idx=req.lora_idx,
             mm=self._mm_chunk(req, start, len(chunk)),
             rope_pos=self._mrope_chunk(req, start, len(chunk)),
+            **self._slot_kw(req),
         )
         self.num_prefill_tokens += len(chunk)
         req.prefill_pos += len(chunk)
@@ -1557,6 +1650,7 @@ class Scheduler:
             lora_idx=req.lora_idx,
             mm=self._mm_chunk(req, start, len(chunk)),
             rope_pos=self._mrope_chunk(req, start, len(chunk)),
+            **self._slot_kw(req),
         )
         self.num_prefill_tokens += len(chunk)
         req.prefill_pos = len(prompt)
@@ -1567,6 +1661,10 @@ class Scheduler:
                 req.rid, "prefill_chunk", start=start, n=len(chunk), final=True
             )
         self._accept_tokens(req, [tok], [lp], outputs, advance_seq=False)
+
+    def _slot_kw(self, req: EngineRequest) -> dict:
+        """A recurrent model's keyword of a solo prefill launch."""
+        return {} if self.state_pool is None else {"state_slot": req.state_slot}
 
     def _mask_for(self, req: EngineRequest) -> np.ndarray:
         """Constrained-decoding vocab mask for the request's next token.
@@ -1617,6 +1715,7 @@ class Scheduler:
                 lora_idx=req.lora_idx,
                 mm=self._mm_chunk(req, start, len(chunk)),
                 rope_pos=self._mrope_chunk(req, start, len(chunk)),
+                **self._slot_kw(req),
             )
             self.num_prefill_tokens += len(chunk)
             start += len(chunk)
@@ -1765,6 +1864,8 @@ class Scheduler:
             lora_idx=lora_idx,
             mm=mm_rows if any(m is not None for m in mm_rows) else None,
             rope=rope_rows if any(r is not None for r in rope_rows) else None,
+            **({"state_slots": [r.state_slot for r in group]}
+               if self.state_pool is not None else {}),
         )
         for i, req in enumerate(group):
             # counted only after the batched call succeeded (a failed group
@@ -1887,6 +1988,11 @@ class Scheduler:
                 ds.reps = up(reps)
             ds.lora_idx = up(lora_idx) if use_lora else None
             ds.rope_delta = up(rope_delta) if use_mrope else None
+            if self.state_pool is not None:
+                state_slots = np.zeros(B, np.int32)
+                for idx, (_slot, req) in enumerate(active):
+                    state_slots[idx] = req.state_slot
+                ds.state_slots = up(state_slots)
             if stop_e > 0:
                 # megastep device stop state: one upload per composition.
                 # stop_ids [B, E] (-1 padded; tokens are always >= 0 so the
@@ -1932,6 +2038,27 @@ class Scheduler:
             ds.pt_sig = pt_sig
             self._pages_dirty = False
         return ds
+
+    def _headroom_pages(self) -> int:
+        """Pages a decode launch may count on without preempting anyone: the
+        free pool.  For a model with recurrent layers the radix cache's
+        pages count too: no match is honoured there without the state at its
+        end, so no cached page is ever pinned, every one can be evicted, and
+        evicting it takes nothing a later request could have used.  (With
+        the free pool alone such a model decodes one or two columns a frame
+        as soon as finished prompts have filled the pool.)"""
+        free = self.pool.free_count
+        if self.state_pool is not None and self.radix is not None:
+            free += self.radix.num_cached_pages
+        return free
+
+    def _state_kw(self, ds: DecodeState, chain=None) -> dict:
+        """A recurrent model's keywords of a decode launch: every lane's
+        state slot and, for a lookahead, the ``clean`` of the frame it is
+        chained on."""
+        if self.state_pool is None:
+            return {}
+        return {"state_slots": ds.state_slots, "chain": chain}
 
     def _pick_horizon(self, active: list) -> tuple[int, int]:
         """Choose this launch's decode horizon K and the compiled loop width
@@ -2024,7 +2151,7 @@ class Scheduler:
                 limit = min(r.seq_len + k, sched.max_seq_len)
                 have = len(r.shared_pages) + len(r.owned_pages)
                 need += max(0, math.ceil(limit / ps) - have)
-            if need <= self.pool.free_count:
+            if need <= self._headroom_pages():
                 break
             k //= 2
             self._picked_reason = "page_headroom"
@@ -2124,10 +2251,12 @@ class Scheduler:
             mask=mask_arr,
             lora_idx=ds.lora_idx if use_lora else None,
             rope_delta=ds.rope_delta if use_mrope else None,
+            **self._state_kw(ds),
         )
         self._note_dispatch(time.perf_counter() - t_dispatch)
         self._count_decode_launch()
         return InFlightFrame(
+            clean=getattr(self.runner, "frame_clean", None),
             lanes=[(i, r, r.seq_len) for i, r in active],
             toks=toks, lps=lps, horizon=horizon, B=B, B_real=B_real,
             mp_b=mp_b, positions=positions, lane_sig=sig,
@@ -2582,6 +2711,7 @@ class Scheduler:
         self.page_tables[slot][:] = 0
         self._pages_dirty = True
         req.slot = None
+        self._free_state_slot(req)
         if (
             req.status is RequestStatus.PREFILLING
             and self.radix is not None
@@ -2674,6 +2804,8 @@ class Scheduler:
         Used by the prefill leg of PD disaggregation; ``token_filter`` and
         penalties apply to the first sampled token exactly as in the
         co-located prefill paths."""
+        if self.state_pool is not None:
+            raise ValueError(self.runner.module.SERVING_LIMITS["kv_transfer"])
         n_pages = math.ceil(len(prompt_ids) / self.ps)
         if not self._ensure_free_pages(n_pages):
             raise RuntimeError("out of KV pages for prefill-only request")
@@ -2713,6 +2845,8 @@ class Scheduler:
     ) -> bool:
         """Adopt a request whose prompt KV was imported (decode leg of PD).
         Pages become owned by the request; returns False when no slot free."""
+        if self.state_pool is not None:
+            raise ValueError(self.runner.module.SERVING_LIMITS["kv_transfer"])
         free_slots = [i for i, s in enumerate(self.slots) if s is None]
         if not free_slots:
             return False
@@ -2758,6 +2892,12 @@ class Scheduler:
             return
         self._release(req, FinishInfo(reason=reason, matched_stop=matched_stop))
 
+    def _free_state_slot(self, req: EngineRequest) -> None:
+        """A recurrent model's state slot goes with the sequence's pages."""
+        if req.state_slot is not None:
+            self.state_pool.free(req.state_slot)
+            req.state_slot = None
+
     def _count_finish(
         self, req: EngineRequest, reason: str, message: str | None = None
     ) -> None:
@@ -2778,6 +2918,7 @@ class Scheduler:
             self._pages_dirty = True
             self.slots[req.slot] = None
             req.slot = None
+        self._free_state_slot(req)
 
         # Only tokens whose KV is actually written may enter the radix cache:
         # the final sampled token is never fed back, so its position has no KV

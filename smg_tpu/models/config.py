@@ -55,6 +55,28 @@ class ModelConfig:
     # placeholder the gateway expands per image (Qwen2-VL <|image_pad|>).
     vision: "object | None" = None  # VisionConfig (kept loose: frozen dataclass)
     image_token_id: int | None = None
+    # ---- hybrid stacks (``olmo_hybrid``): the kind of every layer in order,
+    # and the linear-attention layers' shape.  ``num_layers`` stays the depth;
+    # only the ``full_attention`` layers hold pages (``num_cache_layers``).
+    # ``rope_theta`` 0 means the full layers apply no rotary embedding.
+    layer_types: "tuple[str, ...] | None" = None
+    linear_num_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 0
+    linear_allow_neg_eigval: bool = False
+
+    @property
+    def num_cache_layers(self) -> int:
+        """Layers that hold keys and values in the paged cache."""
+        if self.layer_types is None:
+            return self.num_layers
+        return sum(1 for t in self.layer_types if t == "full_attention")
+
+    @property
+    def recurrent(self) -> bool:
+        """Some layers keep per-sequence state outside the pages."""
+        return self.layer_types is not None and "linear_attention" in self.layer_types
 
     @property
     def mrope_section(self) -> "tuple[int, ...] | None":
@@ -67,6 +89,8 @@ class ModelConfig:
 
     @classmethod
     def from_hf_config(cls, cfg: dict, dtype: str = "bfloat16") -> "ModelConfig":
+        if cfg.get("model_type") == "olmo_hybrid":
+            return cls._from_olmo_hybrid(cfg, dtype)
         arch_names = cfg.get("architectures") or ["LlamaForCausalLM"]
         arch = "llama"
         name = arch_names[0].lower()
@@ -147,6 +171,81 @@ class ModelConfig:
             image_token_id=cfg.get("image_token_id"),
             qk_norm=qk_norm,
             **extra,
+        )
+
+    # what an ``olmo_hybrid`` config.json may hold: keys this loader turns
+    # into the model's shape, and keys that bear on no shape.  Any other key
+    # is an error, so that a config this program would serve wrong fails to
+    # load (ROADMAP D6's rule, on this path).
+    _OLMO_HYBRID_CONSUMED = frozenset({
+        "model_type", "vocab_size", "hidden_size", "intermediate_size",
+        "num_hidden_layers", "num_attention_heads", "num_key_value_heads", "head_dim",
+        "hidden_act", "max_position_embeddings", "attention_bias", "rms_norm_eps",
+        "tie_word_embeddings", "layer_types", "linear_num_key_heads",
+        "linear_num_value_heads", "linear_key_head_dim", "linear_value_head_dim",
+        "linear_conv_kernel_dim", "linear_allow_neg_eigval", "rope_parameters",
+        "rope_theta", "eos_token_id", "bos_token_id",
+    })
+    _OLMO_HYBRID_SHAPELESS = frozenset({
+        "architectures", "torch_dtype", "dtype", "transformers_version", "use_cache",
+        "initializer_range", "pad_token_id", "attention_dropout", "auto_map",
+        "_name_or_path",
+    })
+
+    @classmethod
+    def _from_olmo_hybrid(cls, cfg: dict, dtype: str) -> "ModelConfig":
+        unknown = sorted(set(cfg) - cls._OLMO_HYBRID_CONSUMED - cls._OLMO_HYBRID_SHAPELESS)
+        if unknown:
+            raise ValueError(
+                f"olmo_hybrid config.json has keys this loader does not consume: {unknown}; "
+                "a key that may bear on the model's shape is not dropped in silence")
+        if cfg.get("hidden_act", "silu") != "silu":
+            raise ValueError(f"olmo_hybrid: hidden_act {cfg['hidden_act']!r} is not served")
+        if cfg.get("attention_bias"):
+            raise ValueError("olmo_hybrid: attention_bias is not served")
+        heads = cfg["num_attention_heads"]
+        lin_heads = cfg["linear_num_value_heads"]
+        if cfg["linear_num_key_heads"] != lin_heads:
+            raise ValueError(
+                "olmo_hybrid: linear_num_key_heads and linear_num_value_heads differ; "
+                "grouped value heads are not served")
+        layer_types = tuple(cfg["layer_types"])
+        if len(layer_types) != cfg["num_hidden_layers"]:
+            raise ValueError(
+                f"olmo_hybrid: {len(layer_types)} layer_types for "
+                f"{cfg['num_hidden_layers']} layers")
+        from smg_tpu.models.olmo_hybrid import period_of
+
+        period_of(layer_types)  # one period repeated, or a ValueError that says so
+        rope = dict(cfg.get("rope_parameters") or {})
+        theta = rope.pop("rope_theta", None)
+        if cfg.get("rope_theta", theta) != theta:
+            raise ValueError("olmo_hybrid: rope_theta and rope_parameters.rope_theta disagree")
+        if rope.pop("rope_type", "default") != "default" or rope:
+            raise ValueError(f"olmo_hybrid: rope_parameters {cfg['rope_parameters']} is not served")
+        eos = cfg.get("eos_token_id", 2)
+        return cls(
+            arch="olmo_hybrid",
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=cfg["num_hidden_layers"],
+            num_heads=heads,
+            num_kv_heads=cfg.get("num_key_value_heads") or heads,
+            head_dim=cfg.get("head_dim") or cfg["hidden_size"] // heads,
+            rope_theta=float(theta or 0.0),
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-6),
+            max_position_embeddings=cfg.get("max_position_embeddings", 8192),
+            tie_word_embeddings=bool(cfg.get("tie_word_embeddings", False)),
+            eos_token_ids=tuple(eos) if isinstance(eos, list) else (eos,),
+            bos_token_id=cfg.get("bos_token_id", 1),
+            dtype=dtype,
+            layer_types=layer_types,
+            linear_num_heads=lin_heads,
+            linear_key_head_dim=cfg["linear_key_head_dim"],
+            linear_value_head_dim=cfg["linear_value_head_dim"],
+            linear_conv_kernel_dim=cfg["linear_conv_kernel_dim"],
+            linear_allow_neg_eigval=bool(cfg.get("linear_allow_neg_eigval", False)),
         )
 
     @classmethod
@@ -231,6 +330,28 @@ def tiny_vlm_config() -> ModelConfig:
     )
 
 
+def tiny_olmo_hybrid_config(vocab_size: int = 512) -> ModelConfig:
+    """Tiny Olmo-Hybrid for CPU tests: two periods of two linear-attention
+    layers and one full-attention layer, state heads that fill whole 128-lane
+    tiles so the decode kernel runs in interpret mode."""
+    import dataclasses
+
+    return dataclasses.replace(
+        tiny_test_config(vocab_size),
+        arch="olmo_hybrid",
+        num_layers=6,
+        num_kv_heads=8,
+        rope_theta=0.0,
+        rms_norm_eps=1e-6,
+        layer_types=("linear_attention", "linear_attention", "full_attention") * 2,
+        linear_num_heads=4,
+        linear_key_head_dim=16,
+        linear_value_head_dim=32,
+        linear_conv_kernel_dim=4,
+        linear_allow_neg_eigval=True,
+    )
+
+
 def tiny_gemma2_config(vocab_size: int = 512) -> ModelConfig:
     """Tiny Gemma-2-style model for CPU tests: gelu MLP, (1+w) norms,
     scaled embeddings, post norms, attn/final softcaps, tied unembed."""
@@ -266,6 +387,7 @@ PRESETS = {
     "tiny-gemma2": tiny_gemma2_config,
     "tiny-moe": tiny_moe_config,
     "tiny-vlm": tiny_vlm_config,
+    "tiny-olmo-hybrid": tiny_olmo_hybrid_config,
     "llama3.2-1b": llama32_1b_config,
     "llama3-8b": llama3_8b_config,
     "llama3-70b": llama3_70b_config,

@@ -1,0 +1,225 @@
+"""Gated delta rule (Gated DeltaNet) linear attention and its causal
+depthwise convolution.
+
+One head keeps a state ``S`` of ``[dv, dk]`` floats for every sequence,
+rewritten at every token ``t``::
+
+    S_t = a_t S_{t-1} + b_t (v_t - a_t S_{t-1} k_t) k_t^T        o_t = S_t q_t
+
+with ``a_t`` in (0, 1] the decay and ``b_t`` in [0, 2] the write strength.
+Three forms of it live here:
+
+- ``gated_delta_chunked``: prefill.  Chunks of ``CHUNK`` tokens; inside a
+  chunk the updates are solved together (the WY form: one unit-triangular
+  system a chunk) and the state moves once a chunk, so a 4,096-token prompt is
+  64 dependent steps and not 4,096.
+- ``gated_delta_step``: decode in plain XLA (the CPU, interpret-free tests).
+- ``ops/pallas/linattn_decode.py``: decode as one pass over the state on the
+  chip; ``gated_delta_step`` is its specification.
+
+**State layout.**  A pool holds the state transposed and with the heads fused
+on the minor axis: ``[layers, slots, dk, H * dv]`` float32.  A ``[dv, dk]``
+block per head would put ``dk`` (96) on the 128 lanes of a TPU tile, which pads
+every row to 128 in HBM (a third more bytes to hold and to move); ``H * dv`` is
+a multiple of 128 and ``dk`` a multiple of 8, so this form holds exactly the
+floats the model has.  Slot 0 is the garbage slot, as page 0 is the garbage
+page: padded rows of a batch point at it.
+
+Everything here computes in float32 at ``highest`` matmul precision: the
+products are a few percent of a layer's arithmetic and the triangular solve
+amplifies rounding.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+CHUNK = 64
+_HI = lax.Precision.HIGHEST
+
+
+def _mm(spec: str, *xs):
+    return jnp.einsum(spec, *xs, precision=_HI, preferred_element_type=jnp.float32)
+
+
+# --------------------------------------------------------------------------
+# the convolution
+
+
+@jax.named_scope("smg.linattn.conv")
+def causal_conv(x: jnp.ndarray, tail: jnp.ndarray, weight: jnp.ndarray,
+                t_real: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Causal depthwise convolution over time with SiLU, for a chunk.
+
+    ``x`` [G, T, C] the chunk's inputs, ``tail`` [G, K-1, C] the last K-1
+    inputs before the chunk (zeros at a sequence's start), ``weight`` [K, C],
+    ``t_real`` [G] the real rows of each chunk.  Returns ``(y [G, T, C]
+    float32, new tail)``: the new tail is the last K-1 inputs up to the last
+    REAL row, so padded rows never enter it."""
+    K = weight.shape[0]
+    T = x.shape[1]
+    xf = jnp.concatenate([tail.astype(jnp.float32), x.astype(jnp.float32)], axis=1)
+    w = weight.astype(jnp.float32)
+    y = sum(xf[:, i:i + T] * w[i] for i in range(K))
+    new_tail = jax.vmap(
+        lambda row, n: lax.dynamic_slice_in_dim(row, n, K - 1, axis=0)
+    )(xf, t_real)
+    return jax.nn.silu(y), new_tail.astype(tail.dtype)
+
+
+@jax.named_scope("smg.linattn.conv")
+def conv_step(x: jnp.ndarray, tail: jnp.ndarray, weight: jnp.ndarray
+              ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """One token of ``causal_conv``: ``x`` [B, C], ``tail`` [B, K-1, C].
+    Returns ``(y [B, C] float32, new tail [B, K-1, C])``."""
+    xf = x.astype(jnp.float32)
+    window = jnp.concatenate([tail.astype(jnp.float32), xf[:, None]], axis=1)
+    y = jnp.einsum("bkc,kc->bc", window, weight.astype(jnp.float32))
+    return jax.nn.silu(y), window[:, 1:].astype(tail.dtype)
+
+
+# --------------------------------------------------------------------------
+# prefill: the chunked form
+
+
+def _unit_lower_inverse(A: jnp.ndarray) -> jnp.ndarray:
+    """``(I + A)^-1`` for strictly lower triangular ``A`` [..., C, C], by
+    forward substitution one row at a time (row i needs rows < i)."""
+    C = A.shape[-1]
+    eye = jnp.eye(C, dtype=A.dtype)
+
+    def row(i, T):
+        a = lax.dynamic_slice_in_dim(A, i, 1, axis=-2)  # [..., 1, C]
+        new = -a - _mm("...ij,...jk->...ik", a, T)
+        return lax.dynamic_update_slice_in_dim(T, new, i, axis=-2)
+
+    return lax.fori_loop(0, C, row, jnp.zeros_like(A)) + eye
+
+
+@jax.named_scope("smg.linattn.prefill")
+def gated_delta_chunked(q, k, v, g, beta, S0, chunk: int = CHUNK):
+    """The recurrence over a whole chunk of tokens.
+
+    ``q``, ``k`` [G, T, H, dk] (normalised, ``q`` scaled), ``v`` [G, T, H, dv],
+    ``g`` [G, T, H] the log of the decay (<= 0), ``beta`` [G, T, H], ``S0``
+    [G, H, dk, dv] the state before the first token.  A padded row has
+    ``beta`` 0 and ``g`` 0: it writes nothing and decays nothing.  Returns
+    ``(o [G, T, H, dv], S [G, H, dk, dv])``, float32."""
+    f32 = jnp.float32
+    G, T, H, dk = q.shape
+    dv = v.shape[-1]
+    C = min(chunk, T)
+    pad = (-T) % C
+    if pad:
+        widen = lambda x: jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
+        q, k, v, g, beta = (widen(x) for x in (q, k, v, g, beta))
+    N = (T + pad) // C
+
+    def chunks(x):  # [G, T, H, ...] -> [G, H, N, C, ...]
+        x = x.astype(f32).reshape(G, N, C, *x.shape[2:])
+        return jnp.moveaxis(x, 3, 1)
+
+    q, k, v, g, beta = (chunks(x) for x in (q, k, v, g, beta))
+    gc = jnp.cumsum(g, axis=-1)  # [G, H, N, C]
+    i = jnp.arange(C)
+    lower = i[:, None] >= i[None, :]
+    diff = gc[..., :, None] - gc[..., None, :]
+    decay = jnp.where(lower, jnp.exp(jnp.where(lower, diff, 0.0)), 0.0)  # [.., C, C]
+    kb = k * beta[..., None]
+    A = jnp.where(i[:, None] > i[None, :], _mm("...id,...jd->...ij", kb, k) * decay, 0.0)
+    Tm = _unit_lower_inverse(A)
+    k_cum = _mm("...ij,...jd->...id", Tm, kb * jnp.exp(gc)[..., None])
+    v_new = _mm("...ij,...jd->...id", Tm, v * beta[..., None])
+    qk = _mm("...id,...jd->...ij", q, k) * decay
+    q_dec = q * jnp.exp(gc)[..., None]
+    k_tail = k * jnp.exp(gc[..., -1:] - gc)[..., None]
+    last = jnp.exp(gc[..., -1])  # [G, H, N]
+
+    def step(S, xs):
+        k_cum_n, v_new_n, qk_n, q_dec_n, k_tail_n, last_n = xs
+        v_n = v_new_n - _mm("ghcd,ghde->ghce", k_cum_n, S)
+        o_n = _mm("ghcd,ghde->ghce", q_dec_n, S) + _mm("ghij,ghje->ghie", qk_n, v_n)
+        S = S * last_n[..., None, None] + _mm("ghcd,ghce->ghde", k_tail_n, v_n)
+        return S, o_n
+
+    per_chunk = lambda x: jnp.moveaxis(x, 2, 0)
+    S, o = lax.scan(step, S0.astype(f32),
+                    tuple(per_chunk(x) for x in (k_cum, v_new, qk, q_dec, k_tail, last)))
+    o = jnp.moveaxis(o, 0, 2)  # [G, H, N, C, dv]
+    o = jnp.moveaxis(o, 1, 3).reshape(G, N * C, H, dv)
+    return o[:, :T], S
+
+
+# --------------------------------------------------------------------------
+# the pools.  A row is read and written with a dynamic slice, one for each
+# sequence: written as ``pool[layer, slots]`` and ``pool.at[layer,
+# slots].set`` inside the layer scan, XLA:TPU keeps a second copy of the whole
+# pool beside the carried one (1.9 GB at 72 slots), where slices of a carried
+# buffer update in place.
+
+
+def read_state(pool: jnp.ndarray, layer, slots: jnp.ndarray) -> jnp.ndarray:
+    """Rows ``slots`` [G] of the recurrent-state pool ``[layers, slots, dk,
+    H * dv]`` in ``layer``: ``[G, dk, H * dv]``."""
+    row = lambda s: lax.dynamic_slice(pool, (layer, s, 0, 0), (1, 1, *pool.shape[2:]))[0, 0]
+    return jnp.stack([row(slots[g]) for g in range(slots.shape[0])])
+
+
+def write_state(pool: jnp.ndarray, layer, slots: jnp.ndarray, rows: jnp.ndarray) -> jnp.ndarray:
+    for g in range(slots.shape[0]):
+        pool = lax.dynamic_update_slice(pool, rows[g][None, None].astype(pool.dtype),
+                                        (layer, slots[g], 0, 0))
+    return pool
+
+
+def read_tail(pool: jnp.ndarray, layer, slots: jnp.ndarray, taps: int) -> jnp.ndarray:
+    """Rows ``slots`` [G] of the convolution pool ``[layers, slots, (K - 1) *
+    C]`` in ``layer``, as ``[G, K - 1, C]`` (``taps`` is K - 1).  A row keeps
+    its K - 1 inputs side by side on the minor axis: a ``[K - 1, C]`` block a
+    slot would pad its three rows to a tile of sixteen in HBM."""
+    row = lambda s: lax.dynamic_slice(pool, (layer, s, 0), (1, 1, pool.shape[2]))[0, 0]
+    rows = jnp.stack([row(slots[g]) for g in range(slots.shape[0])])
+    return rows.reshape(slots.shape[0], taps, -1)
+
+
+def write_tail(pool: jnp.ndarray, layer, slots: jnp.ndarray, rows: jnp.ndarray) -> jnp.ndarray:
+    flat = rows.reshape(rows.shape[0], 1, 1, -1).astype(pool.dtype)
+    for g in range(slots.shape[0]):
+        pool = lax.dynamic_update_slice(pool, flat[g], (layer, slots[g], 0))
+    return pool
+
+
+# --------------------------------------------------------------------------
+# decode: one token
+
+
+def pool_to_heads(S: jnp.ndarray, heads: int) -> jnp.ndarray:
+    """The pool's rows ``[..., dk, H * dv]`` as ``[..., H, dk, dv]``."""
+    *lead, dk, HV = S.shape
+    return jnp.moveaxis(S.reshape(*lead, dk, heads, HV // heads), -2, -3)
+
+
+def heads_to_pool(S: jnp.ndarray) -> jnp.ndarray:
+    """``[..., H, dk, dv]`` as the pool's rows ``[..., dk, H * dv]``."""
+    *lead, H, dk, dv = S.shape
+    return jnp.moveaxis(S, -3, -2).reshape(*lead, dk, H * dv)
+
+
+@jax.named_scope("smg.linattn.decode")
+def gated_delta_step(pool, layer, slots, q, k, v, alpha, beta):
+    """One token for every lane, reading and writing the pool's slots.
+
+    ``pool`` [layers, slots, dk, H * dv] float32; ``slots`` [B]; ``q``, ``k``
+    [B, H, dk]; ``v`` [B, H, dv]; ``alpha``, ``beta`` [B, H] (a lane that does
+    not run has ``alpha`` 1 and ``beta`` 0, which leaves its state bit for
+    bit).  Returns ``(o [B, H, dv] float32, pool)``."""
+    H = q.shape[1]
+    S = pool_to_heads(read_state(pool, layer, slots), H)  # [B, H, dk, dv]
+    a = alpha[..., None, None]
+    Sk = _mm("bhkv,bhk->bhv", S, k)
+    u = beta[..., None] * (v - alpha[..., None] * Sk)
+    S = a * S + k[..., :, None] * u[..., None, :]
+    o = _mm("bhkv,bhk->bhv", S, q)
+    return o, write_state(pool, layer, slots, heads_to_pool(S))
