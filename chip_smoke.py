@@ -71,8 +71,8 @@ SEED = 21
 #           prefill_extend and the final chunk is a solo prefill over a
 #           budget-long prefix (the Pallas prefill kernel on one chip)
 #   many    wave iv: budget/2 each, so two fill a step exactly and every step
-#           launches the same grouped-prefill shape; > 2048 tokens of context
-#           with > 32 lanes is where decode picks the Pallas kernel
+#           launches the same grouped-prefill shape, and the decode kernel
+#           (which one chip picks at every shape) runs its widest programs
 FULL = {"short": 32, "prefix": 1500, "tail": 32, "long": 6144,
         "many": 2048, "many_n": 40, "many_n_mesh": 12, "out": 16, "many_out": 32}
 # the rehearsal serves tiny with --max-seq-len 1024 --max-prefill-tokens 256

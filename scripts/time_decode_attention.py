@@ -3,14 +3,29 @@
 between, standalone, on the chip this process holds (ROADMAP S2):
 
     python3 scripts/time_decode_attention.py [--out chiprun_out/attn.json]
+        [--only qwen3-1.7b:16x128,olmo-hybrid-7b:16x256] [--pages-per-block N]
+        [--rehearsal]
 
 ``ops.attention.attention_decode_cached`` (XLA: gathers every lane's whole
 table) against ``ops.pallas.decode_attention.paged_attention_decode_cached``
-(streams the pages that hold tokens), each inside a scan over the layers of
-one cache at the ``qwen3-1.7b.eval`` cell's widths, at batch 8 and 16, tables
-of 64, 128 and 256 pages, lanes filled to a quarter, a half and all of the
-table.  Prints one JSON line per shape: milliseconds per layer for each.
-Refuses to run without a TPU: a CPU time is not a device time.
+(streams the pages each lane holds, in blocks), each inside a scan over the
+layers of one cache at a cell's widths:
+
+- ``qwen3-1.7b`` (the ``eval`` cell): 28 layers, 4,725 pages, 16/8 heads of
+  128; batch 8 and 16 behind tables of 64, 128 and 256 pages, batch 32 and
+  64 behind 128 and 256, and three small programs (1 x 8, 1 x 64, 4 x 32);
+- ``olmo-hybrid-7b`` (the ``gen`` cell's 4 full layers): 5,087 pages, 30/30
+  heads of 128; batch 16 behind 128 and 256 pages;
+
+lanes filled to a quarter, a half and all of the table.  Prints one JSON line
+per shape: milliseconds a layer for each (XLA once a shape: it reads the
+whole table whatever it holds), the kernel's held bytes over its time
+(GB/s), the largest difference between the two outputs on random data, and
+per (batch, table) the line through the kernel's three fills: a fixed cost
+a layer and a rate.  The dispatch rule is read off the half-full column.
+Refuses to run without a TPU: a CPU time is not a device time
+(``--rehearsal`` walks the same code at toy size with the kernel interpreted,
+to find typos before they cost chip time; its times mean nothing).
 """
 
 from __future__ import annotations
@@ -31,11 +46,20 @@ import numpy as np  # noqa: E402
 from smg_tpu.ops.attention import attention_decode_cached  # noqa: E402
 from smg_tpu.ops.pallas.decode_attention import paged_attention_decode_cached  # noqa: E402
 
-L, P, PS, H, K, D, N = 28, 4725, 16, 16, 8, 128, 8
+PS, N = 16, 8
 REPS = 5
+FILLS = (0.25, 0.5, 1.0)
+# name: layers, pages, heads, kv heads, head dim, [(batch, table widths)]
+MODELS = {
+    "qwen3-1.7b": (28, 4725, 16, 8, 128,
+                   [(1, (8, 64)), (4, (32,)), (8, (64, 128, 256)),
+                    (16, (64, 128, 256)), (32, (128, 256)), (64, (128, 256))]),
+    "olmo-hybrid-7b": (4, 5087, 30, 30, 128, [(16, (128, 256))]),
+}
+REHEARSAL = {"toy": (2, 40, 4, 2, 64, [(2, (4, 8)), (8, (8,))])}
 
 
-def columns(attend, q, kc, vc, hk_all, hv_all, tables, entry):
+def columns(attend, L, D, q, kc, vc, hk_all, hv_all, tables, entry):
     """One decode column's attention: every layer of the cache in turn."""
 
     def layer_body(h, xs):
@@ -47,48 +71,95 @@ def columns(attend, q, kc, vc, hk_all, hv_all, tables, entry):
                         (jnp.arange(L), hk_all, hv_all))[0]
 
 
+def timed(fn, *a) -> float:
+    """Seconds a call, host clock round ``block_until_ready``, after one
+    warm-up (which compiles)."""
+    fn(*a).block_until_ready()
+    t = time.perf_counter()
+    for _ in range(REPS):
+        out = fn(*a)
+    out.block_until_ready()
+    return (time.perf_counter() - t) / REPS
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--out")
+    ap.add_argument("--only", default="",
+                    help="comma-separated model:BxMP shapes (default: all)")
+    ap.add_argument("--pages-per-block", type=int, default=None,
+                    help="the kernel's block (default: its own choice)")
+    ap.add_argument("--rehearsal", action="store_true")
     args = ap.parse_args()
+    only = {s for s in args.only.split(",") if s}
     dev = jax.devices()[0]
-    if dev.platform != "tpu":
+    if dev.platform != "tpu" and not args.rehearsal:
         print(f"time_decode_attention: needs a TPU, found {dev.platform}",
               file=sys.stderr)
         return 3
-    key = jax.random.PRNGKey(0)
-    # what the cache holds does not change what the attentions cost
-    kc = jnp.full((L, P, PS, K * D), 0.01, jnp.bfloat16)
-    vc = jnp.full((L, P, PS, K * D), 0.02, jnp.bfloat16)
+    pallas = functools.partial(paged_attention_decode_cached,
+                               pages_per_block=args.pages_per_block,
+                               interpret=args.rehearsal)
     rng = np.random.default_rng(0)
     rows = []
-    for B in (8, 16):
-        q = jax.random.normal(key, (B, H, D), jnp.bfloat16)
-        side = jax.random.normal(key, (L, B, N, K * D), jnp.bfloat16)
-        for mp in (64, 128, 256):
-            tables = jnp.asarray(
-                rng.permutation(P - 1)[: B * mp].reshape(B, mp) + 1, jnp.int32)
-            for fill in (0.25, 0.5, 1.0):
-                entry = jnp.full((B,), int(fill * mp * PS) - N, jnp.int32)
-                row = {"B": B, "mp": mp, "fill": fill,
-                       "device_kind": dev.device_kind}
-                for name, attend in (("xla", attention_decode_cached),
-                                     ("pallas", paged_attention_decode_cached)):
-                    fn = jax.jit(functools.partial(columns, attend))
+    for model, (L, P, H, K, D, shapes) in (REHEARSAL if args.rehearsal else MODELS).items():
+        if only and not any(s.startswith(model + ":") for s in only):
+            continue
+        kd = K * D
+        kq, kk, kv, ks = jax.random.split(jax.random.PRNGKey(0), 4)
+        # random, so that the two outputs can be compared; what the cache
+        # holds does not change what the attentions cost
+        kc = jax.random.normal(kk, (L, P, PS, kd), jnp.bfloat16)
+        vc = jax.random.normal(kv, (L, P, PS, kd), jnp.bfloat16)
+        page_bytes = 2 * PS * kd * 2  # K and V
+        for B, widths in shapes:
+            q = jax.random.normal(kq, (B, H, D), jnp.bfloat16)
+            side = jax.random.normal(ks, (L, B, N, kd), jnp.bfloat16)
+            for mp in widths:
+                if only and f"{model}:{B}x{mp}" not in only:
+                    continue
+                # distinct pages while the pool has them
+                ids = (rng.permutation(P - 1)[: B * mp] if B * mp < P
+                       else rng.integers(0, P - 1, B * mp))
+                tables = jnp.asarray(ids.reshape(B, mp) + 1, jnp.int32)
+                fns = {name: jax.jit(functools.partial(columns, attend, L, D))
+                       for name, attend in (("xla", attention_decode_cached),
+                                            ("pallas", pallas))}
+                xla_ms = None
+                fit = []
+                for fill in FILLS:
+                    held = int(fill * mp * PS) - N
+                    entry = jnp.full((B,), held, jnp.int32)
                     a = (q, kc, vc, side, side, tables, entry)
-                    fn(*a).block_until_ready()
-                    t = time.perf_counter()
-                    for _ in range(REPS):
-                        out = fn(*a)
-                    out.block_until_ready()
-                    row[f"{name}_ms_per_layer"] = (
-                        (time.perf_counter() - t) / (REPS * L) * 1e3)
+                    if xla_ms is None:
+                        xla_ms = timed(fns["xla"], *a) / L * 1e3
+                    ms = timed(fns["pallas"], *a) / L * 1e3
+                    diff = float(jnp.max(jnp.abs(
+                        fns["pallas"](*a).astype(jnp.float32)
+                        - fns["xla"](*a).astype(jnp.float32))))
+                    held_bytes = B * -(-held // PS) * page_bytes
+                    fit.append((held_bytes, ms))
+                    row = {"model": model, "B": B, "mp": mp, "fill": fill,
+                           "xla_ms_per_layer": xla_ms, "pallas_ms_per_layer": ms,
+                           "pallas_held_gb_per_s": held_bytes / ms / 1e6,
+                           "max_abs_diff": diff,
+                           "pages_per_block": args.pages_per_block,
+                           "device_kind": dev.device_kind,
+                           "rehearsal": args.rehearsal}
+                    print(json.dumps(row), flush=True)
+                    rows.append(row)
+                slope, fixed = np.polyfit([b for b, _ in fit], [m for _, m in fit], 1)
+                row = {"model": model, "B": B, "mp": mp, "fit": True,
+                       "pallas_fixed_ms_per_layer": float(fixed),
+                       "pallas_rate_gb_per_s": float(1 / slope / 1e6),
+                       "xla_ms_per_layer": xla_ms}
                 print(json.dumps(row), flush=True)
                 rows.append(row)
-    if args.out:
-        os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
-        with open(args.out, "w") as f:
-            json.dump(rows, f, indent=1)
+                if args.out:
+                    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+                    with open(args.out, "w") as f:
+                        json.dump(rows, f, indent=1)
+        del kc, vc
     return 0
 
 

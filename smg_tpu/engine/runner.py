@@ -410,14 +410,24 @@ class ModelRunner:
         The XLA path gathers ``B*mp*ps`` tokens of KV per layer, whatever
         the lanes hold, and multiplies them where the gather left them (on
         the fused lanes; per head under a mesh that splits them, which
-        copies the gather once more); the kernel streams only the pages
-        that hold tokens.  The 131072-token crossover has no measurement on
-        record (ROADMAP S2)."""
+        copies the gather once more); the kernel streams, for each lane, the
+        pages that lane holds.  Where the kernel can run (one TPU chip, no
+        mesh, no pp, cache lanes a multiple of 128: ``_resolve_attn_impl``)
+        it is the answer at every shape: timed alone on a v5e
+        (``scripts/time_decode_attention.py``; the table is ``PERF.md``
+        7.11, PR 30) it moves held bytes at about 755 GB/s with 0.01-0.04 ms
+        a layer of fixed cost at 1 to 64 lanes, where XLA reads the whole
+        table at about 400 GB/s and four times slower once the gather's
+        result passes 64 MiB; at half-full tables it ties at 1 lane x 8
+        pages (0.01 ms either way), wins by 2.5 x at 8 x 64 and by 6.6 x at
+        16 x 256, and wins at full tables too.  ``B`` and ``mp`` stay in
+        the signature because they are what a program is compiled for and
+        what the next sweep may have to split on."""
         if self.use_pp:
             return "xla"  # pallas kernels don't run inside the pp shard_map
         if self.attn_impl != "auto":
             return self.attn_impl
-        return "pallas" if B * mp * self.spec.page_size > 131072 else "xla"
+        return "pallas"
 
     def _prefill_impl_for(self, T: int, mp: int) -> str:
         """Solo-prefill attention for one (chunk bucket, table width)

@@ -231,6 +231,29 @@ def attention_prefill_batched(
     return out.reshape(G_, T, H, D).astype(q.dtype)
 
 
+def _owns(H: int, K: int) -> jnp.ndarray:
+    """[H, K] bool: query head ``h`` reads KV head ``k`` (GQA groups of H/K)."""
+    return jnp.arange(H)[:, None] // (H // K) == jnp.arange(K)[None, :]
+
+
+def block_diagonal_query(q: jnp.ndarray, K: int) -> jnp.ndarray:
+    """``[..., H, D]`` queries on the cache's fused lanes, ``[..., H, K*D]``:
+    head ``h`` on the ``D`` lanes of its KV head, zeros elsewhere, so one
+    product with ``[.., K*D]`` keys serves all heads (the XLA decode form
+    below and ``ops/pallas/decode_attention.py``)."""
+    H, D = q.shape[-2:]
+    q_bd = jnp.where(_owns(H, K)[:, :, None], q[..., None, :], 0)
+    return q_bd.reshape(*q.shape[:-1], K * D)
+
+
+def own_lanes(out: jnp.ndarray, K: int) -> jnp.ndarray:
+    """The other way: of a product with V on the fused lanes,
+    ``[..., H, K*D]``, each head keeps the ``D`` lanes of its own KV head."""
+    H, KD = out.shape[-2:]
+    out = out.reshape(*out.shape[:-1], K, KD // K)
+    return jnp.where(_owns(H, K)[:, :, None], out, 0).sum(axis=-2)
+
+
 def _attend_cache_and_side(
     q: jnp.ndarray,  # [B, W, H, D] W query tokens per lane (post-rope)
     k_cache: jnp.ndarray,  # [L, P, ps, K*D] read-only cache (fused lanes)
@@ -296,18 +319,15 @@ def _attend_cache_and_side(
                               vals.reshape(B, n, K, D),
                               preferred_element_type=f32).reshape(B, W, H, D)
     else:
-        own = jnp.arange(H)[:, None] // G == jnp.arange(K)[None, :]  # [H, K]
-        q_bd = jnp.where(own[:, :, None], q.astype(cd)[:, :, :, None, :], 0)
-        q_bd = q_bd.reshape(B, W, H, KD)
+        q_bd = block_diagonal_query(q.astype(cd), K)  # [B, W, H, KD]
 
         def score(keys):
             return jnp.einsum("bwhl,bsl->bwhs", q_bd, keys,
                               preferred_element_type=f32)
 
         def weigh(p, vals):
-            out = jnp.einsum("bwhs,bsl->bwhl", p, vals,
-                             preferred_element_type=f32).reshape(B, W, H, K, D)
-            return jnp.where(own[:, :, None], out, 0).sum(axis=3)
+            return own_lanes(jnp.einsum("bwhs,bsl->bwhl", p, vals,
+                                        preferred_element_type=f32), K)
 
     # masks broadcast against [B, W, keys]
     j = jnp.arange(S)

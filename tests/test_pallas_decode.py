@@ -9,21 +9,24 @@ import numpy as np
 import pytest
 
 from smg_tpu.ops.attention import attention_decode_cached
-from smg_tpu.ops.pallas.decode_attention import paged_attention_decode_cached
+from smg_tpu.ops.pallas.decode_attention import (
+    _pages_per_block,
+    paged_attention_decode_cached,
+)
 
 
-def _setup(B, H, D, K, ps, mp, N, entries, P=64, seed=0):
+def _setup(B, H, D, K, ps, mp, N, entries, P=64, seed=0, dtype=jnp.float32):
     rng = np.random.default_rng(seed)
     L, layer = 3, 1
     KD = K * D
-    k_cache = jnp.asarray(rng.standard_normal((L, P, ps, KD)), jnp.float32)
-    v_cache = jnp.asarray(rng.standard_normal((L, P, ps, KD)), jnp.float32)
+    k_cache = jnp.asarray(rng.standard_normal((L, P, ps, KD)), dtype)
+    v_cache = jnp.asarray(rng.standard_normal((L, P, ps, KD)), dtype)
     # distinct pages per sequence (page 0 reserved as garbage)
     pt = rng.permutation(P - 1)[: B * mp].reshape(B, mp) + 1
     page_tables = jnp.asarray(pt, jnp.int32)
-    q = jnp.asarray(rng.standard_normal((B, H, D)), jnp.float32)
-    hk = jnp.asarray(rng.standard_normal((B, N, KD)), jnp.float32)
-    hv = jnp.asarray(rng.standard_normal((B, N, KD)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((B, H, D)), dtype)
+    hk = jnp.asarray(rng.standard_normal((B, N, KD)), dtype)
+    hv = jnp.asarray(rng.standard_normal((B, N, KD)), dtype)
     entry_positions = jnp.asarray(entries, jnp.int32)
     return q, k_cache, v_cache, hk, hv, layer, page_tables, entry_positions
 
@@ -64,10 +67,12 @@ def test_decode_parity_vs_xla(B, H, D, K, entries, n_extra, softcap, window):
                                rtol=2e-5, atol=2e-5)
 
 
-def test_decode_window_skips_out_of_window_pages():
+@pytest.mark.parametrize("pages_per_block", [None, 4, 2])
+def test_decode_window_skips_out_of_window_pages(pages_per_block):
     """With a window, pages wholly below the window must not affect the
     output — poison them with NaN and check the kernel never reads them
-    (the DMA loop starts at the window's first live page)."""
+    (the DMA loop starts at the window's first live page: blocks are
+    counted from it, wherever it lies in the table)."""
     B, H, D, K, ps, mp, N = 1, 8, 64, 8, 16, 13, 4
     entries = [150]
     window = 33  # query at 150: window covers positions 118..150 → pages 7+
@@ -86,6 +91,7 @@ def test_decode_window_skips_out_of_window_pages():
         q, jnp.asarray(kc), jnp.asarray(vc), hk, hv, jnp.int32(1), layer,
         page_tables, entry_positions, scale,
         window=jnp.int32(window), interpret=True,
+        pages_per_block=pages_per_block,
     )
     assert np.isfinite(np.asarray(got)).all()
     want = attention_decode_cached(
@@ -111,3 +117,88 @@ def test_padded_row_stays_finite():
         softcap=30.0, window=jnp.int32(24), interpret=True,
     )
     assert np.isfinite(np.asarray(got)).all()
+
+
+MP, CAP = 13, 13 * 16  # the table of the cases below; ``CAP`` marks a padded row
+BLOCK_CASES = {
+    # B, H, D, K, entries, n_extra, window, softcap, pages_per_block, dtype
+    "fewer_pages_than_a_block": (2, 8, 64, 8, [20, 37], 1, None, None, 4, jnp.float32),
+    "exactly_one_block": (2, 8, 64, 8, [64, 128], 2, None, None, 4, jnp.float32),
+    "one_token_past_a_block": (2, 8, 64, 8, [65, 129], 1, None, None, 4, jnp.float32),
+    "one_token_short_of_a_block": (2, 8, 64, 8, [63, 127], 1, None, None, 4, jnp.float32),
+    "no_page_side_rows_only": (2, 8, 64, 8, [0, 0], 3, None, None, 4, jnp.float32),
+    "one_page_a_block": (2, 8, 64, 2, [100, 37], 3, None, None, 1, jnp.float32),
+    "window_starts_inside_a_block": (2, 8, 64, 8, [200, 90], 1, 40, None, 4, jnp.float32),
+    "window_and_softcap_over_blocks": (2, 8, 64, 8, [200, 90], 2, 70, 30.0, 2, jnp.float32),
+    "three_lanes_one_empty_between": (3, 8, 64, 8, [100, 0, 37], 1, None, None, 2, jnp.float32),
+    "three_lanes_first_two_empty": (3, 8, 64, 8, [0, CAP, 50], 2, None, None, 4, jnp.float32),
+    "five_lanes_padded_and_empty": (5, 8, 64, 2, [CAP, 100, 0, 70, 16], 1, None, None, 2,
+                                    jnp.float32),
+    "five_lanes_last_empty": (5, 8, 64, 8, [33, 100, 207, 1, 0], 4, None, None, 4, jnp.float32),
+    "heads_30_of_128": (2, 30, 128, 30, [100, 37], 2, None, None, 4, jnp.float32),
+    "heads_30_of_128_default_block": (2, 30, 128, 30, [190, 65], 1, None, None, None,
+                                      jnp.float32),
+    "gqa_16_8_of_128": (2, 16, 128, 8, [129, 64], 1, None, None, 4, jnp.float32),
+    "bfloat16_cache": (2, 16, 128, 8, [200, 37], 2, None, None, 4, jnp.bfloat16),
+    "bfloat16_cache_one_block": (3, 8, 64, 8, [100, 0, 207], 1, None, None, None, jnp.bfloat16),
+    "bfloat16_heads_30_of_128": (2, 30, 128, 30, [150, 16], 3, None, None, 8, jnp.bfloat16),
+    "bfloat16_window_softcap": (2, 8, 64, 8, [200, 90], 2, 70, 30.0, 2, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", list(BLOCK_CASES))
+def test_block_loop_parity_vs_xla(case):
+    """What a loop over blocks of pages can get wrong: a short last block,
+    a block boundary, no block at all, a window that opens inside a block,
+    lanes without blocks between lanes that hand their first block on, and
+    the served dtype (bfloat16 against the XLA form in bfloat16: the same
+    operands, another order of summation)."""
+    B, H, D, K, entries, n_extra, window, softcap, n, dtype = BLOCK_CASES[case]
+    ps, N = 16, 4
+    q, k_cache, v_cache, hk, hv, layer, page_tables, entry_positions = _setup(
+        B, H, D, K, ps, MP, N, entries, P=128, dtype=dtype)
+    w = None if window is None else jnp.int32(window)
+    args = (q, k_cache, v_cache, hk, hv, jnp.int32(n_extra), layer, page_tables,
+            entry_positions, 1.0 / np.sqrt(D))
+    got = paged_attention_decode_cached(*args, softcap=softcap, window=w,
+                                        interpret=True, pages_per_block=n)
+    want = attention_decode_cached(*args, softcap=softcap, window=w)
+    assert got.dtype == want.dtype == dtype
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.isfinite(got).all()
+    real = np.asarray(entries) < CAP  # a padded row's output is nobody's
+    np.testing.assert_allclose(got[real], want[real], rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("pages_per_block", [None, 4, 1])
+def test_pages_past_entry_are_not_fetched(pages_per_block):
+    """A lane's last block fetches the pages the lane holds: poison every
+    page of the table past them and the output stays finite and equal (the
+    XLA form gathers the whole table and could not pass this)."""
+    B, H, D, K, ps, N = 3, 8, 64, 8, 16, 4
+    entries = [70, 0, 129]
+    q, k_cache, v_cache, hk, hv, layer, page_tables, entry_positions = _setup(
+        B, H, D, K, ps, MP, N, entries, P=128)
+    pt = np.asarray(page_tables)
+    kc, vc = np.array(k_cache), np.array(v_cache)
+    for b, e in enumerate(entries):
+        for i in range(-(-e // ps), MP):
+            kc[layer, pt[b, i]] = np.nan
+            vc[layer, pt[b, i]] = np.nan
+    args = (hk, hv, jnp.int32(2), layer, page_tables, entry_positions, 1.0 / np.sqrt(D))
+    got = paged_attention_decode_cached(q, jnp.asarray(kc), jnp.asarray(vc), *args,
+                                        interpret=True, pages_per_block=pages_per_block)
+    want = attention_decode_cached(q, k_cache, v_cache, *args)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("ps,lanes,itemsize,mp,want", [
+    (16, 1024, 2, 256, 16),   # qwen3-1.7b: 32 KB pages, 256 tokens a block
+    (16, 3840, 2, 256, 8),    # olmo-hybrid-7b: 122,880 B pages, 128 tokens
+    (16, 512, 2, 512, 16),    # llama3.2-1b
+    (16, 1024, 2, 8, 8),      # a table narrower than a block
+    (16, 8192, 4, 64, 2),     # a page of 512 KB
+])
+def test_pages_per_block(ps, lanes, itemsize, mp, want):
+    assert _pages_per_block(ps, lanes, itemsize, mp) == want

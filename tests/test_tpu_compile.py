@@ -65,10 +65,23 @@ class TestDispatchRule:
         assert r._prefill_impl_for(64, 512) == "pallas"
         assert r._prefill_impl_for(64, 128) == "xla"  # 2048 slots: gather is cheap
         assert r._prefill_impl_for(2 * PREFILL_KERNEL_MAX_T, 1024) == "xla"
-        # decode: the kernel past 131072 gathered tokens
+        # decode: the kernel at every shape (PERF.md 7.11: it won the whole
+        # sweep, the three shapes the old 131072-token constant split too)
         assert r._attn_impl_for(64, 256) == "pallas"
-        assert r._attn_impl_for(32, 256) == "xla"
-        assert r._attn_impl_for(8, 512) == "xla"
+        assert r._attn_impl_for(32, 256) == "pallas"
+        assert r._attn_impl_for(8, 512) == "pallas"
+        assert r._attn_impl_for(1, 8) == "pallas"
+
+    @pytest.mark.parametrize("model", ["qwen3-1.7b", "olmo-hybrid-7b"])
+    @pytest.mark.parametrize("B,mp", [(16, 128), (16, 256)])
+    def test_the_cells_decode_programs_take_the_kernel(self, model, B, mp):
+        """The two table widths the benchmark's cells decode behind, at both
+        cells' page widths (16/8 heads of 128: 32 KB a page; 30/30 heads of
+        128: 122,880 B)."""
+        heads, kv = {"qwen3-1.7b": (16, 8), "olmo-hybrid-7b": (30, 30)}[model]
+        cfg = dataclasses.replace(CFG, num_heads=heads, num_kv_heads=kv, head_dim=128)
+        assert _rule("tpu", model=cfg)._attn_impl_for(B, mp) == "pallas"
+        assert _rule("cpu", model=cfg)._attn_impl_for(B, mp) == "xla"
 
     def test_kernel_never_above_its_bound_even_when_forced(self):
         r = _rule("tpu", attention_impl="pallas")
@@ -169,13 +182,23 @@ class TestCompilesForV5e:
             s((mp,), jnp.int32), s((), jnp.int32), s((), jnp.int32),
         )
 
-    def test_decode_kernel_at_batch_64(self, v5e):
+    @pytest.mark.parametrize("B,mp,N,H,K,D", [
+        (64, 256, 8, CFG.num_heads, CFG.num_kv_heads, CFG.head_dim),
+        (64, 512, 8, 16, 8, 128),   # the widest page table in SMEM: 128 KB
+        (16, 256, 8, 30, 30, 128),  # 122,880 B pages, H not a multiple of 8
+        (16, 128, 1, 16, 8, 128),   # one side row (a K=1 frame)
+        (1, 8, 2, 16, 8, 128),      # the smallest program: a block of 8 pages
+    ], ids=["llama1b_64x256", "qwen_64x512", "olmo_hybrid_16x256", "one_side_row",
+            "smallest"])
+    def test_decode_kernel(self, v5e, B, mp, N, H, K, D):
+        """The decode kernel at the shapes ``_attn_impl_for`` now sends it:
+        every batch bucket and table width, so the corners as well."""
         s = self._sds(v5e)
-        B, mp, N, L, P = 64, 256, 8, CFG.num_layers, 1024
+        L, P, kd = 4, 1024, K * D
         _compile(
             functools.partial(paged_attention_decode_cached, scale=0.125),
-            s((B, CFG.num_heads, CFG.head_dim)), s((L, P, PS, KD)),
-            s((L, P, PS, KD)), s((B, N, KD)), s((B, N, KD)), s((), jnp.int32),
+            s((B, H, D)), s((L, P, PS, kd)), s((L, P, PS, kd)),
+            s((B, N, kd)), s((B, N, kd)), s((), jnp.int32),
             s((), jnp.int32), s((B, mp), jnp.int32), s((B,), jnp.int32),
         )
 
