@@ -1,0 +1,311 @@
+"""Are two trees the same programs?  For a change that must move no number.
+
+    python scripts/same_programs.py dump --root /path/to/tree --out a.npz
+    python scripts/same_programs.py dump --root . --out b.npz
+    python scripts/same_programs.py compare a.npz b.npz
+
+``dump`` imports ``smg_tpu`` from ``--root`` and runs, on the CPU in float32
+with seeded weights, (A) every forward of ``models/llama.py`` under ``jit``
+with the static flags the runner passes, on tiny Llama, the Gemma-style
+preset, a ``qk_norm`` config and an M-RoPE config, under XLA attention and
+under the interpreted kernels, and (B) every program family a runner
+registers (``prefill``, ``prefill_extend``, ``prefill_batched`` cold and
+warm, ``decode_multi``, ``decode_spec``, ``embed``) through the runner's own
+host API, for the same configs and ``tiny-olmo-hybrid`` (through ``Engine``).
+It keeps every output (logits, caches, tokens, logprobs) and each compiled
+program's ``cost_analysis()`` FLOPs and bytes.  ``compare`` wants the outputs
+bit-equal and the costs equal, and names what is not.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import json
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+
+def _cost(compiled) -> dict:
+    c = compiled.cost_analysis()
+    c = c[0] if isinstance(c, (list, tuple)) else c
+    return {"flops": float(c.get("flops", -1.0)),
+            "bytes": float(c.get("bytes accessed", -1.0))}
+
+
+def _configs():
+    from smg_tpu.models.config import (
+        tiny_gemma2_config, tiny_test_config, tiny_vlm_mrope_config)
+
+    tiny = tiny_test_config()
+    return {
+        "tiny": tiny,
+        "gemma": dataclasses.replace(tiny_gemma2_config(), sliding_window=24,
+                                     sliding_window_pattern=2),
+        "qk_norm": dataclasses.replace(tiny, qk_norm=True, tie_word_embeddings=True),
+        "mrope": dataclasses.replace(tiny_vlm_mrope_config(), vision=None),
+    }
+
+
+def _forwards(name, cfg, impl, out, costs):
+    """(A): the module's forwards, one jit each."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from smg_tpu.models import llama
+    from smg_tpu.ops.rope import rope_frequencies
+
+    if impl != "xla":  # the kernels want whole 128-lane tiles
+        cfg = dataclasses.replace(cfg, num_kv_heads=8)
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    # norm weights off their identity, so that a misplaced norm shows
+    params["layers"] = {
+        k: (v + 0.1 * jax.random.normal(jax.random.PRNGKey(i), v.shape, v.dtype)
+            if k.endswith("norm") else v)
+        for i, (k, v) in enumerate(sorted(params["layers"].items()))}
+    inv = jnp.asarray(rope_frequencies(cfg.head_dim, cfg.rope_theta, cfg.rope_scaling))
+    KD = cfg.num_kv_heads * cfg.head_dim
+    L, ps, P = cfg.num_layers, 16, 32
+    zeros = lambda: jnp.zeros((L, P, ps, KD), jnp.float32)
+    mrope = bool(cfg.mrope_section)
+    rng = np.random.default_rng(0)
+    toks = lambda *s: jnp.asarray(rng.integers(3, cfg.vocab_size - 20, s), jnp.int32)
+
+    def run(tag, fn, *args):
+        # smglint: disable-next=RETRACE one jit a program, each run once
+        jitted = jax.jit(fn)
+        res = jitted(*args)
+        costs[f"A/{name}/{impl}/{tag}"] = _cost(jitted.lower(*args).compile())
+        for i, r in enumerate(jax.tree.leaves(res)):
+            out[f"A/{name}/{impl}/{tag}/{i}"] = np.asarray(r)
+        return res
+
+    n_ad = 3
+    bank = {f"w{w}_{ab}": 0.05 * jax.random.normal(
+        jax.random.PRNGKey(40 + j),
+        (L, n_ad, *shape), jnp.float32)
+        for j, (w, ab, shape) in enumerate([
+            ("q", "a", (cfg.hidden_size, 4)), ("q", "b", (4, cfg.num_heads * cfg.head_dim)),
+            ("k", "a", (cfg.hidden_size, 4)), ("k", "b", (4, KD)),
+            ("v", "a", (cfg.hidden_size, 4)), ("v", "b", (4, KD)),
+            ("o", "a", (cfg.num_heads * cfg.head_dim, 4)), ("o", "b", (4, cfg.hidden_size))])}
+    gate1 = jax.nn.one_hot(1, n_ad)
+
+    # solo prefill: cold, then a chunk behind it, then with a LoRA bank
+    pt = jnp.array([1, 2, 3, 0], jnp.int32)
+    seq = toks(40)
+    rp = (jnp.stack([jnp.arange(32)] * 3) + jnp.array([[0], [1], [2]])) if mrope else None
+    kw = dict(attn_impl=impl)
+    _, kc, vc = run("prefill_cold", lambda kc, vc: llama.forward_prefill(
+        params, cfg, inv, seq[:32], jnp.int32(0), jnp.int32(27), kc, vc, pt,
+        rope_pos=rp, **kw), zeros(), zeros())
+    run("prefill_extend", lambda kc, vc: llama.forward_prefill(
+        params, cfg, inv, seq[24:40], jnp.int32(27), jnp.int32(13), kc, vc, pt,
+        rope_pos=None if rp is None else rp[:, :16] + 27, **kw), kc, vc)
+    run("prefill_all_logits", lambda kc, vc: llama.forward_prefill(
+        params, cfg, inv, seq[:32], jnp.int32(0), jnp.int32(27), kc, vc, pt,
+        all_logits=True, **kw), zeros(), zeros())
+    run("prefill_lora", lambda kc, vc: llama.forward_prefill(
+        params, cfg, inv, seq[:32], jnp.int32(0), jnp.int32(27), kc, vc, pt,
+        lora=bank, lora_gates=gate1, **kw), zeros(), zeros())
+    emb = 0.1 * jax.random.normal(jax.random.PRNGKey(9), (32, cfg.hidden_size))
+    run("prefill_embeds", lambda kc, vc: llama.forward_prefill(
+        params, cfg, inv, seq[:32], jnp.int32(0), jnp.int32(27), kc, vc, pt,
+        input_embeds=emb, embeds_mask=jnp.arange(32) % 3 == 0, **kw), zeros(), zeros())
+
+    if impl == "xla":
+        # grouped prefill has no kernel: cold, then rows behind their prefixes
+        pts = jnp.array([[1, 2, 3, 0], [4, 5, 6, 7], [0, 0, 0, 0], [8, 9, 0, 0]], jnp.int32)
+        g = toks(4, 32)
+        tr = jnp.array([32, 19, 0, 7], jnp.int32)
+        grp = None if rp is None else jnp.broadcast_to(rp[None], (4, 3, 32))
+        _, kc, vc = run("batched_cold", lambda kc, vc: llama.forward_prefill_batched(
+            params, cfg, inv, g, jnp.zeros(4, jnp.int32), tr, kc, vc, pts, no_ctx=True,
+            rope_pos=grp), zeros(), zeros())
+        g2 = toks(4, 16)
+        _, kc, vc = run("batched_warm", lambda kc, vc: llama.forward_prefill_batched(
+            params, cfg, inv, g2, tr, jnp.array([9, 16, 0, 3], jnp.int32), kc, vc, pts,
+            rope_pos=None if grp is None else grp[:, :, :16] + tr[:, None, None]), kc, vc)
+        run("batched_lora", lambda kc, vc: llama.forward_prefill_batched(
+            params, cfg, inv, g, jnp.zeros(4, jnp.int32), tr, kc, vc, pts, no_ctx=True,
+            lora=bank, lora_gates=jax.nn.one_hot(jnp.array([0, 1, 2, 0]), n_ad)),
+            zeros(), zeros())
+        entry = jnp.array([41, 35, 0, 10], jnp.int32)
+        # the verify block over what the grouped prefills left
+        run("verify", lambda kc, vc: llama.forward_verify_block(
+            params, cfg, inv, toks(4, 4), entry, kc, vc, pts,
+            rope_delta=jnp.array([2, 0, 0, 1]) if mrope else None), kc, vc)
+        run("embed", lambda t, n: llama.forward_embed(params, cfg, inv, t, n),
+            g, jnp.array([32, 19, 1, 7], jnp.int32))
+        run("train", lambda t: llama.forward_train(params, cfg, inv, t), g[:, :20])
+    else:
+        pts = jnp.array([[1, 2, 3, 0], [4, 5, 6, 7], [0, 0, 0, 0], [8, 9, 0, 0]], jnp.int32)
+        entry = jnp.array([40, 0, 0, 0], jnp.int32)
+        _, kc, vc = run("prefill_kc", lambda kc, vc: llama.forward_prefill(
+            params, cfg, inv, seq, jnp.int32(0), jnp.int32(40), kc, vc, pt, **kw),
+            zeros(), zeros())
+
+    # two columns of a horizon of 4 over the frozen cache, plain and with LoRA
+    for tag, extra in (("decode", {}), ("decode_lora", dict(
+            lora=bank, lora_gates=jax.nn.one_hot(jnp.array([1, 0, 2, 0]), n_ad)))):
+        hk = jnp.zeros((L, 4, 4, KD), jnp.float32)
+        hv = jnp.zeros_like(hk)
+        cur = toks(4)
+        for j in range(2):
+            lo, hk, hv = run(f"{tag}{j}", lambda cur, hk, hv, j=j: llama.forward_decode_horizon(
+                params, cfg, inv, cur, entry + j, entry, jnp.int32(j), kc, vc, pts, hk, hv,
+                rope_delta=jnp.array([2, 0, 0, 1]) if mrope else None, **kw, **extra),
+                cur, hk, hv)
+            cur = jnp.argmax(lo, -1).astype(jnp.int32)
+
+
+def _engine_config(cfg, impl, **kw):
+    from smg_tpu.engine.config import CacheConfig, EngineConfig, SchedulerConfig
+
+    return EngineConfig(
+        model=cfg,
+        cache=CacheConfig(page_size=16, num_pages=64, auto_size=False, dtype="float32"),
+        scheduler=SchedulerConfig(
+            max_batch_size=4, max_seq_len=128, max_prefill_tokens=64,
+            prefill_token_buckets=(32, 64), decode_batch_buckets=(4,), **kw),
+        dtype="float32", attention_impl=impl,
+    )
+
+
+def _program_costs(runner, prefix, costs):
+    for key, rec in runner._programs._records.items():
+        if rec.last_specs is not None:
+            costs[f"{prefix}/{key!r}"] = _cost(rec.fn.lower(*rec.last_specs).compile())
+
+
+def _runner_programs(name, cfg, impl, out, costs):
+    """(B): the programs a ``ModelRunner`` registers, through its host API."""
+    import numpy as np
+
+    from smg_tpu.engine.runner import ModelRunner
+
+    if impl != "xla":
+        cfg = dataclasses.replace(cfg, num_kv_heads=8)
+    r = ModelRunner(_engine_config(cfg, impl))
+    r._programs.arm()
+    pre = f"B/{name}/{impl}"
+    mpz = r.max_pages_per_seq
+    rng = np.random.default_rng(1)
+    ids = lambda n: [int(t) for t in rng.integers(3, cfg.vocab_size - 20, n)]
+    row = lambda *pages: np.array(list(pages) + [0] * (mpz - len(pages)), np.int32)
+    s4 = (np.zeros(4, np.float32), np.full(4, -1, np.int32), np.ones(4, np.float32),
+          np.zeros(4, np.float32))
+
+    out[f"{pre}/prefill"] = np.array(r.prefill(ids(20), 0, row(1, 2), 0.0, -1, 1.0, 0.0))
+    r.prefill_extend(ids(32), 0, row(3, 4, 5))
+    out[f"{pre}/prefill_after_extend"] = np.array(
+        r.prefill(ids(9), 32, row(3, 4, 5), 0.0, -1, 1.0, 0.0))
+    cold = [(ids(30), 0, row(6, 7)), (ids(11), 0, row(8)), (ids(17), 0, row(9, 10))]
+    s3 = tuple(x[:3] for x in s4)
+    out[f"{pre}/batched_cold"] = np.stack(r.prefill_batched(cold, *s3))
+    warm = [(ids(5), 20, row(1, 2)), (ids(13), 17, row(9, 10)), (ids(2), 30, row(6, 7))]
+    out[f"{pre}/batched_warm"] = np.stack(r.prefill_batched(warm, *s3))
+    tables = np.stack([row(1, 2), row(9, 10), row(6, 7), row()])
+    pos = np.array([25, 30, 32, 0], np.int32)
+    cur = np.array(ids(4), np.int32)
+    for n in (1, 4):
+        t, lp = r.decode_multi(cur, pos, tables, *s4, num_steps=n)
+        out[f"{pre}/decode_multi{n}"] = np.stack([t.astype(np.float64), lp])
+        pos = pos + n
+    if hasattr(r, "decode_spec_async") and impl == "xla":
+        block = np.array([ids(4) for _ in range(4)], np.int32)
+        em, ne, lp = r.decode_spec_async(block, np.array([3, 1, 0, 0], np.int32), pos,
+                                         tables, *s4)
+        out[f"{pre}/decode_spec"] = np.concatenate(
+            [np.asarray(em, np.float64), np.asarray(lp), np.asarray(ne, np.float64)[:, None]], 1)
+        out[f"{pre}/embed"] = r.embed([ids(12), ids(31), ids(3)])
+    out[f"{pre}/k_cache"] = np.asarray(r.k_cache)
+    _program_costs(r, pre, costs)
+
+
+def _engine_programs(name, cfg, impl, out, costs):
+    """(B) for a model whose runner keeps state per sequence: through
+    ``Engine``, greedy, one prompt long enough to be cut into chunks."""
+    import numpy as np
+
+    from smg_tpu.engine.engine import Engine
+    from smg_tpu.protocols.sampling import SamplingParams
+
+    eng = Engine(_engine_config(cfg, impl, decode_horizon=4))
+    eng.runner._programs.arm()
+    pre = f"B/{name}/{impl}"
+    sp = SamplingParams(temperature=0.0, max_new_tokens=9, ignore_eos=True)
+    for i, n in enumerate((20, 100, 45)):
+        res = eng.generate(prompt_ids=list(range(5 + i, 5 + i + n)), sampling=sp)
+        out[f"{pre}/generate{n}"] = np.asarray(res.token_ids)
+    out[f"{pre}/k_cache"] = np.asarray(eng.runner.k_cache)
+    if hasattr(eng.runner, "s_pool"):
+        out[f"{pre}/s_pool"] = np.asarray(eng.runner.s_pool)
+    _program_costs(eng.runner, pre, costs)
+
+
+def dump(root: str, path: str) -> None:
+    sys.path.insert(0, os.path.abspath(root))
+    import numpy as np
+
+    from smg_tpu.models.config import tiny_olmo_hybrid_config
+
+    out: dict = {}
+    costs: dict = {}
+    for name, cfg in _configs().items():
+        for impl in ("xla", "pallas_interpret"):
+            _forwards(name, cfg, impl, out, costs)
+            if name != "mrope":  # the runner takes M-RoPE ids with a vision tower only
+                _runner_programs(name, cfg, impl, out, costs)
+        _engine_programs(name, cfg, "xla", out, costs)
+    for impl in ("xla", "pallas_interpret"):
+        _engine_programs("olmo_hybrid", tiny_olmo_hybrid_config(), impl, out, costs)
+    np.savez_compressed(path, __costs__=np.array(json.dumps(costs)), **out)
+    print(f"{len(out)} outputs, {len(costs)} compiled programs -> {path}")
+
+
+def compare(a: str, b: str) -> int:
+    import numpy as np
+
+    A, B = np.load(a), np.load(b)
+    ca, cb = json.loads(str(A["__costs__"])), json.loads(str(B["__costs__"]))
+    bad = []
+    for k in sorted((set(A.files) | set(B.files)) - {"__costs__"}):
+        if k not in A.files or k not in B.files:
+            bad.append(f"output only on one side: {k}")
+        elif A[k].shape != B[k].shape or not np.array_equal(A[k], B[k], equal_nan=True):
+            d = (float(np.max(np.abs(A[k].astype(np.float64) - B[k])))
+                 if A[k].shape == B[k].shape else "shape")
+            bad.append(f"output differs: {k} (max abs {d})")
+    for k in sorted(set(ca) | set(cb)):
+        if ca.get(k) != cb.get(k):
+            bad.append(f"cost differs: {k}: {ca.get(k)} != {cb.get(k)}")
+    n_out = len(set(A.files) & set(B.files)) - 1
+    print(f"{n_out} outputs and {len(set(ca) & set(cb))} programs compared; "
+          f"{len(bad)} differ")
+    for line in bad:
+        print("  " + line)
+    return 1 if bad else 0
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    sub = ap.add_subparsers(dest="cmd", required=True)
+    d = sub.add_parser("dump")
+    d.add_argument("--root", default=".")
+    d.add_argument("--out", required=True)
+    c = sub.add_parser("compare")
+    c.add_argument("a")
+    c.add_argument("b")
+    args = ap.parse_args()
+    if args.cmd == "dump":
+        dump(args.root, args.out)
+        return 0
+    return compare(args.a, args.b)
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -11,7 +11,7 @@ For every jit site carrying ``donate_argnums`` the rule resolves the
 donated positions (literal tuples, or the union of literal assignments to
 a policy variable like ``donate = (4, 5) ... donate = ()``), finds the
 dispatch call sites — immediate invocation, a local ``fn = jax.jit(...)``
-then ``fn(...)``, or the runner's factory shape (``fn = self._decode_fn(...)``
+then ``fn(...)``, or the runner's factory shape (``fn = self._decode_multi_fn(...)``
 resolved through the defining class), including ``fn(*args)`` against a
 literal ``args = [...]`` prefix — and maps donated positions back to the
 caller's argument expressions.  It flags:

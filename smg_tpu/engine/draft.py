@@ -25,7 +25,6 @@ overlaps the target model's device pass.
 
 from __future__ import annotations
 
-import math
 from functools import partial
 
 import jax
@@ -33,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from smg_tpu.models.registry import get_model
+from smg_tpu.ops.attention import land_side_buffers
 from smg_tpu.ops.rope import rope_frequencies
 from smg_tpu.utils import get_logger
 
@@ -103,18 +103,24 @@ class DraftRunner:
         module = self.module
 
         def step(params, inv_freq, token, position, kc, vc, page_table):
-            def body(carry, _):
-                tok, pos, kc, vc = carry
-                logits, kc, vc = module.forward_decode(
-                    params, cfg, inv_freq, tok[None], pos[None], kc, vc,
-                    page_table[None],
+            # the server's decode frame at one lane: k columns over side
+            # buffers against the frozen cache, landed in one scatter
+            entry, tables = position[None], page_table[None]
+            side = jnp.zeros((kc.shape[0], 1, k, kc.shape[-1]), kc.dtype)
+
+            def body(carry, j):
+                tok, hk, hv = carry
+                logits, hk, hv = module.forward_decode_horizon(
+                    params, cfg, inv_freq, tok[None], entry + j, entry, j,
+                    kc, vc, tables, hk, hv,
                 )
                 nxt = jnp.argmax(logits[0], axis=-1).astype(jnp.int32)
-                return (nxt, pos + 1, kc, vc), nxt
+                return (nxt, hk, hv), nxt
 
-            (_, _, kc, vc), drafts = jax.lax.scan(
-                body, (token, position, kc, vc), None, length=k
+            (_, hk, hv), drafts = jax.lax.scan(
+                body, (token, side, side), jnp.arange(k)
             )
+            kc, vc = land_side_buffers(kc, vc, hk, hv, tables, entry, True)
             return drafts, kc, vc
 
         fn = jax.jit(step, donate_argnums=(4, 5))

@@ -38,7 +38,7 @@ from smg_tpu.engine.runner import (
     _pick_sampler,
     logger,
 )
-from smg_tpu.ops.attention import scatter_kv_rows
+from smg_tpu.ops.attention import land_side_buffers
 
 
 class RecurrentModelRunner(ModelRunner):
@@ -134,9 +134,6 @@ class RecurrentModelRunner(ModelRunner):
     def _decode_spec_fn(self, *_a, **_k):
         raise ValueError(self.module.SERVING_LIMITS["speculative"])
 
-    def _decode_fn(self, *_a, **_k):
-        raise ValueError("olmo_hybrid decodes through decode_multi only")
-
     # ---- programs ----
 
     def _plain(self, what: str, **flags) -> None:
@@ -218,7 +215,6 @@ class RecurrentModelRunner(ModelRunner):
         if k in self._compiled:
             return self._compiled[k]
         cfg, module = self.model_cfg, self.module
-        ps = self.spec.page_size
         KD = cfg.num_kv_heads * cfg.head_dim
         L = cfg.num_cache_layers
         from smg_tpu.engine.sampling import apply_penalties
@@ -278,14 +274,8 @@ class RecurrentModelRunner(ModelRunner):
                     jnp.zeros((B, N), jnp.float32), hk0, hv0, counts0, done0, sp, cp)
             (steps_run, _cur, outs, lps, hk, hv, counts, done, sp, cp) = \
                 lax.while_loop(cond, body, init)
-            total = mp * ps
-            pos = entry_pos[:, None] + jnp.arange(N)[None, :]
-            valid = (pos < total) & (jnp.arange(N)[None, :] < steps_run)
-            pos_c = jnp.minimum(pos, total - 1)
-            page = jnp.take_along_axis(page_tables, pos_c // ps, axis=1)
-            dest = jnp.where(valid, page * ps + pos_c % ps, 0).reshape(-1)
-            kc, vc = scatter_kv_rows(
-                kc, vc, hk.reshape(L, B * N, KD), hv.reshape(L, B * N, KD), dest)
+            kc, vc = land_side_buffers(kc, vc, hk, hv, page_tables, entry_pos,
+                                       jnp.arange(N)[None, :] < steps_run)
             clean = chain & ~jnp.any(done & live) if use_stop else chain
             out = (outs, lps, steps_run, kc, vc, sp, cp, clean)
             if use_pen:

@@ -29,7 +29,7 @@ from smg_tpu.engine.sampling import apply_penalties
 from smg_tpu.engine.sampling import sample_tokens as _sample_fast
 from smg_tpu.engine.sampling import sample_tokens_exact as _sample_exact
 from smg_tpu.models.registry import get_model
-from smg_tpu.ops.attention import scatter_kv_rows
+from smg_tpu.ops.attention import land_side_buffers
 from smg_tpu.ops.rope import rope_frequencies
 from smg_tpu.parallel.mesh import build_mesh
 from smg_tpu.parallel.sharding import (
@@ -1042,7 +1042,6 @@ class ModelRunner:
             return self._compiled[k]
         cfg = self.model_cfg
         module = self.module
-        ps = self.spec.page_size
         KD = cfg.num_kv_heads * cfg.head_dim
         L = cfg.num_layers
         mesh, rules = self.mesh, self.rules
@@ -1144,14 +1143,9 @@ class ModelRunner:
             # land the whole horizon into the donated cache in one scatter;
             # uncomputed columns (early exit / n_steps < N) and positions
             # past the table go to the reserved garbage page
-            total = mp * ps
-            pos = entry_pos[:, None] + jnp.arange(N)[None, :]  # [B, N]
-            valid = (pos < total) & (jnp.arange(N)[None, :] < steps_run)
-            pos_c = jnp.minimum(pos, total - 1)
-            page = jnp.take_along_axis(page_tables, pos_c // ps, axis=1)
-            dest = jnp.where(valid, page * ps + pos_c % ps, 0).reshape(-1)  # [B*N]
-            kc, vc = scatter_kv_rows(
-                kc, vc, hk.reshape(L, B * N, KD), hv.reshape(L, B * N, KD), dest
+            kc, vc = land_side_buffers(
+                kc, vc, hk, hv, page_tables, entry_pos,
+                jnp.arange(N)[None, :] < steps_run,
             )
             if use_pen:
                 counts_buf = counts_buf.at[slot_idx].set(counts)
@@ -1339,37 +1333,6 @@ class ModelRunner:
         n = int(steps)
         return toks[:, :n], lps[:, :n]
 
-    def _decode_fn(self, B: int, mp: int):
-        k = ("decode", B, mp)
-        if k in self._compiled:
-            return self._compiled[k]
-        cfg = self.model_cfg
-        module = self.module
-
-        def step(params, inv_freq, tokens, positions, kc, vc, page_tables,
-                 key, temps, topks, topps, minps):
-            logits, kc, vc = module.forward_decode(
-                params, cfg, inv_freq, tokens, positions, kc, vc, page_tables
-            )
-            toks, lps = _pick_sampler()(logits, key, temps, topks, topps, minps)
-            return toks, lps, kc, vc
-
-        if self.mesh is not None:
-            r = self._replicated
-            in_sh = (self.param_shardings, r, r, r,
-                     self.kv_sharding, self.kv_sharding, r, r, r, r, r, r)
-            fn = jax.jit(
-                step,
-                in_shardings=in_sh,
-                out_shardings=(r, r, self.kv_sharding, self.kv_sharding),
-                donate_argnums=(4, 5),
-            )
-        else:
-            in_sh = None
-            fn = jax.jit(step, donate_argnums=(4, 5))
-        return self._register(k, fn, donate=(4, 5), in_shardings=in_sh,
-                              attn="xla")
-
     # ---- host-facing API ----
 
     def _chunk_bucket(self, n_tokens: int) -> int:
@@ -1423,7 +1386,12 @@ class ModelRunner:
             up(t, jnp.int32),
             self.k_cache,
             self.v_cache,
-            up(page_table, jnp.int32),
+            # a COPY of the row: the caller hands a view of the scheduler's
+            # table, the CPU client may alias host memory instead of copying
+            # it, and ``prefill_extend`` returns before the program has run;
+            # a preemption that zeroes the row in the same step would send
+            # the chunk's KV to the garbage page
+            up(np.array(page_table, np.int32)),
         ]
         tail_args = []
         if use_lora:
@@ -1596,9 +1564,6 @@ class ModelRunner:
             return self._compiled[k]
         cfg = self.model_cfg
         module = self.module
-        ps = self.spec.page_size
-        KD = cfg.num_kv_heads * cfg.head_dim
-        L = cfg.num_layers
 
         def spec(params, inv_freq, tokens, draft_n, entry_pos, kc, vc,
                  page_tables, base_key, step0, temps, topks, topps, minps,
@@ -1659,14 +1624,8 @@ class ModelRunner:
             # already-committed y0, c>=1 iff the draft was accepted.  Every
             # rejected column and every out-of-table position masks to the
             # garbage page, so a bad draft can never poison a real slot.
-            total = mp * ps
-            pos = entry_pos[:, None] + jnp.arange(W)[None, :]  # [B, W]
-            valid = (c <= n_acc[:, None]) & (pos < total)
-            pos_c = jnp.minimum(pos, total - 1)
-            page = jnp.take_along_axis(page_tables, pos_c // ps, axis=1)
-            dest = jnp.where(valid, page * ps + pos_c % ps, 0).reshape(-1)
-            kc, vc = scatter_kv_rows(
-                kc, vc, bk.reshape(L, B * W, KD), bv.reshape(L, B * W, KD), dest
+            kc, vc = land_side_buffers(
+                kc, vc, bk, bv, page_tables, entry_pos, c <= n_acc[:, None]
             )
             return emitted, n_emit, lps, kc, vc
 
@@ -1732,35 +1691,6 @@ class ModelRunner:
             args.append(_dev(rope_delta, jnp.int32, up))
         emitted, n_emit, lps, self.k_cache, self.v_cache = fn(*args)
         return emitted, n_emit, lps
-
-    def decode(
-        self,
-        tokens: np.ndarray,  # [B] int32
-        positions: np.ndarray,  # [B] int32
-        page_tables: np.ndarray,  # [B, mp] int32
-        temps: np.ndarray,
-        topks: np.ndarray,
-        topps: np.ndarray,
-        minps: np.ndarray,
-    ) -> tuple[np.ndarray, np.ndarray]:
-        B, mp = page_tables.shape
-        fn = self._decode_fn(B, mp)
-        toks, lps, self.k_cache, self.v_cache = fn(
-            self.params,
-            self.inv_freq,
-            jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(positions, jnp.int32),
-            self.k_cache,
-            self.v_cache,
-            jnp.asarray(page_tables, jnp.int32),
-            self._next_key(),
-            jnp.asarray(temps, jnp.float32),
-            jnp.asarray(topks, jnp.int32),
-            jnp.asarray(topps, jnp.float32),
-            jnp.asarray(minps, jnp.float32),
-        )
-        toks, lps = jax.device_get((toks, lps))  # intended blocking fetch
-        return toks, lps
 
     @property
     def kv_transfer(self):

@@ -17,6 +17,7 @@ SGLang/vLLM workers (SURVEY.md §0); the in-tree engine replaces that layer.
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Any
 
 import jax
@@ -24,17 +25,17 @@ import jax.numpy as jnp
 
 from smg_tpu.models.config import ModelConfig
 from smg_tpu.ops.attention import (
-    attention_decode,
     attention_decode_cached,
     attention_prefill,
     attention_prefill_batched,
     attention_verify_block,
     gather_layer_pages,
     gather_seq_kv,
+    page_slots,
     scatter_kv_pages_full,
 )
 from smg_tpu.ops.norms import rms_norm
-from smg_tpu.ops.rope import apply_rope
+from smg_tpu.ops.rope import apply_mrope, apply_rope
 
 Params = dict[str, Any]
 
@@ -249,14 +250,6 @@ def _attn_out(layer: Params, attn: jnp.ndarray, lora: Params | None = None,
     return o
 
 
-
-def _scan_xs(layers, lora, num_layers):
-    """Layer-scan xs: ``(layer, lora_layer, index)`` when a LoRA bank rides
-    along, else ``(layer, index)`` — shared by the plain scans here and the
-    pp shard_map bodies (``parallel/pp_serving.py``)."""
-    idx = jnp.arange(num_layers)
-    return (layers, lora, idx) if lora is not None else (layers, idx)
-
 def _mlp(layer: Params, h: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     if "router" in layer:
         return _moe_mlp(layer, h, cfg)
@@ -287,6 +280,100 @@ def _moe_mlp(layer: Params, h: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     y = jnp.einsum("...xf,xfe->...xe", jax.nn.silu(g) * u, layer["w_down"])
     out = jnp.einsum("...xe,...x->...e", y.astype(jnp.float32), gates)
     return out.astype(h.dtype)
+
+
+# --------------------------------------------------------------------------
+# the decoder layer.  Every forward below runs this one block; what differs
+# between them is how a layer's queries meet the keys and values its sequence
+# holds, so each forward passes that in: ``rotate(q, k)`` applies its
+# positions, ``attend(q, k, v, l, state)`` puts the new K and V where that
+# forward keeps them (the paged cache, a frame's side buffers, nowhere) and
+# returns the attention's output with the state it changed.  ``state`` is
+# whatever the forward threads through the layer scan besides ``h``.
+
+
+def decoder_block(cfg: ModelConfig, rotate, attend, lora_gates, carry, xs):
+    """One decoder layer as a ``lax.scan`` step: ``carry`` is ``(h, *state)``,
+    ``xs`` the triple of ``_scan_xs``.  ``l`` indexes the layer in ``state``
+    (stage-LOCAL under pp)."""
+    h, *state = carry
+    layer, lora, l = xs
+    q, k, v = _qkv(layer, cfg, _norm(h, layer["attn_norm"], cfg), lora, lora_gates)
+    q, k = rotate(q, k)
+    attn, state = attend(q, k, v, l, tuple(state))
+    h = _attn_residual(h, layer, attn, cfg, lora, lora_gates)
+    return (_mlp_residual(h, layer, cfg), *state), None
+
+
+def _scan_xs(layers, lora, num_layers):
+    """Layer-scan xs ``(layer, lora_layer, index)``; without a LoRA bank
+    ``lora`` is None, an empty pytree — shared by the plain scans here and
+    the pp shard_map body (``parallel/pp_serving.py``)."""
+    return layers, lora, jnp.arange(num_layers)
+
+
+def _scan_layers(make_body, consts, carry, layers, lora=None, pp_mesh=None,
+                 frozen=()):
+    """Run ``make_body(*consts, *frozen)``'s block over the layer stack and
+    return the carry.  The factory, not the block, is what the forwards
+    hand over: pp runs it under shard_map with per-stage consts (everything
+    data-dependent rides the consts tuple so the block never closes over an
+    outer tracer); the plain path calls it once with the outer tracers."""
+    if pp_mesh is not None:
+        from smg_tpu.parallel.pp_serving import pp_serving_scan
+
+        return pp_serving_scan(pp_mesh, make_body, *carry, layers, consts,
+                               lora=lora, frozen=frozen)
+    L = jax.tree.leaves(layers)[0].shape[0]
+    return jax.lax.scan(make_body(*consts, *frozen), carry,
+                        _scan_xs(layers, lora, L))[0]
+
+
+def _scale(cfg: ModelConfig) -> float:
+    return cfg.query_scale or 1.0 / math.sqrt(cfg.head_dim)
+
+
+def _rotary(cfg: ModelConfig, inv_freq, pos, rope_pos=None):
+    """``rotate(q, k)`` for ``[..., T, heads, D]`` at positions ``pos``
+    ``[..., T]``.  Under M-RoPE the 3-axis ids ``rope_pos`` rotate sectioned
+    frequencies; masks and cache destinations keep the sequential ``pos``."""
+    if rope_pos is not None:
+        rot = lambda x: apply_mrope(x, rope_pos, inv_freq, cfg.mrope_section)
+    else:
+        rot = lambda x: apply_rope(x, pos, inv_freq)
+    return lambda q, k: (rot(q), rot(k))
+
+
+def _write_side(side, k, v, l, col):
+    """Put a layer's new rows ``k``, ``v`` ``[B, (n,) K, D]`` into the side
+    buffers ``[L, B, N, K*D]`` from column ``col`` on.  Returns layer ``l``'s
+    two buffers and the updated pair."""
+    B = k.shape[0]
+
+    def put(buf, x):
+        x = x.reshape(1, B, -1, buf.shape[-1]).astype(buf.dtype)
+        return jax.lax.dynamic_update_slice(buf, x, (l, 0, col, 0))
+
+    sk, sv = put(side[0], k), put(side[1], v)
+    row = lambda buf: jax.lax.dynamic_index_in_dim(buf, l, 0, keepdims=False)
+    return row(sk), row(sv), (sk, sv)
+
+
+def _dense_attention(q, k, v, mask, cfg: ModelConfig):
+    """Causal softmax attention with no cache: ``q`` [B, T, H, D], ``k`` and
+    ``v`` [B, T, K, D], ``mask`` [B or 1, T, T] true where a query may look.
+    No per-layer window (its callers bound their lengths to it)."""
+    B, T, H, D = q.shape
+    K = cfg.num_kv_heads
+    qf = q.astype(jnp.float32).reshape(B, T, K, H // K, D)
+    scores = jnp.einsum("btkgd,bskd->bkgts", qf, k.astype(jnp.float32)) * _scale(cfg)
+    if cfg.attn_logit_softcap:
+        c = cfg.attn_logit_softcap
+        scores = c * jnp.tanh(scores / c)
+    scores = jnp.where(mask[:, None, None], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1)
+    attn = jnp.einsum("bkgts,bskd->btkgd", probs, v.astype(jnp.float32))
+    return attn.reshape(B, T, H, D).astype(q.dtype)
 
 
 def forward_prefill(
@@ -324,13 +411,12 @@ def forward_prefill(
     T = tokens.shape[0]
     if lora is not None:
         lora_gates = jnp.broadcast_to(lora_gates, (T, lora_gates.shape[-1]))
-    ps = k_cache.shape[2]
-    mp = page_table.shape[0]
-    scale = cfg.query_scale or 1.0 / math.sqrt(cfg.head_dim)
+    scale = _scale(cfg)
 
+    ps, mp = k_cache.shape[2], page_table.shape[0]
     pos = prefix_len + jnp.arange(T)  # [T]
-    # padded rows and out-of-range positions write to the garbage page (0);
-    # clamping instead would clobber a real slot
+    # ``page_slots`` for the one table, indexed directly: padded rows and
+    # out-of-range positions write to the garbage page (0)
     valid = (jnp.arange(T) < t_real) & (pos < mp * ps)
     pos_c = jnp.minimum(pos, mp * ps - 1)
     dest = jnp.where(valid, page_table[pos_c // ps] * ps + pos_c % ps, 0)
@@ -344,30 +430,9 @@ def forward_prefill(
 
     def make_body(pos, dest, page_table, ctx_len, inv_freq, rope_pos,
                   lora_gates):
-        """Layer-body factory: pp runs it under shard_map with per-stage
-        consts (everything data-dependent rides the consts tuple so the
-        body never closes over an outer tracer), the plain path calls it
-        once with the outer tracers."""
-
-        def layer_body(carry, xs):
-            h, k_cache, v_cache = carry
-            if lora is not None:
-                layer, lor, l = xs
-            else:
-                (layer, l), lor = xs, None
-            hn = _norm(h, layer["attn_norm"], cfg)
-            q, k, v = _qkv(layer, cfg, hn, lor, lora_gates)
-            if rope_pos is not None:
-                # M-RoPE: 3-axis ids rotate sectioned frequencies; masking
-                # and cache destinations keep the sequential ``pos``
-                from smg_tpu.ops.rope import apply_mrope
-
-                q = apply_mrope(q, rope_pos, inv_freq, cfg.mrope_section)
-                k = apply_mrope(k, rope_pos, inv_freq, cfg.mrope_section)
-            else:
-                q = apply_rope(q, pos, inv_freq)
-                k = apply_rope(k, pos, inv_freq)
-            k_cache, v_cache = scatter_kv_pages_full(k_cache, v_cache, l, k, v, dest)
+        def attend(q, k, v, l, caches):
+            """Scatter the chunk into its pages, then attend over them."""
+            k_cache, v_cache = scatter_kv_pages_full(*caches, l, k, v, dest)
             if sp_mesh is not None:
                 from smg_tpu.parallel.ring_attention import ring_attention
 
@@ -391,27 +456,15 @@ def forward_prefill(
                 attn = attention_prefill(q, k_ctx, v_ctx, pos, ctx_len, scale,
                                          softcap=cfg.attn_logit_softcap,
                                          window=_layer_window(cfg, l))
-            h = _attn_residual(h, layer, attn, cfg, lor, lora_gates)
-            h = _mlp_residual(h, layer, cfg)
-            return (h, k_cache, v_cache), None
+            return attn, (k_cache, v_cache)
 
-        return layer_body
+        return partial(decoder_block, cfg, _rotary(cfg, inv_freq, pos, rope_pos),
+                       attend, lora_gates)
 
-    if pp_mesh is not None:
-        from smg_tpu.parallel.pp_serving import pp_serving_scan
-
-        h, k_cache, v_cache = pp_serving_scan(
-            pp_mesh, make_body, h, k_cache, v_cache, params["layers"],
-            (pos, dest, page_table, ctx_len, inv_freq, rope_pos, lora_gates),
-            lora=lora,
-        )
-    else:
-        xs = _scan_xs(params["layers"], lora, cfg.num_layers)
-        (h, k_cache, v_cache), _ = jax.lax.scan(
-            make_body(pos, dest, page_table, ctx_len, inv_freq, rope_pos,
-                      lora_gates),
-            (h, k_cache, v_cache), xs,
-        )
+    h, k_cache, v_cache = _scan_layers(
+        make_body, (pos, dest, page_table, ctx_len, inv_freq, rope_pos, lora_gates),
+        (h, k_cache, v_cache), params["layers"], lora, pp_mesh,
+    )
     if all_logits:
         # speculative verify: every chunk position's next-token distribution
         # in one MXU-friendly pass (ops/speculative.py)
@@ -420,64 +473,6 @@ def forward_prefill(
         h, jnp.maximum(t_real - 1, 0)[None, None].astype(jnp.int32), axis=0
     )[0]
     logits = unembed(params, cfg, last)
-    return logits, k_cache, v_cache
-
-
-def forward_decode(
-    params: Params,
-    cfg: ModelConfig,
-    inv_freq: jnp.ndarray,
-    tokens: jnp.ndarray,  # [B] one token per slot
-    positions: jnp.ndarray,  # [B] position of that token (= ctx_len - 1)
-    k_cache: jnp.ndarray,  # [L, P, ps, K*D] (fused lane layout)
-    v_cache: jnp.ndarray,
-    page_tables: jnp.ndarray,  # [B, mp]; inactive rows all-zero -> garbage page
-    lora: Params | None = None,
-    lora_gates: jnp.ndarray | None = None,  # [B, N] one-hot per slot
-):
-    """One decode step for the whole batch (compat path: XLA attention only —
-    the serving hot path is ``forward_decode_horizon``); returns
-    (logits [B, V], caches)."""
-    B = tokens.shape[0]
-    ps = k_cache.shape[2]
-    mp = page_tables.shape[1]
-    scale = cfg.query_scale or 1.0 / math.sqrt(cfg.head_dim)
-
-    # out-of-range positions (e.g. decode horizon overshooting a finished
-    # sequence) write to the garbage page instead of clobbering a real slot
-    valid = positions < mp * ps
-    pos_c = jnp.minimum(positions, mp * ps - 1)
-    page = jnp.take_along_axis(page_tables, (pos_c // ps)[:, None], axis=1)[:, 0]
-    dest = jnp.where(valid, page * ps + pos_c % ps, 0)
-
-    h = embed_tokens(params, cfg, tokens)  # [B, E]
-
-    # The full stacked cache rides the scan carry and is updated with
-    # layer-indexed scatters — per-layer slice-out/stack-back would copy the
-    # whole cache every step.
-    def layer_body(carry, xs):
-        h, k_cache, v_cache = carry
-        if lora is not None:
-            layer, lor, l = xs
-        else:
-            (layer, l), lor = xs, None
-        hn = _norm(h, layer["attn_norm"], cfg)
-        q, k, v = _qkv(layer, cfg, hn, lor, lora_gates)  # q: [B, H, D]
-        q = apply_rope(q[:, None], positions[:, None], inv_freq)[:, 0]
-        k = apply_rope(k[:, None], positions[:, None], inv_freq)[:, 0]
-        k_cache, v_cache = scatter_kv_pages_full(k_cache, v_cache, l, k, v, dest)
-        attn = attention_decode(q, k_cache[l], v_cache[l], page_tables, positions,
-                                scale, softcap=cfg.attn_logit_softcap,
-                                window=_layer_window(cfg, l))
-        h = _attn_residual(h, layer, attn, cfg, lor, lora_gates)
-        h = _mlp_residual(h, layer, cfg)
-        return (h, k_cache, v_cache), None
-
-    xs = _scan_xs(params["layers"], lora, cfg.num_layers)
-    (h, k_cache, v_cache), _ = jax.lax.scan(
-        layer_body, (h, k_cache, v_cache), xs
-    )
-    logits = unembed(params, cfg, h)  # [B, V]
     return logits, k_cache, v_cache
 
 
@@ -510,14 +505,12 @@ def forward_prefill_batched(
     G_, T = tokens.shape
     ps = k_cache.shape[2]
     mp = page_tables.shape[1]
-    scale = cfg.query_scale or 1.0 / math.sqrt(cfg.head_dim)
+    scale = _scale(cfg)
     K, D = cfg.num_kv_heads, cfg.head_dim
 
     pos = prefix_lens[:, None] + jnp.arange(T)[None, :]  # [G, T]
-    valid = (jnp.arange(T)[None, :] < t_reals[:, None]) & (pos < mp * ps)
-    pos_c = jnp.minimum(pos, mp * ps - 1)
-    page = jnp.take_along_axis(page_tables, pos_c // ps, axis=1)
-    dest = jnp.where(valid, page * ps + pos_c % ps, 0).reshape(-1)  # [G*T]
+    dest = page_slots(page_tables, pos, jnp.arange(T)[None, :] < t_reals[:, None],
+                      ps).reshape(-1)  # [G*T]
     ctx_lens = prefix_lens + t_reals
 
     h = embed_tokens(params, cfg, tokens)  # [G, T, E]
@@ -533,67 +526,27 @@ def forward_prefill_batched(
 
     def make_body(pos, dest, page_tables, ctx_lens, inv_freq, rope_pos,
                   lora_gates):
-        """Layer-body factory mirroring ``forward_prefill``'s: pp runs it
-        under shard_map with per-stage consts."""
-
-        def layer_body(carry, xs):
-            h, k_cache, v_cache = carry
-            if lora is not None:
-                layer, lor, l = xs
-            else:
-                (layer, l), lor = xs, None
-            hn = _norm(h, layer["attn_norm"], cfg)
-            q, k, v = _qkv(layer, cfg, hn, lor, lora_gates)  # [G, T, H/K, D]
-            if rope_pos is not None:
-                # M-RoPE rows rotate sectioned frequencies; masks and cache
-                # destinations keep the sequential ``pos``
-                from smg_tpu.ops.rope import apply_mrope
-
-                q = apply_mrope(q, rope_pos, inv_freq, cfg.mrope_section)
-                k = apply_mrope(k, rope_pos, inv_freq, cfg.mrope_section)
-            else:
-                q = apply_rope(q, pos, inv_freq)
-                k = apply_rope(k, pos, inv_freq)
+        def attend(q, k, v, l, caches):
+            """Scatter every row's chunk, then attend: over the chunk itself
+            when it IS the whole context, else over the row's pages."""
             k_cache, v_cache = scatter_kv_pages_full(
-                k_cache, v_cache, l, k.reshape(G_ * T, K, D),
-                v.reshape(G_ * T, K, D), dest
+                *caches, l, k.reshape(G_ * T, K, D), v.reshape(G_ * T, K, D), dest
             )
-            if no_ctx:
-                # cold prompts: the chunk IS the whole context
-                attn = attention_prefill_batched(q, k, v, pos, ctx_lens, scale,
-                                                 softcap=cfg.attn_logit_softcap,
-                                                 window=_layer_window(cfg, l))
-            else:
-                kl, vl = gather_layer_pages(  # [G, mp, ps, KD]
-                    k_cache, v_cache, l, page_tables)
-                S = mp * ps
-                k_ctx = kl.reshape(G_, S, K, D)
-                v_ctx = vl.reshape(G_, S, K, D)
-                attn = attention_prefill_batched(q, k_ctx, v_ctx, pos, ctx_lens,
-                                                 scale,
-                                                 softcap=cfg.attn_logit_softcap,
-                                                 window=_layer_window(cfg, l))
-            h = _attn_residual(h, layer, attn, cfg, lor, lora_gates)
-            h = _mlp_residual(h, layer, cfg)
-            return (h, k_cache, v_cache), None
+            if not no_ctx:
+                k, v = (x.reshape(G_, mp * ps, K, D) for x in gather_layer_pages(
+                    k_cache, v_cache, l, page_tables))  # [G, mp, ps, KD] each
+            attn = attention_prefill_batched(q, k, v, pos, ctx_lens, scale,
+                                             softcap=cfg.attn_logit_softcap,
+                                             window=_layer_window(cfg, l))
+            return attn, (k_cache, v_cache)
 
-        return layer_body
+        return partial(decoder_block, cfg, _rotary(cfg, inv_freq, pos, rope_pos),
+                       attend, lora_gates)
 
-    if pp_mesh is not None:
-        from smg_tpu.parallel.pp_serving import pp_serving_scan
-
-        h, k_cache, v_cache = pp_serving_scan(
-            pp_mesh, make_body, h, k_cache, v_cache, params["layers"],
-            (pos, dest, page_tables, ctx_lens, inv_freq, rope_pos, lora_gates),
-            lora=lora,
-        )
-    else:
-        xs = _scan_xs(params["layers"], lora, cfg.num_layers)
-        (h, k_cache, v_cache), _ = jax.lax.scan(
-            make_body(pos, dest, page_tables, ctx_lens, inv_freq, rope_pos,
-                      lora_gates),
-            (h, k_cache, v_cache), xs
-        )
+    h, k_cache, v_cache = _scan_layers(
+        make_body, (pos, dest, page_tables, ctx_lens, inv_freq, rope_pos, lora_gates),
+        (h, k_cache, v_cache), params["layers"], lora, pp_mesh,
+    )
     last_idx = jnp.maximum(t_reals - 1, 0)[:, None, None]  # [G, 1, 1]
     last = jnp.take_along_axis(
         h, jnp.broadcast_to(last_idx, (G_, 1, h.shape[-1])).astype(jnp.int32), axis=1
@@ -633,42 +586,21 @@ def forward_decode_horizon(
     Under ``pp_mesh`` the layer stack, the frozen cache, and the side
     buffers shard their layer axis over ``pp`` (``parallel/pp_serving.py``).
     """
-    scale = cfg.query_scale or 1.0 / math.sqrt(cfg.head_dim)
-    K, D = cfg.num_kv_heads, cfg.head_dim
-    B = tokens.shape[0]
-
+    scale = _scale(cfg)
     h = embed_tokens(params, cfg, tokens)  # [B, E]
 
     def make_body(positions, step_idx, entry_positions, page_tables, inv_freq,
                   rope_delta, lora_gates, k_cache, v_cache):
         # generated tokens are text: all three M-RoPE axes are equal, so
-        # decode stays on the standard rope path with a per-slot offset.
-        # Computed from make_body's own params so the pp shard_map never
-        # closes over an outer tracer (rope_delta/lora_gates ride consts).
+        # decode stays on the standard rope path with a per-slot offset
         rope_positions = (
             positions if rope_delta is None else positions + rope_delta
         )
 
-        def layer_body(carry, xs):
-            h, hk_all, hv_all = carry
-            if lora is not None:
-                layer, lor, l = xs
-            else:
-                (layer, l), lor = xs, None
-            hn = _norm(h, layer["attn_norm"], cfg)
-            q, k, v = _qkv(layer, cfg, hn, lor, lora_gates)  # [B, H/K, D]
-            q = apply_rope(q[:, None], rope_positions[:, None], inv_freq)[:, 0]
-            k = apply_rope(k[:, None], rope_positions[:, None], inv_freq)[:, 0]
-            k_f = k.reshape(B, K * D).astype(hk_all.dtype)
-            v_f = v.reshape(B, K * D).astype(hv_all.dtype)
-            hk_all = jax.lax.dynamic_update_slice(
-                hk_all, k_f[None, :, None, :], (l, 0, step_idx, 0)
-            )
-            hv_all = jax.lax.dynamic_update_slice(
-                hv_all, v_f[None, :, None, :], (l, 0, step_idx, 0)
-            )
-            hk_l = jax.lax.dynamic_index_in_dim(hk_all, l, 0, keepdims=False)
-            hv_l = jax.lax.dynamic_index_in_dim(hv_all, l, 0, keepdims=False)
+        def attend(q, k, v, l, side):
+            """Append the column to the side buffers, then attend over the
+            frozen cache and the columns so far."""
+            hk_l, hv_l, side = _write_side(side, k, v, l, step_idx)
             if attn_impl.startswith("pallas"):
                 from smg_tpu.ops.pallas.decode_attention import paged_attention_decode_cached
 
@@ -687,29 +619,19 @@ def forward_decode_horizon(
                     window=_layer_window(cfg, l),
                     lanes_sharded=kv_lanes_sharded,
                 )
-            h = _attn_residual(h, layer, attn, cfg, lor, lora_gates)
-            h = _mlp_residual(h, layer, cfg)
-            return (h, hk_all, hv_all), None
+            return attn, side
 
-        return layer_body
+        # q, k are [B, heads, D]: the lanes stand where rope wants its tokens
+        return partial(decoder_block, cfg, _rotary(cfg, inv_freq, rope_positions),
+                       attend, lora_gates)
 
-    if pp_mesh is not None:
-        from smg_tpu.parallel.pp_serving import pp_decode_scan
-
-        h, hk_all, hv_all = pp_decode_scan(
-            pp_mesh, make_body, h, hk_all, hv_all, k_cache, v_cache,
-            params["layers"],
-            (positions, step_idx, entry_positions, page_tables, inv_freq,
-             rope_delta, lora_gates),
-            lora=lora,
-        )
-    else:
-        xs = _scan_xs(params["layers"], lora, cfg.num_layers)
-        (h, hk_all, hv_all), _ = jax.lax.scan(
-            make_body(positions, step_idx, entry_positions, page_tables,
-                      inv_freq, rope_delta, lora_gates, k_cache, v_cache),
-            (h, hk_all, hv_all), xs,
-        )
+    h, hk_all, hv_all = _scan_layers(
+        make_body,
+        (positions, step_idx, entry_positions, page_tables, inv_freq, rope_delta,
+         lora_gates),
+        (h, hk_all, hv_all), params["layers"], lora, pp_mesh,
+        frozen=(k_cache, v_cache),
+    )
     logits = unembed(params, cfg, h)
     return logits, hk_all, hv_all
 
@@ -745,45 +667,48 @@ def forward_verify_block(
     keeps adapter-pinned lanes on the non-speculative path, and pp engines
     fall back likewise (see ``Scheduler._partition_spec``).
     Returns (logits [B, W, V], bk [L, B, W, K*D], bv [L, B, W, K*D])."""
-    scale = cfg.query_scale or 1.0 / math.sqrt(cfg.head_dim)
-    K, D = cfg.num_kv_heads, cfg.head_dim
     B, W = tokens.shape
     L = cfg.num_layers
 
     pos = entry_positions[:, None] + jnp.arange(W)[None, :]  # [B, W]
     rope_positions = pos if rope_delta is None else pos + rope_delta[:, None]
 
-    h = embed_tokens(params, cfg, tokens)  # [B, W, E]
-    bk0 = jnp.zeros((L, B, W, K * D), k_cache.dtype)
-    bv0 = jnp.zeros((L, B, W, K * D), v_cache.dtype)
-
-    def layer_body(carry, xs):
-        h, bk_all, bv_all = carry
-        layer, l = xs
-        hn = _norm(h, layer["attn_norm"], cfg)
-        q, k, v = _qkv(layer, cfg, hn)  # [B, W, H/K, D]
-        q = apply_rope(q, rope_positions, inv_freq)
-        k = apply_rope(k, rope_positions, inv_freq)
-        k_f = k.reshape(B, W, K * D).astype(bk_all.dtype)
-        v_f = v.reshape(B, W, K * D).astype(bv_all.dtype)
-        bk_all = jax.lax.dynamic_update_slice(bk_all, k_f[None], (l, 0, 0, 0))
-        bv_all = jax.lax.dynamic_update_slice(bv_all, v_f[None], (l, 0, 0, 0))
-        bk_l = jax.lax.dynamic_index_in_dim(bk_all, l, 0, keepdims=False)
-        bv_l = jax.lax.dynamic_index_in_dim(bv_all, l, 0, keepdims=False)
+    def attend(q, k, v, l, side):
+        """The block's rows into its buffers, then frozen cache + block."""
+        bk_l, bv_l, side = _write_side(side, k, v, l, 0)
         attn = attention_verify_block(
             q, k_cache, v_cache, bk_l, bv_l, l, page_tables, entry_positions,
-            scale, softcap=cfg.attn_logit_softcap, window=_layer_window(cfg, l),
-            lanes_sharded=kv_lanes_sharded,
+            _scale(cfg), softcap=cfg.attn_logit_softcap,
+            window=_layer_window(cfg, l), lanes_sharded=kv_lanes_sharded,
         )
-        h = _attn_residual(h, layer, attn, cfg)
-        h = _mlp_residual(h, layer, cfg)
-        return (h, bk_all, bv_all), None
+        return attn, side
 
-    (h, bk_all, bv_all), _ = jax.lax.scan(
-        layer_body, (h, bk0, bv0), (params["layers"], jnp.arange(L))
-    )
+    shape = (L, B, W, cfg.num_kv_heads * cfg.head_dim)
+    h, bk_all, bv_all = jax.lax.scan(
+        partial(decoder_block, cfg, _rotary(cfg, inv_freq, rope_positions), attend, None),
+        (embed_tokens(params, cfg, tokens),  # [B, W, E]
+         jnp.zeros(shape, k_cache.dtype), jnp.zeros(shape, v_cache.dtype)),
+        _scan_xs(params["layers"], None, L),
+    )[0]
     logits = unembed(params, cfg, h)  # [B, W, V]
     return logits, bk_all, bv_all
+
+
+def _dense_layer(layer, h, cfg, inv_freq, mask, ring_mesh=None):
+    """The block over whole sequences ``h`` [B, T, E] with no cache: dense
+    attention under ``mask``, or ring attention (causal) under ``ring_mesh``."""
+    B, T = h.shape[:2]
+    pos = jnp.arange(T)[None, :].repeat(B, axis=0)
+
+    def attend(q, k, v, l, state):
+        if ring_mesh is not None:
+            from smg_tpu.parallel.ring_attention import ring_attention
+
+            return ring_attention(q, k, v, ring_mesh, _scale(cfg)), state
+        return _dense_attention(q, k, v, mask, cfg), state
+
+    return decoder_block(cfg, _rotary(cfg, inv_freq, pos), attend, None,
+                         (h,), (layer, None, None))[0][0]
 
 
 def forward_embed(
@@ -796,40 +721,17 @@ def forward_embed(
     """Sequence embeddings: final-norm hidden state of the last valid token,
     L2-normalized (serves /v1/embeddings — reference routes embeddings to
     engine ``Embed`` RPCs, ``sglang_scheduler.proto``)."""
-    B, T = tokens.shape
+    T = tokens.shape[1]
     # window bound on REAL lengths is enforced host-side in runner.embed —
     # T here is the padded bucket and padding columns are masked anyway
-    scale = cfg.query_scale or 1.0 / math.sqrt(cfg.head_dim)
-    pos = jnp.arange(T)[None, :].repeat(B, axis=0)
     h = embed_tokens(params, cfg, tokens)
     # causal mask also masks padding columns beyond each row's length
-    j = jnp.arange(T)
-    causal = jnp.tril(jnp.ones((T, T), bool))[None] & (j[None, None, :] < lengths[:, None, None])
-
-    def layer_body(h, layer):
-        hn = _norm(h, layer["attn_norm"], cfg)
-        q, k, v = _qkv(layer, cfg, hn)
-        q = apply_rope(q, pos, inv_freq)
-        k = apply_rope(k, pos, inv_freq)
-        K = cfg.num_kv_heads
-        G = cfg.num_heads // K
-        qf = q.astype(jnp.float32).reshape(B, T, K, G, cfg.head_dim)
-        scores = jnp.einsum("btkgd,bskd->bkgts", qf, k.astype(jnp.float32)) * scale
-        if cfg.attn_logit_softcap:
-            c = cfg.attn_logit_softcap
-            scores = c * jnp.tanh(scores / c)
-        scores = jnp.where(causal[:, None, None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        attn = jnp.einsum("bkgts,bskd->btkgd", probs, v.astype(jnp.float32))
-        attn = attn.reshape(B, T, cfg.num_heads, cfg.head_dim).astype(h.dtype)
-        o = jnp.einsum("bthd,hde->bte", attn, layer["wo"])
-        if cfg.post_norms:
-            o = _norm(o, layer["post_attn_norm"], cfg)
-        h = h + o
-        h = _mlp_residual(h, layer, cfg)
-        return h, None
-
-    h, _ = jax.lax.scan(layer_body, h, params["layers"])
+    causal = (jnp.tril(jnp.ones((T, T), bool))[None]
+              & (jnp.arange(T)[None, None, :] < lengths[:, None, None]))
+    h, _ = jax.lax.scan(
+        lambda h, layer: (_dense_layer(layer, h, cfg, inv_freq, causal), None),
+        h, params["layers"],
+    )
     h = _norm(h, params["final_norm"], cfg)
     last = jnp.take_along_axis(
         h, jnp.maximum(lengths - 1, 0)[:, None, None].astype(jnp.int32), axis=1
@@ -858,27 +760,17 @@ def forward_train(
     under GSPMD outside the pipeline region.
     """
     h = embed_tokens(params, cfg, tokens)
+    layer_fn = lambda layer, x: decoder_layer_train(
+        layer, x, cfg, inv_freq, ring_mesh=ring_mesh)
 
     if pp_mesh is not None and pp_mesh.shape.get("pp", 1) > 1:
         from smg_tpu.parallel.pipeline import pipeline_apply
 
-        h = pipeline_apply(
-            lambda layer, x: decoder_layer_train(
-                layer, x, cfg, inv_freq, ring_mesh=ring_mesh
-            ),
-            params["layers"],
-            h,
-            pp_mesh,
-            num_microbatches=num_microbatches,
-        )
+        h = pipeline_apply(layer_fn, params["layers"], h, pp_mesh,
+                           num_microbatches=num_microbatches)
     else:
-        def layer_body(h, layer):
-            return (
-                decoder_layer_train(layer, h, cfg, inv_freq, ring_mesh=ring_mesh),
-                None,
-            )
-
-        h, _ = jax.lax.scan(layer_body, h, params["layers"])
+        h, _ = jax.lax.scan(lambda h, layer: (layer_fn(layer, h), None),
+                            h, params["layers"])
     return unembed(params, cfg, h)
 
 
@@ -893,39 +785,13 @@ def decoder_layer_train(
     ``forward_train`` layer scan and the pipeline-parallel schedule
     (``smg_tpu/parallel/pipeline.py``), which scans it over a pp stage's
     local layer shard."""
-    B, T = h.shape[0], h.shape[1]
+    T = h.shape[1]
     if cfg.sliding_window and T > cfg.sliding_window:
         # training T is the REAL (unpadded) sequence length, so this bound
-        # is exact; decoder_layer_train has no per-layer window alternation
+        # is exact; the dense layer has no per-layer window alternation
         raise ValueError(
             f"training supports contexts <= sliding_window "
             f"({cfg.sliding_window}); got {T}"
         )
-    scale = cfg.query_scale or 1.0 / math.sqrt(cfg.head_dim)
-    pos = jnp.arange(T)[None, :].repeat(B, axis=0)
-    hn = _norm(h, layer["attn_norm"], cfg)
-    q, k, v = _qkv(layer, cfg, hn)  # [B, T, H/K, D]
-    q = apply_rope(q, pos, inv_freq)
-    k = apply_rope(k, pos, inv_freq)
-    K = cfg.num_kv_heads
-    G = cfg.num_heads // K
-    if ring_mesh is not None:
-        from smg_tpu.parallel.ring_attention import ring_attention
-
-        attn = ring_attention(q, k, v, ring_mesh, scale)
-    else:
-        causal = jnp.tril(jnp.ones((T, T), bool))
-        qf = q.astype(jnp.float32).reshape(B, T, K, G, cfg.head_dim)
-        scores = jnp.einsum("btkgd,bskd->bkgts", qf, k.astype(jnp.float32)) * scale
-        if cfg.attn_logit_softcap:
-            c = cfg.attn_logit_softcap
-            scores = c * jnp.tanh(scores / c)
-        scores = jnp.where(causal[None, None, None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        attn = jnp.einsum("bkgts,bskd->btkgd", probs, v.astype(jnp.float32))
-        attn = attn.reshape(B, T, cfg.num_heads, cfg.head_dim).astype(h.dtype)
-    o = jnp.einsum("bthd,hde->bte", attn, layer["wo"])
-    if cfg.post_norms:
-        o = _norm(o, layer["post_attn_norm"], cfg)
-    h = h + o
-    return _mlp_residual(h, layer, cfg)
+    return _dense_layer(layer, h, cfg, inv_freq,
+                        jnp.tril(jnp.ones((T, T), bool))[None], ring_mesh)

@@ -57,13 +57,14 @@ import jax
 import jax.numpy as jnp
 
 from smg_tpu.models.config import ModelConfig
-from smg_tpu.models.llama import _mlp, embed_tokens, unembed
+from smg_tpu.models.llama import _mlp, _write_side, embed_tokens, unembed
 from smg_tpu.ops.attention import (
     attention_decode_cached,
     attention_prefill,
     attention_prefill_batched,
     gather_layer_pages,
     gather_seq_kv,
+    page_slots,
     scatter_kv_pages_full,
 )
 from smg_tpu.ops.linear_attention import (
@@ -323,13 +324,10 @@ def _prefill(params, cfg, inv_freq, tokens, prefix_lens, t_reals, k_cache, v_cac
     padded token has ``beta`` 0 and ``g`` 0 and stays out of the convolution's
     tail, a padded row names the garbage slot."""
     G, T = tokens.shape
-    ps, mp = k_cache.shape[2], page_tables.shape[1]
     K, D, H = cfg.num_kv_heads, cfg.head_dim, cfg.linear_num_heads
     pos = prefix_lens[:, None] + jnp.arange(T)[None, :]
     real = jnp.arange(T)[None, :] < t_reals[:, None]
-    pos_c = jnp.minimum(pos, mp * ps - 1)
-    page = jnp.take_along_axis(page_tables, pos_c // ps, axis=1)
-    dest = jnp.where(real & (pos < mp * ps), page * ps + pos_c % ps, 0).reshape(-1)
+    dest = page_slots(page_tables, pos, real, k_cache.shape[2]).reshape(-1)
     keep = (prefix_lens > 0).astype(jnp.float32)  # 0 where the sequence starts here
     taps = cfg.linear_conv_kernel_dim - 1
     h = embed_tokens(params, cfg, tokens)
@@ -499,12 +497,7 @@ def forward_decode_horizon(
         hk_all, hv_all, s_pool, c_pool = carry
 
         def attend(q, k, v):
-            k_f = k.reshape(B, K * D).astype(hk_all.dtype)
-            v_f = v.reshape(B, K * D).astype(hv_all.dtype)
-            hk2 = jax.lax.dynamic_update_slice(hk_all, k_f[None, :, None, :], (p, 0, step_idx, 0))
-            hv2 = jax.lax.dynamic_update_slice(hv_all, v_f[None, :, None, :], (p, 0, step_idx, 0))
-            hk_l = jax.lax.dynamic_index_in_dim(hk2, p, 0, keepdims=False)
-            hv_l = jax.lax.dynamic_index_in_dim(hv2, p, 0, keepdims=False)
+            hk_l, hv_l, side = _write_side((hk_all, hv_all), k, v, p, step_idx)
             if attn_impl.startswith("pallas"):
                 from smg_tpu.ops.pallas.decode_attention import paged_attention_decode_cached
 
@@ -515,7 +508,7 @@ def forward_decode_horizon(
                 out = attention_decode_cached(
                     q, k_cache, v_cache, hk_l, hv_l, step_idx + 1, p, page_tables,
                     entry_positions, scale)
-            return out, (hk2, hv2)
+            return out, side
 
         h, (hk_all, hv_all) = full_layer(h, layer, cfg, positions, inv_freq, attend)
         return h, (hk_all, hv_all, s_pool, c_pool)

@@ -1,8 +1,23 @@
 """Model registry: architecture name -> model module.
 
-A model module exposes ``init_params``, ``logical_axes``, ``forward_prefill``,
-``forward_decode``, ``forward_train`` with the signatures in
-``smg_tpu/models/llama.py`` (the reference implementation of the contract).
+What the runners call of a model module (``smg_tpu/models/llama.py`` is the
+reference implementation of the contract, ``models/olmo_hybrid.py`` the one
+with state beside its pages):
+
+- ``init_params(cfg, key)``, ``logical_axes(cfg)``;
+- ``forward_prefill`` (one chunk of one sequence), ``forward_prefill_batched``
+  (several sequences' chunks; ``no_ctx`` when every row starts its sequence);
+- ``forward_decode_horizon`` (one column of a decode frame: the frozen cache
+  is read, the column's K and V go to the frame's side buffers, the caller
+  lands them with ``ops.attention.land_side_buffers``).  There is no other
+  decode step;
+- optional, each a serving limit where absent (``SERVING_LIMITS``):
+  ``forward_verify_block`` (speculative verify), ``forward_embed``
+  (``/v1/embeddings``), ``forward_train`` (the dense causal forward of
+  ``smg_tpu/train`` and of the tests);
+- a module whose sequences hold state beside their pages also gives
+  ``state_shapes(cfg)`` and takes the pools and slots after the page tables
+  (``engine/recurrent_runner.py``).
 """
 
 from __future__ import annotations

@@ -37,22 +37,6 @@ NEG_INF = -1e30
 SCORE_BLOCK_BYTES = 256 * 2**20
 
 
-def scatter_kv_pages(
-    k_pages: jnp.ndarray,  # [P, ps, KD]
-    v_pages: jnp.ndarray,
-    k_new: jnp.ndarray,  # [T, K, D]
-    v_new: jnp.ndarray,
-    dest_slots: jnp.ndarray,  # [T] flat slot index (page*ps + offset); 0..ps-1 => garbage page
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    P, ps, KD = k_pages.shape
-    T = k_new.shape[0]
-    k_flat = k_pages.reshape(P * ps, KD)
-    v_flat = v_pages.reshape(P * ps, KD)
-    k_flat = k_flat.at[dest_slots].set(k_new.reshape(T, KD).astype(k_flat.dtype))
-    v_flat = v_flat.at[dest_slots].set(v_new.reshape(T, KD).astype(v_flat.dtype))
-    return k_flat.reshape(P, ps, KD), v_flat.reshape(P, ps, KD)
-
-
 def scatter_kv_pages_full(
     k_cache: jnp.ndarray,  # [L, P, ps, KD] — FULL stacked cache
     v_cache: jnp.ndarray,
@@ -97,6 +81,40 @@ def scatter_kv_rows(
         v_rows.astype(v_cache.dtype)
     )
     return k_flat.reshape(k_cache.shape), v_flat.reshape(v_cache.shape)
+
+
+def page_slots(
+    page_tables: jnp.ndarray,  # [..., mp]
+    pos: jnp.ndarray,  # [..., n] token positions in each table's sequence
+    keep: jnp.ndarray,  # [..., n] bool
+    page_size: int,
+) -> jnp.ndarray:
+    """Flat cache slot (page*ps + offset) of each position.  Rows not kept
+    (padding, columns never computed, rejected drafts) and positions past the
+    table go to the garbage page (slot 0); clamping instead would clobber a
+    real slot."""
+    total = page_tables.shape[-1] * page_size
+    pos_c = jnp.minimum(pos, total - 1)
+    page = jnp.take_along_axis(page_tables, pos_c // page_size, axis=-1)
+    return jnp.where(keep & (pos < total), page * page_size + pos_c % page_size, 0)
+
+
+def land_side_buffers(
+    k_cache: jnp.ndarray,  # [L, P, ps, KD]
+    v_cache: jnp.ndarray,
+    side_k: jnp.ndarray,  # [L, B, N, KD] a frame's side buffers
+    side_v: jnp.ndarray,
+    page_tables: jnp.ndarray,  # [B, mp]
+    entry_positions: jnp.ndarray,  # [B] position of each lane's column 0
+    keep: jnp.ndarray,  # [B, N] (or broadcastable) bool: the column is kept
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Land a decode horizon's (or verify block's) columns in the cache in
+    one scatter, column n of lane b at position ``entry[b] + n``."""
+    L, B, N, KD = side_k.shape
+    pos = entry_positions[:, None] + jnp.arange(N)[None, :]
+    dest = page_slots(page_tables, pos, keep, k_cache.shape[2]).reshape(-1)
+    return scatter_kv_rows(k_cache, v_cache, side_k.reshape(L, B * N, KD),
+                           side_v.reshape(L, B * N, KD), dest)
 
 
 @jax.named_scope("smg.attn.kv_read")
@@ -417,50 +435,3 @@ def attention_verify_block(
         entry_positions[:, None] + w[None, :], w[None, :] <= w[:, None],
         scale, softcap, window, lanes_sharded,
     )
-
-
-@jax.named_scope("smg.attn.decode")
-def attention_decode(
-    q: jnp.ndarray,  # [B, H, D] one new token per sequence (post-rope)
-    k_pages: jnp.ndarray,  # [P, ps, KD]
-    v_pages: jnp.ndarray,
-    page_tables: jnp.ndarray,  # [B, max_pages]
-    positions: jnp.ndarray,  # [B] position of the new token (= ctx len - 1)
-    scale: float,
-    softcap: float | None = None,
-    window: jnp.ndarray | None = None,  # scalar sliding window (<=0 = global)
-) -> jnp.ndarray:
-    """Batched single-token attention over paged KV. GQA-aware.
-
-    XLA fallback: gathers each sequence's pages ([B, max_pages*ps, K, D]) and
-    does a masked softmax.  The Pallas kernel streams pages through VMEM
-    instead of materializing the gather.
-    """
-    B, H, D = q.shape
-    P, ps, KD = k_pages.shape
-    K = KD // D
-    cd = k_pages.dtype  # cache-dtype matmuls, f32 accumulation (HBM-bound op)
-    k = k_pages[page_tables]  # [B, mp, ps, KD]
-    v = v_pages[page_tables]
-    mp = k.shape[1]
-    S = mp * ps
-    k = k.reshape(B, S, K, D)
-    v = v.reshape(B, S, K, D)
-    G = H // K
-    qf = q.astype(cd).reshape(B, K, G, D)
-    scores = jnp.einsum(
-        "bkgd,bskd->bkgs", qf, k, preferred_element_type=jnp.float32
-    ) * scale
-    if softcap:
-        scores = softcap * jnp.tanh(scores / softcap)
-    j = jnp.arange(S)
-    mask = j[None, :] <= positions[:, None]  # [B, S]
-    if window is not None:
-        mask = mask & ((window <= 0) | (j[None, :] > positions[:, None] - window))
-    scores = jnp.where(mask[:, None, None, :], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum(
-        "bkgs,bskd->bkgd", probs.astype(cd), v,
-        preferred_element_type=jnp.float32,
-    )
-    return out.reshape(B, H, D).astype(q.dtype)

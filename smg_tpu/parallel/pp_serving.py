@@ -44,29 +44,30 @@ def pp_serving_scan(
     consts: tuple,             # replicated arrays the body closes over
     axis: str = "pp",
     lora=None,                 # optional adapter bank, leading dim = L
+    frozen: tuple = (),        # read-only layer-stacked arrays (decode's cache)
 ):
-    """Run ``make_body(*consts)``'s layer body over a pp-sharded stack.
+    """Run ``make_body(*consts, *frozen)``'s layer body over a pp-sharded stack.
 
-    ``make_body(*consts) -> body`` where ``body((h, s1, s2), (layer, l))``
-    is a standard ``lax.scan`` layer step; ``l`` is the LOCAL layer index
-    into the stage's state shard.  With ``lora`` the xs triple becomes
-    ``(layer, lora_layer, l)`` — the bank shards its layer axis over ``pp``
-    exactly like the weights.  Returns (h, s1, s2) with ``h`` replicated
-    and state still sharded.
+    ``make_body(...) -> body`` where ``body((h, s1, s2), (layer, lora_layer,
+    l))`` is a standard ``lax.scan`` layer step (``models.llama.decoder_block``);
+    ``l`` is the LOCAL layer index into the stage's state shard.  A ``lora``
+    bank shards its layer axis over ``pp`` exactly like the weights, and so
+    does each array of ``frozen``: the decode horizon's frozen KV cache enters
+    each stage as a LOCAL read-only shard, handed to the factory last.
+    Returns (h, s1, s2) with ``h`` replicated and state still sharded.
     """
     S = mesh.shape[axis]
     L = jax.tree.leaves(layers)[0].shape[0]
     if L % S != 0:
         raise ValueError(f"num_layers {L} not divisible by pp={S}")
 
-    def run(h, s1, s2, layers_local, lora_local, consts):
+    def run(h, s1, s2, layers_local, lora_local, consts, frozen_local):
         from smg_tpu.models.llama import _scan_xs
 
-        body = make_body(*consts)
+        body = make_body(*consts, *frozen_local)
         L_local = jax.tree.leaves(layers_local)[0].shape[0]
         stage = jax.lax.axis_index(axis)
-        xs = _scan_xs(layers_local, lora_local if lora is not None else None,
-                      L_local)
+        xs = _scan_xs(layers_local, lora_local, L_local)
         perm = [(i, (i + 1) % S) for i in range(S)]
 
         def tick(carry, s):
@@ -90,68 +91,10 @@ def pp_serving_scan(
     fn = jax.shard_map(
         run,
         mesh=mesh,
-        in_specs=(P(), P(axis), P(axis), layer_specs, lora_specs, const_specs),
+        in_specs=(P(), P(axis), P(axis), layer_specs, lora_specs, const_specs,
+                  (P(axis),) * len(frozen)),
         out_specs=(P(), P(axis), P(axis)),
         axis_names={axis},
         check_vma=False,
     )
-    return fn(h, s1, s2, layers, lora, consts)
-
-
-def pp_decode_scan(
-    mesh,
-    make_body,
-    h: jnp.ndarray,
-    hk, hv,                    # [L, B, N, KD] horizon side buffers (pp on L)
-    k_cache, v_cache,          # [L, P, ps, KD] frozen cache (pp on L, read-only)
-    layers,
-    consts: tuple,
-    axis: str = "pp",
-    lora=None,                 # optional adapter bank, leading dim = L
-):
-    """Decode-horizon variant of :func:`pp_serving_scan`: the frozen KV
-    cache enters each stage as a LOCAL read-only shard (it is already
-    pp-sharded on its layer axis) and the body factory receives it last:
-    ``make_body(*consts, k_cache_local, v_cache_local)``."""
-    S = mesh.shape[axis]
-    L = jax.tree.leaves(layers)[0].shape[0]
-    if L % S != 0:
-        raise ValueError(f"num_layers {L} not divisible by pp={S}")
-
-    def run(h, hk, hv, kc, vc, layers_local, lora_local, consts):
-        from smg_tpu.models.llama import _scan_xs
-
-        body = make_body(*consts, kc, vc)
-        L_local = jax.tree.leaves(layers_local)[0].shape[0]
-        stage = jax.lax.axis_index(axis)
-        xs = _scan_xs(layers_local, lora_local if lora is not None else None,
-                      L_local)
-        perm = [(i, (i + 1) % S) for i in range(S)]
-
-        def tick(carry, s):
-            h, hk, hv = carry
-            (h2, hk2, hv2), _ = jax.lax.scan(body, (h, hk, hv), xs)
-            my = s == stage
-            h2 = jnp.where(my, h2, h)
-            hk2 = jnp.where(my, hk2, hk)
-            hv2 = jnp.where(my, hv2, hv)
-            h2 = jax.lax.ppermute(h2, axis, perm)
-            return (h2, hk2, hv2), None
-
-        (h, hk, hv), _ = jax.lax.scan(tick, (h, hk, hv), jnp.arange(S))
-        h = jax.lax.psum(jnp.where(stage == 0, h, jnp.zeros_like(h)), axis)
-        return h, hk, hv
-
-    layer_specs = jax.tree.map(lambda _: P(axis), layers)
-    lora_specs = jax.tree.map(lambda _: P(axis), lora)
-    const_specs = jax.tree.map(lambda _: P(), consts)
-    fn = jax.shard_map(
-        run,
-        mesh=mesh,
-        in_specs=(P(), P(axis), P(axis), P(axis), P(axis), layer_specs,
-                  lora_specs, const_specs),
-        out_specs=(P(), P(axis), P(axis)),
-        axis_names={axis},
-        check_vma=False,
-    )
-    return fn(h, hk, hv, k_cache, v_cache, layers, lora, consts)
+    return fn(h, s1, s2, layers, lora, consts, tuple(frozen))

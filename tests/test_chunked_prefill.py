@@ -259,6 +259,27 @@ def test_preemption_under_pressure_lands_mid_prefill():
     assert [o.finished for o in got["s"]][-1]
 
 
+def test_prefill_extend_keeps_its_page_table():
+    """``prefill_extend`` returns before its program has run, and the CPU
+    client aliases a 64-byte-aligned host array instead of copying it: a row
+    of the scheduler's table that a preemption zeroes in the same step would
+    send the chunk's KV to the garbage page.  This is what made the test
+    above pass or fail by where malloc had put the table."""
+    import numpy as np
+
+    r = make_engine(num_pages=17, max_seq_len=256).runner
+    mp = r.max_pages_per_seq
+    raw = np.zeros(4 * mp + 16, np.int32)
+    off = (-raw.ctypes.data % 64) // 4
+    table = raw[off:off + 4 * mp].reshape(4, mp)  # rows on 64-byte boundaries
+    table[1, :4] = [1, 2, 3, 4]
+    r.prefill_extend(list(range(5, 69)), 0, table[1])
+    table[1][:] = 0  # as ``Scheduler._preempt`` does
+    k = np.asarray(r.k_cache)
+    assert np.abs(k[:, 1:5]).sum() > 0, "the chunk's KV did not reach its pages"
+    assert not np.abs(k[:, 5:]).any()
+
+
 def test_loads_exposes_prefill_backlog():
     eng = make_engine(policy="stall-free")
     eng.submit(LONG, greedy(4), rid="long")

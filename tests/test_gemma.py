@@ -190,7 +190,7 @@ def test_sliding_window_attention_masks():
     import jax
     import jax.numpy as jnp
 
-    from smg_tpu.ops.attention import attention_decode, attention_prefill
+    from smg_tpu.ops.attention import attention_decode_cached, attention_prefill
 
     T, K, G, D = 8, 2, 2, 8
     q = jax.random.normal(jax.random.PRNGKey(0), (T, K * G, D))
@@ -221,6 +221,15 @@ def test_sliding_window_attention_masks():
     # window == 0 (traced "global") equals no window
     g0 = attention_prefill(q, k, v, pos, jnp.int32(T), 1.0, window=jnp.int32(0))
     np.testing.assert_allclose(np.asarray(g0), dense_ref(None), rtol=1e-5)
+    # the decode rows: lane t holds tokens 0..t-1 in the (shared) page and
+    # token t in its side buffer, as a decode column does
+    kc = jnp.zeros((1, 2, T, K * D)).at[0, 1].set(k.reshape(T, K * D))
+    vc = jnp.zeros((1, 2, T, K * D)).at[0, 1].set(v.reshape(T, K * D))
+    for w in (3, 5, 0):
+        got = attention_decode_cached(
+            q, kc, vc, k.reshape(T, 1, K * D), v.reshape(T, 1, K * D), jnp.int32(1),
+            jnp.int32(0), jnp.ones((T, 1), jnp.int32), pos, 1.0, window=jnp.int32(w))
+        np.testing.assert_allclose(np.asarray(got), dense_ref(w), rtol=1e-4, atol=1e-5)
 
 
 def test_layer_window_alternation():

@@ -91,10 +91,20 @@ def test_sp_serving_prefill_matches_single(cpu_devices):
     runner = sp4.runner
     res = sp4.generate(prompt_ids=prompt, sampling=sampling)
     assert res.token_ids == ref.token_ids
-    # the ring variant actually compiled (cold chunk T=64 % sp=4 == 0).
-    # Prefill compile keys are ("prefill", T, mp, impl, use_pen, use_mask,
-    # use_lora, use_ring, ...): match use_ring by position, not k[-1], so
-    # appending new flags to the key doesn't break this assertion.
-    assert any(k[0] == "prefill" and k[7] for k in runner._compiled), (
-        "expected a use_ring=True prefill variant to be compiled"
+    # the ring variant actually compiled (cold chunk T=64 % sp=4 == 0).  A
+    # prompt cut by the step's token budget starts through ``prefill_extend``
+    # and ends through ``prefill``; both families key their programs
+    # (family, T, mp, impl, *flags) with the flags in the order of their
+    # ``_fn``'s parameters, so ``use_ring`` is found by its name.
+    import inspect
+
+    def use_ring(key):
+        fn = {"prefill": runner._prefill_fn,
+              "prefill_extend": runner._prefill_extend_fn}.get(key[0])
+        if fn is None:
+            return False
+        return key[list(inspect.signature(fn).parameters).index("use_ring") + 2]
+
+    assert any(use_ring(k) for k in runner._compiled), (
+        f"expected a use_ring=True prefill program, got {list(runner._compiled)}"
     )
