@@ -209,6 +209,12 @@ def kv_bytes_per_token(hf: dict, dtype_bytes: int = 2) -> int:
     return 2 * hf["num_hidden_layers"] * K * D * dtype_bytes
 
 
+def attention_layers(hf: dict) -> int:
+    """Layers that hold keys and values: each runs the decode attention kernel
+    once a column, which is how a trace counts the columns run."""
+    return hf["num_hidden_layers"]
+
+
 def decode_min_seconds(hf: dict, columns: float, lane_tokens: float, chips: int,
                        peak: dict, dtype_bytes: int = 2) -> float:
     """Least time for ``columns`` decode columns (one token for every live

@@ -306,6 +306,12 @@ def linear_layers(hf: dict) -> int:
     return _widths(hf)["n_linear"]
 
 
+def attention_layers(hf: dict) -> int:
+    """The full-attention layers, one a period: each runs the decode attention
+    kernel once a column, which is how a trace counts the columns run."""
+    return _widths(hf)["periods"]
+
+
 def decode_min_seconds(hf: dict, columns: float, lane_tokens: float, chips: int,
                        peak: dict, dtype_bytes: int = 2) -> float:
     """Least time for ``columns`` decode columns: every matmul parameter read

@@ -21,6 +21,25 @@ def decode_records(ctx, window):
             if s["kind"] in ("decode", "mixed") and in_window(s["t"], window)]
 
 
+def columns_run(ctx):
+    """Decode columns the device computed in the traced window: what a frame
+    ran, not the ``horizon`` it asked for (a frame leaves early at a finish).
+    Counted on the device where the decode programs run the paged attention
+    kernel (``trace_reduce.kernel_columns`` over the architecture's
+    ``attention_layers``), which covers exactly the launches whose device time
+    the trace sums.  Else from the step ring, where the program's records
+    carry ``columns_run``.  Else None: the sum of ``horizon`` is no stand-in."""
+    layers = getattr(ctx["costs"], "attention_layers", None)
+    if layers is not None:
+        columns = bench_module("trace_reduce").kernel_columns(ctx["trace"], layers(ctx["hf"]))
+        if columns:
+            return columns
+    recs = decode_records(ctx, ctx["trace_window"])
+    if recs and all("columns_run" in s for s in recs):
+        return sum(s["columns_run"] for s in recs) or None
+    return None
+
+
 def bench_module(name):
     """A module of benchmark/ (trace_reduce, peaks, client_reduce)."""
     import importlib

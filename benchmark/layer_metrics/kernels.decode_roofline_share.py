@@ -1,14 +1,18 @@
-"""Least time for the decode launches of the traced window over their device
-time, in percent.  Least time: the matmul parameters read once per column
-and the live lanes' cached keys and values once per column, over the chips'
-memory bandwidth (decode is bound by bytes).  The live context is taken from
+"""Least time for the decode columns run in the traced window over the device
+time of the decode launches, in percent.  Least time: the matmul parameters
+read once per column and the live lanes' cached keys and values once per
+column, over the chips' memory bandwidth (decode is bound by bytes).  Columns
+are those the device computed (``_common.columns_run``), not the ``horizon``
+the frames asked for: counted by columns asked, the share of a stretch whose
+frames leave early at a finish passes 100 %.  The live context is taken from
 the client's records: a request decodes from its first token to its end and
 holds ``prompt + output so far`` tokens meanwhile."""
 
-from _common import bench_module, decode_records, peak
+from _common import bench_module, columns_run, peak
 
 META = {"layer": "kernels", "unit": "%", "moves": "output_tok_per_s",
-        "source": "device_trace: jit_multi* device time; bytes from shapes (architectures/)"}
+        "source": "device_trace: jit_multi* device time and the columns run (decode kernel "
+                  "executions); bytes from shapes (architectures/)"}
 
 
 def live_tokens(ctx, window) -> float:
@@ -32,7 +36,7 @@ def read(ctx):
     if ctx["trace"] is None or ctx["trace_window"] is None:
         return None
     fam = bench_module("trace_reduce").family_time(ctx["trace"], "decode")
-    columns = sum(s["horizon"] for s in decode_records(ctx, ctx["trace_window"]))
+    columns = columns_run(ctx)
     if not fam or not columns:
         return None
     least = ctx["costs"].decode_min_seconds(

@@ -140,8 +140,8 @@ def main() -> int:
     sw.add_argument("--seed", type=int, default=2024)
     st.add_argument("--seeds", required=True)
     st.add_argument("--sets", type=int, default=2)
-    st.add_argument("--trace", type=int, default=0, choices=(0, 1, 2),
-                    help="1 or 2: one more run at the end, traced so")
+    st.add_argument("--trace", type=int, default=0, choices=(0, 2),
+                    help="2: one more run at the end, traced")
     a = ap.parse_args()
     os.makedirs(a.out, exist_ok=True)
     if a.rehearsal:

@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """One run of one cell of BENCHMARK.json.
 
-    python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1|2>
+    python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|2>
 
 The process holds the cell's chips and does what ``smg-tpu serve`` does, by
 the program's own code: ``gateway.launch._run_gateway`` builds the engine,
@@ -40,6 +40,7 @@ import os  # noqa: E402
 import signal  # noqa: E402
 import statistics  # noqa: E402
 import sys  # noqa: E402
+from collections import deque  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -57,12 +58,18 @@ def log(msg: str) -> None:
 # client-side reduction
 
 
-#: how much of the window's end a ``--trace 1`` run records, and how much of
-#: the replayed window's end a ``--trace 2`` run's trace phase does
-TRACE_SECONDS = 12.0
+#: how much of the replayed window's end a ``--trace 2`` run traces: the
+#: profiler's write grows with the launches it holds, and the whole run has
+#: 360 s (PERF.md, Findings, PR 32)
+TRACE_SECONDS = 6.0
 #: ``--trace 2``: traffic goes on this long after the traced stretch, so that
 #: steps and submits meet the profiler while it writes its trace
 TRACE_TAIL_SECONDS = 4.0
+#: what the flight recorder keeps, so that one read after the window and one
+#: after the replay hold every step and finished request of each: a window of
+#: ``eval`` is about 1,080 steps and 510 requests where ``serve`` keeps 256 and 64
+FLIGHT_RING_STEPS = 4096
+FLIGHT_TIMELINES = 2048
 
 
 def end_to_end(result: dict) -> dict:
@@ -168,6 +175,47 @@ class CompileWatch:
         return [msg for at, msg in self.names if at >= t]
 
 
+class PauseWatch:
+    """The interpreter's garbage collections in this process, timed
+    (``gc.callbacks``): how many of each generation a span of time held and
+    the longest with its generation.  A run that reads some percent low has
+    every lane stalled for seconds at one point of its window (PERF.md,
+    Findings, PR 32); a full collection over a heap that holds every traced
+    program is the first suspect, and this is its witness on the detail line."""
+
+    def __init__(self):
+        import gc
+
+        self.pauses: list = []  # (start, seconds, generation)
+        self._start = 0.0
+        gc.callbacks.append(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        now = time.monotonic()
+        if phase == "start":
+            self._start = now
+        else:
+            self.pauses.append((self._start, now - self._start, info["generation"]))
+
+    def between(self, lo: float, hi: float) -> dict:
+        inside = [p for p in self.pauses if lo <= p[0] <= hi]
+        worst = max(inside, key=lambda p: p[1], default=None)
+        return {"collections": [sum(1 for p in inside if p[2] == g) for g in (0, 1, 2)],
+                "total_s": sum(p[1] for p in inside),
+                "longest": worst and {"at_s": worst[0] - lo, "seconds": worst[1],
+                                      "generation": worst[2]}}
+
+
+def longest_steps(steps: list, lo: float, hi: float, n: int = 3) -> list:
+    """The ``n`` longest steps the flight recorder stamped between ``lo`` and
+    ``hi``: when (the stamp is the step's end), how long, how much of it the
+    step waited for the device, and what it did.  A stall shows here as one
+    step of seconds, and ``fetch_wait_s`` says on which side of the fetch."""
+    inside = sorted((s for s in steps if lo <= s["t"] <= hi), key=lambda s: -s["step_s"])[:n]
+    return [{"at_s": s["t"] - lo, "step_s": s["step_s"], "fetch_wait_s": s.get("fetch_wait_s"),
+             "kind": s["kind"], "prefill_tokens": s.get("prefill_tokens")} for s in inside]
+
+
 class Probe:
     """What a traced run reads from inside the server process: the engine's
     submit and first-output stamps, the flight recorder's step ring and
@@ -261,6 +309,19 @@ async def wait_quiet(engine, timeout: float = 60.0) -> dict:
         await asyncio.sleep(0.1)
 
 
+def keep_timelines(engine, n: int) -> None:
+    """Has the flight recorder keep the last ``n`` finished timelines.
+    ``serve`` has a flag for the step ring (``--flight-ring-size``) and none
+    yet for ``EngineConfig.flight_timeline_keep`` (PERF.md, Open questions), so
+    the harness widens the recorder's own deque, before any request exists.  A
+    recorder without one keeps what it keeps: ``rests_on.window_timelines`` on
+    the detail line says how many were read."""
+    flight = engine.scheduler.flight
+    kept = getattr(flight, "_finished", None)
+    if isinstance(kept, deque) and (kept.maxlen or 0) < n:
+        flight._finished = deque(kept, maxlen=n)
+
+
 def memory_peak(engine) -> int:
     peak = 0
     for d in engine.runner.local_devices():
@@ -286,9 +347,10 @@ async def trace_phase(args, cell: catalog.Cell, engine, probe: Probe, base: dict
     falls into no number; the probe; a second load generator that replays the
     window's own chains with other words (the window's prompts are in the
     radix cache); the profiler through the engine's own ``start_profile``
-    over the stretch a ``--trace 1`` run traces, the window's last
-    ``TRACE_SECONDS``; the stop, with traffic still running, and how long steps
-    and submits stalled meanwhile; the drain.
+    over the replay's last ``TRACE_SECONDS``; the stop, with traffic still
+    running, and how long steps and submits stalled meanwhile; the drain; the
+    second read of the recorder (its ring holds the replay: nothing polls it
+    while the trace is on).
 
     The replay is as long as the window because the arrangement of the
     requests is part of the work: traced right after the callers' ramp, the
@@ -317,28 +379,16 @@ async def trace_phase(args, cell: catalog.Cell, engine, probe: Probe, base: dict
             "drain_s": min(base["drain_s"], 30.0),
             "chains": cell.chains(args.seed ^ 0x7ACE, seconds)}
     load_task = asyncio.create_task(run_loadgen(plan, out_dir, "trace"))
-    polling = True
-
-    async def poll_loop():
-        while polling:
-            await asyncio.to_thread(probe.poll)
-            await asyncio.sleep(1.0)
-
-    poller = asyncio.create_task(poll_loop())
-    try:
-        await asyncio.sleep(max(t0 + lead - time.monotonic(), 0))
-        ts = time.monotonic()
-        await asyncio.to_thread(engine.start_profile, trace_dir)
-        started = time.monotonic()
-        await asyncio.sleep(traced)
-        stopping = time.monotonic()
-        await asyncio.to_thread(engine.stop_profile)
-        te = time.monotonic()
-        result = await load_task
-        loads1 = await wait_quiet(engine)
-    finally:
-        polling = False
-        await poller
+    await asyncio.sleep(max(t0 + lead - time.monotonic(), 0))
+    ts = time.monotonic()
+    await asyncio.to_thread(engine.start_profile, trace_dir)
+    started = time.monotonic()
+    await asyncio.sleep(traced)
+    stopping = time.monotonic()
+    await asyncio.to_thread(engine.stop_profile)
+    te = time.monotonic()
+    result = await load_task
+    loads1 = await wait_quiet(engine)
     await asyncio.to_thread(probe.poll)
     reqs = result["requests"]
     steps = sorted(probe.steps.values(), key=lambda r: r["serial"])
@@ -361,7 +411,7 @@ async def trace_phase(args, cell: catalog.Cell, engine, probe: Probe, base: dict
                  and lo <= tl["first_token_t"] <= hi)
         return n / (hi - lo)
 
-    in_trace = [s for s in steps if started <= s["t"] <= stopping]
+    in_trace = [s for s in steps if ts <= s["t"] <= stopping]  # the readers' trace_window
     w1 = closed_window["t0"] + closed_window["seconds"]
     w0 = w1 - (stopping - started)  # the stretch of the window that the trace replays
     return {
@@ -375,6 +425,7 @@ async def trace_phase(args, cell: catalog.Cell, engine, probe: Probe, base: dict
                             for r, n in loads1["decode_launches"].items()},
         "traced_steps": {
             "records": len(in_trace),
+            # columns asked; main() puts ``columns_run_sum`` beside it from the trace
             "horizon_sum": sum(s["horizon"] for s in in_trace),
             "decode_tokens": sum(s["decode_tokens"] for s in in_trace),
             "early_exits": sum(s["early_exits"] for s in in_trace)},
@@ -390,12 +441,13 @@ async def trace_phase(args, cell: catalog.Cell, engine, probe: Probe, base: dict
                        "stop_profile_max": max(gaps(stopping, te), default=None)},
         "submit_lock_wait_s": {"traced_max": max(lock_waits(started, stopping), default=None),
                                "stop_profile_max": max(lock_waits(stopping, te), default=None)},
+        "window_longest_steps": longest_steps(window_steps, closed_window["t0"], w1),
         "rests_on": {"window_requests": len(closed_window["requests"]),
                      "window_steps": len(window_steps),
                      "window_ring_reaches_back": bool(
                          window_steps and window_steps[0]["t"] <= closed_window["t0"]),
                      "window_timelines": len(window_timelines),
-                     "trace_phase_requests": len(reqs)},
+                     "trace_phase_requests": len(reqs), "steps_lost": probe.lost_steps},
         "ctx": {
             # steps go on while the profiler writes: the traced window ends
             # where the stop was asked for, not where it returned
@@ -438,6 +490,7 @@ async def serve_and_measure(args, bench: dict, cell: catalog.Cell, out_dir: str)
         port = s.getsockname()[1]
     preset = cell.entry["config"]
     argv = ["serve", "--model-preset", preset, *cell.serve_args,
+            "--flight-ring-size", str(FLIGHT_RING_STEPS),
             "--host", "127.0.0.1", "--port", str(port)]
     sargs = build_parser().parse_args(argv)
     configure(level=sargs.log_level, json_logs=None)
@@ -451,6 +504,7 @@ async def serve_and_measure(args, bench: dict, cell: catalog.Cell, out_dir: str)
     def build_and_keep(a):
         t = time.monotonic()
         engine = build(a)
+        keep_timelines(engine, FLIGHT_TIMELINES)
         marks["weights_and_cache"] = time.monotonic() - t
         t = time.monotonic()
         holder["warmed"] = warm.warm_shapes(
@@ -462,6 +516,7 @@ async def serve_and_measure(args, bench: dict, cell: catalog.Cell, out_dir: str)
 
     launch.build_engine_from_args = build_and_keep
     watch = CompileWatch()
+    pauses = PauseWatch()
     gateway = asyncio.create_task(launch._run_gateway(sargs))
     try:
         # _run_gateway blocks the loop while it builds and warms the engine
@@ -513,9 +568,6 @@ async def serve_and_measure(args, bench: dict, cell: catalog.Cell, out_dir: str)
 
         probe = Probe(engine)
         trace_dir = os.path.join(out_dir, "trace")
-        if args.trace == 1:
-            probe.install()
-            await asyncio.to_thread(probe.poll)
         loads0 = await wait_quiet(engine)
         compiles0 = watch.count
 
@@ -525,36 +577,8 @@ async def serve_and_measure(args, bench: dict, cell: catalog.Cell, out_dir: str)
         plan = {**base, "tag": "w", "seconds": float(args.seconds), "t0": t0,
                 "chains": cell.chains(args.seed, float(args.seconds))}
         load_task = asyncio.create_task(run_loadgen(plan, out_dir, "window"))
-        trace_win = None
-        polling = True
-
-        async def poll_loop():
-            while polling:
-                await asyncio.to_thread(probe.poll)
-                await asyncio.sleep(1.0)
-
-        poller = asyncio.create_task(poll_loop()) if args.trace == 1 else None
-        if args.trace == 1:
-            import shutil
-
-            shutil.rmtree(trace_dir, ignore_errors=True)
-            # the last seconds of the window: stop_profile holds the engine's
-            # lock while it writes the trace, which stalls every step and every
-            # submit for seconds, so nothing read after it stands for the cell.
-            # Long enough for dozens of launches of each family.
-            trace_s = min(TRACE_SECONDS, 0.3 * args.seconds)
-            await asyncio.sleep(max(t0 + args.seconds - trace_s - 0.5 - time.monotonic(), 0))
-            ts = time.monotonic()
-            await asyncio.to_thread(engine.start_profile, trace_dir)
-            await asyncio.sleep(trace_s)
-            await asyncio.to_thread(engine.stop_profile)
-            trace_win = (ts, time.monotonic())
         result = await load_task
         loads1 = await wait_quiet(engine)
-        polling = False
-        if poller is not None:
-            await poller
-            await asyncio.to_thread(probe.poll)
         compiles1 = watch.count
         peak = memory_peak(engine)
         e2e = end_to_end(result)
@@ -590,14 +614,11 @@ async def serve_and_measure(args, bench: dict, cell: catalog.Cell, out_dir: str)
         "cell": cell.name, "hf": hf, "costs": cell.architecture, "chips": cell.chips,
         "device": device,
         "rehearsal": args.rehearsal, "requests": result["requests"],
-        # a traced run's whole-window readers stop where the profiler started
-        "window": (result["t0"], trace_win[0] if trace_win
-                   else result["t0"] + result["seconds"]),
+        "window": (result["t0"], result["t0"] + result["seconds"]),
         "loads_before": loads0, "loads_after": loads1,
-        "stamps": probe.stamps, "steps": sorted(probe.steps.values(),
-                                                key=lambda r: r["serial"]),
-        "timelines": list(probe.timelines.values()), "lost_steps": probe.lost_steps,
-        "trace_window": trace_win, "trace": None, "kv_dtype_bytes":
+        # the window runs unwatched: no stamps, and the recorder is read after it
+        "stamps": {}, "steps": [], "timelines": [], "lost_steps": 0,
+        "trace_window": None, "trace": None, "kv_dtype_bytes":
             2 if engine.config.cache.dtype == "bfloat16" else 4,
     }
     if phase is not None:
@@ -610,6 +631,7 @@ async def serve_and_measure(args, bench: dict, cell: catalog.Cell, out_dir: str)
             "trace_phase": phase, "device": device, "memory_peak_bytes": peak, "new_programs": new_programs,
             "compiles_in_window": compiles1 - compiles0,
             "compiled_in_window": watch.since(t0), "warmed": holder["warmed"],
+            "gc_in_window": pauses.between(t0, t0 + float(args.seconds)),
             "slice_grew": holder["slice_grew"], "trace_dir": trace_dir,
             "attention": loads1["attention"], "mesh": loads1["mesh"],
             "counters": {k: loads1[k] - loads0[k] for k in COUNTERS},
@@ -636,7 +658,7 @@ def main() -> int:
     ap.add_argument("--workload")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--seconds", type=float, default=None)
-    ap.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
+    ap.add_argument("--trace", type=int, choices=(0, 2), default=0)
     ap.add_argument("--rehearsal", action="store_true",
                     help="tiny widths on the CPU; proves nothing about a chip")
     ap.add_argument("--out", default=os.path.join(ROOT, "bench_out"),
@@ -685,8 +707,7 @@ def main() -> int:
 
     run = asyncio.run(serve_and_measure(args, bench, cell, out_dir))
     e2e, ctx = run["e2e"], run.pop("ctx")
-    # the context that holds the trace: the window's own (--trace 1), or the
-    # trace phase's, which comes after the window (--trace 2)
+    # the context that holds the trace is the trace phase's, after the window
     tctx = run["trace_phase"].pop("ctx") if run["trace_phase"] else ctx
     device = dict(run["device"], memory_peak_bytes=run["memory_peak_bytes"])
     line = {"correct": all(run["verdict"].values()), "attempted": e2e["attempted"],
@@ -715,14 +736,14 @@ def main() -> int:
             return 4
         device["busy_s"] = sum(b["busy_s"].values()) / len(b["busy_s"])
         device["window_s"] = b["window_s"]
-        if args.trace == 2:
-            # both kinds of metric side by side; idle gaps by the program's spans
-            line["metrics"] = {**e2e_metrics, **per_layer(bench, cell.name, ctx, tctx)}
-            gaps = trace_reduce.idle_gaps(trace, span_prefix="smg.")
-        else:
-            line["metrics"] = per_layer(bench, cell.name, ctx)
-            gaps = trace_reduce.idle_gaps(trace)
-        line["breakdown"] = {"device_ops": trace_reduce.top_ops(trace), "idle_gaps": gaps}
+        # both kinds of metric side by side; idle gaps by the program's spans
+        line["metrics"] = {**e2e_metrics, **per_layer(bench, cell.name, ctx, tctx)}
+        line["breakdown"] = {"device_ops": trace_reduce.top_ops(trace),
+                             "idle_gaps": trace_reduce.idle_gaps(trace, span_prefix="smg.")}
+        # columns the device ran in the traced launches, beside the columns the
+        # frames asked for (``horizon_sum``): what both decode readers divide by
+        run["trace_phase"]["traced_steps"]["columns_run_sum"] = (
+            catalog.layer_metric_reader("_common").columns_run(tctx))
         steps = [s for s in ctx["steps"] if s["horizon"] > 0
                  and ctx["window"][0] <= s["t"] <= ctx["window"][1]]
         report["probe"] = {
@@ -762,7 +783,7 @@ def main() -> int:
          **e2e["detail"], "e2e": e2e["metrics"], "attention": run["attention"], "counters": run["counters"],
          "total_pages": run["total_pages"], "check_worst": run["check"]["worst"],
          "control": run["check"]["control_errors"], "new_programs": run["new_programs"],
-         "compiles_in_window": run["compiles_in_window"],
+         "compiles_in_window": run["compiles_in_window"], "gc_in_window": run["gc_in_window"],
          "compiled_in_window": run["compiled_in_window"],
          "programs_warmed": len(run["warmed"]),
          **({"trace_phase": run["trace_phase"]} if run["trace_phase"] else {})}), flush=True)

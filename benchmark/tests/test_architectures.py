@@ -121,7 +121,8 @@ for mode in ("sound", "blind", "off_row"):
 four = json.load(open("benchmark/tests/data/four_devices.json"))
 ctx = {"trace": four, "trace_window": (0.0, 0.0095), "hf": hf, "costs": arch, "chips": 1,
        "device": {"kind": "TPU v5 lite"}, "kv_dtype_bytes": 2,
-       "steps": [{"kind": "decode", "t": 0.004, "horizon": 4}],
+       # no decode kernel of the program's runs here: the columns run come from the ring
+       "steps": [{"kind": "decode", "t": 0.004, "horizon": 8, "columns_run": 4}],
        "requests": [{"first": 0.0, "done": 0.0095, "prompt_tokens": 100, "output_tokens": 10}],
        "timelines": [{"first_token_t": 0.009, "prompt_tokens": 600, "cached_tokens": 100}]}
 out["decode_share"] = catalog.layer_metric_reader("kernels.decode_roofline_share").read(ctx)
@@ -156,7 +157,7 @@ def test_an_architecture_is_one_new_file_and_the_shared_verdict_holds_it(tmp_pat
 
     out = json.loads(subprocess.run([sys.executable, "-c", SCRIPT], cwd=root, check=True,
                                     stdout=subprocess.PIPE, text=True).stdout)
-    assert out["seen"] == ["llama", "toy"]
+    assert {"llama", "toy"} <= set(out["seen"])  # whatever else later PRs have added
     assert out["module"].endswith("toy") and out["old_cell"].endswith("llama")
     assert out["own_keys_kept_from_the_program"] == []
 

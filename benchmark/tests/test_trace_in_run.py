@@ -45,7 +45,8 @@ def test_new_metrics_are_entries_with_readers_and_the_switch_is_declared():
     bench = catalog.load_benchmark()
     assert bench["trace_in_run"] is True
     by_name = {m["name"]: m for m in bench["per_layer"]}
-    assert [m["name"] for m in bench["per_layer"]][-3:] == list(NEW)  # appended, in order
+    names = [m["name"] for m in bench["per_layer"]]
+    assert sorted(NEW, key=names.index) == list(NEW)  # there, in order, wherever later ones went
     for name in NEW:
         m, meta = by_name[name], catalog.layer_metric_reader(name).META
         assert (m["layer"], m["moves"], m["unit"]) == ("scheduler", "output_tok_per_s",
@@ -173,6 +174,8 @@ def test_trace_phase_starts_probe_and_profiler_only_after_the_window(tmp_path, m
                          "loadgen:trace", "start_profile:trace", "stop_profile"]
     assert order.index("stop_profile", 2) < order.index("loadgen:trace:done")
     assert log[0] == "flight.snapshot"  # before anything of the profiler
+    # and once more after the replay: nothing polls the recorder while the trace is on
+    assert log.count("flight.snapshot") == 2 and log[-1] == "flight.snapshot"
     assert plans[0]["chains"] != cell.chains(args.seed, plans[0]["seconds"])  # other words
     assert plans[0]["tag"] == "t" and plans[0]["drain_s"] <= 30.0
     assert phase["window_steps"] and phase["window_timelines"]
@@ -193,11 +196,11 @@ def test_trace_phase_starts_probe_and_profiler_only_after_the_window(tmp_path, m
 
 def test_trace_2_is_trace_0_until_the_window_has_closed():
     """The source of ``serve_and_measure``: whatever a traced run does before
-    or inside the window is behind ``args.trace == 1``, and the trace phase is
-    entered after the window's result, its ``loads()`` and its end-to-end
-    numbers exist."""
+    or inside the window does not ask ``args.trace`` (``--trace 1``, which
+    traced inside the window, is gone), and the trace phase is entered after
+    the window's result, its ``loads()`` and its end-to-end numbers exist."""
     src = inspect.getsource(run.serve_and_measure)
-    assert "if args.trace:" not in src and "if args.trace else" not in src
+    assert src.count("args.trace") == 1 and "probe.install" not in src
     order = [src.index(needle) for needle in (
         "load_task = asyncio.create_task(run_loadgen(plan", "result = await load_task",
         "loads1 = await wait_quiet(engine)", "e2e = end_to_end(result)",
@@ -216,4 +219,8 @@ def test_the_command_takes_trace_2(capsys):
     out = subprocess.run([sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
                           "--workload", "qwen3-1.7b.eval", "--trace", "3"],
                          capture_output=True, text=True)
-    assert out.returncode == 2 and "choose from 0, 1, 2" in out.stderr
+    assert out.returncode == 2 and "choose from 0, 2" in out.stderr
+    one = subprocess.run([sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
+                          "--workload", "qwen3-1.7b.eval", "--trace", "1"],
+                         capture_output=True, text=True)
+    assert one.returncode == 2 and "choose from 0, 2" in one.stderr  # retired by PR 32

@@ -11,6 +11,8 @@ Everything else here is plain Python over those lists:
   ran on it (the "XLA Ops" line; the "XLA Modules" line if there is none);
 - a program's device time is the sum of its executions on the "XLA Modules"
   line, found by the program's jit name (no program has a named scope yet);
+- the decode columns a device ran are the executions of the decode attention
+  kernel inside the decode launches, over the layers that run it once a column;
 - a collective's exposed time is the part of its interval during which no
   other leaf operation runs on the same device (a ``while`` or ``call``
   that merely encloses other operations is not a leaf);
@@ -19,6 +21,7 @@ Everything else here is plain Python over those lists:
 
 from __future__ import annotations
 
+import bisect
 import re
 
 #: jit names of the runner's step programs (engine/runner.py): the decode
@@ -161,6 +164,40 @@ def family_time(trace: dict, family: str) -> dict | None:
     return {"launches": sum(c for c, _, _ in per_dev) / n,
             "seconds": sum(s for _, s, _ in per_dev) / n,
             "durations": per_dev[0][2]}
+
+
+#: the paged decode attention kernel's own name (ops/pallas/decode_attention.py):
+#: a decode column runs it once in every layer that holds keys and values
+DECODE_KERNEL = "smg.attn.decode"
+
+
+def kernel_columns(trace: dict, layers: int) -> float | None:
+    """Columns the devices computed inside the decode launches: the operations
+    named ``DECODE_KERNEL`` that start inside such a launch, over the
+    ``layers`` that run it once a column, averaged over the devices.  An
+    event's name is its whole HLO instruction, so the name is matched at its
+    head: the operation that consumes the kernel's result names it too.
+    A frame that left early at a finish counts the columns it ran, a frame
+    launched ahead and thrown away counts what it ran too, and one that ran
+    no column counts none.  None where no decode launch runs the kernel (XLA
+    attention under a mesh): there is nothing to count."""
+    prefixes = PROGRAM_FAMILIES["decode"]
+    per_dev = []
+    for dev in trace["devices"].values():
+        spans = sorted((s, s + d) for name, s, d in dev["modules"]
+                       if _base(name).startswith(prefixes))
+        starts = [a for a, _b in spans]
+        runs = 0
+        for name, s, _d in dev["ops"]:
+            if name.lstrip("%").startswith(DECODE_KERNEL):
+                i = bisect.bisect_right(starts, s) - 1
+                if i >= 0 and s < spans[i][1]:
+                    runs += 1
+        if runs:
+            per_dev.append(runs)
+    if not per_dev or layers <= 0:
+        return None
+    return sum(per_dev) / len(per_dev) / layers
 
 
 def leaves(events: list) -> list:
