@@ -6,9 +6,19 @@ step).  Wire-parity with the reference's ``SamplingParams``
 (``sglang_scheduler.proto:67-101``).
 
 TPU-first implementation: **no full-vocab sort**.  Filtering works by
-computing per-row probability thresholds from ``lax.top_k`` over the top
-``K_CAP`` candidates, then sampling with gumbel-argmax over the masked
-logits.  top-k is exact for ``top_k <= K_CAP``; top-p is exact whenever the
+computing per-row probability thresholds from the ``K_CAP`` largest values of
+a row, then sampling with gumbel-argmax over the masked logits.  Those values
+come from ``top_values``: the maxima of the row's blocks of 128 columns,
+``lax.top_k`` over the maxima, the ``K_CAP`` blocks it names gathered, the
+same once more inside them with blocks of 16, and ``lax.top_k`` over the 1,024
+candidates left.  That is exact in the values (bitwise ``lax.top_k`` of the
+row; ties cost nothing because no index is used), where ``lax.top_k`` of a
+152k-column row, compared against the row afterwards as the thresholds are,
+compiles for the v5e as a sort of the whole row: 3.2 ms of a 10.7 ms decode
+column (PERF.md, PR 33).  A row too short to hold four blocks for each value
+wanted is not cut at that block size, so the toy vocabularies of the tests are
+sorted as they are; the choice rests on the static shape of the logits alone.
+top-k is exact for ``top_k <= K_CAP``; top-p is exact whenever the
 nucleus fits in ``K_CAP`` candidates and conservatively includes the whole
 distribution otherwise (wider, never narrower, than requested).  A full-sort
 exact reference (``sample_tokens_exact``) backs the property tests.
@@ -21,6 +31,43 @@ import jax.numpy as jnp
 
 NEG_INF = -1e30
 K_CAP = 64  # top-k candidates examined for thresholds
+# ``top_values`` cuts a row into blocks of the first size, the blocks it keeps
+# into blocks of the second (one lane tile of a float32 row, then an eighth)
+BLOCKS = (128, 16)
+MIN_BLOCKS_PER_K = 4  # fewer blocks than this for each value wanted: no cut
+
+
+def _held_blocks(z: jnp.ndarray, k: int, block: int) -> jnp.ndarray:
+    """Cut each row of ``z`` [B, V] into blocks of ``block`` columns (the
+    tail padded with -inf) and keep the ``k`` blocks whose maxima are
+    largest: [B, k * block], which holds the row's ``k`` largest values."""
+    B, V = z.shape
+    pad = -V % block
+    if pad:
+        z = jnp.concatenate([z, jnp.full((B, pad), -jnp.inf, z.dtype)], axis=1)
+    blocks = z.reshape(B, -1, block)
+    _, held = jax.lax.top_k(blocks.max(axis=-1), k)  # [B, k] block numbers
+    held = jnp.take_along_axis(blocks, held[:, :, None], axis=1, mode="promise_in_bounds")
+    return held.reshape(B, k * block)
+
+
+def top_values(z: jnp.ndarray, k: int, blocks: tuple[int, ...] = BLOCKS) -> jnp.ndarray:
+    """The ``k`` largest values of each row of ``z`` [B, V], descending:
+    bitwise ``jax.lax.top_k(z, k)[0]``, without a sort of the row.
+
+    Only values are wanted, so ties cost nothing.  Every element above the
+    row's k-th largest value v sits in a block whose maximum is above v, there
+    are fewer than k such blocks, and the k blocks with the largest maxima
+    hold all of them; the places left go to blocks whose maximum equals v,
+    one copy of v each.  The k largest of those blocks' elements are
+    therefore the k largest of the row.  The same holds of the candidates,
+    cut again into smaller blocks.  A row is cut at a block size only where
+    it has ``MIN_BLOCKS_PER_K`` x k such blocks or more: a shorter row is
+    sorted as it is."""
+    for block in blocks:
+        if z.shape[1] >= MIN_BLOCKS_PER_K * k * block:
+            z = _held_blocks(z, k, block)
+    return jax.lax.top_k(z, k)[0]
 
 
 @jax.named_scope("smg.sample")
@@ -48,7 +95,7 @@ def sample_tokens(
 
     # top-K_CAP candidates give us every threshold we need
     k_cap = min(K_CAP, V)
-    top_vals, _ = jax.lax.top_k(z, k_cap)  # [B, k_cap] descending
+    top_vals = top_values(z, k_cap)  # [B, k_cap] descending
 
     # top-k threshold: value of the k-th largest (clamped to k_cap)
     k_eff = jnp.where(top_k <= 0, k_cap, jnp.minimum(top_k, k_cap)).astype(jnp.int32)

@@ -23,6 +23,7 @@ from jax.sharding import SingleDeviceSharding
 from smg_tpu.engine.config import EngineConfig, ParallelConfig
 from smg_tpu.engine.kv_cache import KvCacheSpec
 from smg_tpu.engine.runner import PREFILL_KERNEL_MAX_T, ModelRunner
+from smg_tpu.engine.sampling import sample_tokens
 from smg_tpu.models.config import llama32_1b_config
 from smg_tpu.models.registry import get_model
 from smg_tpu.ops.attention import (
@@ -201,6 +202,24 @@ class TestCompilesForV5e:
             s((B, N, kd)), s((B, N, kd)), s((), jnp.int32),
             s((), jnp.int32), s((B, mp), jnp.int32), s((B,), jnp.int32),
         )
+
+    @pytest.mark.parametrize("B,V", [(16, 151936), (1, 151936), (16, 100352)],
+                             ids=["qwen_decode", "qwen_first_token", "olmo_hybrid_decode"])
+    def test_sampler_sorts_no_row_of_the_vocabulary(self, v5e, B, V):
+        """``lax.top_k`` of a whole row, with the row compared against its
+        result as the sampler's thresholds are, compiles for the v5e as a
+        sort of the vocabulary (3.2 ms a decode column at [16, 151936]; PERF.md,
+        PR 33).  No sort or TopK of the compiled sampler may be that wide."""
+        s = self._sds(v5e)
+        hlo = _compile(
+            sample_tokens, s((B, V), jnp.float32), s((2,), jnp.uint32),
+            s((B,), jnp.float32), s((B,), jnp.int32), s((B,), jnp.float32),
+            s((B,), jnp.float32),
+        ).as_text()
+        sorts = [line.strip() for line in hlo.splitlines()
+                 if re.search(r' sort\(|custom_call_target="TopK"', line)]
+        assert sorts, "the sampler's top_k should show in the compiled text"
+        assert not [line for line in sorts if f",{V}]" in line], sorts
 
     def test_xla_prefill_stays_under_its_score_block(self, v5e):
         """The other side of the switch at the largest chunk: one-shot
