@@ -16,6 +16,11 @@ layers of one cache at a cell's widths:
   64 behind 128 and 256, and three small programs (1 x 8, 1 x 64, 4 x 32);
 - ``olmo-hybrid-7b`` (the ``gen`` cell's 4 full layers): 5,087 pages, 30/30
   heads of 128; batch 16 behind 128 and 256 pages;
+- ``openpangu-ultra-moe-718b`` (the ``reason`` cell): 5 layers of one latent
+  buffer, 640 lanes an entry (kv heads 0 marks it), 128 absorbed query heads;
+  batch 16, 32 and 64 behind 64, 128 and 256 pages.  The XLA form is
+  ``attention_decode_cached`` with one head as wide as the entry and the cache
+  as its own V, the kernel ``latent_attention_decode_cached``;
 
 lanes filled to a quarter, a half and all of the table.  Prints one JSON line
 per shape: milliseconds a layer for each (XLA once a shape: it reads the
@@ -44,7 +49,10 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from smg_tpu.ops.attention import attention_decode_cached  # noqa: E402
-from smg_tpu.ops.pallas.decode_attention import paged_attention_decode_cached  # noqa: E402
+from smg_tpu.ops.pallas.decode_attention import (  # noqa: E402
+    latent_attention_decode_cached,
+    paged_attention_decode_cached,
+)
 
 PS, N = 16, 8
 REPS = 5
@@ -55,8 +63,30 @@ MODELS = {
                    [(1, (8, 64)), (4, (32,)), (8, (64, 128, 256)),
                     (16, (64, 128, 256)), (32, (128, 256)), (64, (128, 256))]),
     "olmo-hybrid-7b": (4, 5087, 30, 30, 128, [(16, (128, 256))]),
+    "openpangu-ultra-moe-718b": (5, 20000, 128, 0, 640,
+                                 [(16, (64, 128, 256)), (32, (64, 128, 256)),
+                                  (64, (64, 128, 256))]),
 }
-REHEARSAL = {"toy": (2, 40, 4, 2, 64, [(2, (4, 8)), (8, (8,))])}
+REHEARSAL = {"toy": (2, 40, 4, 2, 64, [(2, (4, 8)), (8, (8,))]),
+             "toy-latent": (2, 40, 4, 0, 256, [(2, (4, 8))])}
+LATENT_VALUE_LANES = {640: 512, 256: 128}  # entry lanes -> lanes of its value
+
+
+def latent_forms(D: int, pages_per_block, interpret: bool) -> dict:
+    """The two forms over one latent buffer, with ``columns``' signature; the
+    result is padded back to the entry's lanes so that layers chain."""
+    vl = LATENT_VALUE_LANES[D]
+    back = lambda o: jnp.pad(o[..., :vl], ((0, 0), (0, 0), (0, D - vl)))
+
+    def xla(q, kc, _vc, hk, _hv, n, l, tables, entry, scale):
+        return back(attention_decode_cached(q, kc, kc, hk, hk, n, l, tables, entry, scale))
+
+    def pallas(q, kc, _vc, hk, _hv, n, l, tables, entry, scale):
+        return back(latent_attention_decode_cached(
+            q, kc, hk, n, l, tables, entry, latent=vl, scale=scale,
+            pages_per_block=pages_per_block, interpret=interpret))
+
+    return {"xla": xla, "pallas": pallas}
 
 
 def columns(attend, L, D, q, kc, vc, hk_all, hv_all, tables, entry):
@@ -105,13 +135,16 @@ def main() -> int:
     for model, (L, P, H, K, D, shapes) in (REHEARSAL if args.rehearsal else MODELS).items():
         if only and not any(s.startswith(model + ":") for s in only):
             continue
-        kd = K * D
+        latent = K == 0  # one buffer of ``D`` lanes an entry and no V
+        kd = D if latent else K * D
         kq, kk, kv, ks = jax.random.split(jax.random.PRNGKey(0), 4)
         # random, so that the two outputs can be compared; what the cache
         # holds does not change what the attentions cost
         kc = jax.random.normal(kk, (L, P, PS, kd), jnp.bfloat16)
-        vc = jax.random.normal(kv, (L, P, PS, kd), jnp.bfloat16)
-        page_bytes = 2 * PS * kd * 2  # K and V
+        vc = kc if latent else jax.random.normal(kv, (L, P, PS, kd), jnp.bfloat16)
+        page_bytes = (1 if latent else 2) * PS * kd * 2  # K and V, or the one buffer
+        forms = (latent_forms(D, args.pages_per_block, args.rehearsal) if latent
+                 else {"xla": attention_decode_cached, "pallas": pallas})
         for B, widths in shapes:
             q = jax.random.normal(kq, (B, H, D), jnp.bfloat16)
             side = jax.random.normal(ks, (L, B, N, kd), jnp.bfloat16)
@@ -123,8 +156,7 @@ def main() -> int:
                        else rng.integers(0, P - 1, B * mp))
                 tables = jnp.asarray(ids.reshape(B, mp) + 1, jnp.int32)
                 fns = {name: jax.jit(functools.partial(columns, attend, L, D))
-                       for name, attend in (("xla", attention_decode_cached),
-                                            ("pallas", pallas))}
+                       for name, attend in forms.items()}
                 xla_ms = None
                 fit = []
                 for fill in FILLS:

@@ -104,9 +104,10 @@ def validate_engine_config(cfg) -> list[ValidationIssue]:
                     "parallel.ep",
                     f"ep={par.ep} does not divide num_experts={model.num_experts}",
                 ))
-    if model is not None and getattr(model, "recurrent", False):
-        # what a model with recurrent layers cannot do yet is refused here,
-        # at start, and not left to run a wrong model
+    if model is not None and (getattr(model, "recurrent", False)
+                              or getattr(model, "latent_cache", False)):
+        # what a model with recurrent layers, or with a latent cache, cannot do
+        # yet is refused here, at start, and not left to run a wrong model
         from smg_tpu.models.registry import get_model
 
         limits = get_model(model.arch).SERVING_LIMITS

@@ -76,6 +76,10 @@ class Engine:
             from smg_tpu.engine.recurrent_runner import RecurrentModelRunner
 
             runner_cls = RecurrentModelRunner
+        elif getattr(config.model, "latent_cache", False):
+            from smg_tpu.engine.latent_runner import LatentModelRunner
+
+            runner_cls = LatentModelRunner
         self.runner = runner_cls(config, params=params, devices=devices)
         # engine-deep metric set (own registry; the gateway additionally
         # registers it into its CollectorRegistry so /metrics is one scrape)

@@ -66,8 +66,11 @@ logger = get_logger("engine.flight_recorder")
 #: step ran the K it ran — and ``submit_t`` on the timeline, stamped before
 #: the submit waits for the engine lock; v6: ``state_lanes``, the lanes of
 #: the consumed frame that held a recurrent-state slot (0 for a model whose
-#: layers are all attention))
-SCHEMA_VERSION = 6
+#: layers are all attention); v7: ``columns_run``, the decode columns the
+#: device ran of the consumed frame (``min(horizon, steps_run)``; 0 where no
+#: frame was consumed), and for a model with routed experts
+#: ``moe_picks_held`` and ``moe_experts_hit`` of that frame)
+SCHEMA_VERSION = 7
 
 #: stable key set of one step record (schema contract, tested)
 STEP_RECORD_KEYS = frozenset({
@@ -76,7 +79,10 @@ STEP_RECORD_KEYS = frozenset({
     "free_pages", "admissions", "finishes", "overlap", "fetch_wait_s",
     "faults", "horizon", "early_exits", "wasted_decode_tokens",
     "spec_drafted", "spec_accepted", "mesh", "horizon_reason", "state_lanes",
+    "columns_run",
 })
+#: what a model with routed experts adds to a step record (no other writes them)
+MOE_STEP_RECORD_KEYS = frozenset({"moe_picks_held", "moe_experts_hit"})
 
 #: why a decode launch ran the horizon it ran (``Scheduler._pick_horizon``);
 #: a step record of a step that launched no decode carries ""
@@ -214,6 +220,7 @@ class FlightRecorder:
         wasted_decode_tokens: int = 0,
         spec_drafted: int = 0, spec_accepted: int = 0,
         mesh: int = 1, horizon_reason: str = "", state_lanes: int = 0,
+        columns_run: int = 0, moe: "tuple[int, int] | None" = None,
     ) -> int:
         """Append one step record; returns the step serial.  Called once per
         scheduler step with values already in hand — no derivation here."""
@@ -265,6 +272,13 @@ class FlightRecorder:
                 "horizon_reason": horizon_reason,
                 # lanes of the consumed frame that held a recurrent-state slot
                 "state_lanes": state_lanes,
+                # decode columns the device ran of the consumed frame
+                "columns_run": columns_run,
+                # routed experts: token-expert pairs of the consumed frame on
+                # held experts (rows computed), and held experts with at least
+                # one row summed over layers and columns
+                **({"moe_picks_held": moe[0], "moe_experts_hit": moe[1]}
+                   if moe is not None else {}),
             })
             return self.step_serial
 

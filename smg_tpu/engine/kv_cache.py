@@ -115,20 +115,31 @@ class KvCacheSpec:
     num_kv_heads: int
     head_dim: int
     dtype: str
+    # a latent cache (``ops/latent_attention.py``): one buffer whose entries
+    # are this many lanes wide, and no V buffer.  0: K and V of
+    # ``num_kv_heads * head_dim`` lanes each.
+    latent_lanes: int = 0
+
+    @property
+    def lanes(self) -> int:
+        return self.latent_lanes or self.num_kv_heads * self.head_dim
 
     @property
     def shape(self) -> tuple[int, ...]:
         # fused lane layout (see smg_tpu/ops/attention.py)
-        return (
-            self.num_layers, self.num_pages, self.page_size,
-            self.num_kv_heads * self.head_dim,
-        )
+        return (self.num_layers, self.num_pages, self.page_size, self.lanes)
+
+    @property
+    def v_shape(self) -> tuple[int, ...]:
+        """The V buffer's shape: of zero size where the cache is latent."""
+        return (self.num_layers, 0, self.page_size, self.lanes) if self.latent_lanes else self.shape
 
     @property
     def bytes_per_page(self) -> int:
-        # k + v, all layers
+        # k + v (or the one latent buffer), all layers
         itemsize = jnp.dtype(self.dtype).itemsize
-        return 2 * self.num_layers * self.page_size * self.num_kv_heads * self.head_dim * itemsize
+        buffers = 1 if self.latent_lanes else 2
+        return buffers * self.num_layers * self.page_size * self.lanes * itemsize
 
 
 def plan_cache(
@@ -199,16 +210,42 @@ def plan_recurrent_cache(
     return spec, state
 
 
+def plan_latent_cache(
+    model: ModelConfig,
+    cache: CacheConfig,
+    hbm_limit: int | None = None,
+    hbm_in_use: int = 0,
+    workspace: int = 0,
+) -> KvCacheSpec:
+    """Pages of a latent cache: one buffer of ``entry_lanes`` a token and
+    layer.  ``hbm_limit`` and ``hbm_in_use`` are the tightest device's, read
+    **after the weights are on it**, so the weights come off once, as in
+    ``plan_recurrent_cache``.  ``workspace``: bytes the largest program needs
+    beside its arguments, kept free of pages (where the weights take most of
+    the device, what ``hbm_utilization`` leaves does not hold a prefill)."""
+    from smg_tpu.ops.latent_attention import entry_lanes
+
+    spec = KvCacheSpec(
+        num_layers=model.num_layers,
+        num_pages=cache.num_pages,
+        page_size=cache.page_size,
+        num_kv_heads=model.num_kv_heads,
+        head_dim=model.head_dim,
+        dtype=cache.dtype,
+        latent_lanes=entry_lanes(model.kv_lora_rank, model.qk_rope_head_dim),
+    )
+    if cache.auto_size and hbm_limit is not None:
+        budget = int(hbm_limit * cache.hbm_utilization) - hbm_in_use - workspace
+        spec.num_pages = int(max(budget // spec.bytes_per_page, 16))
+    return spec
+
+
 def create_kv_buffers(spec: KvCacheSpec, sharding=None) -> tuple[jax.Array, jax.Array]:
     """Allocate zeroed K and V buffers (optionally with a NamedSharding)."""
-    shape = spec.shape
     dtype = jnp.dtype(spec.dtype)
     if sharding is not None:
         # smglint: disable-next=RETRACE runs at engine init / idle flush_cache only
-        zeros = jax.jit(lambda: jnp.zeros(shape, dtype), out_shardings=(sharding))
-        k = zeros()
-        v = zeros()
+        zeros = lambda shape: jax.jit(lambda: jnp.zeros(shape, dtype), out_shardings=(sharding))()
     else:
-        k = jnp.zeros(shape, dtype)
-        v = jnp.zeros(shape, dtype)
-    return k, v
+        zeros = lambda shape: jnp.zeros(shape, dtype)
+    return zeros(spec.shape), zeros(spec.v_shape)

@@ -1,0 +1,268 @@
+"""The runner of a model whose cache holds one latent entry a token and no V.
+
+``LatentModelRunner`` is ``ModelRunner`` for ``models/pangu_moe.py``.  The
+cache is one buffer (``kv_cache.plan_latent_cache``: sized from the device
+read after the weights are on it, so the weights come off once); ``v_cache``
+stays an attribute, of zero size, and goes through every program untouched,
+so the three prefill families are ``ModelRunner``'s own programs, names and
+positional signatures.  The decode family is this file's: its side buffer
+holds latent entries and lands through ``land_side_buffer``, a padded lane
+picks no expert, and the expert layers' counts ride out with the frame's
+tokens (``frame_counts``; the scheduler fetches them with the tokens).
+
+Where the decode kernel runs it reads each lane's own pages by the lane's
+``entry``, whatever the table's width, so a decode program is compiled for
+the batch bucket alone, at the widest table (``widest_table_only``).
+
+What this runner refuses: everything in the module's ``SERVING_LIMITS``.
+"""
+
+from __future__ import annotations
+
+import types
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+from smg_tpu.engine.kv_cache import plan_latent_cache
+from smg_tpu.engine.runner import ModelRunner, _attn_label, _pick_sampler, logger
+from smg_tpu.ops.latent_attention import land_side_buffer
+
+
+class LatentModelRunner(ModelRunner):
+    def __init__(self, config, params=None, devices=None):
+        super().__init__(config, params=params, devices=devices)
+        # the expert layers' grouped products: the kernel on a TPU, XLA's
+        # ragged product elsewhere
+        self.moe_impl = ("pallas" if self.platform == "tpu"
+                         and config.attention_impl != "xla" else "xla")
+        module = self.module
+        self.module = types.SimpleNamespace(**{
+            **vars(module),
+            **{f: partial(getattr(module, f), moe_impl=self.moe_impl)
+               for f in ("forward_prefill", "forward_prefill_batched",
+                         "forward_decode_horizon")}})
+        # device int32 [4] of the frame launched last: ``[picks, picks on held
+        # experts, held experts hit, most rows one layer and column computed]``
+        self.frame_counts = None
+        logger.info("latent cache: %d lanes an entry (%d B a token and layer as laid out); "
+                    "expert layers %s, experts held %s of %d",
+                    self.spec.lanes, self.spec.lanes * jnp.dtype(self.spec.dtype).itemsize,
+                    self.moe_impl, self.model_cfg.held_experts, self.model_cfg.num_experts)
+
+    # ---- what a sequence holds ----
+
+    def _plan_cache(self, param_bytes: int):
+        if self.mesh is not None:
+            raise ValueError(self.module.SERVING_LIMITS["mesh"])
+        limit = in_use = None
+        stats = self.local_devices()[0].memory_stats() or {}
+        if "bytes_limit" in stats:
+            limit, in_use = stats["bytes_limit"], stats.get("bytes_in_use", 0)
+        elif self.platform == "tpu":
+            raise RuntimeError("the TPU reports no memory_stats(); cannot size the cache")
+        workspace = self.module.prefill_workspace_bytes(
+            self.model_cfg, self.config.scheduler.max_prefill_tokens, self.config.dtype)
+        return plan_latent_cache(self.model_cfg, self.config.cache, limit, in_use, workspace)
+
+    def latent_info(self) -> dict:
+        cfg, itemsize = self.model_cfg, jnp.dtype(self.spec.dtype).itemsize
+        return {"entry_bytes_published": (cfg.kv_lora_rank + cfg.qk_rope_head_dim) * itemsize,
+                "entry_bytes_laid_out": self.spec.lanes * itemsize,
+                "layout": f"one buffer [layers, pages, {self.spec.page_size}, "
+                          f"{self.spec.lanes}]: latent {cfg.kv_lora_rank}, rotary key "
+                          f"{cfg.qk_rope_head_dim}, padded to whole 128-lane tiles; no V buffer"}
+
+    def moe_info(self) -> dict:
+        cfg = self.model_cfg
+        return {"experts": cfg.num_experts, "experts_held": cfg.held_experts[1],
+                "top_k": cfg.num_experts_per_tok, "impl": self.moe_impl}
+
+    def attention_info(self) -> dict:
+        return {**super().attention_info(), "form": "latent: expanded prefill, absorbed decode"}
+
+    # ``Scheduler._headroom_pages``: a decode frame may count on the radix
+    # cache's unpinned pages.  At 64 lanes a frame of 8 columns needs some 32
+    # new pages, and once finished prompts have filled the pool little more
+    # than the watermark is free: on the free pool alone every frame then
+    # runs one column (3,340 tokens/s before the pool filled, 1,200 after;
+    # PERF.md, Findings, PR 34).  The pages are evicted either way, a few
+    # columns later.
+    unpinned_pages_are_headroom = True
+
+    @property
+    def widest_table_only(self) -> bool:
+        """Decode programs are compiled at the widest page table alone."""
+        return self._attn_impl_for(0, 0) == "pallas"
+
+    def _prefill_impl_for(self, T: int, mp: int) -> str:
+        """Prefill attention has one form here (expanded, XLA), so a prompt cut
+        by a step's budget continues at the grouped path's speed."""
+        return "xla"
+
+    def _split_group(self, lengths: "list[int]") -> "list[list[int]]":
+        """A group is padded to ``G x T``, both rounded up, so one long prompt
+        among short ones makes a program of up to four times the step's
+        token budget, which is more than ``_plan_cache`` keeps room for and
+        mostly padding (and the more prompts wait, the larger the groups:
+        under a burst the waste feeds itself).  The rows go into parts of one
+        token bucket each, the widest first, and a part's padded size stays
+        inside the budget (a row longer than the budget alone)."""
+        sched = self.config.scheduler
+        by_bucket: dict[int, list[int]] = {}
+        for i, n in enumerate(lengths):
+            by_bucket.setdefault(sched.prefill_bucket(n), []).append(i)
+        parts = []
+        for T in sorted(by_bucket, reverse=True):
+            most = 1
+            while 2 * most * T <= sched.max_prefill_tokens:
+                most *= 2
+            rows = by_bucket[T]
+            parts += [rows[k:k + most] for k in range(0, len(rows), most)]
+        return parts
+
+    def prefill_batched(self, chunks, temps, topks, topps, minps, pen=None, mask=None,
+                        lora_idx=None, mm=None, rope=None):
+        """``ModelRunner.prefill_batched``, one call for each part of the
+        group (``_split_group``)."""
+        if lora_idx is not None or mm is not None or rope is not None:
+            raise ValueError(self.module.SERVING_LIMITS["lora"])
+        parts = self._split_group([len(c[0]) for c in chunks])
+        toks, lps = np.zeros(len(chunks), np.int32), np.zeros(len(chunks), np.float32)
+        for rows in parts:
+            toks[rows], lps[rows] = super().prefill_batched(
+                [chunks[i] for i in rows], temps[rows], topks[rows], topps[rows], minps[rows],
+                pen=None if pen is None else tuple(a[rows] for a in pen),
+                mask=None if mask is None else mask[rows])
+        return toks, lps
+
+    # ---- refusals ----
+
+    def load_lora(self, name, weights):
+        raise ValueError(self.module.SERVING_LIMITS["lora"])
+
+    def embed(self, batches):
+        raise ValueError(self.module.SERVING_LIMITS["embeddings"])
+
+    @property
+    def supports_kv_transfer(self) -> bool:
+        return False
+
+    def _no_transfer(self, *_a, **_k):
+        raise ValueError(self.module.SERVING_LIMITS["kv_transfer"])
+
+    export_pages = import_pages = export_pages_device = import_pages_device = _no_transfer
+
+    def _decode_spec_fn(self, *_a, **_k):
+        raise ValueError(self.module.SERVING_LIMITS["speculative"])
+
+    def prefill_extend(self, token_ids, prefix_len, page_table, lora_idx=0, mm=None,
+                       rope_pos=None) -> None:
+        """A chunk that is not the prompt's last runs the ``prefill`` program
+        (one program a bucket instead of two) with the unfolded key, so the
+        key counter stands still, and fetches nothing."""
+        T, mp, base, *_ = self._prefill_chunk_prep(
+            token_ids, prefix_len, page_table, lora_idx, mm, rope_pos)
+        up = self.upload
+        _tok, _lp, self.k_cache, self.v_cache = self._prefill_fn(T, mp)(
+            *base, self._rng_key, up([0.0], jnp.float32), up([-1], jnp.int32),
+            up([1.0], jnp.float32), up([0.0], jnp.float32))
+
+    # ---- the decode family ----
+
+    def _decode_multi_fn(self, B: int, mp: int, N: int, E: int = 0,
+                         use_pen: bool = False, use_mask: bool = False,
+                         use_lora: bool = False, use_mrope: bool = False):
+        """``ModelRunner._decode_multi_fn``'s megastep for this model: the
+        same loop, stop detection and in-loop key folds over one latent side
+        buffer, with the expert layers' counts summed over the columns run."""
+        if use_lora or use_mrope:
+            raise ValueError(self.module.SERVING_LIMITS["lora"])
+        use_stop = E > 0
+        attn_impl = self._attn_impl_for(B, mp)
+        k = ("decode_multi", B, mp, N, E, attn_impl, self.moe_impl, use_pen, use_mask)
+        if k in self._compiled:
+            return self._compiled[k]
+        cfg, module = self.model_cfg, self.module
+        L, W = cfg.num_layers, self.spec.lanes
+        from smg_tpu.engine.sampling import apply_penalties
+
+        def multi(params, inv_freq, tokens, entry_pos, kc, vc, page_tables,
+                  base_key, step0, n_steps, temps, topks, topps, minps, *extra):
+            i = 0
+            if use_pen:
+                counts_buf, pmask_buf, slot_idx, freqs, pres, reps = extra[:6]
+                i = 6
+            mask = None
+            if use_mask:
+                mask = extra[i]
+                i += 1
+            if use_stop:
+                stop_ids, limits, live = extra[i], extra[i + 1], extra[i + 2]
+            # a padded lane sits past its table (``Scheduler._launch_frame``)
+            holds = entry_pos < page_tables.shape[1] * kc.shape[2]
+            side0 = jnp.zeros((L, B, N, W), kc.dtype)
+            counts0 = counts_buf[slot_idx] if use_pen else jnp.zeros((B, 0))
+            pmask = pmask_buf[slot_idx] if use_pen else None
+            sampler = _pick_sampler()
+            done0 = (~live) if use_stop else jnp.zeros((B,), jnp.bool_)
+
+            def cond(carry):
+                j, done = carry[0], carry[6]
+                ok = j < n_steps
+                if use_stop:
+                    ok = jnp.logical_and(ok, ~jnp.any(done & live))
+                return ok
+
+            def body(carry):
+                j, cur, toks_out, lps_out, side, counts, done, routed = carry
+                logits, side, c = module.forward_decode_horizon(
+                    params, cfg, inv_freq, cur, entry_pos + j, entry_pos, j,
+                    kc, page_tables, side, holds, attn_impl=attn_impl)
+                routed = module.merge_counts(routed, c)
+                if use_pen:
+                    logits = apply_penalties(logits, counts, pmask, freqs, pres, reps)
+                kj = jax.random.split(jax.random.fold_in(
+                    base_key, step0 + j.astype(jnp.uint32) + jnp.uint32(1)), 1)[0]
+                new, lps = sampler(logits, kj, temps, topks, topps, minps, mask=mask)
+                if use_pen:
+                    counts = counts.at[jnp.arange(B), new].add(1)
+                toks_out = lax.dynamic_update_slice(
+                    toks_out, new[:, None].astype(jnp.int32), (0, j))
+                lps_out = lax.dynamic_update_slice(
+                    lps_out, lps[:, None].astype(jnp.float32), (0, j))
+                if use_stop:
+                    tok_done = jnp.any(new[:, None] == stop_ids, axis=1)
+                    done = done | tok_done | ((entry_pos + j) >= (limits - 2))
+                return (j + 1, new, toks_out, lps_out, side, counts, done, routed)
+
+            init = (jnp.int32(0), tokens, jnp.zeros((B, N), jnp.int32),
+                    jnp.zeros((B, N), jnp.float32), side0, counts0, done0,
+                    jnp.zeros((4,), jnp.int32))
+            steps_run, _cur, outs, lps, side, counts, _done, routed = \
+                lax.while_loop(cond, body, init)
+            kc = land_side_buffer(kc, side, page_tables, entry_pos,
+                                  jnp.arange(N)[None, :] < steps_run)
+            out = (outs, lps, steps_run, kc, vc)
+            if use_pen:
+                out += (counts_buf.at[slot_idx].set(counts),)
+            return out + (routed,)
+
+        donate = (4, 5) + ((14,) if use_pen else ())
+        if not self.donation.donate_kv:
+            donate = ()
+        fn = self._register(k, jax.jit(multi, donate_argnums=donate), donate=donate,
+                            in_shardings=None, attn=_attn_label("decode", attn_impl),
+                            products=("fused_lanes" if attn_impl == "xla" else None))
+
+        def launch(*args):
+            """What ``ModelRunner.decode_multi_async`` unpacks; the frame's
+            counts stay behind as ``frame_counts`` (a device array)."""
+            *out, self.frame_counts = fn(*args)
+            return out
+
+        self._compiled[k] = launch
+        return launch

@@ -380,6 +380,13 @@ class EngineMetrics:
             "shrank K), cap (horizon_cap is 1)",
             ["horizon_reason"], registry=r,
         ))
+        self.moe_picks = _track(Counter(
+            "smg_engine_moe_picks_total",
+            "Token-expert pairs the decode frames consumed routed, by whether "
+            "the picked expert is held by this process (held=\"true\": rows "
+            "its expert layers computed) or by another (held=\"false\")",
+            ["held"], registry=r,
+        ))
 
     # ---- registry unification ----
 

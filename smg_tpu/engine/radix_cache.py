@@ -10,11 +10,18 @@ what the gateway's ``PositionalIndexer`` consumes for cache-aware routing
 Tree keys are full-page token tuples (page_size tokens); partial tail pages
 are never cached.  Nodes hold one page each, a refcount (pages pinned by
 running requests can't be evicted) and an LRU stamp.
+
+Eviction takes the oldest unpinned leaf first.  The leaves wait in a heap by
+their stamp (``_lru``), entered when a node becomes a leaf someone could
+evict and checked when they come out, so ``evict`` costs what it frees and
+not a walk of the tree: at 34,000 cached pages the walk and its sort were
+6 ms, once a decode launch and once an admission (PERF.md, Findings, PR 34).
 """
 
 from __future__ import annotations
 
 import hashlib
+import heapq
 import itertools
 from dataclasses import dataclass, field
 from typing import Callable
@@ -55,6 +62,7 @@ class RadixCache:
         self.page_size = page_size
         self.root = RadixNode(key=(), page=-1, parent=None, block_hash=0)
         self._size = 0  # pages held by the tree
+        self._pinned = 0  # of them, pages a live request holds (refcount > 0)
         # cumulative eviction count (LRU evict + clear) — the cache is the
         # single authority on what left the tree; hit/miss accounting lives
         # in the scheduler (admission-time) because match_prefix re-probes
@@ -62,13 +70,38 @@ class RadixCache:
         self.evicted_pages = 0
         self._event_sink = event_sink
         self._clock = itertools.count()
+        # (stamp, id, node) of every leaf that was unpinned when it got its
+        # stamp or lost its last pin or child; an entry whose node has since
+        # been touched, pinned, extended or removed is dropped when it comes out
+        self._lru: list[tuple[int, int, RadixNode]] = []
 
     @property
     def num_cached_pages(self) -> int:
         return self._size
 
+    @property
+    def num_unpinned_pages(self) -> int:
+        """Cached pages no live request holds: ``evict`` can free every one
+        (a pin counts on the whole path to the root, so an unpinned node has
+        no pinned node below it)."""
+        return self._size - self._pinned
+
     def _touch(self, node: RadixNode) -> None:
         node.last_access = next(self._clock)
+
+    def _offer(self, node: RadixNode) -> None:
+        """``node`` may be an evictable leaf from now on: a traversal ended on
+        it, or it lost its last pin, or its last child."""
+        if node is not self.root and not node.children and node.refcount == 0:
+            heapq.heappush(self._lru, (node.last_access, id(node), node))
+            if len(self._lru) > 4 * self._size + 1024:  # mostly stale entries
+                self._lru = [(n.last_access, id(n), n) for n in self._iter_nodes()
+                             if n.is_leaf and n.refcount == 0]
+                heapq.heapify(self._lru)
+
+    def _evictable(self, stamp: int, node: RadixNode) -> bool:
+        return (node.last_access == stamp and not node.children and node.refcount == 0
+                and node.parent.children.get(node.key) is node)
 
     def _emit(self, ev: KvEvent) -> None:
         if self._event_sink is not None:
@@ -106,6 +139,7 @@ class RadixCache:
             node = child
             self._touch(node)
             pages.append(node.page)
+        self._offer(node)
         return pages, node
 
     # ---- pinning ----
@@ -113,13 +147,18 @@ class RadixCache:
     def lock(self, node: RadixNode) -> None:
         while node is not self.root and node is not None:
             node.refcount += 1
+            self._pinned += node.refcount == 1
             node = node.parent
 
     def unlock(self, node: RadixNode) -> None:
+        deepest = node
         while node is not self.root and node is not None:
             node.refcount -= 1
             assert node.refcount >= 0, "radix cache refcount underflow"
+            self._pinned -= node.refcount == 0
             node = node.parent
+        if deepest is not None:
+            self._offer(deepest)  # the one node of the path that can be a leaf
 
     # ---- insert ----
 
@@ -166,6 +205,7 @@ class RadixCache:
             stored_tokens.extend(page_tokens)
             node = child
             self._touch(node)
+        self._offer(node)
         if stored_hashes:
             self._emit(
                 BlockStored(
@@ -184,15 +224,10 @@ class RadixCache:
         (caller returns them to the PagePool)."""
         freed: list[int] = []
         removed_hashes: list[int] = []
-        # collect evictable leaves, oldest first
-        leaves = [
-            n for n in self._iter_nodes() if n.is_leaf and n.refcount == 0
-        ]
-        leaves.sort(key=lambda n: n.last_access)
-        for leaf in leaves:
-            if len(freed) >= n_pages:
-                break
-            node = leaf
+        while len(freed) < n_pages and self._lru:
+            stamp, _, node = heapq.heappop(self._lru)
+            if not self._evictable(stamp, node):
+                continue
             # walk up freeing chains that become evictable leaves
             while (
                 node is not self.root
@@ -206,6 +241,7 @@ class RadixCache:
                 removed_hashes.append(node.block_hash)
                 self._size -= 1
                 node = parent
+            self._offer(node)  # a parent left behind as a leaf waits its turn
         if removed_hashes:
             self._emit(BlockRemoved(block_hashes=removed_hashes))
         self.evicted_pages += len(freed)

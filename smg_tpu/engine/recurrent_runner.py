@@ -446,3 +446,7 @@ class RecurrentModelRunner(ModelRunner):
         if pen is not None:
             self._counts_buf = out[8]
         return toks, lps, steps_run
+
+    # no match is honoured without the state at its end, so no cached page is
+    # ever pinned and every one is a frame's to count on (``_headroom_pages``)
+    unpinned_pages_are_headroom = True

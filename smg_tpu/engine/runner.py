@@ -186,7 +186,7 @@ class ModelRunner:
             rope_frequencies(
                 # rope_theta 0: the model applies no rotary embedding and
                 # never reads these
-                self.model_cfg.head_dim, self.model_cfg.rope_theta or 10000.0,
+                self.model_cfg.rope_dim, self.model_cfg.rope_theta or 10000.0,
                 self.model_cfg.rope_scaling
             )
         )
@@ -362,8 +362,8 @@ class ModelRunner:
             # Mosaic kernels are not partitioned by GSPMD and the two
             # pallas_calls are not wrapped in shard_map
             mode, why = "xla", "the kernels are not partitioned over a mesh"
-        elif (self.model_cfg.num_kv_heads * self.model_cfg.head_dim) % 128:
-            mode, why = "xla", "kv_heads*head_dim is not a multiple of 128 lanes"
+        elif self.spec.lanes % 128:
+            mode, why = "xla", "the cache's lanes are not a multiple of 128"
         logger.info("attention impl: %s%s", mode, f" ({why})" if why else "")
         return mode
 
@@ -1802,3 +1802,10 @@ class ModelRunner:
     def flush_cache_buffers(self) -> None:
         """Zero the KV buffers (used by flush_cache after the radix reset)."""
         self.k_cache, self.v_cache = create_kv_buffers(self.spec, self.kv_sharding)
+
+    # What the scheduler asks a runner (``Scheduler._headroom_pages``,
+    # ``_mp_bucket``): whether a decode frame may count on the radix cache's
+    # pages no live request holds, and whether decode programs exist at the
+    # widest page table alone.  The Llama family says no to both.
+    unpinned_pages_are_headroom = False
+    widest_table_only = False

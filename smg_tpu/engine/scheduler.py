@@ -128,6 +128,8 @@ class InFlightFrame:
     # discarding it has nothing to take out of the state again.
     clean: "object" = None  # jax.Array scalar bool
     ran: bool = True
+    # routed experts: the frame's counts (``LatentModelRunner.frame_counts``)
+    routed: "object" = None  # jax.Array int32 [4]
 
 
 def _launch_attrs(frame: "InFlightFrame") -> dict:
@@ -152,6 +154,10 @@ class Scheduler:
         self.sched = config.scheduler
         self.ps = runner.spec.page_size
         self.mp = runner.max_pages_per_seq
+        if runner.widest_table_only:
+            # the decode kernel reads each lane's own pages, so the runner has
+            # one decode program a batch bucket, at the widest table
+            self._mp_bucket = lambda pages_needed: self.mp
         self.pool = PagePool(runner.spec.num_pages)
         self.radix = (
             RadixCache(self.ps, event_sink) if self.sched.enable_prefix_cache else None
@@ -217,6 +223,12 @@ class Scheduler:
         self._picked_reason = ""
         self._step_horizon_reason = ""
         self.num_decode_launches = dict.fromkeys(HORIZON_REASONS, 0)
+        self._step_columns_run = 0
+        # routed experts, over the decode frames consumed: token-expert pairs,
+        # those on held experts (rows computed), held experts hit summed over
+        # layers and columns, and the most rows one layer and column computed
+        self.moe_counts = [0, 0, 0, 0] if hasattr(runner, "moe_info") else None
+        self._step_moe = None
         # step-scoped speculative-decoding telemetry (flight-recorder ring
         # spec fields) + the acceptance-length EMA the adaptive depth
         # controller reads (_pick_spec_depth)
@@ -469,6 +481,12 @@ class Scheduler:
                 "state_recomputed_tokens": self.num_state_recomputed_tokens,
                 "linattn_decode": info["linattn_decode"],
             })
+        if self.moe_counts is not None:
+            picks, held, hit, rows_max = self.moe_counts
+            out["moe"] = {**self.runner.moe_info(), "picks": picks, "picks_held": held,
+                          "experts_hit": hit, "rows_max": rows_max}
+        if hasattr(self.runner, "latent_info"):
+            out["latent_cache"] = self.runner.latent_info()
         if self.metrics is not None:
             # rolling-window live signal (p50/p95 step time, tokens/s) for
             # the /scheduler endpoint, dp-aware routing, and benchmarks
@@ -567,6 +585,8 @@ class Scheduler:
         self._step_spec_drafted = 0
         self._step_spec_accepted = 0
         self._step_state_lanes = 0
+        self._step_columns_run = 0
+        self._step_moe = None
         pf0, dc0 = self.num_prefill_tokens, self.num_decode_tokens
         we0, ee0 = self.num_wasted_decode_tokens, self.num_megastep_early_exits
         t0 = time.perf_counter()
@@ -611,6 +631,8 @@ class Scheduler:
                     mesh=self._mesh_devices,
                     horizon_reason=self._step_horizon_reason,
                     state_lanes=self._step_state_lanes,
+                    columns_run=self._step_columns_run,
+                    moe=self._step_moe,
                 )
                 self.flush_pending_dumps()
         return outputs
@@ -1062,6 +1084,19 @@ class Scheduler:
                 if req.sampling.has_penalties and not req.is_finished:
                     req.penalty_synced = False
 
+    def _count_routed(self, routed: list) -> None:
+        """The expert layers' counts of a consumed decode frame."""
+        picks, held, hit, rows_max = routed
+        c = self.moe_counts
+        c[0] += picks
+        c[1] += held
+        c[2] += hit
+        c[3] = max(c[3], rows_max)
+        self._step_moe = (held, hit)
+        if self.metrics is not None:
+            self.metrics.moe_picks.labels(held="true").inc(held)
+            self.metrics.moe_picks.labels(held="false").inc(picks - held)
+
     def _state_lost(self, reqs: list, why: str) -> None:
         """A frame advanced these sequences' recurrent state by columns that
         are not accepted (a discarded frame that ran, or a host trim short of
@@ -1161,8 +1196,8 @@ class Scheduler:
             rids=",".join(r.rid for _s, r, _e in frame.lanes),
         )
         t0 = time.perf_counter()
-        toks, lps, steps_run, clean = jax.device_get(
-            (frame.toks, frame.lps, frame.steps_run, frame.clean)
+        toks, lps, steps_run, clean, routed = jax.device_get(
+            (frame.toks, frame.lps, frame.steps_run, frame.clean, frame.routed)
         )
         # recurrent models: what a frame chained on this one did (ran, or ran
         # no column because this one met a finish)
@@ -1185,6 +1220,9 @@ class Scheduler:
                 if col + 1 < used:
                     used = col + 1
         self._step_horizon = frame.horizon
+        self._step_columns_run = min(frame.horizon, sr)
+        if routed is not None:
+            self._count_routed([int(x) for x in routed])
         if sr < frame.horizon:
             self.num_megastep_early_exits += 1
         if sr > used:
@@ -1309,6 +1347,7 @@ class Scheduler:
         self._count_decode_launch()
         return InFlightFrame(
             clean=getattr(self.runner, "frame_clean", None),
+            routed=getattr(self.runner, "frame_counts", None),
             lanes=[(s, r, e + H) for s, r, e in frame.lanes],
             toks=toks, lps=lps, horizon=H2, B=frame.B, B_real=frame.B_real,
             mp_b=mp_b, positions=positions, lane_sig=frame.lane_sig,
@@ -2041,15 +2080,16 @@ class Scheduler:
 
     def _headroom_pages(self) -> int:
         """Pages a decode launch may count on without preempting anyone: the
-        free pool.  For a model with recurrent layers the radix cache's
-        pages count too: no match is honoured there without the state at its
-        end, so no cached page is ever pinned, every one can be evicted, and
-        evicting it takes nothing a later request could have used.  (With
-        the free pool alone such a model decodes one or two columns a frame
-        as soon as finished prompts have filled the pool.)"""
+        free pool and, where the runner says so, the radix cache's pages no
+        live request holds.  Such a page is freed by the next column that
+        needs one whatever the horizon, and never by a preemption, so
+        counting it moves no stream; with the free pool alone a frame runs
+        one or two columns as soon as finished prompts have filled the pool.
+        (A model with recurrent layers pins no cached page at all: no match
+        is honoured there without the state at its end.)"""
         free = self.pool.free_count
-        if self.state_pool is not None and self.radix is not None:
-            free += self.radix.num_cached_pages
+        if self.radix is not None and self.runner.unpinned_pages_are_headroom:
+            free += self.radix.num_unpinned_pages
         return free
 
     def _state_kw(self, ds: DecodeState, chain=None) -> dict:
@@ -2257,6 +2297,7 @@ class Scheduler:
         self._count_decode_launch()
         return InFlightFrame(
             clean=getattr(self.runner, "frame_clean", None),
+            routed=getattr(self.runner, "frame_counts", None),
             lanes=[(i, r, r.seq_len) for i, r in active],
             toks=toks, lps=lps, horizon=horizon, B=B, B_real=B_real,
             mp_b=mp_b, positions=positions, lane_sig=sig,

@@ -65,6 +65,45 @@ class ModelConfig:
     linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 0
     linear_allow_neg_eigval: bool = False
+    # ---- latent attention (``pangu_ultra_moe``): queries through a rank of
+    # ``q_lora_rank``, keys and values through one latent of ``kv_lora_rank``
+    # and one rotary key of ``qk_rope_head_dim`` a token, which is all a token
+    # leaves in the cache.  ``head_dim`` is then the width queries meet keys
+    # at (``qk_nope_head_dim + qk_rope_head_dim``).  0 = ordinary attention.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # ---- routed experts beyond the Qwen-MoE settings.  ``num_experts`` is the
+    # router's width and is never cut; ``experts_held`` is the contiguous range
+    # ``(first, count)`` of routed experts this process holds (None: all).
+    first_k_dense_replace: int = 0  # leading layers with a dense MLP
+    n_shared_experts: int = 0
+    moe_scoring: str = "softmax"  # "softmax" | "sigmoid" over all experts
+    norm_topk_prob: bool = True  # renormalise the top-k scores to sum 1
+    routed_scaling_factor: float = 1.0
+    experts_held: "tuple[int, int] | None" = None
+    # random weights only (``init_params``): the scale of the routed experts'
+    # output projections beside the shared expert's.  Whoever compares random
+    # weights against a reference sets it (``random_routed_out_gain``), not
+    # the program: 1 draws every expert alike.
+    random_routed_out_gain: float = 1.0
+
+    @property
+    def latent_cache(self) -> bool:
+        """A token leaves one latent entry in the cache and no V."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def rope_dim(self) -> int:
+        """Lanes the rotary embedding turns."""
+        return self.qk_rope_head_dim or self.head_dim
+
+    @property
+    def held_experts(self) -> "tuple[int, int]":
+        """``(first, count)`` of the routed experts this process holds."""
+        return self.experts_held or (0, self.num_experts)
 
     @property
     def num_cache_layers(self) -> int:
@@ -91,6 +130,19 @@ class ModelConfig:
     def from_hf_config(cls, cfg: dict, dtype: str = "bfloat16") -> "ModelConfig":
         if cfg.get("model_type") == "olmo_hybrid":
             return cls._from_olmo_hybrid(cfg, dtype)
+        if cfg.get("model_type") == "pangu_ultra_moe":
+            return cls._from_pangu_ultra_moe(cfg, dtype)
+        # keys that change what the layers compute and that this path would
+        # drop in silence: routed experts beyond Qwen-MoE's settings, latent
+        # attention.  A config that carries one is another model (D6's rule).
+        foreign = sorted(k for k in cls._LLAMA_PATH_REFUSED
+                         if cfg.get(k) not in (None, 0, False))
+        if cfg.get("norm_topk_prob") is False:
+            foreign.append("norm_topk_prob")
+        if foreign:
+            raise ValueError(
+                f"config.json (model_type {cfg.get('model_type')!r}) has keys the Llama-family "
+                f"loader does not consume: {foreign}; it would be served as another model")
         arch_names = cfg.get("architectures") or ["LlamaForCausalLM"]
         arch = "llama"
         name = arch_names[0].lower()
@@ -173,6 +225,10 @@ class ModelConfig:
             **extra,
         )
 
+    _LLAMA_PATH_REFUSED = ("n_routed_experts", "n_shared_experts", "kv_lora_rank",
+                           "q_lora_rank", "scoring_func", "first_k_dense_replace",
+                           "routed_scaling_factor", "qk_rope_head_dim")
+
     # what an ``olmo_hybrid`` config.json may hold: keys this loader turns
     # into the model's shape, and keys that bear on no shape.  Any other key
     # is an error, so that a config this program would serve wrong fails to
@@ -246,6 +302,93 @@ class ModelConfig:
             linear_value_head_dim=cfg["linear_value_head_dim"],
             linear_conv_kernel_dim=cfg["linear_conv_kernel_dim"],
             linear_allow_neg_eigval=bool(cfg.get("linear_allow_neg_eigval", False)),
+        )
+
+    # ``pangu_ultra_moe`` (openPangu-Ultra-MoE): the same rule as above.
+    _PANGU_CONSUMED = frozenset({
+        "model_type", "vocab_size", "hidden_size", "intermediate_size",
+        "moe_intermediate_size", "num_hidden_layers", "num_attention_heads",
+        "num_key_value_heads", "hidden_act", "max_position_embeddings", "attention_bias",
+        "rms_norm_eps", "tie_word_embeddings", "rope_theta", "rope_scaling",
+        "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
+        "first_k_dense_replace", "n_routed_experts", "n_shared_experts",
+        "num_experts_per_tok", "routed_scaling_factor", "norm_topk_prob", "scoring_func",
+        "sandwich_norm", "num_nextn_predict_layers", "eos_token_id", "bos_token_id",
+        # the chip's share of a deployment (model-configs guide, section 4):
+        # ``n_routed_experts`` counts the experts held here, these two say of
+        # how many the router chooses and where the held range starts
+        "router_num_experts", "routed_expert_offset",
+        # random weights only: see ``ModelConfig.random_routed_out_gain``
+        "random_routed_out_gain",
+    })
+
+    @classmethod
+    def _from_pangu_ultra_moe(cls, cfg: dict, dtype: str) -> "ModelConfig":
+        unknown = sorted(set(cfg) - cls._PANGU_CONSUMED - cls._OLMO_HYBRID_SHAPELESS)
+        if unknown:
+            raise ValueError(
+                f"pangu_ultra_moe config.json has keys this loader does not consume: {unknown}; "
+                "a key that may bear on the model's shape is not dropped in silence")
+        if cfg.get("hidden_act", "silu") != "silu":
+            raise ValueError(f"pangu_ultra_moe: hidden_act {cfg['hidden_act']!r} is not served")
+        if cfg.get("attention_bias"):
+            raise ValueError("pangu_ultra_moe: attention_bias is not served")
+        if not cfg.get("sandwich_norm", False):
+            raise ValueError("pangu_ultra_moe: only sandwich_norm true is served")
+        if cfg.get("rope_scaling") not in (None, "none"):
+            raise ValueError(f"pangu_ultra_moe: rope_scaling {cfg['rope_scaling']} is not served")
+        if cfg.get("scoring_func", "sigmoid") not in ("sigmoid", "softmax"):
+            raise ValueError(f"pangu_ultra_moe: scoring_func {cfg['scoring_func']!r} is not served")
+        heads = cfg["num_attention_heads"]
+        if cfg.get("num_key_value_heads", heads) != heads:
+            raise ValueError("pangu_ultra_moe: latent attention has one latent for all heads; "
+                             "num_key_value_heads must equal num_attention_heads")
+        held = cfg["n_routed_experts"]
+        width = cfg.get("router_num_experts", held)
+        first = cfg.get("routed_expert_offset", 0)
+        if not (0 <= first and first + held <= width):
+            raise ValueError(
+                f"pangu_ultra_moe: experts {first}..{first + held - 1} are not among "
+                f"the router's {width}")
+        layers, dense = cfg["num_hidden_layers"], cfg.get("first_k_dense_replace", 0)
+        if not 0 <= dense <= layers:
+            raise ValueError(f"pangu_ultra_moe: first_k_dense_replace {dense} of {layers} layers")
+        # ``num_nextn_predict_layers``: the next-token prediction module is a
+        # drafter; the model's own logits do not depend on it.  It is consumed
+        # here and neither loaded nor served (SERVING_LIMITS["speculative"]).
+        eos = cfg.get("eos_token_id", 2)
+        return cls(
+            arch="pangu_ultra_moe",
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=layers,
+            num_heads=heads,
+            num_kv_heads=heads,
+            head_dim=cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"],
+            rope_theta=float(cfg.get("rope_theta", 10000.0)),
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
+            max_position_embeddings=cfg.get("max_position_embeddings", 8192),
+            tie_word_embeddings=bool(cfg.get("tie_word_embeddings", False)),
+            eos_token_ids=tuple(eos) if isinstance(eos, list) else (eos,),
+            bos_token_id=cfg.get("bos_token_id", 1),
+            dtype=dtype,
+            post_norms=True,
+            num_experts=width,
+            num_experts_per_tok=cfg["num_experts_per_tok"],
+            moe_intermediate_size=cfg["moe_intermediate_size"],
+            q_lora_rank=cfg["q_lora_rank"],
+            kv_lora_rank=cfg["kv_lora_rank"],
+            qk_nope_head_dim=cfg["qk_nope_head_dim"],
+            qk_rope_head_dim=cfg["qk_rope_head_dim"],
+            v_head_dim=cfg["v_head_dim"],
+            first_k_dense_replace=dense,
+            n_shared_experts=cfg.get("n_shared_experts", 0),
+            moe_scoring=cfg.get("scoring_func", "sigmoid"),
+            norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
+            routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
+            experts_held=(first, held),
+            random_routed_out_gain=float(cfg.get("random_routed_out_gain", 1.0)),
         )
 
     @classmethod
@@ -352,6 +495,38 @@ def tiny_olmo_hybrid_config(vocab_size: int = 512) -> ModelConfig:
     )
 
 
+def tiny_pangu_moe_config(vocab_size: int = 512, held: "tuple[int, int] | None" = None
+                          ) -> ModelConfig:
+    """Tiny openPangu-Ultra-MoE for CPU tests: one dense layer and two expert
+    layers, latent attention whose cache entry (96 + 32) fills one 128-lane
+    tile, 16 routed experts (top 4) of which ``held`` are here (None: all)."""
+    import dataclasses
+
+    return dataclasses.replace(
+        tiny_test_config(vocab_size),
+        arch="pangu_ultra_moe",
+        num_layers=3,
+        num_heads=4,
+        num_kv_heads=4,
+        head_dim=48,
+        post_norms=True,
+        num_experts=16,
+        num_experts_per_tok=4,
+        moe_intermediate_size=64,
+        q_lora_rank=64,
+        kv_lora_rank=96,
+        qk_nope_head_dim=32,
+        qk_rope_head_dim=16,
+        v_head_dim=32,
+        first_k_dense_replace=1,
+        n_shared_experts=1,
+        moe_scoring="sigmoid",
+        norm_topk_prob=True,
+        routed_scaling_factor=2.5,
+        experts_held=held,
+    )
+
+
 def tiny_gemma2_config(vocab_size: int = 512) -> ModelConfig:
     """Tiny Gemma-2-style model for CPU tests: gelu MLP, (1+w) norms,
     scaled embeddings, post norms, attn/final softcaps, tied unembed."""
@@ -388,6 +563,7 @@ PRESETS = {
     "tiny-moe": tiny_moe_config,
     "tiny-vlm": tiny_vlm_config,
     "tiny-olmo-hybrid": tiny_olmo_hybrid_config,
+    "tiny-pangu-moe": tiny_pangu_moe_config,
     "llama3.2-1b": llama32_1b_config,
     "llama3-8b": llama3_8b_config,
     "llama3-70b": llama3_70b_config,

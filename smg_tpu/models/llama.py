@@ -214,8 +214,8 @@ def _lora_delta(x: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray,
     """Per-token multi-adapter LoRA delta, dense one-hot dispatch.
 
     ``x`` [..., E_in], ``a`` [N, E_in, r], ``b`` [N, r, E_out] (alpha/r scaling
-    pre-folded into b), ``gates`` [..., N] one-hot adapter selection.  Same
-    TPU-first trade as ``_moe_mlp``: compute every adapter's (tiny, rank-r)
+    pre-folded into b), ``gates`` [..., N] one-hot adapter selection.  A
+    TPU-first trade: compute every adapter's (tiny, rank-r)
     delta and mask — static shapes, no routing collectives; adapter slot 0 is
     all-zeros so un-adapted tokens pay nothing semantically (reference LoRA
     serving: Load/Unload/ListLoRAAdapter, sglang_scheduler.proto:48-62)."""
@@ -259,27 +259,23 @@ def _mlp(layer: Params, h: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
 
 
 def _moe_mlp(layer: Params, h: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
-    """Mixture-of-experts FFN (Qwen-MoE family), EP-sharded dense dispatch.
+    """Mixture-of-experts FFN (Qwen-MoE family): the tree's one expert layer
+    (``ops/moe.py``) with this family's settings: a softmax over all experts
+    renormalised over the top-k (the same numbers as a softmax over the top-k
+    logits), no scaling, every expert held here.  The token-expert pairs are
+    sorted by expert and go through grouped products (XLA's ragged product),
+    so the work follows the ``T x k`` rows routed and no ``[T, experts, ..]``
+    array exists.  Under an ``ep`` mesh GSPMD partitions the products as it
+    sees fit; an exchange between chips that hold different experts is not
+    written yet (ROADMAP M1)."""
+    from smg_tpu.ops import moe
 
-    TPU-first formulation: all experts computed with a gating mask — the
-    expert dim shards over the ``ep`` mesh axis so each device computes its
-    expert shard for every token and GSPMD psums the combine.  Dense dispatch
-    trades FLOPs (num_experts/top_k x) for zero routing collectives and
-    static shapes; sorted token dispatch is the planned optimization for
-    large expert counts."""
-    X = layer["router"].shape[-1]
-    k = max(cfg.num_experts_per_tok, 1)
-    logits = jnp.einsum("...e,ex->...x", h, layer["router"]).astype(jnp.float32)
-    top_vals, top_idx = jax.lax.top_k(logits, k)  # [..., k]
-    top_probs = jax.nn.softmax(top_vals, axis=-1)  # normalized over top-k (qwen)
-    one_hot = jax.nn.one_hot(top_idx, X, dtype=jnp.float32)  # [..., k, X]
-    gates = jnp.einsum("...kx,...k->...x", one_hot, top_probs)  # [..., X]
-
-    g = jnp.einsum("...e,xef->...xf", h, layer["w_gate"])
-    u = jnp.einsum("...e,xef->...xf", h, layer["w_up"])
-    y = jnp.einsum("...xf,xfe->...xe", jax.nn.silu(g) * u, layer["w_down"])
-    out = jnp.einsum("...xe,...x->...e", y.astype(jnp.float32), gates)
-    return out.astype(h.dtype)
+    x = h.reshape(-1, h.shape[-1])
+    routing = moe.route(x, layer["router"], top_k=max(cfg.num_experts_per_tok, 1),
+                        scoring="softmax", norm_topk=True, scale=1.0)
+    out, _counts = moe.expert_layer(x, routing, layer["w_gate"], layer["w_up"],
+                                    layer["w_down"], (0, layer["router"].shape[-1]))
+    return out.astype(h.dtype).reshape(h.shape)
 
 
 # --------------------------------------------------------------------------

@@ -17,7 +17,11 @@ with state beside its pages):
   ``smg_tpu/train`` and of the tests);
 - a module whose sequences hold state beside their pages also gives
   ``state_shapes(cfg)`` and takes the pools and slots after the page tables
-  (``engine/recurrent_runner.py``).
+  (``engine/recurrent_runner.py``);
+- a module whose cache is one latent buffer (``models/pangu_moe.py``) takes
+  ``v_cache`` of zero size through its prefill forwards untouched, and its
+  decode column takes the one side buffer and the lanes that hold a sequence
+  (``engine/latent_runner.py``).
 """
 
 from __future__ import annotations
@@ -27,7 +31,7 @@ from types import ModuleType
 _REGISTRY: dict[str, ModuleType] = {}
 # architectures this package brings itself, loaded on first use
 _LLAMA_FAMILY = ("llama", "qwen", "mistral", "qwen_moe")
-_BUILTIN = (*_LLAMA_FAMILY, "olmo_hybrid")
+_BUILTIN = (*_LLAMA_FAMILY, "olmo_hybrid", "pangu_ultra_moe")
 
 
 def register_model(arch: str, module: ModuleType) -> None:
@@ -49,6 +53,10 @@ def get_model(arch: str) -> ModuleType:
             from smg_tpu.models import olmo_hybrid
 
             _REGISTRY.setdefault("olmo_hybrid", olmo_hybrid)
+        elif arch == "pangu_ultra_moe":
+            from smg_tpu.models import pangu_moe
+
+            _REGISTRY.setdefault("pangu_ultra_moe", pangu_moe)
         else:
             raise KeyError(
                 f"unsupported model architecture: {arch!r} "

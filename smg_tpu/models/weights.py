@@ -180,12 +180,13 @@ def load_vision_params(engine_cfg):
 def load_params(engine_cfg, mesh=None, rules=None):
     """Load params for ``engine_cfg.model`` from ``engine_cfg.model_path``."""
     cfg = engine_cfg.model
-    if cfg.arch == "olmo_hybrid":
-        from smg_tpu.models.olmo_hybrid import SERVING_LIMITS
+    from smg_tpu.models.registry import get_model as _module_of
 
+    limits = getattr(_module_of(cfg.arch), "SERVING_LIMITS", {})
+    if "checkpoint" in limits:
         # the Llama key map below would load some of its tensors and serve
         # another model
-        raise ValueError(SERVING_LIMITS["checkpoint"])
+        raise ValueError(limits["checkpoint"])
     dtype = jnp.dtype(engine_cfg.dtype)
     handles, location = _open_checkpoint(engine_cfg.model_path)
 

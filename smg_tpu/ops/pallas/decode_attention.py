@@ -77,26 +77,33 @@ def _decode_kernel(
     page_tables_ref,  # [B, mp] int32 (SMEM)
     entry_pos_ref,  # [B] int32 (SMEM) — tokens in cache (exclusive bound)
     meta_ref,  # [3] int32 (SMEM): [n_extra, layer, window] (window<=0 = global)
-    # inputs
-    q_ref,  # [1, H, KD] VMEM (block-diagonal query for this sequence)
-    hk_ref,  # [1, N, KD] VMEM (horizon side buffer, rows 0..n_extra-1 valid)
-    hv_ref,  # [1, N, KD] VMEM
-    k_hbm,  # [L, P*ps, KD] HBM (read-only cache)
-    v_hbm,
-    # outputs
-    out_ref,  # [1, H, KD] VMEM
-    # scratch
-    k_buf,  # [2, n*ps, KD] VMEM: two slots of one block each
-    v_buf,
-    acc_ref,  # [H, KD] f32
-    slot_ref,  # [1] int32 SMEM: the slot this lane's first block is in
-    sems,  # DMA sems [2 (K, V), 2 slots]
-    *,
+    *refs,
     ps: int,
     n: int,  # pages a block
     scale: float,
     softcap: float,
+    latent: int = 0,  # > 0: one latent buffer, whose first ``latent`` lanes are the values
 ):
+    if latent:
+        # a latent cache has no V: side rows, pages and block buffers are the
+        # keys', and a value is the first ``latent`` lanes of its key
+        q_ref, hk_ref, k_hbm, out_ref, k_buf, acc_ref, slot_ref, sems = refs
+        hv_ref = v_hbm = v_buf = None
+    else:
+        (q_ref,  # [1, H, KD] VMEM (block-diagonal query for this sequence)
+         hk_ref,  # [1, N, KD] VMEM (horizon side buffer, rows 0..n_extra-1 valid)
+         hv_ref,  # [1, N, KD] VMEM
+         k_hbm,  # [L, P*ps, KD] HBM (read-only cache)
+         v_hbm,
+         out_ref,  # [1, H, KD] VMEM
+         k_buf,  # [2, n*ps, KD] VMEM: two slots of one block each
+         v_buf,
+         acc_ref,  # [H, KD] f32
+         slot_ref,  # [1] int32 SMEM: the slot this lane's first block is in
+         sems,  # DMA sems [2 (K, V), 2 slots]
+         ) = refs
+    streams = (((k_hbm, k_buf, 0),) if latent
+               else ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)))
     b = pl.program_id(0)
     B = pl.num_programs(0)
     H = q_ref.shape[1]
@@ -141,10 +148,9 @@ def _decode_kernel(
         def page(i, _):
             row0 = pl.multiple_of(page_tables_ref[lane, page0 + i] * ps, ps)
             dst = pl.ds(pl.multiple_of(i * ps, ps), ps)
-            for hbm, buf, sem in ((k_hbm, k_buf, sems.at[0, slot]),
-                                  (v_hbm, v_buf, sems.at[1, slot])):
+            for hbm, buf, s in streams:
                 copy = pltpu.make_async_copy(hbm.at[layer, pl.ds(row0, ps)],
-                                             buf.at[slot, dst], sem)
+                                             buf.at[slot, dst], sems.at[s, slot])
                 if wait:
                     copy.wait()
                 else:
@@ -159,7 +165,8 @@ def _decode_kernel(
         # there, and before the first block that is whatever VMEM held: a
         # masked probability of 0 times a NaN is a NaN (a masked score is
         # replaced, so K needs no such care)
-        v_buf[...] = jnp.zeros_like(v_buf)
+        vals = k_buf if latent else v_buf
+        vals[...] = jnp.zeros_like(vals)
 
     # the slot this lane's first block is in; lane 0 starts its own, and a
     # lane without blocks hands the next lane's first block on at once
@@ -194,7 +201,8 @@ def _decode_kernel(
     m0 = jnp.max(s_side, axis=1, keepdims=True)
     p_side = jnp.exp(s_side - m0)
     l0 = jnp.sum(p_side, axis=1, keepdims=True)
-    acc_ref[...] = weigh(p_side, hv_ref[0])
+    values = (lambda ref, i: ref[i][:, :latent]) if latent else (lambda ref, i: ref[i])
+    acc_ref[...] = weigh(p_side, values(hk_ref if latent else hv_ref, 0))
 
     def body(j, carry):
         m_prev, l_prev = carry
@@ -212,7 +220,7 @@ def _decode_kernel(
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
-        acc_ref[...] = acc_ref[...] * alpha + weigh(p, v_buf[slot])
+        acc_ref[...] = acc_ref[...] * alpha + weigh(p, values(k_buf if latent else v_buf, slot))
         return m_new, l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
 
     _, l = jax.lax.fori_loop(0, blocks, body, (m0, l0))
@@ -302,3 +310,65 @@ def paged_attention_decode_cached(
         v_cache.reshape(L, P * ps, KD),
     )
     return own_lanes(out_kd, K).astype(q.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("latent", "scale", "interpret", "pages_per_block"))
+@jax.named_scope("smg.attn.decode")
+def latent_attention_decode_cached(
+    q: jax.Array,  # [B, H, W] absorbed queries on the cache's lanes
+    cache: jax.Array,  # [L, P, ps, W] read-only latent cache
+    side: jax.Array,  # [B, N, W] horizon side buffer (this layer)
+    n_extra,  # scalar int32: valid side-buffer rows (current token included)
+    layer,  # scalar int32
+    page_tables: jax.Array,  # [B, mp] int32
+    entry_positions: jax.Array,  # [B] int32
+    latent: int,  # the entry's first ``latent`` lanes are its value (a multiple of 128)
+    scale: float,
+    interpret: bool = False,
+    pages_per_block: int | None = None,
+) -> jax.Array:
+    """Absorbed latent attention over a cache with no V buffer: the kernel
+    above with one stream of pages.  All ``H`` query heads meet one "head" of
+    ``W`` lanes (the latent and the rotary key, padded to whole 128-lane
+    tiles); a key's first ``latent`` lanes are its value.  Returns ``sum p c``
+    [B, H, latent] in ``q``'s dtype."""
+    B, H, W = q.shape
+    L, P, ps, _ = cache.shape
+    N = side.shape[1]
+    mp = page_tables.shape[1]
+    cd = cache.dtype
+    if W % 128 or latent % 128 or cache.shape[3] != W:
+        raise ValueError(f"latent entries of {cache.shape[3]} lanes (values {latent}) "
+                         "are not whole 128-lane tiles")
+    n = pages_per_block or _pages_per_block(ps, W, cd.itemsize, mp)
+    if N == 1:  # see ``paged_attention_decode_cached``
+        side = jnp.pad(side, ((0, 0), (0, 1), (0, 0)))
+        N = 2
+    meta = jnp.stack([jnp.asarray(n_extra, jnp.int32), jnp.asarray(layer, jnp.int32),
+                      jnp.int32(0)])
+    kernel = functools.partial(_decode_kernel, ps=ps, n=n, scale=scale, softcap=0.0,
+                               latent=latent)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B,),
+        in_specs=[
+            pl.BlockSpec((1, H, W), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec((1, N, W), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, H, latent), lambda b, *_: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, n * ps, W), cd),
+            pltpu.VMEM((H, latent), jnp.float32),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.SemaphoreType.DMA((1, 2)),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, latent), q.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(page_tables.astype(jnp.int32), entry_positions.astype(jnp.int32), meta,
+      q.astype(cd), side.astype(cd), cache.reshape(L, P * ps, W))

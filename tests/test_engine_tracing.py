@@ -88,7 +88,11 @@ def test_trace_holds_every_span_nested_in_the_step(tmp_path):
     eng.start()
     try:
         eng.start_profile(str(tmp_path))
-        run_all(eng, [[3 + i, 4, 5, 6] for i in range(5)])
+        # 48 tokens each: with two lanes the third prompt has to wait for a
+        # finish however late this thread gets to submit it (at 12 tokens a
+        # loaded machine let the first two end before the third arrived, and
+        # no launch ran K=1)
+        run_all(eng, [[3 + i, 4, 5, 6] for i in range(5)], n=48)
         eng.stop_profile()
     finally:
         eng.stop()

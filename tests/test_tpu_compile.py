@@ -320,3 +320,67 @@ class TestCompilesForV5e:
             s((B, mp), jnp.int32), side, side,
         )
         assert _collectives(compiled.as_text()) == {"all-reduce": 3}
+
+
+PANGU_CUT = {
+    "model_type": "pangu_ultra_moe", "sandwich_norm": True, "hidden_size": 7680,
+    "intermediate_size": 18432, "moe_intermediate_size": 2048, "num_attention_heads": 128,
+    "num_key_value_heads": 128, "q_lora_rank": 1536, "kv_lora_rank": 512,
+    "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "v_head_dim": 128, "n_shared_experts": 1,
+    "num_experts_per_tok": 8, "routed_scaling_factor": 2.5, "rope_theta": 25600000,
+    # the benchmark's cut (benchmark/configs/openpangu-ultra-moe-718b.json)
+    "num_hidden_layers": 5, "first_k_dense_replace": 1, "n_routed_experts": 16,
+    "router_num_experts": 256, "vocab_size": 19200}
+
+
+class TestLatentModelCompilesForV5e:
+    """``models/pangu_moe.py`` at the widths of the benchmark's cut."""
+
+    @pytest.mark.parametrize("B", [8, 64])
+    def test_a_decode_frame_runs_both_kernels_and_copies_no_weights(self, v5e, B):
+        """A frame is a loop of columns over a scan of layers.  Both kernels
+        are in it under their own names, and nothing moves a weight into
+        another layout: stored otherwise, the heads' projections were copied
+        a launch (0.6 GB of temporaries) or a layer and column (75 MB), and
+        an expert layer sliced out of its stack for the kernel would be a
+        copy of 1.4 GB (``models/pangu_moe.init_params``,
+        ``ops/pallas/moe_experts.py``)."""
+        from smg_tpu.models import pangu_moe as M
+        from smg_tpu.models.config import ModelConfig
+        from smg_tpu.ops.latent_attention import land_side_buffer
+
+        cfg = ModelConfig.from_hf_config(PANGU_CUT)
+        one = SingleDeviceSharding(v5e[0])
+        s = lambda shape, dtype=BF16: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+        i32 = jnp.int32
+        params = jax.tree.map(
+            lambda x: s(x.shape, x.dtype),
+            jax.eval_shape(functools.partial(M.init_params, cfg), jax.random.PRNGKey(0)))
+        L, mp, N, P, W = cfg.num_layers, 512, 8, 30000, M.cache_lanes(cfg)
+
+        def frame(p, inv, tok, entry, kc, tables, n_steps):
+            holds = entry < mp * PS
+
+            def body(c):
+                j, cur, side, counts = c
+                logits, side, k = M.forward_decode_horizon(
+                    p, cfg, inv, cur, entry + j, entry, j, kc, tables, side, holds,
+                    attn_impl="pallas", moe_impl="pallas")
+                return j + 1, jnp.argmax(logits, -1).astype(i32), side, counts + k
+
+            j, cur, side, counts = jax.lax.while_loop(
+                lambda c: c[0] < n_steps, body,
+                (i32(0), tok, jnp.zeros((L, B, N, W), kc.dtype), jnp.zeros((4,), i32)))
+            return cur, land_side_buffer(kc, side, tables, entry, jnp.arange(N)[None] < j), counts
+
+        compiled = jax.jit(frame, donate_argnums=(4,)).lower(
+            params, s((cfg.rope_dim // 2,), jnp.float32), s((B,), i32), s((B,), i32),
+            s((L, P, PS, W)), s((B, mp), i32), s((), i32)).compile()
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+        hlo = compiled.as_text()
+        assert _relayouts(hlo, 8 * 2**20) == []  # a 64-lane column moves its own 4 M queries
+        calls = collections.Counter(
+            re.findall(r"%(smg\.[\w.]+?)\.\d+ = \S+ custom-call", hlo))
+        # once in each scanned stack's body: attention in both stacks, the
+        # three grouped products in the expert stack
+        assert calls == {"smg.attn.decode": 2, "smg.moe.experts": 3}
