@@ -13,7 +13,7 @@ Two record kinds:
 
 - **Step ring** — a fixed-size ring of per-step records: step serial, step
   kind (prefill/decode/mixed/idle), batch occupancy, prefill-budget tokens
-  spent, overlap outcome (lookahead kept/discarded/sync) with the
+  spent, overlap outcome (OVERLAP_OUTCOMES) with the
   host-busy vs device-wait split, admissions/finishes, and fault flags.
   One dict append per step; the ring bound makes host memory constant.
 - **Request timelines** — per-request event sequences from queued →
@@ -69,8 +69,9 @@ logger = get_logger("engine.flight_recorder")
 #: layers are all attention); v7: ``columns_run``, the decode columns the
 #: device ran of the consumed frame (``min(horizon, steps_run)``; 0 where no
 #: frame was consumed), and for a model with routed experts
-#: ``moe_picks_held`` and ``moe_experts_hit`` of that frame)
-SCHEMA_VERSION = 7
+#: ``moe_picks_held`` and ``moe_experts_hit`` of that frame; v8: ``overlap``
+#: may read "chained" (OVERLAP_OUTCOMES))
+SCHEMA_VERSION = 8
 
 #: stable key set of one step record (schema contract, tested)
 STEP_RECORD_KEYS = frozenset({
@@ -89,6 +90,22 @@ MOE_STEP_RECORD_KEYS = frozenset({"moe_picks_held", "moe_experts_hit"})
 HORIZON_REASONS = (
     "full", "forced_lane", "pending_admission", "adaptive", "page_headroom",
     "cap",
+)
+
+#: what a step of the overlap pipeline did with its decode launch (the step
+#: record's ``overlap``, ``smg_engine_lookahead_launches_total{outcome}``):
+#: ``kept``/``discarded`` a frame launched ahead of the consume, ``chained``
+#: a frame behind this step's grouped prefill before the prefill's first
+#: tokens were fetched, ``sync`` launched with nothing of its own to hide
+#: behind.  None where the step did not pass that pipeline
+OVERLAP_OUTCOMES = ("sync", "kept", "discarded", "chained")
+
+#: why a sampling prefill of the overlap pipeline had its first tokens
+#: fetched before the step's decode launch (``Scheduler._first_tokens_needed``
+#: and ``_launch_behind_prefill``; ``loads()["prefill_sync_launches"]``)
+PREFILL_SYNC_REASONS = (
+    "penalties", "token_filter", "stop_strings", "first_token_ends",
+    "recurrent_stop_ids", "page_headroom", "earlier_group", "solo",
 )
 
 

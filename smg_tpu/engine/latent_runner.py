@@ -124,20 +124,20 @@ class LatentModelRunner(ModelRunner):
             parts += [rows[k:k + most] for k in range(0, len(rows), most)]
         return parts
 
-    def prefill_batched(self, chunks, temps, topks, topps, minps, pen=None, mask=None,
-                        lora_idx=None, mm=None, rope=None):
-        """``ModelRunner.prefill_batched``, one call for each part of the
-        group (``_split_group``)."""
+    def prefill_batched_async(self, chunks, temps, topks, topps, minps, pen=None,
+                              mask=None, lora_idx=None, mm=None, rope=None):
+        """``ModelRunner.prefill_batched_async``, one launch for each part of
+        the group (``_split_group``)."""
         if lora_idx is not None or mm is not None or rope is not None:
             raise ValueError(self.module.SERVING_LIMITS["lora"])
-        parts = self._split_group([len(c[0]) for c in chunks])
-        toks, lps = np.zeros(len(chunks), np.int32), np.zeros(len(chunks), np.float32)
-        for rows in parts:
-            toks[rows], lps[rows] = super().prefill_batched(
+        parts = []
+        for rows in self._split_group([len(c[0]) for c in chunks]):
+            (_r, toks, lps), = super().prefill_batched_async(
                 [chunks[i] for i in rows], temps[rows], topks[rows], topps[rows], minps[rows],
                 pen=None if pen is None else tuple(a[rows] for a in pen),
                 mask=None if mask is None else mask[rows])
-        return toks, lps
+            parts.append((np.asarray(rows), toks, lps))
+        return parts
 
     # ---- refusals ----
 

@@ -315,9 +315,11 @@ class EngineMetrics:
         # overlapped decode pipeline (scheduler one-step lookahead)
         self.lookahead_launches = _track(Counter(
             "smg_engine_lookahead_launches_total",
-            "Overlap-pipeline steps by lookahead outcome (kept = chained "
-            "launch stood; discarded = schedule changed, launch dropped; "
-            "sync = no lookahead launched, forced-sync or unpredictable)",
+            "Overlap-pipeline steps by lookahead outcome (kept = launch "
+            "ahead of the consume stood; discarded = schedule changed, "
+            "launch dropped; chained = launched behind the step's grouped "
+            "prefill before its first tokens were fetched; sync = launched "
+            "with nothing to hide behind)",
             ["outcome"], registry=r,
         ))
         self.deferred_fetch = _track(Histogram(

@@ -335,14 +335,15 @@ class RecurrentModelRunner(ModelRunner):
             up([0.0], jnp.float32), up([-1], jnp.int32), up([1.0], jnp.float32),
             up([0.0], jnp.float32))
 
-    def prefill_batched(self, chunks, temps, topks, topps, minps, pen=None, mask=None,
-                        lora_idx=None, mm=None, rope=None, state_slots=None):
-        """``ModelRunner.prefill_batched``; ``state_slots`` [G_real] names
-        each row's slot (padded rows get the garbage slot).  A group whose
-        padded rows x tokens pass the step's token budget runs as several
-        launches of as many rows as fit it: the chunked recurrence keeps
-        float32 operands of every padded token, and a program of 8 rows x
-        4,096 tokens does not fit beside the caches."""
+    def prefill_batched_async(self, chunks, temps, topks, topps, minps, pen=None,
+                              mask=None, lora_idx=None, mm=None, rope=None,
+                              state_slots=None):
+        """``ModelRunner.prefill_batched_async``; ``state_slots`` [G_real]
+        names each row's slot (padded rows get the garbage slot).  A group
+        whose padded rows x tokens pass the step's token budget runs as
+        several launches of as many rows as fit it, one part each: the
+        chunked recurrence keeps float32 operands of every padded token, and
+        a program of 8 rows x 4,096 tokens does not fit beside the caches."""
         from smg_tpu.engine.runner import _pad_rows, _pad_vec
 
         self._plain("prefill_batched", lora=lora_idx is not None and self._lora_bank is not None,
@@ -352,17 +353,16 @@ class RecurrentModelRunner(ModelRunner):
         T = self.config.scheduler.prefill_bucket(max(len(c[0]) for c in chunks))
         rows = max(1, self.config.scheduler.max_prefill_tokens // T)
         if g_real > rows:
-            toks, lps = [], []
+            parts = []
             for lo in range(0, g_real, rows):
                 part = slice(lo, lo + rows)
-                t, l = self.prefill_batched(
+                (r, t, l), = self.prefill_batched_async(
                     chunks[part], temps[part], topks[part], topps[part], minps[part],
                     pen=None if pen is None else tuple(x[part] for x in pen),
                     mask=None if mask is None else mask[part],
                     state_slots=None if state_slots is None else state_slots[part])
-                toks.append(t)
-                lps.append(l)
-            return np.concatenate(toks), np.concatenate(lps)
+                parts.append((r + lo, t, l))
+            return parts
         G = 1
         while G < g_real:
             G *= 2
@@ -395,8 +395,7 @@ class RecurrentModelRunner(ModelRunner):
         if mask is not None:
             args.append(up(_pad_rows(mask, G, fill=True)))
         toks, lps, self.k_cache, self.v_cache, self.s_pool, self.c_pool = fn(*args)
-        toks, lps = jax.device_get((toks, lps))  # intended blocking fetch
-        return toks[:g_real], lps[:g_real]
+        return [(np.arange(g_real), toks, lps)]
 
     def decode_multi_async(self, tokens, positions, page_tables, temps, topks, topps,
                            minps, num_steps, max_steps=None, stop_state=None, pen=None,

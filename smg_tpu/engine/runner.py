@@ -310,6 +310,7 @@ class ModelRunner:
         if self._replicated is not None:
             self._rng_key = jax.device_put(self._rng_key, self._replicated)
         self._fold_in = None  # jitted fold_in, built on first key (see _next_key)
+        self._merge = None  # jitted merge of first tokens (see chain_first_tokens)
         self._step = 0
         self._compiled: dict = {}
         # compiled-program auditor: every jit family below registers through
@@ -891,7 +892,24 @@ class ModelRunner:
         return self._register(k, fn, donate=(5, 6), in_shardings=in_sh,
                               attn="xla")
 
-    def prefill_batched(
+    def prefill_batched(self, chunks, *args, **kw) -> tuple[np.ndarray, np.ndarray]:
+        """Prefill several single-chunk sequences in one call:
+        ``prefill_batched_async`` and the fetch of what it sampled.
+        Returns (tokens [G_real], logprobs [G_real])."""
+        return self.fetch_first_tokens(
+            self.prefill_batched_async(chunks, *args, **kw), len(chunks))
+
+    @staticmethod
+    def fetch_first_tokens(parts: list, g_real: int) -> tuple[np.ndarray, np.ndarray]:
+        """Materialise what ``prefill_batched_async`` dispatched, in the
+        group's own row order."""
+        got = jax.device_get([(t, l) for _rows, t, l in parts])  # intended blocking fetch
+        toks, lps = np.zeros(g_real, np.int32), np.zeros(g_real, np.float32)
+        for (rows, _t, _l), (t, l) in zip(parts, got):
+            toks[rows], lps[rows] = t[: len(rows)], l[: len(rows)]
+        return toks, lps
+
+    def prefill_batched_async(
         self,
         chunks: "list[tuple[list[int], int, np.ndarray]]",  # (token_ids, prefix_len, page_table_row)
         temps: np.ndarray,  # [G_real]
@@ -903,9 +921,15 @@ class ModelRunner:
         lora_idx: np.ndarray | None = None,  # [G_real] adapter slot per row
         mm: "list[tuple | None] | None" = None,  # per-row (dense [t,E], bool [t])
         rope: "list[np.ndarray | None] | None" = None,  # per-row [3, t] M-RoPE ids
-    ) -> tuple[np.ndarray, np.ndarray]:
-        """Prefill several single-chunk sequences in one call.
-        Returns (tokens [G_real], logprobs [G_real])."""
+    ) -> "list[tuple[np.ndarray, jax.Array, jax.Array]]":
+        """Dispatch the grouped prefill and return its first tokens
+        UNMATERIALISED, as the launches that computed them: a list of
+        ``(rows, tokens [G], logprobs [G])`` where ``rows`` names the
+        members of ``chunks`` that the launch's first ``len(rows)`` rows
+        hold.  One launch here; a runner that splits a group returns one
+        entry for each part.  ``fetch_first_tokens`` brings them to the
+        host; ``chain_first_tokens`` hands them to a decode launch on the
+        device."""
         g_real = len(chunks)
         G = 1
         while G < g_real:
@@ -994,8 +1018,38 @@ class ModelRunner:
                     rp[i, :, : r.shape[1]] = r
             args.append(up(rp))
         toks, lps, self.k_cache, self.v_cache = fn(*args)
-        toks, lps = jax.device_get((toks, lps))  # intended blocking fetch
-        return toks[:g_real], lps[:g_real]
+        return [(np.arange(g_real), toks, lps)]
+
+    def chain_first_tokens(self, tokens: np.ndarray, owner: np.ndarray,
+                           parts: list) -> jax.Array:
+        """The ``tokens`` [B] input of a decode launch dispatched behind a
+        grouped prefill whose first tokens are still on the device:
+        ``tokens`` as the host knows it, with each lane whose ``owner`` [B]
+        is not -1 taking that member's token from ``parts``
+        (``prefill_batched_async``).  One small program a part, keyed by
+        (B, the part's padded rows); every upload is explicit, so the
+        steady-state transfer guard stays green."""
+        up = self._replicated
+        col = _dev(tokens, jnp.int32, up)
+        for rows, toks, _lps in parts:
+            src = np.full(len(tokens), -1, np.int32)
+            for j, i in enumerate(rows):
+                src[owner == i] = j
+            if (src >= 0).any():
+                col = self._merge_fn()(col, _dev(src, jnp.int32, up), toks)
+        return col
+
+    def _merge_fn(self):
+        """``where(src >= 0, first[src], col)``: jitted once, compiled for
+        each (B, G) it meets (``warmup`` meets them all)."""
+        if self._merge is None:
+            def merge(col, src, first):
+                picked = first.astype(jnp.int32)[jnp.maximum(src, 0)]
+                return jnp.where(src >= 0, picked, col)
+            r = self._replicated
+            placed = {} if r is None else {"in_shardings": (r, r, r), "out_shardings": r}
+            self._merge = jax.jit(merge, **placed)
+        return self._merge
 
     def _decode_multi_fn(self, B: int, mp: int, N: int, E: int = 0,
                          use_pen: bool = False, use_mask: bool = False,
@@ -1487,7 +1541,8 @@ class ModelRunner:
         compile, or does not fit beside the cache, stops the process at
         start-up instead of failing every long request later.  (Optional
         features — penalties, masks, LoRA, embeddings, speculation — still
-        compile on first use.)
+        compile on first use.)  Last, every shape of the merge that a decode
+        launch behind a grouped prefill runs (``chain_first_tokens``).
 
         Page tables are all zero and decode positions sit past the table, so
         every KV write lands on the garbage page; the sampling-key counter
@@ -1508,6 +1563,22 @@ class ModelRunner:
         B = sched.decode_bucket(sched.max_batch_size)
         N = sched.horizon_cap
         zeros, ones = np.zeros(B, np.float32), np.ones(B, np.float32)
+
+        def merges():
+            # tiny, and one for every (decode bucket, padded group or part of
+            # one) a launch behind a grouped prefill can meet: none of them
+            # may compile inside traffic
+            g, sizes = 1, []
+            while g < 2 * sched.max_prefill_group:
+                sizes.append(g)
+                g *= 2
+            for b in (b for b in sched.decode_batch_buckets if b <= B):
+                for g in sizes:
+                    first = self.upload(np.zeros(g, np.int32))
+                    self.chain_first_tokens(
+                        np.zeros(b, np.int32), np.zeros(b, np.int32),
+                        [(np.arange(1), first, None)])
+
         steps = {
             "prefill_extend": lambda: self.prefill_extend(ids, 0, table),
             "prefill": lambda: self.prefill(ids, 0, table, 0.0, -1, 1.0, 0.0),
@@ -1519,6 +1590,7 @@ class ModelRunner:
                 np.zeros((B, mp), np.int32), zeros, np.full(B, -1, np.int32),
                 ones, zeros, num_steps=N, max_steps=N,
             ),
+            "chain_first_tokens": merges,
         }
         mark = self.rng_mark()
         took = []

@@ -59,7 +59,7 @@ import numpy as np
 from jax import lax
 
 from smg_tpu.engine.config import EngineConfig
-from smg_tpu.engine.flight_recorder import HORIZON_REASONS
+from smg_tpu.engine.flight_recorder import HORIZON_REASONS, PREFILL_SYNC_REASONS
 from smg_tpu.engine.kv_cache import PagePool, StateSlotPool
 from smg_tpu.engine.radix_cache import RadixCache
 from smg_tpu.engine.request import (
@@ -204,6 +204,14 @@ class Scheduler:
         self._serial = 0  # admission serial for decode-state signatures
         self.num_lookahead_kept = 0
         self.num_lookahead_discarded = 0
+        # a step that prefills: ``_chaining`` is set while the overlap
+        # pipeline's prefill phase may leave a grouped prefill's first tokens
+        # unfetched, ``_pending_group`` is that group, (members, the
+        # runner's unfetched parts), until the step's decode launch is out
+        self._chaining = False
+        self._pending_group: tuple | None = None
+        self.num_prefill_chained = 0
+        self.num_prefill_sync = dict.fromkeys(PREFILL_SYNC_REASONS, 0)
         # megastep decode (device-fused K-step horizon) accounting + the
         # adaptive horizon controller's observed-finish-rate state:
         # wasted tokens = columns computed on device but never accepted
@@ -442,6 +450,12 @@ class Scheduler:
             # discarded after a schedule change (stop/abort/rollback)
             "lookahead_kept": self.num_lookahead_kept,
             "lookahead_discarded": self.num_lookahead_discarded,
+            # sampling prefills of the overlap pipeline: those whose step
+            # launched its decode frame behind them, before their first
+            # tokens were fetched, and those that fetched first, by why
+            # (PREFILL_SYNC_REASONS)
+            "prefill_chained_launches": self.num_prefill_chained,
+            "prefill_sync_launches": dict(self.num_prefill_sync),
             # megastep decode: device-computed-but-never-emitted columns and
             # device-side early exits (a finish ended a horizon early)
             "wasted_decode_tokens": self.num_wasted_decode_tokens,
@@ -876,6 +890,11 @@ class Scheduler:
     # The prefill phase runs every step ahead of launch decisions, so
     # admissions no longer discard — they either fold (suppressing that
     # step's lookahead launch) or stay fold-free (lookahead survives).
+    # A step whose phase folded launches its decode frame after the phase,
+    # as the sync step does, and where the phase was a grouped prefill it
+    # launches BEHIND it: dispatched before the prefill's first tokens are
+    # fetched, so the launch work hides behind the prefill as a lookahead's
+    # hides behind a frame (``_launch_behind_prefill``).
 
     def _step_overlap(self, outputs: list[StepOutput]) -> tuple[float, float, str]:
         """One pipeline iteration; returns (admit_s, fetch_wait_s, outcome)."""
@@ -946,8 +965,20 @@ class Scheduler:
         # step's decode fold happens at the tail cold launch, after the
         # phase.)
         ta = time.perf_counter()
-        disturbed = self._admit(outputs)
+        # with no lookahead in flight this step's decode launch comes after
+        # the phase, and a grouped prefill may leave its first tokens on the
+        # device for that launch to chain on (``_prefill_group``)
+        self._chaining = look is None
+        try:
+            disturbed = self._admit(outputs)
+        except Exception:
+            # whoever handles this reads every lane's last token
+            self._settle_pending(outputs)
+            raise
+        finally:
+            self._chaining = False
         admit_s = time.perf_counter() - ta
+        pend, self._pending_group = self._pending_group, None
         if look is not None:
             if disturbed or self._frame_stale(look):
                 # ``disturbed`` here means the fold-free predictor lied —
@@ -960,11 +991,54 @@ class Scheduler:
             else:
                 self.inflight = look
                 outcome = "kept"
+        if pend is not None:
+            self.inflight = self._launch_behind_prefill(pend, outputs)
+            if self.inflight is not None:
+                outcome = "chained"
         if self.inflight is None:
             active = self._decode_active()
             if active:
                 self.inflight = self._launch_frame(active)
         return admit_s, fetch_s, outcome
+
+    def _launch_behind_prefill(
+        self, pend: tuple, outputs: list[StepOutput]
+    ) -> InFlightFrame | None:
+        """Dispatch this step's decode frame BEHIND the grouped prefill
+        ``pend`` (``_prefill_group`` left its first tokens on the device),
+        then fetch and accept those tokens: the host's launch work runs
+        while the device prefills instead of after it.  The frame is the one
+        the synchronous order would launch (same lanes, positions, tables,
+        keys; the promoted lanes' input tokens come from the prefill's own
+        output), so the streams do not move.  Returns the frame, or None
+        where the caller's cold launch has to follow the fetch after all:
+
+        - the horizon's pages cannot be had from the headroom (a preemption
+          could pick a lane whose first token is not accepted yet);
+        - the prefill failed on the device (surfacing at the fetch): the
+          frame's folds are rewound before the members retry solo, so the
+          quarantine path refolds as it always did;
+        - a first token ended its request: the frame holds a lane the
+          synchronous launch would not, and goes before anything else folds.
+        """
+        active = self._decode_active()
+        horizon, _ = self._pick_horizon(active, owed=pend[0])
+        fits = self._pages_needed(active, horizon) <= self._headroom_pages()
+        frame = None
+        try:
+            if fits:
+                frame = self._launch_frame(active, first=pend)
+        finally:
+            # also where the launch raised: the recovery that follows reads
+            # every lane's last token
+            accepted = self._accept_first_tokens_guarded(pend, outputs, frame)
+        self._count_prefill_launch(None if frame is not None else "page_headroom")
+        if not accepted:
+            return None
+        if frame is not None and self._frame_stale(frame):
+            self._discard_frame(frame)
+            return None
+        return frame
 
     def _mp_bucket(self, pages_needed: int) -> int:
         """Power-of-two page-table width bucket (>= 8, capped at the full
@@ -1462,12 +1536,17 @@ class Scheduler:
         is quarantined and innocent group members still promote this step."""
         try:
             self._prefill_group(group, outputs)
-            return
         except Exception:  # noqa: BLE001 — quarantine boundary
-            self._count_step_failure("prefill")
-            logger.exception(
-                "grouped prefill failed; retrying %d members solo", len(group)
-            )
+            self._retry_group_solo(group, outputs)
+
+    def _retry_group_solo(
+        self, group: list[EngineRequest], outputs: list[StepOutput]
+    ) -> None:
+        """The grouped prefill of ``group`` failed (call from the handler)."""
+        self._count_step_failure("prefill")
+        logger.exception(
+            "grouped prefill failed; retrying %d members solo", len(group)
+        )
         for req in group:
             if req.is_finished:
                 continue
@@ -1475,6 +1554,86 @@ class Scheduler:
                 self._prefill_final(req, outputs)
             except Exception as e:  # noqa: BLE001 — the culprit
                 self._fail_request(req, f"prefill failed: {e}", outputs)
+
+    def _first_tokens_needed(self, group: list[EngineRequest]) -> str | None:
+        """Why the host needs the first tokens of ``group`` before it can
+        launch the step's decode frame (a PREFILL_SYNC_REASONS word), or None
+        where the frame may be dispatched behind the prefill.  Read off what
+        the requests carry: penalty counts, a grammar's mask and a stop
+        string are derived from the token on the host; a first token that
+        ends its request by its length changes the lane set for certain.  A
+        stop id may end it too: a frame that held the lane is then thrown
+        away (``_launch_behind_prefill``), which costs a model with
+        recurrent state every lane's state, so there it waits."""
+        eos = self.config.model.eos_token_ids
+        for req in group:
+            sp = req.sampling
+            if sp.has_penalties:
+                return "penalties"
+            if req.token_filter is not None:
+                return "token_filter"
+            if sp.stop:
+                return "stop_strings"
+            if (len(req.output_ids) + 1 >= sp.max_new_tokens
+                    or req.total_len + 1 >= self.sched.max_seq_len):
+                return "first_token_ends"
+            if self.state_pool is not None and (
+                    sp.stop_token_ids or (eos and not sp.ignore_eos)):
+                return "recurrent_stop_ids"
+        return None
+
+    def _count_prefill_launch(self, sync_reason: str | None) -> None:
+        """One sampling prefill of the overlap pipeline: the step's decode
+        frame went out behind it (None), or its tokens were fetched first."""
+        if sync_reason is None:
+            self.num_prefill_chained += 1
+        else:
+            self.num_prefill_sync[sync_reason] += 1
+
+    def _settle_pending(self, outputs: list[StepOutput]) -> None:
+        """Fetch and accept a grouped prefill's first tokens now, where
+        something other than the step's decode launch comes next (as a
+        rule the phase's next sampling prefill)."""
+        pend, self._pending_group = self._pending_group, None
+        if pend is not None:
+            self._count_prefill_launch("earlier_group")
+            self._accept_first_tokens_guarded(pend, outputs)
+
+    def _accept_first_tokens_guarded(
+        self, pend: tuple, outputs: list[StepOutput],
+        frame: InFlightFrame | None = None,
+    ) -> bool:
+        """``_accept_first_tokens`` outside ``_prefill_group_guarded``: a
+        prefill that failed on the device has its members retried solo, after
+        ``frame`` (dispatched behind it) has given its folds back."""
+        try:
+            self._accept_first_tokens(pend, outputs)
+            return True
+        except Exception:  # noqa: BLE001 — quarantine boundary
+            if frame is not None:
+                self._discard_frame(frame)
+            self._retry_group_solo(pend[0], outputs)
+            return False
+
+    def _accept_first_tokens(self, pend: tuple, outputs: list[StepOutput]) -> None:
+        """The blocking fetch of a grouped prefill's first tokens and their
+        acceptance.  A launch that failed on the device surfaces here: the
+        members go back to where admission left them and the caller retries
+        them solo (counted again there, never double)."""
+        group, parts = pend
+        try:
+            toks, lps = self.runner.fetch_first_tokens(parts, len(group))
+        except Exception:
+            for req in group:
+                self.num_prefill_tokens -= req.seq_len - req.cached_tokens
+                req.seq_len = req.prefill_pos = req.cached_tokens
+                req.status = RequestStatus.PREFILLING
+            raise
+        for i, req in enumerate(group):
+            self._accept_tokens(
+                # smglint: disable-next=HOTSYNC toks/lps fetched in fetch_first_tokens
+                req, [int(toks[i])], [float(lps[i])], outputs, advance_seq=False
+            )
 
     def _admit_legacy(self, outputs: list[StepOutput]) -> bool:
         """Drain-the-queue admission (``prefill_mix_policy="throughput"``):
@@ -1665,6 +1824,9 @@ class Scheduler:
         prompt KV, sample the request's first token (this is the prefill key
         fold the overlap pipeline orders lookahead launches after), and
         promote the request to a decode lane."""
+        self._settle_pending(outputs)
+        if self._chaining:
+            self._count_prefill_launch("solo")
         FAULTS.fire("engine.prefill", rid=req.rid)
         prompt = req.all_token_ids
         start = req.prefill_pos
@@ -1726,6 +1888,9 @@ class Scheduler:
         outputs: list[StepOutput],
     ) -> None:
         """Long prompts: loop chunks under the prefill token budget."""
+        self._settle_pending(outputs)
+        if self._chaining:
+            self._count_prefill_launch("solo")
         FAULTS.fire("engine.prefill", rid=req.rid)
         row = self.page_tables[req.slot]
         start = matched_tokens
@@ -1854,7 +2019,13 @@ class Scheduler:
     def _prefill_group(
         self, group: list[EngineRequest], outputs: list[StepOutput]
     ) -> None:
-        """Batched prefill for a group of single-chunk prompts."""
+        """Batched prefill for a group of single-chunk prompts.  What needs
+        no token's value is done at dispatch; the first tokens are fetched
+        and accepted here, or, in a step of the overlap pipeline whose
+        requests let it (``_first_tokens_needed``), after that step's decode
+        launch has been dispatched behind the prefill
+        (``_launch_behind_prefill``)."""
+        self._settle_pending(outputs)
         for req in group:
             # per-member seam BEFORE any bookkeeping mutates, so the guarded
             # caller's solo fallback sees a clean state for every member
@@ -1896,7 +2067,7 @@ class Scheduler:
                 reps[i] = sp.repetition_penalty
             if use_mask and req.token_filter is not None:
                 mask_arr[i] = self._mask_for(req)
-        toks, lps = self.runner.prefill_batched(
+        parts = self.runner.prefill_batched_async(
             chunks, temps, topks, topps, minps,
             pen=(counts, pmask, freqs, pres, reps) if use_pen else None,
             mask=mask_arr,
@@ -1907,8 +2078,8 @@ class Scheduler:
                if self.state_pool is not None else {}),
         )
         for i, req in enumerate(group):
-            # counted only after the batched call succeeded (a failed group
-            # re-counts through the solo fallback, never double)
+            # counted only after the batched call was dispatched (a failed
+            # group re-counts through the solo fallback, never double)
             self.num_prefill_tokens += len(chunks[i][0])
             req.seq_len = req.total_len
             req.prefill_pos = req.seq_len
@@ -1918,10 +2089,14 @@ class Scheduler:
                     req.rid, "prefill_chunk", start=chunks[i][1],
                     n=len(chunks[i][0]), final=True, grouped=True,
                 )
-            self._accept_tokens(
-                # smglint: disable-next=HOTSYNC toks/lps fetched in prefill_batched
-                req, [int(toks[i])], [float(lps[i])], outputs, advance_seq=False
-            )
+        pend = (group, parts)
+        if self._chaining:
+            why = self._first_tokens_needed(group)
+            if why is None:
+                self._pending_group = pend
+                return
+            self._count_prefill_launch(why)
+        self._accept_first_tokens(pend, outputs)
 
     def _ensure_free_pages(self, n: int) -> bool:
         if self.pool.free_count >= n:
@@ -2100,9 +2275,20 @@ class Scheduler:
             return {}
         return {"state_slots": ds.state_slots, "chain": chain}
 
-    def _pick_horizon(self, active: list) -> tuple[int, int]:
+    def _pages_needed(self, active: list, k: int) -> int:
+        """Pages the lanes of ``active`` lack for ``k`` more tokens each."""
+        need = 0
+        for _, r in active:
+            limit = min(r.seq_len + k, self.sched.max_seq_len)
+            have = len(r.shared_pages) + len(r.owned_pages)
+            need += max(0, math.ceil(limit / self.ps) - have)
+        return need
+
+    def _pick_horizon(self, active: list, owed: list = ()) -> tuple[int, int]:
         """Choose this launch's decode horizon K and the compiled loop width
         ``max_steps``; returns ``(K, max_steps)`` with ``K <= max_steps``.
+        ``owed`` names the requests whose first token is sampled and not
+        yet accepted (``_launch_behind_prefill``): they count as holding it.
 
         Forced K=1 (``max_steps`` 1 too — these batches compile their own
         lean trace, mirroring the overlap pipeline's sync-forcing paths):
@@ -2167,11 +2353,12 @@ class Scheduler:
             ema = self._finish_gap_ema
             while k > 1 and ema > 0.0 and k > ema:
                 k //= 2
+            late = {id(r) for r in owed}
             rem = min(
                 min(
                     r.sampling.max_new_tokens - len(r.output_ids),
                     self.sched.max_seq_len - r.total_len,
-                )
+                ) - (id(r) in late)
                 for _, r in active
             )
             k = max(1, min(k, rem))
@@ -2184,15 +2371,7 @@ class Scheduler:
         # pool, else _ensure_seq_capacity would evict/preempt for a horizon
         # the K=1 schedule never asks for — and a preemption refolds the
         # victim's keys, diverging its stream at temperature > 0
-        ps = self.ps
-        while k > 1:
-            need = 0
-            for _, r in active:
-                limit = min(r.seq_len + k, sched.max_seq_len)
-                have = len(r.shared_pages) + len(r.owned_pages)
-                need += max(0, math.ceil(limit / ps) - have)
-            if need <= self._headroom_pages():
-                break
+        while k > 1 and self._pages_needed(active, k) > self._headroom_pages():
             k //= 2
             self._picked_reason = "page_headroom"
         return k, cap
@@ -2224,10 +2403,16 @@ class Scheduler:
         return e
 
     @spanned("smg.step.launch", _launch_attrs)
-    def _launch_frame(self, active: list) -> InFlightFrame | None:
+    def _launch_frame(
+        self, active: list, first: tuple | None = None
+    ) -> InFlightFrame | None:
         """Plan + dispatch one decode megastep for ``active`` slots; returns
         the in-flight frame (results unmaterialized) or None when capacity
-        pressure evicted every candidate."""
+        pressure evicted every candidate.  ``first`` is a grouped prefill
+        whose first tokens are still on the device
+        (``_launch_behind_prefill``, which has seen to it that nobody is
+        preempted here): its members' input tokens are taken from there."""
+        owed = first[0] if first is not None else ()
         FAULTS.fire(
             "engine.decode_step", rids=",".join(r.rid for _i, r in active)
         )
@@ -2235,7 +2420,7 @@ class Scheduler:
         use_pen = any(r.sampling.has_penalties for _, r in active)
         use_lora = any(r.lora_idx for _, r in active)
         use_mrope = any(r.mrope_delta for _, r in active)
-        horizon, max_steps = self._pick_horizon(active)
+        horizon, max_steps = self._pick_horizon(active, owed=owed)
         # ensure pages exist for the whole horizon's KV writes; may preempt.
         # _ensure_seq_capacity refuses requests already evicted as a PEER's
         # preemption victim earlier in this pass (incl. by the spec leg).
@@ -2270,8 +2455,13 @@ class Scheduler:
         tokens = np.zeros(B, np.int32)
         positions = np.zeros(B, np.int32)
         mask_arr = np.ones((B, V), bool) if use_mask else None
+        member = {id(r): i for i, r in enumerate(owed)}
+        owner = np.full(B, -1, np.int32)  # the lane's place in ``first``'s group
         for idx, (slot, req) in enumerate(active):
-            tokens[idx] = req.output_ids[-1]
+            if id(req) in member:
+                owner[idx] = member[id(req)]
+            else:
+                tokens[idx] = req.output_ids[-1]
             positions[idx] = req.seq_len
             if use_mask and req.token_filter is not None:
                 mask_arr[idx] = self._mask_for(req)
@@ -2281,6 +2471,8 @@ class Scheduler:
 
         mark = self.runner.rng_mark()
         t_dispatch = time.perf_counter()
+        if first is not None:
+            tokens = self.runner.chain_first_tokens(tokens, owner, first[1])
         toks, lps, steps_run = self.runner.decode_multi_async(
             tokens, positions, ds.page_tables,
             ds.temps, ds.topks, ds.topps, ds.minps, horizon,

@@ -393,7 +393,8 @@ def test_engine_auto_size_smoke():
 def test_warmup_runs_largest_programs_and_leaves_no_trace():
     """Engine.warmup (what `serve`/`worker` run before binding a port)
     compiles and runs the largest solo-prefill, grouped-prefill and decode
-    programs; everything it writes lands on the garbage page and the
+    programs, and the small merge that hands a grouped prefill's first tokens
+    to a decode launch; everything it writes lands on the garbage page and the
     sampling-key counter is restored, so serving afterwards is byte-identical
     to an engine that never warmed up."""
     import numpy as np
@@ -406,7 +407,8 @@ def test_warmup_runs_largest_programs_and_leaves_no_trace():
     eng = make_engine(decode_horizon=4)
     took = eng.warmup()
     assert [name for name, _ in took] == [
-        "prefill_extend", "prefill", "prefill_batched", "decode_multi"]
+        "prefill_extend", "prefill", "prefill_batched", "decode_multi",
+        "chain_first_tokens"]
     keys = list(eng.runner._compiled)
     assert ("prefill_extend", 64, 16, "xla") == keys[0][:4]
     assert ("prefill_batched", 8, 16, 16, False) == keys[2][:5]  # ctx variant

@@ -17,6 +17,7 @@ from aiohttp.test_utils import TestClient, TestServer
 from smg_tpu.engine.config import CacheConfig, EngineConfig, SchedulerConfig
 from smg_tpu.engine.engine import Engine
 from smg_tpu.engine.flight_recorder import (
+    OVERLAP_OUTCOMES,
     SCHEMA_VERSION,
     STEP_RECORD_KEYS,
     FlightRecorder,
@@ -140,7 +141,7 @@ def test_timeline_completeness_chunked_prefill_overlap():
     assert tl["itl"]["count"] == 3  # 4 tokens -> 3 gaps
     # overlap outcomes recorded in the ring
     outcomes = {r["overlap"] for r in dump["ring"]}
-    assert outcomes & {"kept", "sync", "discarded"}
+    assert outcomes & {"kept", "sync", "discarded"} and outcomes <= set(OVERLAP_OUTCOMES)
     eng.stop()
 
 
@@ -160,6 +161,10 @@ def test_dump_schema_stable():
     assert dump["reason"] == "manual"
     for rec in dump["ring"]:
         assert set(rec) == STEP_RECORD_KEYS
+        assert rec["overlap"] in OVERLAP_OUTCOMES
+    # the one request's prefill had its step's decode frame launched behind it
+    assert dump["ring"][0]["overlap"] == "chained"
+    assert OVERLAP_OUTCOMES == ("sync", "kept", "discarded", "chained")
     tl = dump["timelines"]["finished"][0]
     assert {
         "rid", "trace_id", "meta", "submit_t", "queued_t", "admitted_t",

@@ -266,6 +266,31 @@ def test_overlapped_and_synchronous_schedules_give_the_same_tokens():
     assert one == streams[0]  # K=8 frames are K=1 steps, byte for byte
 
 
+@pytest.mark.parametrize("horizon", [1, 8])
+def test_a_frame_launched_behind_a_grouped_prefill_leaves_the_streams_alone(horizon):
+    """Requests that arrive while others decode, one of them a group that
+    this runner prefills in two launches (two token buckets): the step's
+    decode frame goes out before the first tokens are fetched, and the
+    streams are the synchronous schedule's at temperature 0 and 0.8."""
+    from tests.test_overlap import staged_streams
+
+    ps = prompts(9, 30, 22, 41, 19, 40, 27)
+    jobs = [(f"j{i}", p, SamplingParams(temperature=t, top_k=20, max_new_tokens=n,
+                                        ignore_eos=True))
+            for i, (p, t, n) in enumerate(zip(
+                ps, (0.8, 0.0, 0.8, 0.8, 0.0, 0.8), (21, 17, 12, 15, 9, 11)))]
+    at = [0, 0, 3, 6, 6, 11]
+    engs = [make_engine(overlap=o, horizon=horizon) for o in (True, False)]
+    assert len(engs[0].runner._split_group([19, 40])) == 2
+    streams = [staged_streams(e, jobs, at) for e in engs]
+    assert streams[0] == streams[1]
+    loads = engs[0].loads()
+    assert loads["prefill_chained_launches"] >= 4
+    assert not any(loads["prefill_sync_launches"].values())
+    assert loads["wasted_decode_tokens"] == 0 and loads["audit"]["clean"]
+    assert engs[1].loads()["prefill_chained_launches"] == 0
+
+
 def test_a_preempted_request_comes_out_as_an_undisturbed_one():
     eng = make_engine(num_pages=12, max_batch=4, max_seq_len=128, watermark_pages=1)
     ps = prompts(4, 30, 33, 36)
@@ -320,7 +345,7 @@ def test_the_step_record_carries_columns_run_and_the_frames_counts():
     (p,) = prompts(7, 20)
     eng.generate(prompt_ids=p, sampling=greedy(12))  # 11 decoded: a frame of 8, a frame of 3
     dump = eng.dump_flight("manual")
-    assert dump["schema_version"] == SCHEMA_VERSION == 7
+    assert dump["schema_version"] == SCHEMA_VERSION == 8
     ring = dump["ring"]
     assert all(STEP_RECORD_KEYS <= set(r) <= STEP_RECORD_KEYS | MOE_STEP_RECORD_KEYS
                for r in ring)

@@ -444,3 +444,213 @@ def test_exhaustive_parity_sweep(horizon):
                                 frequency_penalty=0.3, ignore_eos=True)
         jobs.append((f"x{i}", prompt, sp))
     assert_parity(jobs, decode_horizon=horizon)
+
+
+# ---- a step that prefills launches its decode frame BEHIND the prefill ----
+#
+# The step's decode frame is dispatched before the grouped prefill's first
+# tokens are fetched (``Scheduler._launch_behind_prefill``): same lanes,
+# positions, tables and keys as the synchronous order, the promoted lanes'
+# input tokens taken from the prefill's output on the device.  Streams must
+# not move, and every case in which the host needs the token first must be
+# seen to take the old order.
+
+
+def staged_streams(engine: Engine, jobs: list, at: list) -> dict:
+    """``run_streams`` with job ``i`` submitted before step ``at[i]`` (a
+    request that arrives while others decode); (tokens, finish reason,
+    logprobs) by rid."""
+    chunks: dict[str, list] = {rid: [] for rid, _, _ in jobs}
+    done: set[str] = set()
+
+    def cb(out):
+        chunks[out.rid].append(out)
+        if out.finished:
+            done.add(out.rid)
+
+    due = sorted(zip(at, jobs), key=lambda t: t[0])
+    for step in range(5000):
+        while due and due[0][0] <= step:
+            rid, prompt, sampling = due.pop(0)[1]
+            engine.submit(prompt, sampling, rid=rid, on_output=cb)
+        if len(done) == len(jobs):
+            break
+        engine.step()
+    else:
+        raise TimeoutError(f"jobs stuck: {engine.loads()}")
+    while engine.scheduler.has_work():
+        engine.step()
+    return {
+        rid: ([t for c in chunks[rid] for t in c.new_token_ids],
+              chunks[rid][-1].finish_reason,
+              [round(x, 4) for c in chunks[rid] for x in c.logprobs])
+        for rid, _, _ in jobs
+    }
+
+
+def staged_parity(jobs, at, make=make_engine, **engine_kw):
+    """Streams of the staged jobs under the overlapped schedule, held to the
+    synchronous schedule's; also the overlapped engine."""
+    eng = make(True, **engine_kw)
+    a = staged_streams(eng, jobs, at)
+    b = staged_streams(make(False, **engine_kw), jobs, at)
+    assert a == b, f"overlap diverged from sync:\n{a}\nvs\n{b}"
+    return a, eng
+
+
+def sampled(temp, max_new, **kw) -> SamplingParams:
+    return SamplingParams(temperature=temp, max_new_tokens=max_new,
+                          ignore_eos=True, **kw)
+
+
+@pytest.mark.parametrize("temp", [0.0, 0.8])
+@pytest.mark.parametrize("horizon", [1, 8])
+def test_a_frame_behind_a_grouped_prefill_leaves_the_streams_alone(horizon, temp):
+    # admissions mid-stream, alone and two at once, beside lanes that decode
+    jobs = [(f"j{i}", list(range(5 + 11 * i, 25 + 14 * i)), sampled(temp, 10 + 3 * i))
+            for i in range(6)]
+    _res, eng = staged_parity(jobs, [0, 0, 3, 5, 5, 11], decode_horizon=horizon)
+    loads = eng.loads()
+    assert loads["prefill_chained_launches"] >= 4
+    assert not any(loads["prefill_sync_launches"].values())
+    assert loads["wasted_decode_tokens"] == 0
+    assert eng.scheduler.inflight is None and eng.scheduler._pending_group is None
+
+
+FALLBACKS = {
+    "penalties": dict(frequency_penalty=0.4, presence_penalty=0.2),
+    "stop_strings": dict(stop=["never in this text"]),
+    "token_filter": dict(regex=r"w[0-9 ]*"),
+    "first_token_ends": dict(max_new_tokens=1),
+    "recurrent_stop_ids": dict(ignore_eos=False),
+}
+
+
+@pytest.mark.parametrize("reason", list(FALLBACKS))
+def test_where_the_host_needs_the_first_token_the_fetch_comes_first(reason):
+    make, kw = make_engine, {}
+    if reason == "recurrent_stop_ids":
+        # a frame that ran and is discarded costs a recurrent model its
+        # lanes' state, so a first token that a stop id may end waits
+        from tests.test_recurrent_engine import make_engine as make_recurrent
+
+        def make(overlap, **kw):
+            return make_recurrent(overlap=overlap, **kw)
+        assert make(True).scheduler.config.model.eos_token_ids
+    late = SamplingParams(**{"temperature": 0.8, "max_new_tokens": 7,
+                             "ignore_eos": True, **FALLBACKS[reason]})
+    jobs = [("a", list(range(5, 27)), sampled(0.8, 16)),
+            ("b", list(range(40, 58)), sampled(0.0, 14)),
+            ("late", list(range(70, 95)), late),
+            ("last", list(range(100, 120)), sampled(0.8, 6))]
+    _res, eng = staged_parity(jobs, [0, 0, 3, 9], make=make, **kw)
+    loads = eng.loads()
+    assert loads["prefill_sync_launches"][reason] == 1
+    assert sum(loads["prefill_sync_launches"].values()) == 1
+    assert loads["prefill_chained_launches"] == 2  # "a" with "b", and "last"
+
+
+def test_of_two_sampling_prefills_in_a_step_the_frame_chains_on_the_last():
+    # groups of two: four arrivals in one step prefill as two groups, and the
+    # first group's tokens are fetched before the second is dispatched; a
+    # prompt over the step's budget ends in a solo prefill, which fetches
+    jobs = [("a", list(range(5, 27)), sampled(0.8, 30))]
+    jobs += [(f"g{i}", list(range(40 + 13 * i, 52 + 13 * i)), sampled(0.8, 8 + i))
+             for i in range(4)]
+    jobs += [("long", list(range(100, 200)), sampled(0.8, 6))]
+    _res, eng = staged_parity(jobs, [0, 3, 3, 3, 3, 12], max_prefill_group=2)
+    loads = eng.loads()
+    assert loads["prefill_sync_launches"]["earlier_group"] == 1
+    assert loads["prefill_sync_launches"]["solo"] == 1
+    assert sum(loads["prefill_sync_launches"].values()) == 2
+    assert loads["prefill_chained_launches"] == 2  # "a", and the second pair
+
+
+def test_a_first_token_that_ends_its_request_throws_the_chained_frame_away():
+    prompt = list(range(60, 90))
+    first = run_streams(make_engine(False), [("p", prompt, greedy(2))])["p"][0][0]
+    ends = SamplingParams(temperature=0.0, max_new_tokens=8, ignore_eos=True,
+                          stop_token_ids=[first])
+    jobs = [("a", list(range(5, 27)), sampled(0.8, 24)),
+            ("b", list(range(40, 58)), sampled(0.8, 20)),
+            ("e", prompt, ends),
+            ("c", list(range(100, 120)), sampled(0.8, 9))]
+    res, eng = staged_parity(jobs, [0, 0, 3, 6], decode_horizon=4)
+    assert res["e"][:2] == ([first], "stop")
+    assert eng.loads()["prefill_chained_launches"] == 3
+    # the step itself: the frame held "e", so it goes before anything else
+    # folds, its folds go back, and what the device ran of it is waste
+    eng = make_engine(True, decode_horizon=4)
+    out: dict = {}
+    eng.submit(jobs[0][1], jobs[0][2], rid="a")
+    for _ in range(3):
+        eng.step()
+    eng.submit(prompt, ends, rid="e", on_output=lambda o: out.setdefault("e", o))
+    sched, wasted = eng.scheduler, eng.scheduler.num_wasted_decode_tokens
+    eng.step()
+    assert out["e"].finished and out["e"].finish_reason == "stop"
+    assert sched.num_wasted_decode_tokens - wasted == 2 * 4  # lanes x K
+    frame = sched.inflight
+    assert [r.rid for _s, r, _e in frame.lanes] == ["a"] and not frame.lookahead
+    assert eng.runner._step == frame.rng_mark + frame.folds
+    assert eng.scheduler.flight.snapshot()["ring"][-1]["overlap"] == "sync"
+    eng.stop()
+
+
+def test_a_step_that_chains_is_steady_state_guard_clean():
+    """The merge of the host's column with the prefill's tokens uploads
+    explicitly and compiles with the engine's warm-up, so a step that
+    prefills and launches behind the prefill passes the guard that the
+    steady state passes."""
+    from smg_tpu.analysis.runtime_guards import steady_state_guard
+
+    eng = make_engine(True, decode_horizon=8)
+    eng.warmup()
+    merges = eng.runner._merge._cache_size()
+    assert merges == 2 * 4  # decode buckets x padded group sizes
+    for i in range(2):
+        eng.submit([(7 * i + j) % 90 + 5 for j in range(16)], greedy(90), rid=f"r{i}")
+    for _ in range(4):
+        eng.step()
+    eng.submit(list(range(100, 116)), greedy(40), rid="warm")  # the shapes below
+    for _ in range(3):
+        eng.step()
+    chained = eng.loads()["prefill_chained_launches"]
+    with steady_state_guard() as cc:
+        eng.submit(list(range(120, 136)), greedy(40), rid="guarded")
+        for _ in range(3):
+            eng.step()
+    assert cc.count == 0
+    assert eng.loads()["prefill_chained_launches"] == chained + 1
+    while eng.scheduler.has_work():
+        eng.step()
+    assert eng.runner._merge._cache_size() == merges  # traffic compiled none
+
+
+def test_chained_is_an_outcome_of_the_step_record_and_the_metrics():
+    from prometheus_client import generate_latest
+
+    from smg_tpu.engine.flight_recorder import (
+        OVERLAP_OUTCOMES, PREFILL_SYNC_REASONS,
+    )
+
+    eng = make_engine(True)
+    jobs = [(f"j{i}", list(range(5 + 11 * i, 30 + 11 * i)), greedy(12)) for i in range(3)]
+    staged_streams(eng, jobs, [0, 3, 6])
+    ring = eng.dump_flight()["ring"]
+    outcomes = {r["overlap"] for r in ring}
+    assert "chained" in outcomes and outcomes <= set(OVERLAP_OUTCOMES)
+    # a chained step prefilled, and launched a decode frame of its own
+    assert all(r["prefill_tokens"] and r["horizon_reason"]
+               for r in ring if r["overlap"] == "chained")
+    text = generate_latest(eng.metrics.registry).decode()
+    assert 'smg_engine_lookahead_launches_total{outcome="chained"} 3.0' in text
+    loads = eng.loads()
+    assert loads["prefill_chained_launches"] == 3
+    assert tuple(loads["prefill_sync_launches"]) == PREFILL_SYNC_REASONS
+    # the synchronous schedule passes no such pipeline and counts neither
+    eng2 = make_engine(False)
+    staged_streams(eng2, jobs, [0, 3, 6])
+    loads = eng2.loads()
+    assert loads["prefill_chained_launches"] == 0
+    assert not any(loads["prefill_sync_launches"].values())

@@ -147,6 +147,30 @@ def test_a_finish_inside_a_frame_costs_the_other_lanes_nothing(overlap):
     assert loads["state_recomputed_tokens"] == 0 and loads["preemptions"] == 0
 
 
+@pytest.mark.parametrize("horizon", [1, 8])
+def test_a_frame_launched_behind_a_grouped_prefill_leaves_the_streams_alone(horizon):
+    """Requests that arrive while others decode, three of them at once (a
+    group this runner prefills in two launches): the step's decode frame
+    goes out before the first tokens are fetched, no lane loses its state,
+    and the streams are the synchronous schedule's at temperature 0 and 0.8."""
+    from tests.test_overlap import staged_streams
+
+    ps = prompts(9, 30, 22, 41, 19, 20, 18, 27)
+    jobs = [(f"j{i}", p, SamplingParams(temperature=t, top_k=20, max_new_tokens=n,
+                                        ignore_eos=True))
+            for i, (p, t, n) in enumerate(zip(
+                ps, (0.8, 0.0, 0.8, 0.8, 0.0, 0.8, 0.0), (21, 17, 12, 15, 9, 11, 14)))]
+    at = [0, 0, 3, 6, 6, 6, 11]
+    engs = [make_engine(overlap=o, horizon=horizon) for o in (True, False)]
+    streams = [staged_streams(e, jobs, at) for e in engs]
+    assert streams[0] == streams[1]
+    loads = engs[0].loads()
+    assert loads["prefill_chained_launches"] >= 4
+    assert not any(loads["prefill_sync_launches"].values())
+    assert loads["wasted_decode_tokens"] == 0 and loads["state_recomputed_tokens"] == 0
+    assert loads["audit"]["clean"] and engs[1].loads()["prefill_chained_launches"] == 0
+
+
 def test_a_stop_token_ends_a_lane_and_the_others_go_on():
     """A finish the host cannot foresee, with a lookahead in flight: the frame
     chained on the one that met it runs no column on the device, so the

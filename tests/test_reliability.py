@@ -391,6 +391,74 @@ def test_decode_step_blame_newest_lane():
     assert eng_f.scheduler.consec_step_failures == 0  # clean steps resumed
 
 
+def _late_joiner(overlap: bool, break_it) -> tuple[dict, Engine]:
+    """Two sampled lanes, then a third request beside them, admitted in a
+    step that ``break_it(engine)`` has prepared a fault for."""
+    eng = make_engine(overlap_schedule=overlap, decode_horizon=4)
+    outs: dict = {}
+    sp = SamplingParams(temperature=0.8, max_new_tokens=14, ignore_eos=True)
+    for i in range(2):
+        eng.submit([5 + i, 6 + i, 7 + i], sp, rid=f"r{i}",
+                   on_output=_collector(outs, f"r{i}"))
+    for _ in range(3):
+        eng.step()
+    break_it(eng)
+    eng.submit([40, 41, 42, 43], sp, rid="late", on_output=_collector(outs, "late"))
+    _drive(eng, outs, ["r0", "r1", "late"])
+    FAULTS.clear()
+    return outs, eng
+
+
+def test_a_grouped_prefill_that_fails_at_the_fetch_gives_the_chained_frame_back():
+    """A grouped prefill that fails on the device surfaces where its first
+    tokens are fetched, which under the overlapped schedule is after the
+    step's decode frame went out behind it: the frame is discarded and its
+    folds rewound before the members retry solo, so the retry refolds the
+    keys it always did and every stream is the synchronous schedule's under
+    the same failure."""
+    def fetch_fails_once(eng):
+        real, calls = eng.runner.fetch_first_tokens, []
+
+        def fetch(parts, g_real):
+            calls.append(g_real)
+            if len(calls) == 1:
+                raise RuntimeError("device lost the launch")
+            return real(parts, g_real)
+        eng.runner.fetch_first_tokens = fetch
+
+    got, eng = _late_joiner(True, fetch_fails_once)
+    want, eng_s = _late_joiner(False, fetch_fails_once)
+    for rid in ("r0", "r1", "late"):
+        assert _tokens(got, rid) == _tokens(want, rid)
+        assert len(_tokens(got, rid)) == 14 and got[rid][-1].finish_reason == "length"
+    for e in (eng, eng_s):
+        assert e.scheduler.num_step_failures == 1 and e.scheduler.num_quarantined == 0
+        assert_engine_clean(e)
+    loads = eng.loads()
+    assert loads["prefill_chained_launches"] == 2  # the first pair, and "late"
+    assert loads["wasted_decode_tokens"] == 3 * 4  # the frame behind "late": lanes x K
+    assert loads["prefill_sync_launches"]["solo"] == 0  # the retry is not a launch of the phase
+
+
+def test_a_launch_behind_a_prefill_that_raises_still_accepts_the_first_tokens():
+    """The decode launch dispatched behind a grouped prefill fails before it
+    is out: the first tokens are accepted all the same, so the recovery
+    blames the newest lane and retries the others exactly as the
+    synchronous schedule does."""
+    def launch_with_late_fails(_eng):
+        FAULTS.arm("engine.decode_step", mode="once", match="late")
+
+    got, eng = _late_joiner(True, launch_with_late_fails)
+    want, eng_s = _late_joiner(False, launch_with_late_fails)
+    assert got["late"][-1].finish_reason == "error" == want["late"][-1].finish_reason
+    for rid in ("r0", "r1", "late"):
+        assert _tokens(got, rid) == _tokens(want, rid)
+    assert len(_tokens(got, "late")) == 1 and len(_tokens(got, "r0")) == 14
+    for e in (eng, eng_s):
+        assert e.scheduler.num_quarantined == 1
+        assert_engine_clean(e)
+
+
 def test_decode_poison_batch_condemned_and_unhealthy():
     """A decode fault that survives the single-lane eviction retry condemns
     the whole batch (every lane gets a terminal error), and N consecutive
