@@ -11,7 +11,11 @@ preset, a ``qk_norm`` config and an M-RoPE config, under XLA attention and
 under the interpreted kernels, and (B) every program family a runner
 registers (``prefill``, ``prefill_extend``, ``prefill_batched`` cold and
 warm, ``decode_multi``, ``decode_spec``, ``embed``) through the runner's own
-host API, for the same configs and ``tiny-olmo-hybrid`` (through ``Engine``).
+host API, for the same configs, and for ``tiny-olmo-hybrid`` and
+``tiny-pangu-moe`` through ``Engine`` (the latter where the tree has it),
+and (C) the routed-expert layer itself under XLA's ragged product and under
+the interpreted grouped-product kernel: on the CPU an engine's expert layers
+are XLA's, and the served ones on a TPU the kernel's.
 It keeps every output (logits, caches, tokens, logprobs) and each compiled
 program's ``cost_analysis()`` FLOPs and bytes.  ``compare`` wants the outputs
 bit-equal and the costs equal, and names what is not.
@@ -247,6 +251,36 @@ def _engine_programs(name, cfg, impl, out, costs):
     _program_costs(eng.runner, pre, costs)
 
 
+def _expert_layer_forms(out, costs):
+    """(C): ``ops.moe.expert_layer`` in one pass and in several, a form each."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from smg_tpu.ops import moe
+
+    E, F, held, top_k = 128, 256, (4, 8), 4
+    keys = jax.random.split(jax.random.PRNGKey(0), 5)
+    router = jax.random.normal(keys[0], (E, 16))
+    w_gate, w_up = (0.1 * jax.random.normal(k, (2, held[1], E, F)) for k in keys[1:3])
+    w_down = 0.1 * jax.random.normal(keys[3], (2, held[1], F, E))
+    for T in (8, 1024):  # 32 pairs at once; 4,096 pairs in passes of 2,048 rows
+        x = jax.random.normal(keys[4], (T, E))
+        for impl in ("xla", "pallas_interpret"):
+            def layer(x, w_gate, w_up, w_down):
+                picks = moe.route(x, router, top_k=top_k, scoring="sigmoid",
+                                  norm_topk=True, scale=1.0)
+                return moe.expert_layer(x, picks, w_gate, w_up, w_down, held, impl,
+                                        layer=jnp.int32(1))
+
+            # smglint: disable-next=RETRACE one jit a program, each run once
+            jitted = jax.jit(layer)
+            args = (x, w_gate, w_up, w_down)
+            costs[f"C/expert_layer/{impl}/T{T}"] = _cost(jitted.lower(*args).compile())
+            for i, r in enumerate(jax.tree.leaves(jitted(*args))):
+                out[f"C/expert_layer/{impl}/T{T}/{i}"] = np.asarray(r)
+
+
 def dump(root: str, path: str) -> None:
     sys.path.insert(0, os.path.abspath(root))
     import numpy as np
@@ -263,6 +297,14 @@ def dump(root: str, path: str) -> None:
         _engine_programs(name, cfg, "xla", out, costs)
     for impl in ("xla", "pallas_interpret"):
         _engine_programs("olmo_hybrid", tiny_olmo_hybrid_config(), impl, out, costs)
+    try:
+        from smg_tpu.models.config import tiny_pangu_moe_config
+    except ImportError:  # a tree from before the latent model
+        tiny_pangu_moe_config = None
+    if tiny_pangu_moe_config is not None:
+        for impl in ("xla", "pallas_interpret"):
+            _engine_programs("pangu_moe", tiny_pangu_moe_config(held=(4, 8)), impl, out, costs)
+        _expert_layer_forms(out, costs)
     np.savez_compressed(path, __costs__=np.array(json.dumps(costs)), **out)
     print(f"{len(out)} outputs, {len(costs)} compiled programs -> {path}")
 

@@ -21,6 +21,13 @@ layers of one cache at a cell's widths:
   batch 16, 32 and 64 behind 64, 128 and 256 pages.  The XLA form is
   ``attention_decode_cached`` with one head as wide as the entry and the cache
   as its own V, the kernel ``latent_attention_decode_cached``;
+- ``mimo-v2-flash`` (the ``mixed`` cell's 2 full layers): 64/4 heads, keys of
+  192 and values of 128 (K pages of 768 lanes, V of 512); batch 16, 32 and
+  64 behind 128, 256 and 512 pages;
+- ``mimo-v2-flash:window`` (its 5 window layers): 64/8 heads over a ring of
+  144 entries a lane (1,536 and 1,024 lanes), with the sink; batch 16, 32 and
+  64.  No table and no fill: ``ops.window_attention.window_attention_decode``
+  against ``ops.pallas.window_decode.window_attention_decode``;
 
 lanes filled to a quarter, a half and all of the table.  Prints one JSON line
 per shape: milliseconds a layer for each (XLA once a shape: it reads the
@@ -57,8 +64,12 @@ from smg_tpu.ops.pallas.decode_attention import (  # noqa: E402
 PS, N = 16, 8
 REPS = 5
 FILLS = (0.25, 0.5, 1.0)
-# name: layers, pages, heads, kv heads, head dim, [(batch, table widths)]
+# name: layers, pages, heads, kv heads, head dim, [(batch, table widths)], and
+# the values' head dim where it is not the keys'
 MODELS = {
+    "mimo-v2-flash": (2, 20000, 64, 4, 192,
+                      [(16, (128, 256, 512)), (32, (128, 256, 512)), (64, (128, 256, 512))],
+                      128),
     "qwen3-1.7b": (28, 4725, 16, 8, 128,
                    [(1, (8, 64)), (4, (32,)), (8, (64, 128, 256)),
                     (16, (64, 128, 256)), (32, (128, 256)), (64, (128, 256))]),
@@ -67,7 +78,12 @@ MODELS = {
                                  [(16, (64, 128, 256)), (32, (64, 128, 256)),
                                   (64, (64, 128, 256))]),
 }
+# the window layers' form: layers, slots, ring entries, heads, kv heads, head
+# dims of keys and values, window, batches
+WINDOW_MODELS = {"mimo-v2-flash:window": (5, 73, 144, 64, 8, 192, 128, 128, (16, 32, 64))}
+WINDOW_REHEARSAL = {"toy:window": (2, 5, 32, 16, 8, 64, 32, 8, (2, 8))}
 REHEARSAL = {"toy": (2, 40, 4, 2, 64, [(2, (4, 8)), (8, (8,))]),
+             "toy-narrow-v": (2, 40, 8, 4, 64, [(2, (4, 8))], 32),
              "toy-latent": (2, 40, 4, 0, 256, [(2, (4, 8))])}
 LATENT_VALUE_LANES = {640: 512, 256: 128}  # entry lanes -> lanes of its value
 
@@ -87,6 +103,64 @@ def latent_forms(D: int, pages_per_block, interpret: bool) -> dict:
             pages_per_block=pages_per_block, interpret=interpret))
 
     return {"xla": xla, "pallas": pallas}
+
+
+def narrow_value_forms(forms: dict, D: int) -> dict:
+    """Forms whose values are narrower than their keys, with the result
+    padded back to the keys' width so that layers chain."""
+    def padded(attend):
+        def form(*a):
+            out = attend(*a)
+            return jnp.pad(out, ((0, 0), (0, 0), (0, D - out.shape[-1])))
+        return form
+    return {name: padded(attend) for name, attend in forms.items()}
+
+
+def window_rows(models: dict, only: set, interpret: bool, dev) -> list:
+    """The window layers' two forms, each inside a scan over the layers of
+    one store: milliseconds a layer, the kernel's ring bytes over its time,
+    and the largest difference between the two outputs."""
+    from smg_tpu.ops.pallas.window_decode import window_attention_decode as kernel
+    from smg_tpu.ops.window_attention import window_attention_decode as xla
+
+    rows = []
+    for model, (L, S, R, H, K, D, Dv, W, batches) in models.items():
+        keys = jax.random.split(jax.random.PRNGKey(1), 6)
+        rk = jax.random.normal(keys[0], (L, S, R, K * D), jnp.bfloat16)
+        rv = jax.random.normal(keys[1], (L, S, R, K * Dv), jnp.bfloat16)
+        sink = 4.0 + jax.random.normal(keys[2], (H,), jnp.float32)
+        for B in batches:
+            if only and f"{model}:{B}" not in only and model not in only:
+                continue
+            q = jax.random.normal(keys[3], (B, H, D), jnp.bfloat16)
+            sk = jax.random.normal(keys[4], (L, B, N, K * D), jnp.bfloat16)
+            sv = jax.random.normal(keys[5], (L, B, N, K * Dv), jnp.bfloat16)
+            slots = jnp.asarray(1 + np.arange(B) % (S - 1), jnp.int32)
+            entry = jnp.asarray(1000 + 37 * np.arange(B), jnp.int32)  # past the ring's wrap
+
+            def columns_of(form):
+                def layer_body(h, xs):
+                    l, hk, hv = xs
+                    out = form(q + h, rk, rv, hk, hv, jnp.int32(N), l, slots, entry, W, sink,
+                               D ** -0.5)
+                    return h + jnp.pad(out, ((0, 0), (0, 0), (0, D - Dv))), None
+                return jax.jit(lambda: jax.lax.scan(
+                    layer_body, jnp.zeros_like(q), (jnp.arange(L), sk, sv))[0])
+
+            fns = {"xla": columns_of(xla),
+                   "pallas": columns_of(functools.partial(kernel, interpret=interpret))}
+            ms = {name: timed(fn) / L * 1e3 for name, fn in fns.items()}
+            diff = float(jnp.max(jnp.abs(fns["pallas"]().astype(jnp.float32)
+                                         - fns["xla"]().astype(jnp.float32))))
+            ring_bytes = B * R * K * (D + Dv) * 2
+            row = {"model": model, "B": B, "ring": R, "xla_ms_per_layer": ms["xla"],
+                   "pallas_ms_per_layer": ms["pallas"],
+                   "pallas_ring_gb_per_s": ring_bytes / ms["pallas"] / 1e6,
+                   "max_abs_diff": diff, "device_kind": dev.device_kind,
+                   "rehearsal": interpret}
+            print(json.dumps(row), flush=True)
+            rows.append(row)
+    return rows
 
 
 def columns(attend, L, D, q, kc, vc, hk_all, hv_all, tables, entry):
@@ -132,22 +206,29 @@ def main() -> int:
                                interpret=args.rehearsal)
     rng = np.random.default_rng(0)
     rows = []
-    for model, (L, P, H, K, D, shapes) in (REHEARSAL if args.rehearsal else MODELS).items():
+    rows += window_rows(WINDOW_REHEARSAL if args.rehearsal else WINDOW_MODELS, only,
+                        args.rehearsal, dev)
+    for model, (L, P, H, K, D, shapes, *narrow) in (
+            REHEARSAL if args.rehearsal else MODELS).items():
         if only and not any(s.startswith(model + ":") for s in only):
             continue
         latent = K == 0  # one buffer of ``D`` lanes an entry and no V
         kd = D if latent else K * D
+        vd = K * narrow[0] if narrow else kd  # V's lanes
         kq, kk, kv, ks = jax.random.split(jax.random.PRNGKey(0), 4)
         # random, so that the two outputs can be compared; what the cache
         # holds does not change what the attentions cost
         kc = jax.random.normal(kk, (L, P, PS, kd), jnp.bfloat16)
-        vc = kc if latent else jax.random.normal(kv, (L, P, PS, kd), jnp.bfloat16)
-        page_bytes = (1 if latent else 2) * PS * kd * 2  # K and V, or the one buffer
+        vc = kc if latent else jax.random.normal(kv, (L, P, PS, vd), jnp.bfloat16)
+        page_bytes = PS * (kd if latent else kd + vd) * 2  # K and V, or the one buffer
         forms = (latent_forms(D, args.pages_per_block, args.rehearsal) if latent
                  else {"xla": attention_decode_cached, "pallas": pallas})
+        if narrow:
+            forms = narrow_value_forms(forms, D)
         for B, widths in shapes:
             q = jax.random.normal(kq, (B, H, D), jnp.bfloat16)
             side = jax.random.normal(ks, (L, B, N, kd), jnp.bfloat16)
+            side_v = side[..., :vd]
             for mp in widths:
                 if only and f"{model}:{B}x{mp}" not in only:
                     continue
@@ -162,7 +243,7 @@ def main() -> int:
                 for fill in FILLS:
                     held = int(fill * mp * PS) - N
                     entry = jnp.full((B,), held, jnp.int32)
-                    a = (q, kc, vc, side, side, tables, entry)
+                    a = (q, kc, vc, side, side_v, tables, entry)
                     if xla_ms is None:
                         xla_ms = timed(fns["xla"], *a) / L * 1e3
                     ms = timed(fns["pallas"], *a) / L * 1e3

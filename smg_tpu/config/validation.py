@@ -105,9 +105,11 @@ def validate_engine_config(cfg) -> list[ValidationIssue]:
                     f"ep={par.ep} does not divide num_experts={model.num_experts}",
                 ))
     if model is not None and (getattr(model, "recurrent", False)
-                              or getattr(model, "latent_cache", False)):
-        # what a model with recurrent layers, or with a latent cache, cannot do
-        # yet is refused here, at start, and not left to run a wrong model
+                              or getattr(model, "latent_cache", False)
+                              or getattr(model, "window_cache", False)):
+        # what a model with recurrent layers, with a latent cache or with
+        # window layers cannot do yet is refused here, at start, and not left
+        # to run a wrong model
         from smg_tpu.models.registry import get_model
 
         limits = get_model(model.arch).SERVING_LIMITS

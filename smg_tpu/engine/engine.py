@@ -80,6 +80,10 @@ class Engine:
             from smg_tpu.engine.latent_runner import LatentModelRunner
 
             runner_cls = LatentModelRunner
+        elif getattr(config.model, "window_cache", False):
+            from smg_tpu.engine.window_runner import WindowModelRunner
+
+            runner_cls = WindowModelRunner
         self.runner = runner_cls(config, params=params, devices=devices)
         # engine-deep metric set (own registry; the gateway additionally
         # registers it into its CollectorRegistry so /metrics is one scrape)

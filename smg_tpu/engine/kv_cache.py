@@ -119,6 +119,8 @@ class KvCacheSpec:
     # are this many lanes wide, and no V buffer.  0: K and V of
     # ``num_kv_heads * head_dim`` lanes each.
     latent_lanes: int = 0
+    # values narrower than keys: the V buffer's lanes.  0: as many as K's.
+    v_lanes: int = 0
 
     @property
     def lanes(self) -> int:
@@ -132,14 +134,50 @@ class KvCacheSpec:
     @property
     def v_shape(self) -> tuple[int, ...]:
         """The V buffer's shape: of zero size where the cache is latent."""
-        return (self.num_layers, 0, self.page_size, self.lanes) if self.latent_lanes else self.shape
+        if self.latent_lanes:
+            return (self.num_layers, 0, self.page_size, self.lanes)
+        return (*self.shape[:3], self.v_lanes or self.lanes)
 
     @property
     def bytes_per_page(self) -> int:
         # k + v (or the one latent buffer), all layers
         itemsize = jnp.dtype(self.dtype).itemsize
-        buffers = 1 if self.latent_lanes else 2
-        return buffers * self.num_layers * self.page_size * self.lanes * itemsize
+        lanes = self.lanes + (0 if self.latent_lanes else self.v_lanes or self.lanes)
+        return self.num_layers * self.page_size * lanes * itemsize
+
+
+@dataclass
+class WindowSpec:
+    """The window layers' store: for every sequence one slot, each slot a
+    ring of ``ring_tokens`` entries a window layer (``ops/window_attention.py``),
+    K and V of lanes of their own.  What a slot holds does not grow with the
+    sequence's context."""
+
+    num_slots: int  # allocatable slots + the garbage slot
+    num_layers: int
+    window: int
+    ring_tokens: int
+    k_lanes: int
+    v_lanes: int
+    dtype: str
+
+    @property
+    def k_shape(self) -> tuple[int, ...]:
+        return (self.num_layers, self.num_slots, self.ring_tokens, self.k_lanes)
+
+    @property
+    def v_shape(self) -> tuple[int, ...]:
+        return (self.num_layers, self.num_slots, self.ring_tokens, self.v_lanes)
+
+    @property
+    def slot_bytes(self) -> int:
+        """Bytes one sequence's slot holds over all window layers."""
+        return (self.num_layers * self.ring_tokens * (self.k_lanes + self.v_lanes)
+                * jnp.dtype(self.dtype).itemsize)
+
+    @property
+    def total_bytes(self) -> int:
+        return self.slot_bytes * self.num_slots
 
 
 def plan_cache(
@@ -238,6 +276,47 @@ def plan_latent_cache(
         budget = int(hbm_limit * cache.hbm_utilization) - hbm_in_use - workspace
         spec.num_pages = int(max(budget // spec.bytes_per_page, 16))
     return spec
+
+
+def plan_window_cache(
+    model: ModelConfig,
+    cache: CacheConfig,
+    slots: int,
+    unaccepted: int,
+    hbm_limit: int | None = None,
+    hbm_in_use: int = 0,
+    workspace: int = 0,
+) -> tuple[KvCacheSpec, WindowSpec]:
+    """Split what the device has left between the window layers' slots and
+    the full layers' pages, for a model with both kinds of layer.  Pages
+    exist only for the full layers (``model.num_cache_layers``), K and V of
+    their own widths; the ``slots`` slots (and the garbage slot) are taken
+    first, because a sequence cannot be admitted without one, each a ring of
+    the window and the ``unaccepted`` columns that may lie on the device past
+    what the host has accepted.  ``hbm_limit`` and ``hbm_in_use`` are read
+    **after the weights are on the device**, so the weights come off once,
+    and ``workspace`` is kept free of pages, as in ``plan_latent_cache``."""
+    from smg_tpu.ops.window_attention import ring_tokens
+
+    wk, wv = model.kv_lanes(window=True)
+    window = WindowSpec(
+        num_slots=slots + 1, num_layers=model.num_window_layers, window=model.sliding_window,
+        ring_tokens=ring_tokens(model.sliding_window, unaccepted), k_lanes=wk, v_lanes=wv,
+        dtype=cache.dtype)
+    spec = KvCacheSpec(
+        num_layers=model.num_cache_layers,
+        num_pages=cache.num_pages,
+        page_size=cache.page_size,
+        num_kv_heads=model.num_kv_heads,
+        head_dim=model.head_dim,
+        dtype=cache.dtype,
+        v_lanes=model.kv_lanes()[1],
+    )
+    if cache.auto_size and hbm_limit is not None:
+        budget = (int(hbm_limit * cache.hbm_utilization) - hbm_in_use - workspace
+                  - window.total_bytes)
+        spec.num_pages = int(max(budget // spec.bytes_per_page, 16))
+    return spec, window
 
 
 def create_kv_buffers(spec: KvCacheSpec, sharding=None) -> tuple[jax.Array, jax.Array]:

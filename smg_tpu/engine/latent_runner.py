@@ -19,16 +19,11 @@ What this runner refuses: everything in the module's ``SERVING_LIMITS``.
 
 from __future__ import annotations
 
-import types
-from functools import partial
-
-import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
 from smg_tpu.engine.kv_cache import plan_latent_cache
-from smg_tpu.engine.runner import ModelRunner, _attn_label, _pick_sampler, logger
+from smg_tpu.engine.runner import ModelRunner, logger
 from smg_tpu.ops.latent_attention import land_side_buffer
 
 
@@ -37,17 +32,8 @@ class LatentModelRunner(ModelRunner):
         super().__init__(config, params=params, devices=devices)
         # the expert layers' grouped products: the kernel on a TPU, XLA's
         # ragged product elsewhere
-        self.moe_impl = ("pallas" if self.platform == "tpu"
-                         and config.attention_impl != "xla" else "xla")
-        module = self.module
-        self.module = types.SimpleNamespace(**{
-            **vars(module),
-            **{f: partial(getattr(module, f), moe_impl=self.moe_impl)
-               for f in ("forward_prefill", "forward_prefill_batched",
-                         "forward_decode_horizon")}})
-        # device int32 [4] of the frame launched last: ``[picks, picks on held
-        # experts, held experts hit, most rows one layer and column computed]``
-        self.frame_counts = None
+        self._bind_moe_impl("pallas" if self.platform == "tpu"
+                            and config.attention_impl != "xla" else "xla")
         logger.info("latent cache: %d lanes an entry (%d B a token and layer as laid out); "
                     "expert layers %s, experts held %s of %d",
                     self.spec.lanes, self.spec.lanes * jnp.dtype(self.spec.dtype).itemsize,
@@ -176,93 +162,25 @@ class LatentModelRunner(ModelRunner):
     def _decode_multi_fn(self, B: int, mp: int, N: int, E: int = 0,
                          use_pen: bool = False, use_mask: bool = False,
                          use_lora: bool = False, use_mrope: bool = False):
-        """``ModelRunner._decode_multi_fn``'s megastep for this model: the
-        same loop, stop detection and in-loop key folds over one latent side
-        buffer, with the expert layers' counts summed over the columns run."""
+        """``ModelRunner._decode_multi_routed_fn`` over one latent side
+        buffer."""
         if use_lora or use_mrope:
             raise ValueError(self.module.SERVING_LIMITS["lora"])
-        use_stop = E > 0
-        attn_impl = self._attn_impl_for(B, mp)
-        k = ("decode_multi", B, mp, N, E, attn_impl, self.moe_impl, use_pen, use_mask)
-        if k in self._compiled:
-            return self._compiled[k]
         cfg, module = self.model_cfg, self.module
         L, W = cfg.num_layers, self.spec.lanes
-        from smg_tpu.engine.sampling import apply_penalties
 
-        def multi(params, inv_freq, tokens, entry_pos, kc, vc, page_tables,
-                  base_key, step0, n_steps, temps, topks, topps, minps, *extra):
-            i = 0
-            if use_pen:
-                counts_buf, pmask_buf, slot_idx, freqs, pres, reps = extra[:6]
-                i = 6
-            mask = None
-            if use_mask:
-                mask = extra[i]
-                i += 1
-            if use_stop:
-                stop_ids, limits, live = extra[i], extra[i + 1], extra[i + 2]
+        def frame(params, inv_freq, entry_pos, kc, vc, page_tables, attn_impl):
             # a padded lane sits past its table (``Scheduler._launch_frame``)
             holds = entry_pos < page_tables.shape[1] * kc.shape[2]
-            side0 = jnp.zeros((L, B, N, W), kc.dtype)
-            counts0 = counts_buf[slot_idx] if use_pen else jnp.zeros((B, 0))
-            pmask = pmask_buf[slot_idx] if use_pen else None
-            sampler = _pick_sampler()
-            done0 = (~live) if use_stop else jnp.zeros((B,), jnp.bool_)
 
-            def cond(carry):
-                j, done = carry[0], carry[6]
-                ok = j < n_steps
-                if use_stop:
-                    ok = jnp.logical_and(ok, ~jnp.any(done & live))
-                return ok
-
-            def body(carry):
-                j, cur, toks_out, lps_out, side, counts, done, routed = carry
-                logits, side, c = module.forward_decode_horizon(
+            def column(cur, j, side):
+                return module.forward_decode_horizon(
                     params, cfg, inv_freq, cur, entry_pos + j, entry_pos, j,
                     kc, page_tables, side, holds, attn_impl=attn_impl)
-                routed = module.merge_counts(routed, c)
-                if use_pen:
-                    logits = apply_penalties(logits, counts, pmask, freqs, pres, reps)
-                kj = jax.random.split(jax.random.fold_in(
-                    base_key, step0 + j.astype(jnp.uint32) + jnp.uint32(1)), 1)[0]
-                new, lps = sampler(logits, kj, temps, topks, topps, minps, mask=mask)
-                if use_pen:
-                    counts = counts.at[jnp.arange(B), new].add(1)
-                toks_out = lax.dynamic_update_slice(
-                    toks_out, new[:, None].astype(jnp.int32), (0, j))
-                lps_out = lax.dynamic_update_slice(
-                    lps_out, lps[:, None].astype(jnp.float32), (0, j))
-                if use_stop:
-                    tok_done = jnp.any(new[:, None] == stop_ids, axis=1)
-                    done = done | tok_done | ((entry_pos + j) >= (limits - 2))
-                return (j + 1, new, toks_out, lps_out, side, counts, done, routed)
 
-            init = (jnp.int32(0), tokens, jnp.zeros((B, N), jnp.int32),
-                    jnp.zeros((B, N), jnp.float32), side0, counts0, done0,
-                    jnp.zeros((4,), jnp.int32))
-            steps_run, _cur, outs, lps, side, counts, _done, routed = \
-                lax.while_loop(cond, body, init)
-            kc = land_side_buffer(kc, side, page_tables, entry_pos,
-                                  jnp.arange(N)[None, :] < steps_run)
-            out = (outs, lps, steps_run, kc, vc)
-            if use_pen:
-                out += (counts_buf.at[slot_idx].set(counts),)
-            return out + (routed,)
+            def land(side, ran):
+                return land_side_buffer(kc, side, page_tables, entry_pos, ran), vc
 
-        donate = (4, 5) + ((14,) if use_pen else ())
-        if not self.donation.donate_kv:
-            donate = ()
-        fn = self._register(k, jax.jit(multi, donate_argnums=donate), donate=donate,
-                            in_shardings=None, attn=_attn_label("decode", attn_impl),
-                            products=("fused_lanes" if attn_impl == "xla" else None))
+            return jnp.zeros((L, B, N, W), kc.dtype), column, land
 
-        def launch(*args):
-            """What ``ModelRunner.decode_multi_async`` unpacks; the frame's
-            counts stay behind as ``frame_counts`` (a device array)."""
-            *out, self.frame_counts = fn(*args)
-            return out
-
-        self._compiled[k] = launch
-        return launch
+        return self._decode_multi_routed_fn(B, mp, N, E, use_pen, use_mask, frame)

@@ -210,6 +210,15 @@ class EngineMetrics:
             "smg_engine_kv_total_pages", "Total pages in the KV page pool",
             registry=r,
         ))
+        self.window_slots_total = _track(Gauge(
+            "smg_engine_window_slots_total",
+            "Slots of the window layers' store, one a resident sequence (0 for a "
+            "model without window layers)", registry=r,
+        ))
+        self.window_slots_in_use = _track(Gauge(
+            "smg_engine_window_slots_in_use",
+            "Slots of the window layers' store held by resident sequences", registry=r,
+        ))
         self.kv_page_utilization = _track(Gauge(
             "smg_engine_kv_page_utilization",
             "Fraction of KV pages in use (allocated or cached)", registry=r,

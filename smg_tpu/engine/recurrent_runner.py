@@ -42,6 +42,11 @@ from smg_tpu.ops.attention import land_side_buffers
 
 
 class RecurrentModelRunner(ModelRunner):
+    # a decode frame changes what the slots hold and cannot take it back: a
+    # frame thrown away, or trimmed short of what the device ran, costs its
+    # lanes their state (``Scheduler._state_lost``)
+    frames_advance_state = True
+
     # ---- what a sequence holds ----
 
     def _plan_cache(self, param_bytes: int):
@@ -139,7 +144,7 @@ class RecurrentModelRunner(ModelRunner):
     def _plain(self, what: str, **flags) -> None:
         on = [k for k, v in flags.items() if v]
         if on:
-            raise ValueError(f"olmo_hybrid {what} does not take {', '.join(on)}")
+            raise ValueError(f"{self.model_cfg.arch} {what} does not take {', '.join(on)}")
 
     def _prefill_fn(self, T: int, mp: int, use_pen: bool = False,
                     use_mask: bool = False, use_lora: bool = False,
@@ -419,11 +424,9 @@ class RecurrentModelRunner(ModelRunner):
         mark = self._consume_folds(num_steps)
         if state_slots is None:
             state_slots = np.zeros(B, np.int32)
-        if chain is None:
-            chain = self._unchained
         args = [self.params, self.inv_freq, _dev(tokens, jnp.int32),
                 _dev(positions, jnp.int32), self.k_cache, self.v_cache,
-                _dev(page_tables, jnp.int32), *self._state_args(state_slots), chain,
+                _dev(page_tables, jnp.int32), *self._frame_state_args(state_slots, chain),
                 self._rng_key, self._scalar_up(np.uint32(mark)),
                 self._scalar_up(np.int32(num_steps)), _dev(temps, jnp.float32),
                 _dev(topks, jnp.int32), _dev(topps, jnp.float32), _dev(minps, jnp.float32)]
@@ -439,12 +442,22 @@ class RecurrentModelRunner(ModelRunner):
             stop_ids, limits, live = stop_state
             args += [_dev(stop_ids, jnp.int32), _dev(limits, jnp.int32),
                      _dev(live, jnp.bool_)]
+        # smglint: disable-next=DONATE the linter counts ``_frame_state_args`` as one argument: the donated positions 7 and 8 are the two pools, rebound in ``_take_frame_state``
         out = fn(*args)
-        (toks, lps, steps_run, self.k_cache, self.v_cache, self.s_pool, self.c_pool,
-         self.frame_clean) = out[:8]
+        toks, lps, steps_run, self.k_cache, self.v_cache, *rest = out
+        rest = self._take_frame_state(rest)
         if pen is not None:
-            self._counts_buf = out[8]
+            self._counts_buf, = rest
         return toks, lps, steps_run
+
+    def _frame_state_args(self, state_slots, chain) -> list:
+        """What a decode program takes between the page tables and the key."""
+        return [*self._state_args(state_slots), self._unchained if chain is None else chain]
+
+    def _take_frame_state(self, out: list) -> list:
+        """Rebind what a decode program returns behind the pages; the rest."""
+        self.s_pool, self.c_pool, self.frame_clean, *rest = out
+        return rest
 
     # no match is honoured without the state at its end, so no cached page is
     # ever pinned and every one is a frame's to count on (``_headroom_pages``)

@@ -1236,6 +1236,114 @@ class ModelRunner:
                               products=(self.xla_decode_products
                                         if attn_impl == "xla" else None))
 
+    # ---- models with routed experts (``LatentModelRunner``, ``WindowModelRunner``) ----
+
+    def _bind_moe_impl(self, impl: str) -> None:
+        """Bind the implementation of the expert layers' grouped products to
+        the module's forwards, so that every program family calls them as it
+        calls a model without experts."""
+        import types
+
+        self.moe_impl, module = impl, self.module
+        self.module = types.SimpleNamespace(**{
+            **vars(module),
+            **{f: partial(getattr(module, f), moe_impl=impl)
+               for f in ("forward_prefill", "forward_prefill_batched",
+                         "forward_decode_horizon")}})
+        # device int32 [4] of the frame launched last: ``[picks, picks on held
+        # experts, held experts hit, most rows one layer and column computed]``
+        self.frame_counts = None
+
+    def _decode_multi_routed_fn(self, B: int, mp: int, N: int, E: int, use_pen: bool,
+                                use_mask: bool, frame, n_held: int = 0, donate_held=()):
+        """``_decode_multi_fn``'s megastep for a model with routed experts:
+        the same loop, stop detection and in-loop key folds, over whatever
+        side buffers the model's frame has, with the expert layers' counts
+        summed over the columns run.  What a sequence holds besides its pages
+        is ``n_held`` arguments behind ``page_tables`` (those at
+        ``donate_held`` donated).  ``frame(params, inv_freq, entry_pos, kc,
+        vc, page_tables, *held, attn_impl=)`` gives the frame's empty side
+        buffers, its column ``(cur, j, side) -> (logits, side, counts)`` and
+        ``land(side, ran) -> `` the caches as the program returns them.  The
+        frame's counts stay behind as ``frame_counts`` (a device array)."""
+        use_stop = E > 0
+        attn_impl = self._attn_impl_for(B, mp)
+        k = ("decode_multi", B, mp, N, E, attn_impl, self.moe_impl, use_pen, use_mask)
+        if k in self._compiled:
+            return self._compiled[k]
+        module = self.module
+
+        def multi(params, inv_freq, tokens, entry_pos, kc, vc, page_tables, *rest):
+            held = rest[:n_held]
+            base_key, step0, n_steps, temps, topks, topps, minps, *extra = rest[n_held:]
+            i = 0
+            if use_pen:
+                counts_buf, pmask_buf, slot_idx, freqs, pres, reps = extra[:6]
+                i = 6
+            mask = None
+            if use_mask:
+                mask = extra[i]
+                i += 1
+            if use_stop:
+                stop_ids, limits, live = extra[i], extra[i + 1], extra[i + 2]
+            side0, column, land = frame(params, inv_freq, entry_pos, kc, vc, page_tables,
+                                        *held, attn_impl=attn_impl)
+            counts0 = counts_buf[slot_idx] if use_pen else jnp.zeros((B, 0))
+            pmask = pmask_buf[slot_idx] if use_pen else None
+            sampler = _pick_sampler()
+            done0 = (~live) if use_stop else jnp.zeros((B,), jnp.bool_)
+
+            def cond(carry):
+                j, done = carry[0], carry[6]
+                ok = j < n_steps
+                if use_stop:
+                    ok = jnp.logical_and(ok, ~jnp.any(done & live))
+                return ok
+
+            def body(carry):
+                j, cur, toks_out, lps_out, side, counts, done, routed = carry
+                logits, side, c = column(cur, j, side)
+                routed = module.merge_counts(routed, c)
+                if use_pen:
+                    logits = apply_penalties(logits, counts, pmask, freqs, pres, reps)
+                kj = jax.random.split(jax.random.fold_in(
+                    base_key, step0 + j.astype(jnp.uint32) + jnp.uint32(1)), 1)[0]
+                new, lps = sampler(logits, kj, temps, topks, topps, minps, mask=mask)
+                if use_pen:
+                    counts = counts.at[jnp.arange(B), new].add(1)
+                toks_out = lax.dynamic_update_slice(
+                    toks_out, new[:, None].astype(jnp.int32), (0, j))
+                lps_out = lax.dynamic_update_slice(
+                    lps_out, lps[:, None].astype(jnp.float32), (0, j))
+                if use_stop:
+                    tok_done = jnp.any(new[:, None] == stop_ids, axis=1)
+                    done = done | tok_done | ((entry_pos + j) >= (limits - 2))
+                return (j + 1, new, toks_out, lps_out, side, counts, done, routed)
+
+            init = (jnp.int32(0), tokens, jnp.zeros((B, N), jnp.int32),
+                    jnp.zeros((B, N), jnp.float32), side0, counts0, done0,
+                    jnp.zeros((4,), jnp.int32))
+            steps_run, _cur, outs, lps, side, counts, _done, routed = \
+                lax.while_loop(cond, body, init)
+            out = (outs, lps, steps_run, *land(side, jnp.arange(N)[None, :] < steps_run))
+            if use_pen:
+                out += (counts_buf.at[slot_idx].set(counts),)
+            return out + (routed,)
+
+        donate = (4, 5, *(7 + i for i in donate_held)) + ((14 + n_held,) if use_pen else ())
+        if not self.donation.donate_kv:
+            donate = ()
+        fn = self._register(k, jax.jit(multi, donate_argnums=donate), donate=donate,
+                            in_shardings=None, attn=_attn_label("decode", attn_impl),
+                            products=("fused_lanes" if attn_impl == "xla" else None))
+
+        def launch(*args):
+            *out, self.frame_counts = fn(*args)
+            return out
+
+        self._compiled[k] = launch
+        return launch
+
     def decode_multi_async(
         self,
         tokens,  # [B] int32 (np OR device array — device chaining is free)

@@ -174,6 +174,13 @@ class Scheduler:
             StateSlotPool(runner.state_spec.num_slots)
             if getattr(runner, "state_spec", None) is not None else None
         )
+        # a model with window layers: the slot holds rings of the last tokens,
+        # which a decode frame overwrites only where no later query looks, so
+        # a frame thrown away or trimmed costs nothing; recurrent state moves
+        # on with every column and cannot be taken back
+        self._frames_advance_state = (
+            self.state_pool is not None and runner.frames_advance_state)
+        self._window_slots = self.state_pool is not None and not runner.frames_advance_state
         self.num_state_prefix_hits_declined = 0
         self.num_state_recomputed_tokens = 0
         self._step_state_lanes = 0
@@ -482,7 +489,25 @@ class Scheduler:
             "dispatch_enqueue_seconds": self.dispatch_enqueue_s_total,
             "fetch_wait_seconds": self.fetch_wait_s_total,
         }
-        if self.state_pool is not None:
+        if self._window_slots:
+            spec, info = self.runner.spec, self.runner.window_info()
+            out.update({
+                # the two kinds of cache: pages of the full-attention layers,
+                # and the window layers' slots, whose size is the window's
+                "kv_groups": {
+                    "global": {"layers": spec.num_layers,
+                               "bytes_per_token": spec.bytes_per_page // spec.page_size,
+                               "pages_total": self.pool.num_pages - 1,
+                               "pages_in_use": self.pool.num_pages - 1 - self.pool.free_count},
+                    "window": {**info, "slots_in_use": self.state_pool.in_use},
+                },
+                # radix matches turned down for want of the window entries at
+                # their end, and tokens prefilled again because a sequence
+                # lost its slot to a preemption
+                "window_prefix_hits_declined": self.num_state_prefix_hits_declined,
+                "window_recomputed_tokens": self.num_state_recomputed_tokens,
+            })
+        elif self.state_pool is not None:
             info = self.runner.state_info()
             out.update({
                 "state_slots_total": info["slots_total"],
@@ -545,7 +570,9 @@ class Scheduler:
             held = {r.state_slot for r in live if r.state_slot is not None}
             leaked_slots = self.state_pool.in_use - len(held)
         return {
-            **({"leaked_state_slots": leaked_slots} if self.state_pool is not None else {}),
+            **({("leaked_window_slots" if self._window_slots
+                 else "leaked_state_slots"): leaked_slots}
+               if self.state_pool is not None else {}),
             "live_slots": len(live),
             "waiting_requests": len(self.waiting),
             "inflight_frames": 0 if self.inflight is None else 1,
@@ -719,6 +746,9 @@ class Scheduler:
                 },
                 decode_horizon=self._step_horizon,
             )
+            if self._window_slots:
+                m.window_slots_total.set(self.state_pool.num_slots - 1)
+                m.window_slots_in_use.set(self.state_pool.in_use)
             if outcome is not None:
                 m.observe_overlap(
                     outcome=outcome,
@@ -935,7 +965,7 @@ class Scheduler:
                 if self._prefill_phase_fold_free():
                     look = self._launch_lookahead(frame)
                 fetch_s, used = self._consume_frame(frame, outputs)
-                if look is not None and self.state_pool is not None:
+                if look is not None and self._frames_advance_state:
                     look.ran = bool(frame.clean)
             except Exception:
                 # quarantine path: rewind the NEWEST folds first (the chained
@@ -1151,7 +1181,7 @@ class Scheduler:
         # results are never fetched, so count the full requested width (an
         # upper bound — the device may have early-exited sooner)
         self.num_wasted_decode_tokens += frame.B_real * frame.horizon
-        if self.state_pool is not None and frame.ran:
+        if self._frames_advance_state and frame.ran:
             self._state_lost([r for _s, r, _e in frame.lanes], "discarded frame")
         if frame.use_pen:
             for _slot, req, _expected in frame.lanes:
@@ -1284,7 +1314,7 @@ class Scheduler:
         # host-side trim: earliest finish column across all lanes (scanning
         # only device-computed columns — later ones hold unset zeros)
         used = min(frame.horizon, sr) if sr > 0 else frame.horizon
-        if self.state_pool is not None:
+        if self._frames_advance_state:
             used = min(frame.horizon, sr)  # a frame that ran no column gives no token
         finished_any = False
         for idx, (_slot, req, _expected) in enumerate(frame.lanes):
@@ -1314,7 +1344,7 @@ class Scheduler:
                 outputs,
                 advance_seq=True,
             )
-        if self.state_pool is not None and sr > used:
+        if self._frames_advance_state and sr > used:
             # the device ran past what the host accepts: the state holds
             # tokens that were never emitted
             self._state_lost([r for _s, r, _e in frame.lanes], "host trim short of device")
@@ -1361,7 +1391,7 @@ class Scheduler:
         H2, max_steps = self._pick_horizon(
             [(s, r) for s, r, _ in frame.lanes]
         )
-        if self.state_pool is not None and (max_steps == 1 or frame.clean is None):
+        if self._frames_advance_state and (max_steps == 1 or frame.clean is None):
             # without the device's stop state a frame cannot tell the one
             # chained on it that it met a finish, and a lookahead that ran and
             # is then discarded costs its lanes their state
@@ -1577,7 +1607,7 @@ class Scheduler:
             if (len(req.output_ids) + 1 >= sp.max_new_tokens
                     or req.total_len + 1 >= self.sched.max_seq_len):
                 return "first_token_ends"
-            if self.state_pool is not None and (
+            if self._frames_advance_state and (
                     sp.stop_token_ids or (eos and not sp.ignore_eos)):
                 return "recurrent_stop_ids"
         return None
@@ -2273,6 +2303,8 @@ class Scheduler:
         chained on."""
         if self.state_pool is None:
             return {}
+        if self._window_slots:
+            return {"state_slots": ds.state_slots}
         return {"state_slots": ds.state_slots, "chain": chain}
 
     def _pages_needed(self, active: list, k: int) -> int:

@@ -89,6 +89,28 @@ class ModelConfig:
     # weights against a reference sets it (``random_routed_out_gain``), not
     # the program: 1 draws every expert alike.
     random_routed_out_gain: float = 1.0
+    # ---- window layers beside full ones (``mimo_v2_flash``).  ``layer_types``
+    # names every layer ``full_attention`` or ``sliding_attention``; a window
+    # layer has ``swa_num_kv_heads`` key/value heads, rotary base
+    # ``swa_rope_theta`` and, where ``swa_sink_bias``, a learned logit a head
+    # in its softmax's denominator.  Keys are ``head_dim`` wide and values
+    # ``v_head_dim``, in both kinds; rotary turns the first
+    # ``qk_rope_head_dim`` lanes of a head; values are scaled by
+    # ``attention_value_scale``.  ``moe_layer_freq[l]`` is 1 where layer ``l``
+    # has routed experts; ``moe_select_bias``: the router picks by score plus
+    # a learned bias and weighs by the score.
+    swa_num_kv_heads: int = 0
+    swa_rope_theta: float = 0.0
+    swa_sink_bias: bool = False
+    attention_value_scale: float = 1.0
+    moe_layer_freq: "tuple[int, ...] | None" = None
+    moe_select_bias: bool = False
+
+    @property
+    def window_cache(self) -> bool:
+        """Some layers keep a window of keys and values a sequence, outside
+        the pages."""
+        return self.layer_types is not None and "sliding_attention" in self.layer_types
 
     @property
     def latent_cache(self) -> bool:
@@ -113,6 +135,17 @@ class ModelConfig:
         return sum(1 for t in self.layer_types if t == "full_attention")
 
     @property
+    def num_window_layers(self) -> int:
+        """Layers that keep a window of keys and values a sequence."""
+        return sum(1 for t in self.layer_types or () if t == "sliding_attention")
+
+    def kv_lanes(self, window: bool = False) -> tuple[int, int]:
+        """(K lanes, V lanes) a token leaves in a full-attention layer, or in
+        a window layer."""
+        heads = self.swa_num_kv_heads if window else self.num_kv_heads
+        return heads * self.head_dim, heads * (self.v_head_dim or self.head_dim)
+
+    @property
     def recurrent(self) -> bool:
         """Some layers keep per-sequence state outside the pages."""
         return self.layer_types is not None and "linear_attention" in self.layer_types
@@ -132,6 +165,8 @@ class ModelConfig:
             return cls._from_olmo_hybrid(cfg, dtype)
         if cfg.get("model_type") == "pangu_ultra_moe":
             return cls._from_pangu_ultra_moe(cfg, dtype)
+        if cfg.get("model_type") == "mimo_v2_flash":
+            return cls._from_mimo_v2_flash(cfg, dtype)
         # keys that change what the layers compute and that this path would
         # drop in silence: routed experts beyond Qwen-MoE's settings, latent
         # attention.  A config that carries one is another model (D6's rule).
@@ -391,6 +426,117 @@ class ModelConfig:
             random_routed_out_gain=float(cfg.get("random_routed_out_gain", 1.0)),
         )
 
+    # ``mimo_v2_flash`` (MiMo-V2-Flash): the same rule as above.
+    _MIMO_CONSUMED = frozenset({
+        "model_type", "vocab_size", "hidden_size", "intermediate_size",
+        "moe_intermediate_size", "num_hidden_layers", "num_attention_heads",
+        "num_key_value_heads", "swa_num_key_value_heads", "head_dim", "v_head_dim",
+        "swa_num_attention_heads", "swa_head_dim", "swa_v_head_dim",
+        "hidden_act", "max_position_embeddings", "attention_bias", "layernorm_epsilon",
+        "tie_word_embeddings", "rope_theta", "swa_rope_theta", "rope_scaling",
+        "partial_rotary_factor", "hybrid_layer_pattern", "sliding_window",
+        "sliding_window_size", "attention_chunk_size", "add_swa_attention_sink_bias",
+        "add_full_attention_sink_bias", "attention_value_scale", "moe_layer_freq",
+        "n_routed_experts", "n_shared_experts", "num_experts_per_tok", "scoring_func",
+        "topk_method", "n_group", "topk_group", "norm_topk_prob", "routed_scaling_factor",
+        "eos_token_id", "bos_token_id",
+        # the chip's share of a deployment, as for ``pangu_ultra_moe``
+        "router_num_experts", "routed_expert_offset",
+        # random weights only: see ``ModelConfig.random_routed_out_gain``
+        "random_routed_out_gain",
+    })
+
+    @classmethod
+    def _from_mimo_v2_flash(cls, cfg: dict, dtype: str) -> "ModelConfig":
+        unknown = sorted(set(cfg) - cls._MIMO_CONSUMED - cls._OLMO_HYBRID_SHAPELESS)
+        if unknown:
+            raise ValueError(
+                f"mimo_v2_flash config.json has keys this loader does not consume: {unknown}; "
+                "a key that may bear on the model's shape is not dropped in silence")
+
+        def only(key, served, default):
+            if cfg.get(key, default) not in served:
+                raise ValueError(f"mimo_v2_flash: {key} {cfg[key]!r} is not served")
+
+        only("hidden_act", ("silu",), "silu")
+        only("attention_bias", (False, None), False)
+        only("rope_scaling", (None, "none"), None)
+        only("scoring_func", ("sigmoid",), "sigmoid")
+        only("topk_method", ("noaux_tc",), "noaux_tc")
+        only("n_group", (1, None), 1)
+        only("topk_group", (1, None), 1)
+        only("n_shared_experts", (0, None), 0)
+        only("add_full_attention_sink_bias", (False, None), False)
+        only("routed_scaling_factor", (None, 1, 1.0), None)
+        window = cfg["sliding_window"]
+        for key in ("sliding_window_size", "attention_chunk_size"):
+            if cfg.get(key, window) != window:
+                raise ValueError(
+                    f"mimo_v2_flash: {key} {cfg[key]} differs from sliding_window {window}; "
+                    "what a second window would mean is not served")
+        for key, same in (("swa_num_attention_heads", "num_attention_heads"),
+                          ("swa_head_dim", "head_dim"), ("swa_v_head_dim", "v_head_dim")):
+            if cfg.get(key, cfg[same]) != cfg[same]:
+                raise ValueError(
+                    f"mimo_v2_flash: {key} {cfg[key]} differs from {same} {cfg[same]}; window "
+                    "layers with query heads of their own shape are not served")
+        layers = cfg["num_hidden_layers"]
+        pattern, moe = tuple(cfg["hybrid_layer_pattern"]), tuple(cfg["moe_layer_freq"])
+        if len(pattern) != layers or len(moe) != layers \
+                or not set(pattern) <= {0, 1} or not set(moe) <= {0, 1}:
+            raise ValueError(
+                f"mimo_v2_flash: hybrid_layer_pattern ({len(pattern)}) and moe_layer_freq "
+                f"({len(moe)}) must give a 0 or a 1 for each of {layers} layers")
+        if 1 not in pattern:
+            raise ValueError("mimo_v2_flash: no sliding-window layer in hybrid_layer_pattern")
+        D = cfg["head_dim"]
+        rope_dim = int(D * cfg.get("partial_rotary_factor", 1.0))
+        if rope_dim % 2 or not 0 < rope_dim <= D:
+            raise ValueError(f"mimo_v2_flash: rotary over {rope_dim} of {D} lanes")
+        held = cfg["n_routed_experts"]
+        width = cfg.get("router_num_experts", held)
+        first = cfg.get("routed_expert_offset", 0)
+        if not (0 <= first and first + held <= width):
+            raise ValueError(
+                f"mimo_v2_flash: experts {first}..{first + held - 1} are not among "
+                f"the router's {width}")
+        heads = cfg["num_attention_heads"]
+        eos = cfg.get("eos_token_id", 2)
+        return cls(
+            arch="mimo_v2_flash",
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=layers,
+            num_heads=heads,
+            num_kv_heads=cfg["num_key_value_heads"],
+            head_dim=D,
+            rope_theta=float(cfg.get("rope_theta", 10000.0)),
+            rms_norm_eps=cfg.get("layernorm_epsilon", 1e-5),
+            max_position_embeddings=cfg.get("max_position_embeddings", 8192),
+            tie_word_embeddings=bool(cfg.get("tie_word_embeddings", False)),
+            eos_token_ids=tuple(eos) if isinstance(eos, list) else (eos,),
+            bos_token_id=cfg.get("bos_token_id", 1),
+            dtype=dtype,
+            num_experts=width,
+            num_experts_per_tok=cfg["num_experts_per_tok"],
+            moe_intermediate_size=cfg["moe_intermediate_size"],
+            qk_rope_head_dim=rope_dim,
+            v_head_dim=cfg["v_head_dim"],
+            moe_scoring="sigmoid",
+            norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
+            experts_held=(first, held),
+            sliding_window=window,
+            layer_types=tuple("sliding_attention" if p else "full_attention" for p in pattern),
+            swa_num_kv_heads=cfg["swa_num_key_value_heads"],
+            swa_rope_theta=float(cfg.get("swa_rope_theta", 10000.0)),
+            swa_sink_bias=bool(cfg.get("add_swa_attention_sink_bias", False)),
+            attention_value_scale=float(cfg.get("attention_value_scale") or 1.0),
+            moe_layer_freq=moe,
+            moe_select_bias=True,
+            random_routed_out_gain=float(cfg.get("random_routed_out_gain", 1.0)),
+        )
+
     @classmethod
     def from_pretrained(cls, path: str, dtype: str = "bfloat16") -> "ModelConfig":
         with open(os.path.join(path, "config.json")) as f:
@@ -527,6 +673,45 @@ def tiny_pangu_moe_config(vocab_size: int = 512, held: "tuple[int, int] | None" 
     )
 
 
+def tiny_mimo_config(vocab_size: int = 512, held: "tuple[int, int] | None" = None,
+                     **changes) -> ModelConfig:
+    """Tiny MiMo-V2-Flash for CPU tests: a dense full-attention layer, three
+    window layers (window 8) and a full one with routed experts (16, top 4, of
+    which ``held`` are here; None: all).  16 heads of 64 with values of 32,
+    rotary over 16 lanes; 4 key/value heads in the full layers (K lanes 256,
+    V 128) and 8 in the window layers (512 and 256): whole 128-lane tiles, so
+    that both decode kernels run in interpret mode."""
+    import dataclasses
+
+    return dataclasses.replace(
+        tiny_test_config(vocab_size),
+        arch="mimo_v2_flash",
+        num_layers=5,
+        num_heads=16,
+        num_kv_heads=4,
+        head_dim=64,
+        v_head_dim=32,
+        qk_rope_head_dim=16,
+        rope_theta=5000000.0,
+        num_experts=16,
+        num_experts_per_tok=4,
+        moe_intermediate_size=64,
+        moe_scoring="sigmoid",
+        norm_topk_prob=True,
+        experts_held=held,
+        sliding_window=8,
+        layer_types=("full_attention", "sliding_attention", "sliding_attention",
+                     "sliding_attention", "full_attention"),
+        swa_num_kv_heads=8,
+        swa_rope_theta=10000.0,
+        swa_sink_bias=True,
+        attention_value_scale=0.707,
+        moe_layer_freq=(0, 1, 1, 1, 1),
+        moe_select_bias=True,
+        **changes,
+    )
+
+
 def tiny_gemma2_config(vocab_size: int = 512) -> ModelConfig:
     """Tiny Gemma-2-style model for CPU tests: gelu MLP, (1+w) norms,
     scaled embeddings, post norms, attn/final softcaps, tied unembed."""
@@ -564,6 +749,7 @@ PRESETS = {
     "tiny-vlm": tiny_vlm_config,
     "tiny-olmo-hybrid": tiny_olmo_hybrid_config,
     "tiny-pangu-moe": tiny_pangu_moe_config,
+    "tiny-mimo": tiny_mimo_config,
     "llama3.2-1b": llama32_1b_config,
     "llama3-8b": llama3_8b_config,
     "llama3-70b": llama3_70b_config,

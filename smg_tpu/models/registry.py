@@ -21,7 +21,11 @@ with state beside its pages):
 - a module whose cache is one latent buffer (``models/pangu_moe.py``) takes
   ``v_cache`` of zero size through its prefill forwards untouched, and its
   decode column takes the one side buffer and the lanes that hold a sequence
-  (``engine/latent_runner.py``).
+  (``engine/latent_runner.py``);
+- a module with window layers beside full ones (``models/mimo.py``) takes the
+  window layers' rings and the rows' slots after the page tables, as a module
+  with state does, and its decode column the four side buffers as one tuple
+  (``engine/window_runner.py``).
 """
 
 from __future__ import annotations
@@ -31,7 +35,7 @@ from types import ModuleType
 _REGISTRY: dict[str, ModuleType] = {}
 # architectures this package brings itself, loaded on first use
 _LLAMA_FAMILY = ("llama", "qwen", "mistral", "qwen_moe")
-_BUILTIN = (*_LLAMA_FAMILY, "olmo_hybrid", "pangu_ultra_moe")
+_BUILTIN = (*_LLAMA_FAMILY, "olmo_hybrid", "pangu_ultra_moe", "mimo_v2_flash")
 
 
 def register_model(arch: str, module: ModuleType) -> None:
@@ -57,6 +61,10 @@ def get_model(arch: str) -> ModuleType:
             from smg_tpu.models import pangu_moe
 
             _REGISTRY.setdefault("pangu_ultra_moe", pangu_moe)
+        elif arch == "mimo_v2_flash":
+            from smg_tpu.models import mimo
+
+            _REGISTRY.setdefault("mimo_v2_flash", mimo)
         else:
             raise KeyError(
                 f"unsupported model architecture: {arch!r} "

@@ -50,11 +50,12 @@ def scatter_kv_pages_full(
     carry the write stays in place (the slice/stack dance costs a full layer
     copy per layer per step)."""
     L, P, ps, KD = k_cache.shape
+    VD = v_cache.shape[3]  # V's lanes: KD but where values are narrower than keys
     T = k_new.shape[0]
     k_flat = k_cache.reshape(L, P * ps, KD)
-    v_flat = v_cache.reshape(L, P * ps, KD)
+    v_flat = v_cache.reshape(L, P * ps, VD)
     k_flat = k_flat.at[layer, dest_slots].set(k_new.reshape(T, KD).astype(k_flat.dtype))
-    v_flat = v_flat.at[layer, dest_slots].set(v_new.reshape(T, KD).astype(v_flat.dtype))
+    v_flat = v_flat.at[layer, dest_slots].set(v_new.reshape(T, VD).astype(v_flat.dtype))
     return k_flat.reshape(k_cache.shape), v_flat.reshape(v_cache.shape)
 
 
@@ -77,7 +78,7 @@ def scatter_kv_rows(
     k_flat = k_cache.reshape(L, P * ps, KD).at[layer, dest].set(
         k_rows.astype(k_cache.dtype)
     )
-    v_flat = v_cache.reshape(L, P * ps, KD).at[layer, dest].set(
+    v_flat = v_cache.reshape(L, P * ps, v_cache.shape[3]).at[layer, dest].set(
         v_rows.astype(v_cache.dtype)
     )
     return k_flat.reshape(k_cache.shape), v_flat.reshape(v_cache.shape)
@@ -114,7 +115,7 @@ def land_side_buffers(
     pos = entry_positions[:, None] + jnp.arange(N)[None, :]
     dest = page_slots(page_tables, pos, keep, k_cache.shape[2]).reshape(-1)
     return scatter_kv_rows(k_cache, v_cache, side_k.reshape(L, B * N, KD),
-                           side_v.reshape(L, B * N, KD), dest)
+                           side_v.reshape(L, B * N, side_v.shape[3]), dest)
 
 
 @jax.named_scope("smg.attn.kv_read")
@@ -318,7 +319,7 @@ def _attend_cache_and_side(
     kl, vl = gather_layer_pages(k_cache, v_cache, layer, page_tables)
     S = page_tables.shape[1] * ps
     kl = kl.reshape(B, S, KD)
-    vl = vl.reshape(B, S, KD)
+    vl = vl.reshape(B, S, vl.shape[-1])
     sk = sk.astype(cd)
     sv = sv.astype(cd)
     f32 = jnp.float32
@@ -334,8 +335,8 @@ def _attend_cache_and_side(
         def weigh(p, vals):  # [B, W, H, n], [B, n, KD] -> [B, W, H, D]
             n = vals.shape[1]
             return jnp.einsum("bwkgs,bskd->bwkgd", p.reshape(B, W, K, G, n),
-                              vals.reshape(B, n, K, D),
-                              preferred_element_type=f32).reshape(B, W, H, D)
+                              vals.reshape(B, n, K, -1),
+                              preferred_element_type=f32).reshape(B, W, H, -1)
     else:
         q_bd = block_diagonal_query(q.astype(cd), K)  # [B, W, H, KD]
 

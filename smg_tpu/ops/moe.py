@@ -56,14 +56,20 @@ class Dispatch(NamedTuple):
 
 
 @jax.named_scope("smg.moe.route")
-def route(x, router, *, top_k: int, scoring: str, norm_topk: bool, scale: float) -> Routing:
+def route(x, router, *, top_k: int, scoring: str, norm_topk: bool, scale: float,
+          select_bias=None) -> Routing:
     """Scores of ``x`` [T, E] over all experts of ``router`` [E, X], float32;
     the ``top_k`` largest, renormalised to sum 1 where ``norm_topk``, times
     ``scale``.  ``scoring`` "softmax" is a softmax over all experts, "sigmoid"
-    an independent sigmoid of each."""
+    an independent sigmoid of each.  With ``select_bias`` [X] the experts are
+    picked by score plus bias and weighed by the score alone."""
     logits = jnp.einsum("te,ex->tx", x, router, preferred_element_type=jnp.float32)
     scores = jax.nn.sigmoid(logits) if scoring == "sigmoid" else jax.nn.softmax(logits, axis=-1)
-    top, experts = jax.lax.top_k(scores, top_k)
+    if select_bias is None:
+        top, experts = jax.lax.top_k(scores, top_k)
+    else:
+        _, experts = jax.lax.top_k(scores + select_bias.astype(jnp.float32), top_k)
+        top = jnp.take_along_axis(scores, experts, axis=-1)
     if norm_topk:
         top = top / jnp.sum(top, axis=-1, keepdims=True)
     return Routing(experts.astype(jnp.int32), top * scale)
@@ -96,7 +102,11 @@ def grouped_matmul(rows, weights, group_sizes, impl: str, layer=None):
                       interpret=(impl == "pallas_interpret"))
     if layer is not None:
         weights = jax.lax.dynamic_index_in_dim(weights, layer, 0, keepdims=False)
-    return jax.lax.ragged_dot(rows, weights, group_sizes)
+    out = jax.lax.ragged_dot(rows, weights, group_sizes)
+    # XLA:TPU leaves what it finds in the rows past the groups' total (NaN
+    # among it, which the combine's weight of 0 does not silence)
+    past = jnp.arange(rows.shape[0])[:, None] >= jnp.sum(group_sizes)
+    return jnp.where(past, jnp.zeros((), out.dtype), out)
 
 
 def _experts(rows, w_gate, w_up, w_down, group_sizes, impl, layer):

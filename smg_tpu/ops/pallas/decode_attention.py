@@ -92,13 +92,13 @@ def _decode_kernel(
     else:
         (q_ref,  # [1, H, KD] VMEM (block-diagonal query for this sequence)
          hk_ref,  # [1, N, KD] VMEM (horizon side buffer, rows 0..n_extra-1 valid)
-         hv_ref,  # [1, N, KD] VMEM
+         hv_ref,  # [1, N, VD] VMEM (VD: V's lanes, KD unless values are narrower than keys)
          k_hbm,  # [L, P*ps, KD] HBM (read-only cache)
          v_hbm,
-         out_ref,  # [1, H, KD] VMEM
+         out_ref,  # [1, H, VD] VMEM
          k_buf,  # [2, n*ps, KD] VMEM: two slots of one block each
          v_buf,
-         acc_ref,  # [H, KD] f32
+         acc_ref,  # [H, VD] f32
          slot_ref,  # [1] int32 SMEM: the slot this lane's first block is in
          sems,  # DMA sems [2 (K, V), 2 slots]
          ) = refs
@@ -250,11 +250,12 @@ def paged_attention_decode_cached(
 ) -> jax.Array:
     B, H, D = q.shape
     L, P, ps, KD = k_cache.shape
+    VD = v_cache.shape[3]  # KD, but where values are narrower than keys
     K = KD // D
     N = hk.shape[1]
     mp = page_tables.shape[1]
     cd = k_cache.dtype
-    if KD % 128 != 0:
+    if KD % 128 != 0 or VD % 128 != 0:
         raise ValueError(f"kv_heads*head_dim={KD} must be a multiple of 128 for the "
                          "pallas decode kernel; use the XLA fallback")
     n = pages_per_block or _pages_per_block(ps, KD, cd.itemsize, mp)
@@ -279,15 +280,15 @@ def paged_attention_decode_cached(
         in_specs=[
             pl.BlockSpec((1, H, KD), lambda b, *_: (b, 0, 0)),
             pl.BlockSpec((1, N, KD), lambda b, *_: (b, 0, 0)),
-            pl.BlockSpec((1, N, KD), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec((1, N, VD), lambda b, *_: (b, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, H, KD), lambda b, *_: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, VD), lambda b, *_: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, n * ps, KD), cd),
-            pltpu.VMEM((2, n * ps, KD), cd),
-            pltpu.VMEM((H, KD), jnp.float32),
+            pltpu.VMEM((2, n * ps, VD), cd),
+            pltpu.VMEM((H, VD), jnp.float32),
             pltpu.SMEM((1,), jnp.int32),
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
@@ -295,7 +296,7 @@ def paged_attention_decode_cached(
     out_kd = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, KD), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, VD), q.dtype),
         # lanes in order: a lane's last block starts the next lane's first
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
@@ -307,7 +308,7 @@ def paged_attention_decode_cached(
         hk.astype(cd),
         hv.astype(cd),
         k_cache.reshape(L, P * ps, KD),
-        v_cache.reshape(L, P * ps, KD),
+        v_cache.reshape(L, P * ps, VD),
     )
     return own_lanes(out_kd, K).astype(q.dtype)
 

@@ -384,3 +384,102 @@ class TestLatentModelCompilesForV5e:
         # once in each scanned stack's body: attention in both stacks, the
         # three grouped products in the expert stack
         assert calls == {"smg.attn.decode": 2, "smg.moe.experts": 3}
+
+
+class TestWindowModelCompilesForV5e:
+    """``models/mimo.py`` at the widths of the benchmark's cut
+    (``benchmark/configs/mimo-v2-flash.json``)."""
+
+    @staticmethod
+    def cut():
+        import json
+        import os
+
+        from smg_tpu.models.config import ModelConfig
+
+        path = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "benchmark",
+                            "configs", "mimo-v2-flash.json")
+        own = {"assumed", "deployment", "chips", "serve_args", "rehearsal", "architecture",
+               "reduced", "published"}
+        with open(path) as f:
+            return ModelConfig.from_hf_config(
+                {k: v for k, v in json.load(f).items() if k not in own})
+
+    @pytest.mark.parametrize("B", [8, 64])
+    def test_a_decode_frame_runs_its_kernels_and_copies_no_weights(self, v5e, B):
+        """A frame is a loop of columns over three scans of layers.  The paged
+        kernel (K of 768 lanes, V of 512) and the ring kernel are in it under
+        their own names, the second not beginning with the first's (a trace
+        counts columns by the paged kernel's name), and nothing moves a
+        weight into another layout: stored ``[in, out]`` the input
+        projections of a window layer were copied a layer and column, 121 MB
+        (``models/mimo.init_params``)."""
+        from smg_tpu.models import mimo as M
+        from smg_tpu.ops.attention import land_side_buffers
+        from smg_tpu.ops.window_attention import land_ring_side
+
+        cfg = self.cut()
+        one = SingleDeviceSharding(v5e[0])
+        s = lambda shape, dtype=BF16: jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one)
+        i32 = jnp.int32
+        params = jax.tree.map(
+            lambda x: s(x.shape, x.dtype),
+            jax.eval_shape(functools.partial(M.init_params, cfg), jax.random.PRNGKey(0)))
+        mp, N, P, slots, R = 512, 8, 30000, 73, 144
+
+        def frame(p, inv, tok, entry, kc, vc, tables, rk, rv, lane_slots, n_steps):
+            holds = lane_slots > 0
+
+            def body(c):
+                j, cur, side, counts = c
+                logits, side, k = M.forward_decode_horizon(
+                    p, cfg, inv, cur, entry + j, entry, j, kc, vc, tables, rk, rv, lane_slots,
+                    side, holds, attn_impl="pallas", moe_impl="pallas")
+                return j + 1, jnp.argmax(logits, -1).astype(i32), side, counts + k
+
+            j, cur, (hk, hv, wk, wv), counts = jax.lax.while_loop(
+                lambda c: c[0] < n_steps, body,
+                (i32(0), tok, M.side_buffers(cfg, B, N, kc.dtype), jnp.zeros((4,), i32)))
+            ran = jnp.arange(N)[None] < j
+            kc, vc = land_side_buffers(kc, vc, hk, hv, tables, entry, ran)
+            rk, rv = land_ring_side(rk, rv, wk, wv, lane_slots, entry, ran)
+            return cur, kc, vc, rk, rv, counts
+
+        compiled = jax.jit(frame, donate_argnums=(4, 5, 7, 8)).lower(
+            params, s((cfg.rope_dim // 2,), jnp.float32), s((B,), i32), s((B,), i32),
+            s((2, P, PS, 768)), s((2, P, PS, 512)), s((B, mp), i32),
+            s((5, slots, R, 1536)), s((5, slots, R, 1024)), s((B,), i32), s((), i32)).compile()
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+        hlo = compiled.as_text()
+        assert _relayouts(hlo, 10 * 2**20) == []  # under a projection's size: none is copied
+        calls = collections.Counter(
+            re.findall(r"%(smg\.[\w.]+?)\.\d+ = \S+ custom-call", hlo))
+        # once in each scanned run's body: the paged kernel in the two full
+        # runs, the ring kernel in the window run, the three grouped products
+        # in the two runs with experts
+        assert calls == {"smg.attn.decode": 2, "smg.attn.window_decode": 1,
+                         "smg.moe.experts": 6}
+        assert not "smg.attn.window_decode".startswith("smg.attn.decode")
+
+    def test_a_prefill_of_a_steps_budget_fits_its_workspace(self, v5e):
+        """4,096 tokens in one row: no ``[heads, T, context]`` float32 array
+        (4.3 GB at T = context = 4,096), and the program's temporaries inside
+        what ``plan_window_cache`` keeps free of pages."""
+        from smg_tpu.models import mimo as M
+
+        cfg = self.cut()
+        one = SingleDeviceSharding(v5e[0])
+        s = lambda shape, dtype=BF16: jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one)
+        i32 = jnp.int32
+        params = jax.tree.map(
+            lambda x: s(x.shape, x.dtype),
+            jax.eval_shape(functools.partial(M.init_params, cfg), jax.random.PRNGKey(0)))
+        T, mp, P, slots, R = 4096, 512, 30000, 73, 144
+        compiled = jax.jit(
+            lambda p, inv, *a: M.forward_prefill(p, cfg, inv, *a, moe_impl="pallas"),
+            donate_argnums=(5, 6, 8, 9)).lower(
+            params, s((cfg.rope_dim // 2,), jnp.float32), s((T,), i32), s((), i32), s((), i32),
+            s((2, P, PS, 768)), s((2, P, PS, 512)), s((mp,), i32),
+            s((5, slots, R, 1536)), s((5, slots, R, 1024)), s((), i32)).compile()
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < M.prefill_workspace_bytes(cfg, T, "bfloat16") < 2 * 2**30
