@@ -89,6 +89,9 @@ class LatentModelRunner(ModelRunner):
         by a step's budget continues at the grouped path's speed."""
         return "xla"
 
+    def _grouped_prefill_impl_for(self, G: int, T: int, no_ctx: bool) -> str:
+        return "xla"  # the same one form
+
     def _split_group(self, lengths: "list[int]") -> "list[list[int]]":
         """A group is padded to ``G x T``, both rounded up, so one long prompt
         among short ones makes a program of up to four times the step's

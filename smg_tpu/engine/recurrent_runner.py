@@ -185,13 +185,14 @@ class RecurrentModelRunner(ModelRunner):
         if k in self._compiled:
             return self._compiled[k]
         cfg, module = self.model_cfg, self.module
+        impl = self._grouped_prefill_impl_for(G, T, no_ctx)
         from smg_tpu.engine.sampling import apply_penalties
 
         def step(params, inv_freq, tokens, prefix_lens, t_reals, kc, vc, page_tables,
                  sp, cp, slots, key, temps, topks, topps, minps, *extra):
             logits, kc, vc, sp, cp = module.forward_prefill_batched(
                 params, cfg, inv_freq, tokens, prefix_lens, t_reals, kc, vc, page_tables,
-                sp, cp, slots, no_ctx=no_ctx)
+                sp, cp, slots, no_ctx=no_ctx, attn_impl=impl)
             i = 0
             if use_pen:
                 logits = apply_penalties(logits, *extra[:5])
@@ -202,7 +203,7 @@ class RecurrentModelRunner(ModelRunner):
 
         donate = (5, 6, 8, 9)
         return self._register(k, jax.jit(step, donate_argnums=donate), donate=donate,
-                              in_shardings=None, attn="xla")
+                              in_shardings=None, attn=_attn_label("prefill", impl))
 
     def _decode_multi_fn(self, B: int, mp: int, N: int, E: int = 0,
                          use_pen: bool = False, use_mask: bool = False,

@@ -48,6 +48,17 @@ logger = get_logger("engine.runner")
 # tests/test_tpu_compile.py compiles for a v5e.
 PREFILL_KERNEL_MAX_T = 4096
 
+# Float32 scores of a cold grouped prefill, ``G x T x H x T x 4`` bytes, up
+# to which the XLA form stays the faster one.  Timed alone on a v5e
+# (``scripts/time_prefill_attention.py``; the table is ``PERF.md``, Findings,
+# PR 38) XLA keeps scores of 96 MiB and less on the chip and beats the
+# online-softmax kernel by 1.1 to 3 times there (0.08 against 0.10 ms a layer
+# at 1 x 1,024 tokens of 16 heads); from 120 MiB on they go through HBM and
+# the kernel wins by 1.8 to 12 times (0.26 against 1.15 ms at 1 x 2,048).
+# Nothing between the two sizes was timed, and no program of a model with 16
+# or 30 heads falls there.
+FLASH_PREFILL_MIN_SCORE_BYTES = 96 * 2**20
+
 
 def _dev(x, dtype, sharding=None) -> jax.Array:
     """Explicit upload for decode hot-path inputs: resident ``jax.Array``s
@@ -450,6 +461,29 @@ class ModelRunner:
             return "pallas"
         return "pallas" if mp * self.spec.page_size > 2048 else "xla"
 
+    def _grouped_prefill_impl_for(self, G: int, T: int, no_ctx: bool) -> str:
+        """Attention of one grouped-prefill program of ``G`` rows in the
+        ``T``-token bucket.  Behind a cached prefix (``no_ctx`` false) it is
+        XLA's over the rows' gathered pages.  When every row is cold the keys
+        are the chunk's own and there are two forms: XLA's, which scores the
+        whole ``[G, T, H, T]`` square in float32, and the online-softmax
+        kernel (``ops/pallas/flash_prefill.py``), which keeps a block of
+        scores in VMEM and stops at the diagonal and at a row's length.
+        Where kernels can run at all (``_resolve_attn_impl``), the heads are
+        whole 128-lane tiles and the model has neither a softcap nor a
+        window (the kernel takes none), the kernel is chosen from the size
+        on at which XLA's scores no longer stay on the chip
+        (``FLASH_PREFILL_MIN_SCORE_BYTES``); ``attention_impl='pallas'``
+        forces it at every size."""
+        cfg = self.model_cfg
+        if (not no_ctx or self.use_pp or self.attn_impl == "xla"
+                or cfg.head_dim % 128 or cfg.attn_logit_softcap or cfg.sliding_window):
+            return "xla"
+        if self.attn_impl != "auto":
+            return self.attn_impl
+        scores = G * T * cfg.num_heads * T * 4
+        return "pallas" if scores > FLASH_PREFILL_MIN_SCORE_BYTES else "xla"
+
     def _local_param_bytes(self) -> int:
         """Bytes of parameters resident on ONE device (the sizing unit)."""
         leaves = jax.tree.leaves(self.params)
@@ -839,6 +873,7 @@ class ModelRunner:
         module = self.module
         n_slots = self.lora_slots
         pp_mesh = self.mesh if self.use_pp else None
+        impl = self._grouped_prefill_impl_for(G, T, no_ctx)
 
         def step(params, inv_freq, tokens, prefix_lens, t_reals, kc, vc, page_tables,
                  key, temps, topks, topps, minps, *extra):
@@ -864,7 +899,7 @@ class ModelRunner:
                 params, cfg, inv_freq, tokens, prefix_lens, t_reals, kc, vc, page_tables,
                 no_ctx=no_ctx, lora=lora_bank, lora_gates=lora_gates,
                 input_embeds=input_embeds, embeds_mask=embeds_mask,
-                rope_pos=rope_pos, pp_mesh=pp_mesh,
+                rope_pos=rope_pos, pp_mesh=pp_mesh, attn_impl=impl,
             )
             if use_pen:
                 logits = apply_penalties(logits, counts, pmask, freqs, pres, reps)
@@ -890,7 +925,7 @@ class ModelRunner:
             in_sh = None
             fn = jax.jit(step, donate_argnums=(5, 6))
         return self._register(k, fn, donate=(5, 6), in_shardings=in_sh,
-                              attn="xla")
+                              attn=_attn_label("prefill", impl))
 
     def prefill_batched(self, chunks, *args, **kw) -> tuple[np.ndarray, np.ndarray]:
         """Prefill several single-chunk sequences in one call:

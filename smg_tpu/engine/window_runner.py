@@ -115,6 +115,9 @@ class WindowModelRunner(RecurrentModelRunner):
         prompt cut by a step's budget continues at the grouped path's speed."""
         return "xla"
 
+    def _grouped_prefill_impl_for(self, G: int, T: int, no_ctx: bool) -> str:
+        return "xla"  # the same one form
+
     _chunk_bucket = ModelRunner._chunk_bucket  # every bucket: a cut prompt is common here
 
     # ---- the decode family ----

@@ -489,6 +489,7 @@ def forward_prefill_batched(
     embeds_mask: jnp.ndarray | None = None,  # [G, T] bool: row from input_embeds
     rope_pos: jnp.ndarray | None = None,  # [G, 3, T] M-RoPE position ids
     pp_mesh=None,  # Mesh: serving pipeline parallelism over the "pp" axis
+    attn_impl: str = "xla",  # "xla" | "pallas" | "pallas_interpret" (tests); no_ctx only
 ):
     """Prefill several sequences in one device call (fills the MXU and
     amortizes dispatch; single-sequence prefill wastes both).  Returns
@@ -496,7 +497,10 @@ def forward_prefill_batched(
 
     ``no_ctx=True`` (every row is a cold single-chunk prompt — the common
     case) attends over the chunk's own K/V instead of gathering the
-    sequence's full page range, cutting attention reads by max_seq_len/T.
+    sequence's full page range, cutting attention reads by max_seq_len/T;
+    under a ``pallas`` ``attn_impl`` it does so as an online softmax that
+    keeps the scores in VMEM (``ops/pallas/flash_prefill.py``; the caller
+    vouches for a model with no softcap and no window).
     """
     G_, T = tokens.shape
     ps = k_cache.shape[2]
@@ -528,12 +532,19 @@ def forward_prefill_batched(
             k_cache, v_cache = scatter_kv_pages_full(
                 *caches, l, k.reshape(G_ * T, K, D), v.reshape(G_ * T, K, D), dest
             )
-            if not no_ctx:
-                k, v = (x.reshape(G_, mp * ps, K, D) for x in gather_layer_pages(
-                    k_cache, v_cache, l, page_tables))  # [G, mp, ps, KD] each
-            attn = attention_prefill_batched(q, k, v, pos, ctx_lens, scale,
-                                             softcap=cfg.attn_logit_softcap,
-                                             window=_layer_window(cfg, l))
+            if no_ctx and attn_impl.startswith("pallas"):
+                from smg_tpu.ops.pallas.flash_prefill import flash_attention_prefill
+
+                # prefix 0: a row's context is its own ``t_real`` tokens
+                attn = flash_attention_prefill(
+                    q, k, v, ctx_lens, scale, interpret=(attn_impl == "pallas_interpret"))
+            else:
+                if not no_ctx:
+                    k, v = (x.reshape(G_, mp * ps, K, D) for x in gather_layer_pages(
+                        k_cache, v_cache, l, page_tables))  # [G, mp, ps, KD] each
+                attn = attention_prefill_batched(q, k, v, pos, ctx_lens, scale,
+                                                 softcap=cfg.attn_logit_softcap,
+                                                 window=_layer_window(cfg, l))
             return attn, (k_cache, v_cache)
 
         return partial(decoder_block, cfg, _rotary(cfg, inv_freq, pos, rope_pos),

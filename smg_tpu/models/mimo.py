@@ -399,6 +399,7 @@ def forward_prefill_batched(
     slots: jnp.ndarray,  # [G]; a padded row names slot 0
     no_ctx: bool = False,  # static: every row starts its sequence
     moe_impl: str = "xla",
+    attn_impl: str = "xla",  # prefill attention has one form; kept for the runner
 ):
     """Several sequences' chunks in one call.  Returns (logits [G, V],
     k_cache, v_cache, ring_k, ring_v)."""

@@ -416,6 +416,7 @@ def forward_prefill_batched(
     c_pool: jnp.ndarray,
     slots: jnp.ndarray,  # [G]; a padded row names slot 0
     no_ctx: bool = False,  # static: every row starts its sequence
+    attn_impl: str = "xla",  # "xla" | "pallas" | "pallas_interpret" (tests); no_ctx only
 ):
     """Several sequences' chunks in one call.  Returns (logits [G, V],
     k_cache, v_cache, s_pool, c_pool)."""
@@ -425,6 +426,11 @@ def forward_prefill_batched(
     ctx_lens = prefix_lens + t_reals
 
     def attention(q, k, v, kc, vc, p, pos):
+        if no_ctx and attn_impl.startswith("pallas"):
+            from smg_tpu.ops.pallas.flash_prefill import flash_attention_prefill
+
+            return flash_attention_prefill(q, k, v, ctx_lens, scale,
+                                           interpret=(attn_impl == "pallas_interpret"))
         if no_ctx:  # the chunk is the whole context
             return attention_prefill_batched(q, k, v, pos, ctx_lens, scale)
         kl, vl = gather_layer_pages(kc, vc, p, page_tables)  # [G, mp, ps, KD]

@@ -376,6 +376,7 @@ def forward_prefill_batched(
     page_tables: jnp.ndarray,  # [G, mp]
     no_ctx: bool = False,  # static: every row starts its sequence
     moe_impl: str = "xla",
+    attn_impl: str = "xla",  # prefill attention has one form; kept for the runner
     **unserved,
 ):
     """Several sequences' chunks in one call.  Returns (logits [G, V],

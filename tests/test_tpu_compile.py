@@ -22,7 +22,14 @@ from jax.sharding import SingleDeviceSharding
 
 from smg_tpu.engine.config import EngineConfig, ParallelConfig
 from smg_tpu.engine.kv_cache import KvCacheSpec
-from smg_tpu.engine.runner import PREFILL_KERNEL_MAX_T, ModelRunner
+from smg_tpu.engine.latent_runner import LatentModelRunner
+from smg_tpu.engine.runner import (
+    FLASH_PREFILL_MIN_SCORE_BYTES,
+    PREFILL_KERNEL_MAX_T,
+    ModelRunner,
+    _attn_label,
+)
+from smg_tpu.engine.window_runner import WindowModelRunner
 from smg_tpu.engine.sampling import sample_tokens
 from smg_tpu.models.config import llama32_1b_config
 from smg_tpu.models.registry import get_model
@@ -33,6 +40,7 @@ from smg_tpu.ops.attention import (
     scatter_kv_rows,
 )
 from smg_tpu.ops.pallas.decode_attention import paged_attention_decode_cached
+from smg_tpu.ops.pallas.flash_prefill import flash_attention_prefill
 from smg_tpu.ops.pallas.prefill_attention import paged_attention_prefill
 from smg_tpu.parallel.mesh import build_mesh
 from smg_tpu.parallel.sharding import ShardingRules, logical_to_sharding, tree_shardings
@@ -83,6 +91,60 @@ class TestDispatchRule:
         cfg = dataclasses.replace(CFG, num_heads=heads, num_kv_heads=kv, head_dim=128)
         assert _rule("tpu", model=cfg)._attn_impl_for(B, mp) == "pallas"
         assert _rule("cpu", model=cfg)._attn_impl_for(B, mp) == "xla"
+
+    @pytest.mark.parametrize("model", ["qwen3-1.7b", "olmo-hybrid-7b"])
+    def test_cold_grouped_prefill_takes_the_kernel_from_its_size_on(self, model):
+        """The online-softmax kernel only for a group of cold rows, from the
+        size on at which XLA's float32 scores ``G x T x H x T`` no longer stay
+        on the chip (PERF.md, PR 38: 96 MiB XLA's, 120 MiB the kernel's), at
+        both cells' head counts; its launches count as ``pallas_prefill``."""
+        heads, kv = {"qwen3-1.7b": (16, 8), "olmo-hybrid-7b": (30, 30)}[model]
+        cfg = dataclasses.replace(CFG, num_heads=heads, num_kv_heads=kv, head_dim=128)
+        r = _rule("tpu", model=cfg)
+        for G in (1, 2, 4, 8):
+            for T in (256, 512, 1024, 2048, 4096):
+                want = ("pallas" if G * T * heads * T * 4 > FLASH_PREFILL_MIN_SCORE_BYTES
+                        else "xla")
+                assert r._grouped_prefill_impl_for(G, T, True) == want, (G, T)
+                assert r._grouped_prefill_impl_for(G, T, False) == "xla"  # behind a prefix
+        # what the sweep timed on either side of the constant
+        took = {(G, T) for G in (1, 2, 4, 8) for T in (512, 1024, 2048)
+                if r._grouped_prefill_impl_for(G, T, True) == "pallas"}
+        assert took == {"qwen3-1.7b": {(8, 512), (2, 1024), (4, 1024), (8, 1024), (1, 2048),
+                                       (2, 2048), (4, 2048), (8, 2048)},
+                        "olmo-hybrid-7b": {(4, 512), (8, 512), (1, 1024), (2, 1024), (4, 1024),
+                                           (8, 1024), (1, 2048), (2, 2048), (4, 2048),
+                                           (8, 2048)}}[model]
+        assert _attn_label("prefill", r._grouped_prefill_impl_for(1, 4096, True)) == (
+            "pallas_prefill")
+        assert _attn_label("prefill", r._grouped_prefill_impl_for(1, 256, True)) == "xla"
+        assert _rule("cpu", model=cfg)._grouped_prefill_impl_for(1, 4096, True) == "xla"
+
+    def test_cold_grouped_prefill_stays_on_xla_where_the_kernel_cannot_serve(self, cpu_devices):
+        """Never under a mesh or pp, with a softcap, a window or heads that
+        are not whole 128-lane tiles, nor in the runners whose models have
+        one prefill attention of their own; forced, at every size."""
+        cfg = dataclasses.replace(CFG, num_heads=16, num_kv_heads=8, head_dim=128)
+        big = (1, 4096, True)
+        assert _rule("tpu", model=cfg)._grouped_prefill_impl_for(*big) == "pallas"
+        mesh = build_mesh(ParallelConfig(tp=4), devices=cpu_devices[:4])
+        assert _rule("tpu", mesh=mesh, model=cfg)._grouped_prefill_impl_for(*big) == "xla"
+        pp = _rule("tpu", model=cfg)
+        pp.use_pp = True
+        assert pp._grouped_prefill_impl_for(*big) == "xla"
+        for change in ({"attn_logit_softcap": 50.0}, {"sliding_window": 4096},
+                       {"head_dim": 64, "num_kv_heads": 16}):
+            other = dataclasses.replace(cfg, **change)
+            assert _rule("tpu", model=other)._grouped_prefill_impl_for(*big) == "xla", change
+            assert _rule("tpu", attention_impl="pallas", model=other)._grouped_prefill_impl_for(
+                *big) == "xla", change
+        assert _rule("tpu", attention_impl="xla", model=cfg)._grouped_prefill_impl_for(
+            *big) == "xla"
+        forced = _rule("tpu", attention_impl="pallas", model=cfg)
+        assert forced._grouped_prefill_impl_for(1, 256, True) == "pallas"
+        assert forced._grouped_prefill_impl_for(1, 256, False) == "xla"
+        for cls in (LatentModelRunner, WindowModelRunner):
+            assert cls._grouped_prefill_impl_for(object.__new__(cls), *big) == "xla"
 
     def test_kernel_never_above_its_bound_even_when_forced(self):
         r = _rule("tpu", attention_impl="pallas")
@@ -202,6 +264,28 @@ class TestCompilesForV5e:
             s((B, N, kd)), s((B, N, kd)), s((), jnp.int32),
             s((), jnp.int32), s((B, mp), jnp.int32), s((B,), jnp.int32),
         )
+
+    @pytest.mark.parametrize("G,T", [(1, 4096), (2, 2048), (4, 1024)])
+    @pytest.mark.parametrize("H,K", [(16, 8), (30, 30)], ids=["qwen", "olmo_hybrid"])
+    def test_flash_prefill_kernel_at_the_cells_shapes(self, v5e, G, T, H, K):
+        """The cold grouped prefill's kernel at the widest programs of the two
+        cells that reach it, queries, keys and values flat as the projections
+        leave them: one custom call under its own name, and no temporary the
+        size of a ``[T, T]`` score tensor beside it."""
+        s = self._sds(v5e)
+        D = 128
+
+        def attend(q, k, v, t_reals):
+            out = flash_attention_prefill(q.reshape(G, T, H, D), k.reshape(G, T, K, D),
+                                          v.reshape(G, T, K, D), t_reals, scale=0.088)
+            return out.reshape(G, T, H * D)
+
+        compiled = _compile(attend, s((G, T, H * D)), s((G, T, K * D)), s((G, T, K * D)),
+                            s((G,), jnp.int32))
+        calls = [line for line in compiled.as_text().splitlines() if "custom-call(" in line]
+        assert len(calls) == 1 and "%smg.attn.prefill" in calls[0], calls
+        assert "tpu_custom_call" in calls[0]
+        assert compiled.memory_analysis().temp_size_in_bytes < T * T * 4
 
     @pytest.mark.parametrize("B,V", [(16, 151936), (1, 151936), (16, 100352)],
                              ids=["qwen_decode", "qwen_first_token", "olmo_hybrid_decode"])
