@@ -603,8 +603,8 @@ def main() -> dict:
             temperature=0.0, max_new_tokens=8, ignore_eos=True))
         e.flush_cache()
         sched = e.scheduler
-        d0 = sched.dispatch_enqueue_s_total
-        f0 = sched.fetch_wait_s_total
+        phases = lambda: e.loads(include_audit=False)["step_phases"]["seconds"]
+        p0 = phases()
         t0_tok = sched.num_decode_tokens
         done: dict = {}
         for i, p in enumerate(probe_prompts):
@@ -621,8 +621,9 @@ def main() -> dict:
                 raise TimeoutError("tp probe stuck")
         dt = time.perf_counter() - t0
         toks = sched.num_decode_tokens - t0_tok
-        dispatch_s = sched.dispatch_enqueue_s_total - d0
-        fetch_s = sched.fetch_wait_s_total - f0
+        p1 = phases()
+        dispatch_s = p1["launch_dispatch"] - p0["launch_dispatch"]
+        fetch_s = p1["consume_fetch"] - p0["consume_fetch"]
         streams = [
             [t for o in done[i] for t in o.new_token_ids]
             for i in sorted(done)

@@ -13,9 +13,11 @@ Two record kinds:
 
 - **Step ring** — a fixed-size ring of per-step records: step serial, step
   kind (prefill/decode/mixed/idle), batch occupancy, prefill-budget tokens
-  spent, overlap outcome (OVERLAP_OUTCOMES) with the
-  host-busy vs device-wait split, admissions/finishes, and fault flags.
-  One dict append per step; the ring bound makes host memory constant.
+  spent, overlap outcome (OVERLAP_OUTCOMES), where the step's host
+  seconds went and when the chip had nothing queued (PHASE_RECORD_KEYS),
+  admissions/finishes, and fault flags.
+  One dict append per step; the ring bound makes host memory constant.  The
+  last few records past SLOW_STEP_S are kept beside the ring, whole.
 - **Request timelines** — per-request event sequences from queued →
   admitted → each prefill chunk → first token → ITL samples → terminal
   finish, with preempt/quarantine/deadline events, the request's sampling
@@ -70,8 +72,33 @@ logger = get_logger("engine.flight_recorder")
 #: device ran of the consumed frame (``min(horizon, steps_run)``; 0 where no
 #: frame was consumed), and for a model with routed experts
 #: ``moe_picks_held`` and ``moe_experts_hit`` of that frame; v8: ``overlap``
-#: may read "chained" (OVERLAP_OUTCOMES))
-SCHEMA_VERSION = 8
+#: may read "chained" (OVERLAP_OUTCOMES); v9: PHASE_RECORD_KEYS, where the
+#: step's host seconds went and when the chip had nothing queued
+#: (``spans.StepAccount``), and ``slow_steps`` in the dump)
+SCHEMA_VERSION = 9
+
+#: what ``spans.StepAccount.end_step`` gives a step record beside ``step_s``
+#: and ``fetch_wait_s`` (the seconds inside ``smg.step.consume.fetch``):
+#: seconds inside each of the three phases and inside admit's two sub-spans;
+#: ``dispatch_s``, the seconds inside jitted calls that enqueue device work
+#: (``admit_dispatch_s`` and what the launches' ``smg.step.launch.dispatch``
+#: took); ``gap_s``, from the end of the previous step's record to this
+#: step's start where that step left work behind; ``starved_s``, the seconds
+#: of the gap and the step in which the host knew the chip had nothing
+#: queued, and its parts inside the three phases (the rest fell in the gap
+#: or between the phases)
+PHASE_RECORD_KEYS = frozenset({
+    "consume_s", "admit_s", "admit_pack_s", "admit_dispatch_s", "launch_s",
+    "dispatch_s", "gap_s", "starved_s", "starved_consume_s",
+    "starved_admit_s", "starved_launch_s",
+})
+_NO_PHASES = dict.fromkeys(PHASE_RECORD_KEYS, 0.0)
+
+#: a step whose ``step_s + gap_s`` passes this is kept whole among the
+#: recorder's slow steps (``loads()["slow_steps"]``): the witness of a
+#: stalled window, in every run
+SLOW_STEP_S = 1.0
+SLOW_STEPS_KEPT = 8
 
 #: stable key set of one step record (schema contract, tested)
 STEP_RECORD_KEYS = frozenset({
@@ -81,7 +108,7 @@ STEP_RECORD_KEYS = frozenset({
     "faults", "horizon", "early_exits", "wasted_decode_tokens",
     "spec_drafted", "spec_accepted", "mesh", "horizon_reason", "state_lanes",
     "columns_run",
-})
+}) | PHASE_RECORD_KEYS
 #: what a model with routed experts adds to a step record (no other writes them)
 MOE_STEP_RECORD_KEYS = frozenset({"moe_picks_held", "moe_experts_hit"})
 
@@ -211,6 +238,8 @@ class FlightRecorder:
         self.dump_min_interval_secs = dump_min_interval_secs
         self._lock = make_lock("flight_recorder")
         self._ring: deque = deque(maxlen=ring_size)
+        self._slow: deque = deque(maxlen=SLOW_STEPS_KEPT)
+        self.num_slow_steps = 0
         self._live: dict[str, RequestTimeline] = {}
         self._finished: deque = deque(maxlen=timeline_keep)
         #: completed auto-dump snapshots, newest last (bounded)
@@ -238,9 +267,11 @@ class FlightRecorder:
         spec_drafted: int = 0, spec_accepted: int = 0,
         mesh: int = 1, horizon_reason: str = "", state_lanes: int = 0,
         columns_run: int = 0, moe: "tuple[int, int] | None" = None,
+        phases: dict | None = None,
     ) -> int:
         """Append one step record; returns the step serial.  Called once per
-        scheduler step with values already in hand — no derivation here."""
+        scheduler step with values already in hand — no derivation here.
+        ``phases`` holds the PHASE_RECORD_KEYS (zeros without it)."""
         if prefill_tokens and decode_tokens:
             kind = "mixed"
         elif prefill_tokens:
@@ -249,9 +280,10 @@ class FlightRecorder:
             kind = "decode"
         else:
             kind = "idle"
+        phases = phases or _NO_PHASES
         with self._lock:
             self.step_serial += 1
-            self._ring.append({
+            rec = {
                 "serial": self.step_serial,
                 "t": time.monotonic(),
                 "kind": kind,
@@ -296,8 +328,20 @@ class FlightRecorder:
                 # one row summed over layers and columns
                 **({"moe_picks_held": moe[0], "moe_experts_hit": moe[1]}
                    if moe is not None else {}),
-            })
+                **phases,
+            }
+            self._ring.append(rec)
+            if step_s + phases["gap_s"] > SLOW_STEP_S:
+                self._slow.append(rec)
+                self.num_slow_steps += 1
             return self.step_serial
+
+    def slow_steps(self) -> dict:
+        """The last SLOW_STEPS_KEPT step records past SLOW_STEP_S, whole, and
+        how many there have been (``loads()["slow_steps"]``)."""
+        with self._lock:
+            return {"threshold_s": SLOW_STEP_S, "count": self.num_slow_steps,
+                    "steps": [dict(r) for r in self._slow]}
 
     # ---- request timelines ----
 
@@ -377,6 +421,7 @@ class FlightRecorder:
                 "t_mono": time.monotonic(),
                 "last_step_serial": self.step_serial,
                 "ring": [dict(r) for r in self._ring],
+                "slow_steps": [dict(r) for r in self._slow],
                 "timelines": {
                     "live": [tl.to_dict() for tl in self._live.values()],
                     "finished": [tl.to_dict() for tl in self._finished],

@@ -153,12 +153,14 @@ class LatentModelRunner(ModelRunner):
         """A chunk that is not the prompt's last runs the ``prefill`` program
         (one program a bucket instead of two) with the unfolded key, so the
         key counter stands still, and fetches nothing."""
-        T, mp, base, *_ = self._prefill_chunk_prep(
+        T, mp, _lora, _ring, host = self._prefill_chunk_prep(
             token_ids, prefix_len, page_table, lora_idx, mm, rope_pos)
-        up = self.upload
-        _tok, _lp, self.k_cache, self.v_cache = self._prefill_fn(T, mp)(
-            *base, self._rng_key, up([0.0], jnp.float32), up([-1], jnp.int32),
-            up([1.0], jnp.float32), up([0.0], jnp.float32))
+        fn, up = self._prefill_fn(T, mp), self.upload
+        with self.account.span("smg.step.admit.dispatch"):
+            base, _tail = self._chunk_args(host)
+            _tok, _lp, self.k_cache, self.v_cache = fn(
+                *base, self._rng_key, up([0.0], jnp.float32), up([-1], jnp.int32),
+                up([1.0], jnp.float32), up([0.0], jnp.float32))
 
     # ---- the decode family ----
 

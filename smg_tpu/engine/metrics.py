@@ -33,6 +33,7 @@ from collections import deque
 
 from prometheus_client import CollectorRegistry, Counter, Gauge, Histogram
 
+from smg_tpu.engine.spans import PHASES, STARVED_PHASES
 from smg_tpu.utils import get_logger
 
 logger = get_logger("engine.metrics")
@@ -130,7 +131,9 @@ class EngineMetrics:
 
         self.step_duration = _track(Histogram(
             "smg_engine_step_duration_seconds",
-            "Engine step latency by phase (prefill admission / decode / full step)",
+            "Engine step latency by phase, from the step account: prefill = "
+            "the step's admit seconds, decode = its consume and launch "
+            "seconds (fetch wait included), step = the whole step",
             ["phase"], buckets=STEP_LATENCY_BUCKETS, registry=r,
         ))
         self.prefill_tokens = _track(Counter(
@@ -243,8 +246,8 @@ class EngineMetrics:
         ))
         self.decode_stall = _track(Counter(
             "smg_engine_decode_stall_seconds_total",
-            "Decode delay attributable to same-step prefill work (host-side "
-            "prefill-phase seconds in steps that also decoded); bounded by "
+            "Decode delay attributable to same-step prefill work (the step "
+            "account's admit seconds of steps that also decoded); bounded by "
             "~one chunk per step under stall-free scheduling, by the whole "
             "prompt under the legacy throughput policy",
             registry=r,
@@ -337,18 +340,6 @@ class EngineMetrics:
             "(device not yet done when the host came back for them)",
             buckets=STEP_LATENCY_BUCKETS, registry=r,
         ))
-        self.overlap_host_busy = _track(Counter(
-            "smg_engine_overlap_host_busy_seconds_total",
-            "Host-side step time excluding the deferred fetch wait "
-            "(scheduling, detokenize, bookkeeping that overlap device work)",
-            registry=r,
-        ))
-        self.overlap_device_wait = _track(Counter(
-            "smg_engine_overlap_device_wait_seconds_total",
-            "Cumulative deferred-fetch wait (host stalled on the device); "
-            "rate vs overlap_host_busy gives the pipeline balance",
-            registry=r,
-        ))
         # tensor-parallel sharded decode (first-class runner mode)
         self.mesh_devices = _track(Gauge(
             "smg_engine_mesh_devices",
@@ -357,15 +348,35 @@ class EngineMetrics:
             "multiplies over",
             registry=r,
         ))
-        self.dispatch_seconds = _track(Counter(
-            "smg_engine_dispatch_seconds_total",
-            "Per-step host time by dispatch phase: enqueue = async launch "
-            "of the (sharded or single-device) decode/verify programs, "
-            "fetch = blocked materializing their results.  On a mesh the "
-            "enqueue share is the sharded-dispatch overhead the megastep "
-            "must amortize",
+        # the step account (engine/spans.py): one export of where the step
+        # thread's seconds went, and of when the chip had nothing queued
+        self.step_phase_seconds = _track(Counter(
+            "smg_engine_step_phase_seconds_total",
+            "Step-thread seconds by phase, from the step account "
+            "(engine/spans.py): consume and inside it consume_fetch (blocked "
+            "in jax.device_get on the frame in flight), admit (the prefill "
+            "phase) and inside it admit_pack (numpy building of a prefill's "
+            "operands) and admit_dispatch (its uploads and jitted call), "
+            "launch and inside it launch_dispatch (a decode frame's jitted "
+            "call; on a mesh the sharded-dispatch work a megastep "
+            "amortizes), gap (between two steps while work was pending).  A "
+            "step's host-busy time is its step_duration less consume_fetch",
             ["phase"], registry=r,
         ))
+        self.chip_starved_seconds = _track(Counter(
+            "smg_engine_chip_starved_seconds_total",
+            "Seconds in which the host knew the chip had nothing queued "
+            "(from the return of a fetch that left no launch outstanding to "
+            "the return of the next dispatch), by the phase they fell in "
+            "(consume, admit, launch; other = between steps and outside the "
+            "three).  Errs low: launches that are never fetched are proved "
+            "done only by the next fetch",
+            ["phase"], registry=r,
+        ))
+        self._phase_children = {
+            p: self.step_phase_seconds.labels(phase=p) for p in PHASES + ("gap",)}
+        self._starved_children = {
+            p: self.chip_starved_seconds.labels(phase=p) for p in STARVED_PHASES}
 
         # where a caller's time to first token goes inside the engine
         self.submit_lock_wait = _track(Counter(
@@ -514,22 +525,20 @@ class EngineMetrics:
         self.spec_accepted.labels(tier=tier).inc(accepted)
         self.spec_accept_len.observe(accepted)
 
-    def observe_overlap(
-        self, *, outcome: str, fetch_wait_s: float, host_s: float
-    ) -> None:
-        """Record one overlap-pipeline step: its lookahead outcome and the
-        host-busy vs device-wait split (the numbers that show whether host
-        work actually hides behind device compute)."""
+    def observe_overlap(self, *, outcome: str, fetch_wait_s: float) -> None:
+        """Record one overlap-pipeline step: its lookahead outcome and how
+        long the host was blocked on the frame in flight."""
         self.lookahead_launches.labels(outcome=outcome).inc()
         self.deferred_fetch.observe(fetch_wait_s)
-        self.overlap_host_busy.inc(max(host_s, 0.0))
-        self.overlap_device_wait.inc(max(fetch_wait_s, 0.0))
 
-    def observe_dispatch(self, *, enqueue_s: float, fetch_s: float) -> None:
-        """Record one step's dispatch-time split (async launch enqueue vs
-        deferred-fetch block); see ``smg_engine_dispatch_seconds_total``."""
-        self.dispatch_seconds.labels(phase="enqueue").inc(max(enqueue_s, 0.0))
-        self.dispatch_seconds.labels(phase="fetch").inc(max(fetch_s, 0.0))
+    def observe_phases(self, account) -> None:
+        """Add the step that ``account`` (``spans.StepAccount``) has just
+        closed to the two counters of the step account."""
+        for p, v in account.step.items():
+            self._phase_children[p].inc(v)
+        self._phase_children["gap"].inc(account.gap_s)
+        for p, v in account.starved.items():
+            self._starved_children[p].inc(v)
 
     def observe_submit_lock_wait(self, seconds: float) -> None:
         self.submits.inc()

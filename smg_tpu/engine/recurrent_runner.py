@@ -306,23 +306,17 @@ class RecurrentModelRunner(ModelRunner):
                 state_slot: int = 0):
         """``ModelRunner.prefill`` from the state in ``state_slot`` (zero
         state where ``prefix_len`` is 0)."""
-        T, mp, base, use_lora, use_ring, _tail = self._prefill_chunk_prep(
+        T, mp, use_lora, use_ring, host = self._prefill_chunk_prep(
             token_ids, prefix_len, page_table, lora_idx, mm, rope_pos)
         fn = self._prefill_fn(T, mp, use_pen=pen is not None, use_mask=mask is not None,
                               use_lora=use_lora, use_ring=use_ring,
                               use_embeds=mm is not None, use_mrope=rope_pos is not None)
-        up = self.upload
-        args = base + self._state_args(np.int32(state_slot)) + [
-            self._next_key(), up([temperature], jnp.float32), up([top_k], jnp.int32),
-            up([top_p], jnp.float32), up([min_p], jnp.float32)]
-        if pen is not None:
-            counts, pmask, freq, pres, rep = pen
-            args += [up(counts, jnp.int32)[None], up(pmask)[None], up([freq], jnp.float32),
-                     up([pres], jnp.float32), up([rep], jnp.float32)]
-        if mask is not None:
-            args.append(up(mask)[None])
-        tok, lp, self.k_cache, self.v_cache, self.s_pool, self.c_pool = fn(*args)
-        return int(tok), float(lp)
+        with self.account.span("smg.step.admit.dispatch"):
+            base, _tail = self._chunk_args(host)
+            args = base + self._state_args(np.int32(state_slot)) + self._solo_sampling_args(
+                temperature, top_k, top_p, min_p, pen, mask)
+            tok, lp, self.k_cache, self.v_cache, self.s_pool, self.c_pool = fn(*args)
+        return self._fetch_solo(tok, lp)
 
     def prefill_extend(self, token_ids, prefix_len, page_table, lora_idx=0, mm=None,
                        rope_pos=None, state_slot: int = 0) -> None:
@@ -331,15 +325,17 @@ class RecurrentModelRunner(ModelRunner):
         the ``prefill`` program (one program a bucket instead of two) with the
         unfolded key, so the key counter stands still, and fetches nothing:
         the token it samples from one row of logits is dropped on the device."""
-        T, mp, base, use_lora, use_ring, _tail = self._prefill_chunk_prep(
+        T, mp, use_lora, use_ring, host = self._prefill_chunk_prep(
             token_ids, prefix_len, page_table, lora_idx, mm, rope_pos)
         fn = self._prefill_fn(T, mp, use_lora=use_lora, use_ring=use_ring,
                               use_embeds=mm is not None, use_mrope=rope_pos is not None)
         up = self.upload
-        _tok, _lp, self.k_cache, self.v_cache, self.s_pool, self.c_pool = fn(
-            *base, *self._state_args(np.int32(state_slot)), self._rng_key,
-            up([0.0], jnp.float32), up([-1], jnp.int32), up([1.0], jnp.float32),
-            up([0.0], jnp.float32))
+        with self.account.span("smg.step.admit.dispatch"):
+            base, _tail = self._chunk_args(host)
+            _tok, _lp, self.k_cache, self.v_cache, self.s_pool, self.c_pool = fn(
+                *base, *self._state_args(np.int32(state_slot)), self._rng_key,
+                up([0.0], jnp.float32), up([-1], jnp.int32), up([1.0], jnp.float32),
+                up([0.0], jnp.float32))
 
     def prefill_batched_async(self, chunks, temps, topks, topps, minps, pen=None,
                               mask=None, lora_idx=None, mm=None, rope=None,
@@ -369,38 +365,40 @@ class RecurrentModelRunner(ModelRunner):
                     state_slots=None if state_slots is None else state_slots[part])
                 parts.append((r + lo, t, l))
             return parts
-        G = 1
-        while G < g_real:
-            G *= 2
-        mp = len(chunks[0][2])
-        tokens = np.zeros((G, T), np.int32)
-        prefix_lens, t_reals = np.zeros(G, np.int32), np.zeros(G, np.int32)
-        page_tables = np.zeros((G, mp), np.int32)
-        slots = np.zeros(G, np.int32)
-        for i, (ids, pfx, row) in enumerate(chunks):
-            tokens[i, : len(ids)] = ids
-            prefix_lens[i], t_reals[i], page_tables[i] = pfx, len(ids), row
-        if state_slots is not None:
-            slots[:g_real] = state_slots
-        fn = self._prefill_batched_fn(G, T, mp, all(c[1] == 0 for c in chunks),
-                                      use_pen=pen is not None, use_mask=mask is not None)
-        up = self.upload
-        args = [self.params, self.inv_freq, up(tokens), up(prefix_lens), up(t_reals),
-                self.k_cache, self.v_cache, up(page_tables), *self._state_args(slots),
-                self._next_key(),
-                up(_pad_vec(np.asarray(temps, np.float32), G, 0.0)),
-                up(_pad_vec(np.asarray(topks, np.int32), G, -1)),
-                up(_pad_vec(np.asarray(topps, np.float32), G, 1.0)),
-                up(_pad_vec(np.asarray(minps, np.float32), G, 0.0))]
-        if pen is not None:
-            counts, pmask, freqs, pres, reps = pen
-            args += [up(_pad_rows(counts, G).astype(np.int32)), up(_pad_rows(pmask, G)),
-                     up(_pad_vec(freqs, G, 0.0), jnp.float32),
-                     up(_pad_vec(pres, G, 0.0), jnp.float32),
-                     up(_pad_vec(reps, G, 1.0), jnp.float32)]
-        if mask is not None:
-            args.append(up(_pad_rows(mask, G, fill=True)))
-        toks, lps, self.k_cache, self.v_cache, self.s_pool, self.c_pool = fn(*args)
+        with self.account.span("smg.step.admit.pack"):
+            G = 1
+            while G < g_real:
+                G *= 2
+            mp = len(chunks[0][2])
+            tokens = np.zeros((G, T), np.int32)
+            prefix_lens, t_reals = np.zeros(G, np.int32), np.zeros(G, np.int32)
+            page_tables = np.zeros((G, mp), np.int32)
+            slots = np.zeros(G, np.int32)
+            for i, (ids, pfx, row) in enumerate(chunks):
+                tokens[i, : len(ids)] = ids
+                prefix_lens[i], t_reals[i], page_tables[i] = pfx, len(ids), row
+            if state_slots is not None:
+                slots[:g_real] = state_slots
+            fn = self._prefill_batched_fn(G, T, mp, all(c[1] == 0 for c in chunks),
+                                          use_pen=pen is not None, use_mask=mask is not None)
+        with self.account.span("smg.step.admit.dispatch"):
+            up = self.upload
+            args = [self.params, self.inv_freq, up(tokens), up(prefix_lens), up(t_reals),
+                    self.k_cache, self.v_cache, up(page_tables), *self._state_args(slots),
+                    self._next_key(),
+                    up(_pad_vec(np.asarray(temps, np.float32), G, 0.0)),
+                    up(_pad_vec(np.asarray(topks, np.int32), G, -1)),
+                    up(_pad_vec(np.asarray(topps, np.float32), G, 1.0)),
+                    up(_pad_vec(np.asarray(minps, np.float32), G, 0.0))]
+            if pen is not None:
+                counts, pmask, freqs, pres, reps = pen
+                args += [up(_pad_rows(counts, G).astype(np.int32)), up(_pad_rows(pmask, G)),
+                         up(_pad_vec(freqs, G, 0.0), jnp.float32),
+                         up(_pad_vec(pres, G, 0.0), jnp.float32),
+                         up(_pad_vec(reps, G, 1.0), jnp.float32)]
+            if mask is not None:
+                args.append(up(_pad_rows(mask, G, fill=True)))
+            toks, lps, self.k_cache, self.v_cache, self.s_pool, self.c_pool = fn(*args)
         return [(np.arange(g_real), toks, lps)]
 
     def decode_multi_async(self, tokens, positions, page_tables, temps, topks, topps,

@@ -28,6 +28,7 @@ from smg_tpu.engine.kv_cache import KvCacheSpec, create_kv_buffers, plan_cache
 from smg_tpu.engine.sampling import apply_penalties
 from smg_tpu.engine.sampling import sample_tokens as _sample_fast
 from smg_tpu.engine.sampling import sample_tokens_exact as _sample_exact
+from smg_tpu.engine.spans import StepAccount
 from smg_tpu.models.registry import get_model
 from smg_tpu.ops.attention import land_side_buffers
 from smg_tpu.ops.rope import rope_frequencies
@@ -167,6 +168,10 @@ class ModelRunner:
         self.config = config
         self.model_cfg = config.model
         self.module = get_model(self.model_cfg.arch)
+        # the prefill entry points mark ``smg.step.admit.pack`` and
+        # ``smg.step.admit.dispatch`` on this account; a scheduler puts its
+        # own here, a runner driven alone keeps one nobody reads
+        self.account = StepAccount()
         # serving pp: the layer axis of the param stack AND the KV cache
         # shard over "pp" (parallel/pp_serving.py); each stage holds L/S
         # layers — the capacity path for models that don't fit TP-only
@@ -965,94 +970,96 @@ class ModelRunner:
         entry for each part.  ``fetch_first_tokens`` brings them to the
         host; ``chain_first_tokens`` hands them to a decode launch on the
         device."""
-        g_real = len(chunks)
-        G = 1
-        while G < g_real:
-            G *= 2
-        t_max = max(len(c[0]) for c in chunks)
-        T = self.config.scheduler.prefill_bucket(t_max)
-        mp = len(chunks[0][2])
-        V = self.model_cfg.vocab_size
-        tokens = np.zeros((G, T), np.int32)
-        prefix_lens = np.zeros(G, np.int32)
-        t_reals = np.zeros(G, np.int32)
-        page_tables = np.zeros((G, mp), np.int32)
-        ftemps = np.zeros(G, np.float32)
-        ftopks = np.full(G, -1, np.int32)
-        ftopps = np.ones(G, np.float32)
-        fminps = np.zeros(G, np.float32)
-        for i, (ids, pfx, row) in enumerate(chunks):
-            tokens[i, : len(ids)] = ids
-            prefix_lens[i] = pfx
-            t_reals[i] = len(ids)
-            page_tables[i] = row
-            ftemps[i] = temps[i]
-            ftopks[i] = topks[i]
-            ftopps[i] = topps[i]
-            fminps[i] = minps[i]
-        no_ctx = all(c[1] == 0 for c in chunks)
-        use_lora = lora_idx is not None and self._lora_bank is not None
-        use_embeds = mm is not None and any(m is not None for m in mm)
-        use_mrope = rope is not None and any(r is not None for r in rope)
-        fn = self._prefill_batched_fn(G, T, mp, no_ctx,
-                                      use_pen=pen is not None,
-                                      use_mask=mask is not None,
-                                      use_lora=use_lora,
-                                      use_embeds=use_embeds,
-                                      use_mrope=use_mrope)
-        up = self.upload
-        args = [
-            self.params,
-            self.inv_freq,
-            up(tokens),
-            up(prefix_lens),
-            up(t_reals),
-            self.k_cache,
-            self.v_cache,
-            up(page_tables),
-            self._next_key(),
-            up(ftemps),
-            up(ftopks),
-            up(ftopps),
-            up(fminps),
-        ]
-        if pen is not None:
-            counts, pmask, freqs, pres, reps = pen
-            args += [
-                up(_pad_rows(counts, G).astype(np.int32)),
-                up(_pad_rows(pmask, G)),
-                up(_pad_vec(freqs, G, 0.0), jnp.float32),
-                up(_pad_vec(pres, G, 0.0), jnp.float32),
-                up(_pad_vec(reps, G, 1.0), jnp.float32),
+        with self.account.span("smg.step.admit.pack"):
+            g_real = len(chunks)
+            G = 1
+            while G < g_real:
+                G *= 2
+            t_max = max(len(c[0]) for c in chunks)
+            T = self.config.scheduler.prefill_bucket(t_max)
+            mp = len(chunks[0][2])
+            V = self.model_cfg.vocab_size
+            tokens = np.zeros((G, T), np.int32)
+            prefix_lens = np.zeros(G, np.int32)
+            t_reals = np.zeros(G, np.int32)
+            page_tables = np.zeros((G, mp), np.int32)
+            ftemps = np.zeros(G, np.float32)
+            ftopks = np.full(G, -1, np.int32)
+            ftopps = np.ones(G, np.float32)
+            fminps = np.zeros(G, np.float32)
+            for i, (ids, pfx, row) in enumerate(chunks):
+                tokens[i, : len(ids)] = ids
+                prefix_lens[i] = pfx
+                t_reals[i] = len(ids)
+                page_tables[i] = row
+                ftemps[i] = temps[i]
+                ftopks[i] = topks[i]
+                ftopps[i] = topps[i]
+                fminps[i] = minps[i]
+            no_ctx = all(c[1] == 0 for c in chunks)
+            use_lora = lora_idx is not None and self._lora_bank is not None
+            use_embeds = mm is not None and any(m is not None for m in mm)
+            use_mrope = rope is not None and any(r is not None for r in rope)
+            fn = self._prefill_batched_fn(G, T, mp, no_ctx,
+                                          use_pen=pen is not None,
+                                          use_mask=mask is not None,
+                                          use_lora=use_lora,
+                                          use_embeds=use_embeds,
+                                          use_mrope=use_mrope)
+        with self.account.span("smg.step.admit.dispatch"):
+            up = self.upload
+            args = [
+                self.params,
+                self.inv_freq,
+                up(tokens),
+                up(prefix_lens),
+                up(t_reals),
+                self.k_cache,
+                self.v_cache,
+                up(page_tables),
+                self._next_key(),
+                up(ftemps),
+                up(ftopks),
+                up(ftopps),
+                up(fminps),
             ]
-        if mask is not None:
-            args.append(up(_pad_rows(mask, G, fill=True)))
-        if use_lora:
-            args += [
-                self._lora_bank,
-                up(_pad_vec(np.asarray(lora_idx, np.int32), G, 0)),
-            ]
-        if use_embeds:
-            E = next(m[0].shape[1] for m in mm if m is not None)
-            dense = np.zeros((G, T, E), np.float32)
-            emask = np.zeros((G, T), bool)
-            for i, m in enumerate(mm):
-                if m is not None:
-                    d, bm = m
-                    dense[i, : d.shape[0]] = d
-                    emask[i, : bm.shape[0]] = bm
-            args += [up(dense), up(emask)]
-        if use_mrope:
-            # default rows: all three axes = sequential position, which makes
-            # apply_mrope EXACTLY apply_rope for the text rows in the group
-            rp = np.broadcast_to(
-                (prefix_lens[:, None] + np.arange(T))[:, None, :], (G, 3, T)
-            ).astype(np.int32).copy()
-            for i, r in enumerate(rope):
-                if r is not None:
-                    rp[i, :, : r.shape[1]] = r
-            args.append(up(rp))
-        toks, lps, self.k_cache, self.v_cache = fn(*args)
+            if pen is not None:
+                counts, pmask, freqs, pres, reps = pen
+                args += [
+                    up(_pad_rows(counts, G).astype(np.int32)),
+                    up(_pad_rows(pmask, G)),
+                    up(_pad_vec(freqs, G, 0.0), jnp.float32),
+                    up(_pad_vec(pres, G, 0.0), jnp.float32),
+                    up(_pad_vec(reps, G, 1.0), jnp.float32),
+                ]
+            if mask is not None:
+                args.append(up(_pad_rows(mask, G, fill=True)))
+            if use_lora:
+                args += [
+                    self._lora_bank,
+                    up(_pad_vec(np.asarray(lora_idx, np.int32), G, 0)),
+                ]
+            if use_embeds:
+                E = next(m[0].shape[1] for m in mm if m is not None)
+                dense = np.zeros((G, T, E), np.float32)
+                emask = np.zeros((G, T), bool)
+                for i, m in enumerate(mm):
+                    if m is not None:
+                        d, bm = m
+                        dense[i, : d.shape[0]] = d
+                        emask[i, : bm.shape[0]] = bm
+                args += [up(dense), up(emask)]
+            if use_mrope:
+                # default rows: all three axes = sequential position, which makes
+                # apply_mrope EXACTLY apply_rope for the text rows in the group
+                rp = np.broadcast_to(
+                    (prefix_lens[:, None] + np.arange(T))[:, None, :], (G, 3, T)
+                ).astype(np.int32).copy()
+                for i, r in enumerate(rope):
+                    if r is not None:
+                        rp[i, :, : r.shape[1]] = r
+                args.append(up(rp))
+            toks, lps, self.k_cache, self.v_cache = fn(*args)
         return [(np.arange(g_real), toks, lps)]
 
     def chain_first_tokens(self, tokens: np.ndarray, owner: np.ndarray,
@@ -1541,7 +1548,10 @@ class ModelRunner:
     ):
         """Shared host-side packing/validation for one prefill chunk — the
         invariants the sampling (``prefill``) and KV-only
-        (``prefill_extend``) entry points must never diverge on.
+        (``prefill_extend``) entry points must never diverge on — inside
+        the span ``smg.step.admit.pack``.  Nothing is uploaded here:
+        ``_chunk_args`` does that, inside the caller's
+        ``smg.step.admit.dispatch``.
 
         - Bucket padding: chunk padded to the prefill token bucket.
         - Scheduler invariant the Pallas prefill kernel relies on: every
@@ -1552,59 +1562,72 @@ class ModelRunner:
         - Sequence-parallel prefill: cold chunks (the long-context case — a
           huge first chunk is exactly what sp exists for) ring-attend with
           the token dim sharded over sp; warm chunks need the cache gather.
-        Returns (T, mp, base_args, use_lora, use_ring, tail_args) where
-        ``base_args`` is the common [params..page_table] prefix and
-        ``tail_args`` the lora/mm/rope suffix in extra-arg order."""
-        t = len(token_ids)
-        T = self._chunk_bucket(t)
-        tokens = np.zeros(T, np.int32)
-        tokens[:t] = token_ids
-        mp = len(page_table)
-        ps = self.config.cache.page_size
-        if prefix_len + t > mp * ps:
-            raise ValueError(
-                f"prefill chunk overruns page table: prefix {prefix_len} + "
-                f"chunk {t} > {mp} pages * {ps}"
+        Returns (T, mp, use_lora, use_ring, host) where ``host`` is what
+        ``_chunk_args`` uploads."""
+        with self.account.span("smg.step.admit.pack"):
+            t = len(token_ids)
+            T = self._chunk_bucket(t)
+            tokens = np.zeros(T, np.int32)
+            tokens[:t] = token_ids
+            mp = len(page_table)
+            ps = self.config.cache.page_size
+            if prefix_len + t > mp * ps:
+                raise ValueError(
+                    f"prefill chunk overruns page table: prefix {prefix_len} + "
+                    f"chunk {t} > {mp} pages * {ps}"
+                )
+            use_lora = lora_idx > 0 and self._lora_bank is not None
+            sp = self.config.parallel.sp
+            use_ring = (
+                self.mesh is not None and sp > 1 and prefix_len == 0 and T % sp == 0
+                and not self.use_pp  # ring + pp composition is future work
             )
-        use_lora = lora_idx > 0 and self._lora_bank is not None
-        sp = self.config.parallel.sp
-        use_ring = (
-            self.mesh is not None and sp > 1 and prefix_len == 0 and T % sp == 0
-            and not self.use_pp  # ring + pp composition is future work
-        )
-        if rope_pos is not None and use_ring:
-            raise ValueError("M-RoPE does not compose with ring prefill yet")
-        up = self.upload  # mesh-replicated commit under tp>1; jnp.asarray else
-        base_args = [
-            self.params,
-            self.inv_freq,
-            up(tokens),
-            up(prefix_len, jnp.int32),
-            up(t, jnp.int32),
-            self.k_cache,
-            self.v_cache,
+            if rope_pos is not None and use_ring:
+                raise ValueError("M-RoPE does not compose with ring prefill yet")
             # a COPY of the row: the caller hands a view of the scheduler's
             # table, the CPU client may alias host memory instead of copying
             # it, and ``prefill_extend`` returns before the program has run;
             # a preemption that zeroes the row in the same step would send
             # the chunk's KV to the garbage page
-            up(np.array(page_table, np.int32)),
+            host = {"tokens": tokens, "prefix_len": prefix_len, "t": t,
+                    "page_table": np.array(page_table, np.int32),
+                    "lora_idx": lora_idx if use_lora else None}
+            if mm is not None:
+                embeds, emask = mm
+                pe = np.zeros((T, embeds.shape[1]), np.float32)
+                pe[:t] = embeds
+                pm = np.zeros(T, bool)
+                pm[:t] = emask
+                host["mm"] = (pe, pm)
+            if rope_pos is not None:
+                rp = np.zeros((3, T), np.int32)
+                rp[:, :t] = rope_pos
+                host["rope"] = rp
+        return T, mp, use_lora, use_ring, host
+
+    def _chunk_args(self, host: dict) -> tuple[list, list]:
+        """Upload what ``_prefill_chunk_prep`` packed: ``(base_args,
+        tail_args)``, the common [params..page_table] prefix of a chunk
+        program's arguments and the lora/mm/rope suffix in extra-arg order."""
+        up = self.upload  # mesh-replicated commit under tp>1; jnp.asarray else
+        base_args = [
+            self.params,
+            self.inv_freq,
+            up(host["tokens"]),
+            up(host["prefix_len"], jnp.int32),
+            up(host["t"], jnp.int32),
+            self.k_cache,
+            self.v_cache,
+            up(host["page_table"]),
         ]
         tail_args = []
-        if use_lora:
-            tail_args += [self._lora_bank, up(lora_idx, jnp.int32)]
-        if mm is not None:
-            embeds, emask = mm
-            pe = np.zeros((T, embeds.shape[1]), np.float32)
-            pe[:t] = embeds
-            pm = np.zeros(T, bool)
-            pm[:t] = emask
-            tail_args += [up(pe), up(pm)]
-        if rope_pos is not None:
-            rp = np.zeros((3, T), np.int32)
-            rp[:, :t] = rope_pos
-            tail_args.append(up(rp))
-        return T, mp, base_args, use_lora, use_ring, tail_args
+        if host["lora_idx"] is not None:
+            tail_args += [self._lora_bank, up(host["lora_idx"], jnp.int32)]
+        if "mm" in host:
+            tail_args += [up(x) for x in host["mm"]]
+        if "rope" in host:
+            tail_args.append(up(host["rope"]))
+        return base_args, tail_args
 
     def prefill(
         self,
@@ -1622,16 +1645,25 @@ class ModelRunner:
         rope_pos: "np.ndarray | None" = None,  # [3, t] M-RoPE position ids
     ) -> tuple[int, float]:
         """Run one prefill chunk; returns (sampled_token, logprob)."""
-        T, mp, base_args, use_lora, use_ring, tail_args = \
-            self._prefill_chunk_prep(
-                token_ids, prefix_len, page_table, lora_idx, mm, rope_pos
-            )
+        T, mp, use_lora, use_ring, host = self._prefill_chunk_prep(
+            token_ids, prefix_len, page_table, lora_idx, mm, rope_pos
+        )
         fn = self._prefill_fn(T, mp, use_pen=pen is not None,
                               use_mask=mask is not None, use_lora=use_lora,
                               use_ring=use_ring, use_embeds=mm is not None,
                               use_mrope=rope_pos is not None)
+        with self.account.span("smg.step.admit.dispatch"):
+            base_args, tail_args = self._chunk_args(host)
+            args = base_args + self._solo_sampling_args(
+                temperature, top_k, top_p, min_p, pen, mask) + tail_args
+            tok, lp, self.k_cache, self.v_cache = fn(*args)
+        return self._fetch_solo(tok, lp)
+
+    def _solo_sampling_args(self, temperature, top_k, top_p, min_p, pen, mask) -> list:
+        """A solo sampling prefill's arguments behind the page table: the
+        folded key, the four sampling scalars, then ``pen`` and ``mask``."""
         up = self.upload
-        args = base_args + [
+        args = [
             self._next_key(),
             up([temperature], jnp.float32),
             up([top_k], jnp.int32),
@@ -1649,9 +1681,14 @@ class ModelRunner:
             ]
         if mask is not None:
             args.append(up(mask)[None])
-        args += tail_args
-        tok, lp, self.k_cache, self.v_cache = fn(*args)
-        return int(tok), float(lp)
+        return args
+
+    def _fetch_solo(self, tok, lp) -> tuple[int, float]:
+        """The blocking fetch of a solo prefill's token: it proves the
+        newest launch done (``spans.StepAccount``)."""
+        out = int(tok), float(lp)
+        self.account.fetched(self.account.launched)
+        return out
 
     def prefill_extend(
         self,
@@ -1667,15 +1704,16 @@ class ModelRunner:
         The budgeted scheduler advances a ``PREFILLING`` request's cursor
         with this between steps; the FINAL chunk goes through ``prefill``,
         which samples the first token."""
-        T, mp, base_args, use_lora, use_ring, tail_args = \
-            self._prefill_chunk_prep(
-                token_ids, prefix_len, page_table, lora_idx, mm, rope_pos
-            )
+        T, mp, use_lora, use_ring, host = self._prefill_chunk_prep(
+            token_ids, prefix_len, page_table, lora_idx, mm, rope_pos
+        )
         fn = self._prefill_extend_fn(T, mp, use_lora=use_lora,
                                      use_ring=use_ring,
                                      use_embeds=mm is not None,
                                      use_mrope=rope_pos is not None)
-        self.k_cache, self.v_cache = fn(*(base_args + tail_args))
+        with self.account.span("smg.step.admit.dispatch"):
+            base_args, tail_args = self._chunk_args(host)
+            self.k_cache, self.v_cache = fn(*(base_args + tail_args))
 
     def warmup(self) -> list[tuple[str, float]]:
         """Compile and run, once each, the largest solo-prefill,

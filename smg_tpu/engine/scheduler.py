@@ -59,7 +59,11 @@ import numpy as np
 from jax import lax
 
 from smg_tpu.engine.config import EngineConfig
-from smg_tpu.engine.flight_recorder import HORIZON_REASONS, PREFILL_SYNC_REASONS
+from smg_tpu.engine.flight_recorder import (
+    HORIZON_REASONS,
+    PREFILL_SYNC_REASONS,
+    SLOW_STEP_S,
+)
 from smg_tpu.engine.kv_cache import PagePool, StateSlotPool
 from smg_tpu.engine.radix_cache import RadixCache
 from smg_tpu.engine.request import (
@@ -70,7 +74,7 @@ from smg_tpu.engine.request import (
     StepOutput,
 )
 from smg_tpu.engine.runner import DecodeState, ModelRunner
-from smg_tpu.engine.spans import spanned
+from smg_tpu.engine.spans import StepAccount, spanned
 from smg_tpu.faults import FAULTS
 from smg_tpu.utils import get_logger
 
@@ -130,6 +134,9 @@ class InFlightFrame:
     ran: bool = True
     # routed experts: the frame's counts (``LatentModelRunner.frame_counts``)
     routed: "object" = None  # jax.Array int32 [4]
+    # the launch's serial in the step account: fetching this frame proves
+    # every launch up to it done (``spans.StepAccount``)
+    serial: int = 0
 
 
 def _launch_attrs(frame: "InFlightFrame") -> dict:
@@ -279,15 +286,10 @@ class Scheduler:
                 ),
             )
             self.flight.metrics = metrics
-        # sharded-dispatch accounting: host seconds spent ENQUEUEING device
-        # launches (async dispatch of the sharded/single-device programs)
-        # vs BLOCKED on the deferred fetch — the split that shows whether a
-        # mesh's extra dispatch work (sharded arg binding, per-device
-        # buffers) is eating the megastep's host-amortization win.  Step-
-        # scoped for the ring + metrics, cumulative for benches.
-        self._step_dispatch_s = 0.0
-        self.dispatch_enqueue_s_total = 0.0
-        self.fetch_wait_s_total = 0.0
+        # the step account (engine/spans.py): where the step thread's
+        # seconds go, by the spans that mark them, and when the chip had
+        # nothing queued.  The runner's prefill spans feed the same one
+        self.account = runner.account = StepAccount()
         # mesh device count riding every flight-ring record (1 = single
         # -device): postmortems from a mixed fleet self-describe their
         # topology; runner.mesh_devices is the single source
@@ -296,7 +298,6 @@ class Scheduler:
         self._step_fault_phases: list[str] = []
         self._step_admissions = 0
         self._step_outcome: str | None = None
-        self._step_fetch_s = 0.0
         # dump reasons raised mid-step (quarantine, health flip): fired AFTER
         # the step's own ring record lands, so the dump contains the failing
         # step rather than ending one short of it
@@ -389,13 +390,6 @@ class Scheduler:
             or self.inflight is not None
         )
 
-    def _note_dispatch(self, seconds: float) -> None:
-        """Account one async device-launch enqueue (megastep, chained
-        lookahead, or spec verify block): step-scoped for the flight ring /
-        metrics split, cumulative for the tp-scaling bench."""
-        self._step_dispatch_s += seconds
-        self.dispatch_enqueue_s_total += seconds
-
     def prefill_inflight_tokens(self) -> int:
         """Un-prefilled prompt tokens of admitted, in-progress (resumable)
         prefills — the slot-holding half of the prefill backlog."""
@@ -479,15 +473,19 @@ class Scheduler:
             "deadline_expirations_running": self.num_deadline_running,
             "draining": self.draining,
             # sharded runner mode: mesh topology (devices / per-axis shape /
-            # platform / donation verdict) + the dispatch-vs-fetch host-time
-            # split, so operators can see a TP worker's sharding from
-            # /scheduler without reaching into the runner
+            # platform / donation verdict), so operators can see a TP
+            # worker's sharding from /scheduler without reaching into the runner
             "mesh": self.runner.mesh_info(),
             # which attention implementation the dispatch rule resolved to,
             # and how many launches each one has had
             "attention": self.runner.attention_info(),
-            "dispatch_enqueue_seconds": self.dispatch_enqueue_s_total,
-            "fetch_wait_seconds": self.fetch_wait_s_total,
+            # the step account's sums: seconds by phase (with ``gap`` and
+            # the whole ``step``) and the seconds the chip had nothing queued
+            "step_phases": self.account.sums(),
+            # the last steps that took over a second with the gap before
+            # them, whole: where a stalled window is read
+            "slow_steps": (self.flight.slow_steps() if self.flight is not None
+                           else {"threshold_s": SLOW_STEP_S, "count": 0, "steps": []}),
         }
         if self._window_slots:
             spec, info = self.runner.spec, self.runner.window_info()
@@ -619,8 +617,6 @@ class Scheduler:
         self._step_fault_phases = []
         self._step_admissions = 0
         self._step_outcome = None
-        self._step_fetch_s = 0.0
-        self._step_dispatch_s = 0.0
         self._step_horizon = 0
         self._step_horizon_reason = ""
         self._step_spec_drafted = 0
@@ -630,7 +626,7 @@ class Scheduler:
         self._step_moe = None
         pf0, dc0 = self.num_prefill_tokens, self.num_decode_tokens
         we0, ee0 = self.num_wasted_decode_tokens, self.num_megastep_early_exits
-        t0 = time.perf_counter()
+        self.account.begin_step()
         escaped = True  # exception past recovery -> engine loop (phase=loop)
         try:
             try:
@@ -646,12 +642,15 @@ class Scheduler:
                     self.consec_step_failures = 0
             escaped = False
         finally:
+            phases = self.account.end_step(self.has_work())
+            if self.metrics is not None:
+                self.metrics.observe_phases(self.account)
             if fl is not None:
                 # the ring record lands even for a step whose exception is
                 # escaping to the engine loop — a postmortem that omits the
                 # failing step is useless
                 fl.record_step(
-                    step_s=time.perf_counter() - t0,
+                    step_s=phases.pop("step_s"),
                     prefill_tokens=self.num_prefill_tokens - pf0,
                     decode_tokens=self.num_decode_tokens - dc0,
                     running=sum(1 for s in self.slots if s is not None),
@@ -662,7 +661,7 @@ class Scheduler:
                     admissions=self._step_admissions,
                     finishes=sum(1 for o in outputs if o.finished),
                     overlap=self._step_outcome,
-                    fetch_wait_s=self._step_fetch_s,
+                    fetch_wait_s=phases.pop("fetch_wait_s"),
                     faults=self._step_fault_phases + (["loop"] if escaped else []),
                     horizon=self._step_horizon,
                     early_exits=self.num_megastep_early_exits - ee0,
@@ -674,6 +673,7 @@ class Scheduler:
                     state_lanes=self._step_state_lanes,
                     columns_run=self._step_columns_run,
                     moe=self._step_moe,
+                    phases=phases,
                 )
                 self.flush_pending_dumps()
         return outputs
@@ -702,30 +702,22 @@ class Scheduler:
         spec_mode = self.sched.speculative or self.draft is not None
         overlap = self.sched.overlap_schedule and not spec_mode
         if overlap:
-            admit_s, fetch_s, outcome = self._step_overlap(outputs)
-            # stash for the step's flight-recorder ring record (+=: the
-            # accumulator is reset at the top of each step, and sub-phases
-            # like the spec rest-megastep may already have deposited fetch
-            # time — overwriting would undercount the dispatch split)
-            self._step_outcome = outcome
-            self._step_fetch_s += fetch_s
+            outcome = self._step_outcome = self._step_overlap(outputs)
         elif spec_mode and self.sched.overlap_schedule:
-            admit_s, fetch_s, outcome = self._step_spec(outputs)
-            self._step_outcome = outcome
-            self._step_fetch_s += fetch_s
+            outcome = self._step_outcome = self._step_spec(outputs)
         else:
             self.drop_inflight()  # mode flip mid-run: never strand a frame
             self._admit(outputs)
-            admit_s = (time.perf_counter() - t0) if m else 0.0
             self._decode(outputs)
-            fetch_s, outcome = 0.0, None
+            outcome = None
         if m is not None:
-            t2 = time.perf_counter()
-            step_s = t2 - t0
+            # the three schedules alike, from the step account: the fetch of
+            # the frame in flight is the decode side's, wherever it fell
+            spent = self.account.step
             m.observe_step(
-                step_s=step_s,
-                prefill_s=admit_s,
-                decode_s=step_s - admit_s,
+                step_s=time.perf_counter() - t0,
+                prefill_s=spent["admit"],
+                decode_s=spent["consume"] + spent["launch"],
                 prefill_tokens=self.num_prefill_tokens - pf0,
                 decode_tokens=self.num_decode_tokens - dc0,
                 running=sum(1 for s in self.slots if s is not None),
@@ -750,18 +742,8 @@ class Scheduler:
                 m.window_slots_total.set(self.state_pool.num_slots - 1)
                 m.window_slots_in_use.set(self.state_pool.in_use)
             if outcome is not None:
-                m.observe_overlap(
-                    outcome=outcome,
-                    fetch_wait_s=fetch_s,
-                    host_s=max(step_s - fetch_s, 0.0),
-                )
-            if self._step_dispatch_s or self._step_fetch_s:
-                # sharded-dispatch split: host time enqueueing the (mesh or
-                # single-device) programs vs blocked on the deferred fetch
-                m.observe_dispatch(
-                    enqueue_s=self._step_dispatch_s,
-                    fetch_s=self._step_fetch_s,
-                )
+                m.observe_overlap(outcome=outcome,
+                                  fetch_wait_s=spent["consume_fetch"])
 
     # ---- failure isolation (poison-step quarantine) ----
 
@@ -926,11 +908,10 @@ class Scheduler:
     # fetched, so the launch work hides behind the prefill as a lookahead's
     # hides behind a frame (``_launch_behind_prefill``).
 
-    def _step_overlap(self, outputs: list[StepOutput]) -> tuple[float, float, str]:
-        """One pipeline iteration; returns (admit_s, fetch_wait_s, outcome)."""
+    def _step_overlap(self, outputs: list[StepOutput]) -> str:
+        """One pipeline iteration; returns its outcome (OVERLAP_OUTCOMES)."""
         frame = self.inflight
         self.inflight = None
-        fetch_s = 0.0
         outcome = "sync"
         if frame is not None and self._frame_stale(frame):
             # the schedule changed while the frame was in flight (stop-string
@@ -964,7 +945,7 @@ class Scheduler:
             try:
                 if self._prefill_phase_fold_free():
                     look = self._launch_lookahead(frame)
-                fetch_s, used = self._consume_frame(frame, outputs)
+                used = self._consume_frame(frame, outputs)
                 if look is not None and self._frames_advance_state:
                     look.ran = bool(frame.clean)
             except Exception:
@@ -994,7 +975,6 @@ class Scheduler:
         # phase is fold-free by the predictor's guarantee; otherwise this
         # step's decode fold happens at the tail cold launch, after the
         # phase.)
-        ta = time.perf_counter()
         # with no lookahead in flight this step's decode launch comes after
         # the phase, and a grouped prefill may leave its first tokens on the
         # device for that launch to chain on (``_prefill_group``)
@@ -1007,7 +987,6 @@ class Scheduler:
             raise
         finally:
             self._chaining = False
-        admit_s = time.perf_counter() - ta
         pend, self._pending_group = self._pending_group, None
         if look is not None:
             if disturbed or self._frame_stale(look):
@@ -1029,7 +1008,7 @@ class Scheduler:
             active = self._decode_active()
             if active:
                 self.inflight = self._launch_frame(active)
-        return admit_s, fetch_s, outcome
+        return outcome
 
     def _launch_behind_prefill(
         self, pend: tuple, outputs: list[StepOutput]
@@ -1282,9 +1261,9 @@ class Scheduler:
     @spanned("smg.step.consume")
     def _consume_frame(
         self, frame: InFlightFrame, outputs: list[StepOutput]
-    ) -> tuple[float, int]:
-        """Deferred fetch + host-side acceptance; returns (seconds blocked on
-        the device, columns accepted).  ``jax.device_get`` is the EXPLICIT
+    ) -> int:
+        """Deferred fetch + host-side acceptance; returns the columns
+        accepted.  ``jax.device_get`` is the EXPLICIT
         materialization of the async results — the one intended device→host
         sync per steady-state step, and the form the transfer guard permits.
 
@@ -1299,15 +1278,13 @@ class Scheduler:
             "engine.device_fetch",
             rids=",".join(r.rid for _s, r, _e in frame.lanes),
         )
-        t0 = time.perf_counter()
-        toks, lps, steps_run, clean, routed = jax.device_get(
-            (frame.toks, frame.lps, frame.steps_run, frame.clean, frame.routed)
-        )
+        with self.account.span("smg.step.consume.fetch", proves=frame.serial):
+            toks, lps, steps_run, clean, routed = jax.device_get(
+                (frame.toks, frame.lps, frame.steps_run, frame.clean, frame.routed)
+            )
         # recurrent models: what a frame chained on this one did (ran, or ran
         # no column because this one met a finish)
         frame.clean = clean
-        fetch_s = time.perf_counter() - t0
-        self.fetch_wait_s_total += fetch_s
         if frame.lookahead:
             self.num_lookahead_kept += 1
         sr = int(steps_run) if steps_run is not None else frame.horizon
@@ -1358,7 +1335,7 @@ class Scheduler:
                 else 0.7 * self._finish_gap_ema + 0.3 * gap
             )
             self._cols_since_finish = 0
-        return fetch_s, used
+        return used
 
     @spanned("smg.step.launch", _launch_attrs)
     def _launch_lookahead(self, frame: InFlightFrame) -> InFlightFrame | None:
@@ -1428,28 +1405,29 @@ class Scheduler:
             frame.use_pen, frame.use_lora, frame.use_mrope, frame.lane_sig,
         )
         mark = self.runner.rng_mark()
-        t_dispatch = time.perf_counter()
-        # the chained input column comes off the in-flight frame with a
-        # STATIC lax slice: `frame.toks[:, -1]` would route the index through
-        # eager dispatch as a scalar operand — an implicit host→device
-        # transfer every launch, which the steady-state guard forbids
-        last_col = lax.index_in_dim(frame.toks, frame.horizon - 1, axis=1,
-                                    keepdims=False)
-        toks, lps, steps_run = self.runner.decode_multi_async(
-            last_col, positions, ds.page_tables,
-            ds.temps, ds.topks, ds.topps, ds.minps, H2,
-            max_steps=max_steps,
-            stop_state=(ds.stop_ids, ds.limits, ds.live)
-            if max_steps > 1 else None,
-            pen=(ds.slot_idx, ds.freqs, ds.pres, ds.reps)
-            if frame.use_pen else None,
-            lora_idx=ds.lora_idx if frame.use_lora else None,
-            rope_delta=ds.rope_delta if frame.use_mrope else None,
-            **self._state_kw(ds, chain=frame.clean),
-        )
-        self._note_dispatch(time.perf_counter() - t_dispatch)
+        with self.account.span("smg.step.launch.dispatch"):
+            # the chained input column comes off the in-flight frame with a
+            # STATIC lax slice: `frame.toks[:, -1]` would route the index
+            # through eager dispatch as a scalar operand — an implicit
+            # host→device transfer every launch, which the steady-state guard
+            # forbids
+            last_col = lax.index_in_dim(frame.toks, frame.horizon - 1, axis=1,
+                                        keepdims=False)
+            toks, lps, steps_run = self.runner.decode_multi_async(
+                last_col, positions, ds.page_tables,
+                ds.temps, ds.topks, ds.topps, ds.minps, H2,
+                max_steps=max_steps,
+                stop_state=(ds.stop_ids, ds.limits, ds.live)
+                if max_steps > 1 else None,
+                pen=(ds.slot_idx, ds.freqs, ds.pres, ds.reps)
+                if frame.use_pen else None,
+                lora_idx=ds.lora_idx if frame.use_lora else None,
+                rope_delta=ds.rope_delta if frame.use_mrope else None,
+                **self._state_kw(ds, chain=frame.clean),
+            )
         self._count_decode_launch()
         return InFlightFrame(
+            serial=self.account.launched,
             clean=getattr(self.runner, "frame_clean", None),
             routed=getattr(self.runner, "frame_counts", None),
             lanes=[(s, r, e + H) for s, r, e in frame.lanes],
@@ -1650,9 +1628,10 @@ class Scheduler:
         acceptance.  A launch that failed on the device surfaces here: the
         members go back to where admission left them and the caller retries
         them solo (counted again there, never double)."""
-        group, parts = pend
+        group, parts, serial = pend
         try:
             toks, lps = self.runner.fetch_first_tokens(parts, len(group))
+            self.account.fetched(serial)
         except Exception:
             for req in group:
                 self.num_prefill_tokens -= req.seq_len - req.cached_tokens
@@ -1829,14 +1808,17 @@ class Scheduler:
         this step."""
         FAULTS.fire("engine.prefill", rid=req.rid)
         start = req.prefill_pos
-        chunk = req.all_token_ids[start : start + take]
+        with self.account.span("smg.step.admit.pack"):
+            chunk = req.all_token_ids[start : start + take]
+            mm = self._mm_chunk(req, start, len(chunk))
+            rope_pos = self._mrope_chunk(req, start, len(chunk))
         self.runner.prefill_extend(
             chunk,
             prefix_len=start,
             page_table=self.page_tables[req.slot],
             lora_idx=req.lora_idx,
-            mm=self._mm_chunk(req, start, len(chunk)),
-            rope_pos=self._mrope_chunk(req, start, len(chunk)),
+            mm=mm,
+            rope_pos=rope_pos,
             **self._slot_kw(req),
         )
         self.num_prefill_tokens += len(chunk)
@@ -1858,16 +1840,14 @@ class Scheduler:
         if self._chaining:
             self._count_prefill_launch("solo")
         FAULTS.fire("engine.prefill", rid=req.rid)
-        prompt = req.all_token_ids
-        start = req.prefill_pos
-        chunk = prompt[start:]
         sp = req.sampling
-        pen = None
-        if sp.has_penalties:
-            counts, pmask = self._req_pen_state(req)
-            pen = (counts, pmask, sp.frequency_penalty, sp.presence_penalty,
-                   sp.repetition_penalty)
-        mask = self._mask_for(req) if req.token_filter is not None else None
+        with self.account.span("smg.step.admit.pack"):
+            prompt = req.all_token_ids
+            start = req.prefill_pos
+            chunk = prompt[start:]
+            pen, mask = self._solo_pen_and_mask(req)
+            mm = self._mm_chunk(req, start, len(chunk))
+            rope_pos = self._mrope_chunk(req, start, len(chunk))
         tok, lp = self.runner.prefill(
             chunk,
             prefix_len=start,
@@ -1879,8 +1859,8 @@ class Scheduler:
             pen=pen,
             mask=mask,
             lora_idx=req.lora_idx,
-            mm=self._mm_chunk(req, start, len(chunk)),
-            rope_pos=self._mrope_chunk(req, start, len(chunk)),
+            mm=mm,
+            rope_pos=rope_pos,
             **self._slot_kw(req),
         )
         self.num_prefill_tokens += len(chunk)
@@ -1913,6 +1893,18 @@ class Scheduler:
         """Host-side (counts [V], pmask [V]) snapshot for a prefill call."""
         return self.runner.penalty_state(req.prompt_ids, req.output_ids)
 
+    def _solo_pen_and_mask(self, req: EngineRequest) -> tuple:
+        """A solo sampling prefill's ``pen`` and ``mask`` (None where the
+        request has no penalties, no grammar)."""
+        sp = req.sampling
+        pen = None
+        if sp.has_penalties:
+            counts, pmask = self._req_pen_state(req)
+            pen = (counts, pmask, sp.frequency_penalty, sp.presence_penalty,
+                   sp.repetition_penalty)
+        mask = self._mask_for(req) if req.token_filter is not None else None
+        return pen, mask
+
     def _prefill_solo(
         self, req: EngineRequest, prompt: list[int], matched_tokens: int,
         outputs: list[StepOutput],
@@ -1925,17 +1917,14 @@ class Scheduler:
         row = self.page_tables[req.slot]
         start = matched_tokens
         sp = req.sampling
-        pen = None
-        if sp.has_penalties:
-            counts, pmask = self._req_pen_state(req)
-            pen = (counts, pmask, sp.frequency_penalty, sp.presence_penalty,
-                   sp.repetition_penalty)
-        mask = None
-        if req.token_filter is not None:
-            mask = self._mask_for(req)
+        with self.account.span("smg.step.admit.pack"):
+            pen, mask = self._solo_pen_and_mask(req)
         tok = lp = None
         while start < len(prompt):
-            chunk = prompt[start : start + self.sched.max_prefill_tokens]
+            with self.account.span("smg.step.admit.pack"):
+                chunk = prompt[start : start + self.sched.max_prefill_tokens]
+                mm = self._mm_chunk(req, start, len(chunk))
+                rope_pos = self._mrope_chunk(req, start, len(chunk))
             tok, lp = self.runner.prefill(
                 chunk,
                 prefix_len=start,
@@ -1947,8 +1936,8 @@ class Scheduler:
                 pen=pen,
                 mask=mask,
                 lora_idx=req.lora_idx,
-                mm=self._mm_chunk(req, start, len(chunk)),
-                rope_pos=self._mrope_chunk(req, start, len(chunk)),
+                mm=mm,
+                rope_pos=rope_pos,
                 **self._slot_kw(req),
             )
             self.num_prefill_tokens += len(chunk)
@@ -2060,43 +2049,44 @@ class Scheduler:
             # per-member seam BEFORE any bookkeeping mutates, so the guarded
             # caller's solo fallback sees a clean state for every member
             FAULTS.fire("engine.prefill", rid=req.rid)
-        chunks = []
-        g = len(group)
-        V = self.runner.model_cfg.vocab_size
-        temps = np.zeros(g, np.float32)
-        topks = np.full(g, -1, np.int32)
-        topps = np.ones(g, np.float32)
-        minps = np.zeros(g, np.float32)
-        use_pen = any(r.sampling.has_penalties for r in group)
-        use_mask = any(r.token_filter is not None for r in group)
-        counts = np.zeros((g, V), np.int32) if use_pen else None
-        pmask = np.zeros((g, V), bool) if use_pen else None
-        freqs = np.zeros(g, np.float32)
-        pres = np.zeros(g, np.float32)
-        reps = np.ones(g, np.float32)
-        mask_arr = np.ones((g, V), bool) if use_mask else None
-        use_lora = any(r.lora_idx for r in group)
-        lora_idx = np.array([r.lora_idx for r in group], np.int32) if use_lora else None
-        mm_rows: list = []
-        rope_rows: list = []
-        for i, req in enumerate(group):
-            prompt = req.all_token_ids
-            chunk = prompt[req.cached_tokens :]
-            chunks.append((chunk, req.cached_tokens, self.page_tables[req.slot]))
-            mm_rows.append(self._mm_chunk(req, req.cached_tokens, len(chunk)))
-            rope_rows.append(self._mrope_chunk(req, req.cached_tokens, len(chunk)))
-            sp = req.sampling
-            temps[i] = sp.temperature
-            topks[i] = sp.top_k
-            topps[i] = sp.top_p
-            minps[i] = sp.min_p
-            if use_pen and sp.has_penalties:
-                counts[i], pmask[i] = self._req_pen_state(req)
-                freqs[i] = sp.frequency_penalty
-                pres[i] = sp.presence_penalty
-                reps[i] = sp.repetition_penalty
-            if use_mask and req.token_filter is not None:
-                mask_arr[i] = self._mask_for(req)
+        with self.account.span("smg.step.admit.pack"):
+            chunks = []
+            g = len(group)
+            V = self.runner.model_cfg.vocab_size
+            temps = np.zeros(g, np.float32)
+            topks = np.full(g, -1, np.int32)
+            topps = np.ones(g, np.float32)
+            minps = np.zeros(g, np.float32)
+            use_pen = any(r.sampling.has_penalties for r in group)
+            use_mask = any(r.token_filter is not None for r in group)
+            counts = np.zeros((g, V), np.int32) if use_pen else None
+            pmask = np.zeros((g, V), bool) if use_pen else None
+            freqs = np.zeros(g, np.float32)
+            pres = np.zeros(g, np.float32)
+            reps = np.ones(g, np.float32)
+            mask_arr = np.ones((g, V), bool) if use_mask else None
+            use_lora = any(r.lora_idx for r in group)
+            lora_idx = np.array([r.lora_idx for r in group], np.int32) if use_lora else None
+            mm_rows: list = []
+            rope_rows: list = []
+            for i, req in enumerate(group):
+                prompt = req.all_token_ids
+                chunk = prompt[req.cached_tokens :]
+                chunks.append((chunk, req.cached_tokens, self.page_tables[req.slot]))
+                mm_rows.append(self._mm_chunk(req, req.cached_tokens, len(chunk)))
+                rope_rows.append(self._mrope_chunk(req, req.cached_tokens, len(chunk)))
+                sp = req.sampling
+                temps[i] = sp.temperature
+                topks[i] = sp.top_k
+                topps[i] = sp.top_p
+                minps[i] = sp.min_p
+                if use_pen and sp.has_penalties:
+                    counts[i], pmask[i] = self._req_pen_state(req)
+                    freqs[i] = sp.frequency_penalty
+                    pres[i] = sp.presence_penalty
+                    reps[i] = sp.repetition_penalty
+                if use_mask and req.token_filter is not None:
+                    mask_arr[i] = self._mask_for(req)
         parts = self.runner.prefill_batched_async(
             chunks, temps, topks, topps, minps,
             pen=(counts, pmask, freqs, pres, reps) if use_pen else None,
@@ -2119,7 +2109,7 @@ class Scheduler:
                     req.rid, "prefill_chunk", start=chunks[i][1],
                     n=len(chunks[i][0]), final=True, grouped=True,
                 )
-        pend = (group, parts)
+        pend = (group, parts, self.account.launched)
         if self._chaining:
             why = self._first_tokens_needed(group)
             if why is None:
@@ -2161,8 +2151,7 @@ class Scheduler:
         frame = self._launch_frame(active)
         if frame is not None:
             try:
-                _fetch_s, used = self._consume_frame(frame, outputs)
-                self._step_fetch_s += _fetch_s
+                used = self._consume_frame(frame, outputs)
             except Exception:
                 # stash so the quarantine handler's drop_inflight rewinds
                 # this frame's sampling-key folds before any retry refolds
@@ -2502,24 +2491,24 @@ class Scheduler:
             positions[idx] = mp_b * self.ps
 
         mark = self.runner.rng_mark()
-        t_dispatch = time.perf_counter()
-        if first is not None:
-            tokens = self.runner.chain_first_tokens(tokens, owner, first[1])
-        toks, lps, steps_run = self.runner.decode_multi_async(
-            tokens, positions, ds.page_tables,
-            ds.temps, ds.topks, ds.topps, ds.minps, horizon,
-            max_steps=max_steps,
-            stop_state=(ds.stop_ids, ds.limits, ds.live)
-            if max_steps > 1 else None,
-            pen=(ds.slot_idx, ds.freqs, ds.pres, ds.reps) if use_pen else None,
-            mask=mask_arr,
-            lora_idx=ds.lora_idx if use_lora else None,
-            rope_delta=ds.rope_delta if use_mrope else None,
-            **self._state_kw(ds),
-        )
-        self._note_dispatch(time.perf_counter() - t_dispatch)
+        with self.account.span("smg.step.launch.dispatch"):
+            if first is not None:
+                tokens = self.runner.chain_first_tokens(tokens, owner, first[1])
+            toks, lps, steps_run = self.runner.decode_multi_async(
+                tokens, positions, ds.page_tables,
+                ds.temps, ds.topks, ds.topps, ds.minps, horizon,
+                max_steps=max_steps,
+                stop_state=(ds.stop_ids, ds.limits, ds.live)
+                if max_steps > 1 else None,
+                pen=(ds.slot_idx, ds.freqs, ds.pres, ds.reps) if use_pen else None,
+                mask=mask_arr,
+                lora_idx=ds.lora_idx if use_lora else None,
+                rope_delta=ds.rope_delta if use_mrope else None,
+                **self._state_kw(ds),
+            )
         self._count_decode_launch()
         return InFlightFrame(
+            serial=self.account.launched,
             clean=getattr(self.runner, "frame_clean", None),
             routed=getattr(self.runner, "frame_counts", None),
             lanes=[(i, r, r.seq_len) for i, r in active],
@@ -2709,13 +2698,14 @@ class Scheduler:
             self.inflight = frame
         else:
             try:
-                self._step_fetch_s += self._consume_spec_frame(frame, outputs)
+                self._consume_spec_frame(frame, outputs)
             except Exception:
                 # stash: the quarantine handler's drop_inflight rewinds the
                 # launch fold before any retry refolds
                 self.inflight = frame
                 raise
 
+    @spanned("smg.step.launch", _launch_attrs)
     def _launch_spec_frame(
         self, drafting: list, drafts: dict, pipelined: bool
     ) -> InFlightFrame | None:
@@ -2783,14 +2773,14 @@ class Scheduler:
             # the garbage page, and the all-zero page-table row is inert
             positions[idx] = mp_b * self.ps
         mark = self.runner.rng_mark()
-        t_dispatch = time.perf_counter()
-        emitted, n_emit, lps = self.runner.decode_spec_async(
-            tokens, draft_n, positions, page_tables,
-            temps, topks, topps, minps,
-            rope_delta=rope_delta,
-        )
-        self._note_dispatch(time.perf_counter() - t_dispatch)
+        with self.account.span("smg.step.launch.dispatch"):
+            emitted, n_emit, lps = self.runner.decode_spec_async(
+                tokens, draft_n, positions, page_tables,
+                temps, topks, topps, minps,
+                rope_delta=rope_delta,
+            )
         return InFlightFrame(
+            serial=self.account.launched,
             lanes=[(s, r, r.seq_len) for s, r in lanes],
             toks=emitted, lps=lps, horizon=W, B=B, B_real=B_real,
             mp_b=mp_b, rng_mark=mark, lookahead=pipelined, folds=1,
@@ -2819,9 +2809,10 @@ class Scheduler:
                 return True
         return False
 
+    @spanned("smg.step.consume")
     def _consume_spec_frame(
         self, frame: InFlightFrame, outputs: list[StepOutput]
-    ) -> float:
+    ) -> None:
         """Deferred fetch + acceptance bookkeeping for one verify block.
         Unlike the megastep's batch-wide trim, acceptance is PER LANE: each
         lane's emitted run is its own accepted drafts + bonus/correction,
@@ -2833,12 +2824,10 @@ class Scheduler:
             "engine.device_fetch",
             rids=",".join(r.rid for _s, r, _e in frame.lanes),
         )
-        t0 = time.perf_counter()
-        toks, lps, n_emit = jax.device_get(
-            (frame.toks, frame.lps, frame.n_emit)
-        )
-        fetch_s = time.perf_counter() - t0
-        self.fetch_wait_s_total += fetch_s
+        with self.account.span("smg.step.consume.fetch", proves=frame.serial):
+            toks, lps, n_emit = jax.device_get(
+                (frame.toks, frame.lps, frame.n_emit)
+            )
         if frame.lookahead:
             self.num_lookahead_kept += 1
         m = self.metrics
@@ -2884,13 +2873,11 @@ class Scheduler:
                 req.draft_len = min(
                     _expected + 1 + accepted, _expected + drafted, req.seq_len
                 )
-        return fetch_s
 
     def _step_spec(
         self, outputs: list[StepOutput]
-    ) -> tuple[float, float, str | None]:
-        """One pipelined speculative iteration; returns (admit_s, fetch_s,
-        outcome).  Mirrors ``_step_overlap``'s shape: consume the in-flight
+    ) -> str | None:
+        """One pipelined speculative iteration; returns its outcome.  Mirrors ``_step_overlap``'s shape: consume the in-flight
         verify frame first (admission must see slots/pages its finishes
         freed), run the prefill phase, then the spec decode phase leaves the
         next verify block in flight.  Fold order — prefill, rest-megastep,
@@ -2898,7 +2885,6 @@ class Scheduler:
         are byte-identical to ``overlap_schedule off``."""
         frame = self.inflight
         self.inflight = None
-        fetch_s = 0.0
         outcome = None
         if frame is not None:
             if self._spec_frame_stale(frame):
@@ -2906,18 +2892,16 @@ class Scheduler:
                 outcome = "discarded" if frame.lookahead else None
             else:
                 try:
-                    fetch_s = self._consume_spec_frame(frame, outputs)
+                    self._consume_spec_frame(frame, outputs)
                 except Exception:
                     # stash so the step-level handler's drop_inflight rewinds
                     # the launch fold before the blame/retry refolds
                     self.inflight = frame
                     raise
                 outcome = "kept"
-        ta = time.perf_counter()
         self._admit(outputs)
-        admit_s = time.perf_counter() - ta
         self._spec_phase(outputs, pipelined=True)
-        return admit_s, fetch_s, outcome
+        return outcome
 
     def _new_spec_index(self, req: EngineRequest, cfg) -> "object":
         from smg_tpu.engine.speculative import NgramIndex

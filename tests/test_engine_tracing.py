@@ -1,8 +1,11 @@
 """The engine's own measurement (ISSUE 26): ``smg.*`` spans on the
 profiler's clock, the profiler's start and stop outside the engine lock, the
 ``submit_t`` stamp and the lock-wait counters, ``horizon_reason`` on the step
-ring and its counter, and ``loads()["programs"]`` counting unarmed.  CPU,
-seconds each."""
+ring and its counter, and ``loads()["programs"]`` counting unarmed.  And the
+step account that the same spans keep (ISSUE 39): where a step's host seconds
+went and when the chip had nothing queued, on the step record, in
+``loads()["step_phases"]`` and the two counters, and the slow steps kept
+whole.  CPU, seconds each."""
 
 import glob
 import os
@@ -14,13 +17,26 @@ import pytest
 
 from smg_tpu.engine.config import CacheConfig, EngineConfig, SchedulerConfig
 from smg_tpu.engine.engine import Engine
-from smg_tpu.engine.flight_recorder import HORIZON_REASONS, STEP_RECORD_KEYS
-from smg_tpu.engine.spans import SPAN_NAMES
-from smg_tpu.models.config import tiny_test_config
+from smg_tpu.engine.flight_recorder import (
+    HORIZON_REASONS,
+    MOE_STEP_RECORD_KEYS,
+    PHASE_RECORD_KEYS,
+    SCHEMA_VERSION,
+    SLOW_STEP_S,
+    SLOW_STEPS_KEPT,
+    STEP_RECORD_KEYS,
+)
+from smg_tpu.engine.spans import PHASES, SPAN_NAMES, STARVED_PHASES, StepAccount
+from smg_tpu.models.config import (
+    tiny_mimo_config,
+    tiny_olmo_hybrid_config,
+    tiny_pangu_moe_config,
+    tiny_test_config,
+)
 from smg_tpu.protocols.sampling import SamplingParams
 
 
-def make_engine(**sched_kw) -> Engine:
+def make_engine(model=None, **sched_kw) -> Engine:
     sched = dict(
         max_batch_size=4, max_seq_len=128, max_prefill_tokens=32,
         prefill_token_buckets=(16, 32), decode_batch_buckets=(4,),
@@ -28,7 +44,7 @@ def make_engine(**sched_kw) -> Engine:
     )
     sched.update(sched_kw)
     return Engine(EngineConfig(
-        model=tiny_test_config(),
+        model=model or tiny_test_config(),
         cache=CacheConfig(page_size=16, num_pages=128, auto_size=False, dtype="float32"),
         scheduler=SchedulerConfig(**sched), dtype="float32", model_id="tiny-tracing",
     ))
@@ -82,7 +98,10 @@ def host_spans(trace_dir: str) -> dict:
     return out
 
 
-def test_trace_holds_every_span_nested_in_the_step(tmp_path):
+@pytest.fixture(scope="module")
+def traced_spans(tmp_path_factory) -> dict:
+    """``host_spans`` of one real trace of a tiny engine under traffic."""
+    tmp_path = tmp_path_factory.mktemp("trace")
     eng = make_engine(max_batch_size=2)
     eng.generate(prompt_ids=[5, 6, 7], sampling=greedy(6))  # compile outside the trace
     eng.start()
@@ -96,7 +115,11 @@ def test_trace_holds_every_span_nested_in_the_step(tmp_path):
         eng.stop_profile()
     finally:
         eng.stop()
-    by_line = host_spans(str(tmp_path))
+    return host_spans(str(tmp_path))
+
+
+def test_trace_holds_every_span_nested_in_the_step(traced_spans):
+    by_line = traced_spans
     seen = {n for evs in by_line.values() for n, *_ in evs}
     assert seen == set(SPAN_NAMES)
     inner = {"smg.step.consume", "smg.step.admit", "smg.step.launch", "smg.step.postprocess"}
@@ -274,3 +297,256 @@ def test_programs_count_launches_and_compiles_unarmed():
     launch(jax.numpy.zeros(4))
     assert eng.loads()["programs"]["recompiles"] == 1
     eng.runner._programs.forget([key])
+
+
+# ---- the step account (ISSUE 39) ----
+
+SUB_SPANS = {
+    "smg.step.consume.fetch": "smg.step.consume", "smg.step.admit.pack": "smg.step.admit",
+    "smg.step.admit.dispatch": "smg.step.admit", "smg.step.launch.dispatch": "smg.step.launch",
+}
+
+
+def test_the_four_sub_spans_are_named_and_nest_in_their_parents(traced_spans):
+    assert set(SUB_SPANS) <= set(SPAN_NAMES) and len(SPAN_NAMES) == 12
+    counted = dict.fromkeys(SUB_SPANS, 0)
+    for evs in traced_spans.values():
+        for n, s, e, _a in evs:
+            if n in SUB_SPANS:
+                parents = [(s0, e0) for n0, s0, e0, _ in evs if n0 == SUB_SPANS[n]]
+                assert any(s0 <= s and e <= e0 for s0, e0 in parents), n
+                counted[n] += 1
+    assert all(counted.values()), counted
+    # every launch dispatches once, and every consume fetches once
+    names = [n for evs in traced_spans.values() for n, *_ in evs]
+    assert names.count("smg.step.consume.fetch") == names.count("smg.step.consume")
+    assert 0 < names.count("smg.step.launch.dispatch") <= names.count("smg.step.launch")
+
+
+def drive(eng: Engine, prompts: list, n: int = 12, **sampling) -> list:
+    """Submit and step on this thread until nothing is left; the ring."""
+    for p in prompts:
+        eng.submit(p, SamplingParams(temperature=0.0, max_new_tokens=n, ignore_eos=True,
+                                     **sampling))
+    while eng.scheduler.has_work():
+        eng.step()
+    return eng.dump_flight("test")["ring"]
+
+
+@pytest.mark.parametrize("family", ["llama", "recurrent", "latent", "window"])
+def test_schema_9_step_records_carry_the_account_in_every_runner(family):
+    model = {"llama": tiny_test_config,
+             "recurrent": tiny_olmo_hybrid_config,
+             "latent": lambda: tiny_pangu_moe_config(held=(4, 8)),
+             "window": lambda: tiny_mimo_config(held=(4, 8))}[family]()
+    eng = make_engine(model=model)
+    ring = drive(eng, [[5, 6, 7, 8 + i] for i in range(3)], n=8)
+    dump = eng.dump_flight("test")
+    assert dump["schema_version"] == SCHEMA_VERSION == 9
+    assert PHASE_RECORD_KEYS <= STEP_RECORD_KEYS and len(PHASE_RECORD_KEYS) == 11
+    moe = MOE_STEP_RECORD_KEYS if family in ("latent", "window") else frozenset()
+    assert all(STEP_RECORD_KEYS <= set(r) <= STEP_RECORD_KEYS | moe for r in ring)
+    assert all(isinstance(r[k], float) for r in ring for k in PHASE_RECORD_KEYS)
+    assert sum(r["admit_dispatch_s"] for r in ring) > 0  # the runner's own spans fed it
+    assert sum(r["dispatch_s"] - r["admit_dispatch_s"] for r in ring) > 0
+    assert dump["slow_steps"] == eng.loads()["slow_steps"]["steps"]
+
+
+def check_account(ring: list, sums: dict) -> None:
+    """What holds of every step record's account, and of its sums."""
+    eps = 1e-9
+    for r in ring:
+        assert r["consume_s"] + r["admit_s"] + r["launch_s"] <= r["step_s"] + eps
+        assert 0.0 <= r["fetch_wait_s"] <= r["consume_s"] + eps
+        assert r["admit_pack_s"] + r["admit_dispatch_s"] <= r["admit_s"] + eps
+        assert r["admit_dispatch_s"] <= r["dispatch_s"] + eps
+        assert r["dispatch_s"] - r["admit_dispatch_s"] <= r["launch_s"] + eps
+        assert 0.0 <= r["starved_s"] <= r["step_s"] + r["gap_s"] + eps
+        parts = r["starved_consume_s"] + r["starved_admit_s"] + r["starved_launch_s"]
+        assert 0.0 <= parts <= r["starved_s"] + eps
+        assert r["starved_consume_s"] <= r["consume_s"] and r["starved_admit_s"] <= r["admit_s"]
+        assert r["starved_launch_s"] <= r["launch_s"] and r["gap_s"] >= 0.0
+    sec, starved = sums["seconds"], sums["starved_seconds"]
+    assert set(sec) == set(PHASES) | {"gap", "step"} and set(starved) == set(STARVED_PHASES)
+    assert sums["steps"] == ring[-1]["serial"]
+    for sub, parent in (("consume_fetch", "consume"), ("admit_pack", "admit"),
+                        ("admit_dispatch", "admit"), ("launch_dispatch", "launch")):
+        assert 0.0 < sec[sub] <= sec[parent]
+    if len(ring) == sums["steps"]:  # the ring holds every step: the sums are the records'
+        for key, rec in (("consume", "consume_s"), ("consume_fetch", "fetch_wait_s"),
+                         ("admit", "admit_s"), ("launch", "launch_s"), ("gap", "gap_s"),
+                         ("step", "step_s"), ("admit_pack", "admit_pack_s")):
+            assert sec[key] == pytest.approx(sum(r[rec] for r in ring))
+        assert sum(starved.values()) == pytest.approx(sum(r["starved_s"] for r in ring))
+
+
+@pytest.mark.parametrize("schedule", ["overlap", "sync", "spec", "spec_sync"])
+def test_the_account_adds_up_under_every_schedule(schedule):
+    kw = {"overlap": {}, "sync": {"overlap_schedule": False},
+          "spec": {"speculative": True, "spec_max_draft": 4},
+          "spec_sync": {"speculative": True, "spec_max_draft": 4,
+                        "overlap_schedule": False}}[schedule]
+    eng = make_engine(**kw)
+    # a prompt over the step's budget of 32 (KV-only chunks, then a solo last
+    # one), short ones that group, and repeats for the n-gram drafts
+    prompts = [[5, 6, 7] * 14, [5, 6, 7, 8], [9, 8, 7, 9, 8, 7, 9, 8], [3, 4, 3, 4, 3, 4]]
+    ring = drive(eng, prompts, n=16)
+    check_account(ring, eng.loads()["step_phases"])
+    assert {r["kind"] for r in ring} >= {"decode"}
+    if schedule.startswith("spec"):
+        assert sum(r["spec_drafted"] for r in ring) > 0  # verify frames were among them
+    text = {s.labels["phase"]: s.value for m in eng.metrics.step_phase_seconds.collect()
+            for s in m.samples if s.name.endswith("_total")}
+    assert text == pytest.approx(
+        {k: v for k, v in eng.loads()["step_phases"]["seconds"].items() if k != "step"})
+    starved = {s.labels["phase"]: s.value for m in eng.metrics.chip_starved_seconds.collect()
+               for s in m.samples if s.name.endswith("_total")}
+    assert starved == pytest.approx(eng.loads()["step_phases"]["starved_seconds"])
+
+
+class Script:
+    """A clock that reads what the test wrote next."""
+
+    def __init__(self, *times):
+        self.times = list(times)
+
+    def __call__(self) -> float:
+        return self.times.pop(0)
+
+
+def test_a_lookahead_in_flight_starves_nothing_and_a_cold_launch_does():
+    """The account alone, driven as the overlapped step drives it, on a
+    scripted clock: the fake runner is the test."""
+    clock = Script()
+    a = StepAccount(clock=clock)
+
+    def span(name, t0, t1, proves=None, inside=lambda: None):
+        clock.times += [t0]
+        with a.span(name, proves=proves):
+            inside()
+            clock.times += [t1]
+
+    # step 1, from idle: a prefill and the frame behind it (launches 1 and 2)
+    clock.times += [10.0]
+    a.begin_step()
+    span("smg.step.admit", 10.0, 10.5,
+         inside=lambda: span("smg.step.admit.dispatch", 10.1, 10.4))
+    span("smg.step.launch", 10.5, 10.8,
+         inside=lambda: span("smg.step.launch.dispatch", 10.6, 10.7))
+    clock.times += [10.85, 10.9]
+    a.fetched(1)  # the prefill's first tokens: launch 2 is still out
+    rec = a.end_step(has_work=True)
+    assert rec["gap_s"] == 0.0  # nothing was waiting before
+    # an idle chip starves from the step's start to the first dispatch's return
+    assert rec["starved_s"] == pytest.approx(0.4) == pytest.approx(rec["starved_admit_s"])
+
+    # step 2: a lookahead (launch 3) goes out before frame 2 is fetched
+    clock.times += [11.0]
+    a.begin_step()
+    span("smg.step.launch", 11.0, 11.2,
+         inside=lambda: span("smg.step.launch.dispatch", 11.05, 11.15))
+    span("smg.step.consume", 11.2, 11.6,
+         inside=lambda: span("smg.step.consume.fetch", 11.2, 11.5, proves=2))
+    span("smg.step.admit", 11.6, 11.7)
+    clock.times += [11.8]
+    rec = a.end_step(has_work=True)
+    assert rec["gap_s"] == pytest.approx(0.1)
+    assert rec["starved_s"] == 0.0 and rec["fetch_wait_s"] == pytest.approx(0.3)
+
+    # step 3: frame 3 met a finish, nothing is behind it; a cold launch follows
+    clock.times += [12.0]
+    a.begin_step()
+    span("smg.step.consume", 12.0, 12.5,
+         inside=lambda: span("smg.step.consume.fetch", 12.0, 12.3, proves=3))
+    span("smg.step.admit", 12.6, 12.8)
+    span("smg.step.launch", 13.0, 13.5,
+         inside=lambda: span("smg.step.launch.dispatch", 13.2, 13.4))
+    clock.times += [13.6]
+    rec = a.end_step(has_work=True)
+    assert rec["starved_consume_s"] == pytest.approx(0.2)  # 12.3 to 12.5
+    assert rec["starved_admit_s"] == pytest.approx(0.2)
+    assert rec["starved_launch_s"] == pytest.approx(0.4)  # 13.0 to the dispatch's return
+    assert rec["starved_s"] == pytest.approx(13.4 - 12.3)  # the fetch's return to the dispatch's
+    assert rec["launch_s"] == pytest.approx(0.5) and rec["dispatch_s"] == pytest.approx(0.2)
+
+    # step 4 ends with nothing left: the next step has no gap, and the empty
+    # chip between them counts for nothing
+    clock.times += [13.7]
+    a.begin_step()
+    span("smg.step.consume", 13.7, 13.9,
+         inside=lambda: span("smg.step.consume.fetch", 13.7, 13.8, proves=4))
+    clock.times += [14.0]
+    rec = a.end_step(has_work=False)
+    assert rec["starved_s"] == pytest.approx(0.2)
+    clock.times += [20.0, 20.5]
+    a.begin_step()
+    rec = a.end_step(has_work=False)
+    assert rec["gap_s"] == 0.0 and rec["starved_s"] == pytest.approx(0.5)
+    assert a.sums()["steps"] == 5 and not clock.times
+
+
+def ticking(eng: Engine, tick: float) -> None:
+    """Every reading of the account's clock is ``tick`` seconds after the
+    last: what a step records is then a count of the account's own readings."""
+    state = {"t": 0.0}
+
+    def clock() -> float:
+        state["t"] += tick
+        return state["t"]
+
+    eng.scheduler.account.clock = clock
+
+
+def test_on_the_engine_a_kept_lookahead_starves_nothing_and_a_finish_leaves_the_chip_empty():
+    eng = make_engine(max_batch_size=2)
+    ticking(eng, 1.0)
+    # two lanes; the first ends by its length 8 tokens before the second
+    eng.submit([5, 6, 7, 8], greedy(9))
+    eng.submit([9, 8, 7, 6], greedy(17))
+    while eng.scheduler.has_work():
+        eng.step()
+    ring = eng.dump_flight("test")["ring"]
+    kept = [r for r in ring if r["overlap"] == "kept"]
+    assert kept and all(r["starved_s"] == 0.0 for r in kept)
+    # no lookahead goes out where a lane is certain to end inside the frame:
+    # the fetch then leaves nothing behind it, and the cold launch that
+    # follows closes the empty interval inside ``smg.step.launch``
+    cold = [r for r in ring if r["overlap"] == "sync" and r["horizon"] and r["horizon_reason"]]
+    assert cold
+    for r in cold:
+        # readings between the fetch's return and the dispatch's: the end of
+        # consume, admit's two, launch's start, the dispatch's two
+        assert r["starved_s"] == 6.0
+        assert (r["starved_consume_s"], r["starved_admit_s"], r["starved_launch_s"]) == (1.0, 1.0, 2.0)
+    check_account(ring, eng.loads()["step_phases"])
+
+
+def test_gap_is_zero_after_an_idle_engine_and_counted_between_busy_steps():
+    eng = make_engine()
+    first = drive(eng, [[5, 6, 7, 8]], n=8)
+    assert first[0]["gap_s"] == 0.0 and all(r["gap_s"] > 0.0 for r in first[1:])
+    time.sleep(0.05)  # idle: no step left work behind
+    ring = drive(eng, [[9, 8, 7, 6]], n=8)
+    second = ring[len(first):]
+    assert second[0]["gap_s"] == 0.0 and all(r["gap_s"] > 0.0 for r in second[1:])
+    # the idle stretch is nobody's: neither a gap nor starved seconds
+    assert second[0]["starved_s"] <= second[0]["step_s"] + 1e-9
+    assert eng.loads()["step_phases"]["seconds"]["gap"] == pytest.approx(
+        sum(r["gap_s"] for r in ring))
+
+
+def test_slow_steps_are_kept_whole_and_the_ninth_pushes_out_the_first():
+    eng = make_engine()
+    drive(eng, [[5, 6, 7, 8]], n=6)
+    before = eng.loads()["slow_steps"]  # a compile may have passed a second
+    assert all(s["step_s"] + s["gap_s"] > SLOW_STEP_S for s in before["steps"])
+    assert before["threshold_s"] == SLOW_STEP_S == 1.0 and SLOW_STEPS_KEPT == 8
+    last = eng.scheduler.flight.step_serial
+    ticking(eng, 0.6)  # two readings of the clock are over SLOW_STEP_S: every step is slow
+    made = [r for r in drive(eng, [[9, 8, 7, 6]], n=40) if r["serial"] > last]
+    assert len(made) >= 9 and all(r["step_s"] > SLOW_STEP_S for r in made)
+    slow = eng.loads()["slow_steps"]
+    assert slow["count"] == before["count"] + len(made)
+    assert slow["steps"] == made[-8:]  # whole records, the newest eight
+    assert made[-9] not in slow["steps"]
+    assert all(set(s) == STEP_RECORD_KEYS for s in slow["steps"])

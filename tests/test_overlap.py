@@ -254,8 +254,8 @@ def test_overlap_metrics_recorded():
     assert 'smg_engine_lookahead_launches_total{outcome="kept"}' in text
     assert 'smg_engine_lookahead_launches_total{outcome="discarded"}' in text
     assert "smg_engine_deferred_fetch_seconds" in text
-    assert "smg_engine_overlap_host_busy_seconds_total" in text
-    assert "smg_engine_overlap_device_wait_seconds_total" in text
+    assert 'smg_engine_step_phase_seconds_total{phase="launch_dispatch"}' in text
+    assert 'smg_engine_step_phase_seconds_total{phase="consume_fetch"}' in text
     assert eng.scheduler.num_lookahead_discarded > 0
 
 

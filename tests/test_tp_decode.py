@@ -223,7 +223,7 @@ def test_mesh_surfaces(cpu_devices):
     assert mesh["shape"]["tp"] == 2
     assert mesh["platform"] == "cpu"
     assert mesh["donate_kv"] is False  # overlap on a CPU mesh
-    assert loads["dispatch_enqueue_seconds"] > 0.0
+    assert loads["step_phases"]["seconds"]["launch_dispatch"] > 0.0
     # flight ring: every step record carries the mesh device count (since v4)
     assert SCHEMA_VERSION >= 4
     assert "mesh" in STEP_RECORD_KEYS

@@ -287,7 +287,7 @@ def test_the_step_record_has_no_new_field():
     eng = make_engine()
     eng.generate(prompt_ids=prompts(11, 20)[0], sampling=greedy(10))
     dump = eng.dump_flight("manual")
-    assert dump["schema_version"] == SCHEMA_VERSION == 8
+    assert dump["schema_version"] == SCHEMA_VERSION == 9
     assert all(STEP_RECORD_KEYS <= set(s) <= STEP_RECORD_KEYS | MOE_STEP_RECORD_KEYS
                for s in dump["ring"])
 
