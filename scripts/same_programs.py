@@ -11,8 +11,9 @@ preset, a ``qk_norm`` config and an M-RoPE config, under XLA attention and
 under the interpreted kernels, and (B) every program family a runner
 registers (``prefill``, ``prefill_extend``, ``prefill_batched`` cold and
 warm, ``decode_multi``, ``decode_spec``, ``embed``) through the runner's own
-host API, for the same configs, and for ``tiny-olmo-hybrid`` and
-``tiny-pangu-moe`` through ``Engine`` (the latter where the tree has it),
+host API, for the same configs, and for ``tiny-olmo-hybrid``,
+``tiny-pangu-moe`` and ``tiny-mimo`` through ``Engine`` (the last two where
+the tree has them), a sampled group and a sampled stream among them,
 and (C) the routed-expert layer itself under XLA's ragged product and under
 the interpreted grouped-product kernel: on the CPU an engine's expert layers
 are XLA's, and the served ones on a TPU the kernel's.
@@ -212,6 +213,11 @@ def _runner_programs(name, cfg, impl, out, costs):
     out[f"{pre}/batched_cold"] = np.stack(r.prefill_batched(cold, *s3))
     warm = [(ids(5), 20, row(1, 2)), (ids(13), 17, row(9, 10)), (ids(2), 30, row(6, 7))]
     out[f"{pre}/batched_warm"] = np.stack(r.prefill_batched(warm, *s3))
+    # a group that samples: the key's fold shows in its tokens
+    hot = (np.full(3, 0.8, np.float32), np.array([-1, 20, -1], np.int32),
+           np.array([1.0, 1.0, 0.9], np.float32), np.array([0.0, 0.0, 0.02], np.float32))
+    drawn = [(ids(21), 0, row(11, 12)), (ids(7), 0, row(13)), (ids(32), 0, row(14, 15))]
+    out[f"{pre}/batched_sampled"] = np.stack(r.prefill_batched(drawn, *hot))
     tables = np.stack([row(1, 2), row(9, 10), row(6, 7), row()])
     pos = np.array([25, 30, 32, 0], np.int32)
     cur = np.array(ids(4), np.int32)
@@ -245,6 +251,9 @@ def _engine_programs(name, cfg, impl, out, costs):
     for i, n in enumerate((20, 100, 45)):
         res = eng.generate(prompt_ids=list(range(5 + i, 5 + i + n)), sampling=sp)
         out[f"{pre}/generate{n}"] = np.asarray(res.token_ids)
+    hot = SamplingParams(temperature=0.8, top_p=0.9, max_new_tokens=9, ignore_eos=True)
+    res = eng.generate(prompt_ids=list(range(9, 40)), sampling=hot)
+    out[f"{pre}/generate_sampled"] = np.asarray(res.token_ids)
     out[f"{pre}/k_cache"] = np.asarray(eng.runner.k_cache)
     if hasattr(eng.runner, "s_pool"):
         out[f"{pre}/s_pool"] = np.asarray(eng.runner.s_pool)
@@ -305,6 +314,13 @@ def dump(root: str, path: str) -> None:
         for impl in ("xla", "pallas_interpret"):
             _engine_programs("pangu_moe", tiny_pangu_moe_config(held=(4, 8)), impl, out, costs)
         _expert_layer_forms(out, costs)
+    try:
+        from smg_tpu.models.config import tiny_mimo_config
+    except ImportError:  # a tree from before the window model
+        tiny_mimo_config = None
+    if tiny_mimo_config is not None:
+        for impl in ("xla", "pallas_interpret"):
+            _engine_programs("mimo", tiny_mimo_config(held=(4, 8)), impl, out, costs)
     np.savez_compressed(path, __costs__=np.array(json.dumps(costs)), **out)
     print(f"{len(out)} outputs, {len(costs)} compiled programs -> {path}")
 

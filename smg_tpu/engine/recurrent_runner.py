@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from smg_tpu.engine import prefill_pack
 from smg_tpu.engine.kv_cache import plan_recurrent_cache
 from smg_tpu.engine.runner import (
     ModelRunner,
@@ -188,8 +189,10 @@ class RecurrentModelRunner(ModelRunner):
         impl = self._grouped_prefill_impl_for(G, T, no_ctx)
         from smg_tpu.engine.sampling import apply_penalties
 
-        def step(params, inv_freq, tokens, prefix_lens, t_reals, kc, vc, page_tables,
-                 sp, cp, slots, key, temps, topks, topps, minps, *extra):
+        def step(params, inv_freq, packed, kc, vc, sp, cp, rng_key, *extra):
+            (tokens, page_tables, prefix_lens, t_reals, topks, temps, topps, minps,
+             counter, slots) = prefill_pack.unpack(packed, G, T, mp, slots=True)
+            key = jax.random.fold_in(rng_key, counter)
             logits, kc, vc, sp, cp = module.forward_prefill_batched(
                 params, cfg, inv_freq, tokens, prefix_lens, t_reals, kc, vc, page_tables,
                 sp, cp, slots, no_ctx=no_ctx, attn_impl=impl)
@@ -201,7 +204,7 @@ class RecurrentModelRunner(ModelRunner):
             toks, lps = _pick_sampler()(logits, key, temps, topks, topps, minps, mask=mask)
             return toks, lps, kc, vc, sp, cp
 
-        donate = (5, 6, 8, 9)
+        donate = (3, 4, 5, 6)
         return self._register(k, jax.jit(step, donate_argnums=donate), donate=donate,
                               in_shardings=None, attn=_attn_label("prefill", impl))
 
@@ -370,26 +373,17 @@ class RecurrentModelRunner(ModelRunner):
             while G < g_real:
                 G *= 2
             mp = len(chunks[0][2])
-            tokens = np.zeros((G, T), np.int32)
-            prefix_lens, t_reals = np.zeros(G, np.int32), np.zeros(G, np.int32)
-            page_tables = np.zeros((G, mp), np.int32)
-            slots = np.zeros(G, np.int32)
-            for i, (ids, pfx, row) in enumerate(chunks):
-                tokens[i, : len(ids)] = ids
-                prefix_lens[i], t_reals[i], page_tables[i] = pfx, len(ids), row
-            if state_slots is not None:
-                slots[:g_real] = state_slots
             fn = self._prefill_batched_fn(G, T, mp, all(c[1] == 0 for c in chunks),
                                           use_pen=pen is not None, use_mask=mask is not None)
+            if state_slots is None:
+                state_slots = np.zeros(g_real, np.int32)
+            packed = self._pack_prefill(chunks, temps, topks, topps, minps, G, T,
+                                        state_slots=state_slots)
         with self.account.span("smg.step.admit.dispatch"):
-            up = self.upload
-            args = [self.params, self.inv_freq, up(tokens), up(prefix_lens), up(t_reals),
-                    self.k_cache, self.v_cache, up(page_tables), *self._state_args(slots),
-                    self._next_key(),
-                    up(_pad_vec(np.asarray(temps, np.float32), G, 0.0)),
-                    up(_pad_vec(np.asarray(topks, np.int32), G, -1)),
-                    up(_pad_vec(np.asarray(topps, np.float32), G, 1.0)),
-                    up(_pad_vec(np.asarray(minps, np.float32), G, 0.0))]
+            up = self._prefill_upload
+            self.prefill_uploads["launches"] += 1
+            args = [self.params, self.inv_freq, up(packed), self.k_cache, self.v_cache,
+                    self.s_pool, self.c_pool, self._rng_key]
             if pen is not None:
                 counts, pmask, freqs, pres, reps = pen
                 args += [up(_pad_rows(counts, G).astype(np.int32)), up(_pad_rows(pmask, G)),

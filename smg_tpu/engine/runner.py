@@ -22,6 +22,7 @@ import numpy as np
 from jax import lax
 
 from smg_tpu.analysis.runtime_guards import ProgramAuditor
+from smg_tpu.engine import prefill_pack
 from smg_tpu.engine.config import EngineConfig
 from smg_tpu.engine.donation import kv_donation_policy
 from smg_tpu.engine.kv_cache import KvCacheSpec, create_kv_buffers, plan_cache
@@ -172,6 +173,8 @@ class ModelRunner:
         # ``smg.step.admit.dispatch`` on this account; a scheduler puts its
         # own here, a runner driven alone keeps one nobody reads
         self.account = StepAccount()
+        # grouped prefill launches, and the host arrays they uploaded
+        self.prefill_uploads = {"launches": 0, "arrays": 0}
         # serving pp: the layer axis of the param stack AND the KV cache
         # shard over "pp" (parallel/pp_serving.py); each stage holds L/S
         # layers — the capacity path for models that don't fit TP-only
@@ -704,6 +707,22 @@ class ModelRunner:
             return jax.device_put(np.asarray(x, dtype), self._replicated)
         return jnp.asarray(x) if dtype is None else jnp.asarray(x, dtype)
 
+    def _pack_prefill(self, chunks, temps, topks, topps, minps, G: int, T: int,
+                      state_slots=None) -> np.ndarray:
+        """A grouped prefill's host inputs as the one array its program
+        takes apart (``prefill_pack``), with the next key counter in it: the
+        value ``_next_key`` would upload, which the program folds itself."""
+        self._step += 1
+        return prefill_pack.pack(chunks, temps, topks, topps, minps, self._step, G, T,
+                                 state_slots=state_slots)
+
+    def _prefill_upload(self, x, dtype=None) -> jax.Array:
+        """``upload`` for a grouped prefill's dispatch, counted
+        (``loads()["prefill_uploads"]``: one array a launch under plain
+        sampling, the packed inputs; the optional arms' arrays beside it)."""
+        self.prefill_uploads["arrays"] += 1
+        return self.upload(x, dtype)
+
     def rng_mark(self) -> int:
         """Snapshot the sampling-key counter before a speculative (lookahead)
         dispatch; ``rng_restore`` rewinds it if the dispatch is discarded so
@@ -880,8 +899,10 @@ class ModelRunner:
         pp_mesh = self.mesh if self.use_pp else None
         impl = self._grouped_prefill_impl_for(G, T, no_ctx)
 
-        def step(params, inv_freq, tokens, prefix_lens, t_reals, kc, vc, page_tables,
-                 key, temps, topks, topps, minps, *extra):
+        def step(params, inv_freq, packed, kc, vc, rng_key, *extra):
+            (tokens, page_tables, prefix_lens, t_reals, topks, temps, topps, minps,
+             counter, _slots) = prefill_pack.unpack(packed, G, T, mp, slots=False)
+            key = jax.random.fold_in(rng_key, counter)
             i = 0
             if use_pen:
                 counts, pmask, freqs, pres, reps = extra[:5]
@@ -917,19 +938,18 @@ class ModelRunner:
                    + (1 if use_mrope else 0))
         if self.mesh is not None:
             r = self._replicated
-            in_sh = (self.param_shardings, r, r, r, r,
-                     self.kv_sharding, self.kv_sharding, r, r, r, r, r, r)
+            in_sh = (self.param_shardings, r, r, self.kv_sharding, self.kv_sharding, r)
             in_sh = in_sh + (r,) * n_extra
             fn = jax.jit(
                 step,
                 in_shardings=in_sh,
                 out_shardings=(r, r, self.kv_sharding, self.kv_sharding),
-                donate_argnums=(5, 6),
+                donate_argnums=(3, 4),
             )
         else:
             in_sh = None
-            fn = jax.jit(step, donate_argnums=(5, 6))
-        return self._register(k, fn, donate=(5, 6), in_shardings=in_sh,
+            fn = jax.jit(step, donate_argnums=(3, 4))
+        return self._register(k, fn, donate=(3, 4), in_shardings=in_sh,
                               attn=_attn_label("prefill", impl))
 
     def prefill_batched(self, chunks, *args, **kw) -> tuple[np.ndarray, np.ndarray]:
@@ -978,24 +998,6 @@ class ModelRunner:
             t_max = max(len(c[0]) for c in chunks)
             T = self.config.scheduler.prefill_bucket(t_max)
             mp = len(chunks[0][2])
-            V = self.model_cfg.vocab_size
-            tokens = np.zeros((G, T), np.int32)
-            prefix_lens = np.zeros(G, np.int32)
-            t_reals = np.zeros(G, np.int32)
-            page_tables = np.zeros((G, mp), np.int32)
-            ftemps = np.zeros(G, np.float32)
-            ftopks = np.full(G, -1, np.int32)
-            ftopps = np.ones(G, np.float32)
-            fminps = np.zeros(G, np.float32)
-            for i, (ids, pfx, row) in enumerate(chunks):
-                tokens[i, : len(ids)] = ids
-                prefix_lens[i] = pfx
-                t_reals[i] = len(ids)
-                page_tables[i] = row
-                ftemps[i] = temps[i]
-                ftopks[i] = topks[i]
-                ftopps[i] = topps[i]
-                fminps[i] = minps[i]
             no_ctx = all(c[1] == 0 for c in chunks)
             use_lora = lora_idx is not None and self._lora_bank is not None
             use_embeds = mm is not None and any(m is not None for m in mm)
@@ -1006,23 +1008,12 @@ class ModelRunner:
                                           use_lora=use_lora,
                                           use_embeds=use_embeds,
                                           use_mrope=use_mrope)
+            packed = self._pack_prefill(chunks, temps, topks, topps, minps, G, T)
         with self.account.span("smg.step.admit.dispatch"):
-            up = self.upload
-            args = [
-                self.params,
-                self.inv_freq,
-                up(tokens),
-                up(prefix_lens),
-                up(t_reals),
-                self.k_cache,
-                self.v_cache,
-                up(page_tables),
-                self._next_key(),
-                up(ftemps),
-                up(ftopks),
-                up(ftopps),
-                up(fminps),
-            ]
+            up = self._prefill_upload
+            self.prefill_uploads["launches"] += 1
+            args = [self.params, self.inv_freq, up(packed), self.k_cache, self.v_cache,
+                    self._rng_key]
             if pen is not None:
                 counts, pmask, freqs, pres, reps = pen
                 args += [
@@ -1052,6 +1043,7 @@ class ModelRunner:
             if use_mrope:
                 # default rows: all three axes = sequential position, which makes
                 # apply_mrope EXACTLY apply_rope for the text rows in the group
+                prefix_lens = _pad_vec(np.array([c[1] for c in chunks], np.int32), G, 0)
                 rp = np.broadcast_to(
                     (prefix_lens[:, None] + np.arange(T))[:, None, :], (G, 3, T)
                 ).astype(np.int32).copy()

@@ -479,6 +479,9 @@ class Scheduler:
             # which attention implementation the dispatch rule resolved to,
             # and how many launches each one has had
             "attention": self.runner.attention_info(),
+            # grouped prefill launches and the host arrays their dispatches
+            # uploaded: one a launch (the packed inputs) under plain sampling
+            "prefill_uploads": dict(self.runner.prefill_uploads),
             # the step account's sums: seconds by phase (with ``gap`` and
             # the whole ``step``) and the seconds the chip had nothing queued
             "step_phases": self.account.sums(),
