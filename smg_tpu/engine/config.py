@@ -16,6 +16,7 @@ TPU-first design notes:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass, field
 
 
@@ -107,7 +108,13 @@ class SchedulerConfig:
     # one — and decode runs every step, so running lanes never observe a
     # multi-chunk stall while a long prompt streams in
     max_prefill_tokens: int = 4096
-    prefill_token_buckets: tuple[int, ...] = (64, 128, 256, 512, 1024, 2048, 4096)
+    # padded lengths a prefill program is compiled for.  The octaves down
+    # from the top (``coarse_prefill_buckets``) serve every launch; a rung
+    # between two of them (1,536, 3,072) serves a launch of one cold row
+    # alone, where the padding is that row's own and a rung is one program
+    # (PERF.md, Findings, PR 42: a 3,072-token row of olmo-hybrid-7b takes
+    # two thirds of a 4,096-token one's time)
+    prefill_token_buckets: tuple[int, ...] = (64, 128, 256, 512, 1024, 1536, 2048, 3072, 4096)
     decode_batch_buckets: tuple[int, ...] = (8, 16, 32, 64)
     schedule_policy: str = "fcfs"  # fcfs | priority
     enable_prefix_cache: bool = True
@@ -226,16 +233,37 @@ class SchedulerConfig:
         return max(self.decode_horizon_max, self.decode_horizon, 1)
 
     def prefill_bucket(self, n_tokens: int) -> int:
-        for b in self.prefill_token_buckets:
-            if n_tokens <= b:
-                return b
-        return max(self.prefill_token_buckets)
+        """The finest rung that holds ``n_tokens``: what a launch of one
+        cold row pads to."""
+        return _rung(sorted(self.prefill_token_buckets), n_tokens)
+
+    @functools.cached_property
+    def coarse_prefill_buckets(self) -> tuple[int, ...]:
+        """The ladder's octaves, ascending: its top rung, and going down
+        every rung at most half the one kept last.  A launch of several
+        rows, a solo chunk and an embedding batch pad to these: their
+        padding is the longest row's or they are rare, and each rung there
+        is a program for every group size."""
+        kept: list[int] = []
+        for b in sorted(self.prefill_token_buckets, reverse=True):
+            if not kept or 2 * b <= kept[-1]:
+                kept.append(b)
+        return tuple(reversed(kept))
+
+    def coarse_prefill_bucket(self, n_tokens: int) -> int:
+        return _rung(self.coarse_prefill_buckets, n_tokens)
 
     def decode_bucket(self, batch: int) -> int:
         for b in self.decode_batch_buckets:
             if batch <= b:
                 return b
         return max(self.decode_batch_buckets)
+
+
+def _rung(ladder, n: int) -> int:
+    """The first rung of the ascending ``ladder`` that holds ``n``, else its
+    top."""
+    return next((b for b in ladder if n <= b), ladder[-1])
 
 
 @dataclass

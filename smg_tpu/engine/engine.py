@@ -108,7 +108,7 @@ class Engine:
                 config.draft_model,
                 num_pages=self.runner.spec.num_pages,
                 page_size=self.runner.spec.page_size,
-                prefill_bucket=config.scheduler.prefill_bucket,
+                prefill_bucket=config.scheduler.coarse_prefill_bucket,
                 dtype=config.cache.dtype,  # draft cache follows the KV dtype
                 seed=config.draft_seed,
                 device=self.runner._device,

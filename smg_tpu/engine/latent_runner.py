@@ -20,7 +20,6 @@ What this runner refuses: everything in the module's ``SERVING_LIMITS``.
 from __future__ import annotations
 
 import jax.numpy as jnp
-import numpy as np
 
 from smg_tpu.engine.kv_cache import plan_latent_cache
 from smg_tpu.engine.runner import ModelRunner, logger
@@ -93,40 +92,27 @@ class LatentModelRunner(ModelRunner):
         return "xla"  # the same one form
 
     def _split_group(self, lengths: "list[int]") -> "list[list[int]]":
-        """A group is padded to ``G x T``, both rounded up, so one long prompt
-        among short ones makes a program of up to four times the step's
-        token budget, which is more than ``_plan_cache`` keeps room for and
-        mostly padding (and the more prompts wait, the larger the groups:
-        under a burst the waste feeds itself).  The rows go into parts of one
-        token bucket each, the widest first, and a part's padded size stays
-        inside the budget (a row longer than the budget alone)."""
+        """The rows go into parts of one octave each, the widest first, and a
+        part's padded size stays inside the step's budget (a row longer than
+        the budget alone): a launch here reads 9 GB of weights whatever it
+        holds, and under a burst the padding of a mixed group feeds itself."""
         sched = self.config.scheduler
         by_bucket: dict[int, list[int]] = {}
         for i, n in enumerate(lengths):
-            by_bucket.setdefault(sched.prefill_bucket(n), []).append(i)
+            by_bucket.setdefault(sched.coarse_prefill_bucket(n), []).append(i)
         parts = []
         for T in sorted(by_bucket, reverse=True):
-            most = 1
-            while 2 * most * T <= sched.max_prefill_tokens:
-                most *= 2
+            most = self._rows_inside_budget(T)
             rows = by_bucket[T]
             parts += [rows[k:k + most] for k in range(0, len(rows), most)]
         return parts
 
     def prefill_batched_async(self, chunks, temps, topks, topps, minps, pen=None,
                               mask=None, lora_idx=None, mm=None, rope=None):
-        """``ModelRunner.prefill_batched_async``, one launch for each part of
-        the group (``_split_group``)."""
         if lora_idx is not None or mm is not None or rope is not None:
             raise ValueError(self.module.SERVING_LIMITS["lora"])
-        parts = []
-        for rows in self._split_group([len(c[0]) for c in chunks]):
-            (_r, toks, lps), = super().prefill_batched_async(
-                [chunks[i] for i in rows], temps[rows], topks[rows], topps[rows], minps[rows],
-                pen=None if pen is None else tuple(a[rows] for a in pen),
-                mask=None if mask is None else mask[rows])
-            parts.append((np.asarray(rows), toks, lps))
-        return parts
+        return super().prefill_batched_async(chunks, temps, topks, topps, minps,
+                                             pen=pen, mask=mask)
 
     # ---- refusals ----
 

@@ -109,11 +109,11 @@ class RecurrentModelRunner(ModelRunner):
         return "xla"
 
     def _chunk_bucket(self, n_tokens: int) -> int:
-        """Every second bucket of the ladder, from the top: a prompt cut by a
+        """Every second octave of the ladder, from the top: a prompt cut by a
         step's budget is rare, and each bucket is a program of 5 MB that takes
         10-20 s to compile (of this model's programs the compile cache a chip
         machine keeps, 192 MiB, holds about forty)."""
-        ladder = sorted(self.config.scheduler.prefill_token_buckets, reverse=True)[::2]
+        ladder = self.config.scheduler.coarse_prefill_buckets[::-1][::2]
         return min((b for b in ladder if b >= n_tokens), default=ladder[0])
 
     def flush_cache_buffers(self) -> None:
@@ -354,43 +354,25 @@ class RecurrentModelRunner(ModelRunner):
         return [self._rng_key, up([0.0], jnp.float32), up([-1], jnp.int32),
                 up([1.0], jnp.float32), up([0.0], jnp.float32)]
 
-    def prefill_batched_async(self, chunks, temps, topks, topps, minps, pen=None,
-                              mask=None, lora_idx=None, mm=None, rope=None,
-                              state_slots=None):
-        """``ModelRunner.prefill_batched_async``; ``state_slots`` [G_real]
-        names each row's slot (padded rows get the garbage slot).  A group
-        whose padded rows x tokens pass the step's token budget runs as
-        several launches of as many rows as fit it, one part each: the
-        chunked recurrence keeps float32 operands of every padded token, and
-        a program of 8 rows x 4,096 tokens does not fit beside the caches."""
+    def _launch_group(self, chunks, temps, topks, topps, minps, pen, mask, lora_idx, mm,
+                      rope, state_slots=None):
+        """``ModelRunner._launch_group``; ``state_slots`` [G_real] names each
+        row's slot (padded rows get the garbage slot).  That a group goes up
+        in parts (``_split_group``) is a need here: the chunked recurrence
+        keeps float32 operands of every padded token, and a program of
+        8 rows x 4,096 tokens does not fit beside the caches."""
         from smg_tpu.engine.runner import _pad_rows, _pad_vec
 
         self._plain("prefill_batched", lora=lora_idx is not None and self._lora_bank is not None,
                     embeds=mm is not None and any(m is not None for m in mm),
                     mrope=rope is not None and any(r is not None for r in rope))
-        g_real = len(chunks)
-        T = self.config.scheduler.prefill_bucket(max(len(c[0]) for c in chunks))
-        rows = max(1, self.config.scheduler.max_prefill_tokens // T)
-        if g_real > rows:
-            parts = []
-            for lo in range(0, g_real, rows):
-                part = slice(lo, lo + rows)
-                (r, t, l), = self.prefill_batched_async(
-                    chunks[part], temps[part], topks[part], topps[part], minps[part],
-                    pen=None if pen is None else tuple(x[part] for x in pen),
-                    mask=None if mask is None else mask[part],
-                    state_slots=None if state_slots is None else state_slots[part])
-                parts.append((r + lo, t, l))
-            return parts
         with self.account.span("smg.step.admit.pack"):
-            G = 1
-            while G < g_real:
-                G *= 2
+            G, T = self._group_shape(chunks)
             mp = len(chunks[0][2])
             fn = self._prefill_batched_fn(G, T, mp, all(c[1] == 0 for c in chunks),
                                           use_pen=pen is not None, use_mask=mask is not None)
             if state_slots is None:
-                state_slots = np.zeros(g_real, np.int32)
+                state_slots = np.zeros(len(chunks), np.int32)
             packed = self._pack_prefill(chunks, temps, topks, topps, minps, G, T,
                                         state_slots=state_slots)
         with self.account.span("smg.step.admit.dispatch"):
@@ -409,7 +391,7 @@ class RecurrentModelRunner(ModelRunner):
             # smglint: disable-next=DONATE the linter counts ``*self._pools()`` as one argument: the donated positions behind the caches are the pools, rebound in ``_take_state``
             toks, lps, self.k_cache, self.v_cache, *state = fn(*args)
             self._take_state(state)
-        return [(np.arange(g_real), toks, lps)]
+        return toks, lps
 
     def decode_multi_async(self, tokens, positions, page_tables, temps, topks, topps,
                            minps, num_steps, max_steps=None, stop_state=None, pen=None,

@@ -175,6 +175,11 @@ class ModelRunner:
         self.account = StepAccount()
         # grouped prefill launches, and the host arrays they uploaded
         self.prefill_uploads = {"launches": 0, "arrays": 0}
+        # what the grouped prefills computed against what they were asked
+        # for (``_group_shape``): tokens of the rows, ``G x T`` of the
+        # launches, launches by shape, and the groups that went up in parts
+        self.prefill_padding = {"real_tokens": 0, "padded_tokens": 0, "launches": {},
+                                "groups_in_parts": 0}
         # serving pp: the layer axis of the param stack AND the KV cache
         # shard over "pp" (parallel/pp_serving.py); each stage holds L/S
         # layers — the capacity path for models that don't fit TP-only
@@ -969,6 +974,50 @@ class ModelRunner:
             toks[rows], lps[rows] = t[: len(rows)], l[: len(rows)]
         return toks, lps
 
+    def _prefill_rung(self, chunks) -> int:
+        """The padded length of one grouped launch.  A single cold row takes
+        the ladder's finest rung: the padding is all its own, and the rung
+        is one program.  Every other launch takes the octave of its longest
+        row (``SchedulerConfig.coarse_prefill_buckets``), where a rung is a
+        program for every group size and for the rows behind a prefix."""
+        sched = self.config.scheduler
+        longest = max(len(c[0]) for c in chunks)
+        if len(chunks) == 1 and chunks[0][1] == 0:
+            return sched.prefill_bucket(longest)
+        return sched.coarse_prefill_bucket(longest)
+
+    def _group_shape(self, chunks) -> tuple[int, int]:
+        """``(G, T)`` of the program that one launch of ``chunks`` runs, the
+        rows rounded up to a power of two; counted in ``prefill_padding``."""
+        G = 1 << (len(chunks) - 1).bit_length()
+        T = self._prefill_rung(chunks)
+        pad, shape = self.prefill_padding, f"{G}x{T}"
+        pad["real_tokens"] += sum(len(c[0]) for c in chunks)
+        pad["padded_tokens"] += G * T
+        pad["launches"][shape] = pad["launches"].get(shape, 0) + 1
+        return G, T
+
+    def _split_group(self, lengths: "list[int]") -> "list[list[int]]":
+        """The rows of a group as the launches that run them.  A group is
+        padded to ``G x T``, both rounded up, so one long prompt among short
+        ones makes a program of up to four times the step's token budget:
+        more than ``_plan_cache`` keeps room for, mostly padding, and a
+        program for every such shape.  A group whose padded size passes the
+        budget goes up in parts of as many rows as fit it at the longest
+        row's octave, in the group's order."""
+        most = self._rows_inside_budget(
+            self.config.scheduler.coarse_prefill_bucket(max(lengths)))
+        rows = list(range(len(lengths)))
+        return [rows[k:k + most] for k in range(0, len(rows), most)]
+
+    def _rows_inside_budget(self, T: int) -> int:
+        """The most rows of ``T`` padded tokens, a power of two as a program's
+        rows are, that stay inside the step's budget (one row at least)."""
+        most = 1
+        while 2 * most * T <= self.config.scheduler.max_prefill_tokens:
+            most *= 2
+        return most
+
     def prefill_batched_async(
         self,
         chunks: "list[tuple[list[int], int, np.ndarray]]",  # (token_ids, prefix_len, page_table_row)
@@ -981,22 +1030,37 @@ class ModelRunner:
         lora_idx: np.ndarray | None = None,  # [G_real] adapter slot per row
         mm: "list[tuple | None] | None" = None,  # per-row (dense [t,E], bool [t])
         rope: "list[np.ndarray | None] | None" = None,  # per-row [3, t] M-RoPE ids
+        **per_row: "np.ndarray | None",  # [G_real] each: what a runner's launch takes besides
     ) -> "list[tuple[np.ndarray, jax.Array, jax.Array]]":
         """Dispatch the grouped prefill and return its first tokens
         UNMATERIALISED, as the launches that computed them: a list of
         ``(rows, tokens [G], logprobs [G])`` where ``rows`` names the
         members of ``chunks`` that the launch's first ``len(rows)`` rows
-        hold.  One launch here; a runner that splits a group returns one
-        entry for each part.  ``fetch_first_tokens`` brings them to the
-        host; ``chain_first_tokens`` hands them to a decode launch on the
-        device."""
+        hold.  One launch (``_launch_group``) for each part of the group
+        (``_split_group``), each folding the next sampling key.
+        ``fetch_first_tokens`` brings them to the host;
+        ``chain_first_tokens`` hands them to a decode launch on the device."""
+        split = self._split_group([len(c[0]) for c in chunks])
+        self.prefill_padding["groups_in_parts"] += len(split) > 1
+        parts = []
+        for rows in split:
+            rows = np.array(rows, np.int64)
+            take = lambda a: None if a is None else np.take(a, rows, axis=0)
+            some = lambda v: None if v is None else [v[i] for i in rows]
+            toks, lps = self._launch_group(
+                some(chunks), take(temps), take(topks), take(topps), take(minps),
+                None if pen is None else tuple(take(a) for a in pen),
+                take(mask), take(lora_idx), some(mm), some(rope),
+                **{k: take(v) for k, v in per_row.items()})
+            parts.append((rows, toks, lps))
+        return parts
+
+    def _launch_group(self, chunks, temps, topks, topps, minps, pen, mask, lora_idx, mm,
+                      rope) -> "tuple[jax.Array, jax.Array]":
+        """One part of ``prefill_batched_async`` as one launch: its tokens
+        and logprobs ``[G]``, still on the device."""
         with self.account.span("smg.step.admit.pack"):
-            g_real = len(chunks)
-            G = 1
-            while G < g_real:
-                G *= 2
-            t_max = max(len(c[0]) for c in chunks)
-            T = self.config.scheduler.prefill_bucket(t_max)
+            G, T = self._group_shape(chunks)
             mp = len(chunks[0][2])
             no_ctx = all(c[1] == 0 for c in chunks)
             use_lora = lora_idx is not None and self._lora_bank is not None
@@ -1052,7 +1116,7 @@ class ModelRunner:
                         rp[i, :, : r.shape[1]] = r
                 args.append(up(rp))
             toks, lps, self.k_cache, self.v_cache = fn(*args)
-        return [(np.arange(g_real), toks, lps)]
+        return toks, lps
 
     def chain_first_tokens(self, tokens: np.ndarray, owner: np.ndarray,
                            parts: list) -> jax.Array:
@@ -1534,8 +1598,8 @@ class ModelRunner:
     # ---- host-facing API ----
 
     def _chunk_bucket(self, n_tokens: int) -> int:
-        """Padded length of a solo or continuing prefill chunk."""
-        return self.config.scheduler.prefill_bucket(n_tokens)
+        """Padded length of a solo or continuing prefill chunk: an octave."""
+        return self.config.scheduler.coarse_prefill_bucket(n_tokens)
 
     def _prefill_chunk_prep(
         self, token_ids, prefix_len, page_table, lora_idx, mm, rope_pos
@@ -2018,7 +2082,7 @@ class ModelRunner:
         # embeddings truncate at the context budget (OpenAI-style) rather than fail
         batches = [b[:cap] for b in batches]
         t_max = max(len(b) for b in batches)
-        T = self.config.scheduler.prefill_bucket(t_max)
+        T = self.config.scheduler.coarse_prefill_bucket(t_max)
         tokens = np.zeros((B, T), np.int32)
         lengths = np.zeros(B, np.int32)
         for i, ids in enumerate(batches):

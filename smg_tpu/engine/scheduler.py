@@ -493,6 +493,11 @@ class Scheduler:
             # grouped prefill launches and the host arrays their dispatches
             # uploaded: one a launch (the packed inputs) under plain sampling
             "prefill_uploads": dict(self.runner.prefill_uploads),
+            # what the grouped prefills computed (``padded_tokens``: rows x
+            # tokens of every launch) for the ``real_tokens`` of their rows,
+            # the launches by padded shape, and the groups sent up in parts
+            "prefill_padding": {**self.runner.prefill_padding,
+                                "launches": dict(self.runner.prefill_padding["launches"])},
             # the step account's sums: seconds by phase (with ``gap`` and
             # the whole ``step``) and the seconds the chip had nothing queued
             "step_phases": self.account.sums(),

@@ -411,7 +411,9 @@ def test_warmup_runs_largest_programs_and_leaves_no_trace():
         "chain_first_tokens"]
     keys = list(eng.runner._compiled)
     assert ("prefill_extend", 64, 16, "xla") == keys[0][:4]
-    assert ("prefill_batched", 8, 16, 16, False) == keys[2][:5]  # ctx variant
+    # ctx variant; 8 rows of 8 tokens pad to 8 x 16, twice the step's budget
+    # of 64, and go up as two launches of 4 x 16
+    assert ("prefill_batched", 4, 16, 16, False) == keys[2][:5]
     assert ("decode_multi", 8, 16, 4) == keys[3][:4]
     assert eng.runner._step == 0
     assert not np.asarray(eng.runner.k_cache[:, 1:]).any()
