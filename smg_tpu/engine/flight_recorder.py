@@ -78,8 +78,10 @@ logger = get_logger("engine.flight_recorder")
 #: self-drafting runner gives a lane one or two tokens a column, so
 #: ``decode_tokens`` counts the tokens accepted while ``columns_run`` counts
 #: the columns (lane-columns are ``decode_tokens - spec_accepted``), and
-#: ``spec_drafted``/``spec_accepted`` are filled for those frames too)
-SCHEMA_VERSION = 10
+#: ``spec_drafted``/``spec_accepted`` are filled for those frames too; v11:
+#: ``moe_picks_zero``, the consumed frame's token-expert pairs on identity
+#: experts, for a model whose router has them (``models/longcat_flash.py``))
+SCHEMA_VERSION = 11
 
 #: what ``spans.StepAccount.end_step`` gives a step record beside ``step_s``
 #: and ``fetch_wait_s`` (the seconds inside ``smg.step.consume.fetch``):
@@ -113,8 +115,9 @@ STEP_RECORD_KEYS = frozenset({
     "spec_drafted", "spec_accepted", "mesh", "horizon_reason", "state_lanes",
     "columns_run",
 }) | PHASE_RECORD_KEYS
-#: what a model with routed experts adds to a step record (no other writes them)
-MOE_STEP_RECORD_KEYS = frozenset({"moe_picks_held", "moe_experts_hit"})
+#: what a model with routed experts adds to a step record (no other writes
+#: them; ``moe_picks_zero`` only where the router has identity experts)
+MOE_STEP_RECORD_KEYS = frozenset({"moe_picks_held", "moe_experts_hit", "moe_picks_zero"})
 
 #: why a decode launch ran the horizon it ran (``Scheduler._pick_horizon``);
 #: a step record of a step that launched no decode carries ""
@@ -270,7 +273,7 @@ class FlightRecorder:
         wasted_decode_tokens: int = 0,
         spec_drafted: int = 0, spec_accepted: int = 0,
         mesh: int = 1, horizon_reason: str = "", state_lanes: int = 0,
-        columns_run: int = 0, moe: "tuple[int, int] | None" = None,
+        columns_run: int = 0, moe: "dict | None" = None,
         phases: dict | None = None,
     ) -> int:
         """Append one step record; returns the step serial.  Called once per
@@ -329,9 +332,10 @@ class FlightRecorder:
                 "columns_run": columns_run,
                 # routed experts: token-expert pairs of the consumed frame on
                 # held experts (rows computed), and held experts with at least
-                # one row summed over layers and columns
-                **({"moe_picks_held": moe[0], "moe_experts_hit": moe[1]}
-                   if moe is not None else {}),
+                # one row summed over layers and columns; pairs on identity
+                # experts where the router has them
+                **({f"moe_{name}": n for name, n in moe.items()
+                    if f"moe_{name}" in MOE_STEP_RECORD_KEYS} if moe is not None else {}),
                 **phases,
             }
             self._ring.append(rec)

@@ -256,7 +256,9 @@ def plan_latent_cache(
     workspace: int = 0,
 ) -> KvCacheSpec:
     """Pages of a latent cache: one buffer of ``entry_lanes`` a token and
-    layer.  ``hbm_limit`` and ``hbm_in_use`` are the tightest device's, read
+    cache layer (``model.num_cache_layers``: one for every attention
+    sublayer, which is not every model's depth).  ``hbm_limit`` and
+    ``hbm_in_use`` are the tightest device's, read
     **after the weights are on it**, so the weights come off once, as in
     ``plan_recurrent_cache``.  ``workspace``: bytes the largest program needs
     beside its arguments, kept free of pages (where the weights take most of
@@ -264,7 +266,7 @@ def plan_latent_cache(
     from smg_tpu.ops.latent_attention import entry_lanes
 
     spec = KvCacheSpec(
-        num_layers=model.num_layers,
+        num_layers=model.num_cache_layers,
         num_pages=cache.num_pages,
         page_size=cache.page_size,
         num_kv_heads=model.num_kv_heads,

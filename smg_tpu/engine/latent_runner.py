@@ -1,6 +1,8 @@
 """The runner of a model whose cache holds one latent entry a token and no V.
 
-``LatentModelRunner`` is ``ModelRunner`` for ``models/pangu_moe.py``.  The
+``LatentModelRunner`` is ``ModelRunner`` for ``models/pangu_moe.py`` and
+``models/longcat_flash.py`` (two attention sublayers a layer: the cache and the
+side buffer are ``cfg.num_cache_layers`` deep, not ``cfg.num_layers``).  The
 cache is one buffer (``kv_cache.plan_latent_cache``: sized from the device
 read after the weights are on it, so the weights come off once); ``v_cache``
 stays an attribute, of zero size, and goes through every program untouched,
@@ -63,7 +65,8 @@ class LatentModelRunner(ModelRunner):
 
     def moe_info(self) -> dict:
         cfg = self.model_cfg
-        return {"experts": cfg.num_experts, "experts_held": cfg.held_experts[1],
+        zero = {"experts_zero": cfg.zero_experts} if cfg.zero_experts else {}
+        return {"experts": cfg.num_experts, "experts_held": cfg.held_experts[1], **zero,
                 "top_k": cfg.num_experts_per_tok, "impl": self.moe_impl}
 
     def attention_info(self) -> dict:
@@ -158,7 +161,7 @@ class LatentModelRunner(ModelRunner):
         if use_lora or use_mrope:
             raise ValueError(self.module.SERVING_LIMITS["lora"])
         cfg, module = self.model_cfg, self.module
-        L, W = cfg.num_layers, self.spec.lanes
+        L, W = cfg.num_cache_layers, self.spec.lanes
 
         def frame(params, inv_freq, entry_pos, kc, vc, page_tables, attn_impl):
             # a padded lane sits past its table (``Scheduler._launch_frame``)

@@ -406,7 +406,9 @@ class EngineMetrics:
             "smg_engine_moe_picks_total",
             "Token-expert pairs the decode frames consumed routed, by whether "
             "the picked expert is held by this process (held=\"true\": rows "
-            "its expert layers computed) or by another (held=\"false\")",
+            "its expert layers computed), by another (held=\"false\"), or is an "
+            "identity expert that no process holds and that adds the weighted "
+            "token itself (held=\"identity\")",
             ["held"], registry=r,
         ))
 

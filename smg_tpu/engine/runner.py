@@ -1350,8 +1350,8 @@ class ModelRunner:
                          "forward_decode_horizon", "forward_verify_column",
                          "forward_mtp_prefill", "forward_mtp_column", "forward_mtp_draft")
                if hasattr(module, f)}})
-        # device int32 [4] of the frame launched last: ``[picks, picks on held
-        # experts, held experts hit, most rows one layer and column computed]``
+        # device int32 of the frame launched last, one count a name of the
+        # module's ``ROUTED_COUNTS``
         self.frame_counts = None
 
     def _decode_multi_routed_fn(self, B: int, mp: int, N: int, E: int, use_pen: bool,
@@ -1422,7 +1422,7 @@ class ModelRunner:
 
             init = (jnp.int32(0), tokens, jnp.zeros((B, N), jnp.int32),
                     jnp.zeros((B, N), jnp.float32), side0, counts0, done0,
-                    jnp.zeros((4,), jnp.int32))
+                    jnp.zeros((len(module.ROUTED_COUNTS),), jnp.int32))
             steps_run, _cur, outs, lps, side, counts, _done, routed = \
                 lax.while_loop(cond, body, init)
             out = (outs, lps, steps_run, *land(side, jnp.arange(N)[None, :] < steps_run))

@@ -133,7 +133,7 @@ class InFlightFrame:
     clean: "object" = None  # jax.Array scalar bool
     ran: bool = True
     # routed experts: the frame's counts (``LatentModelRunner.frame_counts``)
-    routed: "object" = None  # jax.Array int32 [4]
+    routed: "object" = None  # jax.Array int32, one count a name of ``module.ROUTED_COUNTS``
     # a self-drafting runner's verify frame (``SelfDraftingRunner.frame_tail``):
     # ``(emitted [B, N], last [B], positions [B], [drafted, accepted])``, device
     # arrays; ``toks``/``lps`` are then ``[B, N, 2]`` and a lane's tokens of
@@ -257,10 +257,13 @@ class Scheduler:
         self._step_horizon_reason = ""
         self.num_decode_launches = dict.fromkeys(HORIZON_REASONS, 0)
         self._step_columns_run = 0
-        # routed experts, over the decode frames consumed: token-expert pairs,
-        # those on held experts (rows computed), held experts hit summed over
-        # layers and columns, and the most rows one layer and column computed
-        self.moe_counts = [0, 0, 0, 0] if hasattr(runner, "moe_info") else None
+        # routed experts, over the decode frames consumed: the module's
+        # ``ROUTED_COUNTS`` by name (token-expert pairs, those on held experts,
+        # held experts hit summed over layers and columns, the most rows one
+        # layer and column computed; for a model with identity experts the
+        # pairs on those)
+        self.moe_counts = (dict.fromkeys(runner.module.ROUTED_COUNTS, 0)
+                           if hasattr(runner, "moe_info") else None)
         self._step_moe = None
         # step-scoped speculative-decoding telemetry (flight-recorder ring
         # spec fields) + the acceptance-length EMA the adaptive depth
@@ -542,9 +545,7 @@ class Scheduler:
                 "linattn_decode": info["linattn_decode"],
             })
         if self.moe_counts is not None:
-            picks, held, hit, rows_max = self.moe_counts
-            out["moe"] = {**self.runner.moe_info(), "picks": picks, "picks_held": held,
-                          "experts_hit": hit, "rows_max": rows_max}
+            out["moe"] = {**self.runner.moe_info(), **self.moe_counts}
         if hasattr(self.runner, "latent_info"):
             out["latent_cache"] = self.runner.latent_info()
         if self.metrics is not None:
@@ -1195,16 +1196,17 @@ class Scheduler:
 
     def _count_routed(self, routed: list) -> None:
         """The expert layers' counts of a consumed decode frame."""
-        picks, held, hit, rows_max = routed
+        new = dict(zip(self.runner.module.ROUTED_COUNTS, routed))
         c = self.moe_counts
-        c[0] += picks
-        c[1] += held
-        c[2] += hit
-        c[3] = max(c[3], rows_max)
-        self._step_moe = (held, hit)
+        for name, n in new.items():
+            c[name] = max(c[name], n) if name == "rows_max" else c[name] + n
+        self._step_moe = new
         if self.metrics is not None:
+            held, zero = new["picks_held"], new.get("picks_zero", 0)
             self.metrics.moe_picks.labels(held="true").inc(held)
-            self.metrics.moe_picks.labels(held="false").inc(picks - held)
+            self.metrics.moe_picks.labels(held="false").inc(new["picks"] - held - zero)
+            if "picks_zero" in new:
+                self.metrics.moe_picks.labels(held="identity").inc(zero)
 
     def _state_lost(self, reqs: list, why: str) -> None:
         """A frame advanced these sequences' recurrent state by columns that

@@ -434,7 +434,8 @@ class SelfDraftingRunner(WindowModelRunner):
             init = (jnp.int32(0), tokens, drafts[slots], jnp.zeros((B,), jnp.int32),
                     jnp.zeros((B, N, W), jnp.int32), jnp.zeros((B, N, W), jnp.float32),
                     jnp.zeros((B, N), jnp.int32), module.side_buffers(cfg, B, W * N, kc.dtype),
-                    done0, counts0, jnp.zeros((4,), jnp.int32), jnp.zeros((2,), jnp.int32))
+                    done0, counts0, jnp.zeros((len(module.ROUTED_COUNTS),), jnp.int32),
+                    jnp.zeros((2,), jnp.int32))
             (steps_run, last, draft, held, outs, lps, emitted, side, _done, counts, routed,
              spec) = lax.while_loop(cond, body, init)
             # a lane's side rows below ``held`` are the tokens it accepted

@@ -110,6 +110,17 @@ class ModelConfig:
     # is a full-attention layer whose keys and values take one more layer of
     # the pages (``num_cache_layers`` counts it).
     mtp_layers: int = 0
+    # ---- two attention sublayers a layer, identity experts (``longcat_flash``).
+    # Every attention sublayer leaves its own entry in the cache, so a layer is
+    # ``attention_sublayers`` cache layers (``num_cache_layers``).  The last
+    # ``zero_experts`` of the router's ``num_experts`` outputs are experts that
+    # compute nothing (``E_i(x) = x``) and that no chip holds.  The latent
+    # attention's two normed low-rank vectors are scaled by ``mla_q_scale`` and
+    # ``mla_kv_scale`` (1: not scaled, and nothing is multiplied in).
+    attention_sublayers: int = 1
+    zero_experts: int = 0
+    mla_q_scale: float = 1.0
+    mla_kv_scale: float = 1.0
 
     @property
     def window_cache(self) -> bool:
@@ -129,14 +140,15 @@ class ModelConfig:
 
     @property
     def held_experts(self) -> "tuple[int, int]":
-        """``(first, count)`` of the routed experts this process holds."""
-        return self.experts_held or (0, self.num_experts)
+        """``(first, count)`` of the routed experts this process holds (the
+        identity experts are nobody's to hold)."""
+        return self.experts_held or (0, self.num_experts - self.zero_experts)
 
     @property
     def num_cache_layers(self) -> int:
         """Layers that hold keys and values in the paged cache."""
         if self.layer_types is None:
-            return self.num_layers
+            return self.num_layers * self.attention_sublayers
         return sum(1 for t in self.layer_types if t == "full_attention") + self.mtp_layers
 
     @property
@@ -174,6 +186,8 @@ class ModelConfig:
             return cls._from_mimo_v2_flash(cfg, dtype)
         if cfg.get("model_type") == "exaone_moe":
             return cls._from_exaone_moe(cfg, dtype)
+        if cfg.get("model_type") == "longcat_flash":
+            return cls._from_longcat_flash(cfg, dtype)
         # keys that change what the layers compute and that this path would
         # drop in silence: routed experts beyond Qwen-MoE's settings, latent
         # attention.  A config that carries one is another model (D6's rule).
@@ -651,6 +665,88 @@ class ModelConfig:
             mtp_layers=mtp,
         )
 
+    # ``longcat_flash`` (LongCat-Flash): the same rule as above, over the
+    # published config's own key names (``num_layers``, ``ffn_hidden_size``,
+    # ``expert_ffn_hidden_size``, ``moe_topk``, ``zero_expert_num``).
+    _LONGCAT_CONSUMED = frozenset({
+        "model_type", "vocab_size", "hidden_size", "ffn_hidden_size", "expert_ffn_hidden_size",
+        "num_layers", "num_attention_heads", "hidden_act", "max_position_embeddings",
+        "attention_bias", "attention_method", "rms_norm_eps", "tie_word_embeddings",
+        "rope_theta", "rope_scaling", "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+        "qk_rope_head_dim", "v_head_dim", "mla_scale_q_lora", "mla_scale_kv_lora",
+        "n_routed_experts", "zero_expert_num", "zero_expert_type", "moe_topk",
+        "routed_scaling_factor", "norm_topk_prob", "router_bias", "eos_token_id",
+        "bos_token_id",
+        # the chip's share of a deployment, as for ``pangu_ultra_moe``: the
+        # real experts the router chooses among (its outputs are these and the
+        # ``zero_expert_num`` identity experts behind them) and where the held
+        # range starts
+        "router_num_experts", "routed_expert_offset",
+    })
+
+    @classmethod
+    def _from_longcat_flash(cls, cfg: dict, dtype: str) -> "ModelConfig":
+        unknown = sorted(set(cfg) - cls._LONGCAT_CONSUMED - cls._OLMO_HYBRID_SHAPELESS)
+        if unknown:
+            raise ValueError(
+                f"longcat_flash config.json has keys this loader does not consume: {unknown}; "
+                "a key that may bear on the model's shape is not dropped in silence")
+
+        def only(key, served, default):
+            if cfg.get(key, default) not in served:
+                raise ValueError(f"longcat_flash: {key} {cfg[key]!r} is not served")
+
+        only("hidden_act", ("silu",), "silu")
+        only("attention_bias", (False, None), False)
+        only("attention_method", ("MLA",), "MLA")
+        only("rope_scaling", (None, "none"), None)
+        only("zero_expert_type", ("identity",), "identity")
+        only("norm_topk_prob", (False, None), False)
+        only("router_bias", (False, None), False)
+        held = cfg["n_routed_experts"]
+        real = cfg.get("router_num_experts", held)
+        first = cfg.get("routed_expert_offset", 0)
+        if not (0 <= first and first + held <= real):
+            raise ValueError(
+                f"longcat_flash: experts {first}..{first + held - 1} are not among "
+                f"the router's {real} real experts")
+        zero = cfg.get("zero_expert_num", 0)
+        E, eos = cfg["hidden_size"], cfg.get("eos_token_id", 2)
+        return cls(
+            arch="longcat_flash",
+            vocab_size=cfg["vocab_size"],
+            hidden_size=E,
+            intermediate_size=cfg["ffn_hidden_size"],
+            num_layers=cfg["num_layers"],
+            num_heads=cfg["num_attention_heads"],
+            num_kv_heads=cfg["num_attention_heads"],
+            head_dim=cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"],
+            rope_theta=float(cfg.get("rope_theta", 10000.0)),
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
+            max_position_embeddings=cfg.get("max_position_embeddings", 8192),
+            tie_word_embeddings=bool(cfg.get("tie_word_embeddings", False)),
+            eos_token_ids=tuple(eos) if isinstance(eos, list) else (eos,),
+            bos_token_id=cfg.get("bos_token_id", 1),
+            dtype=dtype,
+            num_experts=real + zero,
+            num_experts_per_tok=cfg["moe_topk"],
+            moe_intermediate_size=cfg["expert_ffn_hidden_size"],
+            q_lora_rank=cfg["q_lora_rank"],
+            kv_lora_rank=cfg["kv_lora_rank"],
+            qk_nope_head_dim=cfg["qk_nope_head_dim"],
+            qk_rope_head_dim=cfg["qk_rope_head_dim"],
+            v_head_dim=cfg["v_head_dim"],
+            moe_scoring="softmax",
+            norm_topk_prob=False,
+            routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
+            experts_held=(first, held),
+            moe_select_bias=True,
+            attention_sublayers=2,
+            zero_experts=zero,
+            mla_q_scale=(E / cfg["q_lora_rank"]) ** 0.5 if cfg.get("mla_scale_q_lora") else 1.0,
+            mla_kv_scale=(E / cfg["kv_lora_rank"]) ** 0.5 if cfg.get("mla_scale_kv_lora") else 1.0,
+        )
+
     @classmethod
     def from_pretrained(cls, path: str, dtype: str = "bfloat16") -> "ModelConfig":
         with open(os.path.join(path, "config.json")) as f:
@@ -787,6 +883,42 @@ def tiny_pangu_moe_config(vocab_size: int = 512, held: "tuple[int, int] | None" 
     )
 
 
+def tiny_longcat_flash_config(vocab_size: int = 512, held: "tuple[int, int] | None" = None,
+                              **changes) -> ModelConfig:
+    """Tiny LongCat-Flash for CPU tests: two double layers (four cache
+    layers), latent attention whose cache entry (96 + 32) fills one 128-lane
+    tile, a router of 24 real experts (of which ``held`` are here; None: all)
+    and 8 identity experts behind them, top 6, both scales on."""
+    import dataclasses
+
+    return dataclasses.replace(
+        tiny_test_config(vocab_size),
+        arch="longcat_flash",
+        num_layers=2,
+        num_heads=4,
+        num_kv_heads=4,
+        head_dim=48,
+        num_experts=32,
+        num_experts_per_tok=6,
+        moe_intermediate_size=64,
+        q_lora_rank=64,
+        kv_lora_rank=96,
+        qk_nope_head_dim=32,
+        qk_rope_head_dim=16,
+        v_head_dim=32,
+        moe_scoring="softmax",
+        norm_topk_prob=False,
+        routed_scaling_factor=6.0,
+        experts_held=held,
+        moe_select_bias=True,
+        attention_sublayers=2,
+        zero_experts=8,
+        mla_q_scale=(128 / 64) ** 0.5,
+        mla_kv_scale=(128 / 96) ** 0.5,
+        **changes,
+    )
+
+
 def tiny_mimo_config(vocab_size: int = 512, held: "tuple[int, int] | None" = None,
                      **changes) -> ModelConfig:
     """Tiny MiMo-V2-Flash for CPU tests: a dense full-attention layer, three
@@ -906,6 +1038,7 @@ PRESETS = {
     "tiny-pangu-moe": tiny_pangu_moe_config,
     "tiny-mimo": tiny_mimo_config,
     "tiny-exaone-moe": tiny_exaone_moe_config,
+    "tiny-longcat-flash": tiny_longcat_flash_config,
     "llama3.2-1b": llama32_1b_config,
     "llama3-8b": llama3_8b_config,
     "llama3-70b": llama3_70b_config,

@@ -72,6 +72,7 @@ from smg_tpu.models import mimo
 from smg_tpu.models.config import ModelConfig
 from smg_tpu.models.llama import _mlp, _norm, embed_tokens, unembed
 from smg_tpu.models.mimo import (  # noqa: F401  (the runner's, by these names)
+    ROUTED_COUNTS,
     _scale,
     layer_kinds,
     layer_runs,
@@ -324,7 +325,8 @@ def _layers(stacks: Params, runs, cfg: ModelConfig, inv_freq, h, positions, live
 
         return body
 
-    return mimo.scan_runs(stacks, runs, (h, state, jnp.zeros((4,), jnp.int32)), layer_of)
+    counts = jnp.zeros((len(ROUTED_COUNTS),), jnp.int32)
+    return mimo.scan_runs(stacks, runs, (h, state, counts), layer_of)
 
 
 def _stack(params, cfg, inv_freq, h, positions, live, state, attend, moe_impl):

@@ -53,7 +53,10 @@ import jax.numpy as jnp
 
 from smg_tpu.models.config import ModelConfig
 from smg_tpu.models.llama import _mlp_residual, _norm, _write_side, embed_tokens, unembed
-from smg_tpu.models.pangu_moe import merge_counts  # noqa: F401  (the runner's, by this name)
+from smg_tpu.models.pangu_moe import (  # noqa: F401  (the runner's, by these names)
+    ROUTED_COUNTS,
+    merge_counts,
+)
 from smg_tpu.ops import moe
 from smg_tpu.ops import window_attention as wa
 from smg_tpu.ops.attention import (
@@ -287,7 +290,8 @@ def _stack(params: Params, cfg: ModelConfig, inv_freq, h, positions, live, state
 
         return body
 
-    return scan_runs(params, layer_runs(cfg), (h, state, jnp.zeros((4,), jnp.int32)), layer_of)
+    counts = jnp.zeros((len(ROUTED_COUNTS),), jnp.int32)
+    return scan_runs(params, layer_runs(cfg), (h, state, counts), layer_of)
 
 
 def scan_runs(stacks: Params, runs, carry, layer_of):

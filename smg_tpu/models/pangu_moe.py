@@ -41,6 +41,9 @@ and ``forward_decode_horizon`` on one device; everything in
 ``SERVING_LIMITS`` is refused at start, not run wrong.  The leading dense
 layers and the expert layers are two parameter stacks (their shapes differ),
 scanned in turn; layer ``l`` of the model is layer ``l`` of the cache.
+``models/longcat_flash.py`` serves another block over the same attention: it
+gives these forwards its own ``stack`` and calls ``latent_attention`` twice a
+layer.
 """
 
 from __future__ import annotations
@@ -197,25 +200,46 @@ def logical_axes(cfg: ModelConfig) -> Params:
 # them and returns the heads' outputs [..., H, dv] with the state it changed.
 
 
+def _scaled(weight, scale: float):
+    """A norm's weight times a static ``scale``, in float32, so that ``scale x
+    RMSNorm(x)`` is rounded once, with the norm.  A scale of 1 is the weight
+    itself: nothing is multiplied in."""
+    return weight if scale == 1.0 else weight.astype(jnp.float32) * scale
+
+
 def _latent_qkv(layer: Params, cfg: ModelConfig, x, positions, inv_freq):
     """Queries ``q_nope`` [..., H, dn], ``q_pe`` [..., H, dr] (rotated) and the
-    cache entry ``[c | k_pe | 0]`` [..., W] of the tokens ``x`` [..., E]."""
+    cache entry ``[c | k_pe | 0]`` [..., W] of the tokens ``x`` [..., E].  A
+    model that scales its two normed low-rank vectors (``cfg.mla_q_scale``,
+    ``cfg.mla_kv_scale``; ``models/longcat_flash.py``) has them scaled here,
+    behind the norms: the entry then holds the scaled latent, and the rotary
+    key is not scaled."""
     dn, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
     with jax.named_scope("smg.mla.q"):
-        c_q = rms_norm(jnp.einsum("...e,er->...r", x, layer["w_dq"]), layer["q_norm"],
-                       cfg.rms_norm_eps)
+        c_q = rms_norm(jnp.einsum("...e,er->...r", x, layer["w_dq"]),
+                       _scaled(layer["q_norm"], cfg.mla_q_scale), cfg.rms_norm_eps)
         q_nope = jnp.einsum("...r,fr->...f", c_q, layer["w_uq_nope"])
         q_nope = q_nope.reshape(*q_nope.shape[:-1], cfg.num_heads, dn)
         q_pe = apply_rope(jnp.einsum("...r,dhr->...hd", c_q, layer["w_uq_pe"]),
                           positions, inv_freq)
     with jax.named_scope("smg.mla.kv"):
-        c = rms_norm(jnp.einsum("...e,ec->...c", x, layer["w_dkv"]), layer["kv_norm"],
-                     cfg.rms_norm_eps)
+        c = rms_norm(jnp.einsum("...e,ec->...c", x, layer["w_dkv"]),
+                     _scaled(layer["kv_norm"], cfg.mla_kv_scale), cfg.rms_norm_eps)
         k_pe = jnp.einsum("...e,ed->...d", x, layer["w_dk_pe"])
         k_pe = apply_rope(k_pe[..., None, :], positions, inv_freq)[..., 0, :]
         pad = jnp.zeros((*c.shape[:-1], cache_lanes(cfg) - rkv - k_pe.shape[-1]), c.dtype)
         entry = jnp.concatenate([c, k_pe, pad], axis=-1)
     return q_nope, q_pe, entry
+
+
+def latent_attention(layer: Params, cfg: ModelConfig, x, positions, inv_freq, attend, l, state):
+    """One latent attention over the normed tokens ``x`` [..., E], as cache
+    layer ``l``: ``W_o`` over the heads' outputs, and the forward's ``state``
+    as ``attend`` changed it."""
+    q_nope, q_pe, entry = _latent_qkv(layer, cfg, x, positions, inv_freq)
+    out, state = attend(q_nope, q_pe, entry, layer, l, state)
+    o = jnp.einsum("...f,fe->...e", out.astype(x.dtype).reshape(*x.shape[:-1], -1), layer["wo"])
+    return o, state
 
 
 def _moe_residual(h, layer: Params, experts: Params, i, cfg: ModelConfig, live, impl: str):
@@ -250,6 +274,14 @@ def shared_expert(layer: Params, x, cfg: ModelConfig):
                  "w_down": layer["ws_down"]}, x, cfg)
 
 
+#: what a decode frame counts of its routed experts, in the frame's order
+#: (``engine/runner._decode_multi_routed_fn``): token-expert pairs, those on
+#: held experts (rows computed), held experts hit summed over layers and
+#: columns, and the most rows one layer and column computed.  The scheduler
+#: and the step ring read the counts by these names.
+ROUTED_COUNTS = ("picks", "picks_held", "experts_hit", "rows_max")
+
+
 def merge_counts(total, new):
     """The expert layers' counts of one more layer, or column: the first three
     add up, the fourth is kept as a maximum."""
@@ -264,11 +296,8 @@ def _stack(params: Params, cfg: ModelConfig, inv_freq, h, positions, live, state
     Ld = cfg.first_k_dense_replace
 
     def attention(h, layer, l, state):
-        q_nope, q_pe, entry = _latent_qkv(layer, cfg, _norm(h, layer["attn_norm"], cfg),
-                                          positions, inv_freq)
-        out, state = attend(q_nope, q_pe, entry, layer, l, state)
-        o = jnp.einsum("...f,fe->...e", out.astype(h.dtype).reshape(*h.shape[:-1], -1),
-                       layer["wo"])
+        o, state = latent_attention(layer, cfg, _norm(h, layer["attn_norm"], cfg), positions,
+                                    inv_freq, attend, l, state)
         return h + _norm(o, layer["post_attn_norm"], cfg), state
 
     def dense(carry, xs):
@@ -288,7 +317,7 @@ def _stack(params: Params, cfg: ModelConfig, inv_freq, h, positions, live, state
 
     (h, state), _ = jax.lax.scan(dense, (h, state), (params["dense"], jnp.arange(Ld)))
     (h, state, counts), _ = jax.lax.scan(
-        expert, (h, state, jnp.zeros((4,), jnp.int32)),
+        expert, (h, state, jnp.zeros((len(ROUTED_COUNTS),), jnp.int32)),
         ({k: v for k, v in params["moe"].items() if k not in routed},
          jnp.arange(cfg.num_layers - Ld)))
     return h, state, counts
@@ -303,7 +332,7 @@ def _scale(cfg: ModelConfig) -> float:
 
 
 def _prefill(params, cfg, inv_freq, tokens, prefix_lens, t_reals, cache, page_tables,
-             no_ctx: bool, moe_impl: str):
+             no_ctx: bool, moe_impl: str, stack):
     """Solo and grouped prefill: ``tokens`` [G, T], one row a sequence."""
     G, T = tokens.shape
     rkv, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
@@ -341,7 +370,7 @@ def _prefill(params, cfg, inv_freq, tokens, prefix_lens, t_reals, cache, page_ta
         return out, cache
 
     h = embed_tokens(params, cfg, tokens)
-    h, cache, _counts = _stack(params, cfg, inv_freq, h, pos, real, cache, attend, moe_impl)
+    h, cache, _counts = stack(params, cfg, inv_freq, h, pos, real, cache, attend, moe_impl)
     last = jnp.take_along_axis(
         h, jnp.maximum(t_reals - 1, 0)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
     return unembed(params, cfg, last), cache
@@ -359,14 +388,15 @@ def forward_prefill(
     page_table: jnp.ndarray,  # [mp]
     attn_impl: str = "xla",  # prefill attention has one form; kept for the runner
     moe_impl: str = "xla",  # "xla" | "pallas" | "pallas_interpret"
+    stack=_stack,  # the layers: another model of latent attention gives its own
     **unserved,
 ):
     """One chunk of one sequence behind the prefix its pages hold.  Returns
     (last_token_logits [V], k_cache, v_cache)."""
-    _refuse(unserved)
+    _refuse(cfg, unserved)
     logits, k_cache = _prefill(
         params, cfg, inv_freq, tokens[None], prefix_len[None], t_real[None], k_cache,
-        page_table[None], False, moe_impl)
+        page_table[None], False, moe_impl, stack)
     return logits[0], k_cache, v_cache
 
 
@@ -383,22 +413,23 @@ def forward_prefill_batched(
     no_ctx: bool = False,  # static: every row starts its sequence
     moe_impl: str = "xla",
     attn_impl: str = "xla",  # prefill attention has one form; kept for the runner
+    stack=_stack,
     **unserved,
 ):
     """Several sequences' chunks in one call.  Returns (logits [G, V],
     k_cache, v_cache)."""
-    _refuse(unserved)
+    _refuse(cfg, unserved)
     logits, k_cache = _prefill(params, cfg, inv_freq, tokens, prefix_lens, t_reals, k_cache,
-                               page_tables, no_ctx, moe_impl)
+                               page_tables, no_ctx, moe_impl, stack)
     return logits, k_cache, v_cache
 
 
-def _refuse(unserved: dict) -> None:
+def _refuse(cfg: ModelConfig, unserved: dict) -> None:
     """The runner passes every model the Llama family's keywords; this model
     serves none of them, and one that is set is an error, not ignored."""
     on = sorted(k for k, v in unserved.items() if v is not None and v is not False)
     if on:
-        raise ValueError(f"pangu_ultra_moe does not take {', '.join(on)}")
+        raise ValueError(f"{cfg.arch} does not take {', '.join(on)}")
 
 
 # --------------------------------------------------------------------------
@@ -419,11 +450,13 @@ def forward_decode_horizon(
     live: jnp.ndarray,  # [B] bool: the lane holds a sequence
     attn_impl: str = "xla",
     moe_impl: str = "xla",
+    stack=_stack,
 ):
     """One decode column.  The frozen cache and the side buffer are read, the
     column's entries go to the side buffer.  Returns (logits [B, V], side,
     counts): int32 ``[picks, picks on held experts, held experts hit, most
-    picks on held experts in one layer]`` of this column."""
+    picks on held experts in one layer]`` of this column (what ``stack``
+    counts)."""
     rkv = cfg.kv_lora_rank
     scale = _scale(cfg)
 
@@ -452,5 +485,5 @@ def forward_decode_horizon(
         return out, side
 
     h = embed_tokens(params, cfg, tokens)
-    h, side, counts = _stack(params, cfg, inv_freq, h, positions, live, side, attend, moe_impl)
+    h, side, counts = stack(params, cfg, inv_freq, h, positions, live, side, attend, moe_impl)
     return unembed(params, cfg, h), side, counts

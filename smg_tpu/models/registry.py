@@ -18,10 +18,14 @@ with state beside its pages):
 - a module whose sequences hold state beside their pages also gives
   ``state_shapes(cfg)`` and takes the pools and slots after the page tables
   (``engine/recurrent_runner.py``);
-- a module whose cache is one latent buffer (``models/pangu_moe.py``) takes
-  ``v_cache`` of zero size through its prefill forwards untouched, and its
-  decode column takes the one side buffer and the lanes that hold a sequence
-  (``engine/latent_runner.py``);
+- a module whose cache is one latent buffer (``models/pangu_moe.py``,
+  ``models/longcat_flash.py``) takes ``v_cache`` of zero size through its
+  prefill forwards untouched, and its decode column takes the one side buffer
+  (``cfg.num_cache_layers`` deep, which for ``longcat_flash`` is twice the
+  model's layers) and the lanes that hold a sequence
+  (``engine/latent_runner.py``); a module with routed experts gives
+  ``merge_counts`` and ``ROUTED_COUNTS``, the names of what its frame counts
+  (``pangu_moe``'s four; ``longcat_flash`` adds the picks on identity experts);
 - a module with window layers beside full ones (``models/mimo.py``) takes the
   window layers' rings and the rows' slots after the page tables, as a module
   with state does, and its decode column the four side buffers as one tuple
@@ -40,7 +44,8 @@ from types import ModuleType
 _REGISTRY: dict[str, ModuleType] = {}
 # architectures this package brings itself, loaded on first use
 _LLAMA_FAMILY = ("llama", "qwen", "mistral", "qwen_moe")
-_BUILTIN = (*_LLAMA_FAMILY, "olmo_hybrid", "pangu_ultra_moe", "mimo_v2_flash", "exaone_moe")
+_BUILTIN = (*_LLAMA_FAMILY, "olmo_hybrid", "pangu_ultra_moe", "mimo_v2_flash", "exaone_moe",
+            "longcat_flash")
 
 
 def register_model(arch: str, module: ModuleType) -> None:
@@ -74,6 +79,10 @@ def get_model(arch: str) -> ModuleType:
             from smg_tpu.models import exaone_moe
 
             _REGISTRY.setdefault("exaone_moe", exaone_moe)
+        elif arch == "longcat_flash":
+            from smg_tpu.models import longcat_flash
+
+            _REGISTRY.setdefault("longcat_flash", longcat_flash)
         else:
             raise KeyError(
                 f"unsupported model architecture: {arch!r} "

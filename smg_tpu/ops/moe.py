@@ -3,11 +3,19 @@
 One place for every model that routes tokens to experts
 (``models/pangu_moe.py``; ``models/llama.py``'s Qwen-MoE branch).  A process
 **holds** a contiguous range of the routed experts (all of them, or one chip's
-share of a deployment).  The layer scores every token over all the experts the
-router has, keeps the ``top_k`` largest, and computes, for the token-expert
-pairs that fall on held experts, what those experts give; pairs on experts
-held elsewhere add nothing here (their chips would add it, over an exchange
-this layer does not have: no code stands in for them).
+share of a deployment).  The layer scores every token over all the outputs the
+router has and keeps the ``top_k`` largest.  A pick is of one of three kinds:
+
+- on a **held** expert: the pairs are sorted by expert and go through the
+  grouped products (``expert_layer``);
+- on a real expert **held elsewhere**: it adds nothing here (its chip would
+  add it, over an exchange this layer does not have: no code stands in);
+- on an **identity** expert (``models/longcat_flash.py``: the router's last
+  outputs are experts that compute nothing, ``E_i(x) = x``): ``w_i x``, added
+  on the token's own chip with no exchange, as a shared expert is, because
+  every chip computes it alike for its own tokens (``identity_picks``).  No
+  chip holds such an expert and it is in no dispatch: to ``dispatch`` it is a
+  pick that is not on a held expert.
 
 Shapes follow the rows routed here: the pairs on held experts, sorted by
 expert, are the rows of three grouped matrix products (one group an expert).
@@ -30,10 +38,10 @@ import jax
 import jax.numpy as jnp
 
 # Rows a pass computes at most.  Small inputs take all their pairs at once;
-# a 4,096-token prefill (32,768 pairs at top 8) takes an eighth of them, twice
-# what an even routing sends to a sixteenth of the experts, so that the
-# gathered rows, the experts' hidden activations and their results stay near
-# 150 MB beside a cache that fills the chip.
+# a 4,096-token prefill (32,768 pairs at top 8; 49,152 at top 12) takes an
+# eighth of them, twice what an even routing sends to a sixteenth of the
+# experts, so that the gathered rows, the experts' hidden activations and
+# their results stay near 150 MB beside a cache that fills the chip.
 ROWS_ALL_AT_ONCE = 2048
 
 
@@ -88,6 +96,17 @@ def dispatch(experts, held: tuple[int, int]) -> Dispatch:
     bounds = jnp.searchsorted(flat[order], jnp.arange(count + 1, dtype=jnp.int32))
     sizes = jnp.diff(bounds).astype(jnp.int32)
     return Dispatch(order // k, place.reshape(T, k), sizes, bounds[count].astype(jnp.int32))
+
+
+@jax.named_scope("smg.moe.zero")
+def identity_picks(x, routing: Routing, first: int):
+    """What the identity experts give for ``x`` [T, E]: ``(sum of w_i over a
+    token's picks i >= first) x``, float32, and how many such picks there
+    were.  The router's outputs from ``first`` on are identity experts; a
+    padded token's picks are -1 and on none."""
+    on = routing.experts >= first
+    weight = jnp.sum(jnp.where(on, routing.weights, 0.0), axis=-1, keepdims=True)
+    return weight * x.astype(jnp.float32), jnp.sum(on).astype(jnp.int32)
 
 
 def grouped_matmul(rows, weights, group_sizes, impl: str, layer=None):

@@ -342,7 +342,7 @@ def test_schema_9_step_records_carry_the_account_in_every_runner(family):
     eng = make_engine(model=model)
     ring = drive(eng, [[5, 6, 7, 8 + i] for i in range(3)], n=8)
     dump = eng.dump_flight("test")
-    assert dump["schema_version"] == SCHEMA_VERSION == 10
+    assert dump["schema_version"] == SCHEMA_VERSION == 11
     assert PHASE_RECORD_KEYS <= STEP_RECORD_KEYS and len(PHASE_RECORD_KEYS) == 11
     moe = MOE_STEP_RECORD_KEYS if family in ("latent", "window") else frozenset()
     assert all(STEP_RECORD_KEYS <= set(r) <= STEP_RECORD_KEYS | moe for r in ring)

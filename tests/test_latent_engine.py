@@ -345,7 +345,7 @@ def test_the_step_record_carries_columns_run_and_the_frames_counts():
     (p,) = prompts(7, 20)
     eng.generate(prompt_ids=p, sampling=greedy(12))  # 11 decoded: a frame of 8, a frame of 3
     dump = eng.dump_flight("manual")
-    assert dump["schema_version"] == SCHEMA_VERSION == 10
+    assert dump["schema_version"] == SCHEMA_VERSION == 11
     ring = dump["ring"]
     assert all(STEP_RECORD_KEYS <= set(r) <= STEP_RECORD_KEYS | MOE_STEP_RECORD_KEYS
                for r in ring)

@@ -680,3 +680,97 @@ class TestSelfDraftingModelCompilesForV5e:
             s((4, slots, R, 1024)), s((4, slots, R, 1024)), s((), i32)).compile()
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < X.prefill_workspace_bytes(cfg, T, "bfloat16") < 2 * 2**30
+
+
+class TestDoubleBlockModelCompilesForV5e:
+    """``models/longcat_flash.py`` at the widths of the benchmark's cut
+    (``benchmark/configs/longcat-flash-chat.json``): two attention sublayers a
+    layer over 8 cache layers, the expert branch a shortcut round the second."""
+
+    @staticmethod
+    def cut():
+        import json
+        import os
+
+        from smg_tpu.models.config import ModelConfig
+
+        path = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "benchmark",
+                            "configs", "longcat-flash-chat.json")
+        own = {"assumed", "deployment", "chips", "serve_args", "rehearsal", "architecture",
+               "reduced", "published"}
+        with open(path) as f:
+            return ModelConfig.from_hf_config(
+                {k: v for k, v in json.load(f).items() if k not in own})
+
+    @staticmethod
+    def shapes(cfg, device):
+        from smg_tpu.models import longcat_flash as M
+
+        one = SingleDeviceSharding(device)
+        s = lambda shape, dtype=BF16: jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one)
+        params = jax.tree.map(
+            lambda x: s(x.shape, x.dtype),
+            jax.eval_shape(functools.partial(M.init_params, cfg), jax.random.PRNGKey(0)))
+        return s, params
+
+    @pytest.mark.parametrize("B", [8, 64])
+    def test_a_decode_frame_runs_its_kernels_and_copies_no_weights(self, v5e, B):
+        """One scanned body: the latent decode kernel twice (a sublayer each),
+        the three grouped products once, and no
+        weight moved into another layout (the two sublayers' matrices are two
+        stacks, so that none is sliced out of a pair)."""
+        from smg_tpu.models import longcat_flash as M
+        from smg_tpu.ops.latent_attention import land_side_buffer
+
+        cfg = self.cut()
+        assert (cfg.num_layers, cfg.num_cache_layers, cfg.num_heads) == (4, 8, 64)
+        s, params = self.shapes(cfg, v5e[0])
+        i32 = jnp.int32
+        L, mp, N, P, W = cfg.num_cache_layers, 512, 8, 20000, M.cache_lanes(cfg)
+
+        def frame(p, inv, tok, entry, kc, tables, n_steps):
+            holds = entry < mp * PS
+
+            def body(c):
+                j, cur, side, counts = c
+                logits, side, k = M.forward_decode_horizon(
+                    p, cfg, inv, cur, entry + j, entry, j, kc, tables, side, holds,
+                    attn_impl="pallas", moe_impl="pallas")
+                return j + 1, jnp.argmax(logits, -1).astype(i32), side, M.merge_counts(counts, k)
+
+            j, cur, side, counts = jax.lax.while_loop(
+                lambda c: c[0] < n_steps, body,
+                (i32(0), tok, jnp.zeros((L, B, N, W), kc.dtype),
+                 jnp.zeros((len(M.ROUTED_COUNTS),), i32)))
+            return cur, land_side_buffer(kc, side, tables, entry, jnp.arange(N)[None] < j), counts
+
+        compiled = jax.jit(frame, donate_argnums=(4,)).lower(
+            params, s((cfg.rope_dim // 2,), jnp.float32), s((B,), i32), s((B,), i32),
+            s((L, P, PS, W)), s((B, mp), i32), s((), i32)).compile()
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+        hlo = compiled.as_text()
+        assert _relayouts(hlo, 8 * 2**20) == []
+        calls = collections.Counter(
+            re.findall(r"%(smg\.[\w.]+?)\.\d+ = \S+ custom-call", hlo))
+        assert calls == {"smg.attn.decode": 2, "smg.moe.experts": 3}
+
+    def test_a_prefill_of_a_steps_budget_fits_its_workspace(self, v5e):
+        """4,096 tokens in one row, 12 picks a token through the rows' buffer
+        in passes: the program's temporaries inside what ``plan_latent_cache``
+        keeps free of pages; the branch's scopes are in the program's text."""
+        from smg_tpu.models import longcat_flash as M
+
+        cfg = self.cut()
+        s, params = self.shapes(cfg, v5e[0])
+        i32 = jnp.int32
+        T, mp, P, W = 4096, 512, 20000, M.cache_lanes(cfg)
+        compiled = jax.jit(
+            lambda p, inv, *a: M.forward_prefill(p, cfg, inv, *a, moe_impl="pallas"),
+            donate_argnums=(5,)).lower(
+            params, s((cfg.rope_dim // 2,), jnp.float32), s((T,), i32), s((), i32), s((), i32),
+            s((cfg.num_cache_layers, P, PS, W)), s((cfg.num_cache_layers, 0, PS, W)),
+            s((mp,), i32)).compile()
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < M.prefill_workspace_bytes(cfg, T, "bfloat16") < 2 * 2**30
+        hlo = compiled.as_text()
+        assert "smg.scmoe.shortcut" in hlo and "smg.moe.zero" in hlo

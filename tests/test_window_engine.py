@@ -287,7 +287,7 @@ def test_the_step_record_has_no_new_field():
     eng = make_engine()
     eng.generate(prompt_ids=prompts(11, 20)[0], sampling=greedy(10))
     dump = eng.dump_flight("manual")
-    assert dump["schema_version"] == SCHEMA_VERSION == 10
+    assert dump["schema_version"] == SCHEMA_VERSION == 11
     assert all(STEP_RECORD_KEYS <= set(s) <= STEP_RECORD_KEYS | MOE_STEP_RECORD_KEYS
                for s in dump["ring"])
 
@@ -464,7 +464,7 @@ def test_a_verify_frames_step_record_counts_tokens_beside_columns():
     e = make_engine(model=cfg, params=params, speculative=True)
     run_all(e, [(prompts(4, 16)[0], greedy(17))])
     dump = e.dump_flight("manual")
-    assert dump["schema_version"] == SCHEMA_VERSION == 10
+    assert dump["schema_version"] == SCHEMA_VERSION == 11
     frames = [s for s in dump["ring"] if s["columns_run"]]
     assert frames and all(set(s) >= STEP_RECORD_KEYS for s in frames)
     assert sum(s["decode_tokens"] for s in frames) == 16
