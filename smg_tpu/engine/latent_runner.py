@@ -24,7 +24,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from smg_tpu.engine.kv_cache import plan_latent_cache
-from smg_tpu.engine.runner import ModelRunner, logger
+from smg_tpu.engine.runner import FLASH_PREFILL_MIN_SCORE_BYTES, ModelRunner, logger
 from smg_tpu.ops.latent_attention import land_side_buffer
 
 
@@ -70,7 +70,9 @@ class LatentModelRunner(ModelRunner):
                 "top_k": cfg.num_experts_per_tok, "impl": self.moe_impl}
 
     def attention_info(self) -> dict:
-        return {**super().attention_info(), "form": "latent: expanded prefill, absorbed decode"}
+        return {**super().attention_info(),
+                "form": "latent: expanded prefill (XLA's score blocks, or the online-softmax "
+                        "kernel for cold groups: launches say which), absorbed decode"}
 
     # ``Scheduler._headroom_pages``: a decode frame may count on the radix
     # cache's unpinned pages.  At 64 lanes a frame of 8 columns needs some 32
@@ -87,12 +89,37 @@ class LatentModelRunner(ModelRunner):
         return self._attn_impl_for(0, 0) == "pallas"
 
     def _prefill_impl_for(self, T: int, mp: int) -> str:
-        """Prefill attention has one form here (expanded, XLA), so a prompt cut
-        by a step's budget continues at the grouped path's speed."""
+        """A solo chunk attends expanded in XLA's form over what its pages
+        hold (``latent_attention_prefill_cached``), as a grouped chunk behind
+        a prefix does: a prompt cut by a step's budget continues at that
+        path's speed."""
         return "xla"
 
     def _grouped_prefill_impl_for(self, G: int, T: int, no_ctx: bool) -> str:
-        return "xla"  # the same one form
+        """Attention of one grouped-prefill program of ``G`` rows in the
+        ``T``-token bucket, expanded either way.  Behind a prefix it is XLA's
+        over the pages.  Cold rows meet their own rebuilt keys and values:
+        in XLA's form (``latent_attention_prefill``: float32 scores ``[G, H,
+        qb, T]`` in query blocks) or in the online-softmax kernel
+        (``ops/pallas/flash_prefill.py``, the rotary key as the operand the
+        heads share), which is chosen where kernels run at all
+        (``_resolve_attn_impl``) and the heads' widths are whole 128-lane
+        tiles, from the size on at which XLA's scores leave the chip: the
+        Llama path's rule and its constant (``FLASH_PREFILL_MIN_SCORE_BYTES``),
+        which the sweep at these widths found again (128 and 64 heads of
+        192/128; ``PERF.md``, Findings, PR 44: at 32 MiB and under XLA's form
+        is twice as fast alone and 1 to 7 % faster in the launch whole, at 64
+        MiB the two are within 2.3 % of each other either way in the launch
+        whole, from 128 MiB on the kernel wins by 15 % of a launch to 3.3
+        times).  ``attention_impl='pallas'`` forces it at every size."""
+        cfg = self.model_cfg
+        if (not no_ctx or self.attn_impl == "xla" or cfg.qk_nope_head_dim % 128
+                or cfg.v_head_dim % 128):
+            return "xla"
+        if self.attn_impl != "auto":
+            return self.attn_impl
+        scores = G * T * cfg.num_heads * T * 4
+        return "pallas" if scores > FLASH_PREFILL_MIN_SCORE_BYTES else "xla"
 
     def _split_group(self, lengths: "list[int]") -> "list[list[int]]":
         """The rows go into parts of one octave each, the widest first, and a

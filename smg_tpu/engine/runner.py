@@ -58,7 +58,10 @@ PREFILL_KERNEL_MAX_T = 4096
 # at 1 x 1,024 tokens of 16 heads); from 120 MiB on they go through HBM and
 # the kernel wins by 1.8 to 12 times (0.26 against 1.15 ms at 1 x 2,048).
 # Nothing between the two sizes was timed, and no program of a model with 16
-# or 30 heads falls there.
+# or 30 heads falls there.  ``LatentModelRunner`` reads the same constant for
+# the expanded latent attention (128 and 64 heads): there the two forms are
+# within 2.3 % of each other in the launch whole at 64 MiB, XLA's wins under
+# it and the kernel from 128 MiB on (``PERF.md``, Findings, PR 44).
 FLASH_PREFILL_MIN_SCORE_BYTES = 96 * 2**20
 
 

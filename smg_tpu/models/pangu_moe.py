@@ -332,8 +332,10 @@ def _scale(cfg: ModelConfig) -> float:
 
 
 def _prefill(params, cfg, inv_freq, tokens, prefix_lens, t_reals, cache, page_tables,
-             no_ctx: bool, moe_impl: str, stack):
-    """Solo and grouped prefill: ``tokens`` [G, T], one row a sequence."""
+             no_ctx: bool, moe_impl: str, stack, attn_impl: str = "xla"):
+    """Solo and grouped prefill: ``tokens`` [G, T], one row a sequence.  Cold
+    rows (``no_ctx``) under ``attn_impl`` "pallas" meet their rebuilt keys and
+    values in the online-softmax kernel; every other chunk in XLA's forms."""
     G, T = tokens.shape
     rkv, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
     pos = prefix_lens[:, None] + jnp.arange(T)[None, :]
@@ -345,9 +347,20 @@ def _prefill(params, cfg, inv_freq, tokens, prefix_lens, t_reals, cache, page_ta
     def expanded(layer, q_nope, q_pe, ctx, pos, ctx_lens):
         """Rebuild K and V of the context entries ``ctx`` [g, S, W] and attend."""
         c, k_pe = ctx[..., :rkv], ctx[..., rkv:rkv + dr]
+        kernel = attn_impl.startswith("pallas")  # only where ``no_ctx`` calls this
+        # the weights are stored by head, so a product batched by head leaves
+        # the heads first; the kernel takes them so, XLA's form by token
+        out = "ghsd" if kernel else "gshd"
         with jax.named_scope("smg.mla.kv"):
-            k_nope = jnp.einsum("gsc,hcd->gshd", c, layer["w_uk"])
-            v = jnp.einsum("gsc,hcd->gshd", c, layer["w_uv"])
+            k_nope = jnp.einsum(f"gsc,hcd->{out}", c, layer["w_uk"])
+            v = jnp.einsum(f"gsc,hcd->{out}", c, layer["w_uv"])
+        if kernel:
+            from smg_tpu.ops.pallas.flash_prefill import flash_attention_prefill
+
+            # prefix 0: a row's context is its own ``t_real`` tokens
+            return flash_attention_prefill(
+                q_nope, k_nope, v, ctx_lens, scale, q_pe=q_pe, k_pe=k_pe, kv_heads_first=True,
+                interpret=(attn_impl == "pallas_interpret"))
         return latent_attention_prefill(q_nope, q_pe, k_nope, k_pe, v, pos, ctx_lens, scale)
 
     def attend(q_nope, q_pe, entry, layer, l, cache):
@@ -386,7 +399,7 @@ def forward_prefill(
     k_cache: jnp.ndarray,  # [L, P, ps, W]: the latent entries
     v_cache: jnp.ndarray,  # of zero size: this cache has no V buffer
     page_table: jnp.ndarray,  # [mp]
-    attn_impl: str = "xla",  # prefill attention has one form; kept for the runner
+    attn_impl: str = "xla",  # the solo chunk attends in XLA's form; kept for the runner
     moe_impl: str = "xla",  # "xla" | "pallas" | "pallas_interpret"
     stack=_stack,  # the layers: another model of latent attention gives its own
     **unserved,
@@ -412,7 +425,7 @@ def forward_prefill_batched(
     page_tables: jnp.ndarray,  # [G, mp]
     no_ctx: bool = False,  # static: every row starts its sequence
     moe_impl: str = "xla",
-    attn_impl: str = "xla",  # prefill attention has one form; kept for the runner
+    attn_impl: str = "xla",  # "pallas" | "pallas_interpret": the kernel, where ``no_ctx``
     stack=_stack,
     **unserved,
 ):
@@ -420,7 +433,8 @@ def forward_prefill_batched(
     k_cache, v_cache)."""
     _refuse(cfg, unserved)
     logits, k_cache = _prefill(params, cfg, inv_freq, tokens, prefix_lens, t_reals, k_cache,
-                               page_tables, no_ctx, moe_impl, stack)
+                               page_tables, no_ctx, moe_impl, stack,
+                               attn_impl if no_ctx else "xla")
     return logits, k_cache, v_cache
 
 

@@ -13,8 +13,13 @@ lanes a token, and the one buffer needs one DMA a page.
 Two forms, the same mathematics (``models/pangu_moe.py`` has the equations):
 
 - **expanded**, for prefill: keys and values of every head are rebuilt from
-  the entries of the context and the chunk's queries meet them in query blocks
-  (``latent_attention_prefill``);
+  the entries of the context and the chunk's queries meet them, in float32
+  score blocks a query block at a time here (``latent_attention_prefill``
+  for cold rows up to the size at which those blocks leave the chip, and for
+  every solo chunk; ``latent_attention_prefill_cached`` behind a prefix), or,
+  for a larger group of cold rows, in the online-softmax kernel
+  ``ops/pallas/flash_prefill.py`` (``LatentModelRunner.
+  _grouped_prefill_impl_for`` chooses);
 - **absorbed**, for decode: the up-projections are folded into the query and
   the output, so that all heads meet the entry itself: scores over all
   ``entry_lanes``, values the entry's first ``kv_lora_rank`` lanes.  That is

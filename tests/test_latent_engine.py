@@ -141,6 +141,39 @@ def test_a_runner_with_one_decode_program_a_bucket_gets_the_widest_table(monkeyp
     assert [sched._mp_bucket(n) for n in (1, 9, 99)] == [sched.mp] * 3 == [16] * 3
 
 
+@pytest.mark.parametrize("model", ["pangu", "longcat"])
+@pytest.mark.parametrize("lengths", [(40,), (30, 27)], ids=["a-group-of-1", "a-group-of-2"])
+def test_cold_groups_serve_the_same_tokens_through_the_prefill_kernel(model, lengths,
+                                                                      monkeypatch):
+    """Heads of the published widths (128 + 64 lanes of key, 128 of value), the
+    cold grouped prefill's attention through the online-softmax kernel
+    (interpreted: the CPU has no Mosaic) and through XLA's form: the same
+    tokens from the first on through ``Engine.submit`` and ``step``, the same
+    entries in the pages, and the launches counted under ``pallas_prefill``."""
+    import dataclasses
+
+    from smg_tpu.models.config import tiny_longcat_flash_config
+
+    tiny = tiny_pangu_moe_config(held=HELD) if model == "pangu" else tiny_longcat_flash_config(
+        held=(6, 6))
+    cfg = dataclasses.replace(tiny, num_heads=2, qk_nope_head_dim=128, qk_rope_head_dim=64,
+                              v_head_dim=128)
+    jobs = [(p, greedy(6)) for p in prompts(21, *lengths)]
+    xla, kernel = make_engine(model=cfg), make_engine(model=cfg)
+    assert kernel.runner._grouped_prefill_impl_for(2, 64, True) == "xla"  # off the TPU
+    monkeypatch.setattr(kernel.runner, "_grouped_prefill_impl_for",
+                        lambda G, T, no_ctx: "pallas_interpret" if no_ctx else "xla")
+    want, got = run_all(xla, jobs), run_all(kernel, jobs)
+    assert got == want and all(len(t) == 6 for t in got.values())
+    lx, lk = (e.loads()["attention"]["launches"] for e in (xla, kernel))
+    assert lx["pallas_prefill"] == 0 and lx["xla"] > 0
+    assert lk["pallas_prefill"] == 1  # the one cold group; decode stays XLA's here
+    shape = f"{len(lengths)}x{32 if len(lengths) > 1 else 64}"
+    assert kernel.loads()["prefill_padding"]["launches"] == {shape: 1}
+    np.testing.assert_allclose(np.asarray(kernel.runner.k_cache[:, 1:]),
+                               np.asarray(xla.runner.k_cache[:, 1:]), atol=1e-5)
+
+
 def test_a_radix_hit_on_a_latent_prefix_is_reused(engine):
     (p,) = prompts(3, 80)
     first = engine.generate(prompt_ids=p, sampling=greedy(8))

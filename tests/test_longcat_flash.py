@@ -166,6 +166,21 @@ def test_grouped_prefill_with_and_without_context_matches_the_solo_chunks(world)
     np.testing.assert_allclose(lg[1], lb, atol=2e-4)
 
 
+@pytest.mark.parametrize("t_reals", [[50], [64, 0, 23]], ids=["one-row", "three-rows-one-padded"])
+def test_cold_grouped_prefill_under_the_kernel_matches_xla_at_the_published_head_widths(t_reals):
+    """Both sublayers of a layer through the online-softmax kernel
+    (interpreted), keys of 128 + 64 lanes a head and values of 128: the same
+    logits and the same entries in all four cache layers as XLA's form."""
+    from tests.test_pangu_moe import _cold_group_under_both_attentions
+
+    W = World(dataclasses.replace(tiny_longcat_flash_config(held=(6, 6)), num_heads=2,
+                                  qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128))
+    lx, lp, kx, kp = _cold_group_under_both_attentions(M, W, t_reals)
+    np.testing.assert_allclose(lp, lx, rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(kp, kx, atol=1e-5)
+    assert rel_err(lp, lx) < 1e-4 and kx.shape[0] == 4 and np.std(lx) > 0.1
+
+
 def test_the_shares_of_all_chips_add_up_to_the_uncut_layer():
     """The guide's share test: a router of 24 real and 8 identity outputs, 4
     shares of 6 experts.  What each share's expert branch gives beyond the

@@ -18,7 +18,12 @@ from smg_tpu.engine.engine import Engine
 from smg_tpu.models.config import ModelConfig, tiny_olmo_hybrid_config
 from smg_tpu.models.registry import get_model
 from smg_tpu.ops.attention import SCORE_BLOCK_BYTES, attention_prefill_batched
-from smg_tpu.ops.pallas.flash_prefill import BLOCK_K, BLOCK_Q, flash_attention_prefill
+from smg_tpu.ops.pallas.flash_prefill import (
+    BLOCK_K,
+    BLOCK_Q,
+    _block,
+    flash_attention_prefill,
+)
 from smg_tpu.ops.rope import rope_frequencies
 from smg_tpu.protocols.sampling import SamplingParams
 from smg_tpu.tokenizer import MockTokenizer
@@ -90,6 +95,93 @@ def test_kernel_in_bfloat16_is_within_its_rounding():
     bfloat16 ones, so the two differ by the rounding of one output."""
     t_reals = [256, 100]
     got, want = _both(GQA, 256, t_reals, (128, 128), dtype=jnp.bfloat16)
+    _check(got, want, 256, t_reals, 128, 2e-2)
+
+
+# the latent models' per-head widths as published: keys of 128 lanes a head
+# and 64 rotary lanes that the heads of a row share, values of 128
+DN, DR, DV = 128, 64, 128
+
+
+def _latent_both(H, T, t_reals, blocks=(None, None), dtype=jnp.float32, concat=False,
+                 heads_first=True):
+    """(kernel, ``latent_attention_prefill``) on random rows of ``t_reals`` real
+    tokens; ``concat``: the rotary key written into every head's key, 256
+    lanes a head against values of 128, and no shared operand;
+    ``heads_first``: keys and values ``[G, H, T, d]``, as the models pass them."""
+    from smg_tpu.ops.latent_attention import latent_attention_prefill
+
+    G = len(t_reals)
+    ks = jax.random.split(jax.random.PRNGKey(T + H), 5)
+    q_nope = jax.random.normal(ks[0], (G, T, H, DN), dtype)
+    q_pe = jax.random.normal(ks[1], (G, T, H, DR), dtype)
+    k_nope = jax.random.normal(ks[2], (G, T, H, DN), dtype)
+    k_pe = jax.random.normal(ks[3], (G, T, DR), dtype)
+    v = jax.random.normal(ks[4], (G, T, H, DV), dtype)
+    t = jnp.asarray(t_reals, jnp.int32)
+    pos = jnp.broadcast_to(jnp.arange(T), (G, T))
+    scale = (DN + DR) ** -0.5
+    kw = dict(interpret=True, block_q=blocks[0], block_k=blocks[1])
+    if concat:
+        zeros = jnp.zeros((G, T, H, 256 - DN - DR), dtype)
+        keys = jnp.broadcast_to(k_pe[:, :, None], (G, T, H, DR))
+        got = flash_attention_prefill(jnp.concatenate([q_nope, q_pe, zeros], -1),
+                                      jnp.concatenate([k_nope, keys, zeros], -1), v, t, scale,
+                                      **kw)
+    elif heads_first:
+        got = flash_attention_prefill(q_nope, k_nope.swapaxes(1, 2), v.swapaxes(1, 2), t, scale,
+                                      q_pe=q_pe, k_pe=k_pe, kv_heads_first=True, **kw)
+    else:
+        got = flash_attention_prefill(q_nope, k_nope, v, t, scale, q_pe=q_pe, k_pe=k_pe, **kw)
+    want = latent_attention_prefill(q_nope, q_pe, k_nope, k_pe, v, pos, t, scale)
+    assert got.shape == want.shape == (G, T, H, DV)
+    return np.asarray(got, np.float32), np.asarray(want, np.float32)
+
+
+@pytest.mark.parametrize("H,T,t_reals,blocks", [
+    (2, 256, [256], (None, None)),
+    (3, 256, [256, 200, 1], (128, 64)),  # 200: inside a query block and inside a key block
+    (2, 256, [97, 0], (64, 128)),  # a padded row; key blocks wider than query blocks
+    (2, 64, [64, 33], (None, None)),  # a bucket below one block
+    (2, 1536, [1536], (None, None)),  # the half-octave rung: blocks of 512
+    (2, 1536, [1100, 0], (None, None)),
+    (4, 1024, [1023, 514], (None, None)),  # queries in 512s, keys in one step of 1,024
+], ids=["one-row", "g3-mid-blocks", "padded-row", "below-a-block", "1536-full",
+        "1536-ragged-padded-row", "1024-g2"])
+def test_kernel_with_a_shared_rotary_key_matches_the_latent_xla_form(H, T, t_reals, blocks):
+    """A key wider than its value, the rotary part once a row, keys and values
+    with the heads first: what ``ops.latent_attention.latent_attention_prefill``
+    computes."""
+    if T == 1536:
+        assert (_block(T, BLOCK_Q), _block(T, BLOCK_K)) == (512, 512)
+    got, want = _latent_both(H, T, t_reals, blocks)
+    _check(got, want, T, t_reals, blocks[0], 2e-5)
+
+
+@pytest.mark.parametrize("H,T,t_reals,blocks", [
+    (3, 256, [256, 200, 1], (128, 64)),
+    (2, 256, [97, 0], (64, 128)),
+    (2, 64, [64, 33], (None, None)),
+], ids=["g3-mid-blocks", "padded-row", "below-a-block"])
+def test_kernel_with_a_shared_rotary_key_takes_flat_keys_and_values_too(H, T, t_reals, blocks):
+    """The same with keys and values ``[G, T, H, d]``, as projections with the
+    heads fused leave them (``scripts/time_prefill_attention.py`` times both)."""
+    got, want = _latent_both(H, T, t_reals, blocks, heads_first=False)
+    _check(got, want, T, t_reals, blocks[0], 2e-5)
+
+
+def test_kernel_takes_keys_wider_than_values_without_a_shared_operand():
+    """The other way to feed it the latent shapes (``scripts/
+    time_prefill_attention.py --concat-keys`` times it): keys of 256 lanes a
+    head, values of 128."""
+    t_reals = [256, 130]
+    got, want = _latent_both(2, 256, t_reals, (128, 128), concat=True)
+    _check(got, want, 256, t_reals, 128, 2e-5)
+
+
+def test_latent_kernel_in_bfloat16_is_within_its_rounding():
+    t_reals = [256, 100]
+    got, want = _latent_both(2, 256, t_reals, (128, 128), dtype=jnp.bfloat16)
     _check(got, want, 256, t_reals, 128, 2e-2)
 
 

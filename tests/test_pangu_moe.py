@@ -123,6 +123,38 @@ def test_grouped_prefill_with_and_without_context_matches_the_solo_chunks(world)
     np.testing.assert_allclose(lg[1], lb, atol=2e-5)
 
 
+def _cold_group_under_both_attentions(module, W, t_reals, T=64):
+    """``forward_prefill_batched`` of cold rows under XLA's attention and under
+    the online-softmax kernel (interpreted): the logits of the real rows and
+    every page but the garbage page."""
+    G = len(t_reals)
+    rng = np.random.default_rng(7)
+    tokens = jnp.asarray(rng.integers(2, 512, (G, T)), jnp.int32)
+    tables = jnp.asarray(1 + np.arange(G * 4).reshape(G, 4), jnp.int32)
+    out = {}
+    for impl in ("xla", "pallas_interpret"):
+        kc, vc = W.cache()
+        out[impl] = jax.jit(lambda kc, vc, impl=impl: module.forward_prefill_batched(
+            W.params, W.cfg, W.inv, tokens, jnp.zeros(G, jnp.int32), jnp.asarray(t_reals), kc,
+            vc, tables, no_ctx=True, attn_impl=impl))(kc, vc)
+    real = np.asarray(t_reals) > 0
+    (lx, kx, _), (lp, kp, _) = out["xla"], out["pallas_interpret"]
+    return np.asarray(lx)[real], np.asarray(lp)[real], np.asarray(kx)[:, 1:], np.asarray(kp)[:, 1:]
+
+
+@pytest.mark.parametrize("t_reals", [[50], [64, 0, 23]], ids=["one-row", "three-rows-one-padded"])
+def test_cold_grouped_prefill_under_the_kernel_matches_xla_at_the_published_head_widths(t_reals):
+    """Keys of 128 + 64 lanes a head and values of 128, two heads: the same
+    logits and the same entries under either form of the expanded attention;
+    behind a prefix ``attn_impl`` changes nothing."""
+    W = World(dataclasses.replace(tiny_pangu_moe_config(held=(4, 8)), num_heads=2,
+                                  qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128))
+    lx, lp, kx, kp = _cold_group_under_both_attentions(M, W, t_reals)
+    np.testing.assert_allclose(lp, lx, rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(kp, kx, atol=1e-5)
+    assert rel_err(lp, lx) < 1e-4 and np.std(lx) > 0.1
+
+
 def test_absorbed_and_expanded_attention_agree_on_one_cache(world):
     """The last token of a prefill (expanded) and the same token decoded
     behind the others (absorbed) see the same cache and give the same logits."""

@@ -21,7 +21,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from smg_tpu.engine.config import EngineConfig, ParallelConfig
-from smg_tpu.engine.kv_cache import KvCacheSpec
+from smg_tpu.engine.config import CacheConfig
+from smg_tpu.engine.kv_cache import KvCacheSpec, plan_latent_cache
 from smg_tpu.engine.latent_runner import LatentModelRunner
 from smg_tpu.engine.runner import (
     FLASH_PREFILL_MIN_SCORE_BYTES,
@@ -31,7 +32,11 @@ from smg_tpu.engine.runner import (
 )
 from smg_tpu.engine.window_runner import WindowModelRunner
 from smg_tpu.engine.sampling import sample_tokens
-from smg_tpu.models.config import llama32_1b_config
+from smg_tpu.models.config import (
+    llama32_1b_config,
+    tiny_longcat_flash_config,
+    tiny_pangu_moe_config,
+)
 from smg_tpu.models.registry import get_model
 from smg_tpu.ops.attention import (
     SCORE_BLOCK_BYTES,
@@ -51,18 +56,35 @@ KD = CFG.num_kv_heads * CFG.head_dim
 BF16 = jnp.bfloat16
 
 
-def _rule(platform, mesh=None, attention_impl="auto", model=CFG) -> ModelRunner:
+def _rule(platform, mesh=None, attention_impl="auto", model=CFG, cls=ModelRunner) -> ModelRunner:
     """A runner with just the state the dispatch rule reads."""
-    r = object.__new__(ModelRunner)
+    r = object.__new__(cls)
     r.config = EngineConfig(model=model, attention_impl=attention_impl)
     r.model_cfg = model
     r.platform = platform
     r.mesh = mesh
     r.use_pp = False
-    r.spec = KvCacheSpec(model.num_layers, 64, PS, model.num_kv_heads,
-                         model.head_dim, "bfloat16")
+    r.spec = (plan_latent_cache(model, CacheConfig(dtype="bfloat16")) if model.latent_cache
+              else KvCacheSpec(model.num_layers, 64, PS, model.num_kv_heads, model.head_dim,
+                               "bfloat16"))
     r.attn_impl = r._resolve_attn_impl()
     return r
+
+
+# heads of the two latent configurations the benchmark serves, at the per-head
+# widths both publish: keys of 128 + 64 lanes, values of 128
+LATENT_HEADS = {"openpangu-ultra-moe-718b": 128, "longcat-flash-chat": 64}
+
+
+def _latent_rule(platform, model="openpangu-ultra-moe-718b", attention_impl="auto",
+                 **widths) -> LatentModelRunner:
+    """A latent runner with just the state its dispatch rule reads."""
+    tiny = (tiny_longcat_flash_config(held=(6, 6)) if model == "longcat-flash-chat"
+            else tiny_pangu_moe_config(held=(4, 8)))
+    cfg = dataclasses.replace(tiny, **{
+        "num_heads": LATENT_HEADS[model], "kv_lora_rank": 512, "qk_nope_head_dim": 128,
+        "qk_rope_head_dim": 64, "v_head_dim": 128, **widths})
+    return _rule(platform, attention_impl=attention_impl, model=cfg, cls=LatentModelRunner)
 
 
 class TestDispatchRule:
@@ -143,8 +165,56 @@ class TestDispatchRule:
         forced = _rule("tpu", attention_impl="pallas", model=cfg)
         assert forced._grouped_prefill_impl_for(1, 256, True) == "pallas"
         assert forced._grouped_prefill_impl_for(1, 256, False) == "xla"
-        for cls in (LatentModelRunner, WindowModelRunner):
-            assert cls._grouped_prefill_impl_for(object.__new__(cls), *big) == "xla"
+        assert WindowModelRunner._grouped_prefill_impl_for(
+            object.__new__(WindowModelRunner), *big) == "xla"
+
+    @pytest.mark.parametrize("model", list(LATENT_HEADS))
+    def test_latent_cold_grouped_prefill_takes_the_kernel_from_its_size_on(self, model):
+        """The latent runner's rule at 128 and at 64 heads: the online-softmax
+        kernel for a group of cold rows from the size on at which the float32
+        scores of XLA's expanded form, ``G x T x H x T``, leave the chip: the
+        Llama path's constant (PERF.md, PR 44: in the launch whole XLA's form
+        1 to 7 % faster at 32 MiB and under, the two within 2.3 % of each
+        other at 64 MiB, the kernel 15 % of a launch to 3.3 times faster from
+        128 MiB on)."""
+        heads = LATENT_HEADS[model]
+        r = _latent_rule("tpu", model)
+        assert r.attn_impl == "auto" and r.model_cfg.num_heads == heads
+        for G in (1, 2, 4):
+            for T in (128, 256, 512, 1024, 1536, 2048, 4096):
+                want = ("pallas" if G * T * heads * T * 4 > FLASH_PREFILL_MIN_SCORE_BYTES
+                        else "xla")
+                assert r._grouped_prefill_impl_for(G, T, True) == want, (G, T)
+                assert r._grouped_prefill_impl_for(G, T, False) == "xla"  # behind a prefix
+        # what the sweep timed on either side of the constant
+        xla = {(G, T) for G in (1, 2) for T in (256, 512, 1024, 1536, 2048)
+               if r._grouped_prefill_impl_for(G, T, True) == "xla"}
+        assert xla == {"openpangu-ultra-moe-718b": {(1, 256), (2, 256)},
+                       "longcat-flash-chat": {(1, 256), (2, 256), (1, 512)}}[model]
+        assert _attn_label("prefill", r._grouped_prefill_impl_for(1, 1536, True)) == (
+            "pallas_prefill")
+        assert _attn_label("prefill", r._grouped_prefill_impl_for(1, 256, True)) == "xla"
+
+    @pytest.mark.parametrize("model", list(LATENT_HEADS))
+    @pytest.mark.parametrize("G,T", [(1, 512), (2, 2048), (1, 4096)])
+    def test_latent_cold_grouped_prefill_stays_on_xla_where_the_kernel_cannot_serve(
+            self, model, G, T):
+        """Off the TPU, under ``attention_impl='xla'``, behind a prefix and at
+        head widths that are not whole 128-lane tiles; forced, at every size
+        for cold rows and never behind a prefix."""
+        assert _latent_rule("cpu", model)._grouped_prefill_impl_for(G, T, True) == "xla"
+        assert _latent_rule("tpu", model, attention_impl="xla")._grouped_prefill_impl_for(
+            G, T, True) == "xla"
+        for widths in ({"qk_nope_head_dim": 64}, {"v_head_dim": 64},
+                       {"qk_nope_head_dim": 192, "v_head_dim": 192}):
+            for impl in ("auto", "pallas"):
+                assert _latent_rule("tpu", model, attention_impl=impl, **widths
+                                    )._grouped_prefill_impl_for(G, T, True) == "xla", widths
+        forced = _latent_rule("tpu", model, attention_impl="pallas")
+        assert forced._grouped_prefill_impl_for(1, 128, True) == "pallas"
+        assert forced._grouped_prefill_impl_for(G, T, False) == "xla"
+        # a solo chunk attends over its pages in XLA's form whatever the mode
+        assert forced._prefill_impl_for(T, 512) == "xla"
 
     def test_kernel_never_above_its_bound_even_when_forced(self):
         r = _rule("tpu", attention_impl="pallas")
@@ -286,6 +356,33 @@ class TestCompilesForV5e:
         assert len(calls) == 1 and "%smg.attn.prefill" in calls[0], calls
         assert "tpu_custom_call" in calls[0]
         assert compiled.memory_analysis().temp_size_in_bytes < T * T * 4
+
+    @pytest.mark.parametrize("G,T", [(1, 512), (1, 1536), (2, 2048), (1, 4096)])
+    @pytest.mark.parametrize("H", [128, 64], ids=["openpangu", "longcat"])
+    def test_flash_prefill_kernel_at_the_latent_cells_widths(self, v5e, G, T, H):
+        """The same kernel at the published widths of the two latent
+        configurations: keys of 128 lanes a head and 64 rotary lanes that the
+        heads of a row share (the operand of its own), values of 128, keys and
+        values with the heads first as the models' up-projections leave them;
+        from the smallest program the rule sends it to the widest a launch
+        can be."""
+        s = self._sds(v5e)
+        dn, dr, dv = 128, 64, 128
+
+        def attend(q, k, v, t_reals, q_pe, k_pe):
+            out = flash_attention_prefill(
+                q.reshape(G, T, H, dn), k, v, t_reals, scale=0.072,
+                q_pe=q_pe.reshape(G, T, H, dr), k_pe=k_pe, kv_heads_first=True)
+            return out.reshape(G, T, H * dv)
+
+        compiled = _compile(attend, s((G, T, H * dn)), s((G, H, T, dn)), s((G, H, T, dv)),
+                            s((G,), jnp.int32), s((G, T, H * dr)), s((G, T, dr)))
+        calls = [line for line in compiled.as_text().splitlines() if "custom-call(" in line]
+        assert len(calls) == 1 and "%smg.attn.prefill" in calls[0], calls
+        assert "tpu_custom_call" in calls[0]
+        # the rotary queries padded to whole tiles are the one temporary: no score block
+        assert compiled.memory_analysis().temp_size_in_bytes < min(
+            T * T * H * 4, 2 * G * T * H * 128 * 2 + 2**20)
 
     @pytest.mark.parametrize("B,V", [(16, 151936), (1, 151936), (16, 100352)],
                              ids=["qwen_decode", "qwen_first_token", "olmo_hybrid_decode"])
