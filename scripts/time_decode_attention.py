@@ -21,6 +21,9 @@ layers of one cache at a cell's widths:
   batch 16, 32 and 64 behind 64, 128 and 256 pages.  The XLA form is
   ``attention_decode_cached`` with one head as wide as the entry and the cache
   as its own V, the kernel ``latent_attention_decode_cached``;
+- ``longcat-flash-chat`` (its ``reason`` cell): 8 cache layers (two sublayers
+  a layer) of the same 640-lane entries under 64 absorbed heads; batch 64
+  behind 64, 128 and 256 pages;
 - ``mimo-v2-flash`` (the ``mixed`` cell's 2 full layers): 64/4 heads, keys of
   192 and values of 128 (K pages of 768 lanes, V of 512); batch 16, 32 and
   64 behind 128, 256 and 512 pages;
@@ -32,7 +35,9 @@ layers of one cache at a cell's widths:
 lanes filled to a quarter, a half and all of the table.  Prints one JSON line
 per shape: milliseconds a layer for each (XLA once a shape: it reads the
 whole table whatever it holds), the kernel's held bytes over its time
-(GB/s), the largest difference between the two outputs on random data, and
+(GB/s) and its microseconds a block of its own size (a lane's fixed part
+spread over its blocks: the fit below parts the two), the largest
+difference between the two outputs on random data, and
 per (batch, table) the line through the kernel's three fills: a fixed cost
 a layer and a rate.  The dispatch rule is read off the half-full column.
 Refuses to run without a TPU: a CPU time is not a device time
@@ -57,6 +62,8 @@ import numpy as np  # noqa: E402
 
 from smg_tpu.ops.attention import attention_decode_cached  # noqa: E402
 from smg_tpu.ops.pallas.decode_attention import (  # noqa: E402
+    _latent_pages_per_block,
+    _pages_per_block,
     latent_attention_decode_cached,
     paged_attention_decode_cached,
 )
@@ -77,6 +84,7 @@ MODELS = {
     "openpangu-ultra-moe-718b": (5, 20000, 128, 0, 640,
                                  [(16, (64, 128, 256)), (32, (64, 128, 256)),
                                   (64, (64, 128, 256))]),
+    "longcat-flash-chat": (8, 20000, 64, 0, 640, [(64, (64, 128, 256))]),
 }
 # the window layers' form: layers, slots, ring entries, heads, kv heads, head
 # dims of keys and values, window, batches
@@ -84,7 +92,8 @@ WINDOW_MODELS = {"mimo-v2-flash:window": (5, 73, 144, 64, 8, 192, 128, 128, (16,
 WINDOW_REHEARSAL = {"toy:window": (2, 5, 32, 16, 8, 64, 32, 8, (2, 8))}
 REHEARSAL = {"toy": (2, 40, 4, 2, 64, [(2, (4, 8)), (8, (8,))]),
              "toy-narrow-v": (2, 40, 8, 4, 64, [(2, (4, 8))], 32),
-             "toy-latent": (2, 40, 4, 0, 256, [(2, (4, 8))])}
+             "toy-latent": (2, 40, 4, 0, 256, [(2, (4, 8))]),
+             "toy-latent-8-layers": (8, 40, 8, 0, 256, [(2, (8,))])}
 LATENT_VALUE_LANES = {640: 512, 256: 128}  # entry lanes -> lanes of its value
 
 
@@ -240,6 +249,10 @@ def main() -> int:
                        for name, attend in forms.items()}
                 xla_ms = None
                 fit = []
+                # the pages of one of the kernel's blocks
+                block_pages = args.pages_per_block or (
+                    _latent_pages_per_block(PS, mp) if latent
+                    else _pages_per_block(PS, max(kd, vd), 2, mp))
                 for fill in FILLS:
                     held = int(fill * mp * PS) - N
                     entry = jnp.full((B,), held, jnp.int32)
@@ -255,6 +268,8 @@ def main() -> int:
                     row = {"model": model, "B": B, "mp": mp, "fill": fill,
                            "xla_ms_per_layer": xla_ms, "pallas_ms_per_layer": ms,
                            "pallas_held_gb_per_s": held_bytes / ms / 1e6,
+                           "pallas_us_per_block": ms * 1e3 * block_pages / (B * -(-held // PS)),
+                           "pallas_block_bytes": block_pages * page_bytes,
                            "max_abs_diff": diff,
                            "pages_per_block": args.pages_per_block,
                            "device_kind": dev.device_kind,

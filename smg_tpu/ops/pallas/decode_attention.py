@@ -25,12 +25,46 @@ next block's are in flight while the current block is multiplied, and a
 lane's last block starts the next lane's first, so the kernel waits for HBM
 with nothing behind it only at the very first block of the call.  A lane's
 last block fetches the pages it has and masks the rest by ``entry``.  The
-page loops are loops, not unrolled code: timed on a v5e the two run alike
-(as do one wait a block and one a page), and a decode program is traced in
-a third of the time (``PERF.md`` section 6, PR 30).  Per block there is one score product
+page loops are loops, not unrolled code: timed on a v5e at ``eval``'s and
+``gen``'s shapes (16-30 heads on 1,024-3,840 lanes, a block of 1 MB whose
+bytes are the bound) the two run alike, as do one wait a block and one a
+page, and a decode program is traced in a third of the time (``PERF.md``
+section 6, PR 30).  Per block there is one score product
 ``[H, K*D] x [K*D, block tokens]``, one softmax update and one rescale of the
 ``[H, K*D]`` accumulator; the side rows are scored first, while the first
 block is on its way, and seed the running maximum and sum.
+
+The latent cache has a body of its own (``_latent_decode_kernel``), because
+there the loops do not run alike.  64-128 heads meet one buffer of 640 lanes:
+a block of 256 entries is 327,680 B, 0.4 us of the memory's time, and the
+paged body spent 1.3 us on it: 0.68 us with the products taken out, 0.62 us
+with the fetches taken out, the two one after the other, since a loop over a
+count is a region of its own that nothing else is scheduled into (40 bundles
+a page to start it, 9 to wait for it; ``PERF.md`` section 6, PR 45).  So the
+latent body
+(a) starts a block's pages with one predicated copy a page and no loop,
+straight-line code in the block of the loop that holds the products, with
+whether anything follows folded into the page count;
+(b) waits once for a full block: the semaphore counts bytes, so one wait a
+set bit of the block's page count takes a short block too;
+(c) takes ``LATENT_BLOCK_TOKENS`` (512) entries a block, 655,360 B a slot, so
+that every per-block part halves;
+(d) reads the tables as one row and the cache as ``[L * P, ps, W]``, a page a
+row of the leading axis, which is a third less address arithmetic a copy,
+and leaves the copies' bounds checks out (13 of a start's 27 scalar
+operations);
+(e) starts a lane's second block at the lane's top, into the slot the lane
+before has left, so that its copies are issued beside the side rows'
+products and HBM works through a lane's fixed part (the loop then starts
+this lane's blocks from the third on, and the next lane's first);
+(f) zeroes its buffer once in a loop over a count, where a ``pl.when`` round
+the stores became predicated stores in every lane's code.
+The operand roles are the paged body's: a block of the cache is the MXU's
+stationary operand in both products; the block as the streamed operand
+(``s^T = keys . q^T``) was timed and is slower at 64 and at 128 heads (the
+second product then wants the values transposed).  What the interpreter
+cannot see of this loop: a block started twice leaves its semaphore above
+zero, which only the chip refuses, at the kernel's exit.
 
 Operands are the XLA form's (``ops.attention._attend_cache_and_side``): K, V
 and the query in the cache's dtype on the MXU with float32 accumulation, the
@@ -62,6 +96,7 @@ from smg_tpu.ops.attention import block_diagonal_query, own_lanes
 NEG_INF = -1e30
 BLOCK_TOKENS = 256  # tokens a compute step, where the buffers allow
 BLOCK_BUFFER_BYTES = 2**20  # one of the four (K, V) x (two slots) buffers
+LATENT_BLOCK_TOKENS = 512  # the latent kernel's block: 32 pages of 20,480 B, 655,360 B a slot
 
 
 def _pages_per_block(ps: int, lanes: int, itemsize: int, mp: int) -> int:
@@ -70,6 +105,34 @@ def _pages_per_block(ps: int, lanes: int, itemsize: int, mp: int) -> int:
     ``BLOCK_BUFFER_BYTES``, never more than the table has."""
     by_bytes = BLOCK_BUFFER_BYTES // (ps * lanes * itemsize)
     return max(1, min(BLOCK_TOKENS // ps, by_bytes, mp))
+
+
+_div = jax.lax.div  # of non-negative ints (``//`` lowers through sign())
+
+
+def _lane_pages(entry_pos_ref, lane, *, mp: int, ps: int, n: int, n_extra=None, window=None):
+    """(entry, lo, first live page, pages held, blocks) of a lane.  The
+    cache holds tokens 0..entry-1; a padded row (``entry`` at or past the
+    table's capacity) holds none.  Sliding window: the query sits at
+    ``entry + n_extra - 1`` and keys below ``lo`` are outside it, so
+    whole pages below it are SKIPPED: blocks are counted from the
+    window's first live page, which is the point of sliding-window
+    attention at long contexts (Mistral W=4096).  Without a window
+    (``None``: the latent cache has none) ``lo`` and ``first`` are 0."""
+    entry = entry_pos_ref[lane]
+    n_pages = jnp.where(entry >= mp * ps, 0, _div(entry + ps - 1, ps))
+    if window is None:
+        return entry, 0, 0, n_pages, _div(n_pages + n - 1, n)
+    q_pos = entry + n_extra - 1
+    lo = jnp.where(window > 0, jnp.maximum(q_pos - window + 1, 0), 0)
+    first = jnp.minimum(_div(lo, ps), n_pages)
+    return entry, lo, first, n_pages, _div(n_pages - first + n - 1, n)
+
+
+def _latent_pages_per_block(ps: int, mp: int) -> int:
+    """Pages a compute step of the latent kernel: ``LATENT_BLOCK_TOKENS``
+    entries, never more than the table has."""
+    return max(1, min(LATENT_BLOCK_TOKENS // ps, mp))
 
 
 def _decode_kernel(
@@ -82,33 +145,25 @@ def _decode_kernel(
     n: int,  # pages a block
     scale: float,
     softcap: float,
-    latent: int = 0,  # > 0: one latent buffer, whose first ``latent`` lanes are the values
     rows: int = 1,  # > 1: a verify column, ``rows`` query rows a lane (``held_ref`` first)
 ):
     if rows > 1:
         # [B] int32 (SMEM): side rows a lane holds before this column's; row w
         # of the lane sees ``held + w + 1`` side rows
         held_ref, *refs = refs
-    if latent:
-        # a latent cache has no V: side rows, pages and block buffers are the
-        # keys', and a value is the first ``latent`` lanes of its key
-        q_ref, hk_ref, k_hbm, out_ref, k_buf, acc_ref, slot_ref, sems = refs
-        hv_ref = v_hbm = v_buf = None
-    else:
-        (q_ref,  # [1, H, KD] VMEM (block-diagonal query for this sequence)
-         hk_ref,  # [1, N, KD] VMEM (horizon side buffer, rows 0..n_extra-1 valid)
-         hv_ref,  # [1, N, VD] VMEM (VD: V's lanes, KD unless values are narrower than keys)
-         k_hbm,  # [L, P*ps, KD] HBM (read-only cache)
-         v_hbm,
-         out_ref,  # [1, H, VD] VMEM
-         k_buf,  # [2, n*ps, KD] VMEM: two slots of one block each
-         v_buf,
-         acc_ref,  # [H, VD] f32
-         slot_ref,  # [1] int32 SMEM: the slot this lane's first block is in
-         sems,  # DMA sems [2 (K, V), 2 slots]
-         ) = refs
-    streams = (((k_hbm, k_buf, 0),) if latent
-               else ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)))
+    (q_ref,  # [1, H, KD] VMEM (block-diagonal query for this sequence)
+     hk_ref,  # [1, N, KD] VMEM (horizon side buffer, rows 0..n_extra-1 valid)
+     hv_ref,  # [1, N, VD] VMEM (VD: V's lanes, KD unless values are narrower than keys)
+     k_hbm,  # [L, P*ps, KD] HBM (read-only cache)
+     v_hbm,
+     out_ref,  # [1, H, VD] VMEM
+     k_buf,  # [2, n*ps, KD] VMEM: two slots of one block each
+     v_buf,
+     acc_ref,  # [H, VD] f32
+     slot_ref,  # [1] int32 SMEM: the slot this lane's first block is in
+     sems,  # DMA sems [2 (K, V), 2 slots]
+     ) = refs
+    streams = ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1))
     b = pl.program_id(0)
     B = pl.num_programs(0)
     H = q_ref.shape[1]
@@ -118,23 +173,8 @@ def _decode_kernel(
     n_extra = held_ref[b] + 1 if rows > 1 else meta_ref[0]
     layer = meta_ref[1]
     window = meta_ref[2]  # a verify column has none (``paged_attention_verify_cached``)
-
-    div = jax.lax.div  # of non-negative ints (``//`` lowers through sign())
-
-    def lane_pages(lane):
-        """(entry, lo, first live page, pages held, blocks) of a lane.  The
-        cache holds tokens 0..entry-1; a padded row (``entry`` at or past the
-        table's capacity) holds none.  Sliding window: the query sits at
-        ``entry + n_extra - 1`` and keys below ``lo`` are outside it, so
-        whole pages below it are SKIPPED: blocks are counted from the
-        window's first live page, which is the point of sliding-window
-        attention at long contexts (Mistral W=4096)."""
-        entry = entry_pos_ref[lane]
-        n_pages = jnp.where(entry >= mp * ps, 0, div(entry + ps - 1, ps))
-        q_pos = entry + n_extra - 1
-        lo = jnp.where(window > 0, jnp.maximum(q_pos - window + 1, 0), 0)
-        first = jnp.minimum(div(lo, ps), n_pages)
-        return entry, lo, first, n_pages, div(n_pages - first + n - 1, n)
+    lane_pages = functools.partial(_lane_pages, entry_pos_ref, mp=mp, ps=ps, n=n,
+                                   n_extra=n_extra, window=window)
 
     entry, lo, first, n_pages, blocks = lane_pages(b)
     nxt = jnp.minimum(b + 1, B - 1)
@@ -170,8 +210,7 @@ def _decode_kernel(
         # there, and before the first block that is whatever VMEM held: a
         # masked probability of 0 times a NaN is a NaN (a masked score is
         # replaced, so K needs no such care)
-        vals = k_buf if latent else v_buf
-        vals[...] = jnp.zeros_like(vals)
+        v_buf[...] = jnp.zeros_like(v_buf)
 
     # the slot this lane's first block is in; lane 0 starts its own, and a
     # lane without blocks hands the next lane's first block on at once
@@ -204,13 +243,12 @@ def _decode_kernel(
     col = jax.lax.broadcasted_iota(jnp.int32, (H, N), 1)
     seen = n_extra
     if rows > 1:  # each row its own count: H is rows x heads here
-        seen = n_extra + div(jax.lax.broadcasted_iota(jnp.int32, (H, N), 0), H // rows)
+        seen = n_extra + _div(jax.lax.broadcasted_iota(jnp.int32, (H, N), 0), H // rows)
     s_side = scores_of(hk_ref[0], jnp.where(col < seen, entry + col, -1))
     m0 = jnp.max(s_side, axis=1, keepdims=True)
     p_side = jnp.exp(s_side - m0)
     l0 = jnp.sum(p_side, axis=1, keepdims=True)
-    values = (lambda ref, i: ref[i][:, :latent]) if latent else (lambda ref, i: ref[i])
-    acc_ref[...] = weigh(p_side, values(hk_ref if latent else hv_ref, 0))
+    acc_ref[...] = weigh(p_side, hv_ref[0])
 
     def body(j, carry):
         m_prev, l_prev = carry
@@ -228,7 +266,7 @@ def _decode_kernel(
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
-        acc_ref[...] = acc_ref[...] * alpha + weigh(p, values(k_buf if latent else v_buf, slot))
+        acc_ref[...] = acc_ref[...] * alpha + weigh(p, v_buf[slot])
         return m_new, l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
 
     _, l = jax.lax.fori_loop(0, blocks, body, (m0, l0))
@@ -321,6 +359,140 @@ def paged_attention_decode_cached(
     return own_lanes(out_kd, K).astype(q.dtype)
 
 
+def _latent_decode_kernel(
+    # scalar prefetch
+    page_tables_ref,  # [B * mp] int32 (SMEM): the tables, one row after another
+    entry_pos_ref,  # [B] int32 (SMEM)
+    meta_ref,  # [2] int32 (SMEM): [n_extra, layer]
+    q_ref,  # [1, H, W] VMEM: the absorbed queries on the entry's lanes
+    side_ref,  # [1, N, W] VMEM
+    cache_hbm,  # [L * P, ps, W] HBM: a page is one row of the leading axis
+    out_ref,  # [1, H, latent] VMEM
+    buf,  # [2, n * ps, W] VMEM: two slots of one block each
+    acc_ref,  # [H, latent] f32
+    slot_ref,  # [1] int32 SMEM: the slot this lane's first block is in
+    sems,  # DMA sems [2 slots]
+    *,
+    ps: int,
+    n: int,  # pages a block
+    mp: int,
+    pages: int,  # P: pages a layer
+    scale: float,
+    latent: int,
+):
+    """The latent cache's own body: one buffer whose entries are keys and,
+    in their first ``latent`` lanes, values.  64-128 heads meet 640 lanes, so a
+    block's bytes no longer hide what the loop does beside them, and the loop
+    is built round that (module docstring)."""
+    b = pl.program_id(0)
+    B = pl.num_programs(0)
+    H = q_ref.shape[1]
+    N = side_ref.shape[1]
+    S = n * ps
+    n_extra = meta_ref[0]
+    layer_row = meta_ref[1] * pages
+    lane_pages = functools.partial(_lane_pages, entry_pos_ref, mp=mp, ps=ps, n=n)
+
+    entry, _, _, n_pages, blocks = lane_pages(b)
+    nxt = jnp.minimum(b + 1, B - 1)
+    _, _, _, next_pages, next_blocks = lane_pages(nxt)
+    next_has_blocks = (b + 1 < B) & (next_blocks > 0)
+
+    def start(lane, page0, held, slot):
+        """Start the pages ``page0 .. min(page0 + n, held) - 1`` of a lane
+        into a slot: one predicated copy a page and no loop, so that they
+        are straight-line code (a loop over a count is a region of its own,
+        40 bundles a page with nothing else in them)."""
+        for i in range(n):
+            @pl.when(page0 + i < held)
+            def _():
+                page = page_tables_ref[lane * mp + page0 + i]
+                pltpu.make_async_copy(cache_hbm.at[layer_row + page],
+                                      buf.at[slot, pl.ds(i * ps, ps)], sems.at[slot]).start()
+
+    def wait(count, slot):
+        """Wait for the ``count`` pages of a slot's block.  The semaphore
+        counts bytes, so a descriptor of k pages waits for any k of them: one
+        wait a set bit of ``count``, one in all for a full block."""
+        for bit in range(n.bit_length()):
+            k = 1 << bit
+
+            @pl.when((count & k) != 0)
+            def _():
+                rows = pl.ds(0, k * ps)
+                pltpu.make_async_copy(buf.at[1 - slot, rows], buf.at[slot, rows],
+                                      sems.at[slot]).wait()
+
+    # rows of a slot past a short block keep what the block before left
+    # there, and before the first block that is whatever VMEM held: a masked
+    # probability of 0 times a NaN is a NaN.  A loop over a count, because a
+    # ``pl.when`` round plain stores becomes predicated stores, which every
+    # lane would pay for
+    def zero_page(i, _):
+        rows = pl.ds(pl.multiple_of((i % n) * ps, ps), ps)
+        buf[i // n, rows] = jnp.zeros((ps, buf.shape[2]), buf.dtype)
+        return 0
+
+    jax.lax.fori_loop(0, jnp.where(b == 0, 2 * n, 0), zero_page, 0)
+
+    # the slot this lane's first block is in; lane 0 starts its own, and a
+    # lane without blocks hands the next lane's first block on at once
+    slot0 = jnp.where(b == 0, 0, slot_ref[0])
+    own_first = (b == 0) & (blocks > 0)
+
+    @pl.when(own_first | ((blocks == 0) & next_has_blocks))
+    def _start():
+        start(jnp.where(own_first, b, nxt), 0, jnp.where(own_first, n_pages, next_pages), slot0)
+
+    # this lane's second block goes into the other slot now (the lane before
+    # is done with it), so that its copies are started beside the side rows'
+    # products and HBM works while a lane's fixed part runs
+    start(b, n, jnp.where(blocks > 1, n_pages, 0), 1 - slot0)
+
+    def scores_of(keys, valid):
+        s = jax.lax.dot_general(q_ref[0], keys, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        return jnp.where(valid, s, NEG_INF)
+
+    def weigh(p, vals):
+        return jax.lax.dot_general(p.astype(vals.dtype), vals, (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+
+    # the side rows first (the current token's own row is among them, so the
+    # maximum is finite from here on), while the first two blocks are on their way
+    col = jax.lax.broadcasted_iota(jnp.int32, (H, N), 1)
+    s_side = scores_of(side_ref[0], col < n_extra)
+    m0 = jnp.max(s_side, axis=1, keepdims=True)
+    p_side = jnp.exp(s_side - m0)
+    l0 = jnp.sum(p_side, axis=1, keepdims=True)
+    acc_ref[...] = weigh(p_side, side_ref[0, :, :latent])
+
+    def body(j, carry):
+        m_prev, l_prev = carry
+        slot = (slot0 + j) & 1
+        more = j + 1 < blocks  # else the next lane's first block
+        # block j + 1 of this lane from its third on (the second was started
+        # above), or the next lane's first.  Whether there is one is folded
+        # into the count, not put round the starts: a branch is a region of
+        # its own
+        held = jnp.where(more, jnp.where(j > 0, n_pages, 0),
+                         jnp.where(next_has_blocks, next_pages, 0))
+        start(jnp.where(more, b, nxt), jnp.where(more, (j + 1) * n, 0), held, 1 - slot)
+        wait(jnp.minimum(n_pages - j * n, n), slot)
+        pos = j * S + jax.lax.broadcasted_iota(jnp.int32, (H, S), 1)
+        s = scores_of(buf[slot], pos < entry)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        acc_ref[...] = acc_ref[...] * alpha + weigh(p, buf[slot, :, :latent])
+        return m_new, l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+
+    _, l = jax.lax.fori_loop(0, blocks, body, (m0, l0))
+
+    slot_ref[0] = (slot0 + blocks) & 1
+    out_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-20)).astype(out_ref.dtype)
+
+
 @functools.partial(jax.jit, static_argnames=("latent", "scale", "interpret", "pages_per_block"))
 @jax.named_scope("smg.attn.decode")
 def latent_attention_decode_cached(
@@ -334,10 +506,10 @@ def latent_attention_decode_cached(
     latent: int,  # the entry's first ``latent`` lanes are its value (a multiple of 128)
     scale: float,
     interpret: bool = False,
-    pages_per_block: int | None = None,
+    pages_per_block: int | None = None,  # None: ``LATENT_BLOCK_TOKENS`` of entries (tests set it)
 ) -> jax.Array:
-    """Absorbed latent attention over a cache with no V buffer: the kernel
-    above with one stream of pages.  All ``H`` query heads meet one "head" of
+    """Absorbed latent attention over a cache with no V buffer
+    (``_latent_decode_kernel``).  All ``H`` query heads meet one "head" of
     ``W`` lanes (the latent and the rotary key, padded to whole 128-lane
     tiles); a key's first ``latent`` lanes are its value.  Returns ``sum p c``
     [B, H, latent] in ``q``'s dtype."""
@@ -349,13 +521,12 @@ def latent_attention_decode_cached(
     if W % 128 or latent % 128 or cache.shape[3] != W:
         raise ValueError(f"latent entries of {cache.shape[3]} lanes (values {latent}) "
                          "are not whole 128-lane tiles")
-    n = pages_per_block or _pages_per_block(ps, W, cd.itemsize, mp)
+    n = pages_per_block or _latent_pages_per_block(ps, mp)
     if N == 1:  # see ``paged_attention_decode_cached``
         side = jnp.pad(side, ((0, 0), (0, 1), (0, 0)))
         N = 2
-    meta = jnp.stack([jnp.asarray(n_extra, jnp.int32), jnp.asarray(layer, jnp.int32),
-                      jnp.int32(0)])
-    kernel = functools.partial(_decode_kernel, ps=ps, n=n, scale=scale, softcap=0.0,
+    meta = jnp.stack([jnp.asarray(n_extra, jnp.int32), jnp.asarray(layer, jnp.int32)])
+    kernel = functools.partial(_latent_decode_kernel, ps=ps, n=n, mp=mp, pages=P, scale=scale,
                                latent=latent)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -370,17 +541,22 @@ def latent_attention_decode_cached(
             pltpu.VMEM((2, n * ps, W), cd),
             pltpu.VMEM((H, latent), jnp.float32),
             pltpu.SMEM((1,), jnp.int32),
-            pltpu.SemaphoreType.DMA((1, 2)),
+            pltpu.SemaphoreType.DMA((2,)),
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, latent), q.dtype),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        # lanes in order, as in the paged kernel.  No bounds checks on the
+        # copies: 13 of a start's 27 scalar operations, a fifth of the call
+        # (``PERF.md`` section 6, PR 45); a page index comes from the table of
+        # a lane that holds the page, as in every kernel that reads one
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",),
+                                             disable_bounds_checks=True),
         interpret=interpret,
-    )(page_tables.astype(jnp.int32), entry_positions.astype(jnp.int32), meta,
-      q.astype(cd), side.astype(cd), cache.reshape(L, P * ps, W))
+    )(page_tables.astype(jnp.int32).reshape(B * mp), entry_positions.astype(jnp.int32), meta,
+      q.astype(cd), side.astype(cd), cache.reshape(L * P, ps, W))
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret", "pages_per_block"))
