@@ -10,7 +10,7 @@ the repo's whole observability contract as hard pass/fail:
   (chunked-prefill budget), JSON-constrained decode, tool-call loops,
   streaming with mid-stream client disconnects, deadline'd requests (every
   request rides ``--request-timeout-secs``), and Zipf multi-turn sessions
-  reusing the PR 9 routing-probe trace (``benches/bench_gateway.py``);
+  (the PR 9 routing-probe trace, ``_zipf_trace``);
 - open-loop arrivals: Poisson (exponential gaps) or bursty, from a seeded
   RNG threaded through ``LoadgenConfig`` — a given (seed, matrix) emits the
   identical request schedule every run;
@@ -45,7 +45,6 @@ from __future__ import annotations
 
 import argparse
 import asyncio
-import importlib.util
 import json
 import os
 import random
@@ -92,21 +91,23 @@ DEFAULT_SLO_SPECS = [
 
 def _zipf_trace(rng, n_requests, n_users, system_tokens, turn_tokens,
                 vocab_size, max_prompt):
-    """The PR 9 routing-probe trace (``bench_gateway._zipf_multi_turn_trace``)
-    scaled to the tiny test model: token ids folded into the vocab, prompts
-    truncated to the engine's sequence budget.  Loaded by file path so the
-    trace GENERATOR is shared, not copied."""
-    spec = importlib.util.spec_from_file_location(
-        "smg_bench_gateway", os.path.join(_REPO_ROOT, "benches", "bench_gateway.py")
-    )
-    mod = importlib.util.module_from_spec(spec)
-    sys.modules["smg_bench_gateway"] = mod
-    spec.loader.exec_module(mod)
-    trace = mod._zipf_multi_turn_trace(
-        rng, n_requests=n_requests, n_users=n_users,
-        system_tokens=system_tokens, turn_tokens=turn_tokens,
-    )
-    return [[t % vocab_size for t in toks[-max_prompt:]] for toks in trace]
+    """Zipf-ish multi-turn chat trace (the PR 9 routing-probe trace): a few
+    hot users dominate, and every prompt is a shared system prefix, the
+    user's growing history and a fresh turn, which is the workload
+    cache-aware routing exists for.  The turn length is deliberately NOT
+    page-aligned, so reconciliation sees the engine's page-granular rounding
+    as honest small error.  Scaled to the tiny test model: token ids folded
+    into the vocab, prompts truncated to the engine's sequence budget."""
+    system = [rng.randrange(32000) for _ in range(system_tokens)]
+    weights = [1.0 / (rank + 1) for rank in range(n_users)]
+    histories: dict[int, list[int]] = {}
+    trace = []
+    for _ in range(n_requests):
+        uid = rng.choices(range(n_users), weights=weights)[0]
+        hist = histories.setdefault(uid, list(system))
+        hist.extend(rng.randrange(32000) for _ in range(turn_tokens))
+        trace.append([t % vocab_size for t in hist[-max_prompt:]])
+    return trace
 
 
 @dataclass

@@ -11,10 +11,16 @@ preset, a ``qk_norm`` config and an M-RoPE config, under XLA attention and
 under the interpreted kernels, and (B) every program family a runner
 registers (``prefill``, ``prefill_extend``, ``prefill_batched`` cold and
 warm, ``decode_multi``, ``decode_spec``, ``embed``) through the runner's own
-host API, for the same configs, and for ``tiny-olmo-hybrid``,
-``tiny-pangu-moe`` and ``tiny-mimo`` through ``Engine`` (the last two where
-the tree has them), a sampled group and a sampled stream among them,
-and (C) the routed-expert layer itself under XLA's ragged product and under
+host API, for the same configs (decode frames with penalties, a stop token
+met inside the frame, a vocabulary mask, an M-RoPE offset and a LoRA bank
+among them, and tiny Llama once more under ``tp=2`` on forced host devices:
+the Llama frame alone carries ``in_shardings``, ``out_shardings`` and
+``shard_hint``), and for ``tiny-olmo-hybrid``, ``tiny-pangu-moe``,
+``tiny-mimo``, ``tiny-longcat-flash`` and ``tiny-exaone-moe`` through
+``Engine`` (all but the first where the tree has them; the last one plain,
+self-drafting, and self-drafting with weights whose drafts are right, so
+that the verify frame's accept branch runs), a sampled group, a sampled
+stream and a stream with penalties and a stop token among them, and (C) the routed-expert layer itself under XLA's ragged product and under
 the interpreted grouped-product kernel: on the CPU an engine's expert layers
 are XLA's, and the served ones on a TPU the kernel's.
 It keeps every output (logits, caches, tokens, logprobs) and each compiled
@@ -31,6 +37,10 @@ import os
 import sys
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
+    # two host devices, for the mesh form of the Llama programs
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                               + " --xla_force_host_platform_device_count=2").strip()
 
 
 def _cost(compiled) -> dict:
@@ -167,10 +177,12 @@ def _forwards(name, cfg, impl, out, costs):
             cur = jnp.argmax(lo, -1).astype(jnp.int32)
 
 
-def _engine_config(cfg, impl, **kw):
+def _engine_config(cfg, impl, tp=1, **kw):
     from smg_tpu.engine.config import CacheConfig, EngineConfig, SchedulerConfig
+    from smg_tpu.parallel.mesh import ParallelConfig
 
     return EngineConfig(
+        parallel=ParallelConfig(tp=tp),
         model=cfg,
         cache=CacheConfig(page_size=16, num_pages=64, auto_size=False, dtype="float32"),
         scheduler=SchedulerConfig(
@@ -186,17 +198,18 @@ def _program_costs(runner, prefix, costs):
             costs[f"{prefix}/{key!r}"] = _cost(rec.fn.lower(*rec.last_specs).compile())
 
 
-def _runner_programs(name, cfg, impl, out, costs):
+def _runner_programs(name, cfg, impl, out, costs, tp=1):
     """(B): the programs a ``ModelRunner`` registers, through its host API."""
     import numpy as np
 
     from smg_tpu.engine.runner import ModelRunner
+    from smg_tpu.models.lora import empty_adapter
 
     if impl != "xla":
         cfg = dataclasses.replace(cfg, num_kv_heads=8)
-    r = ModelRunner(_engine_config(cfg, impl))
+    r = ModelRunner(_engine_config(cfg, impl, tp=tp))
     r._programs.arm()
-    pre = f"B/{name}/{impl}"
+    pre = f"B/{name}/{impl}" + (f"/tp{tp}" if tp > 1 else "")
     mpz = r.max_pages_per_seq
     rng = np.random.default_rng(1)
     ids = lambda n: [int(t) for t in rng.integers(3, cfg.vocab_size - 20, n)]
@@ -225,6 +238,43 @@ def _runner_programs(name, cfg, impl, out, costs):
         t, lp = r.decode_multi(cur, pos, tables, *s4, num_steps=n)
         out[f"{pre}/decode_multi{n}"] = np.stack([t.astype(np.float64), lp])
         pos = pos + n
+    # the frame's optional arms: penalties with a stop token and a limit met
+    # inside the frame, an M-RoPE offset, a vocabulary mask, a LoRA bank
+    def frame(tag, t, lp):
+        out[f"{pre}/decode_{tag}"] = np.stack([t.astype(np.float64), lp])
+
+    for slot in range(4):
+        r.sync_slot_penalty_state(slot, ids(12), ids(3))
+    pen = (np.arange(4, dtype=np.int32), np.array([0.5, 0.0, 0.3, 0.0], np.float32),
+           np.array([0.0, 0.4, 0.2, 0.0], np.float32), np.array([1.3, 1.0, 1.1, 1.0], np.float32))
+    warm_s4 = (np.array([0.0, 0.7, 0.9, 0.0], np.float32), s4[1], s4[2], s4[3])
+    t, lp = r.decode_multi(cur, pos, tables, *warm_s4, num_steps=4, pen=pen)
+    frame("pen", t, lp)
+    # lane 0 ends on its second token; nothing ends on the first
+    stop = (np.stack([[int(t[0, 1]), -1], [-1, -1], [-1, -1], [-1, -1]]).astype(np.int32),
+            np.array([2**30, 2**30, pos[2] + 4, 2**30], np.int32),
+            np.array([True, True, True, False]))
+    for slot in range(4):
+        r.sync_slot_penalty_state(slot, ids(12), ids(3))
+    t, lp = r.decode_multi(cur, pos, tables, *warm_s4, num_steps=4, pen=pen, stop_state=stop)
+    frame("pen_stop", t, lp)
+    out[f"{pre}/counts_buf"] = np.asarray(r._counts_buf)
+    t, lp = r.decode_multi(cur, pos, tables, *s4, num_steps=3, max_steps=4, stop_state=stop)
+    frame("stop", t, lp)
+    t, lp = r.decode_multi(cur, pos, tables, *s4, num_steps=4,
+                           rope_delta=np.array([2, 0, 5, 0], np.int32))
+    frame("mrope", t, lp)
+    mask = np.arange(cfg.vocab_size)[None, :] % np.arange(2, 6)[:, None] == 0
+    t, lp = r.decode_multi(cur, pos, tables, *warm_s4, num_steps=1, mask=mask)
+    frame("mask", t, lp)
+    if name == "tiny":
+        rng_l = np.random.default_rng(2)
+        w = {k: rng_l.normal(0, 0.5, v.shape).astype(np.float32)
+             for k, v in empty_adapter(cfg, 4).items()}
+        r.load_lora("a", w)
+        t, lp = r.decode_multi(cur, pos, tables, *s4, num_steps=4, pen=pen,
+                               lora_idx=np.array([1, 0, 1, 0], np.int32))
+        frame("lora_pen", t, lp)
     if hasattr(r, "decode_spec_async") and impl == "xla":
         block = np.array([ids(4) for _ in range(4)], np.int32)
         em, ne, lp = r.decode_spec_async(block, np.array([3, 1, 0, 0], np.int32), pos,
@@ -236,15 +286,19 @@ def _runner_programs(name, cfg, impl, out, costs):
     _program_costs(r, pre, costs)
 
 
-def _engine_programs(name, cfg, impl, out, costs):
+def _engine_programs(name, cfg, impl, out, costs, params=None, **sched):
     """(B) for a model whose runner keeps state per sequence: through
     ``Engine``, greedy, one prompt long enough to be cut into chunks."""
+    import jax
     import numpy as np
 
     from smg_tpu.engine.engine import Engine
     from smg_tpu.protocols.sampling import SamplingParams
 
-    eng = Engine(_engine_config(cfg, impl, decode_horizon=4))
+    config = _engine_config(cfg, impl, decode_horizon=4, **sched)
+    if params is not None:
+        params = params(config.model)
+    eng = Engine(config, params=params)
     eng.runner._programs.arm()
     pre = f"B/{name}/{impl}"
     sp = SamplingParams(temperature=0.0, max_new_tokens=9, ignore_eos=True)
@@ -254,9 +308,27 @@ def _engine_programs(name, cfg, impl, out, costs):
     hot = SamplingParams(temperature=0.8, top_p=0.9, max_new_tokens=9, ignore_eos=True)
     res = eng.generate(prompt_ids=list(range(9, 40)), sampling=hot)
     out[f"{pre}/generate_sampled"] = np.asarray(res.token_ids)
+    # penalties, and a stop token and a limit that fall inside a frame
+    base = eng.generate(prompt_ids=list(range(30, 52)), sampling=sp).token_ids
+    pen = SamplingParams(temperature=0.0, max_new_tokens=7, repetition_penalty=1.3,
+                         frequency_penalty=0.4, presence_penalty=0.2,
+                         stop_token_ids=[int(base[5])])
+    res = eng.generate(prompt_ids=list(range(30, 52)), sampling=pen)
+    out[f"{pre}/generate_pen_stop"] = np.asarray(res.token_ids)
+    hot_pen = SamplingParams(temperature=0.7, top_k=40, max_new_tokens=10,
+                             frequency_penalty=0.5, ignore_eos=True)
+    res = eng.generate(prompt_ids=list(range(60, 95)), sampling=hot_pen)
+    out[f"{pre}/generate_sampled_pen"] = np.asarray(res.token_ids)
     out[f"{pre}/k_cache"] = np.asarray(eng.runner.k_cache)
-    if hasattr(eng.runner, "s_pool"):
-        out[f"{pre}/s_pool"] = np.asarray(eng.runner.s_pool)
+    for what in ("s_pool", "c_pool", "draft_buf", "frame_counts", "frame_clean"):
+        if getattr(eng.runner, what, None) is not None:
+            out[f"{pre}/{what}"] = np.asarray(getattr(eng.runner, what))
+    if getattr(eng.runner, "frame_tail", None) is not None:
+        # the verify frame launched last: emitted, last, positions, [drafted, accepted]
+        for i, x in enumerate(jax.tree.leaves(eng.runner.frame_tail)):
+            out[f"{pre}/frame_tail/{i}"] = np.asarray(x)
+        out[f"{pre}/mtp"] = np.array([eng.loads()["mtp"][k] for k in
+                                      ("drafted", "accepted", "columns", "tokens")])
     _program_costs(eng.runner, pre, costs)
 
 
@@ -304,6 +376,7 @@ def dump(root: str, path: str) -> None:
             if name != "mrope":  # the runner takes M-RoPE ids with a vision tower only
                 _runner_programs(name, cfg, impl, out, costs)
         _engine_programs(name, cfg, "xla", out, costs)
+    _runner_programs("tiny", _configs()["tiny"], "xla", out, costs, tp=2)
     for impl in ("xla", "pallas_interpret"):
         _engine_programs("olmo_hybrid", tiny_olmo_hybrid_config(), impl, out, costs)
     try:
@@ -321,6 +394,33 @@ def dump(root: str, path: str) -> None:
     if tiny_mimo_config is not None:
         for impl in ("xla", "pallas_interpret"):
             _engine_programs("mimo", tiny_mimo_config(held=(4, 8)), impl, out, costs)
+    try:
+        from smg_tpu.models.config import tiny_longcat_flash_config
+    except ImportError:  # a tree from before the model with two attention sublayers
+        tiny_longcat_flash_config = None
+    if tiny_longcat_flash_config is not None:
+        for impl in ("xla", "pallas_interpret"):
+            _engine_programs("longcat_flash", tiny_longcat_flash_config(held=(4, 12)), impl,
+                             out, costs)
+    try:
+        from smg_tpu.models.config import tiny_exaone_moe_config
+    except ImportError:  # a tree from before the self-drafting model
+        tiny_exaone_moe_config = None
+    if tiny_exaone_moe_config is not None:
+        from smg_tpu.models import exaone_moe
+
+        def right(model):  # every draft is the model's own next token
+            import jax
+
+            return exaone_moe.params_whose_drafts_are_right(
+                exaone_moe.init_params(model, jax.random.PRNGKey(0)))
+
+        cfg = tiny_exaone_moe_config(held=(4, 8))
+        for impl in ("xla", "pallas_interpret"):
+            _engine_programs("exaone_moe", cfg, impl, out, costs)
+            _engine_programs("exaone_moe_drafting", cfg, impl, out, costs, speculative=True)
+        _engine_programs("exaone_moe_drafts_right", tiny_exaone_moe_config(), "xla", out,
+                         costs, params=right, speculative=True)
     np.savez_compressed(path, __costs__=np.array(json.dumps(costs)), **out)
     print(f"{len(out)} outputs, {len(costs)} compiled programs -> {path}")
 

@@ -11,8 +11,10 @@ For every jit site carrying ``donate_argnums`` the rule resolves the
 donated positions (literal tuples, or the union of literal assignments to
 a policy variable like ``donate = (4, 5) ... donate = ()``), finds the
 dispatch call sites — immediate invocation, a local ``fn = jax.jit(...)``
-then ``fn(...)``, or the runner's factory shape (``fn = self._decode_multi_fn(...)``
-resolved through the defining class), including ``fn(*args)`` against a
+then ``fn(...)``, or the runner's factory shape (``fn = self._prefill_fn(...)``
+resolved through the defining class; the decode frame's positions are computed
+from the frame's description in ``ModelRunner._decode_frame_fn`` and are held
+by ``program_audit()``'s aliasing check instead), including ``fn(*args)`` against a
 literal ``args = [...]`` prefix — and maps donated positions back to the
 caller's argument expressions.  It flags:
 

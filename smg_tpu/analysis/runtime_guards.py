@@ -2,8 +2,7 @@
 lock-order guards.
 
 Static analysis catches the patterns; these guards catch the *effects* on
-the real engine, wired into ``tests/test_analysis.py`` and the
-``benches/bench_engine.py`` steady-state probe:
+the real engine, wired into ``tests/test_analysis.py``:
 
 - :func:`no_implicit_transfers` — ``jax.transfer_guard("disallow")`` around
   the steady-state decode section.  The hot path performs its intended

@@ -127,8 +127,9 @@ class SchedulerConfig:
     # per-lane done mask (EOS/stop-token ids + max-token budget) early-exits
     # the loop at the first finish, and the host trims acceptance at that
     # column and rewinds the unused key folds before relaunching.  >1
-    # amortizes the per-step host round trip ~K-fold — the decisive lever on
-    # TPU where dispatch latency rivals step compute.
+    # amortizes the per-step host round trip ~K-fold.  Every cell of the
+    # benchmark passes 8; at that width the device's idle share read 2.8 %
+    # (ledger, before PR 27).
     decode_horizon: int = 1
     # adaptive horizon controller: pick K per step from page headroom and
     # observed finish rates (EMA of columns-until-finish), capped at

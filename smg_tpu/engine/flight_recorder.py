@@ -33,8 +33,7 @@ Hard constraints (the reason this module exists at all on a TPU engine):
 - **Bounded overhead.**  Appends into ``deque(maxlen=...)`` under a small
   dedicated lock (NOT the engine lock — the watchdog must be able to dump
   while the step thread is wedged holding the engine lock).
-  ``benches/bench_engine.py`` scenario 7 gates the on-vs-off step-loop
-  overhead at <= 2%.
+  ``tests/test_flight_recorder.py`` holds the ring's bounds.
 - **Dumps never raise.**  ``auto_dump`` is called from failure paths; a
   broken dump directory (or the ``flight.dump`` fault point) degrades to a
   log line, never to a second failure.

@@ -24,7 +24,12 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from smg_tpu.engine.kv_cache import plan_latent_cache
-from smg_tpu.engine.runner import FLASH_PREFILL_MIN_SCORE_BYTES, ModelRunner, logger
+from smg_tpu.engine.runner import (
+    FLASH_PREFILL_MIN_SCORE_BYTES,
+    ModelRunner,
+    logger,
+    one_token_column,
+)
 from smg_tpu.ops.latent_attention import land_side_buffer
 
 
@@ -183,14 +188,14 @@ class LatentModelRunner(ModelRunner):
     def _decode_multi_fn(self, B: int, mp: int, N: int, E: int = 0,
                          use_pen: bool = False, use_mask: bool = False,
                          use_lora: bool = False, use_mrope: bool = False):
-        """``ModelRunner._decode_multi_routed_fn`` over one latent side
-        buffer."""
+        """This model's decode frame, for ``ModelRunner._decode_frame_fn``'s
+        loop: one latent side buffer."""
         if use_lora or use_mrope:
             raise ValueError(self.module.SERVING_LIMITS["lora"])
         cfg, module = self.model_cfg, self.module
-        L, W = cfg.num_cache_layers, self.spec.lanes
+        L, lanes = cfg.num_cache_layers, self.spec.lanes
 
-        def frame(params, inv_freq, entry_pos, kc, vc, page_tables, attn_impl):
+        def frame(params, inv_freq, entry_pos, kc, vc, page_tables, *, attn_impl, arms):
             # a padded lane sits past its table (``Scheduler._launch_frame``)
             holds = entry_pos < page_tables.shape[1] * kc.shape[2]
 
@@ -199,9 +204,10 @@ class LatentModelRunner(ModelRunner):
                     params, cfg, inv_freq, cur, entry_pos + j, entry_pos, j,
                     kc, page_tables, side, holds, attn_impl=attn_impl)
 
-            def land(side, ran):
-                return land_side_buffer(kc, side, page_tables, entry_pos, ran), vc
+            def land(side, ran, _last):
+                return (land_side_buffer(kc, side, page_tables, entry_pos, ran), vc), None
 
-            return jnp.zeros((L, B, N, W), kc.dtype), column, land
+            return jnp.zeros((L, B, N, lanes), kc.dtype), one_token_column(column), land
 
-        return self._decode_multi_routed_fn(B, mp, N, E, use_pen, use_mask, frame)
+        return self._decode_frame_fn(B, mp, N, E, use_pen, use_mask, frame,
+                                     variant=(self.moe_impl,))

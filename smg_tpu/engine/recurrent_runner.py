@@ -28,7 +28,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
 from smg_tpu.engine import prefill_pack
 from smg_tpu.engine.kv_cache import plan_recurrent_cache
@@ -38,6 +37,7 @@ from smg_tpu.engine.runner import (
     _dev,
     _pick_sampler,
     logger,
+    one_token_column,
 )
 from smg_tpu.ops.attention import land_side_buffers
 
@@ -74,10 +74,8 @@ class RecurrentModelRunner(ModelRunner):
         if self._device is not None:
             self.s_pool = jax.device_put(self.s_pool, self._device)
             self.c_pool = jax.device_put(self.c_pool, self._device)
-        # whether the frame launched last met no finish (a device scalar the
-        # next launch may chain on; see the module docstring), and what a
-        # frame that chains on none is given in its place
-        self.frame_clean = None
+        # what a frame that chains on none is given for the ``frame_clean``
+        # of the frame before it (see the module docstring)
         self._unchained = self._scalar_up(np.bool_(True))
         from smg_tpu.ops.pallas import linattn_decode
 
@@ -211,93 +209,39 @@ class RecurrentModelRunner(ModelRunner):
     def _decode_multi_fn(self, B: int, mp: int, N: int, E: int = 0,
                          use_pen: bool = False, use_mask: bool = False,
                          use_lora: bool = False, use_mrope: bool = False):
-        """``ModelRunner._decode_multi_fn``'s megastep for this model: the
-        same loop, stop detection and in-loop key folds, with the state pools
-        carried through the columns beside the side buffers.  ``chain`` (a
-        device bool, true for a frame that chains on none) turns the frame
-        into no columns at all when the frame before it met a finish."""
+        """This model's decode frame, for ``ModelRunner._decode_frame_fn``'s
+        loop: the state pools are carried through the columns beside the K
+        and V side buffers, and the frame is ``chained``: launched ahead of
+        one that met a finish it runs no column at all."""
         self._plain("decode", lora=use_lora, mrope=use_mrope)
-        use_stop = E > 0
-        attn_impl = self._attn_impl_for(B, mp)
         lin_impl = self.linattn_impl
-        k = ("decode_multi", B, mp, N, E, attn_impl, lin_impl, use_pen, use_mask)
-        if k in self._compiled:
-            return self._compiled[k]
         cfg, module = self.model_cfg, self.module
         KD = cfg.num_kv_heads * cfg.head_dim
         L = cfg.num_cache_layers
-        from smg_tpu.engine.sampling import apply_penalties
 
-        def multi(params, inv_freq, tokens, entry_pos, kc, vc, page_tables, sp, cp,
-                  slots, chain, base_key, step0, n_steps, temps, topks, topps, minps,
-                  *extra):
-            i = 0
-            if use_pen:
-                counts_buf, pmask_buf, slot_idx, freqs, pres, reps = extra[:6]
-                i = 6
-            mask = None
-            if use_mask:
-                mask = extra[i]
-                i += 1
-            if use_stop:
-                stop_ids, limits, live = extra[i], extra[i + 1], extra[i + 2]
-            n_steps = jnp.where(chain, n_steps, 0)
+        def frame(params, inv_freq, entry_pos, kc, vc, page_tables, sp, cp, slots, _chain, *,
+                  attn_impl, arms):
             runs = slots > 0
+
+            def column(cur, j, side):
+                logits, *side = module.forward_decode_horizon(
+                    params, cfg, inv_freq, cur, entry_pos + j, entry_pos, j,
+                    kc, vc, page_tables, *side, slots, runs,
+                    attn_impl=attn_impl, linattn_impl=lin_impl)
+                return logits, tuple(side), None
+
+            def land(side, ran, _last):
+                hk, hv, sp, cp = side
+                return (*land_side_buffers(kc, vc, hk, hv, page_tables, entry_pos, ran),
+                        sp, cp), None
+
             hk0 = jnp.zeros((L, B, N, KD), kc.dtype)
             hv0 = jnp.zeros((L, B, N, KD), kc.dtype)
-            counts0 = counts_buf[slot_idx] if use_pen else jnp.zeros((B, 0))
-            pmask = pmask_buf[slot_idx] if use_pen else None
-            sampler = _pick_sampler()
-            done0 = (~live) if use_stop else jnp.zeros((B,), jnp.bool_)
+            return (hk0, hv0, sp, cp), one_token_column(column), land
 
-            def cond(carry):
-                j, done = carry[0], carry[7]
-                ok = j < n_steps
-                if use_stop:
-                    ok = jnp.logical_and(ok, ~jnp.any(done & live))
-                return ok
-
-            def body(carry):
-                j, cur, toks_out, lps_out, hk, hv, counts, done, sp, cp = carry
-                logits, hk, hv, sp, cp = module.forward_decode_horizon(
-                    params, cfg, inv_freq, cur, entry_pos + j, entry_pos, j,
-                    kc, vc, page_tables, hk, hv, sp, cp, slots, runs,
-                    attn_impl=attn_impl, linattn_impl=lin_impl)
-                if use_pen:
-                    logits = apply_penalties(logits, counts, pmask, freqs, pres, reps)
-                kj = jax.random.split(jax.random.fold_in(
-                    base_key, step0 + j.astype(jnp.uint32) + jnp.uint32(1)), 1)[0]
-                new, lps = sampler(logits, kj, temps, topks, topps, minps, mask=mask)
-                if use_pen:
-                    counts = counts.at[jnp.arange(B), new].add(1)
-                toks_out = lax.dynamic_update_slice(
-                    toks_out, new[:, None].astype(jnp.int32), (0, j))
-                lps_out = lax.dynamic_update_slice(
-                    lps_out, lps[:, None].astype(jnp.float32), (0, j))
-                if use_stop:
-                    tok_done = jnp.any(new[:, None] == stop_ids, axis=1)
-                    done = done | tok_done | ((entry_pos + j) >= (limits - 2))
-                return (j + 1, new, toks_out, lps_out, hk, hv, counts, done, sp, cp)
-
-            init = (jnp.int32(0), tokens, jnp.zeros((B, N), jnp.int32),
-                    jnp.zeros((B, N), jnp.float32), hk0, hv0, counts0, done0, sp, cp)
-            (steps_run, _cur, outs, lps, hk, hv, counts, done, sp, cp) = \
-                lax.while_loop(cond, body, init)
-            kc, vc = land_side_buffers(kc, vc, hk, hv, page_tables, entry_pos,
-                                       jnp.arange(N)[None, :] < steps_run)
-            clean = chain & ~jnp.any(done & live) if use_stop else chain
-            out = (outs, lps, steps_run, kc, vc, sp, cp, clean)
-            if use_pen:
-                out += (counts_buf.at[slot_idx].set(counts),)
-            return out
-
-        donate = (4, 5, 7, 8) + ((18,) if use_pen else ())
-        if not self.donation.donate_kv:
-            donate = ()
-        return self._register(k, jax.jit(multi, donate_argnums=donate), donate=donate,
-                              in_shardings=None, attn=_attn_label("decode", attn_impl),
-                              products=(self.xla_decode_products
-                                        if attn_impl == "xla" else None))
+        return self._decode_frame_fn(B, mp, N, E, use_pen, use_mask, frame,
+                                     variant=(lin_impl,), n_held=4, donate_held=(0, 1),
+                                     chained=True)
 
     # ---- host-facing API: ModelRunner's, with the rows' slots as keywords ----
 
@@ -393,62 +337,12 @@ class RecurrentModelRunner(ModelRunner):
             self._take_state(state)
         return toks, lps
 
-    def decode_multi_async(self, tokens, positions, page_tables, temps, topks, topps,
-                           minps, num_steps, max_steps=None, stop_state=None, pen=None,
-                           mask=None, lora_idx=None, rope_delta=None, state_slots=None,
-                           chain=None):
-        """``ModelRunner.decode_multi_async``.  ``state_slots`` [B] names
-        each lane's slot (0: the lane does not run); ``chain`` is the
-        ``frame_clean`` of the frame this one was launched ahead of, None
-        for a frame that follows a consumed one.  After the call
-        ``self.frame_clean`` is this frame's."""
-        B, mp = page_tables.shape
-        N = max_steps or num_steps
-        self._plain("decode", lora=lora_idx is not None and self._lora_bank is not None,
-                    mrope=rope_delta is not None)
-        E = 0
-        if N > 1:
-            if stop_state is None:
-                raise ValueError("decode megastep with N > 1 requires stop_state")
-            E = stop_state[0].shape[1]
-        fn = self._decode_multi_fn(B, mp, N, E, pen is not None, mask is not None)
-        mark = self._consume_folds(num_steps)
-        if state_slots is None:
-            state_slots = np.zeros(B, np.int32)
-        args = [self.params, self.inv_freq, _dev(tokens, jnp.int32),
-                _dev(positions, jnp.int32), self.k_cache, self.v_cache,
-                _dev(page_tables, jnp.int32), *self._frame_state_args(state_slots, chain),
-                self._rng_key, self._scalar_up(np.uint32(mark)),
-                self._scalar_up(np.int32(num_steps)), _dev(temps, jnp.float32),
-                _dev(topks, jnp.int32), _dev(topps, jnp.float32), _dev(minps, jnp.float32)]
-        if pen is not None:
-            self._ensure_penalty_buffers()
-            slot_idx, freqs, pres, reps = pen
-            args += [self._counts_buf, self._pmask_buf, _dev(slot_idx, jnp.int32),
-                     _dev(freqs, jnp.float32), _dev(pres, jnp.float32),
-                     _dev(reps, jnp.float32)]
-        if mask is not None:
-            args.append(_dev(mask, jnp.bool_))
-        if E:
-            stop_ids, limits, live = stop_state
-            args += [_dev(stop_ids, jnp.int32), _dev(limits, jnp.int32),
-                     _dev(live, jnp.bool_)]
-        # smglint: disable-next=DONATE the linter counts ``_frame_state_args`` as one argument: the donated positions 7 and 8 are the two pools, rebound in ``_take_frame_state``
-        out = fn(*args)
-        toks, lps, steps_run, self.k_cache, self.v_cache, *rest = out
-        rest = self._take_frame_state(rest)
-        if pen is not None:
-            self._counts_buf, = rest
-        return toks, lps, steps_run
-
     def _frame_state_args(self, state_slots, chain) -> list:
         """What a decode program takes between the page tables and the key."""
         return [*self._state_args(state_slots), self._unchained if chain is None else chain]
 
-    def _take_frame_state(self, out: list) -> list:
-        """Rebind what a decode program returns behind the pages; the rest."""
-        self.s_pool, self.c_pool, self.frame_clean, *rest = out
-        return rest
+    def _take_frame_state(self, state: list) -> None:
+        self.s_pool, self.c_pool = state
 
     # no match is honoured without the state at its end, so no cached page is
     # ever pinned and every one is a frame's to count on (``_headroom_pages``)

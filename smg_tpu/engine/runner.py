@@ -15,6 +15,7 @@ from __future__ import annotations
 import math
 import time
 from functools import partial
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -118,6 +119,19 @@ def _pick_sampler():
     import os
 
     return _sample_exact if os.environ.get("SMG_EXACT_SAMPLING") == "1" else _sample_fast
+
+
+def one_token_column(step):
+    """A decode frame's column (``ModelRunner._decode_frame_fn``) that emits
+    one token a lane, from ``step(cur, j, side) -> (logits, side, counts)``:
+    the token the loop samples from the logits is the one written and the
+    lane's last, and the lane stands one further."""
+    def column(cur, j, side, sample):
+        logits, side, counts = step(cur, j, side)
+        new, lps = sample(logits)
+        return new, lps, new, None, side, counts
+
+    return column
 
 
 class DecodeState:
@@ -356,6 +370,15 @@ class ModelRunner:
         self._lora_bank = None
         self._lora_names: dict[str, int] = {}
         self._lora_rank = 0
+        # what the decode frame launched last left besides its tokens, on the
+        # device (``decode_multi_async`` sets all three; None: the frame has
+        # none): ``frame_clean``, whether it met no finish, which a frame
+        # launched ahead of it chains on (``RecurrentModelRunner``);
+        # ``frame_counts``, the expert layers' int32 counts, one a name of
+        # the module's ``ROUTED_COUNTS``; ``frame_tail``, a verify frame's
+        # ``(emitted, last, positions, [drafted, accepted])``
+        # (``SelfDraftingRunner``)
+        self.frame_clean = self.frame_counts = self.frame_tail = None
 
     def _plan_cache(self, param_bytes: int) -> KvCacheSpec:
         """Size the paged cache from what the tightest device has free."""
@@ -464,7 +487,7 @@ class ModelRunner:
         streams only the prefix pages that hold tokens.  The kernel is
         chosen only at shapes it is known to compile at: 128-lane-sliceable
         heads and ``T <= PREFILL_KERNEL_MAX_T``.  The 2048-slot crossover
-        has no measurement on record (ROADMAP S4)."""
+        has no measurement on record (ROADMAP D4, S7(1))."""
         if self.use_pp or self.attn_impl == "xla":
             return "xla"
         d = self.model_cfg.head_dim
@@ -1155,13 +1178,98 @@ class ModelRunner:
     def _decode_multi_fn(self, B: int, mp: int, N: int, E: int = 0,
                          use_pen: bool = False, use_mask: bool = False,
                          use_lora: bool = False, use_mrope: bool = False):
-        """The decode MEGASTEP: up to N decode steps fused into one jitted
-        ``lax.while_loop`` with in-loop sampling-key folds and device-side
-        stop detection.  Sampled tokens feed back on-device, so host round
-        trips amortize K-fold (the decisive win when dispatch latency rivals
-        step compute) — and the loop bound ``n_steps`` rides a device scalar,
-        so ONE trace per batch bucket serves every K <= N (compile time no
-        longer scales with the horizon).
+        """The Llama family's decode frame, for ``_decode_frame_fn``'s loop:
+        K and V side buffers ``[L, B, N, KD]`` that land in the pages at the
+        frame's end.  ``use_lora`` adds the adapter bank and the rows' adapter
+        indices, ``use_mrope`` a [B] rope position delta (M-RoPE decode: the
+        text axes are equal, so the offset rides the standard rope path)."""
+        cfg, module = self.model_cfg, self.module
+        KD = cfg.num_kv_heads * cfg.head_dim
+        L = cfg.num_layers
+        mesh, rules = self.mesh, self.rules
+        pp_mesh = self.mesh if self.use_pp else None
+        kv_lanes_sharded = self.kv_lanes_sharded
+
+        def frame(params, inv_freq, entry_pos, kc, vc, page_tables, *, attn_impl, arms):
+            hk0 = jnp.zeros((L, B, N, KD), kc.dtype)
+            hv0 = jnp.zeros((L, B, N, KD), kc.dtype)
+            # align the horizon KV carry with the cache's lane sharding so
+            # the final scatter is shard-local — without the hint the SPMD
+            # partitioner is free to replicate the carry and all-gather at
+            # the scatter (layers/kv_lanes mirror kv_cache_logical_axes)
+            hk0 = shard_hint(hk0, ("layers", None, None, "kv_lanes"), mesh, rules)
+            hv0 = shard_hint(hv0, ("layers", None, None, "kv_lanes"), mesh, rules)
+
+            def column(cur, j, side):
+                logits, hk, hv = module.forward_decode_horizon(
+                    params, cfg, inv_freq, cur, entry_pos + j, entry_pos, j,
+                    kc, vc, page_tables, *side, attn_impl=attn_impl,
+                    lora=arms.lora, lora_gates=arms.lora_gates, pp_mesh=pp_mesh,
+                    rope_delta=arms.rope_delta, kv_lanes_sharded=kv_lanes_sharded)
+                return logits, (hk, hv), None
+
+            def land(side, ran, _last):
+                # one scatter into the donated cache; columns that did not run
+                # and positions past the table go to the reserved garbage page
+                return land_side_buffers(kc, vc, *side, page_tables, entry_pos, ran), None
+
+            return (hk0, hv0), one_token_column(column), land
+
+        return self._decode_frame_fn(B, mp, N, E, use_pen, use_mask, frame,
+                                     arms=(use_lora, use_mrope))
+
+    def _bind_moe_impl(self, impl: str) -> None:
+        """Bind the implementation of the expert layers' grouped products to
+        the module's forwards, so that every program family calls them as it
+        calls a model without experts (``LatentModelRunner``,
+        ``WindowModelRunner``)."""
+        self.moe_impl, module = impl, self.module
+        self.module = SimpleNamespace(**{
+            **vars(module),
+            **{f: partial(getattr(module, f), moe_impl=impl)
+               for f in ("forward_prefill", "forward_prefill_batched",
+                         "forward_decode_horizon", "forward_verify_column",
+                         "forward_mtp_prefill", "forward_mtp_column", "forward_mtp_draft")
+               if hasattr(module, f)}})
+
+    def _decode_frame_fn(self, B: int, mp: int, N: int, E: int, use_pen: bool,
+                         use_mask: bool, frame, *, variant: tuple = (),
+                         arms: "tuple[bool, bool] | None" = None, n_held: int = 0,
+                         donate_held: tuple = (), chained: bool = False, W: int = 1):
+        """The decode MEGASTEP, every runner's: up to N columns fused into
+        one jitted ``lax.while_loop`` with in-loop sampling-key folds and
+        device-side stop detection.  Sampled tokens feed back on the device,
+        so a frame costs the host one dispatch and one fetch whatever its
+        width, and the loop bound ``n_steps`` rides a device scalar, so ONE
+        trace per batch bucket serves every K <= N (compile time does not
+        scale with the horizon).
+
+        **The frame** is the runner's: ``frame(params, inv_freq, entry_pos,
+        kc, vc, page_tables, *held, attn_impl=, arms=) -> (side0, column,
+        land)``.  What a sequence holds besides its pages is ``n_held``
+        arguments behind ``page_tables`` (those at ``donate_held`` donated).
+        ``side0`` is whatever the frame carries from column to column (its
+        empty side buffers, and anything else of its own: the loop never
+        looks inside).  ``column(cur, j, side, sample)`` runs column ``j``
+        behind the lanes' last tokens ``cur`` and returns ``(tokens, logprobs,
+        last, reach, side, counts)``: the up to ``W`` tokens a lane it wrote
+        (``[B]``, or ``[B, W]``), each lane's last token, where each lane
+        then stands (None for a column of one token a lane: ``entry_pos + j
+        + 1``), and the expert layers' counts (None without
+        ``module.ROUTED_COUNTS``, which the loop then does not carry).
+        ``sample(logits)`` is the loop's, once a column: penalties, the
+        column's key, the sampler, the count.  ``one_token_column`` makes a
+        column from ``(cur, j, side) -> (logits, side, counts)``.
+        ``land(side, ran, last) -> (caches, tail)`` gives the caches as the
+        program returns them (``ran`` [1, N]: the columns run) and what the
+        frame hands the host besides (None: nothing).  ``arms`` is what the
+        frame may read of the loop's arguments: ``lora``, ``lora_gates``,
+        ``rope_delta`` (None where off), ``temps``, and the stop state as
+        ``ends(tokens)`` and ``limits`` (None where ``E`` is 0).
+
+        **The key**: ``("decode_multi", B, mp, N, E, attn_impl, *variant,
+        use_pen, use_mask)`` and, for the frame that has them (``arms``
+        given), ``use_lora, use_mrope``.
 
         Byte-parity with the single-step path at any temperature: column j
         folds ``fold_in(base_key, step0 + 1 + j)`` — exactly the key
@@ -1175,9 +1283,7 @@ class ModelRunner:
         Because the host trims acceptance at the earliest finish anyway (the
         K=1-equivalence rule), exiting at the FIRST done lane strictly
         subsumes per-lane freezing: no token beyond the exit column is ever
-        computed, so a finish inside a large horizon wastes nothing.  KV for
-        uncomputed columns is masked to the garbage page in the final
-        scatter.
+        computed, so a finish inside a large horizon wastes nothing.
 
         ``use_pen`` threads the per-slot [S+1, V] output-count/prompt-mask
         buffers through the loop (counts update on-device as tokens are
@@ -1185,27 +1291,32 @@ class ModelRunner:
         under a trim, since every computed column is an accepted column).
         ``use_mask`` adds a [B, V] constrained-decoding vocab mask; the
         scheduler forces N=1 for masked batches since the mask is
-        host-derived per token.  ``use_lora`` adds the adapter bank +
-        per-slot adapter indices.  ``use_mrope`` adds a [B] rope position
-        delta (M-RoPE decode: text axes are equal, so the offset rides the
-        standard rope path)."""
+        host-derived per token.
+
+        ``chained``: the last held argument is ``chain``, a device bool
+        (true for a frame that chains on none).  A frame launched ahead of
+        one that met a finish then runs no column at all, and the program
+        returns ``clean``: whether a frame chained on this one may run.
+
+        The program returns ``(tokens [B, N(, W)], logprobs, steps_run,
+        *caches, extras)``; ``extras`` holds ``counts_buf``, ``clean``,
+        ``routed`` and ``tail`` where the frame has them
+        (``decode_multi_async`` is the one caller)."""
         use_stop = E > 0
+        use_lora, use_mrope = arms or (False, False)
         attn_impl = self._attn_impl_for(B, mp)
-        k = ("decode_multi", B, mp, N, E, attn_impl, use_pen, use_mask,
-             use_lora, use_mrope)
+        k = ("decode_multi", B, mp, N, E, attn_impl, *variant, use_pen, use_mask,
+             *(arms or ()))
         if k in self._compiled:
             return self._compiled[k]
-        cfg = self.model_cfg
         module = self.module
-        KD = cfg.num_kv_heads * cfg.head_dim
-        L = cfg.num_layers
-        mesh, rules = self.mesh, self.rules
-
         n_slots = self.lora_slots
+        routed_names = getattr(module, "ROUTED_COUNTS", ())
+        wide = () if W == 1 else (W,)
 
-        def multi(params, inv_freq, tokens, entry_pos, kc, vc, page_tables,
-                  base_key, step0, n_steps, temps, topks, topps, minps,
-                  *extra):
+        def multi(params, inv_freq, tokens, entry_pos, kc, vc, page_tables, *rest):
+            held = rest[:n_held]
+            base_key, step0, n_steps, temps, topks, topps, minps, *extra = rest[n_held:]
             i = 0
             if use_pen:
                 counts_buf, pmask_buf, slot_idx, freqs, pres, reps = extra[:6]
@@ -1223,26 +1334,28 @@ class ModelRunner:
             if use_mrope:
                 rope_delta = extra[i]
                 i += 1
+            ends = limits = None
             if use_stop:
                 stop_ids, limits, live = extra[i], extra[i + 1], extra[i + 2]
-            cache_dtype = kc.dtype
-            hk0 = jnp.zeros((L, B, N, KD), cache_dtype)
-            hv0 = jnp.zeros((L, B, N, KD), cache_dtype)
-            # align the horizon KV carry with the cache's lane sharding so
-            # the final scatter is shard-local — without the hint the SPMD
-            # partitioner is free to replicate the carry and all-gather at
-            # the scatter (layers/kv_lanes mirror kv_cache_logical_axes)
-            hk0 = shard_hint(hk0, ("layers", None, None, "kv_lanes"), mesh, rules)
-            hv0 = shard_hint(hv0, ("layers", None, None, "kv_lanes"), mesh, rules)
+                ends = lambda t: jnp.any(t[:, None] == stop_ids, axis=1)
+            if chained:
+                chain = held[-1]
+                n_steps = jnp.where(chain, n_steps, 0)
+            side0, column, land = frame(
+                params, inv_freq, entry_pos, kc, vc, page_tables, *held, attn_impl=attn_impl,
+                arms=SimpleNamespace(lora=lora_bank, lora_gates=lora_gates,
+                                     rope_delta=rope_delta, temps=temps, ends=ends,
+                                     limits=limits))
             counts0 = counts_buf[slot_idx] if use_pen else jnp.zeros((B, 0))
             pmask = pmask_buf[slot_idx] if use_pen else None
             sampler = _pick_sampler()
             # padded lanes start done so the any-real-lane-done exit ignores
             # them; without stop detection nothing is ever done
             done0 = (~live) if use_stop else jnp.zeros((B,), jnp.bool_)
+            routed0 = jnp.zeros((len(routed_names),), jnp.int32) if routed_names else None
 
             def cond(carry):
-                j, done = carry[0], carry[7]
+                j, done = carry[0], carry[6]
                 ok = j < n_steps
                 if use_stop:
                     # first finish ends the horizon: the host accepts nothing
@@ -1251,61 +1364,55 @@ class ModelRunner:
                 return ok
 
             def body(carry):
-                j, cur, toks_out, lps_out, hk, hv, counts, done = carry
-                logits, hk, hv = module.forward_decode_horizon(
-                    params, cfg, inv_freq, cur, entry_pos + j, entry_pos, j,
-                    kc, vc, page_tables, hk, hv, attn_impl=attn_impl,
-                    lora=lora_bank, lora_gates=lora_gates,
-                    pp_mesh=(self.mesh if self.use_pp else None),
-                    rope_delta=rope_delta,
-                    kv_lanes_sharded=self.kv_lanes_sharded,
-                )
-                if use_pen:
-                    logits = apply_penalties(logits, counts, pmask, freqs,
-                                             pres, reps)
-                # the IN-LOOP fold: column j's key is the key the K=1 path
-                # folds at global step step0+1+j (then split(.., 1)[0], the
-                # same per-launch split the single-step scan applied)
-                kj = jax.random.split(jax.random.fold_in(
-                    base_key, step0 + j.astype(jnp.uint32) + jnp.uint32(1)
-                ), 1)[0]
-                new, lps = sampler(logits, kj, temps, topks, topps, minps,
-                                   mask=mask)
-                if use_pen:
-                    counts = counts.at[jnp.arange(B), new].add(1)
+                j, cur, toks_out, lps_out, side, counts, done, routed = carry
+
+                def sample(logits):
+                    nonlocal counts
+                    if use_pen:
+                        logits = apply_penalties(logits, counts, pmask, freqs, pres, reps)
+                    # the IN-LOOP fold: column j's key is the key the K=1 path
+                    # folds at global step step0+1+j (then split(.., 1)[0], the
+                    # same per-launch split the single-step scan applied)
+                    kj = jax.random.split(jax.random.fold_in(
+                        base_key, step0 + j.astype(jnp.uint32) + jnp.uint32(1)), 1)[0]
+                    new, lps = sampler(logits, kj, temps, topks, topps, minps, mask=mask)
+                    if use_pen:
+                        counts = counts.at[jnp.arange(B), new].add(1)
+                    return new, lps
+
+                toks, lps, last, reach, side, c = column(cur, j, side, sample)
+                if routed_names:
+                    routed = module.merge_counts(routed, c)
+                at = (0, j) + (0,) * len(wide)
                 toks_out = lax.dynamic_update_slice(
-                    toks_out, new[:, None].astype(jnp.int32), (0, j)
-                )
+                    toks_out, toks[:, None].astype(jnp.int32), at)
                 lps_out = lax.dynamic_update_slice(
-                    lps_out, lps[:, None].astype(jnp.float32), (0, j)
-                )
+                    lps_out, lps[:, None].astype(jnp.float32), at)
                 if use_stop:
-                    tok_done = jnp.any(new[:, None] == stop_ids, axis=1)
-                    # length finish: total_len after accepting column j is
-                    # entry_pos + j + 2 (decode steady state: total = seq+1),
-                    # so the lane is done once entry_pos + j >= limit - 2
-                    done = done | tok_done | ((entry_pos + j) >= (limits - 2))
-                return (j + 1, new, toks_out, lps_out, hk, hv, counts, done)
+                    # length finish: a lane that stands at ``reach`` holds
+                    # reach + 1 tokens in all (decode steady state: total =
+                    # seq + 1), so it is done once reach >= limit - 1; a
+                    # one-token column's reach is entry_pos + j + 1
+                    over = ((entry_pos + j) >= (limits - 2) if reach is None
+                            else reach >= (limits - 1))
+                    done = done | ends(last) | over
+                return (j + 1, last, toks_out, lps_out, side, counts, done, routed)
 
-            init = (
-                jnp.int32(0), tokens,
-                jnp.zeros((B, N), jnp.int32), jnp.zeros((B, N), jnp.float32),
-                hk0, hv0, counts0, done0,
-            )
-            (steps_run, _cur, outs, lps, hk, hv, counts, _done) = \
+            init = (jnp.int32(0), tokens, jnp.zeros((B, N, *wide), jnp.int32),
+                    jnp.zeros((B, N, *wide), jnp.float32), side0, counts0, done0, routed0)
+            steps_run, last, outs, lps, side, counts, done, routed = \
                 lax.while_loop(cond, body, init)
-
-            # land the whole horizon into the donated cache in one scatter;
-            # uncomputed columns (early exit / n_steps < N) and positions
-            # past the table go to the reserved garbage page
-            kc, vc = land_side_buffers(
-                kc, vc, hk, hv, page_tables, entry_pos,
-                jnp.arange(N)[None, :] < steps_run,
-            )
+            caches, tail = land(side, jnp.arange(N)[None, :] < steps_run, last)
+            extras = {}
             if use_pen:
-                counts_buf = counts_buf.at[slot_idx].set(counts)
-                return outs, lps, steps_run, kc, vc, counts_buf
-            return outs, lps, steps_run, kc, vc  # [B, N] toks/lps
+                extras["counts_buf"] = counts_buf.at[slot_idx].set(counts)
+            if chained:
+                extras["clean"] = chain & ~jnp.any(done & live) if use_stop else chain
+            if routed_names:
+                extras["routed"] = routed
+            if tail is not None:
+                extras["tail"] = tail
+            return (outs, lps, steps_run, *caches, extras)
 
         n_extra = ((6 if use_pen else 0) + (1 if use_mask else 0)
                    + (2 if use_lora else 0) + (1 if use_mrope else 0)
@@ -1315,137 +1422,24 @@ class ModelRunner:
         # aliases its local cache shard.  The per-backend/per-mode rules
         # (CPU-PJRT blocks dispatch on donated inputs, which would serialize
         # the overlapped pipeline) live in engine/donation.py.
-        donate = (4, 5) + ((14,) if use_pen else ())
-        if not self.donation.donate_kv:
-            donate = ()
-        if self.mesh is not None:
-            r = self._replicated
-            in_sh = (self.param_shardings, r, r, r,
-                     self.kv_sharding, self.kv_sharding, r, r, r, r,
-                     r, r, r, r)
-            in_sh = in_sh + (r,) * n_extra
-            out_sh = (r, r, r, self.kv_sharding, self.kv_sharding)
-            if use_pen:
-                out_sh = out_sh + (r,)
-            fn = jax.jit(multi, in_shardings=in_sh, out_shardings=out_sh,
-                         donate_argnums=donate)
-        else:
-            in_sh = None
-            fn = jax.jit(multi, donate_argnums=donate)
-        return self._register(k, fn, donate=donate, in_shardings=in_sh,
-                              attn=_attn_label("decode", attn_impl),
-                              products=(self.xla_decode_products
-                                        if attn_impl == "xla" else None))
-
-    # ---- models with routed experts (``LatentModelRunner``, ``WindowModelRunner``) ----
-
-    def _bind_moe_impl(self, impl: str) -> None:
-        """Bind the implementation of the expert layers' grouped products to
-        the module's forwards, so that every program family calls them as it
-        calls a model without experts."""
-        import types
-
-        self.moe_impl, module = impl, self.module
-        self.module = types.SimpleNamespace(**{
-            **vars(module),
-            **{f: partial(getattr(module, f), moe_impl=impl)
-               for f in ("forward_prefill", "forward_prefill_batched",
-                         "forward_decode_horizon", "forward_verify_column",
-                         "forward_mtp_prefill", "forward_mtp_column", "forward_mtp_draft")
-               if hasattr(module, f)}})
-        # device int32 of the frame launched last, one count a name of the
-        # module's ``ROUTED_COUNTS``
-        self.frame_counts = None
-
-    def _decode_multi_routed_fn(self, B: int, mp: int, N: int, E: int, use_pen: bool,
-                                use_mask: bool, frame, n_held: int = 0, donate_held=()):
-        """``_decode_multi_fn``'s megastep for a model with routed experts:
-        the same loop, stop detection and in-loop key folds, over whatever
-        side buffers the model's frame has, with the expert layers' counts
-        summed over the columns run.  What a sequence holds besides its pages
-        is ``n_held`` arguments behind ``page_tables`` (those at
-        ``donate_held`` donated).  ``frame(params, inv_freq, entry_pos, kc,
-        vc, page_tables, *held, attn_impl=)`` gives the frame's empty side
-        buffers, its column ``(cur, j, side) -> (logits, side, counts)`` and
-        ``land(side, ran) -> `` the caches as the program returns them.  The
-        frame's counts stay behind as ``frame_counts`` (a device array)."""
-        use_stop = E > 0
-        attn_impl = self._attn_impl_for(B, mp)
-        k = ("decode_multi", B, mp, N, E, attn_impl, self.moe_impl, use_pen, use_mask)
-        if k in self._compiled:
-            return self._compiled[k]
-        module = self.module
-
-        def multi(params, inv_freq, tokens, entry_pos, kc, vc, page_tables, *rest):
-            held = rest[:n_held]
-            base_key, step0, n_steps, temps, topks, topps, minps, *extra = rest[n_held:]
-            i = 0
-            if use_pen:
-                counts_buf, pmask_buf, slot_idx, freqs, pres, reps = extra[:6]
-                i = 6
-            mask = None
-            if use_mask:
-                mask = extra[i]
-                i += 1
-            if use_stop:
-                stop_ids, limits, live = extra[i], extra[i + 1], extra[i + 2]
-            side0, column, land = frame(params, inv_freq, entry_pos, kc, vc, page_tables,
-                                        *held, attn_impl=attn_impl)
-            counts0 = counts_buf[slot_idx] if use_pen else jnp.zeros((B, 0))
-            pmask = pmask_buf[slot_idx] if use_pen else None
-            sampler = _pick_sampler()
-            done0 = (~live) if use_stop else jnp.zeros((B,), jnp.bool_)
-
-            def cond(carry):
-                j, done = carry[0], carry[6]
-                ok = j < n_steps
-                if use_stop:
-                    ok = jnp.logical_and(ok, ~jnp.any(done & live))
-                return ok
-
-            def body(carry):
-                j, cur, toks_out, lps_out, side, counts, done, routed = carry
-                logits, side, c = column(cur, j, side)
-                routed = module.merge_counts(routed, c)
-                if use_pen:
-                    logits = apply_penalties(logits, counts, pmask, freqs, pres, reps)
-                kj = jax.random.split(jax.random.fold_in(
-                    base_key, step0 + j.astype(jnp.uint32) + jnp.uint32(1)), 1)[0]
-                new, lps = sampler(logits, kj, temps, topks, topps, minps, mask=mask)
-                if use_pen:
-                    counts = counts.at[jnp.arange(B), new].add(1)
-                toks_out = lax.dynamic_update_slice(
-                    toks_out, new[:, None].astype(jnp.int32), (0, j))
-                lps_out = lax.dynamic_update_slice(
-                    lps_out, lps[:, None].astype(jnp.float32), (0, j))
-                if use_stop:
-                    tok_done = jnp.any(new[:, None] == stop_ids, axis=1)
-                    done = done | tok_done | ((entry_pos + j) >= (limits - 2))
-                return (j + 1, new, toks_out, lps_out, side, counts, done, routed)
-
-            init = (jnp.int32(0), tokens, jnp.zeros((B, N), jnp.int32),
-                    jnp.zeros((B, N), jnp.float32), side0, counts0, done0,
-                    jnp.zeros((len(module.ROUTED_COUNTS),), jnp.int32))
-            steps_run, _cur, outs, lps, side, counts, _done, routed = \
-                lax.while_loop(cond, body, init)
-            out = (outs, lps, steps_run, *land(side, jnp.arange(N)[None, :] < steps_run))
-            if use_pen:
-                out += (counts_buf.at[slot_idx].set(counts),)
-            return out + (routed,)
-
         donate = (4, 5, *(7 + i for i in donate_held)) + ((14 + n_held,) if use_pen else ())
         if not self.donation.donate_kv:
             donate = ()
-        fn = self._register(k, jax.jit(multi, donate_argnums=donate), donate=donate,
-                            in_shardings=None, attn=_attn_label("decode", attn_impl),
-                            products=("fused_lanes" if attn_impl == "xla" else None))
-
-        def launch(*args):
-            *out, self.frame_counts = fn(*args)
-            return out
-
-        self._compiled[k] = launch
-        return launch
+        in_sh = None
+        placed = {}
+        if self.mesh is not None:
+            # a mesh is the Llama frame's alone: nothing held, K and V out
+            r = self._replicated
+            in_sh = (self.param_shardings, r, r, r, self.kv_sharding, self.kv_sharding,
+                     r) + (r,) * (n_held + 7 + n_extra)
+            placed = {"in_shardings": in_sh, "out_shardings": (
+                r, r, r, self.kv_sharding, self.kv_sharding,
+                {"counts_buf": r} if use_pen else {})}
+        return self._register(k, jax.jit(multi, donate_argnums=donate, **placed),
+                              donate=donate, in_shardings=in_sh,
+                              attn=_attn_label("decode", attn_impl),
+                              products=(self.xla_decode_products
+                                        if attn_impl == "xla" else None))
 
     def decode_multi_async(
         self,
@@ -1463,6 +1457,8 @@ class ModelRunner:
         mask: np.ndarray | None = None,  # [B, V] bool
         lora_idx=None,  # [B] adapter slot per row (0 = none)
         rope_delta=None,  # [B] M-RoPE decode offsets
+        state_slots=None,  # [B] each lane's state slot (0: the lane does not run)
+        chain=None,  # ``frame_clean`` of the frame this one is launched ahead of
     ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
         """Dispatch a decode megastep and return UNMATERIALIZED result arrays
         (tokens [B, N], logprobs [B, N], steps_run scalar) where
@@ -1479,7 +1475,13 @@ class ModelRunner:
         the loop early-exits at the first finishing lane.  The launch
         consumes ``num_steps`` sampling-key folds (one per column, in-loop);
         the caller rewinds the unused tail via ``rng_restore(mark + used)``
-        when a finish trims the horizon."""
+        when a finish trims the horizon.
+
+        ``state_slots`` and ``chain`` are for the runners whose sequences
+        hold a slot beside their pages (``_frame_state_args``); ``chain``
+        None is a frame that follows a consumed one.  After the call
+        ``frame_clean``, ``frame_counts`` and ``frame_tail`` are this
+        frame's."""
         B, mp = page_tables.shape
         N = max_steps or num_steps
         use_pen = pen is not None
@@ -1501,6 +1503,9 @@ class ModelRunner:
         # values and upload the pre-advance mark; column j folds mark+1+j,
         # exactly _next_key's value at that global step
         mark = self._consume_folds(num_steps)
+        if state_slots is None:
+            # a caller that knows nothing of slots: every lane on the garbage slot
+            state_slots = np.zeros(B, np.int32)
         # _dev: resident DecodeState buffers pass through (zero transfers in
         # steady state); host inputs upload EXPLICITLY — committed to the
         # mesh when sharded — so the transfer guard can police this launch
@@ -1514,6 +1519,7 @@ class ModelRunner:
             self.k_cache,
             self.v_cache,
             _dev(page_tables, jnp.int32, up),
+            *self._frame_state_args(state_slots, chain),
             self._rng_key,
             self._scalar_up(np.uint32(mark)),
             self._scalar_up(np.int32(num_steps)),
@@ -1546,13 +1552,23 @@ class ModelRunner:
                 _dev(limits, jnp.int32, up),
                 _dev(live, jnp.bool_, up),
             ]
-        out = fn(*args)
+        toks, lps, steps_run, self.k_cache, self.v_cache, *state, extras = fn(*args)
+        self._take_frame_state(state)
         if use_pen:
-            toks, lps, steps_run, self.k_cache, self.v_cache, \
-                self._counts_buf = out
-        else:
-            toks, lps, steps_run, self.k_cache, self.v_cache = out
+            self._counts_buf = extras["counts_buf"]
+        self.frame_clean = extras.get("clean")
+        self.frame_counts = extras.get("routed")
+        self.frame_tail = extras.get("tail")
         return toks, lps, steps_run
+
+    def _frame_state_args(self, state_slots, chain) -> list:
+        """What a decode program takes between the page tables and the key:
+        nothing, for a model whose sequences hold pages alone."""
+        return []
+
+    def _take_frame_state(self, state: list) -> None:
+        """Rebind what a decode program returns behind the pages."""
+        assert not state
 
     def decode_multi(
         self,

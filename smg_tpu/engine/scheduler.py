@@ -1498,9 +1498,9 @@ class Scheduler:
         self._count_decode_launch()
         return InFlightFrame(
             serial=self.account.launched,
-            clean=getattr(self.runner, "frame_clean", None),
-            routed=getattr(self.runner, "frame_counts", None),
-            tail=getattr(self.runner, "frame_tail", None),
+            clean=self.runner.frame_clean,
+            routed=self.runner.frame_counts,
+            tail=self.runner.frame_tail,
             lanes=[(s, r, e + H) for s, r, e in frame.lanes],
             toks=toks, lps=lps, horizon=H2, B=frame.B, B_real=frame.B_real,
             mp_b=mp_b, positions=positions, lane_sig=frame.lane_sig,
@@ -2605,9 +2605,9 @@ class Scheduler:
         self._count_decode_launch()
         return InFlightFrame(
             serial=self.account.launched,
-            clean=getattr(self.runner, "frame_clean", None),
-            routed=getattr(self.runner, "frame_counts", None),
-            tail=getattr(self.runner, "frame_tail", None),
+            clean=self.runner.frame_clean,
+            routed=self.runner.frame_counts,
+            tail=self.runner.frame_tail,
             lanes=[(i, r, r.seq_len) for i, r in active],
             toks=toks, lps=lps, horizon=horizon, B=B, B_real=B_real,
             mp_b=mp_b, positions=positions, lane_sig=sig,

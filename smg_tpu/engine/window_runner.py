@@ -43,7 +43,14 @@ from jax import lax
 from smg_tpu.engine import prefill_pack
 from smg_tpu.engine.kv_cache import plan_window_cache
 from smg_tpu.engine.recurrent_runner import RecurrentModelRunner
-from smg_tpu.engine.runner import ModelRunner, _attn_label, _dev, _pick_sampler, logger
+from smg_tpu.engine.runner import (
+    ModelRunner,
+    _attn_label,
+    _dev,
+    _pick_sampler,
+    logger,
+    one_token_column,
+)
 from smg_tpu.engine.sampling import apply_penalties
 from smg_tpu.ops.attention import land_side_buffers
 from smg_tpu.ops.window_attention import land_ring_side
@@ -139,13 +146,14 @@ class WindowModelRunner(RecurrentModelRunner):
     def _decode_multi_fn(self, B: int, mp: int, N: int, E: int = 0,
                          use_pen: bool = False, use_mask: bool = False,
                          use_lora: bool = False, use_mrope: bool = False):
-        """``ModelRunner._decode_multi_routed_fn`` over four side buffers
-        (the full layers' and the window layers', K and V).  The rings are
-        read and not written until the frame's end."""
+        """This model's decode frame, for ``ModelRunner._decode_frame_fn``'s
+        loop: four side buffers (the full layers' and the window layers', K
+        and V).  The rings are read and not written until the frame's end."""
         self._plain("decode", lora=use_lora, mrope=use_mrope)
         cfg, module = self.model_cfg, self.module
 
-        def frame(params, inv_freq, entry_pos, kc, vc, page_tables, rk, rv, slots, attn_impl):
+        def frame(params, inv_freq, entry_pos, kc, vc, page_tables, rk, rv, slots, *,
+                  attn_impl, arms):
             holds = slots > 0  # a padded lane names the garbage slot and picks no expert
 
             def column(cur, j, side):
@@ -153,25 +161,21 @@ class WindowModelRunner(RecurrentModelRunner):
                     params, cfg, inv_freq, cur, entry_pos + j, entry_pos, j,
                     kc, vc, page_tables, rk, rv, slots, side, holds, attn_impl=attn_impl)
 
-            def land(side, ran):
+            def land(side, ran, _last):
                 hk, hv, wk, wv = side
                 return (*land_side_buffers(kc, vc, hk, hv, page_tables, entry_pos, ran),
-                        *land_ring_side(rk, rv, wk, wv, slots, entry_pos, ran))
+                        *land_ring_side(rk, rv, wk, wv, slots, entry_pos, ran)), None
 
-            return module.side_buffers(cfg, B, N, kc.dtype), column, land
+            return module.side_buffers(cfg, B, N, kc.dtype), one_token_column(column), land
 
-        return self._decode_multi_routed_fn(B, mp, N, E, use_pen, use_mask, frame,
-                                            n_held=3, donate_held=(0, 1))
+        return self._decode_frame_fn(B, mp, N, E, use_pen, use_mask, frame,
+                                     variant=(self.moe_impl,), n_held=3, donate_held=(0, 1))
 
-    # ``RecurrentModelRunner.decode_multi_async`` launches it: no frame is
-    # chained on another's ``clean`` here, and none returns one
+    # no frame is chained on another's ``clean`` here, and none returns one
 
     def _frame_state_args(self, state_slots, chain) -> list:
         return self._state_args(state_slots)
 
-    def _take_frame_state(self, out: list) -> list:
-        self.s_pool, self.c_pool, *rest = out
-        return rest
 
 
 class SelfDraftingRunner(WindowModelRunner):
@@ -213,7 +217,6 @@ class SelfDraftingRunner(WindowModelRunner):
         self.draft_buf = jnp.zeros((self.state_spec.num_slots,), jnp.int32)
         if self._device is not None:
             self.draft_buf = jax.device_put(self.draft_buf, self._device)
-        self.frame_tail = None
         self._next_token = -1
 
     def attention_info(self) -> dict:
@@ -238,9 +241,8 @@ class SelfDraftingRunner(WindowModelRunner):
         # a solo program takes the drafts behind the row's slot
         return [self.s_pool, self.c_pool, _dev(slots, jnp.int32), self.draft_buf]
 
-    def _take_frame_state(self, out: list) -> list:
-        self.s_pool, self.c_pool, self.draft_buf, *rest = out
-        return rest
+    def _take_frame_state(self, state: list) -> None:
+        self.s_pool, self.c_pool, self.draft_buf = state
 
     # ---- prefill: the stack, the first token, the module, the first draft ----
 
@@ -341,62 +343,31 @@ class SelfDraftingRunner(WindowModelRunner):
     def _decode_multi_fn(self, B: int, mp: int, N: int, E: int = 0,
                          use_pen: bool = False, use_mask: bool = False,
                          use_lora: bool = False, use_mrope: bool = False):
-        """``ModelRunner._decode_multi_routed_fn``'s loop (the same stop
-        detection and in-loop key folds, a column a fold) over verify
-        columns.  A lane accepts where nothing but its greedy token decides
-        what it emits: temperature 0, no penalties, no grammar, and the
-        device's stop state at hand (``E`` > 0) to hold it to its limit."""
+        """The verify frame, for ``ModelRunner._decode_frame_fn``'s loop (the
+        same stop detection and in-loop key folds, a column a fold): beside
+        the side buffers it carries each lane's draft, the tokens it has
+        accepted in the frame (``held``), what it emitted column by column
+        and ``[drafted, accepted]``.  A lane accepts where nothing but its
+        greedy token decides what it emits: temperature 0, no penalties, no
+        grammar, and the device's stop state at hand (``E`` > 0) to hold it to
+        its limit."""
         self._plain("decode", lora=use_lora, mrope=use_mrope)
-        use_stop = E > 0
-        may_accept = use_stop and not use_pen and not use_mask
-        attn_impl = self._attn_impl_for(B, mp)
-        k = ("decode_multi", B, mp, N, E, attn_impl, self.moe_impl, use_pen, use_mask)
-        if k in self._compiled:
-            return self._compiled[k]
+        may_accept = E > 0 and not use_pen and not use_mask
         cfg, module = self.model_cfg, self.module
         W = self.tokens_per_column
 
-        def multi(params, inv_freq, tokens, entry_pos, kc, vc, page_tables, rk, rv, slots,
-                  drafts, base_key, step0, n_steps, temps, topks, topps, minps, *extra):
-            i = 0
-            if use_pen:
-                counts_buf, pmask_buf, slot_idx, freqs, pres, reps = extra[:6]
-                i = 6
-            mask = None
-            if use_mask:
-                mask = extra[i]
-                i += 1
-            if use_stop:
-                stop_ids, limits, live = extra[i], extra[i + 1], extra[i + 2]
+        def frame(params, inv_freq, entry_pos, kc, vc, page_tables, rk, rv, slots, drafts, *,
+                  attn_impl, arms):
             holds = slots > 0  # a padded lane names the garbage slot and picks no expert
-            greedy = holds & (temps <= 0.0)
-            counts0 = counts_buf[slot_idx] if use_pen else jnp.zeros((B, 0))
-            pmask = pmask_buf[slot_idx] if use_pen else None
-            sampler = _pick_sampler()
-            done0 = (~live) if use_stop else jnp.zeros((B,), jnp.bool_)
-            ends = (lambda t: jnp.any(t[:, None] == stop_ids, axis=1)) if use_stop else None
+            greedy = holds & (arms.temps <= 0.0)
 
-            def cond(carry):
-                j, done = carry[0], carry[8]
-                ok = j < n_steps
-                if use_stop:
-                    ok = jnp.logical_and(ok, ~jnp.any(done & live))
-                return ok
-
-            def body(carry):
-                (j, cur, draft, held, toks_out, lps_out, emitted, side, done, counts, routed,
-                 spec) = carry
+            def column(cur, j, own, sample):
+                side, draft, held, emitted, spec = own
                 pos = entry_pos + held  # where ``cur`` stands
                 logits, hidden, side, c = module.forward_verify_column(
                     params, cfg, inv_freq, jnp.stack([cur, draft], axis=1), held, entry_pos,
                     kc, vc, page_tables, rk, rv, slots, side, holds, attn_impl=attn_impl)
-                routed = module.merge_counts(routed, c)
-                first = logits[:, 0]
-                if use_pen:
-                    first = apply_penalties(first, counts, pmask, freqs, pres, reps)
-                kj = jax.random.split(jax.random.fold_in(
-                    base_key, step0 + j.astype(jnp.uint32) + jnp.uint32(1)), 1)[0]
-                t0, lp0 = sampler(first, kj, temps, topks, topps, minps, mask=mask)
+                t0, lp0 = sample(logits[:, 0])
                 t0 = t0.astype(jnp.int32)
                 # the second row's token, greedy: emitted where the draft was
                 # the first row's
@@ -407,58 +378,37 @@ class SelfDraftingRunner(WindowModelRunner):
                 if may_accept:
                     # not where the first token ends the lane (a stop token,
                     # or the last one its limit leaves it)
-                    accept = greedy & (draft == t0) & ~ends(t0) & (pos + 2 < limits)
+                    accept = (greedy & (draft == t0) & ~arms.ends(t0)
+                              & (pos + 2 < arms.limits))
                 else:
                     accept = jnp.zeros((B,), jnp.bool_)
-                if use_pen:
-                    counts = counts.at[jnp.arange(B), t0].add(1)
                 n = 1 + accept.astype(jnp.int32)
-                pair = lambda a, b: jnp.stack([a, b], axis=1)[:, None]
-                toks_out = lax.dynamic_update_slice(toks_out, pair(t0, t1), (0, j, 0))
-                lps_out = lax.dynamic_update_slice(
-                    lps_out, pair(lp0, lp1).astype(jnp.float32), (0, j, 0))
+                toks = jnp.stack([t0, t1], axis=1)
                 emitted = lax.dynamic_update_slice(emitted, n[:, None], (0, j))
-                last = jnp.where(accept, t1, t0)
-                if use_stop:
-                    done = done | ends(last) | ((pos + n) >= (limits - 1))
+                last, reach = jnp.where(accept, t1, t0), pos + n
                 # the module over the rows accepted, for the next draft
-                draft, side, c = module.forward_mtp_draft(
-                    params, cfg, inv_freq, hidden, pair(t0, t1)[:, 0], accept, held, entry_pos,
+                draft, side, c2 = module.forward_mtp_draft(
+                    params, cfg, inv_freq, hidden, toks, accept, held, entry_pos,
                     kc, vc, page_tables, side, holds, attn_impl=attn_impl)
-                routed = module.merge_counts(routed, c)
                 spec = spec + jnp.stack([jnp.sum(greedy) if may_accept else 0,
                                          jnp.sum(accept)]).astype(jnp.int32)
-                return (j + 1, last, draft, held + n, toks_out, lps_out,
-                        emitted, side, done, counts, routed, spec)
+                return (toks, jnp.stack([lp0, lp1], axis=1), last, reach,
+                        (side, draft, held + n, emitted, spec), module.merge_counts(c, c2))
 
-            init = (jnp.int32(0), tokens, drafts[slots], jnp.zeros((B,), jnp.int32),
-                    jnp.zeros((B, N, W), jnp.int32), jnp.zeros((B, N, W), jnp.float32),
-                    jnp.zeros((B, N), jnp.int32), module.side_buffers(cfg, B, W * N, kc.dtype),
-                    done0, counts0, jnp.zeros((len(module.ROUTED_COUNTS),), jnp.int32),
+            def land(own, _ran, last):
+                (hk, hv, wk, wv), draft, held, emitted, spec = own
+                # a lane's side rows below ``held`` are the tokens it accepted
+                keep = jnp.arange(W * N)[None, :] < held[:, None]
+                return ((*land_side_buffers(kc, vc, hk, hv, page_tables, entry_pos, keep),
+                         *land_ring_side(rk, rv, wk, wv, slots, entry_pos, keep),
+                         drafts.at[slots].set(draft)),
+                        (emitted, last, entry_pos + held, spec))
+
+            own0 = (module.side_buffers(cfg, B, W * N, kc.dtype), drafts[slots],
+                    jnp.zeros((B,), jnp.int32), jnp.zeros((B, N), jnp.int32),
                     jnp.zeros((2,), jnp.int32))
-            (steps_run, last, draft, held, outs, lps, emitted, side, _done, counts, routed,
-             spec) = lax.while_loop(cond, body, init)
-            # a lane's side rows below ``held`` are the tokens it accepted
-            hk, hv, wk, wv = side
-            keep = jnp.arange(W * N)[None, :] < held[:, None]
-            out = (outs, lps, steps_run,
-                   *land_side_buffers(kc, vc, hk, hv, page_tables, entry_pos, keep),
-                   *land_ring_side(rk, rv, wk, wv, slots, entry_pos, keep),
-                   drafts.at[slots].set(draft))
-            if use_pen:
-                out += (counts_buf.at[slot_idx].set(counts),)
-            return out + (routed, (emitted, last, entry_pos + held, spec))
+            return own0, column, land
 
-        donate = (4, 5, 7, 8, 10) + ((18,) if use_pen else ())
-        if not self.donation.donate_kv:
-            donate = ()
-        fn = self._register(k, jax.jit(multi, donate_argnums=donate), donate=donate,
-                            in_shardings=None, attn=_attn_label("decode", attn_impl),
-                            products=("fused_lanes" if attn_impl == "xla" else None))
-
-        def launch(*args):
-            *out, self.frame_counts, self.frame_tail = fn(*args)
-            return out
-
-        self._compiled[k] = launch
-        return launch
+        return self._decode_frame_fn(B, mp, N, E, use_pen, use_mask, frame,
+                                     variant=(self.moe_impl,), n_held=4,
+                                     donate_held=(0, 1, 3), W=W)

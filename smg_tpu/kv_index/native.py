@@ -9,7 +9,7 @@ pure-Python ``RadixTree`` serves instead; it has the same interface, so the
 cache_aware policy can swap implementations (``SMG_NATIVE_RADIX=0`` forces
 Python).
 
-Measured (benches/bench_gateway.py): at small trees the FFI boundary makes
+Measured on a CPU host (a harness from before the chip, since removed): at small trees the FFI boundary makes
 the implementations comparable; at 30k sequences x 64-512 tokens the native
 tree leads (insert 0.69s vs 0.86s, match 35.5k vs 33.9k ops/s) and its
 memory stays flat where Python dict nodes bloat — the gap widens with tree

@@ -275,7 +275,7 @@ def shared_expert(layer: Params, x, cfg: ModelConfig):
 
 
 #: what a decode frame counts of its routed experts, in the frame's order
-#: (``engine/runner._decode_multi_routed_fn``): token-expert pairs, those on
+#: (``engine/runner.ModelRunner._decode_frame_fn``): token-expert pairs, those on
 #: held experts (rows computed), held experts hit summed over layers and
 #: columns, and the most rows one layer and column computed.  The scheduler
 #: and the step ring read the counts by these names.

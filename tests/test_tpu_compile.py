@@ -6,13 +6,14 @@ libtpu compiles for a topology it does not have
 here, on the CPU, before it costs chip time.  This checks compilation and
 compile-time memory only; what the kernels compute on the chip is
 ``chip_smoke.py`` phase b's business, and interpret-mode parity is
-``test_pallas_*.py``'s.
+``test_pallas_*.py``'s.  The Llama family's kernels and programs are here;
+the latent runner's are in ``test_tpu_compile_latent.py`` and the window
+runners' in ``test_tpu_compile_window.py`` (``v5e_compile.py`` is what the
+three share).
 """
 
-import collections
 import dataclasses
 import functools
-import math
 import re
 
 import jax
@@ -50,10 +51,10 @@ from smg_tpu.ops.pallas.prefill_attention import paged_attention_prefill
 from smg_tpu.parallel.mesh import build_mesh
 from smg_tpu.parallel.sharding import ShardingRules, logical_to_sharding, tree_shardings
 
+from tests.v5e_compile import BF16, PS, _collectives, _compile, _relayouts, v5e  # noqa: F401
+
 CFG = llama32_1b_config()
-PS = 16
 KD = CFG.num_kv_heads * CFG.head_dim
-BF16 = jnp.bfloat16
 
 
 def _rule(platform, mesh=None, attention_impl="auto", model=CFG, cls=ModelRunner) -> ModelRunner:
@@ -249,52 +250,6 @@ class TestDispatchRule:
         assert r.attn_impl == "auto"
         assert r._prefill_impl_for(4096, 512) == "pallas"
         assert r._attn_impl_for(64, 256) == "pallas"
-
-
-@pytest.fixture(scope="module")
-def v5e():
-    from jax.experimental import topologies
-
-    try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — whatever libtpu raises without support
-        pytest.skip(f"libtpu cannot build the v5e:2x2 topology here: {e}")
-    return list(topo.devices)
-
-
-def _compile(fn, *args):
-    return jax.jit(fn).lower(*args).compile()
-
-
-_HLO_OP = re.compile(r"= \w+\[([\d,]*)\]\S* (\w[\w-]*)\((.*)")
-
-
-def _relayouts(hlo: str, min_elements: int) -> list[str]:
-    """Instructions of the compiled text that move an array of at least
-    ``min_elements`` into another layout: every ``copy``, and every
-    ``transpose`` whose permutation is not the identity."""
-    found = []
-    for line in hlo.splitlines():
-        m = _HLO_OP.search(line)
-        if not m or m.group(2) not in ("copy", "transpose"):
-            continue
-        dims = [int(d) for d in m.group(1).split(",") if d]
-        if math.prod(dims) < min_elements:
-            continue
-        perm = re.search(r"dimensions=\{([\d,]*)\}", m.group(3))
-        if m.group(2) == "transpose" and perm and [
-                int(d) for d in perm.group(1).split(",")] == list(range(len(dims))):
-            continue
-        found.append(line.strip()[:160])
-    return found
-
-
-def _collectives(hlo: str) -> collections.Counter:
-    """Collective operations in the compiled text (an async pair counts
-    once, at its ``-start``)."""
-    return collections.Counter(re.findall(
-        r" (all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
-        r"(?:-start)?\(", hlo))
 
 
 class TestCompilesForV5e:
@@ -501,373 +456,3 @@ class TestCompilesForV5e:
             s((B, mp), jnp.int32), side, side,
         )
         assert _collectives(compiled.as_text()) == {"all-reduce": 3}
-
-
-PANGU_CUT = {
-    "model_type": "pangu_ultra_moe", "sandwich_norm": True, "hidden_size": 7680,
-    "intermediate_size": 18432, "moe_intermediate_size": 2048, "num_attention_heads": 128,
-    "num_key_value_heads": 128, "q_lora_rank": 1536, "kv_lora_rank": 512,
-    "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "v_head_dim": 128, "n_shared_experts": 1,
-    "num_experts_per_tok": 8, "routed_scaling_factor": 2.5, "rope_theta": 25600000,
-    # the benchmark's cut (benchmark/configs/openpangu-ultra-moe-718b.json)
-    "num_hidden_layers": 5, "first_k_dense_replace": 1, "n_routed_experts": 16,
-    "router_num_experts": 256, "vocab_size": 19200}
-
-
-class TestLatentModelCompilesForV5e:
-    """``models/pangu_moe.py`` at the widths of the benchmark's cut."""
-
-    @pytest.mark.parametrize("B", [8, 64])
-    def test_a_decode_frame_runs_both_kernels_and_copies_no_weights(self, v5e, B):
-        """A frame is a loop of columns over a scan of layers.  Both kernels
-        are in it under their own names, and nothing moves a weight into
-        another layout: stored otherwise, the heads' projections were copied
-        a launch (0.6 GB of temporaries) or a layer and column (75 MB), and
-        an expert layer sliced out of its stack for the kernel would be a
-        copy of 1.4 GB (``models/pangu_moe.init_params``,
-        ``ops/pallas/moe_experts.py``)."""
-        from smg_tpu.models import pangu_moe as M
-        from smg_tpu.models.config import ModelConfig
-        from smg_tpu.ops.latent_attention import land_side_buffer
-
-        cfg = ModelConfig.from_hf_config(PANGU_CUT)
-        one = SingleDeviceSharding(v5e[0])
-        s = lambda shape, dtype=BF16: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-        i32 = jnp.int32
-        params = jax.tree.map(
-            lambda x: s(x.shape, x.dtype),
-            jax.eval_shape(functools.partial(M.init_params, cfg), jax.random.PRNGKey(0)))
-        L, mp, N, P, W = cfg.num_layers, 512, 8, 30000, M.cache_lanes(cfg)
-
-        def frame(p, inv, tok, entry, kc, tables, n_steps):
-            holds = entry < mp * PS
-
-            def body(c):
-                j, cur, side, counts = c
-                logits, side, k = M.forward_decode_horizon(
-                    p, cfg, inv, cur, entry + j, entry, j, kc, tables, side, holds,
-                    attn_impl="pallas", moe_impl="pallas")
-                return j + 1, jnp.argmax(logits, -1).astype(i32), side, counts + k
-
-            j, cur, side, counts = jax.lax.while_loop(
-                lambda c: c[0] < n_steps, body,
-                (i32(0), tok, jnp.zeros((L, B, N, W), kc.dtype), jnp.zeros((4,), i32)))
-            return cur, land_side_buffer(kc, side, tables, entry, jnp.arange(N)[None] < j), counts
-
-        compiled = jax.jit(frame, donate_argnums=(4,)).lower(
-            params, s((cfg.rope_dim // 2,), jnp.float32), s((B,), i32), s((B,), i32),
-            s((L, P, PS, W)), s((B, mp), i32), s((), i32)).compile()
-        assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
-        hlo = compiled.as_text()
-        assert _relayouts(hlo, 8 * 2**20) == []  # a 64-lane column moves its own 4 M queries
-        calls = collections.Counter(
-            re.findall(r"%(smg\.[\w.]+?)\.\d+ = \S+ custom-call", hlo))
-        # once in each scanned stack's body: attention in both stacks, the
-        # three grouped products in the expert stack
-        assert calls == {"smg.attn.decode": 2, "smg.moe.experts": 3}
-
-
-class TestWindowModelCompilesForV5e:
-    """``models/mimo.py`` at the widths of the benchmark's cut
-    (``benchmark/configs/mimo-v2-flash.json``)."""
-
-    @staticmethod
-    def cut():
-        import json
-        import os
-
-        from smg_tpu.models.config import ModelConfig
-
-        path = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "benchmark",
-                            "configs", "mimo-v2-flash.json")
-        own = {"assumed", "deployment", "chips", "serve_args", "rehearsal", "architecture",
-               "reduced", "published"}
-        with open(path) as f:
-            return ModelConfig.from_hf_config(
-                {k: v for k, v in json.load(f).items() if k not in own})
-
-    @pytest.mark.parametrize("B", [8, 64])
-    def test_a_decode_frame_runs_its_kernels_and_copies_no_weights(self, v5e, B):
-        """A frame is a loop of columns over three scans of layers.  The paged
-        kernel (K of 768 lanes, V of 512) and the ring kernel are in it under
-        their own names, the second not beginning with the first's (a trace
-        counts columns by the paged kernel's name), and nothing moves a
-        weight into another layout: stored ``[in, out]`` the input
-        projections of a window layer were copied a layer and column, 121 MB
-        (``models/mimo.init_params``)."""
-        from smg_tpu.models import mimo as M
-        from smg_tpu.ops.attention import land_side_buffers
-        from smg_tpu.ops.window_attention import land_ring_side
-
-        cfg = self.cut()
-        one = SingleDeviceSharding(v5e[0])
-        s = lambda shape, dtype=BF16: jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one)
-        i32 = jnp.int32
-        params = jax.tree.map(
-            lambda x: s(x.shape, x.dtype),
-            jax.eval_shape(functools.partial(M.init_params, cfg), jax.random.PRNGKey(0)))
-        mp, N, P, slots, R = 512, 8, 30000, 73, 144
-
-        def frame(p, inv, tok, entry, kc, vc, tables, rk, rv, lane_slots, n_steps):
-            holds = lane_slots > 0
-
-            def body(c):
-                j, cur, side, counts = c
-                logits, side, k = M.forward_decode_horizon(
-                    p, cfg, inv, cur, entry + j, entry, j, kc, vc, tables, rk, rv, lane_slots,
-                    side, holds, attn_impl="pallas", moe_impl="pallas")
-                return j + 1, jnp.argmax(logits, -1).astype(i32), side, counts + k
-
-            j, cur, (hk, hv, wk, wv), counts = jax.lax.while_loop(
-                lambda c: c[0] < n_steps, body,
-                (i32(0), tok, M.side_buffers(cfg, B, N, kc.dtype), jnp.zeros((4,), i32)))
-            ran = jnp.arange(N)[None] < j
-            kc, vc = land_side_buffers(kc, vc, hk, hv, tables, entry, ran)
-            rk, rv = land_ring_side(rk, rv, wk, wv, lane_slots, entry, ran)
-            return cur, kc, vc, rk, rv, counts
-
-        compiled = jax.jit(frame, donate_argnums=(4, 5, 7, 8)).lower(
-            params, s((cfg.rope_dim // 2,), jnp.float32), s((B,), i32), s((B,), i32),
-            s((2, P, PS, 768)), s((2, P, PS, 512)), s((B, mp), i32),
-            s((5, slots, R, 1536)), s((5, slots, R, 1024)), s((B,), i32), s((), i32)).compile()
-        assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
-        hlo = compiled.as_text()
-        assert _relayouts(hlo, 10 * 2**20) == []  # under a projection's size: none is copied
-        calls = collections.Counter(
-            re.findall(r"%(smg\.[\w.]+?)\.\d+ = \S+ custom-call", hlo))
-        # once in each scanned run's body: the paged kernel in the two full
-        # runs, the ring kernel in the window run, the three grouped products
-        # in the two runs with experts
-        assert calls == {"smg.attn.decode": 2, "smg.attn.window_decode": 1,
-                         "smg.moe.experts": 6}
-        assert not "smg.attn.window_decode".startswith("smg.attn.decode")
-
-    def test_a_prefill_of_a_steps_budget_fits_its_workspace(self, v5e):
-        """4,096 tokens in one row: no ``[heads, T, context]`` float32 array
-        (4.3 GB at T = context = 4,096), and the program's temporaries inside
-        what ``plan_window_cache`` keeps free of pages."""
-        from smg_tpu.models import mimo as M
-
-        cfg = self.cut()
-        one = SingleDeviceSharding(v5e[0])
-        s = lambda shape, dtype=BF16: jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one)
-        i32 = jnp.int32
-        params = jax.tree.map(
-            lambda x: s(x.shape, x.dtype),
-            jax.eval_shape(functools.partial(M.init_params, cfg), jax.random.PRNGKey(0)))
-        T, mp, P, slots, R = 4096, 512, 30000, 73, 144
-        compiled = jax.jit(
-            lambda p, inv, *a: M.forward_prefill(p, cfg, inv, *a, moe_impl="pallas"),
-            donate_argnums=(5, 6, 8, 9)).lower(
-            params, s((cfg.rope_dim // 2,), jnp.float32), s((T,), i32), s((), i32), s((), i32),
-            s((2, P, PS, 768)), s((2, P, PS, 512)), s((mp,), i32),
-            s((5, slots, R, 1536)), s((5, slots, R, 1024)), s((), i32)).compile()
-        temp = compiled.memory_analysis().temp_size_in_bytes
-        assert temp < M.prefill_workspace_bytes(cfg, T, "bfloat16") < 2 * 2**30
-
-
-class TestSelfDraftingModelCompilesForV5e:
-    """``models/exaone_moe.py`` at the widths of the benchmark's cut
-    (``benchmark/configs/k-exaone-236b-a23b.json``), the module drafting."""
-
-    @staticmethod
-    def cut():
-        import json
-        import os
-
-        from smg_tpu.models.config import ModelConfig
-
-        path = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "benchmark",
-                            "configs", "k-exaone-236b-a23b.json")
-        own = {"assumed", "deployment", "chips", "serve_args", "rehearsal", "architecture",
-               "reduced", "published"}
-        with open(path) as f:
-            return ModelConfig.from_hf_config(
-                {k: v for k, v in json.load(f).items() if k not in own})
-
-    @staticmethod
-    def shapes(cfg, device):
-        from smg_tpu.models import exaone_moe as X
-
-        one = SingleDeviceSharding(device)
-        s = lambda shape, dtype=BF16: jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one)
-        params = jax.tree.map(
-            lambda x: s(x.shape, x.dtype),
-            jax.eval_shape(functools.partial(X.init_params, cfg), jax.random.PRNGKey(0)))
-        return s, params
-
-    @pytest.mark.parametrize("B", [8, 64])
-    def test_a_verify_frame_runs_its_kernels_and_copies_no_weights(self, v5e, B):
-        """A verify frame is a loop of columns, each the stack over two rows a
-        lane and the module behind it.  The paged kernel's two-row form keeps
-        the paged kernel's name (a trace counts columns by it: the full layer
-        and the module's attention), the ring kernel's two-row form has a name
-        of its own, the module lies between its two marks, and nothing moves
-        a weight into another layout."""
-        from smg_tpu.models import exaone_moe as X
-        from smg_tpu.ops.attention import land_side_buffers
-        from smg_tpu.ops.window_attention import land_ring_side
-
-        cfg = self.cut()
-        s, params = self.shapes(cfg, v5e[0])
-        i32 = jnp.int32
-        mp, N, P, slots, R = 512, 8, 30000, 73, 160
-
-        def frame(p, inv, tok, draft, entry, kc, vc, tables, rk, rv, lane_slots, n_steps):
-            holds = lane_slots > 0
-
-            def body(c):
-                j, cur, draft, held, side, counts = c
-                logits, hidden, side, k = X.forward_verify_column(
-                    p, cfg, inv, jnp.stack([cur, draft], 1), held, entry, kc, vc, tables, rk,
-                    rv, lane_slots, side, holds, attn_impl="pallas", moe_impl="pallas")
-                t = jnp.argmax(logits, -1).astype(i32)
-                accept = holds & (t[:, 0] == draft)
-                draft, side, k2 = X.forward_mtp_draft(
-                    p, cfg, inv, hidden, t, accept, held, entry, kc, vc, tables, side, holds,
-                    attn_impl="pallas", moe_impl="pallas")
-                return (j + 1, jnp.where(accept, t[:, 1], t[:, 0]), draft,
-                        held + 1 + accept.astype(i32), side, counts + k + k2)
-
-            j, cur, draft, held, (hk, hv, wk, wv), counts = jax.lax.while_loop(
-                lambda c: c[0] < n_steps, body,
-                (i32(0), tok, draft, jnp.zeros((B,), i32),
-                 X.side_buffers(cfg, B, 2 * N, kc.dtype), jnp.zeros((4,), i32)))
-            keep = jnp.arange(2 * N)[None] < held[:, None]
-            kc, vc = land_side_buffers(kc, vc, hk, hv, tables, entry, keep)
-            rk, rv = land_ring_side(rk, rv, wk, wv, lane_slots, entry, keep)
-            return cur, draft, kc, vc, rk, rv, counts
-
-        compiled = jax.jit(frame, donate_argnums=(5, 6, 8, 9)).lower(
-            params, s((cfg.rope_dim // 2,), jnp.float32), s((B,), i32), s((B,), i32),
-            s((B,), i32), s((2, P, PS, 1024)), s((2, P, PS, 1024)), s((B, mp), i32),
-            s((4, slots, R, 1024)), s((4, slots, R, 1024)), s((B,), i32), s((), i32)).compile()
-        assert compiled.memory_analysis().temp_size_in_bytes < 96 * 2**20
-        hlo = compiled.as_text()
-        assert _relayouts(hlo, 10 * 2**20) == []  # under a projection's size: none is copied
-        calls = collections.Counter(
-            re.findall(r"%(smg\.[\w.]+?)\.\d+ = \S+ custom-call", hlo))
-        # once in each scanned run's body: the ring kernel in the two window
-        # runs, the paged kernel in the full run and in the module, the three
-        # grouped products in the three runs with experts
-        assert calls == {"smg.attn.decode": 2, "smg.attn.window_verify": 2,
-                         "smg.moe.experts": 9, "smg.mtp.begin": 1, "smg.mtp.end": 1}
-
-    def test_a_prefill_of_a_steps_budget_with_the_module_fits_its_workspace(self, v5e):
-        from smg_tpu.models import exaone_moe as X
-
-        cfg = self.cut()
-        s, params = self.shapes(cfg, v5e[0])
-        i32 = jnp.int32
-        T, mp, P, slots, R = 4096, 512, 30000, 73, 160
-
-        def step(p, inv, tokens, lo, n, kc, vc, table, rk, rv, slot):
-            out, kc, vc, rk, rv, hidden = X.forward_prefill(
-                p, cfg, inv, tokens, lo, n, kc, vc, table, rk, rv, slot, moe_impl="pallas",
-                with_hidden=True)
-            first = jnp.argmax(out).astype(i32)
-            m_out, kc, vc = X.forward_mtp_prefill(
-                p, cfg, inv, hidden, tokens[None], first[None], lo[None], n[None], kc, vc,
-                table[None], moe_impl="pallas")
-            return first, jnp.argmax(m_out[0]), kc, vc, rk, rv
-
-        compiled = jax.jit(step, donate_argnums=(5, 6, 8, 9)).lower(
-            params, s((cfg.rope_dim // 2,), jnp.float32), s((T,), i32), s((), i32), s((), i32),
-            s((2, P, PS, 1024)), s((2, P, PS, 1024)), s((mp,), i32),
-            s((4, slots, R, 1024)), s((4, slots, R, 1024)), s((), i32)).compile()
-        temp = compiled.memory_analysis().temp_size_in_bytes
-        assert temp < X.prefill_workspace_bytes(cfg, T, "bfloat16") < 2 * 2**30
-
-
-class TestDoubleBlockModelCompilesForV5e:
-    """``models/longcat_flash.py`` at the widths of the benchmark's cut
-    (``benchmark/configs/longcat-flash-chat.json``): two attention sublayers a
-    layer over 8 cache layers, the expert branch a shortcut round the second."""
-
-    @staticmethod
-    def cut():
-        import json
-        import os
-
-        from smg_tpu.models.config import ModelConfig
-
-        path = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "benchmark",
-                            "configs", "longcat-flash-chat.json")
-        own = {"assumed", "deployment", "chips", "serve_args", "rehearsal", "architecture",
-               "reduced", "published"}
-        with open(path) as f:
-            return ModelConfig.from_hf_config(
-                {k: v for k, v in json.load(f).items() if k not in own})
-
-    @staticmethod
-    def shapes(cfg, device):
-        from smg_tpu.models import longcat_flash as M
-
-        one = SingleDeviceSharding(device)
-        s = lambda shape, dtype=BF16: jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one)
-        params = jax.tree.map(
-            lambda x: s(x.shape, x.dtype),
-            jax.eval_shape(functools.partial(M.init_params, cfg), jax.random.PRNGKey(0)))
-        return s, params
-
-    @pytest.mark.parametrize("B", [8, 64])
-    def test_a_decode_frame_runs_its_kernels_and_copies_no_weights(self, v5e, B):
-        """One scanned body: the latent decode kernel twice (a sublayer each),
-        the three grouped products once, and no
-        weight moved into another layout (the two sublayers' matrices are two
-        stacks, so that none is sliced out of a pair)."""
-        from smg_tpu.models import longcat_flash as M
-        from smg_tpu.ops.latent_attention import land_side_buffer
-
-        cfg = self.cut()
-        assert (cfg.num_layers, cfg.num_cache_layers, cfg.num_heads) == (4, 8, 64)
-        s, params = self.shapes(cfg, v5e[0])
-        i32 = jnp.int32
-        L, mp, N, P, W = cfg.num_cache_layers, 512, 8, 20000, M.cache_lanes(cfg)
-
-        def frame(p, inv, tok, entry, kc, tables, n_steps):
-            holds = entry < mp * PS
-
-            def body(c):
-                j, cur, side, counts = c
-                logits, side, k = M.forward_decode_horizon(
-                    p, cfg, inv, cur, entry + j, entry, j, kc, tables, side, holds,
-                    attn_impl="pallas", moe_impl="pallas")
-                return j + 1, jnp.argmax(logits, -1).astype(i32), side, M.merge_counts(counts, k)
-
-            j, cur, side, counts = jax.lax.while_loop(
-                lambda c: c[0] < n_steps, body,
-                (i32(0), tok, jnp.zeros((L, B, N, W), kc.dtype),
-                 jnp.zeros((len(M.ROUTED_COUNTS),), i32)))
-            return cur, land_side_buffer(kc, side, tables, entry, jnp.arange(N)[None] < j), counts
-
-        compiled = jax.jit(frame, donate_argnums=(4,)).lower(
-            params, s((cfg.rope_dim // 2,), jnp.float32), s((B,), i32), s((B,), i32),
-            s((L, P, PS, W)), s((B, mp), i32), s((), i32)).compile()
-        assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
-        hlo = compiled.as_text()
-        assert _relayouts(hlo, 8 * 2**20) == []
-        calls = collections.Counter(
-            re.findall(r"%(smg\.[\w.]+?)\.\d+ = \S+ custom-call", hlo))
-        assert calls == {"smg.attn.decode": 2, "smg.moe.experts": 3}
-
-    def test_a_prefill_of_a_steps_budget_fits_its_workspace(self, v5e):
-        """4,096 tokens in one row, 12 picks a token through the rows' buffer
-        in passes: the program's temporaries inside what ``plan_latent_cache``
-        keeps free of pages; the branch's scopes are in the program's text."""
-        from smg_tpu.models import longcat_flash as M
-
-        cfg = self.cut()
-        s, params = self.shapes(cfg, v5e[0])
-        i32 = jnp.int32
-        T, mp, P, W = 4096, 512, 20000, M.cache_lanes(cfg)
-        compiled = jax.jit(
-            lambda p, inv, *a: M.forward_prefill(p, cfg, inv, *a, moe_impl="pallas"),
-            donate_argnums=(5,)).lower(
-            params, s((cfg.rope_dim // 2,), jnp.float32), s((T,), i32), s((), i32), s((), i32),
-            s((cfg.num_cache_layers, P, PS, W)), s((cfg.num_cache_layers, 0, PS, W)),
-            s((mp,), i32)).compile()
-        temp = compiled.memory_analysis().temp_size_in_bytes
-        assert temp < M.prefill_workspace_bytes(cfg, T, "bfloat16") < 2 * 2**30
-        hlo = compiled.as_text()
-        assert "smg.scmoe.shortcut" in hlo and "smg.moe.zero" in hlo
