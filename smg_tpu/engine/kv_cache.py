@@ -87,9 +87,8 @@ class StateSlotPool:
 
 @dataclass
 class StateSpec:
-    """The two state pools of a model with recurrent layers (shapes as
-    ``models/olmo_hybrid.state_shapes`` gives them, the garbage slot
-    included)."""
+    """The two state pools of a model with recurrent layers (shapes as the
+    module's ``state_shapes`` gives them, the garbage slot included)."""
 
     num_slots: int  # allocatable slots + the garbage slot
     state_shape: tuple[int, ...]  # float32
@@ -219,6 +218,7 @@ def plan_recurrent_cache(
     state_shapes,
     hbm_limit: int | None = None,
     hbm_in_use: int = 0,
+    workspace: int = 0,
 ) -> tuple[KvCacheSpec, StateSpec]:
     """Split what the device has left between state slots and pages, for a
     model whose layers are not all attention.  Pages exist only for the
@@ -230,7 +230,8 @@ def plan_recurrent_cache(
     the weights are on it**: the budget is ``hbm_utilization`` of the whole
     device less what is in use, so the weights come off once
     (``plan_cache`` takes them off what is free after them, a second time,
-    PERF.md 7.3a; that path is left as it is)."""
+    PERF.md 7.3a; that path is left as it is).  ``workspace`` is kept free of
+    pages, as in ``plan_latent_cache``."""
     s_shape, c_shape = state_shapes(model, state_slots + 1)
     state = StateSpec(num_slots=state_slots + 1, state_shape=tuple(s_shape),
                       conv_shape=tuple(c_shape), conv_dtype=model.dtype)
@@ -243,7 +244,8 @@ def plan_recurrent_cache(
         dtype=cache.dtype,
     )
     if cache.auto_size and hbm_limit is not None:
-        budget = int(hbm_limit * cache.hbm_utilization) - hbm_in_use - state.total_bytes
+        budget = (int(hbm_limit * cache.hbm_utilization) - hbm_in_use - state.total_bytes
+                  - workspace)
         spec.num_pages = int(max(budget // spec.bytes_per_page, 16))
     return spec, state
 

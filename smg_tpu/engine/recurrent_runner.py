@@ -3,8 +3,13 @@
 ``RecurrentModelRunner`` is ``ModelRunner`` with a second kind of
 per-sequence memory: beside the pages of the full-attention layers every
 sequence holds one **state slot** (``kv_cache.StateSlotPool`` hands them out;
-the two device pools are ``s_pool`` and ``c_pool``, laid out as
-``models/olmo_hybrid.state_shapes`` says).  It builds the same four program
+the two device pools are ``s_pool`` and ``c_pool``, laid out as the module's
+``state_shapes`` says: ``models/olmo_hybrid.py``'s gated delta rule,
+``models/nemotron_h.py``'s state-space layers).  What the recurrent layers'
+decode step is, and whether its kernel fits the model's shape, is the
+module's to say (``decode_step``); a module with routed experts beside its
+state has their grouped products bound as the latent runner binds them and
+its frames carry the routed counts (``ROUTED_COUNTS``).  It builds the same four program
 families under the same names and positional signatures (``prefill``,
 ``prefill_extend``, ``prefill_batched``, ``decode_multi_async``); the slot of
 each row arrives as a keyword whose default is the garbage slot 0, so a caller
@@ -48,6 +53,23 @@ class RecurrentModelRunner(ModelRunner):
     # lanes their state (``Scheduler._state_lost``)
     frames_advance_state = True
 
+    def __init__(self, config, params=None, devices=None):
+        super().__init__(config, params=params, devices=devices)
+        if hasattr(self.module, "ROUTED_COUNTS"):
+            # the expert layers' grouped products: the kernel on a TPU, XLA's
+            # ragged product elsewhere.  ``moe_info`` is the scheduler's sign
+            # that this runner's frames carry routed counts
+            self._bind_moe_impl("pallas" if self.platform == "tpu"
+                                and config.attention_impl != "xla" else "xla")
+            self.moe_info = self._moe_info
+            logger.info("expert layers %s, experts held %s of %d", self.moe_impl,
+                        self.model_cfg.held_experts, self.model_cfg.num_experts)
+
+    def _moe_info(self) -> dict:
+        cfg = self.model_cfg
+        return {"experts": cfg.num_experts, "experts_held": cfg.held_experts[1],
+                "top_k": cfg.num_experts_per_tok, "impl": self.moe_impl}
+
     # ---- what a sequence holds ----
 
     def _plan_cache(self, param_bytes: int):
@@ -62,9 +84,11 @@ class RecurrentModelRunner(ModelRunner):
             limit, in_use = stats["bytes_limit"], stats.get("bytes_in_use", 0)
         elif self.platform == "tpu":
             raise RuntimeError("the TPU reports no memory_stats(); cannot size the caches")
+        workspace = getattr(self.module, "prefill_workspace_bytes", None)
         spec, self.state_spec = plan_recurrent_cache(
             cfg, self.config.cache, sched.max_batch_size + sched.max_prefill_group,
-            self.module.state_shapes, limit, in_use)
+            self.module.state_shapes, limit, in_use,
+            workspace(cfg, sched.max_prefill_tokens, self.config.dtype) if workspace else 0)
         return spec
 
     def _create_state_buffers(self) -> None:
@@ -77,25 +101,39 @@ class RecurrentModelRunner(ModelRunner):
         # what a frame that chains on none is given for the ``frame_clean``
         # of the frame before it (see the module docstring)
         self._unchained = self._scalar_up(np.bool_(True))
-        from smg_tpu.ops.pallas import linattn_decode
-
-        self.linattn_kernel_fits = linattn_decode.supported(
-            self.model_cfg.linear_num_heads, self.model_cfg.linear_key_head_dim,
-            self.model_cfg.linear_value_head_dim)
-        # the linear layers' decode step: the kernel on a TPU where its blocks
-        # fit the state's shape, the XLA form elsewhere
-        self.linattn_impl = ("pallas" if self.platform == "tpu" and self.linattn_kernel_fits
-                             and self.config.attention_impl != "xla" else "xla")
+        # the recurrent layers' decode step, as the module describes it: the
+        # kernel on a TPU where its blocks fit the state's shape, the XLA
+        # form elsewhere
+        self.state_step = step = self.module.decode_step(self.model_cfg)
+        self.state_kernel_fits = step["kernel_fits"]
+        self.state_impl = ("pallas" if self.platform == "tpu" and self.state_kernel_fits
+                           and self.config.attention_impl != "xla" else "xla")
         logger.info(
-            "state slots: %d x %.1f MiB (%d linear-attention layers), decode step %s; "
+            "state slots: %d x %.1f MiB (%d %s layers), decode step %s; "
             "pages for %d full-attention layers",
-            st.num_slots - 1, st.slot_bytes / 2**20, st.state_shape[0], self.linattn_impl,
-            self.spec.num_layers)
+            st.num_slots - 1, st.slot_bytes / 2**20, st.state_shape[0], step["layers"],
+            self.state_impl, self.spec.num_layers)
+
+    # the names ``benchmark/architectures/olmo_hybrid.py`` reads the two by
+    linattn_impl = property(lambda self: self.state_impl)
+    linattn_kernel_fits = property(lambda self: self.state_kernel_fits)
 
     def state_info(self) -> dict:
+        """Slots, a slot's bytes and which decode step runs, under the
+        module's name for it (``linattn_decode``, ``ssm_decode``)."""
         st = self.state_spec
         return {"slots_total": st.num_slots - 1, "slot_bytes": st.slot_bytes,
-                "linattn_decode": self.linattn_impl}
+                self.state_step["name"]: self.state_impl}
+
+    @property
+    def widest_table_only(self) -> bool:
+        """Decode programs are compiled at the widest page table alone where
+        the paged kernel runs: it reads each lane's own pages by its
+        ``entry`` whatever the table's width, and the recurrent layers read
+        no table (the latent and the window runners' rule).  Only decode
+        frames take a table bucket (``Scheduler._mp_bucket``): a prefill's
+        table is the whole one either way."""
+        return self._attn_impl_for(0, 0) == "pallas"
 
     def _prefill_impl_for(self, T: int, mp: int) -> str:
         """A chunk that continues a prompt runs on XLA attention here: the
@@ -214,21 +252,23 @@ class RecurrentModelRunner(ModelRunner):
         and V side buffers, and the frame is ``chained``: launched ahead of
         one that met a finish it runs no column at all."""
         self._plain("decode", lora=use_lora, mrope=use_mrope)
-        lin_impl = self.linattn_impl
+        step_impl = {self.state_step["arg"]: self.state_impl}
         cfg, module = self.model_cfg, self.module
+        routed = hasattr(module, "ROUTED_COUNTS")
         KD = cfg.num_kv_heads * cfg.head_dim
         L = cfg.num_cache_layers
 
         def frame(params, inv_freq, entry_pos, kc, vc, page_tables, sp, cp, slots, _chain, *,
                   attn_impl, arms):
-            runs = slots > 0
+            runs = slots > 0  # a lane on the garbage slot does not run and picks no expert
 
             def column(cur, j, side):
                 logits, *side = module.forward_decode_horizon(
                     params, cfg, inv_freq, cur, entry_pos + j, entry_pos, j,
                     kc, vc, page_tables, *side, slots, runs,
-                    attn_impl=attn_impl, linattn_impl=lin_impl)
-                return logits, tuple(side), None
+                    attn_impl=attn_impl, **step_impl)
+                counts = side.pop() if routed else None
+                return logits, tuple(side), counts
 
             def land(side, ran, _last):
                 hk, hv, sp, cp = side
@@ -239,8 +279,9 @@ class RecurrentModelRunner(ModelRunner):
             hv0 = jnp.zeros((L, B, N, KD), kc.dtype)
             return (hk0, hv0, sp, cp), one_token_column(column), land
 
+        variant = (self.state_impl, *((self.moe_impl,) if routed else ()))
         return self._decode_frame_fn(B, mp, N, E, use_pen, use_mask, frame,
-                                     variant=(lin_impl,), n_held=4, donate_held=(0, 1),
+                                     variant=variant, n_held=4, donate_held=(0, 1),
                                      chained=True)
 
     # ---- host-facing API: ModelRunner's, with the rows' slots as keywords ----

@@ -532,17 +532,19 @@ class Scheduler:
                 # run and the tokens they gave
                 out["mtp"] = dict(self.mtp_counts)
         elif self.state_pool is not None:
-            info = self.runner.state_info()
+            info = dict(self.runner.state_info())
             out.update({
-                "state_slots_total": info["slots_total"],
+                "state_slots_total": info.pop("slots_total"),
                 "state_slots_in_use": self.state_pool.in_use,
-                "state_slot_bytes": info["slot_bytes"],
+                "state_slot_bytes": info.pop("slot_bytes"),
                 # radix matches turned down for want of a state snapshot, and
                 # tokens prefilled again because a sequence lost its state
                 # (preemption, or a discarded frame that had advanced it)
                 "state_prefix_hits_declined": self.num_state_prefix_hits_declined,
                 "state_recomputed_tokens": self.num_state_recomputed_tokens,
-                "linattn_decode": info["linattn_decode"],
+                # which decode step the recurrent layers run, under the
+                # module's name for it (``linattn_decode``, ``ssm_decode``)
+                **info,
             })
         if self.moe_counts is not None:
             out["moe"] = {**self.runner.moe_info(), **self.moe_counts}

@@ -63,11 +63,7 @@ class WindowModelRunner(RecurrentModelRunner):
     tokens_per_column = 1
 
     def __init__(self, config, params=None, devices=None):
-        super().__init__(config, params=params, devices=devices)
-        # the expert layers' grouped products: the kernel on a TPU, XLA's
-        # ragged product elsewhere
-        self._bind_moe_impl("pallas" if self.platform == "tpu"
-                            and config.attention_impl != "xla" else "xla")
+        super().__init__(config, params=params, devices=devices)  # binds the experts' products
         w = self.state_spec
         logger.info(
             "window slots: %d x %.2f MiB (%d window layers, rings of %d entries for a window "
@@ -112,24 +108,12 @@ class WindowModelRunner(RecurrentModelRunner):
         return {"layers": w.num_layers, "window": w.window, "ring_tokens": w.ring_tokens,
                 "slot_bytes": w.slot_bytes, "slots_total": w.num_slots - 1}
 
-    def moe_info(self) -> dict:
-        cfg = self.model_cfg
-        return {"experts": cfg.num_experts, "experts_held": cfg.held_experts[1],
-                "top_k": cfg.num_experts_per_tok, "impl": self.moe_impl}
-
     def attention_info(self) -> dict:
         kernel = self._attn_impl_for(0, 0) == "pallas"
         return {**super().attention_info(), "decode_forms": {
             "full": "smg.attn.decode: " + ("paged kernel" if kernel else "xla") + " over pages",
             "window": "smg.attn.window_decode: " + ("ring kernel" if kernel else "xla")
                       + " over a lane's ring, with the sink"}}
-
-    @property
-    def widest_table_only(self) -> bool:
-        """Decode programs are compiled at the widest page table alone: the
-        paged kernel reads each lane's own pages by its ``entry``, and the
-        ring kernel has no table."""
-        return self._attn_impl_for(0, 0) == "pallas"
 
     def _prefill_impl_for(self, T: int, mp: int) -> str:
         """Prefill attention has one form here (XLA, queries in blocks), so a

@@ -121,6 +121,30 @@ class ModelConfig:
     zero_experts: int = 0
     mla_q_scale: float = 1.0
     mla_kv_scale: float = 1.0
+    # ---- one mixer or one feed-forward part a layer (``nemotron_h``).
+    # ``layer_types`` names every layer ``mamba`` (a Mamba-2 state-space
+    # mixer: ``ssm_num_heads`` heads of ``ssm_head_dim``, a state of
+    # ``ssm_state_size`` a lane of a head, ``ssm_groups`` groups of heads that
+    # share the input and output vectors, a causal convolution of
+    # ``ssm_conv_kernel`` taps, prefill in chunks of ``ssm_chunk_size``),
+    # ``moe`` (routed experts that work in a latent of ``moe_latent_size``,
+    # ungated, ``activation`` "relu2"; one shared expert of
+    # ``moe_shared_intermediate_size`` on the model's own width) or
+    # ``full_attention`` (``rope_theta`` 0: unrotated).
+    ssm_num_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state_size: int = 0
+    ssm_groups: int = 0
+    ssm_conv_kernel: int = 0
+    ssm_chunk_size: int = 0
+    moe_latent_size: int = 0
+    moe_shared_intermediate_size: int = 0
+    # random weights only (``nemotron_h.init_params``): sizes of the drawing
+    # that whoever compares random weights against a reference sets, as
+    # ``(name, value)`` pairs (``random_weights`` in a config.json; the names
+    # and what each is 1 or the published value without are
+    # ``models/nemotron_h.DRAW``'s).  Empty draws every part alike.
+    random_init: "tuple[tuple[str, float], ...]" = ()
 
     @property
     def window_cache(self) -> bool:
@@ -165,7 +189,8 @@ class ModelConfig:
     @property
     def recurrent(self) -> bool:
         """Some layers keep per-sequence state outside the pages."""
-        return self.layer_types is not None and "linear_attention" in self.layer_types
+        return self.layer_types is not None and bool(
+            {"linear_attention", "mamba"} & set(self.layer_types))
 
     @property
     def mrope_section(self) -> "tuple[int, ...] | None":
@@ -188,6 +213,8 @@ class ModelConfig:
             return cls._from_exaone_moe(cfg, dtype)
         if cfg.get("model_type") == "longcat_flash":
             return cls._from_longcat_flash(cfg, dtype)
+        if cfg.get("model_type") == "nemotron_h":
+            return cls._from_nemotron_h(cfg, dtype)
         # keys that change what the layers compute and that this path would
         # drop in silence: routed experts beyond Qwen-MoE's settings, latent
         # attention.  A config that carries one is another model (D6's rule).
@@ -747,6 +774,136 @@ class ModelConfig:
             mla_kv_scale=(E / cfg["kv_lora_rank"]) ** 0.5 if cfg.get("mla_scale_kv_lora") else 1.0,
         )
 
+    # ``nemotron_h`` (Nemotron-H, Nemotron 3): the same rule as above.
+    _NEMOTRON_H_CONSUMED = frozenset({
+        "model_type", "vocab_size", "hidden_size", "intermediate_size", "num_hidden_layers",
+        "hybrid_override_pattern", "num_attention_heads", "num_key_value_heads", "head_dim",
+        "attention_bias", "mlp_bias", "use_bias", "mlp_hidden_act", "max_position_embeddings",
+        "norm_eps", "layer_norm_epsilon", "tie_word_embeddings", "sliding_window",
+        "mamba_num_heads", "mamba_head_dim", "mamba_hidden_act", "mamba_proj_bias",
+        "ssm_state_size", "n_groups", "conv_kernel", "chunk_size", "expand", "use_conv_bias",
+        "n_routed_experts", "n_shared_experts", "num_experts_per_tok", "moe_intermediate_size",
+        "moe_latent_size", "moe_shared_expert_intermediate_size", "moe_shared_expert_overlap",
+        "n_group", "topk_group", "norm_topk_prob", "routed_scaling_factor",
+        "num_nextn_predict_layers", "mtp_hybrid_override_pattern", "eos_token_id",
+        "bos_token_id",
+        # read by no layer of the published forward: the attention layers
+        # carry no rotary embedding (the state-space layers carry order), the
+        # time-step range shapes a checkpoint's initial ``dt_bias`` and clamps
+        # nothing at inference, the rest say how the published code runs
+        "rope_theta", "partial_rotary_factor", "time_step_min", "time_step_max",
+        "time_step_floor", "rescale_prenorm_residual", "residual_in_fp32",
+        "use_mamba_kernels", "num_logits_to_keep",
+        # the chip's share of a deployment, as for ``pangu_ultra_moe``
+        "router_num_experts", "routed_expert_offset",
+        # random weights only: see ``ModelConfig.random_init``
+        "random_weights",
+    })
+    #: ``hybrid_override_pattern``'s letters as ``layer_types`` names them
+    NEMOTRON_H_LETTERS = {"M": "mamba", "E": "moe", "*": "full_attention"}
+
+    @classmethod
+    def _from_nemotron_h(cls, cfg: dict, dtype: str) -> "ModelConfig":
+        unknown = sorted(set(cfg) - cls._NEMOTRON_H_CONSUMED - cls._OLMO_HYBRID_SHAPELESS)
+        if unknown:
+            raise ValueError(
+                f"nemotron_h config.json has keys this loader does not consume: {unknown}; "
+                "a key that may bear on the model's shape is not dropped in silence")
+
+        def only(key, served, default):
+            if cfg.get(key, default) not in served:
+                raise ValueError(f"nemotron_h: {key} {cfg[key]!r} is not served")
+
+        only("mlp_hidden_act", ("relu2",), "relu2")
+        only("mamba_hidden_act", ("silu",), "silu")
+        only("use_conv_bias", (True,), True)
+        only("sliding_window", (None,), None)
+        only("n_shared_experts", (1,), 1)
+        only("moe_shared_expert_overlap", (False, None), False)
+        for key in ("attention_bias", "mlp_bias", "use_bias", "mamba_proj_bias"):
+            only(key, (False, None), False)
+        if (cfg.get("n_group", 1), cfg.get("topk_group", 1)) != (1, 1):
+            raise ValueError("nemotron_h: a group limit on the router's picks "
+                             "(n_group, topk_group) is not served")
+        eps = cfg.get("norm_eps", 1e-5)
+        if cfg.get("layer_norm_epsilon", eps) != eps:
+            raise ValueError("nemotron_h: norm_eps and layer_norm_epsilon disagree")
+        pattern = cfg["hybrid_override_pattern"]
+        letters = cls.NEMOTRON_H_LETTERS
+        if "-" in pattern:
+            from smg_tpu.models.nemotron_h import SERVING_LIMITS
+
+            raise ValueError(SERVING_LIMITS["dense_mlp_layer"])
+        strange = sorted(set(pattern) - set(letters))
+        if strange:
+            raise ValueError(
+                f"nemotron_h: hybrid_override_pattern has letters {strange} that name no "
+                f"layer this program knows ({', '.join(sorted(letters))} are served)")
+        if len(pattern) != cfg["num_hidden_layers"]:
+            raise ValueError(f"nemotron_h: a pattern of {len(pattern)} letters for "
+                             f"{cfg['num_hidden_layers']} layers")
+        E = cfg["hidden_size"]
+        Hm, Pm = cfg["mamba_num_heads"], cfg["mamba_head_dim"]
+        if Hm * Pm != cfg.get("expand", Hm * Pm // E) * E:
+            raise ValueError(f"nemotron_h: {Hm} state-space heads of {Pm} are not "
+                             f"expand {cfg['expand']} x hidden_size {E}")
+        if Hm % cfg["n_groups"]:
+            raise ValueError(f"nemotron_h: n_groups {cfg['n_groups']} does not divide "
+                             f"mamba_num_heads {Hm}")
+        held = cfg["n_routed_experts"]
+        width = cfg.get("router_num_experts", held)
+        first = cfg.get("routed_expert_offset", 0)
+        if not (0 <= first and first + held <= width):
+            raise ValueError(f"nemotron_h: experts {first}..{first + held - 1} are not among "
+                             f"the router's {width}")
+        if cfg.get("intermediate_size", cfg["moe_intermediate_size"]) != cfg["moe_intermediate_size"]:
+            # ``intermediate_size`` is the width of a ``-`` layer's MLP, which
+            # this row's pattern has none of; the family writes both alike
+            raise ValueError("nemotron_h: intermediate_size and moe_intermediate_size differ")
+        # ``num_nextn_predict_layers`` and ``mtp_hybrid_override_pattern``: the
+        # next-token module is a drafter; it is consumed here and neither
+        # loaded nor served (SERVING_LIMITS["speculative"])
+        heads = cfg["num_attention_heads"]
+        eos = cfg.get("eos_token_id", 2)
+        return cls(
+            arch="nemotron_h",
+            vocab_size=cfg["vocab_size"],
+            hidden_size=E,
+            intermediate_size=cfg["moe_intermediate_size"],
+            num_layers=len(pattern),
+            num_heads=heads,
+            num_kv_heads=cfg.get("num_key_value_heads") or heads,
+            head_dim=cfg.get("head_dim") or E // heads,
+            rope_theta=0.0,
+            rms_norm_eps=eps,
+            max_position_embeddings=cfg.get("max_position_embeddings", 8192),
+            tie_word_embeddings=bool(cfg.get("tie_word_embeddings", False)),
+            eos_token_ids=tuple(eos) if isinstance(eos, list) else (eos,),
+            bos_token_id=cfg.get("bos_token_id", 1),
+            dtype=dtype,
+            activation="relu2",
+            layer_types=tuple(letters[c] for c in pattern),
+            num_experts=width,
+            num_experts_per_tok=cfg["num_experts_per_tok"],
+            moe_intermediate_size=cfg["moe_intermediate_size"],
+            n_shared_experts=1,
+            moe_scoring="sigmoid",
+            norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
+            routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
+            experts_held=(first, held),
+            random_init=tuple(sorted((k, float(v)) for k, v in
+                                     (cfg.get("random_weights") or {}).items())),
+            moe_select_bias=True,
+            ssm_num_heads=Hm,
+            ssm_head_dim=Pm,
+            ssm_state_size=cfg["ssm_state_size"],
+            ssm_groups=cfg["n_groups"],
+            ssm_conv_kernel=cfg["conv_kernel"],
+            ssm_chunk_size=cfg.get("chunk_size", 128),
+            moe_latent_size=cfg["moe_latent_size"],
+            moe_shared_intermediate_size=cfg["moe_shared_expert_intermediate_size"],
+        )
+
     @classmethod
     def from_pretrained(cls, path: str, dtype: str = "bfloat16") -> "ModelConfig":
         with open(os.path.join(path, "config.json")) as f:
@@ -919,6 +1076,53 @@ def tiny_longcat_flash_config(vocab_size: int = 512, held: "tuple[int, int] | No
     )
 
 
+def tiny_nemotron_h_config(vocab_size: int = 512, held: "tuple[int, int] | None" = None,
+                           pattern: str = "MEM*EME", **changes) -> ModelConfig:
+    """Tiny Nemotron-H for CPU tests: ``pattern`` of state-space (``M``: 4
+    heads of 16 in 2 groups, state 16, chunks of 8), latent-expert (``E``: a
+    router of 16, top 4, of which ``held`` are here, None: all; latent 32,
+    experts 48 wide, the shared expert 96) and unrotated attention layers
+    (``*``: 4 query and 2 key/value heads of 64, a whole 128-lane tile a
+    token), one mixer or one feed-forward part a layer."""
+    import dataclasses
+
+    return dataclasses.replace(
+        tiny_test_config(vocab_size),
+        arch="nemotron_h",
+        num_layers=len(pattern),
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=64,
+        rope_theta=0.0,
+        rms_norm_eps=1e-5,
+        activation="relu2",
+        layer_types=tuple(ModelConfig.NEMOTRON_H_LETTERS[c] for c in pattern),
+        intermediate_size=48,
+        num_experts=16,
+        num_experts_per_tok=4,
+        moe_intermediate_size=48,
+        n_shared_experts=1,
+        moe_scoring="sigmoid",
+        norm_topk_prob=True,
+        routed_scaling_factor=2.5,
+        experts_held=held,
+        moe_select_bias=True,
+        ssm_num_heads=4,
+        ssm_head_dim=16,
+        ssm_state_size=16,
+        ssm_groups=2,
+        ssm_conv_kernel=4,
+        ssm_chunk_size=8,
+        moe_latent_size=32,
+        moe_shared_intermediate_size=96,
+        # the sizes of the drawing the benchmark's configuration of this
+        # architecture sets, so that the toy's tests hear what its check hears
+        random_init=(("attn_out", 4.0), ("bc_gain", 2.0), ("dt_max", 0.5), ("dt_min", 0.02),
+                     ("routed_out", 1.5), ("score_std", 1.5), ("shared_out", 0.5)),
+        **changes,
+    )
+
+
 def tiny_mimo_config(vocab_size: int = 512, held: "tuple[int, int] | None" = None,
                      **changes) -> ModelConfig:
     """Tiny MiMo-V2-Flash for CPU tests: a dense full-attention layer, three
@@ -1039,6 +1243,7 @@ PRESETS = {
     "tiny-mimo": tiny_mimo_config,
     "tiny-exaone-moe": tiny_exaone_moe_config,
     "tiny-longcat-flash": tiny_longcat_flash_config,
+    "tiny-nemotron-h": tiny_nemotron_h_config,
     "llama3.2-1b": llama32_1b_config,
     "llama3-8b": llama3_8b_config,
     "llama3-70b": llama3_70b_config,

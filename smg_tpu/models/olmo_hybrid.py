@@ -129,7 +129,9 @@ def period_of(layer_types: tuple[str, ...]) -> tuple[int, int]:
     if n == 0 or len(layer_types) % (n + 1) or layer_types != period * (len(layer_types) // (n + 1)):
         raise ValueError(
             "olmo_hybrid: layer_types must repeat one period of linear_attention "
-            f"layers followed by one full_attention layer; got {list(layer_types)}")
+            f"layers followed by one full_attention layer; got {list(layer_types)} "
+            "(a stack in any other order is models/nemotron_h.py's, whose layers "
+            "are written out one by one from a pattern string)")
     return len(layer_types) // (n + 1), n
 
 
@@ -145,6 +147,17 @@ def state_shapes(cfg: ModelConfig, slots: int) -> tuple[tuple, tuple]:
     H, dk, dv = cfg.linear_num_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim
     K = cfg.linear_conv_kernel_dim
     return (P * n, slots, dk, H * dv), (P * n, slots, (K - 1) * conv_channels(cfg))
+
+
+def decode_step(cfg: ModelConfig) -> dict:
+    """The linear layers' decode step, for the runner: the name it goes by in
+    ``loads()``, the forwards' keyword that picks its form, what the layers
+    are called, and whether the kernel's blocks fit this shape."""
+    from smg_tpu.ops.pallas import linattn_decode
+
+    return {"name": "linattn_decode", "arg": "linattn_impl", "layers": "linear-attention",
+            "kernel_fits": linattn_decode.supported(
+                cfg.linear_num_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim)}
 
 
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:

@@ -16,8 +16,12 @@ with state beside its pages):
   (``/v1/embeddings``), ``forward_train`` (the dense causal forward of
   ``smg_tpu/train`` and of the tests);
 - a module whose sequences hold state beside their pages also gives
-  ``state_shapes(cfg)`` and takes the pools and slots after the page tables
-  (``engine/recurrent_runner.py``);
+  ``state_shapes(cfg, slots)`` and ``decode_step(cfg)`` (what its recurrent
+  layers' decode step is called, the keyword that picks its form, whether the
+  kernel fits) and takes the pools and slots after the page tables
+  (``engine/recurrent_runner.py``; ``models/nemotron_h.py``, whose layers are
+  state-space mixers, latent experts and attention in any order, has routed
+  counts beside its state);
 - a module whose cache is one latent buffer (``models/pangu_moe.py``,
   ``models/longcat_flash.py``) takes ``v_cache`` of zero size through its
   prefill forwards untouched, and its decode column takes the one side buffer
@@ -45,7 +49,7 @@ _REGISTRY: dict[str, ModuleType] = {}
 # architectures this package brings itself, loaded on first use
 _LLAMA_FAMILY = ("llama", "qwen", "mistral", "qwen_moe")
 _BUILTIN = (*_LLAMA_FAMILY, "olmo_hybrid", "pangu_ultra_moe", "mimo_v2_flash", "exaone_moe",
-            "longcat_flash")
+            "longcat_flash", "nemotron_h")
 
 
 def register_model(arch: str, module: ModuleType) -> None:
@@ -83,6 +87,10 @@ def get_model(arch: str) -> ModuleType:
             from smg_tpu.models import longcat_flash
 
             _REGISTRY.setdefault("longcat_flash", longcat_flash)
+        elif arch == "nemotron_h":
+            from smg_tpu.models import nemotron_h
+
+            _REGISTRY.setdefault("nemotron_h", nemotron_h)
         else:
             raise KeyError(
                 f"unsupported model architecture: {arch!r} "

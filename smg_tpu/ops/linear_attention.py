@@ -48,36 +48,43 @@ def _mm(spec: str, *xs):
 # the convolution
 
 
-@jax.named_scope("smg.linattn.conv")
-def causal_conv(x: jnp.ndarray, tail: jnp.ndarray, weight: jnp.ndarray,
-                t_real: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+def conv_chunk(x: jnp.ndarray, tail: jnp.ndarray, weight: jnp.ndarray,
+               t_real: jnp.ndarray, bias=None) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Causal depthwise convolution over time with SiLU, for a chunk.
 
     ``x`` [G, T, C] the chunk's inputs, ``tail`` [G, K-1, C] the last K-1
     inputs before the chunk (zeros at a sequence's start), ``weight`` [K, C],
-    ``t_real`` [G] the real rows of each chunk.  Returns ``(y [G, T, C]
-    float32, new tail)``: the new tail is the last K-1 inputs up to the last
-    REAL row, so padded rows never enter it."""
+    ``bias`` [C] or None, ``t_real`` [G] the real rows of each chunk.  Returns
+    ``(y [G, T, C] float32, new tail)``: the new tail is the last K-1 inputs
+    up to the last REAL row, so padded rows never enter it."""
     K = weight.shape[0]
     T = x.shape[1]
     xf = jnp.concatenate([tail.astype(jnp.float32), x.astype(jnp.float32)], axis=1)
     w = weight.astype(jnp.float32)
     y = sum(xf[:, i:i + T] * w[i] for i in range(K))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     new_tail = jax.vmap(
         lambda row, n: lax.dynamic_slice_in_dim(row, n, K - 1, axis=0)
     )(xf, t_real)
     return jax.nn.silu(y), new_tail.astype(tail.dtype)
 
 
-@jax.named_scope("smg.linattn.conv")
-def conv_step(x: jnp.ndarray, tail: jnp.ndarray, weight: jnp.ndarray
-              ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """One token of ``causal_conv``: ``x`` [B, C], ``tail`` [B, K-1, C].
+def conv_token(x: jnp.ndarray, tail: jnp.ndarray, weight: jnp.ndarray, bias=None
+               ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """One token of ``conv_chunk``: ``x`` [B, C], ``tail`` [B, K-1, C].
     Returns ``(y [B, C] float32, new tail [B, K-1, C])``."""
     xf = x.astype(jnp.float32)
     window = jnp.concatenate([tail.astype(jnp.float32), xf[:, None]], axis=1)
     y = jnp.einsum("bkc,kc->bc", window, weight.astype(jnp.float32))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return jax.nn.silu(y), window[:, 1:].astype(tail.dtype)
+
+
+# under this module's span; ``ops/ssm.py`` runs the same two under its own
+causal_conv = jax.named_scope("smg.linattn.conv")(conv_chunk)
+conv_step = jax.named_scope("smg.linattn.conv")(conv_token)
 
 
 # --------------------------------------------------------------------------

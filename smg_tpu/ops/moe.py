@@ -47,7 +47,9 @@ ROWS_ALL_AT_ONCE = 2048
 
 def rows_buffer(pairs: int) -> int:
     if pairs <= ROWS_ALL_AT_ONCE:
-        return pairs
+        # whole sublane tiles of rows for the kernel's blocks: one lane at
+        # top 22 is 22 pairs, which no tile of 8 divides
+        return pairs if pairs % 8 == 0 else -(-pairs // 16) * 16
     return max(ROWS_ALL_AT_ONCE, -(-pairs // 8 // 256) * 256)
 
 
@@ -128,10 +130,18 @@ def grouped_matmul(rows, weights, group_sizes, impl: str, layer=None):
     return jnp.where(past, jnp.zeros((), out.dtype), out)
 
 
+def relu2(x):
+    """``relu(x)^2``, squared in float32, in ``x``'s dtype."""
+    return jnp.square(jax.nn.relu(x.astype(jnp.float32))).astype(x.dtype)
+
+
 def _experts(rows, w_gate, w_up, w_down, group_sizes, impl, layer):
-    gate = grouped_matmul(rows, w_gate, group_sizes, impl, layer)
     up = grouped_matmul(rows, w_up, group_sizes, impl, layer)
-    return grouped_matmul(jax.nn.silu(gate) * up, w_down, group_sizes, impl, layer)
+    if w_gate is None:  # no gate matrix: a squared ReLU
+        hidden = relu2(up)
+    else:
+        hidden = jax.nn.silu(grouped_matmul(rows, w_gate, group_sizes, impl, layer)) * up
+    return grouped_matmul(hidden, w_down, group_sizes, impl, layer)
 
 
 def expert_layer(x, routing: Routing, w_gate, w_up, w_down, held: tuple[int, int],
@@ -139,8 +149,12 @@ def expert_layer(x, routing: Routing, w_gate, w_up, w_down, held: tuple[int, int
     """What the held experts give for ``x`` [T, E]: ``sum_i w_i E_i(x)`` over
     each token's picks on held experts.  ``w_gate``, ``w_up`` [held, E, F] and
     ``w_down`` [held, F, E] are the held experts' gated MLPs (with ``layer``:
-    the stacks of all layers, [L, held, ..]).  Returns the result [T, E]
-    (float32) and ``(picks on held experts, held experts that got a row)``."""
+    the stacks of all layers, [L, held, ..]); with ``w_gate`` None an expert is
+    ``w_down relu(w_up x)^2`` (``models/nemotron_h.py``).  ``E`` is whatever
+    width the experts work in: ``routing`` may come from a wider input than
+    ``x`` (there the router reads the model's width and the experts a latent).
+    Returns the result [T, E] (float32) and ``(picks on held experts, held
+    experts that got a row)``."""
     T, k = routing.experts.shape
     E = x.shape[-1]
     d = dispatch(routing.experts, held)
@@ -168,7 +182,7 @@ def expert_layer(x, routing: Routing, w_gate, w_up, w_down, held: tuple[int, int
         return y
 
     y = jnp.zeros((T, E), jnp.float32)
-    if R == T * k:
+    if R >= T * k:
         y = one_pass(jnp.int32(0), y)
     else:
         _, y = jax.lax.while_loop(

@@ -71,6 +71,12 @@ NEG_INF = -1e30
 # loop's own cost are paid half as often), and queries in blocks of 512 keep
 # a row that ends just past a block's edge cheaper than blocks of 1,024 do.
 BLOCK_Q = 512  # queries a program, a head
+# rows a program stacks at most (the query heads that share a KV head under one
+# another, ``H/K * block_q``): its float32 scores and probabilities against a
+# key block are two arrays of that many rows.  512 queries of 16 heads a KV
+# head (``models/nemotron_h.py``, 32/2) are 8,192 rows and do not fit VMEM;
+# up to 4 heads a KV head ``BLOCK_Q`` stands as it was timed.
+STACKED_ROWS = 2048
 BLOCK_K = 1024  # keys a step of the walk
 VMEM_LIMIT_BYTES = 64 * 2**20
 
@@ -186,7 +192,8 @@ def flash_attention_prefill(
     if D % 128 or Dv % 128 or H % K:
         raise ValueError(f"{H}/{K} heads of {D} and {Dv}: the kernel slices lanes by whole "
                          "128-lane tiles; use the XLA form")
-    bq, bk = _block(T, block_q or BLOCK_Q), _block(T, block_k or BLOCK_K)
+    bq = _block(T, block_q or min(BLOCK_Q, max(STACKED_ROWS // Gq, 8)))
+    bk = _block(T, block_k or BLOCK_K)
     if bq % 8 or bk % 8:
         raise ValueError(f"{T} tokens in blocks of {bq} queries and {bk} keys: "
                          "not whole tiles; use the XLA form")
