@@ -27,6 +27,12 @@ layers of one cache at a cell's widths:
 - ``mimo-v2-flash`` (the ``mixed`` cell's 2 full layers): 64/4 heads, keys of
   192 and values of 128 (K pages of 768 lanes, V of 512); batch 16, 32 and
   64 behind 128, 256 and 512 pages;
+- ``nemotron-3-super-120b-a12b`` (its ``reason`` cell's one attention layer):
+  32/2 heads of 128, pages of 256 lanes; batch 64 behind 64, 128 and 256 pages;
+- ``k-exaone-236b-a23b:verify`` (its ``reason`` cell's 2 full layers, the
+  verify column): two rows a lane, 64/8 heads of 128; batch 64 behind 128
+  and 256 pages.  ``ops.attention.
+  attention_verify_cached`` against ``paged_attention_verify_cached``;
 - ``mimo-v2-flash:window`` (its 5 window layers): 64/8 heads over a ring of
   144 entries a lane (1,536 and 1,024 lanes), with the sink; batch 16, 32 and
   64.  No table and no fill: ``ops.window_attention.window_attention_decode``
@@ -60,19 +66,20 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from smg_tpu.ops.attention import attention_decode_cached  # noqa: E402
+from smg_tpu.ops.attention import attention_decode_cached, attention_verify_cached  # noqa: E402
 from smg_tpu.ops.pallas.decode_attention import (  # noqa: E402
-    _latent_pages_per_block,
     _pages_per_block,
     latent_attention_decode_cached,
     paged_attention_decode_cached,
+    paged_attention_verify_cached,
 )
 
 PS, N = 16, 8
 REPS = 5
 FILLS = (0.25, 0.5, 1.0)
-# name: layers, pages, heads, kv heads, head dim, [(batch, table widths)], and
-# the values' head dim where it is not the keys'
+# name: layers, pages, heads, kv heads, head dim, [(batch, table widths)], the
+# values' head dim where it is not the keys', and the rows a lane of a verify
+# column (then the values' head dim is given too)
 MODELS = {
     "mimo-v2-flash": (2, 20000, 64, 4, 192,
                       [(16, (128, 256, 512)), (32, (128, 256, 512)), (64, (128, 256, 512))],
@@ -85,6 +92,8 @@ MODELS = {
                                  [(16, (64, 128, 256)), (32, (64, 128, 256)),
                                   (64, (64, 128, 256))]),
     "longcat-flash-chat": (8, 20000, 64, 0, 640, [(64, (64, 128, 256))]),
+    "nemotron-3-super-120b-a12b": (1, 20000, 32, 2, 128, [(64, (64, 128, 256))]),
+    "k-exaone-236b-a23b:verify": (2, 20000, 64, 8, 128, [(64, (128, 256))], 128, 2),
 }
 # the window layers' form: layers, slots, ring entries, heads, kv heads, head
 # dims of keys and values, window, batches
@@ -92,9 +101,17 @@ WINDOW_MODELS = {"mimo-v2-flash:window": (5, 73, 144, 64, 8, 192, 128, 128, (16,
 WINDOW_REHEARSAL = {"toy:window": (2, 5, 32, 16, 8, 64, 32, 8, (2, 8))}
 REHEARSAL = {"toy": (2, 40, 4, 2, 64, [(2, (4, 8)), (8, (8,))]),
              "toy-narrow-v": (2, 40, 8, 4, 64, [(2, (4, 8))], 32),
+             "toy:verify": (2, 40, 4, 2, 64, [(2, (4, 8))], 64, 2),
              "toy-latent": (2, 40, 4, 0, 256, [(2, (4, 8))]),
              "toy-latent-8-layers": (8, 40, 8, 0, 256, [(2, (8,))])}
 LATENT_VALUE_LANES = {640: 512, 256: 128}  # entry lanes -> lanes of its value
+
+
+def model_shape(entry):
+    """A ``MODELS`` entry whole: layers, pages, heads, kv heads, head dim, [(batch,
+    table widths)], the values' head dim, the rows a lane (1 but for a verify column)."""
+    L, P, H, K, D, shapes, *rest = entry
+    return L, P, H, K, D, shapes, (rest[0] if rest else D), (rest[1] if len(rest) > 1 else 1)
 
 
 def latent_forms(D: int, pages_per_block, interpret: bool) -> dict:
@@ -123,6 +140,24 @@ def narrow_value_forms(forms: dict, D: int) -> dict:
             return jnp.pad(out, ((0, 0), (0, 0), (0, D - out.shape[-1])))
         return form
     return {name: padded(attend) for name, attend in forms.items()}
+
+
+def verify_forms(rows: int, pages_per_block, interpret: bool) -> dict:
+    """The verify column's two forms with ``columns``' signature: ``rows``
+    query rows a lane (the lane's query shifted a little a row), each lane
+    holding ``n - rows`` side rows before them; the rows' mean chains the
+    layers."""
+    def form(attend, **kw):
+        def verify(q, kc, vc, hk, hv, n, l, tables, entry, scale):
+            q_rows = jnp.stack([q + w for w in range(rows)], axis=1)
+            held = jnp.full((q.shape[0],), n - rows, jnp.int32)
+            out = attend(q_rows, kc, vc, hk, hv, held, l, tables, entry, scale, **kw)
+            return out.mean(axis=1).astype(q.dtype)
+        return verify
+
+    return {"xla": form(attention_verify_cached),
+            "pallas": form(paged_attention_verify_cached, pages_per_block=pages_per_block,
+                           interpret=interpret)}
 
 
 def window_rows(models: dict, only: set, interpret: bool, dev) -> list:
@@ -217,13 +252,13 @@ def main() -> int:
     rows = []
     rows += window_rows(WINDOW_REHEARSAL if args.rehearsal else WINDOW_MODELS, only,
                         args.rehearsal, dev)
-    for model, (L, P, H, K, D, shapes, *narrow) in (
-            REHEARSAL if args.rehearsal else MODELS).items():
+    for model, entry in (REHEARSAL if args.rehearsal else MODELS).items():
+        L, P, H, K, D, shapes, Dv, q_rows = model_shape(entry)
         if only and not any(s.startswith(model + ":") for s in only):
             continue
         latent = K == 0  # one buffer of ``D`` lanes an entry and no V
         kd = D if latent else K * D
-        vd = K * narrow[0] if narrow else kd  # V's lanes
+        vd = K * Dv if K else kd  # V's lanes
         kq, kk, kv, ks = jax.random.split(jax.random.PRNGKey(0), 4)
         # random, so that the two outputs can be compared; what the cache
         # holds does not change what the attentions cost
@@ -231,8 +266,9 @@ def main() -> int:
         vc = kc if latent else jax.random.normal(kv, (L, P, PS, vd), jnp.bfloat16)
         page_bytes = PS * (kd if latent else kd + vd) * 2  # K and V, or the one buffer
         forms = (latent_forms(D, args.pages_per_block, args.rehearsal) if latent
+                 else verify_forms(q_rows, args.pages_per_block, args.rehearsal) if q_rows > 1
                  else {"xla": attention_decode_cached, "pallas": pallas})
-        if narrow:
+        if vd != kd:
             forms = narrow_value_forms(forms, D)
         for B, widths in shapes:
             q = jax.random.normal(kq, (B, H, D), jnp.bfloat16)
@@ -250,9 +286,7 @@ def main() -> int:
                 xla_ms = None
                 fit = []
                 # the pages of one of the kernel's blocks
-                block_pages = args.pages_per_block or (
-                    _latent_pages_per_block(PS, mp) if latent
-                    else _pages_per_block(PS, max(kd, vd), 2, mp))
+                block_pages = args.pages_per_block or _pages_per_block(PS, kd, 2, mp)
                 for fill in FILLS:
                     held = int(fill * mp * PS) - N
                     entry = jnp.full((B,), held, jnp.int32)

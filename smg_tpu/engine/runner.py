@@ -465,13 +465,16 @@ class ModelRunner:
         pages that lane holds.  Where the kernel can run (one TPU chip, no
         mesh, no pp, cache lanes a multiple of 128: ``_resolve_attn_impl``)
         it is the answer at every shape: timed alone on a v5e
-        (``scripts/time_decode_attention.py``; the table is ``PERF.md``
-        7.11, PR 30) it moves held bytes at about 755 GB/s with 0.01-0.04 ms
-        a layer of fixed cost at 1 to 64 lanes, where XLA reads the whole
-        table at about 400 GB/s and four times slower once the gather's
-        result passes 64 MiB; at half-full tables it ties at 1 lane x 8
-        pages (0.01 ms either way), wins by 2.5 x at 8 x 64 and by 6.6 x at
-        16 x 256, and wins at full tables too.  ``B`` and ``mp`` stay in
+        (``scripts/time_decode_attention.py``; the tables are ``PERF.md``
+        7.11, PR 30 and PR 48) it moves held bytes at about 755 GB/s with
+        0.01-0.04 ms a layer of fixed cost at 1 to 64 lanes, at every page
+        width from 256 lanes (2 KV heads of 128) to 3,840 since PR 48 built
+        the copies' loops out of a block (before it 215 GB/s at 256 lanes
+        and 475 at ``mimo-v2-flash``'s 768 + 512), where XLA reads the
+        whole table at about 400 GB/s and four times slower once the
+        gather's result passes 64 MiB; at half-full tables it ties at 1
+        lane x 8 pages (0.01 ms either way), wins by 2.5 x at 8 x 64 and by
+        6.6 x at 16 x 256, and wins at full tables too.  ``B`` and ``mp`` stay in
         the signature because they are what a program is compiled for and
         what the next sweep may have to split on."""
         if self.use_pp:

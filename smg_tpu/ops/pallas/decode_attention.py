@@ -18,61 +18,76 @@ TPU-native decode structure (multi-step horizon, ``runner.decode_multi``):
   (tokens fed during this horizon), merged in one online softmax.
 
 What is streamed: for each lane, the pages that lane holds and no others, in
-**blocks** of ``pages_per_block`` pages (``_pages_per_block``: 256 tokens,
-128 where a page is wide, so that the double buffer stays within a few MiB of
-VMEM).  A block's page DMAs signal one semaphore per buffer and slot; the
+**blocks** of ``pages_per_block`` pages (``_pages_per_block``: 512 tokens,
+128 where a page is wide, so that the double buffers stay within a few MiB of
+VMEM).  A block's page copies signal one semaphore a stream and slot; the
 next block's are in flight while the current block is multiplied, and a
 lane's last block starts the next lane's first, so the kernel waits for HBM
 with nothing behind it only at the very first block of the call.  A lane's
-last block fetches the pages it has and masks the rest by ``entry``.  The
-page loops are loops, not unrolled code: timed on a v5e at ``eval``'s and
-``gen``'s shapes (16-30 heads on 1,024-3,840 lanes, a block of 1 MB whose
-bytes are the bound) the two run alike, as do one wait a block and one a
-page, and a decode program is traced in a third of the time (``PERF.md``
-section 6, PR 30).  Per block there is one score product
-``[H, K*D] x [K*D, block tokens]``, one softmax update and one rescale of the
-``[H, K*D]`` accumulator; the side rows are scored first, while the first
-block is on its way, and seed the running maximum and sum.
+last block fetches the pages it has and masks the rest by ``entry``.  Per
+block there is one score product ``[H, K*D] x [K*D, block tokens]``, one
+softmax update and one rescale of the ``[H, K*D]`` accumulator; the side rows
+are scored first, while the first two blocks are on their way, and seed the
+running maximum and sum.
 
-The latent cache has a body of its own (``_latent_decode_kernel``), because
-there the loops do not run alike.  64-128 heads meet one buffer of 640 lanes:
-a block of 256 entries is 327,680 B, 0.4 us of the memory's time, and the
-paged body spent 1.3 us on it: 0.68 us with the products taken out, 0.62 us
-with the fetches taken out, the two one after the other, since a loop over a
-count is a region of its own that nothing else is scheduled into (40 bundles
-a page to start it, 9 to wait for it; ``PERF.md`` section 6, PR 45).  So the
-latent body
-(a) starts a block's pages with one predicated copy a page and no loop,
-straight-line code in the block of the loop that holds the products, with
-whether anything follows folded into the page count;
-(b) waits once for a full block: the semaphore counts bytes, so one wait a
-set bit of the block's page count takes a short block too;
-(c) takes ``LATENT_BLOCK_TOKENS`` (512) entries a block, 655,360 B a slot, so
-that every per-block part halves;
-(d) reads the tables as one row and the cache as ``[L * P, ps, W]``, a page a
-row of the leading axis, which is a third less address arithmetic a copy,
-and leaves the copies' bounds checks out (13 of a start's 27 scalar
+**What a block costs beyond its bytes is built out of the loop**, in both
+bodies.  As loops over a count, a page an iteration, the starts and the
+waits of a block were regions of their own that nothing else is scheduled
+into: 55 bundles a page to start K and V (4 bounds checks among them) and 6
+to wait for them, about 1,520 bundles (1.2 us) a block of 16 pages at
+``mimo-v2-flash``'s 768 + 512 lanes whose bytes take 0.80 us, 1,150 (0.9 us)
+at ``nemotron-3-super-120b-a12b``'s 256 lanes whose bytes take 0.32
+(``scripts/kernel_schedule.py`` counts them off the compiler's schedule).
+PR 30 timed loops and unrolled code at ``eval``'s and ``gen``'s shapes
+(16-30 heads on 1,024-3,840 lanes) and found them alike; that holds where a
+block's bytes (1 MB, 1.28 us) take as long as its loops and nowhere else
+(``PERF.md`` section 6, PR 30, PR 45 and PR 48).  So ``_start_pages``,
+``_wait_pages`` and ``_zero_slots`` serve the paged body (``_decode_kernel``:
+two streams, K and V, a window's first live page, a verify column's rows) and
+the latent one (``_latent_decode_kernel``: one buffer whose entries are keys
+and, in their first lanes, values; 64-128 heads on 640 lanes), and both
+(a) start a block's pages with one predicated copy a page and stream and no
+loop, straight-line code in the block of the loop that holds the products,
+with whether anything follows folded into the page count (the paged body
+traces a page's code once and has it unrolled when the kernel is lowered,
+and leaves the start that only lane 0 and a lane behind an empty one take a
+loop: the same schedule, and a third of the tracing a decode program's
+set-up pays; ``_start_pages``);
+(b) wait once a stream for a full block: the semaphore counts bytes, so one
+wait a set bit of the block's page count takes a short block too;
+(c) read the tables as one row and a cache as ``[L * P, ps, lanes]``, a page
+a row of the leading axis, which is a third less address arithmetic a copy,
+and leave the copies' bounds checks out (13 of a start's 27 scalar
 operations);
-(e) starts a lane's second block at the lane's top, into the slot the lane
+(d) start a lane's second block at the lane's top, into the slot the lane
 before has left, so that its copies are issued beside the side rows'
 products and HBM works through a lane's fixed part (the loop then starts
 this lane's blocks from the third on, and the next lane's first);
-(f) zeroes its buffer once in a loop over a count, where a ``pl.when`` round
-the stores became predicated stores in every lane's code.
-The operand roles are the paged body's: a block of the cache is the MXU's
+(e) zero the value buffer once in a loop over a count, where a ``pl.when``
+round the stores became predicated stores in every lane's code.
+A block is ``BLOCK_TOKENS`` (512) tokens in both, so that every per-block part
+halves: timed at 256, 384 and 512 on the latent cache (PR 45) and on the paged
+one (PR 48: at ``mimo-v2-flash``'s widths a block of 256 tokens takes 0.97 us
+in blocks of 256 and 0.87 in blocks of 512, which is what its copies alone
+take, 757 GB/s; at 1,024 lanes the bytes bound either).  A lane's last block
+is multiplied whole whatever it holds.
+The operand roles are the same in both: a block of the cache is the MXU's
 stationary operand in both products; the block as the streamed operand
-(``s^T = keys . q^T``) was timed and is slower at 64 and at 128 heads (the
-second product then wants the values transposed).  What the interpreter
-cannot see of this loop: a block started twice leaves its semaphore above
-zero, which only the chip refuses, at the kernel's exit.
+(``s^T = keys . q^T``) was timed on the latent cache and is slower at 64 and
+at 128 heads (the second product then wants the values transposed).  What
+the plain interpreter cannot see of this loop: a block started twice leaves
+its semaphore above zero, which the chip refuses at the kernel's exit;
+``tests/test_pallas_decode.py`` walks the rule in plain Python and runs the
+kernels under the interpreter that models the semaphores.
 
 Operands are the XLA form's (``ops.attention._attend_cache_and_side``): K, V
 and the query in the cache's dtype on the MXU with float32 accumulation, the
 probabilities cast to the cache's dtype for the product with V, maxima and
 sums in float32.  No float32 copy of a page is made.
 
-Tiling: pages are viewed as fused ``[ps, K*D]`` tiles (K*D >= 512 lanes,
-always 128-aligned).  GQA is folded into the matmuls with block-diagonal
+Tiling: pages are viewed as fused ``[ps, K*D]`` tiles (whole 128-lane tiles:
+256 lanes at ``nemotron-3-super-120b-a12b``'s 2 KV heads, 3,840 at
+``olmo-hybrid-7b``'s 30).  GQA is folded into the matmuls with block-diagonal
 queries (``ops.attention.block_diagonal_query``) so one MXU matmul serves
 all heads; the ``p @ v`` product is ``[H, K*D]`` and each head keeps its own
 D lanes afterwards (``ops.attention.own_lanes``).
@@ -94,15 +109,17 @@ from jax.experimental.pallas import tpu as pltpu
 from smg_tpu.ops.attention import block_diagonal_query, own_lanes
 
 NEG_INF = -1e30
-BLOCK_TOKENS = 256  # tokens a compute step, where the buffers allow
-BLOCK_BUFFER_BYTES = 2**20  # one of the four (K, V) x (two slots) buffers
-LATENT_BLOCK_TOKENS = 512  # the latent kernel's block: 32 pages of 20,480 B, 655,360 B a slot
+BLOCK_TOKENS = 512  # tokens a compute step, where the buffers allow
+BLOCK_BUFFER_BYTES = 2**20  # one slot of one stream (K, V or the latent entries)
 
 
 def _pages_per_block(ps: int, lanes: int, itemsize: int, mp: int) -> int:
-    """Pages a compute step: ``BLOCK_TOKENS`` tokens, fewer where a page is
-    so wide (30 heads of 128: 122,880 B) that a buffer of them would pass
-    ``BLOCK_BUFFER_BYTES``, never more than the table has."""
+    """Pages a compute step: ``BLOCK_TOKENS`` tokens (32 pages of 16: a slot
+    of 262,144 B at 2 KV heads of 128, 655,360 B of latent entries, 786,432
+    B of ``mimo-v2-flash``'s keys, the limit itself at 8 heads of 128), fewer
+    where a page is so wide (30 heads of 128: 122,880 B, 8 pages) that a
+    slot of them would pass ``BLOCK_BUFFER_BYTES``, never more than the
+    table has."""
     by_bytes = BLOCK_BUFFER_BYTES // (ps * lanes * itemsize)
     return max(1, min(BLOCK_TOKENS // ps, by_bytes, mp))
 
@@ -129,24 +146,85 @@ def _lane_pages(entry_pos_ref, lane, *, mp: int, ps: int, n: int, n_extra=None, 
     return entry, lo, first, n_pages, _div(n_pages - first + n - 1, n)
 
 
-def _latent_pages_per_block(ps: int, mp: int) -> int:
-    """Pages a compute step of the latent kernel: ``LATENT_BLOCK_TOKENS``
-    entries, never more than the table has."""
-    return max(1, min(LATENT_BLOCK_TOKENS // ps, mp))
+def _start_pages(tables_ref, streams, layer_row, lane, page0, held, slot, *, n: int, ps: int,
+                 mp: int, unroll: bool | None = None):
+    """Start the pages ``page0 .. min(page0 + n, held) - 1`` of a lane (the
+    tables are one row, ``mp`` entries a lane) into a slot of each stream
+    ``(cache [L * P, ps, lanes], buffer, semaphores [2])``, the layer's pages
+    from row ``layer_row`` of a cache on: one predicated copy a page and
+    stream and no loop, so that they are straight-line code (a loop over a
+    count is a region of its own, 40 bundles a page copy with nothing else in
+    them).  What a program's set-up pays is the tracing and the lowering of
+    ``n`` predicated regions a call of this, so ``unroll`` says how the
+    page's code is written out: ``True`` traces it once and unrolls it when
+    it is lowered (a loop of ``n`` steps unrolled ``n`` times is the same
+    straight-line code, a third of a second less tracing a kernel at 32
+    pages); ``False`` leaves it a loop at run time, for a start that few
+    lanes take; ``None`` unrolls it in Python, which the latent body keeps
+    only because its traced text is held to what it was
+    (``tests/test_pallas_decode.py``)."""
+    def start_page(i, _=None):
+        @pl.when(page0 + i < held)
+        def _():
+            page = tables_ref[lane * mp + page0 + i]
+            row0 = i * ps if isinstance(i, int) else pl.multiple_of(i * ps, ps)
+            for hbm, buf, sems in streams:
+                pltpu.make_async_copy(hbm.at[layer_row + page],
+                                      buf.at[slot, pl.ds(row0, ps)], sems.at[slot]).start()
+
+    if unroll is None:
+        for i in range(n):
+            start_page(i)
+    else:
+        jax.lax.fori_loop(0, n, start_page, None, unroll=unroll)
+
+
+def _wait_pages(streams, count, slot, *, n: int, ps: int):
+    """Wait for the ``count`` pages of a slot's block, in each stream.  A
+    semaphore counts bytes, so a descriptor of k pages waits for any k of
+    them: one wait a set bit of ``count``, one in all for a full block."""
+    for bit in range(n.bit_length()):
+        k = 1 << bit
+
+        @pl.when((count & k) != 0)
+        def _():
+            rows = pl.ds(0, k * ps)
+            for _, buf, sems in streams:
+                pltpu.make_async_copy(buf.at[1 - slot, rows], buf.at[slot, rows],
+                                      sems.at[slot]).wait()
+
+
+def _zero_slots(buf, when, *, n: int, ps: int):
+    """Zero both slots of a buffer where ``when`` holds.  Rows of a slot past
+    a short block keep what the block before left there, and before the first
+    block that is whatever VMEM held: a masked probability of 0 times a NaN is
+    a NaN (a masked score is replaced, so keys need no such care).  A loop
+    over a count, because a ``pl.when`` round plain stores becomes predicated
+    stores, which every lane would pay for."""
+    def zero_page(i, _):
+        rows = pl.ds(pl.multiple_of((i % n) * ps, ps), ps)
+        buf[i // n, rows] = jnp.zeros((ps, buf.shape[2]), buf.dtype)
+        return 0
+
+    jax.lax.fori_loop(0, jnp.where(when, 2 * n, 0), zero_page, 0)
 
 
 def _decode_kernel(
     # scalar prefetch
-    page_tables_ref,  # [B, mp] int32 (SMEM)
+    page_tables_ref,  # [B * mp] int32 (SMEM): the tables, one row after another
     entry_pos_ref,  # [B] int32 (SMEM) — tokens in cache (exclusive bound)
     meta_ref,  # [3] int32 (SMEM): [n_extra, layer, window] (window<=0 = global)
     *refs,
     ps: int,
     n: int,  # pages a block
+    mp: int,
+    pages: int,  # P: pages a layer
     scale: float,
     softcap: float,
     rows: int = 1,  # > 1: a verify column, ``rows`` query rows a lane (``held_ref`` first)
 ):
+    """The paged cache's body: two streams (K and V) through one loop over a
+    lane's blocks, built as the latent body's is (module docstring)."""
     if rows > 1:
         # [B] int32 (SMEM): side rows a lane holds before this column's; row w
         # of the lane sees ``held + w + 1`` side rows
@@ -154,63 +232,36 @@ def _decode_kernel(
     (q_ref,  # [1, H, KD] VMEM (block-diagonal query for this sequence)
      hk_ref,  # [1, N, KD] VMEM (horizon side buffer, rows 0..n_extra-1 valid)
      hv_ref,  # [1, N, VD] VMEM (VD: V's lanes, KD unless values are narrower than keys)
-     k_hbm,  # [L, P*ps, KD] HBM (read-only cache)
+     k_hbm,  # [L * P, ps, KD] HBM (read-only cache): a page is one row of the leading axis
      v_hbm,
      out_ref,  # [1, H, VD] VMEM
      k_buf,  # [2, n*ps, KD] VMEM: two slots of one block each
      v_buf,
      acc_ref,  # [H, VD] f32
      slot_ref,  # [1] int32 SMEM: the slot this lane's first block is in
-     sems,  # DMA sems [2 (K, V), 2 slots]
+     k_sems,  # DMA sems [2 slots], one set a stream
+     v_sems,
      ) = refs
-    streams = ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1))
     b = pl.program_id(0)
     B = pl.num_programs(0)
     H = q_ref.shape[1]
     N = hk_ref.shape[1]
-    mp = page_tables_ref.shape[1]
     S = n * ps
     n_extra = held_ref[b] + 1 if rows > 1 else meta_ref[0]
-    layer = meta_ref[1]
+    layer_row = meta_ref[1] * pages
     window = meta_ref[2]  # a verify column has none (``paged_attention_verify_cached``)
+    streams = ((k_hbm, k_buf, k_sems), (v_hbm, v_buf, v_sems))
     lane_pages = functools.partial(_lane_pages, entry_pos_ref, mp=mp, ps=ps, n=n,
                                    n_extra=n_extra, window=window)
+    start = functools.partial(_start_pages, page_tables_ref, streams, layer_row, n=n, ps=ps,
+                              mp=mp)
 
     entry, lo, first, n_pages, blocks = lane_pages(b)
     nxt = jnp.minimum(b + 1, B - 1)
     _, _, next_first, next_pages, next_blocks = lane_pages(nxt)
     next_has_blocks = (b + 1 < B) & (next_blocks > 0)
 
-    def fetch(own, j, slot, wait=False):
-        """Start, or wait for, block ``j`` of this lane (``own``) or of the
-        next one into a slot: the pages the lane has there, a loop over them
-        (the lane that starts a block and the lane that waits for it work
-        its bounds out from the same scalars)."""
-        lane = jnp.where(own, b, nxt)
-        page0 = jnp.where(own, first, next_first) + j * n
-        count = jnp.minimum(jnp.where(own, n_pages, next_pages) - page0, n)
-
-        def page(i, _):
-            row0 = pl.multiple_of(page_tables_ref[lane, page0 + i] * ps, ps)
-            dst = pl.ds(pl.multiple_of(i * ps, ps), ps)
-            for hbm, buf, s in streams:
-                copy = pltpu.make_async_copy(hbm.at[layer, pl.ds(row0, ps)],
-                                             buf.at[slot, dst], sems.at[s, slot])
-                if wait:
-                    copy.wait()
-                else:
-                    copy.start()
-            return 0
-
-        jax.lax.fori_loop(0, count, page, 0)
-
-    @pl.when(b == 0)
-    def _first_lane():
-        # rows of a slot past a short block keep what the block before left
-        # there, and before the first block that is whatever VMEM held: a
-        # masked probability of 0 times a NaN is a NaN (a masked score is
-        # replaced, so K needs no such care)
-        v_buf[...] = jnp.zeros_like(v_buf)
+    _zero_slots(v_buf, b == 0, n=n, ps=ps)
 
     # the slot this lane's first block is in; lane 0 starts its own, and a
     # lane without blocks hands the next lane's first block on at once
@@ -219,7 +270,15 @@ def _decode_kernel(
 
     @pl.when(own_first | ((blocks == 0) & next_has_blocks))
     def _start():
-        fetch(own_first, 0, slot0)
+        # few lanes pass here (lane 0, and a lane behind one without blocks):
+        # a loop at run time, which a program's set-up traces and lowers once
+        start(jnp.where(own_first, b, nxt), jnp.where(own_first, first, next_first),
+              jnp.where(own_first, n_pages, next_pages), slot0, unroll=False)
+
+    # this lane's second block goes into the other slot now (the lane before
+    # is done with it), so that its copies are started beside the side rows'
+    # products and HBM works while a lane's fixed part runs
+    start(b, first + n, jnp.where(blocks > 1, n_pages, 0), 1 - slot0, unroll=True)
 
     q = q_ref[0]  # [H, KD] block-diagonal, cache dtype
 
@@ -239,7 +298,7 @@ def _decode_kernel(
 
     # in-flight horizon tokens first (side rows sit at positions entry + col;
     # the current token's own row is among them, so the maximum is finite
-    # from here on), while the first block is on its way
+    # from here on), while the first two blocks are on their way
     col = jax.lax.broadcasted_iota(jnp.int32, (H, N), 1)
     seen = n_extra
     if rows > 1:  # each row its own count: H is rows x heads here
@@ -253,15 +312,18 @@ def _decode_kernel(
     def body(j, carry):
         m_prev, l_prev = carry
         slot = (slot0 + j) & 1
-
         more = j + 1 < blocks  # else the next lane's first block
-
-        @pl.when(more | next_has_blocks)
-        def _prefetch():
-            fetch(more, jnp.where(more, j + 1, 0), 1 - slot)
-
-        fetch(True, j, slot, wait=True)
-        pos = (first + j * n) * ps + jax.lax.broadcasted_iota(jnp.int32, (H, S), 1)
+        # block j + 1 of this lane from its third on (the second was started
+        # above), or the next lane's first.  Whether there is one is folded
+        # into the count, not put round the starts: a branch is a region of
+        # its own
+        held = jnp.where(more, jnp.where(j > 0, n_pages, 0),
+                         jnp.where(next_has_blocks, next_pages, 0))
+        page0 = first + j * n
+        start(jnp.where(more, b, nxt), jnp.where(more, page0 + n, next_first), held, 1 - slot,
+              unroll=True)
+        _wait_pages(streams, jnp.minimum(n_pages - page0, n), slot, n=n, ps=ps)
+        pos = page0 * ps + jax.lax.broadcasted_iota(jnp.int32, (H, S), 1)
         s = scores_of(k_buf[slot], jnp.where(pos < entry, pos, -1))
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -273,6 +335,68 @@ def _decode_kernel(
 
     slot_ref[0] = (slot0 + blocks) & 1
     out_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-20)).astype(out_ref.dtype)
+
+
+def _paged_call(q_bd, hk, hv, k_cache, v_cache, prefetch, *, scale, softcap, rows,
+                interpret, pages_per_block):
+    """``_decode_kernel`` over a lane's ``q_bd`` [B, rows * H, KD]
+    block-diagonal query rows: [B, rows * H, VD] in ``q_bd``'s dtype.
+    ``prefetch``: the tables, the entry positions, the meta row and, for a
+    verify column, the side rows held."""
+    B, HR, KD = q_bd.shape
+    L, P, ps, _ = k_cache.shape
+    VD = v_cache.shape[3]  # KD, but where values are narrower than keys
+    N = hk.shape[1]
+    mp = prefetch[0].shape[1]
+    cd = k_cache.dtype
+    if KD % 128 != 0 or VD % 128 != 0:
+        raise ValueError(f"kv_heads*head_dim={KD} must be a multiple of 128 for the "
+                         "pallas decode kernel; use the XLA fallback")
+    n = pages_per_block or _pages_per_block(ps, KD, cd.itemsize, mp)
+    if N == 1:
+        # Mosaic lowers a product over one row to a broadcast and trips on
+        # its dtypes (bfloat16 operands, float32 result); a second row is
+        # masked like any row past ``n_extra``
+        hk, hv = (jnp.pad(x, ((0, 0), (0, 1), (0, 0))) for x in (hk, hv))
+        N = 2
+    tables, *scalars = (x.astype(jnp.int32) for x in prefetch)
+    kernel = functools.partial(_decode_kernel, ps=ps, n=n, mp=mp, pages=P, scale=scale,
+                               softcap=softcap, rows=rows)
+    lane = lambda b, *_: (b, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(B,),
+        in_specs=[
+            pl.BlockSpec((1, HR, KD), lane),
+            pl.BlockSpec((1, N, KD), lane),
+            pl.BlockSpec((1, N, VD), lane),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, HR, VD), lane),
+        scratch_shapes=[
+            pltpu.VMEM((2, n * ps, KD), cd),
+            pltpu.VMEM((2, n * ps, VD), cd),
+            pltpu.VMEM((HR, VD), jnp.float32),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, HR, VD), q_bd.dtype),
+        # lanes in order: a lane's last block starts the next lane's first.
+        # No bounds checks on the copies (13 of a start's 27 scalar
+        # operations, ``PERF.md`` section 6, PR 45 and PR 48): a page index
+        # comes from the table of a lane that holds the page, as in every
+        # kernel that reads one
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",),
+                                             disable_bounds_checks=True),
+        interpret=interpret,
+    )(tables.reshape(B * mp), *scalars, q_bd, hk.astype(cd), hv.astype(cd),
+      k_cache.reshape(L * P, ps, KD), v_cache.reshape(L * P, ps, VD))
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "softcap", "interpret",
@@ -294,68 +418,16 @@ def paged_attention_decode_cached(
     interpret: bool = False,
     pages_per_block: int | None = None,  # None: ``_pages_per_block`` (tests set it)
 ) -> jax.Array:
-    B, H, D = q.shape
-    L, P, ps, KD = k_cache.shape
-    VD = v_cache.shape[3]  # KD, but where values are narrower than keys
-    K = KD // D
-    N = hk.shape[1]
-    mp = page_tables.shape[1]
-    cd = k_cache.dtype
-    if KD % 128 != 0 or VD % 128 != 0:
-        raise ValueError(f"kv_heads*head_dim={KD} must be a multiple of 128 for the "
-                         "pallas decode kernel; use the XLA fallback")
-    n = pages_per_block or _pages_per_block(ps, KD, cd.itemsize, mp)
-    if N == 1:
-        # Mosaic lowers a product over one row to a broadcast and trips on
-        # its dtypes (bfloat16 operands, float32 result); a second row is
-        # masked like any row past ``n_extra``
-        hk, hv = (jnp.pad(x, ((0, 0), (0, 1), (0, 0))) for x in (hk, hv))
-        N = 2
-
+    K = k_cache.shape[3] // q.shape[2]
     meta = jnp.stack([
         jnp.asarray(n_extra, jnp.int32),
         jnp.asarray(layer, jnp.int32),
         jnp.asarray(0 if window is None else window, jnp.int32),
     ])
-
-    kernel = functools.partial(_decode_kernel, ps=ps, n=n, scale=scale,
-                               softcap=float(softcap or 0.0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, H, KD), lambda b, *_: (b, 0, 0)),
-            pl.BlockSpec((1, N, KD), lambda b, *_: (b, 0, 0)),
-            pl.BlockSpec((1, N, VD), lambda b, *_: (b, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, H, VD), lambda b, *_: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, n * ps, KD), cd),
-            pltpu.VMEM((2, n * ps, VD), cd),
-            pltpu.VMEM((H, VD), jnp.float32),
-            pltpu.SMEM((1,), jnp.int32),
-            pltpu.SemaphoreType.DMA((2, 2)),
-        ],
-    )
-    out_kd = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, VD), q.dtype),
-        # lanes in order: a lane's last block starts the next lane's first
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(
-        page_tables.astype(jnp.int32),
-        entry_positions.astype(jnp.int32),
-        meta,
-        block_diagonal_query(q.astype(cd), K),
-        hk.astype(cd),
-        hv.astype(cd),
-        k_cache.reshape(L, P * ps, KD),
-        v_cache.reshape(L, P * ps, VD),
-    )
+    out_kd = _paged_call(block_diagonal_query(q.astype(k_cache.dtype), K), hk, hv, k_cache,
+                         v_cache, (page_tables, entry_positions, meta), scale=scale,
+                         softcap=float(softcap or 0.0), rows=1, interpret=interpret,
+                         pages_per_block=pages_per_block)
     return own_lanes(out_kd, K).astype(q.dtype)
 
 
@@ -382,8 +454,8 @@ def _latent_decode_kernel(
 ):
     """The latent cache's own body: one buffer whose entries are keys and,
     in their first ``latent`` lanes, values.  64-128 heads meet 640 lanes, so a
-    block's bytes no longer hide what the loop does beside them, and the loop
-    is built round that (module docstring)."""
+    block's bytes do not hide what the loop does beside them, and the loop is
+    built round that (module docstring)."""
     b = pl.program_id(0)
     B = pl.num_programs(0)
     H = q_ref.shape[1]
@@ -398,42 +470,11 @@ def _latent_decode_kernel(
     _, _, _, next_pages, next_blocks = lane_pages(nxt)
     next_has_blocks = (b + 1 < B) & (next_blocks > 0)
 
-    def start(lane, page0, held, slot):
-        """Start the pages ``page0 .. min(page0 + n, held) - 1`` of a lane
-        into a slot: one predicated copy a page and no loop, so that they
-        are straight-line code (a loop over a count is a region of its own,
-        40 bundles a page with nothing else in them)."""
-        for i in range(n):
-            @pl.when(page0 + i < held)
-            def _():
-                page = page_tables_ref[lane * mp + page0 + i]
-                pltpu.make_async_copy(cache_hbm.at[layer_row + page],
-                                      buf.at[slot, pl.ds(i * ps, ps)], sems.at[slot]).start()
+    streams = ((cache_hbm, buf, sems),)
+    start = functools.partial(_start_pages, page_tables_ref, streams, layer_row, n=n, ps=ps,
+                              mp=mp)
 
-    def wait(count, slot):
-        """Wait for the ``count`` pages of a slot's block.  The semaphore
-        counts bytes, so a descriptor of k pages waits for any k of them: one
-        wait a set bit of ``count``, one in all for a full block."""
-        for bit in range(n.bit_length()):
-            k = 1 << bit
-
-            @pl.when((count & k) != 0)
-            def _():
-                rows = pl.ds(0, k * ps)
-                pltpu.make_async_copy(buf.at[1 - slot, rows], buf.at[slot, rows],
-                                      sems.at[slot]).wait()
-
-    # rows of a slot past a short block keep what the block before left
-    # there, and before the first block that is whatever VMEM held: a masked
-    # probability of 0 times a NaN is a NaN.  A loop over a count, because a
-    # ``pl.when`` round plain stores becomes predicated stores, which every
-    # lane would pay for
-    def zero_page(i, _):
-        rows = pl.ds(pl.multiple_of((i % n) * ps, ps), ps)
-        buf[i // n, rows] = jnp.zeros((ps, buf.shape[2]), buf.dtype)
-        return 0
-
-    jax.lax.fori_loop(0, jnp.where(b == 0, 2 * n, 0), zero_page, 0)
+    _zero_slots(buf, b == 0, n=n, ps=ps)
 
     # the slot this lane's first block is in; lane 0 starts its own, and a
     # lane without blocks hands the next lane's first block on at once
@@ -478,7 +519,7 @@ def _latent_decode_kernel(
         held = jnp.where(more, jnp.where(j > 0, n_pages, 0),
                          jnp.where(next_has_blocks, next_pages, 0))
         start(jnp.where(more, b, nxt), jnp.where(more, (j + 1) * n, 0), held, 1 - slot)
-        wait(jnp.minimum(n_pages - j * n, n), slot)
+        _wait_pages(streams, jnp.minimum(n_pages - j * n, n), slot, n=n, ps=ps)
         pos = j * S + jax.lax.broadcasted_iota(jnp.int32, (H, S), 1)
         s = scores_of(buf[slot], pos < entry)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -506,7 +547,7 @@ def latent_attention_decode_cached(
     latent: int,  # the entry's first ``latent`` lanes are its value (a multiple of 128)
     scale: float,
     interpret: bool = False,
-    pages_per_block: int | None = None,  # None: ``LATENT_BLOCK_TOKENS`` of entries (tests set it)
+    pages_per_block: int | None = None,  # None: ``_pages_per_block`` (tests set it)
 ) -> jax.Array:
     """Absorbed latent attention over a cache with no V buffer
     (``_latent_decode_kernel``).  All ``H`` query heads meet one "head" of
@@ -521,7 +562,7 @@ def latent_attention_decode_cached(
     if W % 128 or latent % 128 or cache.shape[3] != W:
         raise ValueError(f"latent entries of {cache.shape[3]} lanes (values {latent}) "
                          "are not whole 128-lane tiles")
-    n = pages_per_block or _latent_pages_per_block(ps, mp)
+    n = pages_per_block or _pages_per_block(ps, W, cd.itemsize, mp)
     if N == 1:  # see ``paged_attention_decode_cached``
         side = jnp.pad(side, ((0, 0), (0, 1), (0, 0)))
         N = 2
@@ -580,53 +621,11 @@ def paged_attention_verify_cached(
     axis: a lane's pages are streamed once for all its rows, every row sees
     all of them, and row ``w`` sees the side rows up to ``held + w``."""
     B, W, H, D = q.shape
-    L, P, ps, KD = k_cache.shape
-    VD = v_cache.shape[3]
+    KD, VD = k_cache.shape[3], v_cache.shape[3]
     K = KD // D
-    N = hk.shape[1]
-    mp = page_tables.shape[1]
-    cd = k_cache.dtype
-    if KD % 128 != 0 or VD % 128 != 0:
-        raise ValueError(f"kv_heads*head_dim={KD} must be a multiple of 128 for the "
-                         "pallas decode kernel; use the XLA fallback")
-    n = pages_per_block or _pages_per_block(ps, KD, cd.itemsize, mp)
     meta = jnp.stack([jnp.int32(0), jnp.asarray(layer, jnp.int32), jnp.int32(0)])
-    kernel = functools.partial(_decode_kernel, ps=ps, n=n, scale=scale, softcap=0.0, rows=W)
-    lane = lambda b, *_: (b, 0, 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, W * H, KD), lane),
-            pl.BlockSpec((1, N, KD), lane),
-            pl.BlockSpec((1, N, VD), lane),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, W * H, VD), lane),
-        scratch_shapes=[
-            pltpu.VMEM((2, n * ps, KD), cd),
-            pltpu.VMEM((2, n * ps, VD), cd),
-            pltpu.VMEM((W * H, VD), jnp.float32),
-            pltpu.SMEM((1,), jnp.int32),
-            pltpu.SemaphoreType.DMA((2, 2)),
-        ],
-    )
-    out_kd = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, W * H, VD), q.dtype),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(
-        page_tables.astype(jnp.int32),
-        entry_positions.astype(jnp.int32),
-        meta,
-        held.astype(jnp.int32),
-        block_diagonal_query(q.astype(cd), K).reshape(B, W * H, KD),
-        hk.astype(cd),
-        hv.astype(cd),
-        k_cache.reshape(L, P * ps, KD),
-        v_cache.reshape(L, P * ps, VD),
-    )
+    q_bd = block_diagonal_query(q.astype(k_cache.dtype), K).reshape(B, W * H, KD)
+    out_kd = _paged_call(q_bd, hk, hv, k_cache, v_cache,
+                         (page_tables, entry_positions, meta, held), scale=scale, softcap=0.0,
+                         rows=W, interpret=interpret, pages_per_block=pages_per_block)
     return own_lanes(out_kd.reshape(B, W, H, VD), K).astype(q.dtype)
