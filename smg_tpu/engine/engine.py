@@ -29,6 +29,12 @@ from smg_tpu.utils import get_logger
 
 logger = get_logger("engine")
 
+#: the longest a step waits for the submissions it lets in
+#: (``Engine._let_submitters_in``): a thread that was blocked on the lock
+#: needs a fraction of a millisecond to take it and queue its request; past
+#: this the step goes on and the submission is admitted a frame later
+SUBMIT_YIELD_S = 0.002
+
 
 @dataclass
 class RequestOutput:
@@ -156,6 +162,12 @@ class Engine:
         # written under the engine lock, read by loads()
         self.num_submits = 0
         self.submit_lock_wait_s_total = 0.0
+        # submissions that wait for the engine lock right now, counted under
+        # a lock of its own (they do not hold the engine's yet): the step lets
+        # them in before its prefill phase (``_let_submitters_in``)
+        self._waiters_lock = make_lock("engine.submit_waiters")
+        self._submit_waiters = 0
+        self.scheduler.let_submitters_in = self._let_submitters_in
         # failure isolation: step-watchdog state.  ``_last_progress`` is a
         # bare float written by the step thread and read by the watchdog
         # WITHOUT the engine lock — the watchdog must never block on a lock
@@ -253,8 +265,14 @@ class Engine:
         frame in flight, so this wait is part of a caller's time to first
         token that ``queued_t`` (stamped after the lock is won) cannot see."""
         req.submit_t = time.monotonic()
-        with TraceAnnotation("smg.submit.lock_wait"):
-            self._wakeup.acquire()
+        with self._waiters_lock:
+            self._submit_waiters += 1
+        try:
+            with TraceAnnotation("smg.submit.lock_wait"):
+                self._wakeup.acquire()
+        finally:
+            with self._waiters_lock:
+                self._submit_waiters -= 1
         try:
             wait = time.monotonic() - req.submit_t
             self.num_submits += 1
@@ -263,6 +281,26 @@ class Engine:
             yield
         finally:
             self._wakeup.release()
+
+    def _let_submitters_in(self) -> None:
+        """Called by the step, which holds the engine lock, where nothing of
+        its own is on the device yet (``Scheduler._admit``): hand the lock to
+        the submissions that wait for it, so that this step's prefill phase
+        admits them.  ``step()`` holds the lock across the blocking fetch of
+        the frame in flight, so a caller's next request, sent when it heard
+        of a finish, waits out the rest of that frame on the lock; let in
+        only after the step, it would find the next frame launched and wait
+        that one out in the queue too, with its lane computed empty all the
+        while.  The wait on the condition lets go of the lock; a submission
+        notifies it once queued; ``SUBMIT_YIELD_S`` bounds it, because the
+        chip has nothing to do meanwhile."""
+        if self._submitters_waiting():
+            self._wakeup.wait_for(lambda: not self._submitters_waiting(),
+                                  timeout=SUBMIT_YIELD_S)
+
+    def _submitters_waiting(self) -> bool:
+        with self._waiters_lock:
+            return self._submit_waiters > 0
 
     def _build_token_filter(self, sampling: SamplingParams):
         """Install the grammar vocab-mask filter for structured output.

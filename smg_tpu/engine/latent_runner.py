@@ -79,15 +79,6 @@ class LatentModelRunner(ModelRunner):
                 "form": "latent: expanded prefill (XLA's score blocks, or the online-softmax "
                         "kernel for cold groups: launches say which), absorbed decode"}
 
-    # ``Scheduler._headroom_pages``: a decode frame may count on the radix
-    # cache's unpinned pages.  At 64 lanes a frame of 8 columns needs some 32
-    # new pages, and once finished prompts have filled the pool little more
-    # than the watermark is free: on the free pool alone every frame then
-    # runs one column (3,340 tokens/s before the pool filled, 1,200 after;
-    # PERF.md, Findings, PR 34).  The pages are evicted either way, a few
-    # columns later.
-    unpinned_pages_are_headroom = True
-
     @property
     def widest_table_only(self) -> bool:
         """Decode programs are compiled at the widest page table alone."""

@@ -384,7 +384,3 @@ class RecurrentModelRunner(ModelRunner):
 
     def _take_frame_state(self, state: list) -> None:
         self.s_pool, self.c_pool = state
-
-    # no match is honoured without the state at its end, so no cached page is
-    # ever pinned and every one is a frame's to count on (``_headroom_pages``)
-    unpinned_pages_are_headroom = True

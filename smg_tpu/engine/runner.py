@@ -2136,9 +2136,7 @@ class ModelRunner:
         """Zero the KV buffers (used by flush_cache after the radix reset)."""
         self.k_cache, self.v_cache = create_kv_buffers(self.spec, self.kv_sharding)
 
-    # What the scheduler asks a runner (``Scheduler._headroom_pages``,
-    # ``_mp_bucket``): whether a decode frame may count on the radix cache's
-    # pages no live request holds, and whether decode programs exist at the
-    # widest page table alone.  The Llama family says no to both.
-    unpinned_pages_are_headroom = False
+    # What the scheduler asks a runner (``Scheduler._mp_bucket``): whether
+    # decode programs exist at the widest page table alone.  The Llama family
+    # says no.
     widest_table_only = False

@@ -235,6 +235,10 @@ class Scheduler:
         # runner's unfetched parts), until the step's decode launch is out
         self._chaining = False
         self._pending_group: tuple | None = None
+        # the engine's: hands its lock to the submissions that wait for it
+        # (``Engine._let_submitters_in``); None where nothing drives the
+        # scheduler through an engine
+        self.let_submitters_in = None
         self.num_prefill_chained = 0
         self.num_prefill_sync = dict.fromkeys(PREFILL_SYNC_REASONS, 0)
         # megastep decode (device-fused K-step horizon) accounting + the
@@ -1459,8 +1463,8 @@ class Scheduler:
         if need > self._headroom_pages():
             return None
         for (_slot, req, expected), limit in zip(frame.lanes, reach):
-            # precheck guarantees allocation without preemption (and, but for
-            # a recurrent model's never-pinned cache, without eviction)
+            # precheck guarantees allocation without preemption (it may evict
+            # cached pages that no request holds)
             if not self._ensure_seq_capacity(req, limit - expected):
                 return None  # defensive; unreachable after the precheck
         mp_b = self._mp_bucket(max(math.ceil(limit / ps) for limit in reach))
@@ -1523,6 +1527,13 @@ class Scheduler:
         resumable chunks, back-pressure, over-budget waiting) leaves the
         global key-fold order untouched, so a chained decode launch stays
         byte-identical to the synchronous schedule."""
+        if self._chaining and self.let_submitters_in is not None:
+            # the overlap pipeline with no lookahead out: the frame in flight
+            # is fetched, the step's decode launch follows this phase, and a
+            # request that came while the fetch blocked is still outside the
+            # lock.  Let in now, it is prefilled in this step; let in after
+            # it, it waits out the frame this step is about to launch
+            self.let_submitters_in()
         if self.sched.prefill_mix_policy == "throughput":
             return self._admit_legacy(outputs)
         return self._admit_budgeted(outputs)
@@ -2361,15 +2372,19 @@ class Scheduler:
 
     def _headroom_pages(self) -> int:
         """Pages a decode launch may count on without preempting anyone: the
-        free pool and, where the runner says so, the radix cache's pages no
-        live request holds.  Such a page is freed by the next column that
-        needs one whatever the horizon, and never by a preemption, so
-        counting it moves no stream; with the free pool alone a frame runs
-        one or two columns as soon as finished prompts have filled the pool.
-        (A model with recurrent layers pins no cached page at all: no match
-        is honoured there without the state at its end.)"""
+        free pool and the radix cache's pages no live request holds.  Such a
+        page is freed by the next column that needs one whatever the horizon,
+        and never by a preemption, so counting it moves no stream; with the
+        free pool alone a frame runs one or two columns as soon as finished
+        prompts have filled the pool, and every frame's end costs the whole
+        batch (``PERF.md``, Findings: PR 34 read 3,340 tokens/s before the
+        pool filled and 1,200 after in ``reason``; PR 49, which took the
+        Llama path's exception away, a third of ``eval``'s launches cut
+        short and 2.3 % of its tokens).  (A model with recurrent layers pins
+        no cached page at all: no match is honoured there without the state
+        at its end.)"""
         free = self.pool.free_count
-        if self.radix is not None and self.runner.unpinned_pages_are_headroom:
+        if self.radix is not None:
             free += self.radix.num_unpinned_pages
         return free
 
@@ -2440,9 +2455,14 @@ class Scheduler:
         bound), so admission bursts don't retrace.  The rule samples the
         queue at LAUNCH time, so a request submitted while a K-column frame
         is already in flight waits up to K decode columns before its first
-        prefill chunk can run — bound the cap accordingly on TTFT-sensitive
-        deployments (the adaptive controller's finish-gap EMA does not see
-        arrival rate).
+        prefill chunk can run, and no longer: the step that fetches that
+        frame lets the submission in ahead of its prefill phase
+        (``_admit``), where it used to find the next frame launched as well.
+        Shorter frames while a slot stands free were measured and lose: a
+        frame's end costs the whole batch about 2 ms on the device and, where
+        no lookahead is out, the host's 2-3 ms beside it, more than the one
+        lane's wait they save (``PERF.md``, Findings, PR 49: ``eval`` -6 % at
+        two columns a frame, -2 % at four).
 
         Otherwise the static path uses ``decode_horizon`` as-is, and the
         adaptive controller (``adaptive_horizon``) starts from the cap and

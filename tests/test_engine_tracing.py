@@ -216,6 +216,38 @@ def test_timeline_stamps_run_in_order_and_the_lock_wait_is_counted():
         loads["submit_lock_wait_seconds"])
 
 
+def test_the_step_hands_the_lock_to_a_submission_that_waits_for_it():
+    """What ``step()`` does before its prefill phase, with the engine lock
+    held as ``step()`` holds it: a submission that waits for the lock is
+    queued when ``_let_submitters_in`` returns, the lock is this thread's
+    again, and the wait is counted as the submission's lock wait."""
+    eng = make_engine()
+    with eng._lock:
+        eng._let_submitters_in()  # nobody waits: returns at once
+        submitter = threading.Thread(target=eng.submit, args=([5, 6, 7], greedy(4)))
+        submitter.start()
+        deadline = time.monotonic() + 10
+        while not eng._submitters_waiting() and time.monotonic() < deadline:
+            time.sleep(0.001)
+        assert eng._submitters_waiting() and not eng.scheduler.waiting
+        held = time.monotonic()
+        while not eng.scheduler.waiting and time.monotonic() < deadline:
+            eng._let_submitters_in()  # bounded by SUBMIT_YIELD_S a call
+        assert len(eng.scheduler.waiting) == 1 and not eng._submitters_waiting()
+        assert time.monotonic() - held < 1.0
+        # the lock is held again: another thread cannot take it
+        took = []
+        prober = threading.Thread(target=lambda: took.append(eng._lock.acquire(timeout=0.05)))
+        prober.start()
+        prober.join(10)
+        assert took == [False]
+    submitter.join(10)
+    assert not submitter.is_alive()
+    assert eng.loads()["submits"] == 1 and eng.loads()["submit_lock_wait_seconds"] > 0.0
+    while eng.scheduler.has_work():
+        eng.step()
+
+
 @pytest.mark.parametrize("case,reason", [
     ("alone", "full"), ("queued", "pending_admission"), ("stop_string", "forced_lane"),
     ("no_megastep", "cap"),

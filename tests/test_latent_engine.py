@@ -268,25 +268,29 @@ def test_eviction_from_the_heap_frees_what_a_walk_of_the_tree_freed(seed):
     assert len(heap._lru) <= 4 * 0 + 1024
 
 
-def test_a_pool_filled_by_finished_prompts_does_not_shorten_the_frames():
+@pytest.mark.parametrize("family", ["latent", "llama"])
+def test_a_pool_filled_by_finished_prompts_does_not_shorten_the_frames(family):
     """64 tokens of pool beyond what the lanes hold, all of it in the radix
-    cache: on the free pool alone a frame may run one column; with the
-    unpinned pages counted it runs its eight, and the streams are the same."""
+    cache: on the free pool alone a frame would run one column; the unpinned
+    pages are a frame's to count on under every runner (the Llama path had
+    its exception until PR 49), so it runs its eight, preempts nobody, and
+    the streams are the one-column schedule's."""
     jobs = [(p, greedy(40)) for p in prompts(5, 30, 45, 28, 50)]
+    model = tiny_test_config() if family == "llama" else None
     streams = {}
-    for counted in (True, False):
-        e = make_engine(num_pages=40, watermark_pages=1)
-        e.runner.unpinned_pages_are_headroom = counted
+    for horizon in (8, 1):
+        e = make_engine(num_pages=40, watermark_pages=1, model=model, horizon=horizon)
         for p in prompts(6, 64, 64, 64, 64, 64, 64):  # fill the cache with finished prompts
             e.generate(prompt_ids=p, sampling=greedy(2))
         assert e.scheduler.pool.free_count < 16 < e.scheduler.radix.num_unpinned_pages
         before = dict(e.loads()["decode_launches"])
-        streams[counted] = run_all(e, jobs)
+        streams[horizon] = run_all(e, jobs)
         after = e.loads()["decode_launches"]
-        short = after["page_headroom"] - before["page_headroom"]
-        assert (short == 0) if counted else (short > 0)
+        assert after["page_headroom"] == before["page_headroom"]
         assert e.loads()["preemptions"] == 0 and e.loads()["audit"]["clean"]
-    assert streams[True] == streams[False]
+        if horizon == 8:
+            assert after["full"] > before["full"]
+    assert streams[8] == streams[1]
 
 
 def test_overlapped_and_synchronous_schedules_give_the_same_tokens():

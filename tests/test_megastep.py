@@ -22,7 +22,7 @@ from smg_tpu.engine.config import SchedulerConfig
 from smg_tpu.faults import FAULTS
 from smg_tpu.protocols.sampling import SamplingParams
 
-from tests.test_overlap import greedy, make_engine, run_streams
+from tests.test_overlap import greedy, make_engine, run_streams, streams_of
 
 
 @pytest.fixture(autouse=True)
@@ -72,6 +72,48 @@ def k1_baseline():
     return run_streams(make_engine(True), MIXED_JOBS)
 
 
+def arrival_streams(engine, jobs, points=None, start=2):
+    """``run_streams`` with arrivals mid-stream: the first ``start`` jobs are
+    submitted together and each later one while they decode.  Without
+    ``points`` a job is submitted after a step that reported a finish, as a
+    closed loop's caller does, and the point of the streams at which it was
+    admitted is recorded: every other request's tokens so far, read when
+    its first token comes.  With ``points`` (another run's) it is submitted
+    before the step that finds the streams at that point, which a schedule
+    of one column a step passes whatever the frames of the other run were.
+    Returns the streams and the points."""
+    chunks: dict[str, list] = {rid: [] for rid, _, _ in jobs}
+    seen: dict[str, dict] = {}
+    count = lambda: {rid: sum(len(c.new_token_ids) for c in cs)
+                     for rid, cs in chunks.items() if cs}
+
+    def cb(out):
+        if not chunks[out.rid]:
+            seen[out.rid] = count()
+        chunks[out.rid].append(out)
+
+    due = list(jobs)
+
+    def submit():
+        rid, prompt, sampling = due.pop(0)
+        engine.submit(prompt, sampling, rid=rid, on_output=cb)
+
+    for _ in range(start):
+        submit()
+    for _ in range(5000):
+        if not due and not engine.scheduler.has_work():
+            break
+        if points is not None and due and count() == points[due[0][0]]:
+            submit()
+        finishes = sum(1 for o in engine.step() if o.finished)
+        if points is None:
+            for _ in range(min(finishes, len(due))):
+                submit()
+    else:
+        raise TimeoutError(f"jobs stuck: {engine.loads()}")
+    return streams_of(chunks), seen
+
+
 # tier-1 wall-clock: K=4 (both schedules) is the in-band gate; the K∈{2,8}
 # variants ride the slow lane with the exhaustive sweep (ROADMAP practical
 # note — the full suite must fit the 870s harness timeout)
@@ -80,10 +122,21 @@ def k1_baseline():
     pytest.param(8, marks=pytest.mark.slow),
 ])
 @pytest.mark.parametrize("overlap", [True, False])
-def test_k_sweep_byte_identical_to_k1(horizon, overlap, k1_baseline):
-    got = run_streams(make_engine(overlap, decode_horizon=horizon), MIXED_JOBS)
-    assert_stream_parity(got, k1_baseline,
-                         f"megastep K={horizon} overlap={overlap}")
+@pytest.mark.parametrize("traffic", ["at_once", "arrivals"])
+def test_k_sweep_byte_identical_to_k1(horizon, overlap, traffic, k1_baseline):
+    if traffic == "at_once":
+        got = run_streams(make_engine(overlap, decode_horizon=horizon), MIXED_JOBS)
+        base = k1_baseline
+    else:
+        # two slots and a caller's next request after every finish: a later
+        # job is admitted between two frames of the others, wherever their
+        # ends fall at this K, and the one-column schedule given the same
+        # arrivals has the same streams
+        got, points = arrival_streams(
+            make_engine(overlap, decode_horizon=horizon, max_batch=2), MIXED_JOBS)
+        base, _ = arrival_streams(make_engine(False, max_batch=2), MIXED_JOBS, points)
+    assert_stream_parity(got, base,
+                         f"megastep K={horizon} overlap={overlap} {traffic}")
 
 
 def test_eos_and_stop_token_finish_inside_horizon():
@@ -321,6 +374,55 @@ def test_adaptive_horizon_controller_behaviors():
         eng2.step()
     act2 = eng2.scheduler._decode_active()
     assert act2 and eng2.scheduler._pick_horizon(act2)[0] < 8
+
+
+def test_a_submission_that_waited_out_the_fetch_is_prefilled_in_that_step():
+    """Three lanes, one ends ("b"), and the step that reports it launches the
+    next frame.  The caller's next request comes while the step after that
+    holds the engine lock in the frame's fetch; no lookahead is out, because
+    another lane's last token lies inside the frame ("d": lengths end most
+    frames of the benchmark's cells so).  The step lets the submission in
+    ahead of its prefill phase (``Scheduler.let_submitters_in``; here the hook
+    submits, as the waiting thread does once the lock is handed over): the
+    request is prefilled in that step, after the one frame, and not after
+    the frame the step would have launched before the submission got the
+    lock."""
+    eng = make_engine(True, decode_horizon=8, max_batch=3)
+    toks: dict = {rid: [] for rid in "abcd"}
+    done = set()
+
+    def cb(out):
+        toks[out.rid].extend(out.new_token_ids)
+        if out.finished:
+            done.add(out.rid)
+
+    for rid, first, n in (("a", 5, 60), ("b", 30, 11), ("d", 90, 15)):
+        eng.submit(list(range(first, first + 20)), greedy(n), rid=rid, on_output=cb)
+    while "b" not in done:
+        eng.step()
+    assert eng.scheduler.inflight is not None  # the frame the submission waits out
+    at_finish, let_in = len(toks["a"]), []
+
+    def waiting_submitter():
+        let_in.append(eng.scheduler.flight.step_serial)
+        if len(let_in) == 1:
+            eng.submit(list(range(60, 85)), greedy(8), rid="c", on_output=cb)
+
+    eng.scheduler.let_submitters_in = waiting_submitter
+    eng.step()
+    assert len(let_in) == 1 and len(toks["c"]) == 1  # let in and prefilled in one step
+    assert "d" in done and len(toks["a"]) - at_finish == 4  # the one frame, cut at d's end
+    frame = eng.scheduler.inflight  # and the launch behind the prefill holds both
+    assert [r.rid for _s, r, _e in frame.lanes] == ["a", "c"]
+    steps = eng.scheduler.flight.step_serial
+    while eng.scheduler.has_work():
+        eng.step()
+    assert len(toks["a"]) == 60 and len(toks["c"]) == 8
+    # a step with a lookahead out lets nobody in: a prefill behind it would
+    # fold a key after the lookahead's, and the lookahead would go
+    loads = eng.loads()
+    assert loads["lookahead_kept"] > 0 and loads["lookahead_discarded"] == 0
+    assert len(let_in) - 1 < eng.scheduler.flight.step_serial - steps
 
 
 def test_adaptive_parity_under_churn(k1_baseline):

@@ -58,13 +58,17 @@ def run_streams(engine: Engine, jobs: list) -> dict:
         engine.step()
     else:
         raise TimeoutError(f"jobs stuck: {engine.loads()}")
+    return streams_of(chunks)
+
+
+def streams_of(chunks: dict) -> dict:
+    """The full stream per rid of the outputs collected per rid."""
     out = {}
-    for rid, _, _ in jobs:
-        toks = [t for c in chunks[rid] for t in c.new_token_ids]
-        text = "".join(c.text_delta for c in chunks[rid])
-        lps = [round(x, 4) for c in chunks[rid] for x in c.logprobs]
-        last = chunks[rid][-1]
-        out[rid] = (toks, text, last.finish_reason, last.matched_stop, lps)
+    for rid, cs in chunks.items():
+        toks = [t for c in cs for t in c.new_token_ids]
+        text = "".join(c.text_delta for c in cs)
+        lps = [round(x, 4) for c in cs for x in c.logprobs]
+        out[rid] = (toks, text, cs[-1].finish_reason, cs[-1].matched_stop, lps)
     return out
 
 
