@@ -16,8 +16,9 @@ met inside the frame, a vocabulary mask, an M-RoPE offset and a LoRA bank
 among them, and tiny Llama once more under ``tp=2`` on forced host devices:
 the Llama frame alone carries ``in_shardings``, ``out_shardings`` and
 ``shard_hint``), and for ``tiny-olmo-hybrid``, ``tiny-pangu-moe``,
-``tiny-mimo``, ``tiny-longcat-flash`` and ``tiny-exaone-moe`` through
-``Engine`` (all but the first where the tree has them; the last one plain,
+``tiny-mimo``, ``tiny-longcat-flash``, ``tiny-exaone-moe``, ``tiny-nemotron-h``
+and ``tiny-kimi-linear`` through ``Engine`` (all but the first where the tree
+has them; the last one plain,
 self-drafting, and self-drafting with weights whose drafts are right, so
 that the verify frame's accept branch runs), a sampled group, a sampled
 stream and a stream with penalties and a stop token among them, and (C) the routed-expert layer itself under XLA's ragged product and under
@@ -379,6 +380,15 @@ def dump(root: str, path: str) -> None:
     _runner_programs("tiny", _configs()["tiny"], "xla", out, costs, tp=2)
     for impl in ("xla", "pallas_interpret"):
         _engine_programs("olmo_hybrid", tiny_olmo_hybrid_config(), impl, out, costs)
+    # the recurrent runner's two other modules, where the tree has them: state
+    # slots with routed counts beside K and V pages, and beside latent pages
+    for name, preset in (("nemotron_h", "tiny_nemotron_h_config"),
+                         ("kimi_linear", "tiny_kimi_linear_config")):
+        from smg_tpu.models import config as presets
+
+        if hasattr(presets, preset):
+            for impl in ("xla", "pallas_interpret"):
+                _engine_programs(name, getattr(presets, preset)(held=(4, 4)), impl, out, costs)
     try:
         from smg_tpu.models.config import tiny_pangu_moe_config
     except ImportError:  # a tree from before the latent model

@@ -231,7 +231,11 @@ def plan_recurrent_cache(
     device less what is in use, so the weights come off once
     (``plan_cache`` takes them off what is free after them, a second time,
     PERF.md 7.3a; that path is left as it is).  ``workspace`` is kept free of
-    pages, as in ``plan_latent_cache``."""
+    pages, as in ``plan_latent_cache``.  Where the model's cache is latent
+    (``model.latent_cache``) the pages hold one entry of ``entry_lanes`` a
+    token and no V, as ``plan_latent_cache``'s do."""
+    from smg_tpu.ops.latent_attention import entry_lanes
+
     s_shape, c_shape = state_shapes(model, state_slots + 1)
     state = StateSpec(num_slots=state_slots + 1, state_shape=tuple(s_shape),
                       conv_shape=tuple(c_shape), conv_dtype=model.dtype)
@@ -242,6 +246,8 @@ def plan_recurrent_cache(
         num_kv_heads=model.num_kv_heads,
         head_dim=model.head_dim,
         dtype=cache.dtype,
+        latent_lanes=(entry_lanes(model.kv_lora_rank, model.qk_rope_head_dim)
+                      if model.latent_cache else 0),
     )
     if cache.auto_size and hbm_limit is not None:
         budget = (int(hbm_limit * cache.hbm_utilization) - hbm_in_use - state.total_bytes

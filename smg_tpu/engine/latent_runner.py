@@ -65,7 +65,7 @@ class LatentModelRunner(ModelRunner):
         return {"entry_bytes_published": (cfg.kv_lora_rank + cfg.qk_rope_head_dim) * itemsize,
                 "entry_bytes_laid_out": self.spec.lanes * itemsize,
                 "layout": f"one buffer [layers, pages, {self.spec.page_size}, "
-                          f"{self.spec.lanes}]: latent {cfg.kv_lora_rank}, rotary key "
+                          f"{self.spec.lanes}]: latent {cfg.kv_lora_rank}, shared key "
                           f"{cfg.qk_rope_head_dim}, padded to whole 128-lane tiles; no V buffer"}
 
     def moe_info(self) -> dict:

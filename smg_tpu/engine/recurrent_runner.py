@@ -5,11 +5,18 @@ per-sequence memory: beside the pages of the full-attention layers every
 sequence holds one **state slot** (``kv_cache.StateSlotPool`` hands them out;
 the two device pools are ``s_pool`` and ``c_pool``, laid out as the module's
 ``state_shapes`` says: ``models/olmo_hybrid.py``'s gated delta rule,
-``models/nemotron_h.py``'s state-space layers).  What the recurrent layers'
-decode step is, and whether its kernel fits the model's shape, is the
-module's to say (``decode_step``); a module with routed experts beside its
-state has their grouped products bound as the latent runner binds them and
-its frames carry the routed counts (``ROUTED_COUNTS``).  It builds the same four program
+``models/nemotron_h.py``'s state-space layers, ``models/kimi_linear.py``'s
+Kimi Delta Attention).  What the recurrent layers' decode step is, and whether
+its kernel fits the model's shape, is the module's to say (``decode_step``); a
+module with routed experts beside its state has their grouped products bound
+as the latent runner binds them and its frames carry the routed counts
+(``ROUTED_COUNTS``).  **What the pages hold is data too** (``spec``): K and V
+of the full-attention layers, or, for a model whose cache is latent
+(``kimi_linear``), one entry a token and no V: ``v_cache`` is then of zero
+size and goes through the prefill families untouched, the decode frame
+carries one side buffer where it carries two, landed through
+``land_side_buffer``, and ``loads()`` has ``latent_cache`` beside the state
+slots.  It builds the same four program
 families under the same names and positional signatures (``prefill``,
 ``prefill_extend``, ``prefill_batched``, ``decode_multi_async``); the slot of
 each row arrives as a keyword whose default is the garbage slot 0, so a caller
@@ -34,8 +41,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from functools import partial
+
 from smg_tpu.engine import prefill_pack
 from smg_tpu.engine.kv_cache import plan_recurrent_cache
+from smg_tpu.engine.latent_runner import LatentModelRunner
 from smg_tpu.engine.runner import (
     ModelRunner,
     _attn_label,
@@ -45,6 +55,7 @@ from smg_tpu.engine.runner import (
     one_token_column,
 )
 from smg_tpu.ops.attention import land_side_buffers
+from smg_tpu.ops.latent_attention import land_side_buffer
 
 
 class RecurrentModelRunner(ModelRunner):
@@ -64,6 +75,10 @@ class RecurrentModelRunner(ModelRunner):
             self.moe_info = self._moe_info
             logger.info("expert layers %s, experts held %s of %d", self.moe_impl,
                         self.model_cfg.held_experts, self.model_cfg.num_experts)
+        if self.spec.latent_lanes:
+            # the scheduler's sign that ``loads()`` has a latent cache to show:
+            # the latent runner's account of the same layout
+            self.latent_info = partial(LatentModelRunner.latent_info, self)
 
     def _moe_info(self) -> dict:
         cfg = self.model_cfg
@@ -110,9 +125,10 @@ class RecurrentModelRunner(ModelRunner):
                            and self.config.attention_impl != "xla" else "xla")
         logger.info(
             "state slots: %d x %.1f MiB (%d %s layers), decode step %s; "
-            "pages for %d full-attention layers",
+            "%s for %d full-attention layers",
             st.num_slots - 1, st.slot_bytes / 2**20, st.state_shape[0], step["layers"],
-            self.state_impl, self.spec.num_layers)
+            self.state_impl, "latent pages" if self.spec.latent_lanes else "pages",
+            self.spec.num_layers)
 
     # the names ``benchmark/architectures/olmo_hybrid.py`` reads the two by
     linattn_impl = property(lambda self: self.state_impl)
@@ -120,7 +136,8 @@ class RecurrentModelRunner(ModelRunner):
 
     def state_info(self) -> dict:
         """Slots, a slot's bytes and which decode step runs, under the
-        module's name for it (``linattn_decode``, ``ssm_decode``)."""
+        module's name for it (``linattn_decode``, ``ssm_decode``,
+        ``kda_decode``)."""
         st = self.state_spec
         return {"slots_total": st.num_slots - 1, "slot_bytes": st.slot_bytes,
                 self.state_step["name"]: self.state_impl}
@@ -143,6 +160,22 @@ class RecurrentModelRunner(ModelRunner):
         if self.attn_impl == "pallas":
             return super()._prefill_impl_for(T, mp)
         return "xla"
+
+    def _grouped_prefill_impl_for(self, G: int, T: int, no_ctx: bool) -> str:
+        """The rule of the kind of cache the model has: the Llama path's for
+        K and V pages, the latent runner's for latent pages (the kernel takes
+        the key the heads share as an operand of its own)."""
+        if self.spec.latent_lanes:
+            return LatentModelRunner._grouped_prefill_impl_for(self, G, T, no_ctx)
+        return super()._grouped_prefill_impl_for(G, T, no_ctx)
+
+    def _prefill_rung(self, chunks) -> int:
+        """A module may take the ladder's octaves alone (``OCTAVE_RUNGS_ONLY``:
+        a rung between two of them is then no program of its own, and a single
+        cold row pads to the next octave as every other launch does)."""
+        if getattr(self.module, "OCTAVE_RUNGS_ONLY", False):
+            return self.config.scheduler.coarse_prefill_bucket(max(len(c[0]) for c in chunks))
+        return super()._prefill_rung(chunks)
 
     def _chunk_bucket(self, n_tokens: int) -> int:
         """Every second octave of the ladder, from the top: a prompt cut by a
@@ -248,15 +281,15 @@ class RecurrentModelRunner(ModelRunner):
                          use_pen: bool = False, use_mask: bool = False,
                          use_lora: bool = False, use_mrope: bool = False):
         """This model's decode frame, for ``ModelRunner._decode_frame_fn``'s
-        loop: the state pools are carried through the columns beside the K
-        and V side buffers, and the frame is ``chained``: launched ahead of
-        one that met a finish it runs no column at all."""
+        loop: the state pools are carried through the columns beside the side
+        buffers the cache's spec asks for (K's and V's, or the one of a latent
+        cache), and the frame is ``chained``: launched ahead of one that met a
+        finish it runs no column at all."""
         self._plain("decode", lora=use_lora, mrope=use_mrope)
         step_impl = {self.state_step["arg"]: self.state_impl}
         cfg, module = self.model_cfg, self.module
         routed = hasattr(module, "ROUTED_COUNTS")
-        KD = cfg.num_kv_heads * cfg.head_dim
-        L = cfg.num_cache_layers
+        L, lanes, latent = cfg.num_cache_layers, self.spec.lanes, bool(self.spec.latent_lanes)
 
         def frame(params, inv_freq, entry_pos, kc, vc, page_tables, sp, cp, slots, _chain, *,
                   attn_impl, arms):
@@ -271,13 +304,15 @@ class RecurrentModelRunner(ModelRunner):
                 return logits, tuple(side), counts
 
             def land(side, ran, _last):
-                hk, hv, sp, cp = side
-                return (*land_side_buffers(kc, vc, hk, hv, page_tables, entry_pos, ran),
-                        sp, cp), None
+                *bufs, sp, cp = side
+                if latent:  # one buffer of entries; ``vc`` is of zero size
+                    caches = (land_side_buffer(kc, *bufs, page_tables, entry_pos, ran), vc)
+                else:
+                    caches = land_side_buffers(kc, vc, *bufs, page_tables, entry_pos, ran)
+                return (*caches, sp, cp), None
 
-            hk0 = jnp.zeros((L, B, N, KD), kc.dtype)
-            hv0 = jnp.zeros((L, B, N, KD), kc.dtype)
-            return (hk0, hv0, sp, cp), one_token_column(column), land
+            bufs = [jnp.zeros((L, B, N, lanes), kc.dtype) for _ in range(1 if latent else 2)]
+            return (*bufs, sp, cp), one_token_column(column), land
 
         variant = (self.state_impl, *((self.moe_impl,) if routed else ()))
         return self._decode_frame_fn(B, mp, N, E, use_pen, use_mask, frame,

@@ -145,6 +145,16 @@ class ModelConfig:
     # and what each is 1 or the published value without are
     # ``models/nemotron_h.DRAW``'s).  Empty draws every part alike.
     random_init: "tuple[tuple[str, float], ...]" = ()
+    # ---- Kimi Delta Attention beside latent attention (``kimi_linear``).
+    # ``layer_types`` names every layer ``kda`` (the delta rule whose decay is
+    # a number a key channel: ``linear_num_heads`` heads with keys of
+    # ``linear_key_head_dim`` and values of ``linear_value_head_dim``, a causal
+    # convolution of ``linear_conv_kernel_dim`` taps, the decay's and the
+    # output gate's low rank ``linear_key_head_dim``) or ``full_attention``
+    # (latent attention, the query projected in one step: ``q_lora_rank`` 0;
+    # ``rope_theta`` 0: the key the heads share is not rotated).  Such a model
+    # is ``recurrent`` and has a ``latent_cache``: state slots beside latent
+    # pages (``engine/recurrent_runner.py``).
 
     @property
     def window_cache(self) -> bool:
@@ -190,7 +200,7 @@ class ModelConfig:
     def recurrent(self) -> bool:
         """Some layers keep per-sequence state outside the pages."""
         return self.layer_types is not None and bool(
-            {"linear_attention", "mamba"} & set(self.layer_types))
+            {"linear_attention", "mamba", "kda"} & set(self.layer_types))
 
     @property
     def mrope_section(self) -> "tuple[int, ...] | None":
@@ -215,6 +225,8 @@ class ModelConfig:
             return cls._from_longcat_flash(cfg, dtype)
         if cfg.get("model_type") == "nemotron_h":
             return cls._from_nemotron_h(cfg, dtype)
+        if cfg.get("model_type") == "kimi_linear":
+            return cls._from_kimi_linear(cfg, dtype)
         # keys that change what the layers compute and that this path would
         # drop in silence: routed experts beyond Qwen-MoE's settings, latent
         # attention.  A config that carries one is another model (D6's rule).
@@ -904,6 +916,125 @@ class ModelConfig:
             moe_shared_intermediate_size=cfg["moe_shared_expert_intermediate_size"],
         )
 
+    # ``kimi_linear`` (Kimi-Linear): the same rule as above.
+    _KIMI_LINEAR_CONSUMED = frozenset({
+        "model_type", "vocab_size", "hidden_size", "intermediate_size", "num_hidden_layers",
+        "num_attention_heads", "num_key_value_heads", "hidden_act", "rms_norm_eps",
+        "tie_word_embeddings", "model_max_length", "max_position_embeddings",
+        "linear_attn_config", "kv_lora_rank", "q_lora_rank", "qk_nope_head_dim",
+        "qk_rope_head_dim", "v_head_dim", "mla_use_nope", "first_k_dense_replace",
+        "moe_layer_freq", "moe_intermediate_size", "num_experts", "num_experts_per_token",
+        "num_shared_experts", "moe_renormalize", "moe_router_activation_func",
+        "use_grouped_topk", "num_expert_group", "topk_group", "routed_scaling_factor",
+        "num_nextn_predict_layers", "eos_token_id", "bos_token_id",
+        # read by no layer of the published forward: nothing is rotated at any
+        # position (``mla_use_nope``: order reaches the latent layers through
+        # the KDA layers), and ``head_dim`` (hidden / heads) shapes nothing
+        # beside ``qk_nope_head_dim``, ``qk_rope_head_dim`` and ``v_head_dim``
+        "rope_theta", "rope_scaling", "head_dim",
+        # the chip's share of a deployment, as for ``pangu_ultra_moe``
+        "router_num_experts", "routed_expert_offset",
+        # random weights only: see ``ModelConfig.random_init``
+        "random_weights",
+    })
+
+    @classmethod
+    def _from_kimi_linear(cls, cfg: dict, dtype: str) -> "ModelConfig":
+        unknown = sorted(set(cfg) - cls._KIMI_LINEAR_CONSUMED - cls._OLMO_HYBRID_SHAPELESS)
+        if unknown:
+            raise ValueError(
+                f"kimi_linear config.json has keys this loader does not consume: {unknown}; "
+                "a key that may bear on the model's shape is not dropped in silence")
+        if cfg.get("hidden_act", "silu") != "silu":
+            raise ValueError(f"kimi_linear: hidden_act {cfg['hidden_act']!r} is not served")
+        if not cfg.get("mla_use_nope", False):
+            raise ValueError("kimi_linear: mla_use_nope false (a rotated shared key) is not "
+                             "served: this loader's latent layers rotate nothing")
+        if cfg.get("q_lora_rank") is not None:
+            raise ValueError(f"kimi_linear: q_lora_rank {cfg['q_lora_rank']} (a low-rank step "
+                             "in the query) is not served: the query is projected in one step")
+        if cfg.get("rope_scaling") is not None:
+            raise ValueError(f"kimi_linear: rope_scaling {cfg['rope_scaling']} is not served")
+        if (cfg.get("num_expert_group", 1), cfg.get("topk_group", 1)) != (1, 1):
+            raise ValueError("kimi_linear: a group limit on the router's picks "
+                             "(num_expert_group, topk_group above 1) is not served")
+        if cfg.get("moe_router_activation_func", "sigmoid") not in ("sigmoid", "softmax"):
+            raise ValueError(f"kimi_linear: moe_router_activation_func "
+                             f"{cfg['moe_router_activation_func']!r} is not served")
+        if cfg.get("moe_layer_freq", 1) != 1:
+            raise ValueError(f"kimi_linear: moe_layer_freq {cfg['moe_layer_freq']} is not served "
+                             "(every layer behind the dense ones is an expert layer)")
+        if cfg.get("num_shared_experts", 1) != 1:
+            raise ValueError(f"kimi_linear: num_shared_experts {cfg['num_shared_experts']} "
+                             "is not served")
+        heads = cfg["num_attention_heads"]
+        if cfg.get("num_key_value_heads", heads) != heads:
+            raise ValueError("kimi_linear: latent attention has one latent for all heads; "
+                             "num_key_value_heads must equal num_attention_heads")
+        layers = cfg["num_hidden_layers"]
+        lin = cfg["linear_attn_config"]
+        kda, full = set(lin["kda_layers"]), set(lin["full_attn_layers"])
+        # both lists count layers from 1
+        both, neither = sorted(kda & full), sorted(set(range(1, layers + 1)) - kda - full)
+        if both or neither or (kda | full) - set(range(1, layers + 1)):
+            raise ValueError(
+                f"kimi_linear: linear_attn_config must name each of the layers 1..{layers} in "
+                f"kda_layers or in full_attn_layers, once: in both {both}, in neither {neither}, "
+                f"past the last {sorted((kda | full) - set(range(1, layers + 1)))}")
+        held = cfg["num_experts"]
+        width = cfg.get("router_num_experts", held)
+        first = cfg.get("routed_expert_offset", 0)
+        if not (0 <= first and first + held <= width):
+            raise ValueError(f"kimi_linear: experts {first}..{first + held - 1} are not among "
+                             f"the router's {width}")
+        dense = cfg.get("first_k_dense_replace", 0)
+        layer_types = tuple("kda" if l in kda else "full_attention"
+                            for l in range(1, layers + 1))
+        eos = cfg.get("eos_token_id", 163586)
+        out = cls(
+            arch="kimi_linear",
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=layers,
+            num_heads=heads,
+            num_kv_heads=heads,
+            head_dim=cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"],
+            rope_theta=0.0,
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
+            max_position_embeddings=cfg.get("model_max_length")
+            or cfg.get("max_position_embeddings", 8192),
+            tie_word_embeddings=bool(cfg.get("tie_word_embeddings", False)),
+            eos_token_ids=tuple(eos) if isinstance(eos, list) else (eos,),
+            bos_token_id=cfg.get("bos_token_id", 163584),
+            dtype=dtype,
+            layer_types=layer_types,
+            linear_num_heads=lin["num_heads"],
+            linear_key_head_dim=lin["head_dim"],
+            linear_value_head_dim=lin["head_dim"],
+            linear_conv_kernel_dim=lin["short_conv_kernel_size"],
+            kv_lora_rank=cfg["kv_lora_rank"],
+            qk_nope_head_dim=cfg["qk_nope_head_dim"],
+            qk_rope_head_dim=cfg["qk_rope_head_dim"],
+            v_head_dim=cfg["v_head_dim"],
+            num_experts=width,
+            num_experts_per_tok=cfg["num_experts_per_token"],
+            moe_intermediate_size=cfg["moe_intermediate_size"],
+            first_k_dense_replace=dense,
+            n_shared_experts=1,
+            moe_scoring=cfg.get("moe_router_activation_func", "sigmoid"),
+            norm_topk_prob=bool(cfg.get("moe_renormalize", True)),
+            routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
+            experts_held=(first, held),
+            moe_select_bias=True,
+            random_init=tuple(sorted((k, float(v)) for k, v in
+                                     (cfg.get("random_weights") or {}).items())),
+        )
+        from smg_tpu.models.kimi_linear import layout
+
+        layout(out)  # the stack this program runs: a sentence for any other
+        return out
+
     @classmethod
     def from_pretrained(cls, path: str, dtype: str = "bfloat16") -> "ModelConfig":
         with open(os.path.join(path, "config.json")) as f:
@@ -1123,6 +1254,73 @@ def tiny_nemotron_h_config(vocab_size: int = 512, held: "tuple[int, int] | None"
     )
 
 
+def tiny_kimi_linear_config(vocab_size: int = 512, held: "tuple[int, int] | None" = None,
+                            layers: int = 8, **changes) -> ModelConfig:
+    """Tiny Kimi-Linear for CPU tests: periods of three KDA layers (4 heads
+    with keys of 16 and values of 32, 4 taps) and one unrotated latent layer (4
+    heads of 32 + 16 and 32 over a latent of 64: one 128-lane entry a token),
+    layer 1's feed-forward part a dense MLP and every other a router of 16,
+    top 4, of which ``held`` are here (None: all), experts 48 wide."""
+    import dataclasses
+
+    changes = {"layer_types": tuple("full_attention" if l % 4 == 3 else "kda"
+                                    for l in range(layers)),
+               "first_k_dense_replace": 1, **changes}
+    return dataclasses.replace(
+        tiny_test_config(vocab_size),
+        arch="kimi_linear",
+        num_layers=layers,
+        num_heads=4,
+        num_kv_heads=4,
+        head_dim=48,
+        rope_theta=0.0,
+        rms_norm_eps=1e-5,
+        linear_num_heads=4,
+        linear_key_head_dim=16,
+        linear_value_head_dim=32,
+        linear_conv_kernel_dim=4,
+        kv_lora_rank=64,
+        qk_nope_head_dim=32,
+        qk_rope_head_dim=16,
+        v_head_dim=32,
+        intermediate_size=96,
+        num_experts=16,
+        num_experts_per_tok=4,
+        moe_intermediate_size=48,
+        n_shared_experts=1,
+        moe_scoring="sigmoid",
+        norm_topk_prob=True,
+        routed_scaling_factor=2.446,
+        experts_held=held,
+        moe_select_bias=True,
+        **changes,
+    )
+
+
+def kimi_linear_48b_a3b_config() -> ModelConfig:
+    """Kimi-Linear-48B-A3B-Instruct as published (27 layers, 256 experts,
+    163,840 rows): 98 GB in bfloat16, so no single chip serves it whole;
+    ``benchmark/configs/kimi-linear-48b-a3b.json`` is one chip's share."""
+    kda = [l for l in range(1, 28) if l % 4 and l != 27]
+    return ModelConfig.from_hf_config({
+        "model_type": "kimi_linear", "vocab_size": 163840, "hidden_size": 2304,
+        "intermediate_size": 9216, "num_hidden_layers": 27, "num_attention_heads": 32,
+        "num_key_value_heads": 32, "head_dim": 72, "hidden_act": "silu", "rms_norm_eps": 1e-5,
+        "tie_word_embeddings": False, "model_max_length": 1048576, "rope_theta": 10000,
+        "rope_scaling": None, "kv_lora_rank": 512, "q_lora_rank": None,
+        "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "v_head_dim": 128,
+        "mla_use_nope": True, "first_k_dense_replace": 1, "moe_layer_freq": 1,
+        "moe_intermediate_size": 1024, "num_experts": 256, "num_experts_per_token": 8,
+        "num_shared_experts": 1, "moe_renormalize": True,
+        "moe_router_activation_func": "sigmoid", "use_grouped_topk": True,
+        "num_expert_group": 1, "topk_group": 1, "routed_scaling_factor": 2.446,
+        "num_nextn_predict_layers": 0,
+        "linear_attn_config": {
+            "kda_layers": kda, "full_attn_layers": [l for l in range(1, 28) if l not in kda],
+            "head_dim": 128, "num_heads": 32, "short_conv_kernel_size": 4},
+    })
+
+
 def tiny_mimo_config(vocab_size: int = 512, held: "tuple[int, int] | None" = None,
                      **changes) -> ModelConfig:
     """Tiny MiMo-V2-Flash for CPU tests: a dense full-attention layer, three
@@ -1244,6 +1442,8 @@ PRESETS = {
     "tiny-exaone-moe": tiny_exaone_moe_config,
     "tiny-longcat-flash": tiny_longcat_flash_config,
     "tiny-nemotron-h": tiny_nemotron_h_config,
+    "tiny-kimi-linear": tiny_kimi_linear_config,
+    "kimi-linear-48b-a3b": kimi_linear_48b_a3b_config,
     "llama3.2-1b": llama32_1b_config,
     "llama3-8b": llama3_8b_config,
     "llama3-70b": llama3_70b_config,

@@ -27,7 +27,13 @@ with state beside its pages):
   prefill forwards untouched, and its decode column takes the one side buffer
   (``cfg.num_cache_layers`` deep, which for ``longcat_flash`` is twice the
   model's layers) and the lanes that hold a sequence
-  (``engine/latent_runner.py``); a module with routed experts gives
+  (``engine/latent_runner.py``); a module with state **and** a latent cache
+  (``models/kimi_linear.py``: ``cfg.recurrent`` and ``cfg.latent_cache``) is
+  a module with state whose forwards take ``v_cache`` of zero size untouched
+  and whose decode column takes the one latent side buffer where the others
+  take K and V's two, then the pools, the slots and the lanes that run
+  (``engine/recurrent_runner.py`` builds the frame from what the cache's spec
+  says); a module with routed experts gives
   ``merge_counts`` and ``ROUTED_COUNTS``, the names of what its frame counts
   (``pangu_moe``'s four; ``longcat_flash`` adds the picks on identity experts);
 - a module with window layers beside full ones (``models/mimo.py``) takes the
@@ -49,7 +55,7 @@ _REGISTRY: dict[str, ModuleType] = {}
 # architectures this package brings itself, loaded on first use
 _LLAMA_FAMILY = ("llama", "qwen", "mistral", "qwen_moe")
 _BUILTIN = (*_LLAMA_FAMILY, "olmo_hybrid", "pangu_ultra_moe", "mimo_v2_flash", "exaone_moe",
-            "longcat_flash", "nemotron_h")
+            "longcat_flash", "nemotron_h", "kimi_linear")
 
 
 def register_model(arch: str, module: ModuleType) -> None:
@@ -91,6 +97,10 @@ def get_model(arch: str) -> ModuleType:
             from smg_tpu.models import nemotron_h
 
             _REGISTRY.setdefault("nemotron_h", nemotron_h)
+        elif arch == "kimi_linear":
+            from smg_tpu.models import kimi_linear
+
+            _REGISTRY.setdefault("kimi_linear", kimi_linear)
         else:
             raise KeyError(
                 f"unsupported model architecture: {arch!r} "

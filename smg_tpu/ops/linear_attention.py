@@ -1,21 +1,42 @@
-"""Gated delta rule (Gated DeltaNet) linear attention and its causal
-depthwise convolution.
+"""The delta rule with a decay (Gated DeltaNet, Kimi Delta Attention) and its
+causal depthwise convolution.
 
 One head keeps a state ``S`` of ``[dv, dk]`` floats for every sequence,
-rewritten at every token ``t``::
+rewritten at every token ``t``.  **Two rules** live here, which differ in what
+the decay is:
 
-    S_t = a_t S_{t-1} + b_t (v_t - a_t S_{t-1} k_t) k_t^T        o_t = S_t q_t
+- *a number a head* (the gated delta rule; ``models/olmo_hybrid.py``)::
 
-with ``a_t`` in (0, 1] the decay and ``b_t`` in [0, 2] the write strength.
-Three forms of it live here:
+      S_t = a_t S_{t-1} + b_t (v_t - a_t S_{t-1} k_t) k_t^T      o_t = S_t q_t
 
-- ``gated_delta_chunked``: prefill.  Chunks of ``CHUNK`` tokens; inside a
-  chunk the updates are solved together (the WY form: one unit-triangular
-  system a chunk) and the state moves once a chunk, so a 4,096-token prompt is
-  64 dependent steps and not 4,096.
-- ``gated_delta_step``: decode in plain XLA (the CPU, interpret-free tests).
+  with ``a_t`` in (0, 1] and ``b_t`` in [0, 2] the write strength:
+  ``gated_delta_chunked`` (``g`` [G, T, H]), ``gated_delta_step`` (``alpha``
+  [B, H]), and the kernel ``linattn_decode``;
+- *a number a key channel* (Kimi Delta Attention; ``models/kimi_linear.py``):
+  ``a_t`` is a vector of ``dk`` and stands where the number stood, as
+  ``Diag(a_t)`` on the state's key axis::
+
+      S_t = (S_{t-1} Diag(a_t)) + b_t (v_t - S_{t-1} Diag(a_t) k_t) k_t^T
+
+  ``kda_chunked`` (``g`` [G, T, H, dk]), ``kda_step`` (``alpha`` [B, H, dk])
+  and the kernel ``kda_decode``.  With every channel of a head at one decay it
+  is the first rule.
+
+Three forms of each:
+
+- chunked, for prefill.  Chunks of ``CHUNK`` tokens; inside a chunk the
+  updates are solved together (the WY form: one unit-triangular system a
+  chunk) and the state moves once a chunk, so a 4,096-token prompt is 64
+  dependent steps and not 4,096.  The two rules share the solve and the scan
+  over chunks (``_solve_and_scan``) and differ in how a chunk's decay weights
+  are made: a head's ``[C, C]`` table of ``exp(G_i - G_j)`` factors out of
+  the keys' products, a channel's does not, and the factored form ``(k_i
+  exp(G_i)) . (k_j exp(-G_j))`` leaves float32 once a channel has fallen by
+  e^-88 inside a chunk.  ``kda_chunked`` therefore takes every exponent of a
+  difference ``<= 0``: the ``[C, C, dk]`` weights of a few chunks at a time.
+- one token in plain XLA (the CPU, interpret-free tests);
 - ``ops/pallas/linattn_decode.py``: decode as one pass over the state on the
-  chip; ``gated_delta_step`` is its specification.
+  chip; the XLA steps are its specification.
 
 **State layout.**  A pool holds the state transposed and with the heads fused
 on the minor axis: ``[layers, slots, dk, H * dv]`` float32.  A ``[dv, dk]``
@@ -85,6 +106,8 @@ def conv_token(x: jnp.ndarray, tail: jnp.ndarray, weight: jnp.ndarray, bias=None
 # under this module's span; ``ops/ssm.py`` runs the same two under its own
 causal_conv = jax.named_scope("smg.linattn.conv")(conv_chunk)
 conv_step = jax.named_scope("smg.linattn.conv")(conv_token)
+kda_causal_conv = jax.named_scope("smg.kda.conv")(conv_chunk)
+kda_conv_step = jax.named_scope("smg.kda.conv")(conv_token)
 
 
 # --------------------------------------------------------------------------
@@ -105,30 +128,65 @@ def _unit_lower_inverse(A: jnp.ndarray) -> jnp.ndarray:
     return lax.fori_loop(0, C, row, jnp.zeros_like(A)) + eye
 
 
+def _chunked(x, C: int):
+    """``[G, T, H, ...]`` (``T`` a multiple of ``C``) as float32 chunks
+    ``[G, H, N, C, ...]``."""
+    G, T = x.shape[:2]
+    x = x.astype(jnp.float32).reshape(G, T // C, C, *x.shape[2:])
+    return jnp.moveaxis(x, 3, 1)
+
+
+def _padded(xs, T: int, chunk: int):
+    """``xs`` padded along the tokens to whole chunks of ``C = min(chunk, T)``
+    (a padded row has ``beta`` 0 and ``g`` 0: it writes and decays nothing),
+    and ``C``."""
+    C = min(chunk, T)
+    pad = (-T) % C
+    if pad:
+        xs = [jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2)) for x in xs]
+    return xs, C
+
+
+def _solve_and_scan(A, qk, k_in, v, beta, q_dec, k_tail, last, S0, T: int):
+    """What the two rules share once a chunk's decay weights are made.  Per
+    chunk ``[G, H, N, C, ..]``: ``A`` [C, C] strictly lower (``b_i`` times the
+    keys' decayed products), ``qk`` [C, C] lower (the queries' with the keys),
+    ``k_in`` the keys times ``b`` and their decay from the chunk's start,
+    ``q_dec`` the queries times theirs, ``k_tail`` the keys times their decay
+    to the chunk's end, ``last`` the whole chunk's decay (broadcast against the
+    state ``[dk, dv]``).  Returns ``(o [G, T, H, dv], S)``."""
+    G, H, N, C, dv = v.shape
+    Tm = _unit_lower_inverse(A)
+    k_cum = _mm("...ij,...jd->...id", Tm, k_in)
+    v_new = _mm("...ij,...jd->...id", Tm, v * beta[..., None])
+
+    def step(S, xs):
+        k_cum_n, v_new_n, qk_n, q_dec_n, k_tail_n, last_n = xs
+        v_n = v_new_n - _mm("ghcd,ghde->ghce", k_cum_n, S)
+        o_n = _mm("ghcd,ghde->ghce", q_dec_n, S) + _mm("ghij,ghje->ghie", qk_n, v_n)
+        S = S * last_n + _mm("ghcd,ghce->ghde", k_tail_n, v_n)
+        return S, o_n
+
+    per_chunk = lambda x: jnp.moveaxis(x, 2, 0)
+    S, o = lax.scan(step, S0.astype(jnp.float32),
+                    tuple(per_chunk(x) for x in (k_cum, v_new, qk, q_dec, k_tail, last)))
+    o = jnp.moveaxis(o, 0, 2)  # [G, H, N, C, dv]
+    o = jnp.moveaxis(o, 1, 3).reshape(G, N * C, H, dv)
+    return o[:, :T], S
+
+
 @jax.named_scope("smg.linattn.prefill")
 def gated_delta_chunked(q, k, v, g, beta, S0, chunk: int = CHUNK):
-    """The recurrence over a whole chunk of tokens.
+    """The recurrence over a whole chunk of tokens, the decay a number a head.
 
     ``q``, ``k`` [G, T, H, dk] (normalised, ``q`` scaled), ``v`` [G, T, H, dv],
     ``g`` [G, T, H] the log of the decay (<= 0), ``beta`` [G, T, H], ``S0``
     [G, H, dk, dv] the state before the first token.  A padded row has
     ``beta`` 0 and ``g`` 0: it writes nothing and decays nothing.  Returns
     ``(o [G, T, H, dv], S [G, H, dk, dv])``, float32."""
-    f32 = jnp.float32
-    G, T, H, dk = q.shape
-    dv = v.shape[-1]
-    C = min(chunk, T)
-    pad = (-T) % C
-    if pad:
-        widen = lambda x: jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
-        q, k, v, g, beta = (widen(x) for x in (q, k, v, g, beta))
-    N = (T + pad) // C
-
-    def chunks(x):  # [G, T, H, ...] -> [G, H, N, C, ...]
-        x = x.astype(f32).reshape(G, N, C, *x.shape[2:])
-        return jnp.moveaxis(x, 3, 1)
-
-    q, k, v, g, beta = (chunks(x) for x in (q, k, v, g, beta))
+    T = q.shape[1]
+    (q, k, v, g, beta), C = _padded((q, k, v, g, beta), T, chunk)
+    q, k, v, g, beta = (_chunked(x, C) for x in (q, k, v, g, beta))
     gc = jnp.cumsum(g, axis=-1)  # [G, H, N, C]
     i = jnp.arange(C)
     lower = i[:, None] >= i[None, :]
@@ -136,27 +194,50 @@ def gated_delta_chunked(q, k, v, g, beta, S0, chunk: int = CHUNK):
     decay = jnp.where(lower, jnp.exp(jnp.where(lower, diff, 0.0)), 0.0)  # [.., C, C]
     kb = k * beta[..., None]
     A = jnp.where(i[:, None] > i[None, :], _mm("...id,...jd->...ij", kb, k) * decay, 0.0)
-    Tm = _unit_lower_inverse(A)
-    k_cum = _mm("...ij,...jd->...id", Tm, kb * jnp.exp(gc)[..., None])
-    v_new = _mm("...ij,...jd->...id", Tm, v * beta[..., None])
     qk = _mm("...id,...jd->...ij", q, k) * decay
-    q_dec = q * jnp.exp(gc)[..., None]
-    k_tail = k * jnp.exp(gc[..., -1:] - gc)[..., None]
-    last = jnp.exp(gc[..., -1])  # [G, H, N]
+    return _solve_and_scan(
+        A, qk, kb * jnp.exp(gc)[..., None], v, beta, q * jnp.exp(gc)[..., None],
+        k * jnp.exp(gc[..., -1:] - gc)[..., None], jnp.exp(gc[..., -1])[..., None, None], S0, T)
 
-    def step(S, xs):
-        k_cum_n, v_new_n, qk_n, q_dec_n, k_tail_n, last_n = xs
-        v_n = v_new_n - _mm("ghcd,ghde->ghce", k_cum_n, S)
-        o_n = _mm("ghcd,ghde->ghce", q_dec_n, S) + _mm("ghij,ghje->ghie", qk_n, v_n)
-        S = S * last_n[..., None, None] + _mm("ghcd,ghce->ghde", k_tail_n, v_n)
-        return S, o_n
 
-    per_chunk = lambda x: jnp.moveaxis(x, 2, 0)
-    S, o = lax.scan(step, S0.astype(f32),
-                    tuple(per_chunk(x) for x in (k_cum, v_new, qk, q_dec, k_tail, last)))
-    o = jnp.moveaxis(o, 0, 2)  # [G, H, N, C, dv]
-    o = jnp.moveaxis(o, 1, 3).reshape(G, N * C, H, dv)
-    return o[:, :T], S
+# Chunks whose ``[C, C, dk]`` decay weights ``kda_chunked`` makes at a time,
+# every head of them: 32 heads of 128 key channels are 64 MiB a chunk of 64.
+_KDA_CHUNKS_AT_ONCE = 4
+
+
+@jax.named_scope("smg.kda.prefill")
+def kda_chunked(q, k, v, g, beta, S0, chunk: int = CHUNK):
+    """``gated_delta_chunked`` with the decay a number a key channel: ``g``
+    [G, T, H, dk].  With the log-decay cumulated inside a chunk, ``G_i`` [dk],
+    the keys' products are ``sum_d k_i[d] k_j[d] exp(G_i[d] - G_j[d])`` for
+    ``i >= j``: every exponent is of a difference ``<= 0``, so a channel that
+    falls by more than float32 holds inside a chunk gives 0 and nothing
+    overflows.  The ``[C, C, dk]`` weights are made for ``_KDA_CHUNKS_AT_ONCE``
+    chunks at a time and summed over ``d`` at once; what goes on to the solve
+    and the scan is ``[C, C]`` a head and chunk, as for the other rule."""
+    T = q.shape[1]
+    (q, k, v, g, beta), C = _padded((q, k, v, g, beta), T, chunk)
+    q, k, v, g, beta = (_chunked(x, C) for x in (q, k, v, g, beta))
+    gc = jnp.cumsum(g, axis=-2)  # [G, H, N, C, dk]
+    i = jnp.arange(C)
+    lower = (i[:, None] >= i[None, :])[..., None]
+    kb = k * beta[..., None]
+
+    def products(xs):  # one chunk of one row: [H, C, dk] each
+        q_n, k_n, kb_n, gc_n = xs
+        w = jnp.where(lower, jnp.exp(jnp.where(
+            lower, gc_n[:, :, None, :] - gc_n[:, None, :, :], 0.0)), 0.0) * k_n[:, None, :, :]
+        return (jnp.sum(kb_n[:, :, None, :] * w, axis=-1), jnp.sum(q_n[:, :, None, :] * w, axis=-1))
+
+    rows = lambda x: jnp.moveaxis(x, 1, 2).reshape(-1, *x.shape[1:2], *x.shape[3:])  # [G*N, H, C, dk]
+    A, qk = lax.map(products, tuple(rows(x) for x in (q, k, kb, gc)),
+                    batch_size=_KDA_CHUNKS_AT_ONCE)
+    G, H, N = q.shape[:3]
+    back = lambda x: jnp.moveaxis(x.reshape(G, N, H, C, C), 1, 2)
+    A = jnp.where(i[:, None] > i[None, :], back(A), 0.0)
+    return _solve_and_scan(
+        A, back(qk), kb * jnp.exp(gc), v, beta, q * jnp.exp(gc),
+        k * jnp.exp(gc[..., -1:, :] - gc), jnp.exp(gc[..., -1, :])[..., None], S0, T)
 
 
 # --------------------------------------------------------------------------
@@ -228,5 +309,19 @@ def gated_delta_step(pool, layer, slots, q, k, v, alpha, beta):
     Sk = _mm("bhkv,bhk->bhv", S, k)
     u = beta[..., None] * (v - alpha[..., None] * Sk)
     S = a * S + k[..., :, None] * u[..., None, :]
+    o = _mm("bhkv,bhk->bhv", S, q)
+    return o, write_state(pool, layer, slots, heads_to_pool(S))
+
+
+@jax.named_scope("smg.kda.decode")
+def kda_step(pool, layer, slots, q, k, v, alpha, beta):
+    """``gated_delta_step`` with the decay a number a key channel: ``alpha``
+    [B, H, dk], one number a row of a head's state where that has one a head
+    (a lane that does not run has ``alpha`` 1 everywhere and ``beta`` 0).  The
+    state is decayed first and the delta taken against the decayed state."""
+    H = q.shape[1]
+    S = alpha[..., None] * pool_to_heads(read_state(pool, layer, slots), H)  # [B, H, dk, dv]
+    u = beta[..., None] * (v - _mm("bhkv,bhk->bhv", S, k))
+    S = S + k[..., :, None] * u[..., None, :]
     o = _mm("bhkv,bhk->bhv", S, q)
     return o, write_state(pool, layer, slots, heads_to_pool(S))

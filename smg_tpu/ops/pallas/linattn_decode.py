@@ -1,5 +1,5 @@
-"""``smg.linattn.decode``: one decode token of the gated delta rule as one
-pass over the state pool, in place.
+"""``smg.linattn.decode`` and ``smg.kda.decode``: one decode token of the delta
+rule with a decay as one pass over the state pool, in place.
 
 For a lane and a block of heads the kernel reads the block of the state,
 decays it, applies the delta update, takes the output and writes the block
@@ -19,6 +19,19 @@ is elementwise work on the block and two reductions over ``dk``.
 The slot of every lane and the layer arrive as scalar prefetch and pick the
 block in the index map; the pool is aliased to the output, so blocks no lane
 names are left as they were.
+
+**Two bodies, one call.**  Where the decay is a number a head (``linattn_decode``;
+``models/olmo_hybrid.py``) it arrives spread over the head's lanes as ``beta``
+and ``v`` do, ``[1, W]``.  Where it is a number a key channel (``kda_decode``;
+``models/kimi_linear.py``, ``ops.linear_attention.kda_step`` the
+specification) it is one more ``[dk, Hp]`` operand that goes through the
+expansion ``k`` and ``q`` go through, and the block is decayed before the
+delta is taken.  The two are bodies of their own under one builder
+(``_decode``): one body with the decay always expanded would have the other
+rule pay a third expansion and a ``[dk, W]`` temporary for a number it has
+once a head, and would change the program ``olmo-hybrid-7b`` compiles
+(``scripts/time_kimi_linear.py`` times both at one shape; ``PERF.md``,
+Findings, PR 50).
 """
 
 from __future__ import annotations
@@ -31,7 +44,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 # the most bytes of state one grid step holds (in, out, double buffered, and
-# about four temporaries of the same size must fit the 16 MiB of scoped VMEM)
+# about four temporaries of the same size must fit the 16 MiB of scoped VMEM;
+# the decay a channel is a fifth, the expanded ``alpha``, which still fits:
+# it costs no block size)
 _BLOCK_BYTES = 1 << 20
 
 
@@ -47,6 +62,10 @@ def heads_per_block(H: int, dk: int, dv: int) -> int | None:
 
 
 def supported(H: int, dk: int, dv: int) -> bool:
+    """Whether a block of heads fits this shape, under either rule: the decay
+    a channel adds one ``[dk, Hp]`` operand (``dk x 16`` floats a lane) and one
+    expanded temporary of the block's size in VMEM, inside what
+    ``_BLOCK_BYTES`` leaves."""
     return dk % 8 == 0 and heads_per_block(H, dk, dv) is not None
 
 
@@ -78,10 +97,38 @@ def _kernel(slots_ref, layer_ref, kT_ref, qT_ref, e_ref, v_ref, a_ref, b_ref,
     s_out_ref[0, 0] = S
 
 
+def _kernel_channel(slots_ref, layer_ref, kT_ref, qT_ref, aT_ref, e_ref, v_ref, b_ref,
+                    s_ref, o_ref, s_out_ref):
+    del slots_ref, layer_ref  # used by the index maps
+    e = e_ref[...]
+    kmat = _expand(kT_ref[0], e)  # [dk, W]
+    qmat = _expand(qT_ref[0], e)
+    S = _expand(aT_ref[0], e) * s_ref[0, 0]  # the decay of the row's channel, first
+    beta, v = b_ref[0], v_ref[0]  # [1, W]
+    u = beta * (v - jnp.sum(S * kmat, axis=0, keepdims=True))
+    S = S + kmat * u
+    o_ref[0] = jnp.sum(S * qmat, axis=0, keepdims=True)
+    s_out_ref[0, 0] = S
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 @jax.named_scope("smg.linattn.decode")
 def linattn_decode(pool, layer, slots, q, k, v, alpha, beta, interpret: bool = False):
     """Same contract as ``ops.linear_attention.gated_delta_step``."""
+    return _decode(pool, layer, slots, q, k, v, alpha, beta, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+@jax.named_scope("smg.kda.decode")
+def kda_decode(pool, layer, slots, q, k, v, alpha, beta, interpret: bool = False):
+    """Same contract as ``ops.linear_attention.kda_step``: ``alpha`` [B, H, dk]."""
+    return _decode(pool, layer, slots, q, k, v, alpha, beta, interpret)
+
+
+def _decode(pool, layer, slots, q, k, v, alpha, beta, interpret: bool):
+    """The call of either body: ``alpha`` [B, H] picks the decay a head,
+    [B, H, dk] the decay a channel."""
+    channel = alpha.ndim == 3
     B, H, dk = q.shape
     dv = v.shape[-1]
     HV = H * dv
@@ -96,25 +143,27 @@ def linattn_decode(pool, layer, slots, q, k, v, alpha, beta, interpret: bool = F
     expand = (jnp.arange(Hp)[:, None] == (jnp.arange(HV) // dv)[None, :]).astype(jnp.bfloat16)
     row = lambda b, c, *_: (b, 0, c)
     state = lambda b, c, slots_ref, layer_ref: (layer_ref[0], slots_ref[b], 0, c)
+    heads = pl.BlockSpec((1, dk, Hp), lambda b, c, *_: (b, 0, 0))
+    lane_row = pl.BlockSpec((1, 1, W), row)
+    spread = pl.BlockSpec((Hp, W), lambda b, c, *_: (0, c))
+    # the decay: one more operand to expand (before ``e``), or a row of lanes
+    # (behind ``v``); the pool stays the seventh operand either way
+    operands = ([heads, heads, heads, spread, lane_row, lane_row] if channel
+                else [heads, heads, spread, lane_row, lane_row, lane_row])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, HV // W),
-        in_specs=[
-            pl.BlockSpec((1, dk, Hp), lambda b, c, *_: (b, 0, 0)),
-            pl.BlockSpec((1, dk, Hp), lambda b, c, *_: (b, 0, 0)),
-            pl.BlockSpec((Hp, W), lambda b, c, *_: (0, c)),
-            pl.BlockSpec((1, 1, W), row),
-            pl.BlockSpec((1, 1, W), row),
-            pl.BlockSpec((1, 1, W), row),
-            pl.BlockSpec((1, 1, dk, W), state),
-        ],
+        in_specs=[*operands, pl.BlockSpec((1, 1, dk, W), state)],
         out_specs=[
             pl.BlockSpec((1, 1, W), row),
             pl.BlockSpec((1, 1, dk, W), state),
         ],
     )
+    vrow = v.astype(f32).reshape(B, 1, HV)
+    args = ((pad(k), pad(q), pad(alpha), expand, vrow, lanes(beta)) if channel
+            else (pad(k), pad(q), expand, vrow, lanes(alpha), lanes(beta)))
     o, pool = pl.pallas_call(
-        _kernel,
+        _kernel_channel if channel else _kernel,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, 1, HV), f32),
                    jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
@@ -123,8 +172,6 @@ def linattn_decode(pool, layer, slots, q, k, v, alpha, beta, interpret: bool = F
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(
-        slots.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
-        pad(k), pad(q), expand,
-        v.astype(f32).reshape(B, 1, HV), lanes(alpha), lanes(beta), pool,
+        slots.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1), *args, pool,
     )
     return o.reshape(B, H, dv), pool

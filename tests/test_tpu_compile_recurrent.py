@@ -1,6 +1,7 @@
-"""Whether the recurrent runner's programs of ``models/nemotron_h.py`` compile
-for a TPU v5e, at the widths of the benchmark's cut (``test_tpu_compile.py``
-says what such a compile shows and what it does not)."""
+"""Whether the recurrent runner's programs of ``models/nemotron_h.py`` and of
+``models/kimi_linear.py`` compile for a TPU v5e, at the widths of the
+benchmark's cuts (``test_tpu_compile.py`` says what such a compile shows and
+what it does not)."""
 
 import functools
 import re
@@ -88,3 +89,103 @@ class TestStateSpaceModelCompilesForV5e:
             s((1, P, PS, 256)), s((G, mp), i32), sp, cp, s((G,), i32)).compile()
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < M.prefill_workspace_bytes(cfg, G * T, "bfloat16") < 3 * 2**30
+
+
+class TestKimiLinearCompilesForV5e:
+    """``benchmark/configs/kimi-linear-48b-a3b.json``: 9 KDA layers (32 heads of
+    128 and 128), 3 unrotated latent layers of 32 heads, a dense MLP and 11
+    expert layers (32 of 256 held, 2,304 x 1,024)."""
+
+    @staticmethod
+    def shapes(v5e):
+        from smg_tpu.models import kimi_linear as M
+
+        cfg = benchmark_cut("kimi-linear-48b-a3b")
+        one = SingleDeviceSharding(v5e[0])
+        s = lambda shape, dtype=BF16: jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one)
+        params = jax.tree.map(
+            lambda x: s(x.shape, x.dtype),
+            jax.eval_shape(functools.partial(M.init_params, cfg), jax.random.PRNGKey(0)))
+        s_shape, c_shape = M.state_shapes(cfg, 73)
+        return M, cfg, s, params, s(s_shape, jnp.float32), s(c_shape)
+
+    @pytest.mark.parametrize("B", [8, 64])
+    def test_a_decode_frame_runs_its_kernels_in_place(self, v5e, B):
+        """A frame is a loop of columns over three periods, the first written
+        out and the other two one scan: the KDA step under its own name (not
+        the gated delta rule's) three times in each of the two bodies, which is
+        nine times a column; the latent kernel at 32 heads once a body; the
+        grouped products at 2,304 x 1,024 three times an expert layer.  The
+        state pool is updated where it lies (no temporary of its size, 1.4 GB)
+        and no weight is moved into another layout."""
+        from smg_tpu.ops.latent_attention import land_side_buffer
+
+        M, cfg, s, params, sp, cp = self.shapes(v5e)
+        assert M.layout(cfg) == {"periods": [(0, 3), (4, 3), (8, 3)], "scan": (1, 2)}
+        i32 = jnp.int32
+        mp, N, P, W = 512, 8, 60000, 640
+
+        def frame(p, tok, entry, kc, vc, tables, sp, cp, slots, n_steps):
+            runs = slots > 0
+
+            def body(c):
+                j, cur, side, sp, cp, counts = c
+                logits, side, sp, cp, k = M.forward_decode_horizon(
+                    p, cfg, None, cur, entry + j, entry, j, kc, vc, tables, side, sp, cp,
+                    slots, runs, attn_impl="pallas", kda_impl="pallas", moe_impl="pallas")
+                return (j + 1, jnp.argmax(logits, -1).astype(i32), side, sp, cp,
+                        M.merge_counts(counts, k))
+
+            j, cur, side, sp, cp, counts = jax.lax.while_loop(
+                lambda c: c[0] < n_steps, body,
+                (i32(0), tok, jnp.zeros((3, B, N, W), kc.dtype), sp, cp, jnp.zeros((4,), i32)))
+            kc = land_side_buffer(kc, side, tables, entry, jnp.arange(N)[None] < j)
+            return cur, kc, vc, sp, cp, counts
+
+        compiled = jax.jit(frame, donate_argnums=(3, 6, 7)).lower(
+            params, s((B,), i32), s((B,), i32), s((3, P, PS, W)), s((3, 0, PS, 0)),
+            s((B, mp), i32), sp, cp, s((B,), i32), s((), i32)).compile()
+        assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2**20
+        hlo = compiled.as_text()
+        assert _relayouts(hlo, 10 * 2**20) == []
+        # period 0 written out (3 expert layers of its 4) and the scan's body (4)
+        assert kernel_calls(hlo) == {"smg.attn.decode": 2, "smg.moe.experts": 21}
+        # the KDA step gives two results (``kernel_calls`` reads one)
+        assert len(re.findall(r"%smg\.kda\.decode\.\d+ = \(.*?\) custom-call\(", hlo)) == 6
+        assert "smg.linattn.decode" not in hlo
+
+    @pytest.mark.parametrize("G,T,cold", [(8, 512, True), (2, 2048, True), (1, 2048, False)])
+    def test_a_prefill_of_a_steps_budget_fits_its_workspace(self, v5e, G, T, cold):
+        """A step's budget as a group of eight rows and of two, cold (the
+        online-softmax kernel with the shared key as its own operand, at 32
+        heads), and one chunk that continues a prompt behind a live state, tail
+        and latent prefix (XLA's form over the pages): the ``[C, C, dk]`` decay
+        weights of a few chunks at a time, and the program's temporaries inside
+        what the cache plan keeps free of pages."""
+        M, cfg, s, params, sp, cp = self.shapes(v5e)
+        i32 = jnp.int32
+        mp, P, W = 512, 60000, 640
+        compiled = jax.jit(
+            lambda p, *a: M.forward_prefill_batched(
+                p, cfg, None, *a, no_ctx=cold, attn_impl="pallas" if cold else "xla",
+                moe_impl="pallas"),
+            donate_argnums=(4, 7, 8)).lower(
+            params, s((G, T), i32), s((G,), i32), s((G,), i32), s((3, P, PS, W)),
+            s((3, 0, PS, 0)), s((G, mp), i32), sp, cp, s((G,), i32)).compile()
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < M.prefill_workspace_bytes(cfg, 4096, "bfloat16") < 3 * 2**30
+        calls = kernel_calls(compiled.as_text())
+        assert ("smg.attn.prefill" in calls) == cold and calls["smg.moe.experts"] > 0
+
+    def test_the_one_row_of_1536_tokens_is_the_shape_the_compiler_refuses(self, v5e):
+        """Why ``kimi_linear.OCTAVE_RUNGS_ONLY``: the expert layer's gather of
+        2,048 rows beside an operand of ``[1536, 2304]`` does not fit VMEM, and
+        XLA:TPU stages it there all the same.  When this compiles, the rung can
+        come back."""
+        M, cfg, s, params, sp, cp = self.shapes(v5e)
+        i32 = jnp.int32
+        with pytest.raises(Exception, match="vmem"):
+            jax.jit(lambda p, *a: M.forward_prefill_batched(
+                p, cfg, None, *a, no_ctx=True, attn_impl="pallas", moe_impl="pallas")).lower(
+                params, s((1, 1536), i32), s((1,), i32), s((1,), i32), s((3, 60000, PS, 640)),
+                s((3, 0, PS, 0)), s((1, 512), i32), sp, cp, s((1,), i32)).compile()
