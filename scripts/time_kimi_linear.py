@@ -29,7 +29,9 @@ lanes, as the kernel ``smg.kda.decode`` (the body that expands the decay a
 channel), as ``smg.linattn.decode`` on the same pool (the body of the decay a
 head, given each head's mean decay: what the extra operand costs is the
 difference) and as the XLA form; a decode frame of 8 columns at 64 lanes; a
-grouped prefill of eight 512-token rows and of two 2,048-token rows.
+grouped prefill of eight 512-token rows and of two 2,048-token rows; the
+chunked prefill form of the recurrence alone (``kda_chunked``), one call a KDA
+layer chained, at eight rows of 512 tokens and at one of 1,024.
 
 ``--picks SEED``: for the check's first sequence of that seed, the experts the
 program's dense forward (bfloat16, ``forward_train``, run un-jitted) and the
@@ -241,8 +243,9 @@ def timings(args, runner) -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from smg_tpu.ops.linear_attention import kda_step
+    from smg_tpu.ops.linear_attention import kda_chunked, kda_step
     from smg_tpu.ops.pallas.linattn_decode import kda_decode, linattn_decode
+    from time_olmo_hybrid import chunked_form_ms
 
     cfg = runner.model_cfg
     B, N, ps = (8 if args.rehearsal else 64), 8, runner.config.cache.page_size
@@ -316,6 +319,9 @@ def timings(args, runner) -> None:
             return runner.k_cache
 
         res[f"grouped_prefill_ms_{G}x{T}"] = timed(prefill)
+    for G, T in ((2, 64),) if args.rehearsal else ((8, 512), (1, 1024)):
+        res[f"kda_chunked_ms_{layers}_layers_{G}x{T}"] = chunked_form_ms(
+            kda_chunked, layers, G, T, H, dk, dv, per_channel=True)
     if args.rehearsal:
         res = {k: v for k, v in res.items() if "_ms" not in k}
         res["rehearsal"] = True

@@ -8,13 +8,15 @@ Builds the runner ``serve`` would build for ``benchmark/configs/
 olmo-hybrid-7b.json`` (random weights, auto-sized caches) and times, after one
 warm-up each, on the host clock round ``block_until_ready``:
 
-- one 4,096-token chunk on the grouped path (``prefill_batched``, one member),
-  on the solo path (``prefill``) and on the continuing path
-  (``prefill_extend``), which is what a prompt cut by a step's budget takes
-  (PERF.md 7.3h);
+- one 4,096-token chunk on the grouped path (``prefill_batched``, one member;
+  and one row of 1,024 and of 2,048 tokens there), on the solo path
+  (``prefill``) and on the continuing path (``prefill_extend``), which is what
+  a prompt cut by a step's budget takes (PERF.md 7.3h);
 - a decode frame of 8 columns at 16 lanes behind 128- and 256-page tables;
 - the linear-attention decode step alone (the kernel ``smg.linattn.decode``
-  and its XLA form) over the 12 layers of the state pool at 16 lanes.
+  and its XLA form) over the 12 layers of the state pool at 16 lanes;
+- the chunked prefill form of the recurrence alone (``gated_delta_chunked``),
+  12 calls chained, at one row of 1,024 and of 2,048 tokens.
 
 Prints one JSON line.  Refuses to run without a TPU: a CPU time is not a
 device time.  ``--rehearsal`` runs the same code at the configuration's
@@ -45,6 +47,43 @@ sys.path.insert(0, ROOT)
 REPS = 3
 
 
+def chunked_form_ms(form, layers: int, G: int, T: int, H: int, dk: int, dv: int,
+                    per_channel: bool) -> list[float]:
+    """Milliseconds of ``layers`` calls of a chunked prefill form
+    (``gated_delta_chunked``, or ``kda_chunked`` with ``per_channel``) chained
+    in one program: a call's keys, queries and decays take a little of the
+    call before's output, so that nothing of a call can leave the loop."""
+    import jax
+    import jax.numpy as jnp
+
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    l2 = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q, k = (jax.random.normal(ks[i], (G, T, H, dk), jnp.float32) for i in (0, 1))
+    v = jax.random.normal(ks[2], (G, T, H, dv), jnp.float32)
+    g = -jax.random.uniform(ks[3], (G, T, H, dk) if per_channel else (G, T, H),
+                            jnp.float32, 0.02, 1.5)
+    beta = jax.random.uniform(ks[4], (G, T, H), jnp.float32)
+    lanes = min(dk, dv)
+
+    @jax.jit
+    def run(q, k, v, g, beta):
+        def layer(_, c):
+            S, o = c
+            mix = jnp.pad(0.01 * o[..., :lanes], [(0, 0)] * 3 + [(0, dk - lanes)])
+            gl = g * (1 + (mix if per_channel else mix[..., 0]))
+            o, S = form(l2(q + mix), l2(k + mix), v, gl, beta, S)
+            return S, o
+        return jax.lax.fori_loop(0, layers, layer, (jnp.zeros((G, H, dk, dv), jnp.float32),
+                                                    jnp.zeros_like(v)))
+
+    out = []
+    for _ in range(REPS + 1):
+        t = time.perf_counter()
+        jax.block_until_ready(run(q, k, v, g, beta))
+        out.append((time.perf_counter() - t) * 1e3)
+    return [round(x, 3) for x in out[1:]]  # the first run compiles
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--out")
@@ -62,7 +101,7 @@ def main() -> int:
     from smg_tpu.engine.config import CacheConfig, EngineConfig, SchedulerConfig
     from smg_tpu.engine.recurrent_runner import RecurrentModelRunner
     from smg_tpu.models.config import ModelConfig
-    from smg_tpu.ops.linear_attention import gated_delta_step
+    from smg_tpu.ops.linear_attention import gated_delta_chunked, gated_delta_step
     from smg_tpu.ops.pallas.linattn_decode import linattn_decode
 
     dev = jax.devices()[0]
@@ -104,6 +143,9 @@ def main() -> int:
     res = {"device": dev.device_kind, "pages": runner.spec.num_pages,
            "state": runner.state_info(), "chunk_tokens": chunk}
     res["grouped_ms"] = timed(lambda: runner.prefill_batched([(ids, 0, table)], *one))
+    for T in () if args.rehearsal else (1024, 2048):
+        res[f"grouped_ms_1x{T}"] = timed(
+            lambda T=T: runner.prefill_batched([(ids[:T], 0, table)], *one))
     res["solo_ms"] = timed(lambda: runner.prefill(ids, 0, table, 0.0, -1, 1.0, 0.0))
     res["continuing_ms"] = timed(lambda: runner.prefill_extend(ids, 0, table))
     B, N, ps = 16, 8, config.cache.page_size
@@ -150,6 +192,9 @@ def main() -> int:
         res[f"linattn_decode_{name}_ms_{layers}_layers"] = timed(once)
     state_bytes = B * layers * 2 * H * dk * dv * 4
     res["linattn_decode_least_ms"] = round(state_bytes / 819e9 * 1e3, 3)
+    for T in (64,) if args.rehearsal else (1024, 2048):
+        res[f"gated_delta_chunked_ms_{layers}_layers_1x{T}"] = chunked_form_ms(
+            gated_delta_chunked, layers, 1, T, H, dk, dv, per_channel=False)
     if args.rehearsal:
         res = {k: v for k, v in res.items() if not k.endswith("_ms") and "_ms_" not in k}
         res["rehearsal"] = True

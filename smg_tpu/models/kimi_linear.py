@@ -259,15 +259,18 @@ def prefill_workspace_bytes(cfg: ModelConfig, tokens: int, dtype: str) -> int:
     shapes and on the high side: a KDA layer's projections in the model's
     dtype, in float32 the convolution's input and output, ``q``, ``k``, ``v``
     and the cumulated decay as they come and as the chunks hold them, with the
-    scan's operands; the ``[C, C, dk]`` decay weights of the chunks made at a
-    time; and what ``pangu_moe``'s count has for a latent layer and an expert
-    layer, which this model's are."""
-    from smg_tpu.ops.linear_attention import _KDA_CHUNKS_AT_ONCE, CHUNK
+    scan's operands; the ``[SUB, SUB, dk]`` decay weights of the diagonal
+    blocks of the chunks made at a time (the compiled program reduces them
+    where it makes them: room, 192 MiB at the benchmark's cut, over the 1,958
+    MiB its largest launch holds; ``tests/test_tpu_compile_recurrent.py``);
+    and what ``pangu_moe``'s count has for a latent layer and an expert layer,
+    which this model's are."""
+    from smg_tpu.ops.linear_attention import _KDA_CHUNKS_AT_ONCE, CHUNK, SUB
 
     H, dk = cfg.linear_num_heads, cfg.linear_key_head_dim
     C = conv_channels(cfg)
     kda = tokens * (C * jnp.dtype(dtype).itemsize + 4 * (3 * C + 8 * H * dk))
-    weights = 3 * _KDA_CHUNKS_AT_ONCE * H * CHUNK * CHUNK * dk * 4
+    weights = 3 * _KDA_CHUNKS_AT_ONCE * H * CHUNK * SUB * dk * 4
     return kda + weights + pangu_moe.prefill_workspace_bytes(cfg, tokens, dtype)
 
 

@@ -33,7 +33,10 @@ Three forms of each:
   the keys' products, a channel's does not, and the factored form ``(k_i
   exp(G_i)) . (k_j exp(-G_j))`` leaves float32 once a channel has fallen by
   e^-88 inside a chunk.  ``kda_chunked`` therefore takes every exponent of a
-  difference ``<= 0``: the ``[C, C, dk]`` weights of a few chunks at a time.
+  difference ``<= 0``.  A chunk is worked in sub-blocks of ``SUB`` rows: what
+  is serial (the solve's substitution) or a channel at a time (the
+  ``[SUB, SUB, dk]`` decay weights) happens inside the diagonal blocks alone,
+  and everything between blocks is a matmul.
 - one token in plain XLA (the CPU, interpret-free tests);
 - ``ops/pallas/linattn_decode.py``: decode as one pass over the state on the
   chip; the XLA steps are its specification.
@@ -58,6 +61,8 @@ import jax.numpy as jnp
 from jax import lax
 
 CHUNK = 64
+# Rows of a sub-block of a chunk (``_sub_blocks``): four of them a chunk.
+SUB = 16
 _HI = lax.Precision.HIGHEST
 
 
@@ -114,26 +119,57 @@ kda_conv_step = jax.named_scope("smg.kda.conv")(conv_token)
 # prefill: the chunked form
 
 
-def _unit_lower_inverse(A: jnp.ndarray) -> jnp.ndarray:
-    """``(I + A)^-1`` for strictly lower triangular ``A`` [..., C, C], by
-    forward substitution one row at a time (row i needs rows < i)."""
-    C = A.shape[-1]
-    eye = jnp.eye(C, dtype=A.dtype)
+def _sub_blocks(C: int) -> int:
+    """Sub-blocks of ``SUB`` rows a chunk of ``C`` rows is worked in: a power
+    of two of them, or the chunk whole as one block."""
+    n = C // SUB
+    return n if n * SUB == C and n & (n - 1) == 0 else 1
+
+
+def _substitution(A: jnp.ndarray) -> jnp.ndarray:
+    """``(I + A)^-1`` for strictly lower triangular ``A`` [c, c, B] by forward
+    substitution one row at a time (row i needs rows < i).  The batch is the
+    minor axis and fills the lanes; a step's products are elementwise."""
+    c = A.shape[0]
 
     def row(i, T):
-        a = lax.dynamic_slice_in_dim(A, i, 1, axis=-2)  # [..., 1, C]
-        new = -a - _mm("...ij,...jk->...ik", a, T)
-        return lax.dynamic_update_slice_in_dim(T, new, i, axis=-2)
+        a = lax.dynamic_index_in_dim(A, i, 0, keepdims=False)  # [c, B]
+        new = -a - jnp.sum(a[:, None, :] * T, axis=0)
+        return lax.dynamic_update_index_in_dim(T, new, i, 0)
 
-    return lax.fori_loop(0, C, row, jnp.zeros_like(A)) + eye
+    return lax.fori_loop(0, c, row, jnp.zeros_like(A)) + jnp.eye(c, dtype=A.dtype)[..., None]
+
+
+def _unit_lower_inverse(A: jnp.ndarray) -> jnp.ndarray:
+    """``(I + A)^-1`` for strictly lower triangular ``A`` [..., C, C]: the
+    diagonal blocks of ``SUB`` rows by forward substitution, all of them and
+    every chunk and head at once (``SUB`` dependent steps), then merged two by
+    two, ``[[P, 0], [B, Q]]^-1 = [[P^-1, 0], [-Q^-1 B P^-1, Q^-1]]``, until
+    one block is left."""
+    *lead, C, _ = A.shape
+    n = _sub_blocks(C)
+    s = C // n
+    block = lambda size, r, c: A[..., r * size:(r + 1) * size, c * size:(c + 1) * size]
+    diag = jnp.stack([block(s, b, b) for b in range(n)], axis=-3)  # [..., n, s, s]
+    inv = jnp.moveaxis(_substitution(jnp.moveaxis(diag.reshape(-1, s, s), 0, -1)), -1, 0)
+    inv = inv.reshape(*lead, n, s, s)
+    while n > 1:
+        P, Q = inv[..., 0::2, :, :], inv[..., 1::2, :, :]
+        B = jnp.stack([block(s, b + 1, b) for b in range(0, n, 2)], axis=-3)
+        low = -_mm("...ij,...jk->...ik", _mm("...ij,...jk->...ik", Q, B), P)
+        inv = jnp.concatenate([jnp.concatenate([P, jnp.zeros_like(P)], axis=-1),
+                               jnp.concatenate([low, Q], axis=-1)], axis=-2)
+        n, s = n // 2, 2 * s
+    return inv[..., 0, :, :]
 
 
 def _chunked(x, C: int):
     """``[G, T, H, ...]`` (``T`` a multiple of ``C``) as float32 chunks
-    ``[G, H, N, C, ...]``."""
+    ``[N, G, H, C, ...]``: the chunks lead, so that the scan over them takes
+    whole slices and a few of them at a time are a reshape away."""
     G, T = x.shape[:2]
     x = x.astype(jnp.float32).reshape(G, T // C, C, *x.shape[2:])
-    return jnp.moveaxis(x, 3, 1)
+    return jnp.moveaxis(x, (1, 3), (0, 2))
 
 
 def _padded(xs, T: int, chunk: int):
@@ -149,13 +185,13 @@ def _padded(xs, T: int, chunk: int):
 
 def _solve_and_scan(A, qk, k_in, v, beta, q_dec, k_tail, last, S0, T: int):
     """What the two rules share once a chunk's decay weights are made.  Per
-    chunk ``[G, H, N, C, ..]``: ``A`` [C, C] strictly lower (``b_i`` times the
+    chunk ``[N, G, H, C, ..]``: ``A`` [C, C] strictly lower (``b_i`` times the
     keys' decayed products), ``qk`` [C, C] lower (the queries' with the keys),
     ``k_in`` the keys times ``b`` and their decay from the chunk's start,
     ``q_dec`` the queries times theirs, ``k_tail`` the keys times their decay
     to the chunk's end, ``last`` the whole chunk's decay (broadcast against the
     state ``[dk, dv]``).  Returns ``(o [G, T, H, dv], S)``."""
-    G, H, N, C, dv = v.shape
+    N, G, H, C, dv = v.shape
     Tm = _unit_lower_inverse(A)
     k_cum = _mm("...ij,...jd->...id", Tm, k_in)
     v_new = _mm("...ij,...jd->...id", Tm, v * beta[..., None])
@@ -167,11 +203,8 @@ def _solve_and_scan(A, qk, k_in, v, beta, q_dec, k_tail, last, S0, T: int):
         S = S * last_n + _mm("ghcd,ghce->ghde", k_tail_n, v_n)
         return S, o_n
 
-    per_chunk = lambda x: jnp.moveaxis(x, 2, 0)
-    S, o = lax.scan(step, S0.astype(jnp.float32),
-                    tuple(per_chunk(x) for x in (k_cum, v_new, qk, q_dec, k_tail, last)))
-    o = jnp.moveaxis(o, 0, 2)  # [G, H, N, C, dv]
-    o = jnp.moveaxis(o, 1, 3).reshape(G, N * C, H, dv)
+    S, o = lax.scan(step, S0.astype(jnp.float32), (k_cum, v_new, qk, q_dec, k_tail, last))
+    o = jnp.moveaxis(o, (0, 2), (1, 3)).reshape(G, N * C, H, dv)  # [N, G, H, C, dv] before
     return o[:, :T], S
 
 
@@ -187,7 +220,7 @@ def gated_delta_chunked(q, k, v, g, beta, S0, chunk: int = CHUNK):
     T = q.shape[1]
     (q, k, v, g, beta), C = _padded((q, k, v, g, beta), T, chunk)
     q, k, v, g, beta = (_chunked(x, C) for x in (q, k, v, g, beta))
-    gc = jnp.cumsum(g, axis=-1)  # [G, H, N, C]
+    gc = jnp.cumsum(g, axis=-1)  # [N, G, H, C]
     i = jnp.arange(C)
     lower = i[:, None] >= i[None, :]
     diff = gc[..., :, None] - gc[..., None, :]
@@ -200,9 +233,48 @@ def gated_delta_chunked(q, k, v, g, beta, S0, chunk: int = CHUNK):
         k * jnp.exp(gc[..., -1:] - gc)[..., None], jnp.exp(gc[..., -1])[..., None, None], S0, T)
 
 
-# Chunks whose ``[C, C, dk]`` decay weights ``kda_chunked`` makes at a time,
-# every head of them: 32 heads of 128 key channels are 64 MiB a chunk of 64.
+# Chunks whose ``[SUB, SUB, dk]`` decay weights ``kda_chunked`` makes at a
+# time, every head and diagonal block of them: 32 heads of 128 key channels
+# are 16 MiB a chunk of 64.
 _KDA_CHUNKS_AT_ONCE = 4
+
+
+def _kda_products(q, k, kb, gc):
+    """A chunk's ``(A, qk)`` [..., C, C] (lower triangles, the diagonal
+    included) of its rows [..., C, dk]: ``sum_d x_i[d] k_j[d] exp(G_i[d] -
+    G_j[d])`` for ``x`` = ``kb`` and ``q``.  Inside a diagonal block of ``SUB``
+    rows the weights are made for every pair and channel.  For a row block and
+    the keys before it they factor about the block's first row, ``exp(G_i -
+    G_ref) exp(G_ref - G_j)`` with ``j < ref <= i``, and the products are
+    matmuls of the rescaled rows and keys: both exponents are of differences
+    ``<= 0``, and what underflows in a factor is smaller still in the product
+    it stands for."""
+    C, dk = q.shape[-2:]
+    n = _sub_blocks(C)
+    s = C // n
+    blocked = lambda x: x.reshape(*x.shape[:-2], n, s, dk)
+    qs, ks, kbs, gs = blocked(q), blocked(k), blocked(kb), blocked(gc)
+    i = jnp.arange(s)
+    lower = (i[:, None] >= i[None, :])[..., None]
+    w = jnp.where(lower, jnp.exp(jnp.where(
+        lower, gs[..., :, None, :] - gs[..., None, :, :], 0.0)), 0.0) * ks[..., None, :, :]
+    A = jnp.sum(kbs[..., :, None, :] * w, axis=-1)  # [..., n, s, s]
+    qk = jnp.sum(qs[..., :, None, :] * w, axis=-1)
+    if n == 1:
+        return A[..., 0, :, :], qk[..., 0, :, :]
+    ref = gs[..., :1, :]  # [..., n, 1, dk]
+    rows = jnp.exp(gs - ref)
+    before = (jnp.arange(C) < (jnp.arange(n) * s)[:, None])[..., None]  # [n, C, 1]
+    keys = jnp.where(before, jnp.exp(jnp.where(
+        before, ref - gc[..., None, :, :], 0.0)), 0.0) * k[..., None, :, :]  # [..., n, C, dk]
+    left = _mm("...id,...jd->...ij", jnp.concatenate([kbs * rows, qs * rows], axis=-2), keys)
+    same = jnp.eye(n, dtype=bool)[:, None, :, None]
+
+    def whole(left, diag):  # [..., n, s, C] and the diagonal blocks [..., n, s, s]
+        diag = jnp.where(same, diag[..., :, :, None, :], 0.0).reshape(left.shape)
+        return (left + diag).reshape(*left.shape[:-3], C, C)
+
+    return whole(left[..., :s, :], A), whole(left[..., s:, :], qk)
 
 
 @jax.named_scope("smg.kda.prefill")
@@ -212,31 +284,21 @@ def kda_chunked(q, k, v, g, beta, S0, chunk: int = CHUNK):
     the keys' products are ``sum_d k_i[d] k_j[d] exp(G_i[d] - G_j[d])`` for
     ``i >= j``: every exponent is of a difference ``<= 0``, so a channel that
     falls by more than float32 holds inside a chunk gives 0 and nothing
-    overflows.  The ``[C, C, dk]`` weights are made for ``_KDA_CHUNKS_AT_ONCE``
-    chunks at a time and summed over ``d`` at once; what goes on to the solve
-    and the scan is ``[C, C]`` a head and chunk, as for the other rule."""
+    overflows (``_kda_products``).  The products are made for
+    ``_KDA_CHUNKS_AT_ONCE`` chunks at a time; what goes on to the solve and
+    the scan is ``[C, C]`` a head and chunk, as for the other rule."""
     T = q.shape[1]
     (q, k, v, g, beta), C = _padded((q, k, v, g, beta), T, chunk)
     q, k, v, g, beta = (_chunked(x, C) for x in (q, k, v, g, beta))
-    gc = jnp.cumsum(g, axis=-2)  # [G, H, N, C, dk]
+    gc = jnp.cumsum(g, axis=-2)  # [N, G, H, C, dk]
     i = jnp.arange(C)
-    lower = (i[:, None] >= i[None, :])[..., None]
     kb = k * beta[..., None]
-
-    def products(xs):  # one chunk of one row: [H, C, dk] each
-        q_n, k_n, kb_n, gc_n = xs
-        w = jnp.where(lower, jnp.exp(jnp.where(
-            lower, gc_n[:, :, None, :] - gc_n[:, None, :, :], 0.0)), 0.0) * k_n[:, None, :, :]
-        return (jnp.sum(kb_n[:, :, None, :] * w, axis=-1), jnp.sum(q_n[:, :, None, :] * w, axis=-1))
-
-    rows = lambda x: jnp.moveaxis(x, 1, 2).reshape(-1, *x.shape[1:2], *x.shape[3:])  # [G*N, H, C, dk]
-    A, qk = lax.map(products, tuple(rows(x) for x in (q, k, kb, gc)),
+    rows = lambda x: x.reshape(-1, *x.shape[2:])  # [N * G, H, C, dk]
+    A, qk = lax.map(lambda xs: _kda_products(*xs), tuple(rows(x) for x in (q, k, kb, gc)),
                     batch_size=_KDA_CHUNKS_AT_ONCE)
-    G, H, N = q.shape[:3]
-    back = lambda x: jnp.moveaxis(x.reshape(G, N, H, C, C), 1, 2)
-    A = jnp.where(i[:, None] > i[None, :], back(A), 0.0)
+    A = jnp.where(i[:, None] > i[None, :], A, 0.0).reshape(*q.shape[:-1], C)
     return _solve_and_scan(
-        A, back(qk), kb * jnp.exp(gc), v, beta, q * jnp.exp(gc),
+        A, qk.reshape(A.shape), kb * jnp.exp(gc), v, beta, q * jnp.exp(gc),
         k * jnp.exp(gc[..., -1:, :] - gc), jnp.exp(gc[..., -1, :])[..., None], S0, T)
 
 

@@ -211,13 +211,37 @@ def drawn(G, T, H=3, dk=8, dv=16, seed=0, strength=1.0):
 @pytest.mark.parametrize("T,chunk,strength", [
     (37, 8, 1.0), (64, 16, 1.0), (5, 8, 1.0), (130, 64, 0.1),
     # a channel falls by more than e^-88 inside a chunk: by up to e^-640 here
-    (64, 16, 40.0), (70, 64, 8.0)])
+    # (and inside one 16-row sub-block of a chunk of 64: by up to e^-128)
+    (64, 16, 40.0), (70, 64, 8.0),
+    # three and four whole chunks of four sub-blocks, the state carried between
+    # them; a chunk of 48 is three sub-blocks and runs as one block
+    (192, 64, 1.0), (256, 64, 8.0), (48, 64, 1.0)])
 def test_the_per_channel_chunked_form_is_the_recurrence_from_a_carried_state(T, chunk, strength):
     q, k, v, g, beta, S0 = drawn(2, T, strength=strength)
     o, S = LA.kda_chunked(q, k, v, g, beta, S0, chunk=chunk)
     o2, S2 = recurrence(q, k, v, g, beta, S0)
     assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(S).all())
     assert float(jnp.abs(o - o2).max()) < 2e-5 and float(jnp.abs(S - S2).max()) < 2e-5
+
+
+@pytest.mark.parametrize("T,chunk", [(130, 64), (48, 64)])
+def test_every_exponent_the_per_channel_form_takes_is_at_most_zero(monkeypatch, T, chunk):
+    """What keeps ``kda_chunked`` inside float32 at any decay: the sub-blocks'
+    factors about their first row (``exp(G_i - G_ref)``, ``exp(G_ref - G_j)``)
+    are of differences ``<= 0`` as the diagonal blocks' weights are, so nothing
+    overflows; a factor ``exp(-G_j)`` would pass e^88 at this strength."""
+    args = drawn(2, T, strength=8.0)
+    exponents, exp = [], jnp.exp
+
+    def checked(x):
+        jax.debug.callback(lambda m: exponents.append(float(np.max(m))), jnp.max(x))
+        return exp(x)
+
+    monkeypatch.setattr(jnp, "exp", checked)
+    o, _ = LA.kda_chunked(*args, chunk=chunk)
+    jax.block_until_ready(o)
+    jax.effects_barrier()
+    assert len(exponents) >= 5 and max(exponents) <= 0.0
 
 
 def test_with_one_decay_a_head_it_is_the_gated_delta_rule_as_it_stands():

@@ -206,7 +206,45 @@ def recurrence(q, k, v, g, beta, S0):
     return np.stack(out, 1), S
 
 
-@pytest.mark.parametrize("T,chunk", [(128, 64), (128, 32), (100, 64), (50, 7), (16, 64)])
+def row_at_a_time_inverse(A):
+    """``(I + A)^-1`` for strictly lower triangular ``A`` [..., C, C] by forward
+    substitution one row at a time (row i needs rows < i): what the chunked
+    form ran until PR 52, 64 dependent steps a layer, and the reference its
+    sub-block inverse is held to."""
+    C = A.shape[-1]
+
+    def row(i, T):
+        a = jax.lax.dynamic_slice_in_dim(A, i, 1, axis=-2)
+        new = -a - jnp.einsum("...ij,...jk->...ik", a, T, precision="highest")
+        return jax.lax.dynamic_update_slice_in_dim(T, new, i, axis=-2)
+
+    return jax.lax.fori_loop(0, C, row, jnp.zeros_like(A)) + jnp.eye(C, dtype=A.dtype)
+
+
+# 48 is three sub-blocks and 8 is half of one: both run as one block
+@pytest.mark.parametrize("C", [64, 48, 32, 16, 8])
+def test_the_sub_block_inverse_is_the_row_at_a_time_one(C):
+    """A chunk's system as the rule makes it: ``b_i (k_i . k_j)`` times the
+    decay from j to i below the diagonal, ``b`` up to 2."""
+    rng = np.random.default_rng(C)
+    unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)
+    k = unit(rng.normal(size=(2, 3, C, 8)))
+    gc = np.cumsum(-0.3 * np.abs(rng.normal(size=(2, 3, C))), axis=-1)
+    beta = 2 / (1 + np.exp(-rng.normal(size=(2, 3, C, 1))))
+    A = np.tril(beta * (k @ np.swapaxes(k, -1, -2)) * np.exp(gc[..., :, None] - gc[..., None, :]),
+                -1).astype(np.float32)
+    got = np.asarray(jax.jit(la._unit_lower_inverse)(A))
+    exact = np.linalg.inv(np.eye(C) + A.astype(np.float64))
+    scale = np.abs(exact).max()
+    assert np.abs(got - np.asarray(row_at_a_time_inverse(jnp.asarray(A)))).max() < 2e-6 * scale
+    assert np.abs(got - exact).max() < 2e-6 * scale
+    assert np.array_equal(np.triu(got, 1), np.zeros_like(got))
+
+
+# (192, 64): three whole chunks of four sub-blocks, the state carried between
+# them; (100, 64): sub-blocks with padding; (48, 64): a chunk of 48, one block
+@pytest.mark.parametrize("T,chunk", [(128, 64), (128, 32), (100, 64), (50, 7), (16, 64),
+                                     (192, 64), (48, 64)])
 def test_chunked_form_is_the_recurrence(T, chunk):
     rng = np.random.default_rng(T + chunk)
     G, H, dk, dv = 2, 3, 8, 16

@@ -1,7 +1,8 @@
 """Whether the recurrent runner's programs of ``models/nemotron_h.py`` and of
 ``models/kimi_linear.py`` compile for a TPU v5e, at the widths of the
 benchmark's cuts (``test_tpu_compile.py`` says what such a compile shows and
-what it does not)."""
+what it does not), and what the compiled chunked prefill form of the two delta
+rules holds (``ops/linear_attention.py``)."""
 
 import functools
 import re
@@ -12,6 +13,54 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from tests.v5e_compile import BF16, PS, _relayouts, benchmark_cut, kernel_calls, v5e  # noqa: F401
+
+
+def loop_bounds(hlo: str, scope: str) -> list[int]:
+    """Of every ``while`` of the compiled text under the named scope, the
+    bound its condition compares the counter with (this libtpu writes no
+    ``known_trip_count``: the bound is the condition's integer constant)."""
+    body = {m.group(1): m.group(2) for m in re.finditer(
+        r"^%?([\w.\-]+) \(.*?\) -> .*? \{\n(.*?)^\}", hlo, re.M | re.S)}
+    bounds = []
+    for line in hlo.splitlines():
+        m = re.search(r" while\(.*condition=%?([\w.\-]+),", line)
+        if m and scope in line:
+            bounds.append(max(int(c) for c in re.findall(
+                r"s32\[\]\S* constant\((\d+)\)", body[m.group(1)])))
+    return bounds
+
+
+def assert_sub_block_form(hlo: str, scope: str, chunks: int, dk: int = 0):
+    """What ISSUE 52 took out of the chunked form stays out: no loop under the
+    scope runs more than ``SUB`` dependent steps but the scan over the
+    sequence's chunks (the row-at-a-time inverse ran ``CHUNK``), and nothing
+    is elementwise over a whole chunk's ``[CHUNK, CHUNK, dk]`` decay weights
+    (a fused computation names the shape it reduces)."""
+    from smg_tpu.ops.linear_attention import CHUNK, SUB
+
+    bounds = loop_bounds(hlo, scope)
+    assert bounds and chunks in bounds, bounds
+    assert [b for b in bounds if b > SUB and b != chunks] == [], bounds
+    if dk:
+        assert re.search(rf"\[[\d,]*{SUB},{SUB},{dk}\]", hlo)
+        assert not re.search(rf"\[[\d,]*{CHUNK},{CHUNK},{dk}\]", hlo)
+
+
+@pytest.mark.parametrize("rule,G,T,H,dk,dv", [
+    ("kda", 8, 512, 32, 128, 128), ("kda", 1, 2048, 32, 128, 128),
+    ("linattn", 1, 1024, 30, 96, 192), ("linattn", 1, 4096, 30, 96, 192)])
+def test_the_chunked_form_is_worked_in_sub_blocks(v5e, rule, G, T, H, dk, dv):
+    """The two rules alone at the shapes ``kimi-linear-48b-a3b`` and
+    ``olmo-hybrid-7b`` launch (a chunk is 64 rows there, four sub-blocks)."""
+    from smg_tpu.ops import linear_attention as LA
+
+    one = SingleDeviceSharding(v5e[0])
+    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)
+    form, g = {"kda": (LA.kda_chunked, s(G, T, H, dk)),
+               "linattn": (LA.gated_delta_chunked, s(G, T, H))}[rule]
+    hlo = jax.jit(form).lower(s(G, T, H, dk), s(G, T, H, dk), s(G, T, H, dv), g, s(G, T, H),
+                              s(G, H, dk, dv)).compile().as_text()
+    assert_sub_block_form(hlo, f"smg.{rule}.prefill", T // LA.CHUNK, dk if rule == "kda" else 0)
 
 
 class TestStateSpaceModelCompilesForV5e:
@@ -174,8 +223,10 @@ class TestKimiLinearCompilesForV5e:
             s((3, 0, PS, 0)), s((G, mp), i32), sp, cp, s((G,), i32)).compile()
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < M.prefill_workspace_bytes(cfg, 4096, "bfloat16") < 3 * 2**30
-        calls = kernel_calls(compiled.as_text())
+        hlo = compiled.as_text()
+        calls = kernel_calls(hlo)
         assert ("smg.attn.prefill" in calls) == cold and calls["smg.moe.experts"] > 0
+        assert_sub_block_form(hlo, "smg.kda.prefill", T // 64, cfg.linear_key_head_dim)
 
     def test_the_one_row_of_1536_tokens_is_the_shape_the_compiler_refuses(self, v5e):
         """Why ``kimi_linear.OCTAVE_RUNGS_ONLY``: the expert layer's gather of
