@@ -53,6 +53,7 @@ jax is imported lazily so the lint-only CLI stays jax-free.
 from __future__ import annotations
 
 import os
+import re
 import threading
 import traceback
 from contextlib import contextmanager
@@ -62,11 +63,15 @@ _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 _compile_count = 0
 _listener_installed = False
+#: ``.scope_map`` is set on the thread that makes a program's scope map: should
+#: that lower and compile (the launch's own lowering is found as a rule), it is
+#: no program's first compile and no retrace
+_thread = threading.local()
 
 
 def _on_event(name: str, *_args, **_kw) -> None:
     global _compile_count
-    if _COMPILE_EVENT in name:
+    if _COMPILE_EVENT in name and not getattr(_thread, "scope_map", False):
         _compile_count += 1
 
 
@@ -82,6 +87,12 @@ def _ensure_listener() -> None:
 
     jax.monitoring.register_event_duration_secs_listener(_on_event)
     _listener_installed = True
+
+
+def _logger():
+    from smg_tpu.utils import get_logger
+
+    return get_logger("analysis.runtime_guards")
 
 
 def compile_count() -> int:
@@ -155,8 +166,9 @@ def _describe_args(args):
     spec-tree).  Each array leaf entry records path / shape / dtype /
     sharding (object + repr) / committed flag / device ids; non-array
     leaves are recorded as host-static.  The spec tree mirrors ``args``
-    with ``ShapeDtypeStruct`` (sharding attached) in place of arrays, so
-    the auditor can re-lower the program later without holding buffers."""
+    with ``ShapeDtypeStruct`` (a committed array's sharding attached) in
+    place of arrays, so the auditor can re-lower the program later without
+    holding buffers."""
     import jax
 
     flat, treedef = jax.tree_util.tree_flatten_with_path(args)
@@ -175,9 +187,13 @@ def _describe_args(args):
                 "devices": tuple(sorted(d.id for d in sh.device_set)),
                 "_sharding": sh,
             })
-            spec_leaves.append(
-                jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=sh)
-            )
+            # an uncommitted array's placement is no part of the program's
+            # signature: with it left out, ``fn.lower(*specs)`` finds the
+            # lowering the launch itself made and ``.compile()`` the
+            # executable that runs, and nothing is lowered or compiled again
+            spec_leaves.append(jax.ShapeDtypeStruct(
+                leaf.shape, leaf.dtype,
+                sharding=sh if entries[-1]["committed"] else None))
         else:
             entries.append({
                 "path": pstr, "shape": None, "dtype": type(leaf).__name__,
@@ -213,8 +229,6 @@ def _count_output_aliases(hlo_text: str) -> int:
     """Number of aliased entries in the compiled module's
     ``input_output_alias={...}`` attribute (brace-matched — the entries
     themselves contain nested ``{}``)."""
-    import re
-
     marker = "input_output_alias={"
     start = hlo_text.find(marker)
     if start < 0:
@@ -235,10 +249,181 @@ def _count_output_aliases(hlo_text: str) -> int:
     return len(re.findall(r"\{[0-9, ]*\}\s*:", "".join(buf)))
 
 
+#: a head is cut here: a ``while``'s result shape names every buffer it carries
+HEAD_CHARS = 160
+
+#: opcodes that compute nothing and so are no event of a trace
+_NO_EVENT = frozenset(("parameter", "constant", "get-tuple-element", "tuple", "bitcast"))
+
+#: opcodes that run other computations
+_RUNS_OTHERS = frozenset(("while", "conditional", "call"))
+
+_INSTRUCTION = re.compile(r"\s*(?:ROOT )?(%?[\w.\-]+) = ")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_LOCATION = re.compile(r'loc\("([^"]*)"')
+_SCOPE = re.compile(r"smg\.[\w.]+")
+_FUSED = re.compile(r"\bfusion\(.*\bcalls=(%?[\w.\-]+)")
+_APPLIED = re.compile(r"\bto_apply=(%?[\w.\-]+)")
+_OPERAND = re.compile(r"%[\w.\-]+")
+
+
+class _Frozen(dict):
+    """A dict that ``json`` writes as one and that nobody changes: a scope
+    map is handed out by reference."""
+
+    def _refuse(self, *_a, **_kw):
+        raise TypeError("a scope map is immutable")
+
+    __setitem__ = __delitem__ = _refuse
+    clear = pop = popitem = setdefault = update = __ior__ = _refuse
+
+
+def _closing(text: str, i: int) -> int:
+    """The index behind the parenthesis that closes the one at ``i``."""
+    depth = 0
+    for j in range(i, len(text)):
+        depth += (text[j] == "(") - (text[j] == ")")
+        if depth == 0:
+            return j + 1
+    return len(text)
+
+
+def instruction_head(text: str) -> "tuple[str, str, str] | None":
+    """``(head, name, rest)`` of one HLO instruction as ``as_text()`` and a
+    trace's event name print it: the head is the name and the result shape
+    with its layout (``%fusion.7 = bf16[64,128]{1,0:T(8,128)(2,1)}``), the text
+    before the opcode, cut at ``HEAD_CHARS``; ``rest`` starts at the opcode.
+    None for a line that is no instruction."""
+    m = _INSTRUCTION.match(text)
+    if m is None:
+        return None
+    i = m.end()
+    if text.startswith("(", i):  # a tuple's shape
+        j = _closing(text, i)
+    else:
+        j = text.find(" ", i)
+        if j < 0:
+            return None
+    head = f"{m.group(1).lstrip('%')} = {text[i:j]}"[:HEAD_CHARS]
+    return head, m.group(1).lstrip("%"), text[j:].lstrip()
+
+
+def scope_of(op_name: str) -> str:
+    """The innermost ``smg.*`` component of an instruction's ``op_name``
+    (``jit(multi)/while/body/smg.mlp/dot_general`` -> ``smg.mlp``), else ""."""
+    for part in reversed(op_name.split("/")):
+        m = _SCOPE.search(part)
+        if m is not None:
+            return m.group(0).rstrip(".")
+    return ""
+
+
+def _operands(rest: str) -> list:
+    """The names of an instruction's operands (``rest`` starts at its opcode)."""
+    i = rest.find("(")
+    if i < 0:
+        return []
+    return [m.lstrip("%") for m in _OPERAND.findall(rest[i:_closing(rest, i)])]
+
+
+def scope_names(names) -> frozenset:
+    """Every ``smg.*`` component of the operation names ``names``, the outer
+    ones with the innermost."""
+    return frozenset(m.rstrip(".") for name in names for m in _SCOPE.findall(name))
+
+
+def scopes_of_hlo(hlo_text: str) -> dict:
+    """``{scope: (heads, ...)}`` of a compiled module's text, ``""`` holding
+    the heads under no scope: every instruction of the entry computation, of
+    ``while`` bodies and conditions and of called computations.  The insides
+    of fused computations and of the scalar computations a reduction applies
+    are left out (a trace shows the fusion, not its parts), and so is what
+    computes nothing (``_NO_EVENT``).  A Pallas custom call that carries its
+    scope as its own name maps to it.  An instruction under no scope whose
+    readers all stand under one scope is listed under ``"~" + scope``: a
+    relayout before a product, a weight's copy into the fast memory before
+    the fusion that reads it (a reader that computes nothing and names no
+    scope is seen through).  Nothing else is guessed: what the program's
+    result alone reads, and what a loop under no scope is handed, stay under
+    no scope."""
+    computations: dict = {}  # name -> [instruction line, ...], in the text's order
+    inner: set = set()  # fused and applied computations
+    body = None
+    for line in hlo_text.splitlines():
+        if body is None:
+            if line.endswith("{") and ") -> " in line:
+                name = line.split("(", 1)[0].split()[-1]
+                body = computations.setdefault(name.lstrip("%"), [])
+            continue
+        if line.startswith("}"):
+            body = None
+            continue
+        body.append(line)
+        fused = _FUSED.search(line)
+        if fused is not None:
+            inner.add(fused.group(1).lstrip("%"))
+        elif " call(" not in line:
+            applied = _APPLIED.search(line)
+            if applied is not None:
+                inner.add(applied.group(1).lstrip("%"))
+    # instruction names are the module's own: one table for all computations
+    scope_of_name: dict = {}  # instruction -> the scope its own metadata names, or ""
+    head_of: dict = {}  # of the instructions a trace can show, in the text's order
+    consumers: dict = {}
+    runs_others: set = set()  # loops, branches, calls: their events enclose others
+    for name, lines in computations.items():
+        if name in inner:
+            continue
+        for line in lines:
+            parsed = instruction_head(line)
+            if parsed is None:
+                continue
+            head, iname, rest = parsed
+            for operand in _operands(rest):
+                consumers.setdefault(operand, []).append(iname)
+            op_name = _OP_NAME.search(rest)
+            scope = scope_of(op_name.group(1)) if op_name is not None else ""
+            if not scope and iname.startswith("smg."):
+                scope = re.sub(r"\.\d+$", "", iname)
+            scope_of_name[iname] = scope
+            opcode = rest.split("(", 1)[0]
+            if opcode not in _NO_EVENT:
+                head_of[iname] = head
+            if opcode in _RUNS_OTHERS:
+                runs_others.add(iname)
+    adopted: dict = {}
+
+    def readers(iname) -> set:
+        """The scopes of what reads ``iname``, "" among them for a reader
+        under no scope."""
+        found = set()
+        for c in consumers.get(iname, ()):
+            sc = scope_of_name.get(c, "")
+            if sc or c in head_of:
+                found.add(sc or adopted.get(c, ""))
+            else:
+                found |= readers(c)
+        return found
+
+    # a consumer stands behind its operands: the readers are settled first.  A
+    # loop takes no reader's scope (what reads its results says nothing of
+    # what it is handed), so nothing reaches a scope through one
+    for iname in reversed(head_of):
+        if not scope_of_name[iname] and iname not in runs_others:
+            found = readers(iname)
+            if len(found) == 1 and "" not in found:
+                adopted[iname] = found.pop()
+    out: dict = {"": []}
+    for iname, head in head_of.items():
+        scope = scope_of_name[iname] or ("~" + adopted[iname] if iname in adopted else "")
+        out.setdefault(scope, []).append(head)
+    return _Frozen({scope: tuple(heads) for scope, heads in out.items()})
+
+
 class _ProgramRecord:
     __slots__ = ("key", "fn", "donate", "in_shardings", "launches",
                  "recompiles", "last_sig", "last_entries", "last_specs",
-                 "provenance")
+                 "provenance", "first_specs", "scopes", "stale")
 
     def __init__(self, key, fn, donate, in_shardings):
         self.key = key
@@ -251,6 +436,9 @@ class _ProgramRecord:
         self.last_entries = None
         self.last_specs = None
         self.provenance: list[dict] = []
+        self.first_specs = None  # the first launch's argument specs
+        self.scopes = None  # scope_map()'s result, built once
+        self.stale = False  # the executable carries another commit's scopes
 
 
 class ProgramAuditor:
@@ -279,6 +467,12 @@ class ProgramAuditor:
         self._mu = threading.Lock()
         self._records: dict = {}
         self.armed = False
+        # what publish_scopes() has made: {repr(key): {"family", "scopes"}},
+        # replaced whole and never changed, so snapshot() hands it out as it is
+        self._published = _Frozen()
+        self.scope_lowerings = 0
+        self.scope_stale = 0  # of them: programs whose executable names other scopes
+        self.scope_seconds = 0.0
         _ensure_listener()  # jax is imported wherever a program is built
 
     # ---- registration / launch path ----
@@ -295,10 +489,16 @@ class ProgramAuditor:
                 # launches come from the step thread alone; a compile on
                 # another thread inside the call would be miscounted here,
                 # which the armed pass (with provenance) is there to settle
-                pre = _compile_count
-                out = fn(*args)
+                # (the scope maps' own lowerings are not counted at all)
                 if rec.launches:
+                    pre = _compile_count
+                    out = fn(*args)
                     rec.recompiles += _compile_count - pre
+                else:
+                    # the first launch, in warm-up: what scope_map() lowers
+                    # the program from, taken before donation ends the buffers
+                    rec.first_specs = _describe_args(args)[2]
+                    out = fn(*args)
                 rec.launches += 1
                 return out
             sig, entries, specs = _describe_args(args)
@@ -355,14 +555,108 @@ class ProgramAuditor:
                 }
                 for rec in self._records.values()
             ]
-        return {
+        out = {
             "armed": self.armed,
             # every XLA compile of the process since this auditor's listener
-            # went in, the programs' first compiles included
+            # went in, the programs' first compiles included; what a scope
+            # map lowered again is counted apart
             "compiles": _compile_count,
             "recompiles": sum(p["recompiles"] for p in programs),
             "programs": programs,
+            "scope_lowerings": self.scope_lowerings,
+            "scope_stale": self.scope_stale,
+            "scope_seconds": self.scope_seconds,
         }
+        if self._published:
+            out["scopes"] = self._published  # by reference: nothing is copied
+        return out
+
+    def launch_counts(self) -> dict:
+        """``{key: launches}`` of every registered program."""
+        with self._mu:
+            return {k: rec.launches for k, rec in self._records.items()}
+
+    def scope_map(self, key) -> "dict | None":
+        """``{scope: (heads, ...)}`` of program ``key`` (``scopes_of_hlo``):
+        which ``smg.*`` named scope each instruction of the compiled program
+        was traced under, by the head a trace prints for it.  Read off
+        ``lower(*specs).compile().as_text()`` as ``audit()`` reads a
+        donation, from the specs of the program's first launch (an armed
+        auditor's last): those find the launch's own lowering and the
+        executable that runs, so as a rule nothing is lowered or compiled
+        again, and where something is, the thread is marked and it counts as
+        no program's recompile and not among ``compiles``.
+
+        **A stale executable names nothing.**  The compile cache's key leaves
+        metadata out, so the executable that runs may be one an earlier commit
+        compiled, with that commit's scopes.  The lowering is this process's
+        own: where the scope names in its locations are not the names in the
+        executable's text, the program counts in ``scope_stale``, is logged,
+        and every head of it is listed under no scope, so its launches read as
+        unscoped time and never as another commit's split.
+
+        Once: the map is kept, and a second call hands out the same object.
+        Tenths of a second a program on a chip (the text is megabytes): never
+        call it on the step thread.  None for a program that was never
+        launched."""
+        with self._mu:
+            rec = self._records.get(key)
+        if rec is None:
+            return None
+        if rec.scopes is None:
+            specs = rec.last_specs if rec.last_specs is not None else rec.first_specs
+            if specs is None:
+                return None
+            _thread.scope_map = True
+            try:
+                lowered = rec.fn.lower(*specs)
+                text = lowered.compile().as_text()
+            finally:
+                _thread.scope_map = False
+            scopes = scopes_of_hlo(text)
+            traced = scope_names(_LOCATION.findall(lowered.as_text(debug_info=True)))
+            runs = scope_names(_OP_NAME.findall(text)) | scope_names(
+                s for s in scopes if not s.startswith("~"))  # a kernel named by its scope
+            if traced != runs:
+                _logger().warning(
+                    "program %r runs an executable of another commit (the compile cache's "
+                    "key has no metadata): it names %s and not %s; its launches read as "
+                    "unscoped", key, sorted(runs - traced), sorted(traced - runs))
+                scopes = _Frozen({"": tuple(h for heads in scopes.values() for h in heads)})
+                rec.stale = True
+                self.scope_stale += 1
+            rec.scopes = scopes
+            self.scope_lowerings += 1
+        return rec.scopes
+
+    def publish_scopes(self, keys) -> dict:
+        """Make the scope maps of the programs ``keys`` and put them in
+        ``snapshot()["scopes"]`` beside what is there: ``{repr(key):
+        {"family", "scopes"}}``, with ``"stale": True`` for a program whose
+        executable another commit compiled (``scope_map``).  A program whose
+        lowering fails is left out and logged: a reader then finds its
+        launches covered by no map, which shows.  Returns what it added."""
+        import time
+
+        t0 = time.monotonic()
+        added = {}
+        for key in keys:
+            try:
+                scopes = self.scope_map(key)
+            except Exception:
+                _logger().exception("no scope map of program %r", key)
+                continue
+            if scopes is not None:
+                with self._mu:
+                    rec = self._records[key]
+                entry = {"family": getattr(rec.fn, "__name__", ""), "scopes": scopes}
+                if rec.stale:
+                    entry["stale"] = True
+                added[repr(key)] = _Frozen(entry)
+        if added:
+            self._published = _Frozen({**self._published, **added})
+        self.scope_seconds += time.monotonic() - t0
+        return added
 
     def audit(self, *, check_donation: bool = True) -> dict:
         """Walk every captured program and verify it from the compiled

@@ -10,6 +10,7 @@ the reference, where the gateway's StreamingProcessor scans stop strings).
 from __future__ import annotations
 
 import contextlib
+import json
 import threading
 import time
 import uuid
@@ -158,6 +159,8 @@ class Engine:
         self._profiling = False  # a trace runs, or is being written
         self._profile_stopping = False  # stop_trace() is writing it
         self._profile_steps_left: int | None = None
+        self._profile_launches: dict = {}  # program launches when the trace began
+        self._profile_dir = ""  # where the running trace goes
         # submit-side lock wait (smg_engine_submit_lock_wait_seconds_total):
         # written under the engine lock, read by loads()
         self.num_submits = 0
@@ -589,6 +592,8 @@ class Engine:
             raise
         with self._profile_lock:
             self._profile_steps_left = num_steps if num_steps > 0 else None
+            self._profile_launches = self.runner._programs.launch_counts()
+            self._profile_dir = output_dir
         logger.info("profiler started -> %s", output_dir)
         return output_dir
 
@@ -600,15 +605,35 @@ class Engine:
                 raise RuntimeError("profiler not running")
             self._profile_stopping = True
             self._profile_steps_left = None
+            before, beside = self._profile_launches, self._profile_dir
+        programs = self.runner._programs
+        t0 = time.monotonic()
         try:
             jax.profiler.stop_trace()  # writes the trace: seconds, no engine lock
+            written = time.monotonic() - t0
+            # the trace names a fusion ``fusion.516``: which named scope each
+            # instruction of the programs it holds belongs to, for its readers
+            # (``loads()["programs"]["scopes"]``).  Here and nowhere else: the
+            # trace is closed, this is no step's thread, no lock is held
+            maps = programs.publish_scopes(
+                [k for k, n in programs.launch_counts().items() if n > before.get(k, 0)])
+            if maps and beside:
+                # whoever holds the trace holds its map: ``<dir>.scopes.json``
+                # stays when the trace's directory is cut down and deleted
+                try:
+                    with open(beside.rstrip("/") + ".scopes.json", "w") as f:
+                        json.dump(maps, f)
+                except OSError:
+                    logger.exception("no scope map beside %s", beside)
         finally:
             # trace serialization can fail (unwritable dir); never wedge
             # the profiler state on it
             with self._profile_lock:
                 self._profiling = False
                 self._profile_stopping = False
-        logger.info("profiler stopped")
+        logger.info("profiler stopped (trace written in %.1f s; scope maps so far: %d "
+                    "programs, %d stale, %.2f s)", written, programs.scope_lowerings,
+                    programs.scope_stale, programs.scope_seconds)
 
     def _profile_step_done(self) -> None:
         """Count one step against a ``num_steps`` trace.  At zero the stop

@@ -236,7 +236,8 @@ class RecurrentModelRunner(ModelRunner):
             logits = logits[None]
             i = 0
             if use_pen:
-                logits = apply_penalties(logits, *extra[:5])
+                with jax.named_scope("smg.sample"):
+                    logits = apply_penalties(logits, *extra[:5])
                 i = 5
             mask = extra[i] if use_mask else None
             toks, lps = _pick_sampler()(logits, key, temp, topk, topp, minp, mask=mask)
@@ -259,15 +260,17 @@ class RecurrentModelRunner(ModelRunner):
         from smg_tpu.engine.sampling import apply_penalties
 
         def step(params, inv_freq, packed, kc, vc, sp, cp, rng_key, *extra):
-            (tokens, page_tables, prefix_lens, t_reals, topks, temps, topps, minps,
-             counter, slots) = prefill_pack.unpack(packed, G, T, mp, slots=True)
-            key = jax.random.fold_in(rng_key, counter)
+            with jax.named_scope("smg.prefill.unpack"):
+                (tokens, page_tables, prefix_lens, t_reals, topks, temps, topps, minps,
+                 counter, slots) = prefill_pack.unpack(packed, G, T, mp, slots=True)
+                key = jax.random.fold_in(rng_key, counter)
             logits, kc, vc, sp, cp = module.forward_prefill_batched(
                 params, cfg, inv_freq, tokens, prefix_lens, t_reals, kc, vc, page_tables,
                 sp, cp, slots, no_ctx=no_ctx, attn_impl=impl)
             i = 0
             if use_pen:
-                logits = apply_penalties(logits, *extra[:5])
+                with jax.named_scope("smg.sample"):
+                    logits = apply_penalties(logits, *extra[:5])
                 i = 5
             mask = extra[i] if use_mask else None
             toks, lps = _pick_sampler()(logits, key, temps, topks, topps, minps, mask=mask)

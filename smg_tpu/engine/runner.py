@@ -825,7 +825,8 @@ class ModelRunner:
             )
             logits = logits[None]
             if use_pen:
-                logits = apply_penalties(logits, counts, pmask, freq, pres, rep)
+                with jax.named_scope("smg.sample"):
+                    logits = apply_penalties(logits, counts, pmask, freq, pres, rep)
             toks, lps = _pick_sampler()(logits, key, temp, topk, topp, minp, mask=mask)
             return toks[0], lps[0], kc, vc
 
@@ -934,9 +935,10 @@ class ModelRunner:
         impl = self._grouped_prefill_impl_for(G, T, no_ctx)
 
         def step(params, inv_freq, packed, kc, vc, rng_key, *extra):
-            (tokens, page_tables, prefix_lens, t_reals, topks, temps, topps, minps,
-             counter, _slots) = prefill_pack.unpack(packed, G, T, mp, slots=False)
-            key = jax.random.fold_in(rng_key, counter)
+            with jax.named_scope("smg.prefill.unpack"):
+                (tokens, page_tables, prefix_lens, t_reals, topks, temps, topps, minps,
+                 counter, _slots) = prefill_pack.unpack(packed, G, T, mp, slots=False)
+                key = jax.random.fold_in(rng_key, counter)
             i = 0
             if use_pen:
                 counts, pmask, freqs, pres, reps = extra[:5]
@@ -962,7 +964,8 @@ class ModelRunner:
                 rope_pos=rope_pos, pp_mesh=pp_mesh, attn_impl=impl,
             )
             if use_pen:
-                logits = apply_penalties(logits, counts, pmask, freqs, pres, reps)
+                with jax.named_scope("smg.sample"):
+                    logits = apply_penalties(logits, counts, pmask, freqs, pres, reps)
             toks, lps = _pick_sampler()(logits, key, temps, topks, topps, minps,
                                         mask=mask)
             return toks, lps, kc, vc
@@ -1331,7 +1334,8 @@ class ModelRunner:
             lora_bank = lora_gates = None
             if use_lora:
                 lora_bank, lora_idx = extra[i], extra[i + 1]
-                lora_gates = jax.nn.one_hot(lora_idx, n_slots, dtype=jnp.float32)
+                with jax.named_scope("smg.frame.begin"):
+                    lora_gates = jax.nn.one_hot(lora_idx, n_slots, dtype=jnp.float32)
                 i += 2
             rope_delta = None
             if use_mrope:
@@ -1343,20 +1347,32 @@ class ModelRunner:
                 ends = lambda t: jnp.any(t[:, None] == stop_ids, axis=1)
             if chained:
                 chain = held[-1]
-                n_steps = jnp.where(chain, n_steps, 0)
-            side0, column, land = frame(
-                params, inv_freq, entry_pos, kc, vc, page_tables, *held, attn_impl=attn_impl,
-                arms=SimpleNamespace(lora=lora_bank, lora_gates=lora_gates,
-                                     rope_delta=rope_delta, temps=temps, ends=ends,
-                                     limits=limits))
-            counts0 = counts_buf[slot_idx] if use_pen else jnp.zeros((B, 0))
-            pmask = pmask_buf[slot_idx] if use_pen else None
+                with jax.named_scope("smg.frame.begin"):
+                    n_steps = jnp.where(chain, n_steps, 0)
+            # the frame's own parts under ``smg.frame.*``: a trace's readers
+            # find an operation's scope in the program's scope map
+            # (``ProgramAuditor.scope_map``), and what the frame does besides
+            # the model's columns is then ``runner.decode_frame_time_share``
+            with jax.named_scope("smg.frame.begin"):
+                side0, column, land = frame(
+                    params, inv_freq, entry_pos, kc, vc, page_tables, *held,
+                    attn_impl=attn_impl,
+                    arms=SimpleNamespace(lora=lora_bank, lora_gates=lora_gates,
+                                         rope_delta=rope_delta, temps=temps, ends=ends,
+                                         limits=limits))
+                counts0 = counts_buf[slot_idx] if use_pen else jnp.zeros((B, 0))
+                pmask = pmask_buf[slot_idx] if use_pen else None
+                # padded lanes start done so the any-real-lane-done exit ignores
+                # them; without stop detection nothing is ever done
+                done0 = (~live) if use_stop else jnp.zeros((B,), jnp.bool_)
+                routed0 = (jnp.zeros((len(routed_names),), jnp.int32)
+                           if routed_names else None)
+                init = (jnp.int32(0), tokens, jnp.zeros((B, N, *wide), jnp.int32),
+                        jnp.zeros((B, N, *wide), jnp.float32), side0, counts0, done0,
+                        routed0)
             sampler = _pick_sampler()
-            # padded lanes start done so the any-real-lane-done exit ignores
-            # them; without stop detection nothing is ever done
-            done0 = (~live) if use_stop else jnp.zeros((B,), jnp.bool_)
-            routed0 = jnp.zeros((len(routed_names),), jnp.int32) if routed_names else None
 
+            @jax.named_scope("smg.frame.emit")
             def cond(carry):
                 j, done = carry[0], carry[6]
                 ok = j < n_steps
@@ -1372,49 +1388,53 @@ class ModelRunner:
                 def sample(logits):
                     nonlocal counts
                     if use_pen:
-                        logits = apply_penalties(logits, counts, pmask, freqs, pres, reps)
+                        with jax.named_scope("smg.frame.penalties"):
+                            logits = apply_penalties(logits, counts, pmask, freqs, pres,
+                                                     reps)
                     # the IN-LOOP fold: column j's key is the key the K=1 path
                     # folds at global step step0+1+j (then split(.., 1)[0], the
                     # same per-launch split the single-step scan applied)
-                    kj = jax.random.split(jax.random.fold_in(
-                        base_key, step0 + j.astype(jnp.uint32) + jnp.uint32(1)), 1)[0]
+                    with jax.named_scope("smg.sample"):
+                        kj = jax.random.split(jax.random.fold_in(
+                            base_key, step0 + j.astype(jnp.uint32) + jnp.uint32(1)), 1)[0]
                     new, lps = sampler(logits, kj, temps, topks, topps, minps, mask=mask)
                     if use_pen:
-                        counts = counts.at[jnp.arange(B), new].add(1)
+                        with jax.named_scope("smg.frame.penalties"):
+                            counts = counts.at[jnp.arange(B), new].add(1)
                     return new, lps
 
                 toks, lps, last, reach, side, c = column(cur, j, side, sample)
-                if routed_names:
-                    routed = module.merge_counts(routed, c)
-                at = (0, j) + (0,) * len(wide)
-                toks_out = lax.dynamic_update_slice(
-                    toks_out, toks[:, None].astype(jnp.int32), at)
-                lps_out = lax.dynamic_update_slice(
-                    lps_out, lps[:, None].astype(jnp.float32), at)
-                if use_stop:
-                    # length finish: a lane that stands at ``reach`` holds
-                    # reach + 1 tokens in all (decode steady state: total =
-                    # seq + 1), so it is done once reach >= limit - 1; a
-                    # one-token column's reach is entry_pos + j + 1
-                    over = ((entry_pos + j) >= (limits - 2) if reach is None
-                            else reach >= (limits - 1))
-                    done = done | ends(last) | over
-                return (j + 1, last, toks_out, lps_out, side, counts, done, routed)
+                with jax.named_scope("smg.frame.emit"):
+                    if routed_names:
+                        routed = module.merge_counts(routed, c)
+                    at = (0, j) + (0,) * len(wide)
+                    toks_out = lax.dynamic_update_slice(
+                        toks_out, toks[:, None].astype(jnp.int32), at)
+                    lps_out = lax.dynamic_update_slice(
+                        lps_out, lps[:, None].astype(jnp.float32), at)
+                    if use_stop:
+                        # length finish: a lane that stands at ``reach`` holds
+                        # reach + 1 tokens in all (decode steady state: total =
+                        # seq + 1), so it is done once reach >= limit - 1; a
+                        # one-token column's reach is entry_pos + j + 1
+                        over = ((entry_pos + j) >= (limits - 2) if reach is None
+                                else reach >= (limits - 1))
+                        done = done | ends(last) | over
+                    return (j + 1, last, toks_out, lps_out, side, counts, done, routed)
 
-            init = (jnp.int32(0), tokens, jnp.zeros((B, N, *wide), jnp.int32),
-                    jnp.zeros((B, N, *wide), jnp.float32), side0, counts0, done0, routed0)
             steps_run, last, outs, lps, side, counts, done, routed = \
                 lax.while_loop(cond, body, init)
-            caches, tail = land(side, jnp.arange(N)[None, :] < steps_run, last)
-            extras = {}
-            if use_pen:
-                extras["counts_buf"] = counts_buf.at[slot_idx].set(counts)
-            if chained:
-                extras["clean"] = chain & ~jnp.any(done & live) if use_stop else chain
-            if routed_names:
-                extras["routed"] = routed
-            if tail is not None:
-                extras["tail"] = tail
+            with jax.named_scope("smg.frame.land"):
+                caches, tail = land(side, jnp.arange(N)[None, :] < steps_run, last)
+                extras = {}
+                if use_pen:
+                    extras["counts_buf"] = counts_buf.at[slot_idx].set(counts)
+                if chained:
+                    extras["clean"] = chain & ~jnp.any(done & live) if use_stop else chain
+                if routed_names:
+                    extras["routed"] = routed
+                if tail is not None:
+                    extras["tail"] = tail
             return (outs, lps, steps_run, *caches, extras)
 
         n_extra = ((6 if use_pen else 0) + (1 if use_mask else 0)

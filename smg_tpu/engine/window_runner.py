@@ -272,7 +272,8 @@ class SelfDraftingRunner(WindowModelRunner):
             logits = logits[None]
             i = 0
             if use_pen:
-                logits = apply_penalties(logits, *extra[:5])
+                with jax.named_scope("smg.sample"):
+                    logits = apply_penalties(logits, *extra[:5])
                 i = 5
             mask = extra[i] if use_mask else None
             toks, lps = _pick_sampler()(logits, key, temp, topk, topp, minp, mask=mask)
@@ -299,15 +300,17 @@ class SelfDraftingRunner(WindowModelRunner):
         impl = self._grouped_prefill_impl_for(G, T, no_ctx)
 
         def step(params, inv_freq, packed, kc, vc, sp, cp, drafts, rng_key, *extra):
-            (tokens, page_tables, prefix_lens, t_reals, topks, temps, topps, minps,
-             counter, slots) = prefill_pack.unpack(packed, G, T, mp, slots=True)
-            key = jax.random.fold_in(rng_key, counter)
+            with jax.named_scope("smg.prefill.unpack"):
+                (tokens, page_tables, prefix_lens, t_reals, topks, temps, topps, minps,
+                 counter, slots) = prefill_pack.unpack(packed, G, T, mp, slots=True)
+                key = jax.random.fold_in(rng_key, counter)
             logits, kc, vc, sp, cp, hidden = module.forward_prefill_batched(
                 params, cfg, inv_freq, tokens, prefix_lens, t_reals, kc, vc, page_tables,
                 sp, cp, slots, no_ctx=no_ctx, attn_impl=impl, with_hidden=True)
             i = 0
             if use_pen:
-                logits = apply_penalties(logits, *extra[:5])
+                with jax.named_scope("smg.sample"):
+                    logits = apply_penalties(logits, *extra[:5])
                 i = 5
             mask = extra[i] if use_mask else None
             toks, lps = _pick_sampler()(logits, key, temps, topks, topps, minps, mask=mask)
@@ -347,37 +350,43 @@ class SelfDraftingRunner(WindowModelRunner):
 
             def column(cur, j, own, sample):
                 side, draft, held, emitted, spec = own
-                pos = entry_pos + held  # where ``cur`` stands
+                with jax.named_scope("smg.frame.emit"):
+                    pos = entry_pos + held  # where ``cur`` stands
+                    rows = jnp.stack([cur, draft], axis=1)
                 logits, hidden, side, c = module.forward_verify_column(
-                    params, cfg, inv_freq, jnp.stack([cur, draft], axis=1), held, entry_pos,
+                    params, cfg, inv_freq, rows, held, entry_pos,
                     kc, vc, page_tables, rk, rv, slots, side, holds, attn_impl=attn_impl)
                 t0, lp0 = sample(logits[:, 0])
-                t0 = t0.astype(jnp.int32)
-                # the second row's token, greedy: emitted where the draft was
-                # the first row's
-                second = logits[:, 1]
-                t1 = jnp.argmax(second, axis=-1).astype(jnp.int32)
-                lp1 = (jnp.take_along_axis(second, t1[:, None], axis=1)[:, 0]
-                       - jax.nn.logsumexp(second, axis=-1))
-                if may_accept:
-                    # not where the first token ends the lane (a stop token,
-                    # or the last one its limit leaves it)
-                    accept = (greedy & (draft == t0) & ~arms.ends(t0)
-                              & (pos + 2 < arms.limits))
-                else:
-                    accept = jnp.zeros((B,), jnp.bool_)
-                n = 1 + accept.astype(jnp.int32)
-                toks = jnp.stack([t0, t1], axis=1)
-                emitted = lax.dynamic_update_slice(emitted, n[:, None], (0, j))
-                last, reach = jnp.where(accept, t1, t0), pos + n
+                with jax.named_scope("smg.sample"):
+                    t0 = t0.astype(jnp.int32)
+                    # the second row's token, greedy: emitted where the draft
+                    # was the first row's
+                    second = logits[:, 1]
+                    t1 = jnp.argmax(second, axis=-1).astype(jnp.int32)
+                    lp1 = (jnp.take_along_axis(second, t1[:, None], axis=1)[:, 0]
+                           - jax.nn.logsumexp(second, axis=-1))
+                with jax.named_scope("smg.frame.emit"):
+                    if may_accept:
+                        # not where the first token ends the lane (a stop token,
+                        # or the last one its limit leaves it)
+                        accept = (greedy & (draft == t0) & ~arms.ends(t0)
+                                  & (pos + 2 < arms.limits))
+                    else:
+                        accept = jnp.zeros((B,), jnp.bool_)
+                    n = 1 + accept.astype(jnp.int32)
+                    toks = jnp.stack([t0, t1], axis=1)
+                    emitted = lax.dynamic_update_slice(emitted, n[:, None], (0, j))
+                    last, reach = jnp.where(accept, t1, t0), pos + n
                 # the module over the rows accepted, for the next draft
                 draft, side, c2 = module.forward_mtp_draft(
                     params, cfg, inv_freq, hidden, toks, accept, held, entry_pos,
                     kc, vc, page_tables, side, holds, attn_impl=attn_impl)
-                spec = spec + jnp.stack([jnp.sum(greedy) if may_accept else 0,
-                                         jnp.sum(accept)]).astype(jnp.int32)
-                return (toks, jnp.stack([lp0, lp1], axis=1), last, reach,
-                        (side, draft, held + n, emitted, spec), module.merge_counts(c, c2))
+                with jax.named_scope("smg.frame.emit"):
+                    spec = spec + jnp.stack([jnp.sum(greedy) if may_accept else 0,
+                                             jnp.sum(accept)]).astype(jnp.int32)
+                    return (toks, jnp.stack([lp0, lp1], axis=1), last, reach,
+                            (side, draft, held + n, emitted, spec),
+                            module.merge_counts(c, c2))
 
             def land(own, _ran, last):
                 (hk, hv, wk, wv), draft, held, emitted, spec = own

@@ -289,6 +289,7 @@ def _moe(h, layer: Params, experts: Params, i, cfg: ModelConfig, live, impl: str
     return y.reshape(h.shape), jnp.stack([picks, rows, hit, rows])
 
 
+@jax.named_scope("smg.moe.residual")
 def _moe_residual(h, layer: Params, experts: Params, i, cfg: ModelConfig, live, impl: str):
     """``h + RMSNorm(MoE(h))``."""
     y, counts = _moe(h, layer, experts, i, cfg, live, impl)
@@ -311,10 +312,12 @@ def _layers(stacks: Params, runs, cfg: ModelConfig, inv_freq, h, positions, live
         def body(carry, xs):
             (h, state, counts), (layer, i) = carry, xs
             q, k, v = _qkv(layer, cfg, h, positions, freq)
-            out, state = attend_kind(q, k, v, layer, cache0 + i, state)
-            o = jnp.einsum("...f,fe->...e", out.astype(h.dtype).reshape(*h.shape[:-1], -1),
-                           layer["wo"])
-            h = h + _norm(o, layer["post_attn_norm"], cfg)
+            with jax.named_scope("smg.attn.kv"):
+                out, state = attend_kind(q, k, v, layer, cache0 + i, state)
+            with jax.named_scope("smg.attn.out"):
+                o = jnp.einsum("...f,fe->...e",
+                               out.astype(h.dtype).reshape(*h.shape[:-1], -1), layer["wo"])
+                h = h + _norm(o, layer["post_attn_norm"], cfg)
             if routed:
                 h, c = _moe_residual(h, layer, experts, first + i, cfg, live, moe_impl)
                 counts = merge_counts(counts, c)
@@ -403,9 +406,10 @@ def forward_mtp_prefill(params, cfg, inv_freq, hidden, tokens, first, prefix_len
     G, T = tokens.shape
     pos, real, attend = mimo.prefill_attends(cfg, G, T, prefix_lens, t_reals, k_cache.shape[2],
                                              page_tables, None, no_ctx)
-    nxt = jnp.concatenate([tokens[:, 1:], jnp.zeros((G, 1), tokens.dtype)], axis=1)
-    nxt = jnp.where(jnp.arange(T)[None, :] == t_reals[:, None] - 1,
-                    first[:, None].astype(tokens.dtype), nxt)
+    with jax.named_scope("smg.mtp"):
+        nxt = jnp.concatenate([tokens[:, 1:], jnp.zeros((G, 1), tokens.dtype)], axis=1)
+        nxt = jnp.where(jnp.arange(T)[None, :] == t_reals[:, None] - 1,
+                        first[:, None].astype(tokens.dtype), nxt)
     u, (k_cache, v_cache, _, _), _counts = _module(
         params, cfg, inv_freq, hidden, nxt, pos, real, (k_cache, v_cache, None, None), attend,
         moe_impl)
@@ -536,12 +540,14 @@ def forward_mtp_draft(params, cfg, inv_freq, hidden, emitted, accept, held, entr
 
         interpret = attn_impl == "pallas_interpret"
         hidden = mark(hidden, "smg.mtp.begin", interpret)
+    with jax.named_scope("smg.mtp"):
+        rows = jnp.stack([holds, holds & accept], axis=1)
     u, side, counts = forward_mtp_column(
         params, cfg, inv_freq, hidden, emitted, held, entry_positions, k_cache, v_cache,
-        page_tables, side, jnp.stack([holds, holds & accept], axis=1), attn_impl=attn_impl,
-        moe_impl=moe_impl)
-    u_last = jnp.where(accept[:, None], u[:, 1], u[:, 0])
-    draft = jnp.argmax(mtp_logits(params, cfg, u_last), axis=-1).astype(jnp.int32)
+        page_tables, side, rows, attn_impl=attn_impl, moe_impl=moe_impl)
+    with jax.named_scope("smg.mtp"):
+        u_last = jnp.where(accept[:, None], u[:, 1], u[:, 0])
+        draft = jnp.argmax(mtp_logits(params, cfg, u_last), axis=-1).astype(jnp.int32)
     if marked:
         draft = mark(draft, "smg.mtp.end", interpret)
     return draft, side, counts

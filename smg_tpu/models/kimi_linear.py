@@ -410,6 +410,7 @@ def logical_axes(cfg: ModelConfig) -> Params:
 # reads the latent pages.  Both return their result and whatever they changed.
 
 
+@jax.named_scope("smg.kda.layer")
 def kda_layer(h, layer: Params, cfg: ModelConfig, mix):
     """``h`` [..., E].  ``mix(qkv [..., C], g [..., H, dk], beta [..., H])``
     (``g`` the log of the decay) returns the recurrence's outputs ``o``
@@ -462,6 +463,7 @@ def _latent_qkv(layer: Params, cfg: ModelConfig, x, positions, inv_freq):
     return q_nope, q_pe, entry
 
 
+@jax.named_scope("smg.mla.block")
 def latent_layer(h, layer: Params, cfg: ModelConfig, positions, inv_freq, attend, l, state):
     """One latent attention sublayer as cache layer ``l``; returns ``(h,
     state)`` with the forward's ``state`` as ``attend`` changed it."""
@@ -477,6 +479,7 @@ def dense_layer(h, layer: Params, cfg: ModelConfig):
     return h + _mlp(layer, _norm(h, layer["norm"], cfg), cfg)
 
 
+@jax.named_scope("smg.moe.residual")
 def moe_layer(h, layer: Params, experts: Params, i, cfg: ModelConfig, live, impl: str):
     """``h + sum_i w_i E_i(u) + E_shared(u)`` over the held experts, ``u =
     RMSNorm(h)``.  ``experts`` holds the routed experts' weights of all expert
@@ -497,12 +500,15 @@ def moe_layer(h, layer: Params, experts: Params, i, cfg: ModelConfig, live, impl
     return h + o, jnp.stack([picks, rows, hit, rows])
 
 
-def _at(tree: Params, i):
-    """Layer ``i`` of a stack: a static slice for a whole number, and for a
-    traced one a dynamic slice that feeds its product directly."""
-    if isinstance(i, int):
-        return jax.tree.map(lambda x: x[i], tree)
-    return jax.tree.map(lambda x: jax.lax.dynamic_index_in_dim(x, i, 0, keepdims=False), tree)
+def _at(tree: Params, i, scope: str):
+    """Layer ``i`` of a stack, taken out under the layer's own ``scope``: a
+    static slice for a whole number, and for a traced one a dynamic slice
+    that feeds its product directly."""
+    with jax.named_scope(scope):
+        if isinstance(i, int):
+            return jax.tree.map(lambda x: x[i], tree)
+        return jax.tree.map(
+            lambda x: jax.lax.dynamic_index_in_dim(x, i, 0, keepdims=False), tree)
 
 
 def stack_with(cfg: ModelConfig, kda, carry):
@@ -520,8 +526,9 @@ def stack_with(cfg: ModelConfig, kda, carry):
 
         def ffn(h, counts, l):
             if isinstance(l, int) and l < Ld:
-                return dense_layer(h, _at(params["dense"], l), cfg), counts
-            h, c = moe_layer(h, _at(params["moe"], l - Ld), experts, l - Ld, cfg, live, moe_impl)
+                return dense_layer(h, _at(params["dense"], l, "smg.mlp"), cfg), counts
+            h, c = moe_layer(h, _at(params["moe"], l - Ld, "smg.moe.residual"), experts,
+                             l - Ld, cfg, live, moe_impl)
             return h, merge_counts(counts, c)
 
         def period(c, first, k0, p, n):
@@ -529,10 +536,10 @@ def stack_with(cfg: ModelConfig, kda, carry):
             layer ``p``); ``k0`` the first's index among the KDA layers."""
             h, state, pools, counts = c
             for i in range(n):
-                h, pools = kda(h, _at(params["kda"], k0 + i), k0 + i, pools)
+                h, pools = kda(h, _at(params["kda"], k0 + i, "smg.kda.layer"), k0 + i, pools)
                 h, counts = ffn(h, counts, first + i)
-            h, state = latent_layer(h, _at(params["mla"], p), cfg, positions, inv_freq,
-                                    attend, p, state)
+            h, state = latent_layer(h, _at(params["mla"], p, "smg.mla.block"), cfg, positions,
+                                    inv_freq, attend, p, state)
             h, counts = ffn(h, counts, first + n)
             return h, state, pools, counts
 
@@ -571,8 +578,9 @@ def _prefill(params, cfg, inv_freq, tokens, prefix_lens, t_reals, k_cache, page_
     row names the garbage slot."""
     G, T = tokens.shape
     H = cfg.linear_num_heads
-    real = jnp.arange(T)[None, :] < t_reals[:, None]
-    keep = (prefix_lens > 0).astype(jnp.float32)  # 0 where the sequence starts here
+    with jax.named_scope("smg.prefill.land"):
+        real = jnp.arange(T)[None, :] < t_reals[:, None]
+        keep = (prefix_lens > 0).astype(jnp.float32)  # 0 where the sequence starts here
     taps = cfg.linear_conv_kernel_dim - 1
 
     def kda(h, layer, li, pools):

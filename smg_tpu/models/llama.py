@@ -136,11 +136,15 @@ def kv_cache_logical_axes() -> tuple[str | None, ...]:
     return ("layers", "pages", None, "kv_lanes")
 
 
-# The ``smg.*`` named scopes (here, in ops/attention.py, ops/pallas and
-# engine/sampling.py) reach the profiler's device trace as part of each HLO
-# operation's name path, so device time can be split by kernel; an operation
-# belongs to the innermost ``smg.`` scope on its path.  Metadata only: the
-# compiled code is the same with and without them.
+# The ``smg.*`` named scopes (here, in the other model files, in ops/ and in
+# engine/) are each compiled instruction's ``op_name``.  A device trace names
+# an operation by its instruction and not by its scope, so the program
+# publishes the map from the one to the other when a profile ends
+# (``analysis/runtime_guards.ProgramAuditor.scope_map``), and device time can
+# be split by layer half and by kernel; an operation belongs to the innermost
+# ``smg.`` scope on its path, and every operation of a layer, from its input
+# norm to its residual add, stands under a scope of its half's family.
+# Metadata only: the compiled code is the same with and without them.
 @jax.named_scope("smg.embed")
 def embed_tokens(params: Params, cfg: ModelConfig, tokens: jnp.ndarray) -> jnp.ndarray:
     h = params["embed"][tokens]
@@ -294,10 +298,13 @@ def decoder_block(cfg: ModelConfig, rotate, attend, lora_gates, carry, xs):
     (stage-LOCAL under pp)."""
     h, *state = carry
     layer, lora, l = xs
-    q, k, v = _qkv(layer, cfg, _norm(h, layer["attn_norm"], cfg), lora, lora_gates)
-    q, k = rotate(q, k)
-    attn, state = attend(q, k, v, l, tuple(state))
-    h = _attn_residual(h, layer, attn, cfg, lora, lora_gates)
+    with jax.named_scope("smg.attn.qkv"):
+        q, k, v = _qkv(layer, cfg, _norm(h, layer["attn_norm"], cfg), lora, lora_gates)
+        q, k = rotate(q, k)
+    with jax.named_scope("smg.attn.kv"):  # the new rows to where the forward keeps them
+        attn, state = attend(q, k, v, l, tuple(state))
+    with jax.named_scope("smg.attn.out"):
+        h = _attn_residual(h, layer, attn, cfg, lora, lora_gates)
     return (_mlp_residual(h, layer, cfg), *state), None
 
 
@@ -410,13 +417,14 @@ def forward_prefill(
     scale = _scale(cfg)
 
     ps, mp = k_cache.shape[2], page_table.shape[0]
-    pos = prefix_len + jnp.arange(T)  # [T]
-    # ``page_slots`` for the one table, indexed directly: padded rows and
-    # out-of-range positions write to the garbage page (0)
-    valid = (jnp.arange(T) < t_real) & (pos < mp * ps)
-    pos_c = jnp.minimum(pos, mp * ps - 1)
-    dest = jnp.where(valid, page_table[pos_c // ps] * ps + pos_c % ps, 0)
-    ctx_len = prefix_len + t_real
+    with jax.named_scope("smg.prefill.land"):  # where the chunk's rows stand and land
+        pos = prefix_len + jnp.arange(T)  # [T]
+        # ``page_slots`` for the one table, indexed directly: padded rows and
+        # out-of-range positions write to the garbage page (0)
+        valid = (jnp.arange(T) < t_real) & (pos < mp * ps)
+        pos_c = jnp.minimum(pos, mp * ps - 1)
+        dest = jnp.where(valid, page_table[pos_c // ps] * ps + pos_c % ps, 0)
+        ctx_len = prefix_len + t_real
 
     h = embed_tokens(params, cfg, tokens)
     if input_embeds is not None:
@@ -465,9 +473,10 @@ def forward_prefill(
         # speculative verify: every chunk position's next-token distribution
         # in one MXU-friendly pass (ops/speculative.py)
         return unembed(params, cfg, h), k_cache, v_cache
-    last = jnp.take_along_axis(
-        h, jnp.maximum(t_real - 1, 0)[None, None].astype(jnp.int32), axis=0
-    )[0]
+    with jax.named_scope("smg.lm_head"):
+        last = jnp.take_along_axis(
+            h, jnp.maximum(t_real - 1, 0)[None, None].astype(jnp.int32), axis=0
+        )[0]
     logits = unembed(params, cfg, last)
     return logits, k_cache, v_cache
 
@@ -508,10 +517,11 @@ def forward_prefill_batched(
     scale = _scale(cfg)
     K, D = cfg.num_kv_heads, cfg.head_dim
 
-    pos = prefix_lens[:, None] + jnp.arange(T)[None, :]  # [G, T]
-    dest = page_slots(page_tables, pos, jnp.arange(T)[None, :] < t_reals[:, None],
-                      ps).reshape(-1)  # [G*T]
-    ctx_lens = prefix_lens + t_reals
+    with jax.named_scope("smg.prefill.land"):  # where the chunks' rows stand and land
+        pos = prefix_lens[:, None] + jnp.arange(T)[None, :]  # [G, T]
+        dest = page_slots(page_tables, pos, jnp.arange(T)[None, :] < t_reals[:, None],
+                          ps).reshape(-1)  # [G*T]
+        ctx_lens = prefix_lens + t_reals
 
     h = embed_tokens(params, cfg, tokens)  # [G, T, E]
     if input_embeds is not None:
@@ -554,10 +564,11 @@ def forward_prefill_batched(
         make_body, (pos, dest, page_tables, ctx_lens, inv_freq, rope_pos, lora_gates),
         (h, k_cache, v_cache), params["layers"], lora, pp_mesh,
     )
-    last_idx = jnp.maximum(t_reals - 1, 0)[:, None, None]  # [G, 1, 1]
-    last = jnp.take_along_axis(
-        h, jnp.broadcast_to(last_idx, (G_, 1, h.shape[-1])).astype(jnp.int32), axis=1
-    )[:, 0]
+    with jax.named_scope("smg.lm_head"):
+        last_idx = jnp.maximum(t_reals - 1, 0)[:, None, None]  # [G, 1, 1]
+        last = jnp.take_along_axis(
+            h, jnp.broadcast_to(last_idx, (G_, 1, h.shape[-1])).astype(jnp.int32), axis=1
+        )[:, 0]
     logits = unembed(params, cfg, last)  # [G, V]
     return logits, k_cache, v_cache
 

@@ -246,6 +246,7 @@ def logical_axes(cfg: ModelConfig) -> Params:
                         jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
 
 
+@jax.named_scope("smg.moe.counts")
 def merge_counts(total, new):
     """The counts of one more layer, or column: all add up but the fourth,
     which is kept as a maximum."""
@@ -287,18 +288,23 @@ def _stack(params: Params, cfg: ModelConfig, inv_freq, h, positions, live, state
     def block(carry, xs):
         (h, state, counts), (layer, l) = carry, xs
         first, second = layer["sub"]
-        o, state = latent_attention(first, cfg, _norm(h, first["attn_norm"], cfg), positions,
-                                    inv_freq, attend, 2 * l, state)
-        u = h + o
-        x0 = _norm(u, first["mlp_norm"], cfg)
+        with jax.named_scope("smg.mla.block"):
+            o, state = latent_attention(first, cfg, _norm(h, first["attn_norm"], cfg),
+                                        positions, inv_freq, attend, 2 * l, state)
+            u = h + o
+        with jax.named_scope("smg.mlp"):
+            x0 = _norm(u, first["mlp_norm"], cfg)
         m, c = shortcut(x0, layer, experts, l, cfg, live, moe_impl)
         with jax.named_scope("smg.mlp"):
             v = u + _mlp(first, x0, cfg)
-        o, state = latent_attention(second, cfg, _norm(v, second["attn_norm"], cfg), positions,
-                                    inv_freq, attend, 2 * l + 1, state)
-        z = _mlp_residual(v + o, second, cfg)
-        return ((z.astype(jnp.float32) + m).astype(h.dtype), state,
-                merge_counts(counts, c)), None
+        with jax.named_scope("smg.mla.block"):
+            o, state = latent_attention(second, cfg, _norm(v, second["attn_norm"], cfg),
+                                        positions, inv_freq, attend, 2 * l + 1, state)
+            w = v + o
+        z = _mlp_residual(w, second, cfg)
+        with jax.named_scope("smg.scmoe.join"):  # the shortcut's branch meets the layer
+            out = (z.astype(jnp.float32) + m).astype(h.dtype)
+        return (out, state, merge_counts(counts, c)), None
 
     (h, state, counts), _ = jax.lax.scan(
         block, (h, state, jnp.zeros((len(ROUTED_COUNTS),), jnp.int32)),

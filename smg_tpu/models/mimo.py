@@ -241,6 +241,7 @@ def _qkv(layer: Params, cfg: ModelConfig, x, positions, inv_freq):
     return _rotary(q, positions, inv_freq), _rotary(k, positions, inv_freq), v
 
 
+@jax.named_scope("smg.moe.residual")
 def _moe_residual(h, layer: Params, experts: Params, i, cfg: ModelConfig, live, impl: str):
     """``h + sum_i w_i E_i(RMSNorm(h))`` over the held experts.  ``experts``
     holds the routed experts' weights of the whole stack, ``i`` picks this
@@ -277,10 +278,14 @@ def _stack(params: Params, cfg: ModelConfig, inv_freq, h, positions, live, state
 
         def body(carry, xs):
             (h, state, counts), (layer, i) = carry, xs
-            q, k, v = _qkv(layer, cfg, _norm(h, layer["attn_norm"], cfg), positions, freq)
-            out, state = attend_kind(q, k, v, layer, cache0 + i, state)
-            h = h + jnp.einsum("...f,fe->...e", out.astype(h.dtype).reshape(*h.shape[:-1], -1),
-                               layer["wo"])
+            with jax.named_scope("smg.attn.qkv"):
+                x = _norm(h, layer["attn_norm"], cfg)
+            q, k, v = _qkv(layer, cfg, x, positions, freq)
+            with jax.named_scope("smg.attn.kv"):
+                out, state = attend_kind(q, k, v, layer, cache0 + i, state)
+            with jax.named_scope("smg.attn.out"):
+                h = h + jnp.einsum("...f,fe->...e",
+                                   out.astype(h.dtype).reshape(*h.shape[:-1], -1), layer["wo"])
             if routed:
                 h, c = _moe_residual(h, layer, experts, first + i, cfg, live, moe_impl)
                 counts = merge_counts(counts, c)
@@ -330,10 +335,11 @@ def prefill_attends(cfg: ModelConfig, G: int, T: int, prefix_lens, t_reals, page
     which of them are real, and the two ``attend`` closures of a prefill over
     the state ``(k_cache, v_cache, ring_k, ring_v)``."""
     W = cfg.sliding_window
-    pos = prefix_lens[:, None] + jnp.arange(T)[None, :]
-    real = jnp.arange(T)[None, :] < t_reals[:, None]
-    ctx_lens = prefix_lens + t_reals
-    dest = page_slots(page_tables, pos, real, page_size).reshape(-1)
+    with jax.named_scope("smg.prefill.land"):  # where the chunks' rows stand and land
+        pos = prefix_lens[:, None] + jnp.arange(T)[None, :]
+        real = jnp.arange(T)[None, :] < t_reals[:, None]
+        ctx_lens = prefix_lens + t_reals
+        dest = page_slots(page_tables, pos, real, page_size).reshape(-1)
     scale = _scale(cfg)
 
     def full(q, k, v, layer, c, state):
@@ -366,6 +372,7 @@ def prefill_attends(cfg: ModelConfig, G: int, T: int, prefix_lens, t_reals, page
     return pos, real, {"full": full, "window": window}
 
 
+@jax.named_scope("smg.lm_head")
 def last_real(h, t_reals):
     """``h`` [G, T, E] at each row's last real token, [G, E]."""
     return jnp.take_along_axis(

@@ -384,6 +384,7 @@ def logical_axes(cfg: ModelConfig) -> Params:
 # result and whatever they changed, which the layer hands back.
 
 
+@jax.named_scope("smg.ssm.layer")
 def mamba_layer(h, layer: Params, cfg: ModelConfig, mix):
     """``h`` [..., E].  ``mix(xbc [..., C], dt [..., H], g [..., H])`` (``g``
     the log of the decay) returns ``y`` [..., H, P] (float32, the ``D x`` term
@@ -418,6 +419,7 @@ def split_xbc(y, cfg: ModelConfig):
             C.reshape(*C.shape[:-1], R, N))
 
 
+@jax.named_scope("smg.attn.layer")
 def attention_layer(h, layer: Params, cfg: ModelConfig, attend):
     """``h`` [..., E].  ``attend(q [..., H, D], k, v [..., K, D])`` returns the
     attention's output [..., H, D] and the caches it wrote.  Returns ``(h,
@@ -437,6 +439,7 @@ def shared_expert(layer: Params, u):
                       layer["ws_down"])
 
 
+@jax.named_scope("smg.moe.residual")
 def moe_layer(h, layer: Params, experts: Params, i, cfg: ModelConfig, live, impl: str):
     """``h + W_ul sum_i w_i E_i(W_dl u) + E_shared(u)`` over the held experts,
     ``u = RMSNorm(h)``.  ``experts`` holds the routed experts' weights of all
@@ -468,14 +471,19 @@ def _stack(params: Params, cfg: ModelConfig, h, carry, live, moe_impl, mamba, at
     cache) and return ``(h, carry)``.  Returns ``h``, the carry and the expert
     layers' counts."""
     counts = jnp.zeros((len(ROUTED_COUNTS),), jnp.int32)
-    at = lambda tree, i: jax.tree.map(lambda x: x[i], tree)
+
+    def at(tree, i, scope):  # a layer's weights out of their stack, under its scope
+        with jax.named_scope(scope):
+            return jax.tree.map(lambda x: x[i], tree)
+
     for kind, i in layers_of(cfg):
         if kind == "mamba":
-            h, carry = mamba(h, at(params["mamba"], i), i, carry)
+            h, carry = mamba(h, at(params["mamba"], i, "smg.ssm.layer"), i, carry)
         elif kind == "full_attention":
-            h, carry = attention(h, at(params["attn"], i), i, carry)
+            h, carry = attention(h, at(params["attn"], i, "smg.attn.layer"), i, carry)
         else:
-            h, c = moe_layer(h, at(params["moe"], i), params["experts"], i, cfg, live, moe_impl)
+            h, c = moe_layer(h, at(params["moe"], i, "smg.moe.residual"), params["experts"],
+                             i, cfg, live, moe_impl)
             counts = merge_counts(counts, c)
     return h, carry, counts
 
@@ -494,10 +502,11 @@ def _prefill(params, cfg, tokens, prefix_lens, t_reals, k_cache, v_cache, page_t
     tail, a padded row names the garbage slot."""
     G, T = tokens.shape
     K, D, H = cfg.num_kv_heads, cfg.head_dim, cfg.ssm_num_heads
-    pos = prefix_lens[:, None] + jnp.arange(T)[None, :]
-    real = jnp.arange(T)[None, :] < t_reals[:, None]
-    dest = page_slots(page_tables, pos, real, k_cache.shape[2]).reshape(-1)
-    keep = (prefix_lens > 0).astype(jnp.float32)  # 0 where the sequence starts here
+    with jax.named_scope("smg.prefill.land"):  # where the chunks' rows stand and land
+        pos = prefix_lens[:, None] + jnp.arange(T)[None, :]
+        real = jnp.arange(T)[None, :] < t_reals[:, None]
+        dest = page_slots(page_tables, pos, real, k_cache.shape[2]).reshape(-1)
+        keep = (prefix_lens > 0).astype(jnp.float32)  # 0 where the sequence starts here
     taps = cfg.ssm_conv_kernel - 1
     h = embed_tokens(params, cfg, tokens)
 
@@ -533,8 +542,9 @@ def _prefill(params, cfg, tokens, prefix_lens, t_reals, k_cache, v_cache, page_t
 
     h, carry, _counts = _stack(params, cfg, h, (k_cache, v_cache, s_pool, c_pool), real,
                                moe_impl, mamba, attn)
-    last = jnp.take_along_axis(
-        h, jnp.maximum(t_reals - 1, 0)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+    with jax.named_scope("smg.lm_head"):
+        last = jnp.take_along_axis(
+            h, jnp.maximum(t_reals - 1, 0)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
     return (unembed(params, cfg, last), *carry)
 
 

@@ -245,6 +245,7 @@ def _mlp_residual(h, layer, cfg):
     return h + rms_norm(_mlp(layer, h, cfg), layer["mlp_post_norm"], cfg.rms_norm_eps)
 
 
+@jax.named_scope("smg.linattn.layer")
 def linear_layer(h, layer: Params, cfg: ModelConfig, mix):
     """``h`` [..., E].  ``mix(qkv [..., C], g [..., H], beta [..., H])`` returns
     the recurrence's outputs ``o`` [..., H, dv] (float32) and its new state.
@@ -277,6 +278,7 @@ def split_qkv(y, cfg: ModelConfig):
     return l2(heads(q, dk)) * (dk ** -0.5), l2(heads(k, dk)), heads(v, dv)
 
 
+@jax.named_scope("smg.attn.layer")
 def full_layer(h, layer: Params, cfg: ModelConfig, positions, inv_freq, attend):
     """``h`` [..., E].  ``attend(q [..., H, D], k, v [..., K, D])`` returns the
     attention's output [..., H, D] and the caches it wrote.  Returns ``(h,
@@ -309,8 +311,9 @@ def _stack(params: Params, cfg: ModelConfig, h, carry, lin, full):
 
     def linear(c, li):
         h, carry = c
-        layer = jax.tree.map(
-            lambda x: jax.lax.dynamic_index_in_dim(x, li, 0, keepdims=False), flat)
+        with jax.named_scope("smg.linattn.layer"):
+            layer = jax.tree.map(
+                lambda x: jax.lax.dynamic_index_in_dim(x, li, 0, keepdims=False), flat)
         return lin(h, layer, li, carry), None
 
     def body(c, xs):
@@ -338,10 +341,11 @@ def _prefill(params, cfg, inv_freq, tokens, prefix_lens, t_reals, k_cache, v_cac
     tail, a padded row names the garbage slot."""
     G, T = tokens.shape
     K, D, H = cfg.num_kv_heads, cfg.head_dim, cfg.linear_num_heads
-    pos = prefix_lens[:, None] + jnp.arange(T)[None, :]
-    real = jnp.arange(T)[None, :] < t_reals[:, None]
-    dest = page_slots(page_tables, pos, real, k_cache.shape[2]).reshape(-1)
-    keep = (prefix_lens > 0).astype(jnp.float32)  # 0 where the sequence starts here
+    with jax.named_scope("smg.prefill.land"):  # where the chunks' rows stand and land
+        pos = prefix_lens[:, None] + jnp.arange(T)[None, :]
+        real = jnp.arange(T)[None, :] < t_reals[:, None]
+        dest = page_slots(page_tables, pos, real, k_cache.shape[2]).reshape(-1)
+        keep = (prefix_lens > 0).astype(jnp.float32)  # 0 where the sequence starts here
     taps = cfg.linear_conv_kernel_dim - 1
     h = embed_tokens(params, cfg, tokens)
 
@@ -374,8 +378,9 @@ def _prefill(params, cfg, inv_freq, tokens, prefix_lens, t_reals, k_cache, v_cac
         return h, (kc, vc, s_pool, c_pool)
 
     h, carry = _stack(params, cfg, h, (k_cache, v_cache, s_pool, c_pool), lin, full)
-    last = jnp.take_along_axis(
-        h, jnp.maximum(t_reals - 1, 0)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+    with jax.named_scope("smg.lm_head"):
+        last = jnp.take_along_axis(
+            h, jnp.maximum(t_reals - 1, 0)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
     return (unembed(params, cfg, last), *carry)
 
 

@@ -242,6 +242,7 @@ def latent_attention(layer: Params, cfg: ModelConfig, x, positions, inv_freq, at
     return o, state
 
 
+@jax.named_scope("smg.moe.residual")
 def _moe_residual(h, layer: Params, experts: Params, i, cfg: ModelConfig, live, impl: str):
     """``h + RMSNorm(sum_i w_i E_i(x) + E_shared(x))`` over the held experts,
     ``x = RMSNorm(h)``.  ``experts`` holds the routed experts' weights of all
@@ -282,6 +283,7 @@ def shared_expert(layer: Params, x, cfg: ModelConfig):
 ROUTED_COUNTS = ("picks", "picks_held", "experts_hit", "rows_max")
 
 
+@jax.named_scope("smg.moe.counts")
 def merge_counts(total, new):
     """The expert layers' counts of one more layer, or column: the first three
     add up, the fourth is kept as a maximum."""
@@ -295,6 +297,7 @@ def _stack(params: Params, cfg: ModelConfig, inv_freq, h, positions, live, state
     (the last kept as a maximum)."""
     Ld = cfg.first_k_dense_replace
 
+    @jax.named_scope("smg.mla.block")
     def attention(h, layer, l, state):
         o, state = latent_attention(layer, cfg, _norm(h, layer["attn_norm"], cfg), positions,
                                     inv_freq, attend, l, state)
@@ -338,10 +341,11 @@ def _prefill(params, cfg, inv_freq, tokens, prefix_lens, t_reals, cache, page_ta
     values in the online-softmax kernel; every other chunk in XLA's forms."""
     G, T = tokens.shape
     rkv, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
-    pos = prefix_lens[:, None] + jnp.arange(T)[None, :]
-    real = jnp.arange(T)[None, :] < t_reals[:, None]
-    ctx_lens = prefix_lens + t_reals
-    dest = page_slots(page_tables, pos, real, cache.shape[2]).reshape(-1)
+    with jax.named_scope("smg.prefill.land"):  # where the chunk's rows stand and land
+        pos = prefix_lens[:, None] + jnp.arange(T)[None, :]
+        real = jnp.arange(T)[None, :] < t_reals[:, None]
+        ctx_lens = prefix_lens + t_reals
+        dest = page_slots(page_tables, pos, real, cache.shape[2]).reshape(-1)
     scale = _scale(cfg)
 
     def expanded(layer, q_nope, q_pe, ctx, pos, ctx_lens):
@@ -384,8 +388,9 @@ def _prefill(params, cfg, inv_freq, tokens, prefix_lens, t_reals, cache, page_ta
 
     h = embed_tokens(params, cfg, tokens)
     h, cache, _counts = stack(params, cfg, inv_freq, h, pos, real, cache, attend, moe_impl)
-    last = jnp.take_along_axis(
-        h, jnp.maximum(t_reals - 1, 0)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+    with jax.named_scope("smg.lm_head"):
+        last = jnp.take_along_axis(
+            h, jnp.maximum(t_reals - 1, 0)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
     return unembed(params, cfg, last), cache
 
 
