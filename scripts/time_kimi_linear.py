@@ -28,7 +28,10 @@ rounded.
 lanes, as the kernel ``smg.kda.decode`` (the body that expands the decay a
 channel), as ``smg.linattn.decode`` on the same pool (the body of the decay a
 head, given each head's mean decay: what the extra operand costs is the
-difference) and as the XLA form; a decode frame of 8 columns at 64 lanes; a
+difference) and as the XLA form; the convolution's decode step alone
+(``conv_decode_step``: a slice and an update of the tail pool a lane) over the
+pool's layers at 64 lanes, eight columns of it a call so that the call's own
+cost stays small beside it; a decode frame of 8 columns at 64 lanes; a
 grouped prefill of eight 512-token rows and of two 2,048-token rows; the
 chunked prefill form of the recurrence alone (``kda_chunked``), one call a KDA
 layer chained, at eight rows of 512 tokens and at one of 1,024.
@@ -48,6 +51,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import math
 import os
 import sys
 import time
@@ -243,7 +247,7 @@ def timings(args, runner) -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from smg_tpu.ops.linear_attention import kda_chunked, kda_step
+    from smg_tpu.ops.linear_attention import conv_decode_step, kda_chunked, kda_step
     from smg_tpu.ops.pallas.linattn_decode import kda_decode, linattn_decode
     from time_olmo_hybrid import chunked_form_ms
 
@@ -294,6 +298,28 @@ def timings(args, runner) -> None:
         except Exception as e:  # noqa: BLE001 - a form that does not compile is a reading too
             res[f"kda_decode_{name}_failed"] = f"{type(e).__name__}: {str(e)[:200]}"
     res["kda_decode_least_ms"] = round(B * layers * 2 * H * dk * dv * 4 / 819e9 * 1e3, 3)
+
+    K = cfg.linear_conv_kernel_dim
+    C = math.prod(runner.c_pool.shape[2:]) // (K - 1)
+    x = jax.random.normal(ks[0], (B, C), jnp.float32).astype(runner.c_pool.dtype)
+    conv_w = jax.random.normal(ks[1], (K, C), jnp.float32).astype(runner.c_pool.dtype)
+
+    def tails(pool):
+        def body(i, c):
+            pool, acc = c
+            y, pool = conv_decode_step(pool, i % layers, slots, slots > 0, x, conv_w)
+            return pool, acc + jnp.sum(y)
+        return jax.lax.fori_loop(0, N * layers, body, (pool, jnp.float32(0)))
+
+    tails = jax.jit(tails, donate_argnums=(0,))
+
+    def once():
+        runner.c_pool, acc = tails(runner.c_pool)
+        return acc
+
+    res[f"conv_decode_ms_{N}_columns_{layers}_layers_{B}_lanes"] = timed(once)
+    res["conv_decode_least_ms"] = round(
+        N * B * layers * 2 * (K - 1) * C * runner.c_pool.dtype.itemsize / 819e9 * 1e3, 3)
 
     zeros, ones = np.zeros(B, np.float32), np.ones(B, np.float32)
     w = 16 if args.rehearsal else 128

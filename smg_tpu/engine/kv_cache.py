@@ -97,9 +97,16 @@ class StateSpec:
 
     @property
     def slot_bytes(self) -> int:
-        """Bytes one sequence's slot holds over all recurrent layers."""
-        n = lambda shape: math.prod(shape) // self.num_slots
-        return n(self.state_shape) * 4 + n(self.conv_shape) * jnp.dtype(self.conv_dtype).itemsize
+        """Bytes one sequence's slot holds over all recurrent layers: a tail of
+        ``[R, W]`` (``tail_block``) as the whole tiles its rows take on the
+        device, a tail of one flat row as the row."""
+        from smg_tpu.ops.linear_attention import tail_padded_rows
+
+        layers, _, *block = self.conv_shape
+        if len(block) == 2:
+            block[0] = tail_padded_rows(block[0], self.conv_dtype)
+        return (math.prod(self.state_shape) // self.num_slots * 4
+                + layers * math.prod(block) * jnp.dtype(self.conv_dtype).itemsize)
 
     @property
     def total_bytes(self) -> int:

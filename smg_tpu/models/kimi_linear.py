@@ -87,11 +87,12 @@ from smg_tpu.ops.linear_attention import (
     heads_to_pool,
     kda_causal_conv,
     kda_chunked,
-    kda_conv_step,
+    kda_conv_decode,
     kda_step,
     pool_to_heads,
     read_state,
     read_tail,
+    tail_block,
     write_state,
     write_tail,
 )
@@ -247,11 +248,12 @@ def count(cfg: ModelConfig, kind: str) -> int:
 
 def state_shapes(cfg: ModelConfig, slots: int) -> tuple[tuple, tuple]:
     """Shapes of the two state pools for ``slots`` slots (the garbage slot
-    included): recurrent state float32, convolution tail in the model's dtype."""
+    included): recurrent state float32, convolution tail in the model's dtype,
+    a slot's tail as whole tiles (``ops.linear_attention.tail_block``)."""
     Lk = count(cfg, "kda")
     H, dk, dv = cfg.linear_num_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim
     return ((Lk, slots, dk, H * dv),
-            (Lk, slots, (cfg.linear_conv_kernel_dim - 1) * conv_channels(cfg)))
+            (Lk, slots, *tail_block(conv_channels(cfg), cfg.linear_conv_kernel_dim, cfg.dtype)))
 
 
 def prefill_workspace_bytes(cfg: ModelConfig, tokens: int, dtype: str) -> int:
@@ -616,7 +618,7 @@ def forward_prefill(
     v_cache: jnp.ndarray,  # of zero size: this cache has no V buffer
     page_table: jnp.ndarray,  # [mp]
     s_pool: jnp.ndarray,  # [KDA layers, slots, dk, H*dv] float32
-    c_pool: jnp.ndarray,  # [KDA layers, slots, (K-1) * C]
+    c_pool: jnp.ndarray,  # [KDA layers, slots, R, W]: ``tail_block``
     slot: jnp.ndarray,  # scalar: the sequence's state slot
     attn_impl: str = "xla",  # the solo chunk attends in XLA's form; kept for the runner
     moe_impl: str = "xla",  # "xla" | "pallas" | "pallas_interpret"
@@ -717,15 +719,11 @@ def forward_decode_horizon(
     ``beta`` 0 and keeps its convolution tail, so its slot is left bit for
     bit, and picks no expert.  Returns (logits [B, V], side, s_pool, c_pool,
     counts)."""
-    taps = cfg.linear_conv_kernel_dim - 1
-
     def kda(h, layer, li, pools):
         s_pool, c_pool = pools
 
         def mix(qkv, g, beta):
-            old = read_tail(c_pool, li, slots, taps)  # [B, K-1, C]
-            y, tail = kda_conv_step(qkv, old, layer["conv"])
-            tail = jnp.where(runs[:, None, None], tail, old)
+            y, c_new = kda_conv_decode(c_pool, li, slots, runs, qkv, layer["conv"])
             q, k, v = split_qkv(y, cfg)
             alpha = jnp.where(runs[:, None, None], jnp.exp(g), 1.0)
             beta = jnp.where(runs[:, None], beta, 0.0)
@@ -736,7 +734,7 @@ def forward_decode_horizon(
                                       interpret=(kda_impl == "pallas_interpret"))
             else:
                 o, s_new = kda_step(s_pool, li, slots, q, k, v, alpha, beta)
-            return o, (s_new, write_tail(c_pool, li, slots, tail))
+            return o, (s_new, c_new)
 
         return kda_layer(h, layer, cfg, mix)
 

@@ -247,7 +247,18 @@ def conv_channels(cfg: ModelConfig) -> int:
 
 def state_shapes(cfg: ModelConfig, slots: int) -> tuple[tuple, tuple]:
     """Shapes of the two state pools for ``slots`` slots (the garbage slot
-    included): recurrent state float32, convolution tail in the model's dtype."""
+    included): recurrent state float32, convolution tail in the model's dtype.
+
+    The tail is still one flat row a slot, ``[layers, slots, (K - 1) * C]``,
+    with the slot on the tiles' sublanes (a decode column writes a 60 KB row at
+    thirty times its bytes: ``PERF.md``, Findings, PR 54) and not
+    ``ops.linear_attention.tail_block``'s whole tiles a slot, which
+    ``models/kimi_linear.py`` and ``models/olmo_hybrid.py`` take: the
+    benchmark's drive of this model scales the pool's slots as a pool of three
+    axes (``benchmark/architectures/nemotron_h.py``, ``Drive.decode``) and a
+    change of the program may not edit it.  Once that line takes a pool of any
+    rank, the second shape here is ``(Lm, slots, *tail_block(conv_channels(cfg),
+    cfg.ssm_conv_kernel, cfg.dtype))`` and nothing else changes."""
     Lm = count(cfg, "mamba")
     return ((Lm, slots, cfg.ssm_state_size, cfg.ssm_num_heads * cfg.ssm_head_dim),
             (Lm, slots, (cfg.ssm_conv_kernel - 1) * conv_channels(cfg)))
@@ -687,16 +698,14 @@ def forward_decode_horizon(
     tail, so its slot is left bit for bit, and picks no expert.  Returns
     (logits [B, V], hk_all, hv_all, s_pool, c_pool, counts)."""
     scale = 1.0 / math.sqrt(cfg.head_dim)
-    taps = cfg.ssm_conv_kernel - 1
     h = embed_tokens(params, cfg, tokens)
 
     def mamba(h, layer, li, carry):
         hk, hv, s_pool, c_pool = carry
 
         def mix(xbc, dt, g):
-            old = read_tail(c_pool, li, slots, taps)  # [B, K-1, C]
-            y, tail = ssm.conv_step(xbc, old, layer["conv_w"], layer["conv_b"])
-            tail = jnp.where(runs[:, None, None], tail, old)
+            y, c_new = ssm.conv_decode(c_pool, li, slots, runs, xbc, layer["conv_w"],
+                                       layer["conv_b"])
             x, B, C = split_xbc(y, cfg)
             dt = jnp.where(runs[:, None], dt, 0.0)
             decay = jnp.where(runs[:, None], jnp.exp(g), 1.0)
@@ -708,7 +717,7 @@ def forward_decode_horizon(
             else:
                 y, s_new = ssm.ssd_step(s_pool, li, slots, x, dt, decay, B, C)
             y = y + layer["D"].astype(jnp.float32)[:, None] * x
-            return y, (s_new, write_tail(c_pool, li, slots, tail))
+            return y, (s_new, c_new)
 
         h, (s_pool, c_pool) = mamba_layer(h, layer, cfg, mix)
         return h, (hk, hv, s_pool, c_pool)

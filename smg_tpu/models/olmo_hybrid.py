@@ -69,13 +69,14 @@ from smg_tpu.ops.attention import (
 )
 from smg_tpu.ops.linear_attention import (
     causal_conv,
-    conv_step,
+    conv_decode,
     gated_delta_chunked,
     gated_delta_step,
     heads_to_pool,
     pool_to_heads,
     read_state,
     read_tail,
+    tail_block,
     write_state,
     write_tail,
 )
@@ -142,11 +143,13 @@ def conv_channels(cfg: ModelConfig) -> int:
 
 def state_shapes(cfg: ModelConfig, slots: int) -> tuple[tuple, tuple]:
     """Shapes of the two state pools for ``slots`` slots (the garbage slot
-    included): recurrent state float32, convolution tail in the model's dtype."""
+    included): recurrent state float32, convolution tail in the model's dtype,
+    a slot's tail as whole tiles (``ops.linear_attention.tail_block``)."""
     P, n = period_of(cfg.layer_types)
     H, dk, dv = cfg.linear_num_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim
     K = cfg.linear_conv_kernel_dim
-    return (P * n, slots, dk, H * dv), (P * n, slots, (K - 1) * conv_channels(cfg))
+    return ((P * n, slots, dk, H * dv),
+            (P * n, slots, *tail_block(conv_channels(cfg), K, cfg.dtype)))
 
 
 def decode_step(cfg: ModelConfig) -> dict:
@@ -395,7 +398,7 @@ def forward_prefill(
     v_cache: jnp.ndarray,
     page_table: jnp.ndarray,  # [mp]
     s_pool: jnp.ndarray,  # [linear layers, slots, dk, H*dv] float32
-    c_pool: jnp.ndarray,  # [linear layers, slots, (K-1) * C]
+    c_pool: jnp.ndarray,  # [linear layers, slots, R, W]: ``tail_block``
     slot: jnp.ndarray,  # scalar: the sequence's state slot
     attn_impl: str = "xla",  # "xla" | "pallas" | "pallas_interpret" (tests)
 ):
@@ -492,16 +495,13 @@ def forward_decode_horizon(
     B = tokens.shape[0]
     K, D = cfg.num_kv_heads, cfg.head_dim
     scale = 1.0 / math.sqrt(D)
-    taps = cfg.linear_conv_kernel_dim - 1
     h = embed_tokens(params, cfg, tokens)
 
     def lin(h, layer, li, carry):
         hk, hv, s_pool, c_pool = carry
 
         def mix(qkv, g, beta):
-            old = read_tail(c_pool, li, slots, taps)  # [B, K-1, C]
-            y, tail = conv_step(qkv, old, layer["conv"])
-            tail = jnp.where(runs[:, None, None], tail, old)
+            y, c_new = conv_decode(c_pool, li, slots, runs, qkv, layer["conv"])
             q, k, v = split_qkv(y, cfg)
             alpha = jnp.where(runs[:, None], jnp.exp(g), 1.0)
             beta = jnp.where(runs[:, None], beta, 0.0)
@@ -512,7 +512,7 @@ def forward_decode_horizon(
                                           interpret=(linattn_impl == "pallas_interpret"))
             else:
                 o, s_new = gated_delta_step(s_pool, li, slots, q, k, v, alpha, beta)
-            return o, (s_new, write_tail(c_pool, li, slots, tail))
+            return o, (s_new, c_new)
 
         h, (s_pool, c_pool) = linear_layer(h, layer, cfg, mix)
         return h, (hk, hv, s_pool, c_pool)

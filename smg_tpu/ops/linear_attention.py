@@ -47,7 +47,9 @@ block per head would put ``dk`` (96) on the 128 lanes of a TPU tile, which pads
 every row to 128 in HBM (a third more bytes to hold and to move); ``H * dv`` is
 a multiple of 128 and ``dk`` a multiple of 8, so this form holds exactly the
 floats the model has.  Slot 0 is the garbage slot, as page 0 is the garbage
-page: padded rows of a batch point at it.
+page: padded rows of a batch point at it.  The convolution's tails lie beside
+it in a pool of their own, ``[layers, slots, R, W]`` in the model's dtype
+(``tail_block``): a slot's block is whole tiles there too.
 
 Everything here computes in float32 at ``highest`` matmul precision: the
 products are a few percent of a layer's arithmetic and the triangular solve
@@ -108,11 +110,31 @@ def conv_token(x: jnp.ndarray, tail: jnp.ndarray, weight: jnp.ndarray, bias=None
     return jax.nn.silu(y), window[:, 1:].astype(tail.dtype)
 
 
+def conv_decode_step(pool: jnp.ndarray, layer, slots: jnp.ndarray, runs: jnp.ndarray,
+                     x: jnp.ndarray, weight: jnp.ndarray, bias=None
+                     ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """One decode token of the convolution for every lane, over the tails in
+    the pool's slots: ``conv_token`` on ``read_tail``'s rows, the new tails
+    written back.
+
+    ``pool`` [layers, slots, R, W] (``tail_block``; or ``read_tail``'s flat
+    row a slot); ``slots`` [B]; ``runs`` [B] bool (a lane that does not run
+    keeps its tail bit for bit); ``x`` [B, C]; ``weight`` [K, C]; ``bias`` [C]
+    or None.  Returns ``(y [B, C] float32, pool)``.  A slice and an update a
+    lane, each a slot's whole tiles: a kernel over the same blocks (the slot
+    picked in an index map, as the state kernels pick it) read a decode frame
+    of ``kimi-linear-48b-a3b`` 6 % shorter on a v5e, under the 10 % its issue
+    asked of it (``PERF.md``, Findings, PR 54)."""
+    old = read_tail(pool, layer, slots, weight.shape[0] - 1)  # [B, K-1, C]
+    y, tail = conv_token(x, old, weight, bias)
+    return y, write_tail(pool, layer, slots, jnp.where(runs[:, None, None], tail, old))
+
+
 # under this module's span; ``ops/ssm.py`` runs the same two under its own
 causal_conv = jax.named_scope("smg.linattn.conv")(conv_chunk)
-conv_step = jax.named_scope("smg.linattn.conv")(conv_token)
+conv_decode = jax.named_scope("smg.linattn.conv")(conv_decode_step)
 kda_causal_conv = jax.named_scope("smg.kda.conv")(conv_chunk)
-kda_conv_step = jax.named_scope("smg.kda.conv")(conv_token)
+kda_conv_decode = jax.named_scope("smg.kda.conv")(conv_decode_step)
 
 
 # --------------------------------------------------------------------------
@@ -303,11 +325,12 @@ def kda_chunked(q, k, v, g, beta, S0, chunk: int = CHUNK):
 
 
 # --------------------------------------------------------------------------
-# the pools.  A row is read and written with a dynamic slice, one for each
-# sequence: written as ``pool[layer, slots]`` and ``pool.at[layer,
-# slots].set`` inside the layer scan, XLA:TPU keeps a second copy of the whole
-# pool beside the carried one (1.9 GB at 72 slots), where slices of a carried
-# buffer update in place.
+# the pools.  A slot's block is whole tiles of its pool (the state ``[dk, H *
+# dv]``, the tail ``tail_block``'s ``[R, W]``) and is read and written with a
+# dynamic slice, one for each sequence: written as ``pool[layer, slots]`` and
+# ``pool.at[layer, slots].set`` inside the layer scan, XLA:TPU keeps a second
+# copy of the whole pool beside the carried one (1.9 GB at 72 slots), where
+# slices of a carried buffer update in place.
 
 
 def read_state(pool: jnp.ndarray, layer, slots: jnp.ndarray) -> jnp.ndarray:
@@ -324,20 +347,51 @@ def write_state(pool: jnp.ndarray, layer, slots: jnp.ndarray, rows: jnp.ndarray)
     return pool
 
 
+def tail_padded_rows(R: int, dtype) -> int:
+    """Rows a tail block of ``R`` rows takes in HBM: whole tiles of the dtype."""
+    tile = 32 // jnp.dtype(dtype).itemsize
+    return -(-R // tile) * tile
+
+
+def tail_block(C: int, K: int, dtype) -> tuple[int, int]:
+    """``(R, W)``: how a slot's convolution tail, the last ``K - 1`` inputs of
+    ``C`` channels, lies in its pool ``[layers, slots, R, W]``, ``R * W = (K -
+    1) * C`` in ``read_tail``'s order.  The slot must not be the pool's
+    second-minor axis: a TPU tiles the last two axes (8 sublanes of 32 bits by
+    128 lanes, 16 rows of bfloat16), so with the tail as one flat row a slot
+    was one sublane of every tile it shared with its neighbours, and writing
+    72 KB moved their rows too, thirty times the bytes (``PERF.md``, PR 54).
+    ``W`` is a multiple of 128 that divides ``C``, so a tap is ``C / W`` whole
+    rows: the widest whose ``R`` the dtype's tile divides (not a byte more
+    than the flat row held), else the one whose ``R`` lacks the fewest rows
+    of whole tiles, the widest of those (a tap's rows are what ``read_tail``
+    stands side by side again).  Where no 128 divides ``C`` the block is
+    ``[K - 1, C]`` (toy widths)."""
+    widths = [w for w in range(128, C + 1, 128) if C % w == 0]
+    if not widths:
+        return K - 1, C
+    rows = lambda w: (K - 1) * C // w
+    W = min(widths, key=lambda w: (tail_padded_rows(rows(w), dtype) - rows(w), -w))
+    return rows(W), W
+
+
 def read_tail(pool: jnp.ndarray, layer, slots: jnp.ndarray, taps: int) -> jnp.ndarray:
-    """Rows ``slots`` [G] of the convolution pool ``[layers, slots, (K - 1) *
-    C]`` in ``layer``, as ``[G, K - 1, C]`` (``taps`` is K - 1).  A row keeps
-    its K - 1 inputs side by side on the minor axis: a ``[K - 1, C]`` block a
-    slot would pad its three rows to a tile of sixteen in HBM."""
-    row = lambda s: lax.dynamic_slice(pool, (layer, s, 0), (1, 1, pool.shape[2]))[0, 0]
+    """Blocks ``slots`` [G] of the convolution pool ``[layers, slots, R, W]``
+    (``tail_block``) in ``layer``, as ``[G, K - 1, C]`` (``taps`` is K - 1).
+    A pool ``[layers, slots, (K - 1) * C]`` is read the same way: the flat
+    row a slot, which ``models/nemotron_h.py`` still keeps (its
+    ``state_shapes`` says why)."""
+    start = (0,) * (pool.ndim - 2)
+    row = lambda s: lax.dynamic_slice(pool, (layer, s, *start), (1, 1, *pool.shape[2:]))[0, 0]
     rows = jnp.stack([row(slots[g]) for g in range(slots.shape[0])])
     return rows.reshape(slots.shape[0], taps, -1)
 
 
 def write_tail(pool: jnp.ndarray, layer, slots: jnp.ndarray, rows: jnp.ndarray) -> jnp.ndarray:
-    flat = rows.reshape(rows.shape[0], 1, 1, -1).astype(pool.dtype)
+    blocks = rows.reshape(rows.shape[0], 1, 1, *pool.shape[2:]).astype(pool.dtype)
+    start = (0,) * (pool.ndim - 2)
     for g in range(slots.shape[0]):
-        pool = lax.dynamic_update_slice(pool, flat[g], (layer, slots[g], 0))
+        pool = lax.dynamic_update_slice(pool, blocks[g], (layer, slots[g], *start))
     return pool
 
 

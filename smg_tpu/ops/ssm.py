@@ -27,9 +27,12 @@ query's.  Three forms of it live here, as there:
 on the minor axis, ``[layers, slots, N, H * P]`` float32, as the gated delta
 rule's pool is ``[layers, slots, dk, H * dv]``: ``B`` and ``C`` then run down
 the sublanes and everything a head has of its own (``x``, ``dt``, the decay,
-the output) lies along the lanes.  Slot 0 is the garbage slot.  The pools are
-read and written with ``ops/linear_attention.py``'s ``read_state``,
-``write_state``, ``read_tail`` and ``write_tail``.
+the output) lies along the lanes.  Slot 0 is the garbage slot.  The
+convolution's tails lie beside it in a pool of their own in the model's dtype
+(``models/nemotron_h.state_shapes`` says which layout and why).  Prefill reads
+and writes the pools with ``ops/linear_attention.py``'s ``read_state``,
+``write_state``, ``read_tail`` and ``write_tail``, and decode steps the tails
+with them too (``conv_decode_step``).
 
 Everything here computes in float32 at ``highest`` matmul precision: the
 products are under a percent of a layer's arithmetic.
@@ -44,7 +47,7 @@ from jax import lax
 from smg_tpu.ops.linear_attention import (
     _mm,
     conv_chunk,
-    conv_token,
+    conv_decode_step,
     heads_to_pool,
     pool_to_heads,
     read_state,
@@ -52,7 +55,7 @@ from smg_tpu.ops.linear_attention import (
 )
 
 causal_conv = jax.named_scope("smg.ssm.conv")(conv_chunk)
-conv_step = jax.named_scope("smg.ssm.conv")(conv_token)
+conv_decode = jax.named_scope("smg.ssm.conv")(conv_decode_step)
 
 
 @jax.named_scope("smg.ssm.scan")

@@ -529,7 +529,8 @@ def test_the_engine_serves_it_through_slots_latent_pages_and_the_one_decode_fram
     assert loads["lookahead_kept"] > 0 and loads["audit"]["clean"]
     # state slots and a latent cache with no V buffer in one engine
     assert loads["state_slots_total"] == 8 + 8 and loads["state_slots_in_use"] == 0
-    assert loads["state_slot_bytes"] == 6 * (16 * 128 * 4 + 3 * 256 * 4)
+    # the tail's 6 rows of 128 take a whole float32 tile of 8
+    assert loads["state_slot_bytes"] == 6 * (16 * 128 * 4 + 8 * 128 * 4)
     assert loads["kda_decode"] == "xla" and "linattn_decode" not in loads
     assert loads["latent_cache"]["entry_bytes_published"] == (64 + 16) * 4
     assert loads["latent_cache"]["entry_bytes_laid_out"] == 128 * 4

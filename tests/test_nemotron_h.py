@@ -21,6 +21,7 @@ from smg_tpu.models import get_model
 from smg_tpu.models import nemotron_h as M
 from smg_tpu.models.config import ModelConfig, tiny_nemotron_h_config
 from smg_tpu.ops import moe, ssm
+from smg_tpu.ops.linear_attention import conv_token
 from smg_tpu.ops.pallas import ssm_decode as kernel
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "benchmark"))
@@ -234,8 +235,7 @@ def test_the_convolution_takes_its_bias_and_keeps_the_last_real_inputs():
     pre = sum(full[:, i:i + 10] * w[i] for i in range(4)) + b
     assert np.allclose(y, pre / (1 + np.exp(-pre)), atol=1e-5)
     assert np.array_equal(new[0], x[0, 7:10]) and np.array_equal(new[1], full[1, 4:7])
-    y1, step = ssm.conv_step(jnp.asarray(x[:, 0]), jnp.asarray(tail), jnp.asarray(w),
-                             jnp.asarray(b))
+    y1, step = conv_token(jnp.asarray(x[:, 0]), jnp.asarray(tail), jnp.asarray(w), jnp.asarray(b))
     assert np.allclose(y1, y[:, 0], atol=1e-5) and np.array_equal(step[:, -1], x[:, 0])
 
 

@@ -288,7 +288,7 @@ def test_convolution_tail_holds_the_last_real_inputs():
     # one token at a time gives the same outputs and the same tail
     t = jnp.asarray(tail)
     for j in range(5):
-        yj, t = la.conv_step(x[:, j], t, w)
+        yj, t = la.conv_token(x[:, j], t, w)
         assert np.allclose(yj, y[:, j], atol=1e-5)
     assert np.array_equal(t[1], new[1])
 

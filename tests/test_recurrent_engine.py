@@ -91,7 +91,8 @@ def test_streams_are_the_references_through_chunks_groups_and_frames(engine):
     loads = engine.loads()
     assert loads["lookahead_kept"] > 0 and loads["audit"]["clean"]
     assert loads["state_slots_total"] == 8 + 8 and loads["state_slots_in_use"] == 0
-    assert loads["state_slot_bytes"] == 4 * (4 * 16 * 32 * 4 + 3 * 256 * 4)
+    # the tail's 6 rows of 128 take a whole float32 tile of 8
+    assert loads["state_slot_bytes"] == 4 * (4 * 16 * 32 * 4 + 8 * 128 * 4)
 
 
 def test_a_reused_slot_starts_from_zero(engine):

@@ -1,8 +1,9 @@
-"""Whether the recurrent runner's programs of ``models/nemotron_h.py`` and of
-``models/kimi_linear.py`` compile for a TPU v5e, at the widths of the
-benchmark's cuts (``test_tpu_compile.py`` says what such a compile shows and
-what it does not), and what the compiled chunked prefill form of the two delta
-rules holds (``ops/linear_attention.py``)."""
+"""Whether the recurrent runner's programs of ``models/nemotron_h.py``, of
+``models/kimi_linear.py`` and of ``models/olmo_hybrid.py`` compile for a TPU
+v5e, at the widths of the benchmark's cuts (``test_tpu_compile.py`` says what
+such a compile shows and what it does not), what the compiled chunked prefill
+form of the two delta rules holds and how the programs reach the convolution's
+tail pool (``ops/linear_attention.py``)."""
 
 import functools
 import re
@@ -44,6 +45,31 @@ def assert_sub_block_form(hlo: str, scope: str, chunks: int, dk: int = 0):
     if dk:
         assert re.search(rf"\[[\d,]*{SUB},{SUB},{dk}\]", hlo)
         assert not re.search(rf"\[[\d,]*{CHUNK},{CHUNK},{dk}\]", hlo)
+
+
+def assert_tails_move_as_whole_blocks(hlo: str, cp, moves: int):
+    """A decode program reaches the tail pool ``cp`` a slot's block at a time
+    (ISSUE 54): ``moves`` slices of ``[1, 1, R, W]`` out of it and as many
+    updates of one into it (a lane a state layer written out), ``W`` whole
+    128-lane tiles and the slot no tiled axis, each update where the pool lies
+    (no copy of the whole pool)."""
+    dims = lambda shape: ",".join(str(d) for d in shape)
+    pool, block = dims(cp.shape), dims((1, 1, *cp.shape[2:]))
+    assert len(cp.shape) == 4 and cp.shape[3] % 128 == 0
+    assert len(re.findall(rf"= bf16\[{block}\]\S* dynamic-slice\(", hlo)) == moves
+    assert len(re.findall(rf"= bf16\[{pool}\]\S* dynamic-update-slice\(", hlo)) == moves
+    assert [line for line in _relayouts(hlo, cp.size) if f"bf16[{pool}]" in line] == []
+
+
+def assert_pool_updates_in_place(compiled, hlo: str, cp):
+    """A prefill program still writes its rows' blocks (or flat rows) into the
+    donated tail pool where it lies: the pool is an aliased output, a block
+    goes in with a ``dynamic-update-slice``, and nothing copies the whole
+    pool."""
+    pool = ",".join(str(d) for d in cp.shape)
+    assert re.search(rf"= bf16\[{pool}\]\S* dynamic-update-slice\(", hlo)
+    assert [line for line in _relayouts(hlo, cp.size) if f"bf16[{pool}]" in line] == []
+    assert compiled.memory_analysis().alias_size_in_bytes >= cp.size * 2
 
 
 @pytest.mark.parametrize("rule,G,T,H,dk,dv", [
@@ -122,6 +148,8 @@ class TestStateSpaceModelCompilesForV5e:
         assert kernel_calls(hlo) == {"smg.attn.decode": 1, "smg.moe.experts": 10}
         # the state-space step gives two results (``kernel_calls`` reads one)
         assert len(re.findall(r"%smg\.ssm\.decode\.\d+ = \(.*?\) custom-call\(", hlo)) == 5
+        # this model's tails are still a flat row a slot (``M.state_shapes`` says why)
+        assert cp.shape == (5, 73, 3 * 10240)
 
     def test_a_prefill_of_a_steps_budget_fits_its_workspace(self, v5e):
         """Two rows of 2,048 tokens, cold: the chunked scan's weights of one
@@ -138,6 +166,7 @@ class TestStateSpaceModelCompilesForV5e:
             s((1, P, PS, 256)), s((G, mp), i32), sp, cp, s((G,), i32)).compile()
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < M.prefill_workspace_bytes(cfg, G * T, "bfloat16") < 3 * 2**30
+        assert_pool_updates_in_place(compiled, compiled.as_text(), cp)
 
 
 class TestKimiLinearCompilesForV5e:
@@ -202,6 +231,7 @@ class TestKimiLinearCompilesForV5e:
         # the KDA step gives two results (``kernel_calls`` reads one)
         assert len(re.findall(r"%smg\.kda\.decode\.\d+ = \(.*?\) custom-call\(", hlo)) == 6
         assert "smg.linattn.decode" not in hlo
+        assert_tails_move_as_whole_blocks(hlo, cp, 6 * B)
 
     @pytest.mark.parametrize("G,T,cold", [(8, 512, True), (2, 2048, True), (1, 2048, False)])
     def test_a_prefill_of_a_steps_budget_fits_its_workspace(self, v5e, G, T, cold):
@@ -227,6 +257,7 @@ class TestKimiLinearCompilesForV5e:
         calls = kernel_calls(hlo)
         assert ("smg.attn.prefill" in calls) == cold and calls["smg.moe.experts"] > 0
         assert_sub_block_form(hlo, "smg.kda.prefill", T // 64, cfg.linear_key_head_dim)
+        assert_pool_updates_in_place(compiled, hlo, cp)
 
     def test_the_one_row_of_1536_tokens_is_the_shape_the_compiler_refuses(self, v5e):
         """Why ``kimi_linear.OCTAVE_RUNGS_ONLY``: the expert layer's gather of
@@ -240,3 +271,62 @@ class TestKimiLinearCompilesForV5e:
                 p, cfg, None, *a, no_ctx=True, attn_impl="pallas", moe_impl="pallas")).lower(
                 params, s((1, 1536), i32), s((1,), i32), s((1,), i32), s((3, 60000, PS, 640)),
                 s((3, 0, PS, 0)), s((1, 512), i32), sp, cp, s((1,), i32)).compile()
+
+
+class TestOlmoHybridCompilesForV5e:
+    """``benchmark/configs/olmo-hybrid-7b.json``: four periods of three
+    gated-delta layers (30 heads of 96 and 192; 11,520 convolution channels,
+    which no width lays out in whole tiles of bfloat16) and one full-attention
+    layer of 30 heads."""
+
+    @staticmethod
+    def shapes(v5e):
+        from smg_tpu.models import olmo_hybrid as M
+
+        cfg = benchmark_cut("olmo-hybrid-7b")
+        one = SingleDeviceSharding(v5e[0])
+        s = lambda shape, dtype=BF16: jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one)
+        params = jax.tree.map(
+            lambda x: s(x.shape, x.dtype),
+            jax.eval_shape(functools.partial(M.init_params, cfg), jax.random.PRNGKey(0)))
+        s_shape, c_shape = M.state_shapes(cfg, 73)
+        return M, cfg, s, params, s(s_shape, jnp.float32), s(c_shape)
+
+    def test_a_decode_frame_moves_its_tails_as_whole_blocks(self, v5e):
+        """A frame of 16 lanes: the periods are one scan and so are a period's
+        linear layers, so the compiled text holds one linear layer: the gated
+        delta step once, and a slice and an update of the tail pool a lane."""
+        from smg_tpu.ops.attention import land_side_buffers
+
+        M, cfg, s, params, sp, cp = self.shapes(v5e)
+        assert cp.shape == (12, 73, 15, 2304)
+        i32 = jnp.int32
+        B, mp, N, P, KD = 16, 512, 8, 4096, cfg.num_kv_heads * cfg.head_dim
+
+        def frame(p, inv_freq, tok, entry, kc, vc, tables, sp, cp, slots, n_steps):
+            runs = slots > 0
+
+            def body(c):
+                j, cur, hk, hv, sp, cp = c
+                logits, hk, hv, sp, cp = M.forward_decode_horizon(
+                    p, cfg, inv_freq, cur, entry + j, entry, j, kc, vc, tables, hk, hv, sp, cp,
+                    slots, runs, attn_impl="pallas", linattn_impl="pallas")
+                return j + 1, jnp.argmax(logits, -1).astype(i32), hk, hv, sp, cp
+
+            side = jnp.zeros((4, B, N, KD), kc.dtype)
+            j, cur, hk, hv, sp, cp = jax.lax.while_loop(
+                lambda c: c[0] < n_steps, body, (i32(0), tok, side, side, sp, cp))
+            kc, vc = land_side_buffers(kc, vc, hk, hv, tables, entry, jnp.arange(N)[None] < j)
+            return cur, kc, vc, sp, cp
+
+        compiled = jax.jit(frame, donate_argnums=(4, 5, 7, 8)).lower(
+            params, s((cfg.head_dim // 2,), jnp.float32), s((B,), i32), s((B,), i32),
+            s((4, P, PS, KD)), s((4, P, PS, KD)), s((B, mp), i32), sp, cp, s((B,), i32),
+            s((), i32)).compile()
+        # the full layers' three projections laid out again once a frame, 118 MB
+        # each (ROADMAP S13), are the temporaries: no pool is among them
+        assert compiled.memory_analysis().temp_size_in_bytes < 512 * 2**20
+        hlo = compiled.as_text()
+        # a period's three linear layers are a scan inside the scan over periods
+        assert len(re.findall(r"%smg\.linattn\.decode\.\d+ = \(.*?\) custom-call\(", hlo)) == 1
+        assert_tails_move_as_whole_blocks(hlo, cp, B)
