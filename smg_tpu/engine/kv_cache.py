@@ -127,6 +127,11 @@ class KvCacheSpec:
     latent_lanes: int = 0
     # values narrower than keys: the V buffer's lanes.  0: as many as K's.
     v_lanes: int = 0
+    # a latent cache whose tokens leave an index key besides, in
+    # ``index_layers`` of the layers (``ops/sparse_attention.py``): the second
+    # buffer holds those, ``index_lanes`` wide, on the same pages.  0: none.
+    index_layers: int = 0
+    index_lanes: int = 0
 
     @property
     def lanes(self) -> int:
@@ -139,17 +144,21 @@ class KvCacheSpec:
 
     @property
     def v_shape(self) -> tuple[int, ...]:
-        """The V buffer's shape: of zero size where the cache is latent."""
+        """The V buffer's shape: where the cache is latent, the index keys'
+        (of zero size for a model that has none)."""
+        if self.latent_lanes and self.index_layers:
+            return (self.index_layers, self.num_pages, self.page_size, self.index_lanes)
         if self.latent_lanes:
             return (self.num_layers, 0, self.page_size, self.lanes)
         return (*self.shape[:3], self.v_lanes or self.lanes)
 
     @property
     def bytes_per_page(self) -> int:
-        # k + v (or the one latent buffer), all layers
+        # k + v (or the one latent buffer and the index keys), all layers
         itemsize = jnp.dtype(self.dtype).itemsize
         lanes = self.lanes + (0 if self.latent_lanes else self.v_lanes or self.lanes)
-        return self.num_layers * self.page_size * lanes * itemsize
+        return (self.num_layers * lanes + self.index_layers * self.index_lanes) \
+            * self.page_size * itemsize
 
 
 @dataclass
@@ -272,7 +281,9 @@ def plan_latent_cache(
 ) -> KvCacheSpec:
     """Pages of a latent cache: one buffer of ``entry_lanes`` a token and
     cache layer (``model.num_cache_layers``: one for every attention
-    sublayer, which is not every model's depth).  ``hbm_limit`` and
+    sublayer, which is not every model's depth), and where some layers have
+    an indexer (``model.num_index_layers``) a second buffer of their index
+    keys on the same pages, sized from the same budget.  ``hbm_limit`` and
     ``hbm_in_use`` are the tightest device's, read
     **after the weights are on it**, so the weights come off once, as in
     ``plan_recurrent_cache``.  ``workspace``: bytes the largest program needs
@@ -288,6 +299,8 @@ def plan_latent_cache(
         head_dim=model.head_dim,
         dtype=cache.dtype,
         latent_lanes=entry_lanes(model.kv_lora_rank, model.qk_rope_head_dim),
+        index_layers=model.num_index_layers,
+        index_lanes=model.index_head_dim if model.num_index_layers else 0,
     )
     if cache.auto_size and hbm_limit is not None:
         budget = int(hbm_limit * cache.hbm_utilization) - hbm_in_use - workspace

@@ -1,16 +1,21 @@
 """The runner of a model whose cache holds one latent entry a token and no V.
 
-``LatentModelRunner`` is ``ModelRunner`` for ``models/pangu_moe.py`` and
+``LatentModelRunner`` is ``ModelRunner`` for ``models/pangu_moe.py``,
 ``models/longcat_flash.py`` (two attention sublayers a layer: the cache and the
-side buffer are ``cfg.num_cache_layers`` deep, not ``cfg.num_layers``).  The
-cache is one buffer (``kv_cache.plan_latent_cache``: sized from the device
-read after the weights are on it, so the weights come off once); ``v_cache``
-stays an attribute, of zero size, and goes through every program untouched,
-so the three prefill families are ``ModelRunner``'s own programs, names and
-positional signatures.  The decode family is this file's: its side buffer
-holds latent entries and lands through ``land_side_buffer``, a padded lane
-picks no expert, and the expert layers' counts ride out with the frame's
-tokens (``frame_counts``; the scheduler fetches them with the tokens).
+side buffer are ``cfg.num_cache_layers`` deep, not ``cfg.num_layers``) and
+``models/glm_moe_dsa.py`` (a learned selector chooses the cached tokens a
+query reads).  The cache is one buffer (``kv_cache.plan_latent_cache``: sized
+from the device read after the weights are on it, so the weights come off
+once); ``v_cache`` stays an attribute and goes through every program, so the
+three prefill families are ``ModelRunner``'s own programs, names and
+positional signatures.  It is of zero size and untouched, but for a model
+with indexers (``cfg.num_index_layers``): there it holds the index keys of
+those layers, on the latent cache's own pages and sized with it from one
+budget, and the prefill forwards write it where they write the entries.  The
+decode family is this file's: its side buffer holds latent entries (and a
+second one the frame's index keys) and lands through ``land_side_buffer``, a
+padded lane picks no expert, and the expert layers' counts ride out with the
+frame's tokens (``frame_counts``; the scheduler fetches them with the tokens).
 
 Where the decode kernel runs it reads each lane's own pages by the lane's
 ``entry``, whatever the table's width, so a decode program is compiled for
@@ -40,10 +45,18 @@ class LatentModelRunner(ModelRunner):
         # ragged product elsewhere
         self._bind_moe_impl("pallas" if self.platform == "tpu"
                             and config.attention_impl != "xla" else "xla")
+        itemsize = jnp.dtype(self.spec.dtype).itemsize
         logger.info("latent cache: %d lanes an entry (%d B a token and layer as laid out); "
-                    "expert layers %s, experts held %s of %d",
-                    self.spec.lanes, self.spec.lanes * jnp.dtype(self.spec.dtype).itemsize,
+                    "second buffer: %s; expert layers %s, experts held %s of %d",
+                    self.spec.lanes, self.spec.lanes * itemsize,
+                    f"index keys of {self.spec.index_layers} layers, {self.spec.index_lanes} "
+                    f"lanes ({self.spec.index_lanes * itemsize} B a token and layer)"
+                    if self.spec.index_layers else "of zero size (no V, no index keys)",
                     self.moe_impl, self.model_cfg.held_experts, self.model_cfg.num_experts)
+        # rows that attended in prefill launches, and those behind more than
+        # ``index_topk`` tokens; the decode frames count theirs on the device
+        self._dsa = {"prefill_rows": 0, "prefill_rows_selecting": 0,
+                     "prefill_index_tokens_scored": 0}
 
     # ---- what a sequence holds ----
 
@@ -56,17 +69,47 @@ class LatentModelRunner(ModelRunner):
             limit, in_use = stats["bytes_limit"], stats.get("bytes_in_use", 0)
         elif self.platform == "tpu":
             raise RuntimeError("the TPU reports no memory_stats(); cannot size the cache")
+        sched = self.config.scheduler
+        # a selector scores a chunk's queries over the whole table
+        over = {"context": sched.max_seq_len} if self.model_cfg.num_index_layers else {}
         workspace = self.module.prefill_workspace_bytes(
-            self.model_cfg, self.config.scheduler.max_prefill_tokens, self.config.dtype)
+            self.model_cfg, sched.max_prefill_tokens, self.config.dtype, **over)
         return plan_latent_cache(self.model_cfg, self.config.cache, limit, in_use, workspace)
 
     def latent_info(self) -> dict:
         cfg, itemsize = self.model_cfg, jnp.dtype(self.spec.dtype).itemsize
+        second = "no V buffer"
+        index = {}
+        if self.spec.index_layers:
+            second = (f"no V buffer; index keys [{self.spec.index_layers}, pages, "
+                      f"{self.spec.page_size}, {self.spec.index_lanes}] on the same pages")
+            index = {"index_key_bytes": self.spec.index_lanes * itemsize,
+                     "index_layers": self.spec.index_layers}
         return {"entry_bytes_published": (cfg.kv_lora_rank + cfg.qk_rope_head_dim) * itemsize,
-                "entry_bytes_laid_out": self.spec.lanes * itemsize,
+                "entry_bytes_laid_out": self.spec.lanes * itemsize, **index,
                 "layout": f"one buffer [layers, pages, {self.spec.page_size}, "
                           f"{self.spec.lanes}]: latent {cfg.kv_lora_rank}, shared key "
-                          f"{cfg.qk_rope_head_dim}, padded to whole 128-lane tiles; no V buffer"}
+                          f"{cfg.qk_rope_head_dim}, padded to whole 128-lane tiles; {second}"}
+
+    def dsa_info(self) -> "dict | None":
+        """What the selector did in prefill launches (host counts of the rows
+        launched; the decode frames' are in ``loads()["moe"]``, counted on the
+        device): None for a model without one."""
+        if not self.model_cfg.num_index_layers:
+            return None
+        return {"index_topk": self.model_cfg.index_topk, **self._dsa}
+
+    def _count_prefill_rows(self, chunks) -> None:
+        """``chunks``: (new tokens, prefix length) of the rows of one launch."""
+        cfg = self.model_cfg
+        if not cfg.num_index_layers:
+            return
+        for n, lo in chunks:
+            self._dsa["prefill_rows"] += n
+            self._dsa["prefill_rows_selecting"] += max(min(n, lo + n - cfg.index_topk), 0)
+            # row ``t`` scores ``t + 1`` cached tokens, in every layer with an indexer
+            self._dsa["prefill_index_tokens_scored"] += \
+                cfg.num_index_layers * ((lo + n) * (lo + n + 1) - lo * (lo + 1)) // 2
 
     def moe_info(self) -> dict:
         cfg = self.model_cfg
@@ -83,6 +126,10 @@ class LatentModelRunner(ModelRunner):
     def widest_table_only(self) -> bool:
         """Decode programs are compiled at the widest page table alone."""
         return self._attn_impl_for(0, 0) == "pallas"
+
+    def prefill(self, token_ids, prefix_len, page_table, *a, **kw):
+        self._count_prefill_rows([(len(token_ids), prefix_len)])
+        return super().prefill(token_ids, prefix_len, page_table, *a, **kw)
 
     def _prefill_impl_for(self, T: int, mp: int) -> str:
         """A solo chunk attends expanded in XLA's form over what its pages
@@ -137,6 +184,7 @@ class LatentModelRunner(ModelRunner):
                               mask=None, lora_idx=None, mm=None, rope=None):
         if lora_idx is not None or mm is not None or rope is not None:
             raise ValueError(self.module.SERVING_LIMITS["lora"])
+        self._count_prefill_rows([(len(c[0]), c[1]) for c in chunks])
         return super().prefill_batched_async(chunks, temps, topks, topps, minps,
                                              pen=pen, mask=mask)
 
@@ -165,6 +213,7 @@ class LatentModelRunner(ModelRunner):
         """A chunk that is not the prompt's last runs the ``prefill`` program
         (one program a bucket instead of two) with the unfolded key, so the
         key counter stands still, and fetches nothing."""
+        self._count_prefill_rows([(len(token_ids), prefix_len)])
         T, mp, _lora, _ring, host = self._prefill_chunk_prep(
             token_ids, prefix_len, page_table, lora_idx, mm, rope_pos)
         fn, up = self._prefill_fn(T, mp), self.upload
@@ -180,11 +229,15 @@ class LatentModelRunner(ModelRunner):
                          use_pen: bool = False, use_mask: bool = False,
                          use_lora: bool = False, use_mrope: bool = False):
         """This model's decode frame, for ``ModelRunner._decode_frame_fn``'s
-        loop: one latent side buffer."""
+        loop: one latent side buffer, and for a model with indexers a second
+        one, the frame's index keys, which lands in ``vc`` as the first lands
+        in ``kc`` (the column then takes the caches and the side buffers as
+        pairs)."""
         if use_lora or use_mrope:
             raise ValueError(self.module.SERVING_LIMITS["lora"])
         cfg, module = self.model_cfg, self.module
         L, lanes = cfg.num_cache_layers, self.spec.lanes
+        indexed = self.spec.index_layers > 0
 
         def frame(params, inv_freq, entry_pos, kc, vc, page_tables, *, attn_impl, arms):
             # a padded lane sits past its table (``Scheduler._launch_frame``)
@@ -193,12 +246,18 @@ class LatentModelRunner(ModelRunner):
             def column(cur, j, side):
                 return module.forward_decode_horizon(
                     params, cfg, inv_freq, cur, entry_pos + j, entry_pos, j,
-                    kc, page_tables, side, holds, attn_impl=attn_impl)
+                    (kc, vc) if indexed else kc, page_tables, side, holds, attn_impl=attn_impl)
 
             def land(side, ran, _last):
+                if indexed:
+                    return tuple(land_side_buffer(c, s, page_tables, entry_pos, ran)
+                                 for c, s in zip((kc, vc), side)), None
                 return (land_side_buffer(kc, side, page_tables, entry_pos, ran), vc), None
 
-            return jnp.zeros((L, B, N, lanes), kc.dtype), one_token_column(column), land
+            side0 = jnp.zeros((L, B, N, lanes), kc.dtype)
+            if indexed:
+                side0 = (side0, jnp.zeros((vc.shape[0], B, N, vc.shape[3]), vc.dtype))
+            return side0, one_token_column(column), land
 
         return self._decode_frame_fn(B, mp, N, E, use_pen, use_mask, frame,
                                      variant=(self.moe_impl,))

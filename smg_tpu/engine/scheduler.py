@@ -554,6 +554,13 @@ class Scheduler:
             out["moe"] = {**self.runner.moe_info(), **self.moe_counts}
         if hasattr(self.runner, "latent_info"):
             out["latent_cache"] = self.runner.latent_info()
+        dsa = getattr(self.runner, "dsa_info", lambda: None)()
+        if dsa is not None:
+            # a learned selector over the cache: rows that attended and those
+            # behind more than ``index_topk`` tokens, cached tokens scored;
+            # prefill launches by the host's count, decode frames by the device's
+            decode = {k[4:]: v for k, v in self.moe_counts.items() if k.startswith("dsa_")}
+            out["dsa"] = {**dsa, **{f"decode_{k}": v for k, v in decode.items()}}
         if self.metrics is not None:
             # rolling-window live signal (p50/p95 step time, tokens/s) for
             # the /scheduler endpoint, dp-aware routing, and benchmarks

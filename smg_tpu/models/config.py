@@ -155,6 +155,18 @@ class ModelConfig:
     # ``rope_theta`` 0: the key the heads share is not rotated).  Such a model
     # is ``recurrent`` and has a ``latent_cache``: state slots beside latent
     # pages (``engine/recurrent_runner.py``).
+    # ---- a learned selector over the latent cache (``glm_moe_dsa``).  A layer
+    # of ``indexer_types`` "full" has an indexer: ``index_n_heads`` query heads
+    # of ``index_head_dim`` against one cached key of that width a token, whose
+    # weighted scores choose the ``index_topk`` cached tokens the layer's
+    # attention reads; a "shared" layer reads the set of the nearest "full"
+    # layer before it and has neither indexer nor index keys.  The index keys
+    # are a second paged buffer on the latent cache's page tables, one layer
+    # for every "full" layer (``num_index_layers``).  0 / None: no selector.
+    index_topk: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    indexer_types: "tuple[str, ...] | None" = None
 
     @property
     def window_cache(self) -> bool:
@@ -166,6 +178,12 @@ class ModelConfig:
     def latent_cache(self) -> bool:
         """A token leaves one latent entry in the cache and no V."""
         return self.kv_lora_rank > 0
+
+    @property
+    def num_index_layers(self) -> int:
+        """Layers with an indexer of their own: each keeps one index key a
+        token in the second paged buffer."""
+        return sum(1 for t in self.indexer_types or () if t == "full")
 
     @property
     def rope_dim(self) -> int:
@@ -227,6 +245,8 @@ class ModelConfig:
             return cls._from_nemotron_h(cfg, dtype)
         if cfg.get("model_type") == "kimi_linear":
             return cls._from_kimi_linear(cfg, dtype)
+        if cfg.get("model_type") == "glm_moe_dsa":
+            return cls._from_glm_moe_dsa(cfg, dtype)
         # keys that change what the layers compute and that this path would
         # drop in silence: routed experts beyond Qwen-MoE's settings, latent
         # attention.  A config that carries one is another model (D6's rule).
@@ -1035,6 +1055,129 @@ class ModelConfig:
         layout(out)  # the stack this program runs: a sentence for any other
         return out
 
+    # ``glm_moe_dsa`` (GLM-5.2): the same rule as above.
+    _GLM_DSA_CONSUMED = frozenset({
+        "model_type", "vocab_size", "hidden_size", "intermediate_size",
+        "moe_intermediate_size", "num_hidden_layers", "num_attention_heads",
+        "num_key_value_heads", "head_dim", "qk_head_dim", "hidden_act",
+        "max_position_embeddings", "attention_bias", "rms_norm_eps", "tie_word_embeddings",
+        "rope_parameters", "rope_interleave", "q_lora_rank", "kv_lora_rank",
+        "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim", "first_k_dense_replace",
+        "mlp_layer_types", "moe_layer_freq", "n_routed_experts", "n_shared_experts",
+        "num_experts_per_tok", "routed_scaling_factor", "norm_topk_prob", "scoring_func",
+        "topk_method", "n_group", "topk_group", "ep_size", "num_nextn_predict_layers",
+        "index_topk", "index_n_heads", "index_head_dim", "indexer_types",
+        "indexer_rope_interleave", "index_share_for_mtp_iteration",
+        # the rule the published ``indexer_types`` was written from; the list
+        # is the truth and these three are read and checked for nothing
+        "index_skip_topk_offset", "index_topk_freq", "index_topk_pattern",
+        "eos_token_id", "bos_token_id",
+        # the chip's share of a deployment, as for ``pangu_ultra_moe``
+        "router_num_experts", "routed_expert_offset",
+    })
+
+    @classmethod
+    def _from_glm_moe_dsa(cls, cfg: dict, dtype: str) -> "ModelConfig":
+        unknown = sorted(set(cfg) - cls._GLM_DSA_CONSUMED - cls._OLMO_HYBRID_SHAPELESS)
+        if unknown:
+            raise ValueError(
+                f"glm_moe_dsa config.json has keys this loader does not consume: {unknown}; "
+                "a key that may bear on the model's shape is not dropped in silence")
+
+        def only(key, served, default):
+            if cfg.get(key, default) not in served:
+                raise ValueError(f"glm_moe_dsa: {key} {cfg[key]!r} is not served")
+
+        only("hidden_act", ("silu",), "silu")
+        only("attention_bias", (False, None), False)
+        only("scoring_func", ("sigmoid",), "sigmoid")
+        only("topk_method", ("noaux_tc",), "noaux_tc")
+        only("n_group", (1,), 1)
+        only("topk_group", (1,), 1)
+        only("moe_layer_freq", (1,), 1)
+        only("ep_size", (1,), 1)
+        only("rope_interleave", (True,), True)
+        only("indexer_rope_interleave", (True,), True)
+        only("index_topk_pattern", (None,), None)
+        rope = cfg.get("rope_parameters")
+        if not isinstance(rope, dict) or set(rope) - {"rope_theta", "rope_type"} \
+                or rope.get("rope_type", "default") != "default" or "rope_theta" not in rope:
+            raise ValueError(f"glm_moe_dsa: rope_parameters {rope!r} is not served (a table "
+                             "of rope_theta and rope_type 'default')")
+        heads = cfg["num_attention_heads"]
+        if cfg.get("num_key_value_heads", heads) != heads:
+            raise ValueError("glm_moe_dsa: latent attention has one latent for all heads; "
+                             "num_key_value_heads must equal num_attention_heads")
+        dn, dr = cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"]
+        if cfg.get("qk_head_dim", dn + dr) != dn + dr or cfg.get("head_dim", dn) not in (dn, dn + dr):
+            raise ValueError(f"glm_moe_dsa: qk_head_dim {cfg.get('qk_head_dim')} and head_dim "
+                             f"{cfg.get('head_dim')} do not follow from qk_nope_head_dim {dn} "
+                             f"and qk_rope_head_dim {dr}")
+        layers = cfg["num_hidden_layers"]
+        mlps = cfg.get("mlp_layer_types")
+        dense = cfg.get("first_k_dense_replace", 0)
+        if mlps is None:
+            mlps = ["dense"] * dense + ["sparse"] * (layers - dense)
+        if list(mlps) != ["dense"] * dense + ["sparse"] * (layers - dense) or not 0 <= dense <= layers:
+            raise ValueError(f"glm_moe_dsa: mlp_layer_types {list(mlps)} is not first_k_dense_replace "
+                             f"{dense} dense layers and then sparse ones, {layers} in all")
+        kinds = cfg.get("indexer_types")
+        if (not isinstance(kinds, (list, tuple)) or len(kinds) != layers
+                or set(kinds) - {"full", "shared"} or kinds[0] != "full"):
+            raise ValueError(f"glm_moe_dsa: indexer_types {kinds!r} is not a list of 'full' and "
+                             f"'shared', one a layer ({layers}), that starts 'full'")
+        topk, ih, idim = cfg["index_topk"], cfg["index_n_heads"], cfg["index_head_dim"]
+        if topk < 1 or ih < 1 or idim < dr:
+            raise ValueError(f"glm_moe_dsa: index_topk {topk}, index_n_heads {ih}, index_head_dim "
+                             f"{idim} (the rotary lanes are its first {dr})")
+        held = cfg["n_routed_experts"]
+        width = cfg.get("router_num_experts", held)
+        first = cfg.get("routed_expert_offset", 0)
+        if not (0 <= first and first + held <= width):
+            raise ValueError(
+                f"glm_moe_dsa: experts {first}..{first + held - 1} are not among "
+                f"the router's {width}")
+        # ``num_nextn_predict_layers`` and ``index_share_for_mtp_iteration``:
+        # the next-token module is a drafter the model's own logits do not
+        # depend on; consumed here, neither loaded nor served.
+        eos = cfg.get("eos_token_id", 2)
+        return cls(
+            arch="glm_moe_dsa",
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=layers,
+            num_heads=heads,
+            num_kv_heads=heads,
+            head_dim=dn + dr,
+            rope_theta=float(rope["rope_theta"]),
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
+            max_position_embeddings=cfg.get("max_position_embeddings", 8192),
+            tie_word_embeddings=bool(cfg.get("tie_word_embeddings", False)),
+            eos_token_ids=tuple(eos) if isinstance(eos, list) else (eos,),
+            bos_token_id=cfg.get("bos_token_id", 1),
+            dtype=dtype,
+            num_experts=width,
+            num_experts_per_tok=cfg["num_experts_per_tok"],
+            moe_intermediate_size=cfg["moe_intermediate_size"],
+            q_lora_rank=cfg["q_lora_rank"],
+            kv_lora_rank=cfg["kv_lora_rank"],
+            qk_nope_head_dim=dn,
+            qk_rope_head_dim=dr,
+            v_head_dim=cfg["v_head_dim"],
+            first_k_dense_replace=dense,
+            n_shared_experts=cfg.get("n_shared_experts", 0),
+            moe_scoring="sigmoid",
+            norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
+            routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
+            experts_held=(first, held),
+            moe_select_bias=True,
+            index_topk=topk,
+            index_n_heads=ih,
+            index_head_dim=idim,
+            indexer_types=tuple(kinds),
+        )
+
     @classmethod
     def from_pretrained(cls, path: str, dtype: str = "bfloat16") -> "ModelConfig":
         with open(os.path.join(path, "config.json")) as f:
@@ -1207,6 +1350,42 @@ def tiny_longcat_flash_config(vocab_size: int = 512, held: "tuple[int, int] | No
     )
 
 
+def tiny_glm_dsa_config(vocab_size: int = 512, held: "tuple[int, int] | None" = None,
+                        **changes) -> ModelConfig:
+    """Tiny GLM-5.2 for CPU tests: a dense layer and four expert layers whose
+    indexers are full, full, shared, shared, shared; latent attention whose
+    cache entry (96 + 32) fills one 128-lane tile; an indexer of 4 heads of 32
+    (rotary on the first 16 lanes) that chooses 16 cached tokens; a router of
+    16 experts (top 4, of which ``held`` are here; None: all) and one shared."""
+    import dataclasses
+
+    return dataclasses.replace(
+        tiny_test_config(vocab_size),
+        arch="glm_moe_dsa",
+        num_layers=5,
+        num_heads=4,
+        num_kv_heads=4,
+        head_dim=48,
+        num_experts=16,
+        num_experts_per_tok=4,
+        moe_intermediate_size=64,
+        q_lora_rank=64,
+        kv_lora_rank=96,
+        qk_nope_head_dim=32,
+        qk_rope_head_dim=16,
+        v_head_dim=32,
+        first_k_dense_replace=1,
+        n_shared_experts=1,
+        moe_scoring="sigmoid",
+        norm_topk_prob=True,
+        routed_scaling_factor=2.5,
+        experts_held=held,
+        moe_select_bias=True,
+        **{"index_topk": 16, "index_n_heads": 4, "index_head_dim": 32,
+           "indexer_types": ("full", "full", "shared", "shared", "shared"), **changes},
+    )
+
+
 def tiny_nemotron_h_config(vocab_size: int = 512, held: "tuple[int, int] | None" = None,
                            pattern: str = "MEM*EME", **changes) -> ModelConfig:
     """Tiny Nemotron-H for CPU tests: ``pattern`` of state-space (``M``: 4
@@ -1318,6 +1497,32 @@ def kimi_linear_48b_a3b_config() -> ModelConfig:
         "linear_attn_config": {
             "kda_layers": kda, "full_attn_layers": [l for l in range(1, 28) if l not in kda],
             "head_dim": 128, "num_heads": 32, "short_conv_kernel_size": 4},
+    })
+
+
+def glm_5_2_config() -> ModelConfig:
+    """GLM-5.2 as published (78 layers, 256 experts, 154,880 rows): 1.5 TB in
+    bfloat16, so no single chip serves it whole;
+    ``benchmark/configs/glm-5.2.json`` is one chip's share."""
+    kinds = ["full" if l < 3 or (l - 3) % 4 == 3 else "shared" for l in range(78)]
+    return ModelConfig.from_hf_config({
+        "model_type": "glm_moe_dsa", "vocab_size": 154880, "hidden_size": 6144,
+        "intermediate_size": 12288, "moe_intermediate_size": 2048, "num_hidden_layers": 78,
+        "num_attention_heads": 64, "num_key_value_heads": 64, "head_dim": 192,
+        "qk_head_dim": 256, "hidden_act": "silu", "rms_norm_eps": 1e-5,
+        "max_position_embeddings": 1048576, "attention_bias": False,
+        "tie_word_embeddings": False, "rope_interleave": True,
+        "rope_parameters": {"rope_theta": 8000000, "rope_type": "default"},
+        "q_lora_rank": 2048, "kv_lora_rank": 512, "qk_nope_head_dim": 192,
+        "qk_rope_head_dim": 64, "v_head_dim": 256, "first_k_dense_replace": 3,
+        "mlp_layer_types": ["dense"] * 3 + ["sparse"] * 75, "moe_layer_freq": 1,
+        "n_routed_experts": 256, "n_shared_experts": 1, "num_experts_per_tok": 8,
+        "routed_scaling_factor": 2.5, "norm_topk_prob": True, "scoring_func": "sigmoid",
+        "topk_method": "noaux_tc", "n_group": 1, "topk_group": 1, "ep_size": 1,
+        "num_nextn_predict_layers": 1, "index_topk": 2048, "index_n_heads": 32,
+        "index_head_dim": 128, "indexer_types": kinds, "indexer_rope_interleave": True,
+        "index_share_for_mtp_iteration": True, "index_skip_topk_offset": 3,
+        "index_topk_freq": 4, "index_topk_pattern": None,
     })
 
 
@@ -1443,7 +1648,9 @@ PRESETS = {
     "tiny-longcat-flash": tiny_longcat_flash_config,
     "tiny-nemotron-h": tiny_nemotron_h_config,
     "tiny-kimi-linear": tiny_kimi_linear_config,
+    "tiny-glm-dsa": tiny_glm_dsa_config,
     "kimi-linear-48b-a3b": kimi_linear_48b_a3b_config,
+    "glm-5.2": glm_5_2_config,
     "llama3.2-1b": llama32_1b_config,
     "llama3-8b": llama3_8b_config,
     "llama3-70b": llama3_70b_config,

@@ -208,8 +208,9 @@ def _scaled(weight, scale: float):
 
 
 def _latent_qkv(layer: Params, cfg: ModelConfig, x, positions, inv_freq):
-    """Queries ``q_nope`` [..., H, dn], ``q_pe`` [..., H, dr] (rotated) and the
-    cache entry ``[c | k_pe | 0]`` [..., W] of the tokens ``x`` [..., E].  A
+    """Queries ``q_nope`` [..., H, dn], ``q_pe`` [..., H, dr] (rotated), the
+    cache entry ``[c | k_pe | 0]`` [..., W] of the tokens ``x`` [..., E], and
+    the queries' normed low-rank vector ``c_q`` [..., rq].  A
     model that scales its two normed low-rank vectors (``cfg.mla_q_scale``,
     ``cfg.mla_kv_scale``; ``models/longcat_flash.py``) has them scaled here,
     behind the norms: the entry then holds the scaled latent, and the rotary
@@ -229,14 +230,19 @@ def _latent_qkv(layer: Params, cfg: ModelConfig, x, positions, inv_freq):
         k_pe = apply_rope(k_pe[..., None, :], positions, inv_freq)[..., 0, :]
         pad = jnp.zeros((*c.shape[:-1], cache_lanes(cfg) - rkv - k_pe.shape[-1]), c.dtype)
         entry = jnp.concatenate([c, k_pe, pad], axis=-1)
-    return q_nope, q_pe, entry
+    return q_nope, q_pe, entry, c_q
 
 
-def latent_attention(layer: Params, cfg: ModelConfig, x, positions, inv_freq, attend, l, state):
+def latent_attention(layer: Params, cfg: ModelConfig, x, positions, inv_freq, attend, l, state,
+                     index=None):
     """One latent attention over the normed tokens ``x`` [..., E], as cache
     layer ``l``: ``W_o`` over the heads' outputs, and the forward's ``state``
-    as ``attend`` changed it."""
-    q_nope, q_pe, entry = _latent_qkv(layer, cfg, x, positions, inv_freq)
+    as ``attend`` changed it.  ``index(x, c_q, state) -> state``: a model
+    whose queries choose the cached tokens they read (``models/glm_moe_dsa.py``)
+    does so here, between the projections and ``attend``."""
+    q_nope, q_pe, entry, c_q = _latent_qkv(layer, cfg, x, positions, inv_freq)
+    if index is not None:
+        state = index(x, c_q, state)
     out, state = attend(q_nope, q_pe, entry, layer, l, state)
     o = jnp.einsum("...f,fe->...e", out.astype(x.dtype).reshape(*x.shape[:-1], -1), layer["wo"])
     return o, state
@@ -348,7 +354,7 @@ def _prefill(params, cfg, inv_freq, tokens, prefix_lens, t_reals, cache, page_ta
         dest = page_slots(page_tables, pos, real, cache.shape[2]).reshape(-1)
     scale = _scale(cfg)
 
-    def expanded(layer, q_nope, q_pe, ctx, pos, ctx_lens):
+    def expanded(layer, q_nope, q_pe, ctx, pos, ctx_lens, *select):
         """Rebuild K and V of the context entries ``ctx`` [g, S, W] and attend."""
         c, k_pe = ctx[..., :rkv], ctx[..., rkv:rkv + dr]
         kernel = attn_impl.startswith("pallas")  # only where ``no_ctx`` calls this
@@ -365,25 +371,30 @@ def _prefill(params, cfg, inv_freq, tokens, prefix_lens, t_reals, cache, page_ta
             return flash_attention_prefill(
                 q_nope, k_nope, v, ctx_lens, scale, q_pe=q_pe, k_pe=k_pe, kv_heads_first=True,
                 interpret=(attn_impl == "pallas_interpret"))
-        return latent_attention_prefill(q_nope, q_pe, k_nope, k_pe, v, pos, ctx_lens, scale)
+        return latent_attention_prefill(q_nope, q_pe, k_nope, k_pe, v, pos, ctx_lens, scale,
+                                        *select)
 
-    def attend(q_nope, q_pe, entry, layer, l, cache):
+    def attend(q_nope, q_pe, entry, layer, l, cache, select=None):
+        """``select`` [G, T, S] bool: the context positions each query reads
+        (``ops/sparse_attention.py``; S the chunk where ``no_ctx``, else the
+        table's width); None reads all."""
         cache = scatter_entries(cache, l, entry.reshape(G * T, -1), dest)
         if not no_ctx:
             # the pages hold the context, the chunk's own entries among them,
             # read back as decode will read them
             return latent_attention_prefill_cached(
                 q_nope, q_pe, cache, l, page_tables, layer["w_uk"], layer["w_uv"], pos,
-                ctx_lens, scale, rkv, dr), cache
+                ctx_lens, scale, rkv, dr, select), cache
         ctx = entry.astype(q_nope.dtype)  # the chunk is the whole context
         kv_bytes = G * T * cfg.num_heads * (cfg.qk_nope_head_dim + cfg.v_head_dim) \
             * ctx.dtype.itemsize
+        chosen = () if select is None else (select,)
         if G > 1 and kv_bytes > EXPANDED_KV_BYTES:
             out = jax.lax.map(
                 lambda r: expanded(layer, *(x[None] for x in r))[0],
-                (q_nope, q_pe, ctx, pos, ctx_lens))
+                (q_nope, q_pe, ctx, pos, ctx_lens, *chosen))
         else:
-            out = expanded(layer, q_nope, q_pe, ctx, pos, ctx_lens)
+            out = expanded(layer, q_nope, q_pe, ctx, pos, ctx_lens, *chosen)
         return out, cache
 
     h = embed_tokens(params, cfg, tokens)
@@ -479,7 +490,10 @@ def forward_decode_horizon(
     rkv = cfg.kv_lora_rank
     scale = _scale(cfg)
 
-    def attend(q_nope, q_pe, entry, layer, l, side):
+    def attend(q_nope, q_pe, entry, layer, l, side, select=None):
+        """``select``: ``(slots, paged, fresh)`` of
+        ``ops/sparse_attention.selected_slots``, the cached and fresh tokens
+        each lane reads; None reads all."""
         B, W = entry.shape
         side = jax.lax.dynamic_update_slice(
             side, entry.reshape(1, B, 1, W).astype(side.dtype), (l, 0, step_idx, 0))
@@ -488,7 +502,13 @@ def forward_decode_horizon(
             q_abs = jnp.einsum("bhd,hcd->bhc", q_nope, layer["w_uk"])
             pad = jnp.zeros((*q_abs.shape[:-1], W - rkv - q_pe.shape[-1]), q_abs.dtype)
             q = jnp.concatenate([q_abs, q_pe, pad], axis=-1)  # on the entry's lanes
-        if attn_impl.startswith("pallas"):
+        if select is not None:
+            from smg_tpu.ops.sparse_attention import attend_selected, gather_selected
+
+            slots, paged, fresh = select
+            ctx = attend_selected(q, gather_selected(k_cache, l, slots), side_l, paged, fresh,
+                                  scale, value_lanes(rkv))
+        elif attn_impl.startswith("pallas"):
             from smg_tpu.ops.pallas.decode_attention import latent_attention_decode_cached
 
             ctx = latent_attention_decode_cached(

@@ -36,6 +36,13 @@ with state beside its pages):
   says); a module with routed experts gives
   ``merge_counts`` and ``ROUTED_COUNTS``, the names of what its frame counts
   (``pangu_moe``'s four; ``longcat_flash`` adds the picks on identity experts);
+- a module whose latent attention reads a learned selection of the cache
+  (``models/glm_moe_dsa.py``: ``cfg.num_index_layers`` > 0) is a module with one
+  latent buffer whose ``v_cache`` is not of zero size: it holds the index keys
+  of the layers with an indexer, on the same page tables, the prefill forwards
+  write both, and the decode column takes the two caches and the two side
+  buffers as pairs and counts the selector's rows beside the experts'
+  (``engine/latent_runner.py``);
 - a module with window layers beside full ones (``models/mimo.py``) takes the
   window layers' rings and the rows' slots after the page tables, as a module
   with state does, and its decode column the four side buffers as one tuple
@@ -55,7 +62,7 @@ _REGISTRY: dict[str, ModuleType] = {}
 # architectures this package brings itself, loaded on first use
 _LLAMA_FAMILY = ("llama", "qwen", "mistral", "qwen_moe")
 _BUILTIN = (*_LLAMA_FAMILY, "olmo_hybrid", "pangu_ultra_moe", "mimo_v2_flash", "exaone_moe",
-            "longcat_flash", "nemotron_h", "kimi_linear")
+            "longcat_flash", "nemotron_h", "kimi_linear", "glm_moe_dsa")
 
 
 def register_model(arch: str, module: ModuleType) -> None:
@@ -101,6 +108,10 @@ def get_model(arch: str) -> ModuleType:
             from smg_tpu.models import kimi_linear
 
             _REGISTRY.setdefault("kimi_linear", kimi_linear)
+        elif arch == "glm_moe_dsa":
+            from smg_tpu.models import glm_moe_dsa
+
+            _REGISTRY.setdefault("glm_moe_dsa", glm_moe_dsa)
         else:
             raise KeyError(
                 f"unsupported model architecture: {arch!r} "

@@ -77,7 +77,8 @@ def land_side_buffer(cache, side, page_tables, entry_positions, keep):
 
 
 @jax.named_scope("smg.attn.prefill")
-def latent_attention_prefill(q_nope, q_pe, k_nope, k_pe, v, q_positions, ctx_lens, scale):
+def latent_attention_prefill(q_nope, q_pe, k_nope, k_pe, v, q_positions, ctx_lens, scale,
+                             select=None):
     """Causal attention of a chunk's queries over rebuilt keys and values.
 
     ``q_nope`` [G, T, H, dn], ``q_pe`` [G, T, H, dr]; ``k_nope`` [G, S, H, dn],
@@ -85,16 +86,20 @@ def latent_attention_prefill(q_nope, q_pe, k_nope, k_pe, v, q_positions, ctx_len
     [G, S, H, dv]; ``q_positions`` [G, T], ``ctx_lens`` [G].  Scores are
     ``(q_nope . k_nope + q_pe . k_pe) * scale`` in float32; the queries go
     through in blocks so that no score tensor passes ``SCORE_BLOCK_BYTES``.
-    Returns [G, T, H, dv]."""
+    ``select`` [G, T, S] bool (``ops/sparse_attention.py``): a query reads the
+    keys it marks and no others.  Returns [G, T, H, dv]."""
     G, T, H, _ = q_nope.shape
     S = k_nope.shape[1]
     f32 = jnp.float32
     j = jnp.arange(S)
+    chosen = () if select is None else (select,)
 
-    def attend(qn, qp, pos):  # [G, n, H, d], [G, n]
+    def attend(qn, qp, pos, *sel):  # [G, n, H, d], [G, n], ([G, n, S])
         s = (jnp.einsum("gthd,gshd->ghts", qn, k_nope, preferred_element_type=f32)
              + jnp.einsum("gthd,gsd->ghts", qp, k_pe, preferred_element_type=f32)) * scale
         mask = (j[None, None, :] <= pos[:, :, None]) & (j[None, None, :] < ctx_lens[:, None, None])
+        for m in sel:
+            mask = mask & m
         p = jax.nn.softmax(jnp.where(mask[:, None], s, NEG_INF), axis=-1)
         return jnp.einsum("ghts,gshd->gthd", p.astype(v.dtype), v,
                           preferred_element_type=f32).astype(q_nope.dtype)
@@ -103,9 +108,10 @@ def latent_attention_prefill(q_nope, q_pe, k_nope, k_pe, v, q_positions, ctx_len
     while qb > 16 and qb % 2 == 0 and G * qb * H * S * 4 > SCORE_BLOCK_BYTES:
         qb //= 2
     if qb == T:
-        return attend(q_nope, q_pe, q_positions)
+        return attend(q_nope, q_pe, q_positions, *chosen)
     blocks = lambda x: jnp.moveaxis(x.reshape(G, T // qb, qb, *x.shape[2:]), 1, 0)
-    out = jax.lax.map(lambda b: attend(*b), (blocks(q_nope), blocks(q_pe), blocks(q_positions)))
+    out = jax.lax.map(lambda b: attend(*b), (blocks(q_nope), blocks(q_pe), blocks(q_positions),
+                                             *map(blocks, chosen)))
     return jnp.moveaxis(out, 0, 1).reshape(G, T, H, -1)
 
 
@@ -116,20 +122,25 @@ CONTEXT_BLOCK_PAGES = 64
 
 @jax.named_scope("smg.attn.prefill")
 def latent_attention_prefill_cached(q_nope, q_pe, cache, layer, page_tables, w_uk, w_uv,
-                                    q_positions, ctx_lens, scale, rkv: int, dr: int):
+                                    q_positions, ctx_lens, scale, rkv: int, dr: int,
+                                    select=None):
     """The same attention over what the pages hold (the chunk's own entries
     among them), a block of ``CONTEXT_BLOCK_PAGES`` pages at a time and **as
     many blocks as the longest context of the group needs**, not as many as
     the table has: a chunk behind 1,000 tokens costs two blocks whatever the
     table's width.  Each block's keys and values are rebuilt once (``w_uk``,
     ``w_uv`` [H, rkv, d]) and met by the queries in blocks, with a running
-    maximum and sum (float32).  Returns [G, T, H, dv]."""
+    maximum and sum (float32).  ``select`` [G, T, mp * page_size] bool
+    (``ops/sparse_attention.py``): a query reads the positions it marks and
+    no others.  Returns [G, T, H, dv]."""
     G, T, H, _ = q_nope.shape
     ps, W = cache.shape[2], cache.shape[3]
     mp = page_tables.shape[1]
     bp = min(CONTEXT_BLOCK_PAGES, mp)
     if mp % bp:
         page_tables = jnp.pad(page_tables, ((0, 0), (0, bp - mp % bp)))  # the garbage page
+        if select is not None:
+            select = jnp.pad(select, ((0, 0), (0, 0), (0, (bp - mp % bp) * ps)))
     S = bp * ps
     dv = w_uv.shape[-1]
     f32 = jnp.float32
@@ -141,6 +152,12 @@ def latent_attention_prefill_cached(q_nope, q_pe, cache, layer, page_tables, w_u
     whole = lambda x: jnp.moveaxis(x, 0, 1).reshape(G, T, *x.shape[3:])
     qn, qp, pos = blocks(q_nope), blocks(q_pe), blocks(q_positions)
 
+    def chosen(b):
+        """The block's stretch of ``select``, by query block: ([nq, G, qb, S],)."""
+        if select is None:
+            return ()
+        return (blocks(jax.lax.dynamic_slice_in_dim(select, b * S, S, axis=2)),)
+
     def block(b, carry):
         pages = jax.lax.dynamic_slice_in_dim(page_tables, b * bp, bp, axis=1)
         ent = cache[layer, pages].reshape(G, S, W).astype(q_nope.dtype)
@@ -151,10 +168,12 @@ def latent_attention_prefill_cached(q_nope, q_pe, cache, layer, page_tables, w_u
         j = b * S + jnp.arange(S)
 
         def meet(x):  # one block of queries against this block of context
-            qn, qp, pos, m, l, acc = x
+            qn, qp, pos, m, l, acc, *sel = x
             s = (jnp.einsum("gthd,gshd->ghts", qn, k_nope, preferred_element_type=f32)
                  + jnp.einsum("gthd,gsd->ghts", qp, k_pe, preferred_element_type=f32)) * scale
             seen = (j[None, None, :] <= pos[:, :, None]) & (j[None, None, :] < ctx_lens[:, None, None])
+            for marked in sel:
+                seen = seen & marked
             s = jnp.where(seen[:, None], s, NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1))
             p = jnp.where(seen[:, None], jnp.exp(s - m_new[..., None]), 0.0)
@@ -163,7 +182,7 @@ def latent_attention_prefill_cached(q_nope, q_pe, cache, layer, page_tables, w_u
                 "ghts,gshd->gthd", p.astype(v.dtype), v, preferred_element_type=f32)
             return m_new, l * a + jnp.sum(p, axis=-1), acc
 
-        return jax.lax.map(meet, (qn, qp, pos, *carry))
+        return jax.lax.map(meet, (qn, qp, pos, *carry, *chosen(b)))
 
     n = jnp.minimum(-(-jnp.max(ctx_lens) // S), page_tables.shape[1] // bp)
     init = (jnp.full((nq, G, H, qb), NEG_INF, f32), jnp.zeros((nq, G, H, qb), f32),
