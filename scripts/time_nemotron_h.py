@@ -41,7 +41,8 @@ at 64 lanes, as the kernel ``smg.ssm.decode``, as its XLA form, and as
 ``smg.linattn.decode`` given the same state (the delta rule with ``beta`` 0 and
 every head its own copy of ``B`` and ``C``: the rule as a static argument of
 that kernel would run no faster than this); a decode frame of 8 columns at 64
-lanes; a grouped prefill of two 2,048-token rows.
+lanes; the grouped prefill launches of one cold row of 512 and of 1,024 tokens
+and of two rows of 2,048.
 
 Prints one JSON line a reading.  Refuses to run without a TPU: a CPU time is
 not a device time.  ``--rehearsal`` runs the same code at the configuration's
@@ -415,17 +416,19 @@ def timings(args, runner) -> None:
         return toks
 
     res[f"decode_frame_ms_{N}_columns_{B}_lanes"] = timed(frame)
-    T = 64 if args.rehearsal else 2048
+    # the cell's launches alone: one cold row (86-94 % of its grouped prefills)
+    # at two rungs, and a step's budget as two rows
     table = np.zeros(runner.max_pages_per_seq, np.int32)
-    group = [([0] * T, 0, table)] * 2
-    one = (np.zeros(2, np.float32), np.full(2, -1, np.int32), np.ones(2, np.float32),
-           np.zeros(2, np.float32))
+    for G, T in ((1, 64), (1, 128), (2, 128)) if args.rehearsal else ((1, 512), (1, 1024), (2, 2048)):
+        group = [([0] * T, 0, table)] * G
+        plain = (np.zeros(G, np.float32), np.full(G, -1, np.int32), np.ones(G, np.float32),
+                 np.zeros(G, np.float32))
 
-    def prefill():
-        runner.prefill_batched(group, *one)
-        return runner.k_cache
+        def prefill():  # timed before the loop moves on
+            runner.prefill_batched(group, *plain)
+            return runner.k_cache
 
-    res[f"grouped_prefill_ms_2x{T}"] = timed(prefill)
+        res[f"grouped_prefill_ms_{G}x{T}"] = timed(prefill)
     if args.rehearsal:
         res = {k: v for k, v in res.items() if "_ms" not in k}
         res["rehearsal"] = True

@@ -266,12 +266,15 @@ def state_shapes(cfg: ModelConfig, slots: int) -> tuple[tuple, tuple]:
 
 def prefill_workspace_bytes(cfg: ModelConfig, tokens: int, dtype: str) -> int:
     """Bytes a prefill of ``tokens`` tokens holds beside its arguments, from
-    shapes and on the high side (the compiler's count for two rows of 2,048
-    at the published widths is 2.46 GB, this 2.53): a state-space layer's
-    projections in the model's dtype; in float32 the convolution's input and
-    output, each once more as the chunks' view of it, and the recurrence's
-    ``x``, its ``y`` and the gated ``y`` as they come and as the chunks hold
-    them; an expert layer's rows gathered pick by pick in the latent."""
+    shapes and on the high side: a state-space layer's projections in the
+    model's dtype; in float32 the convolution's input and output, each once
+    more as the chunks' view of it, and the recurrence's ``x``, its ``y`` and
+    the gated ``y`` as they come and as the chunks hold them; an expert layer's
+    rows gathered pick by pick in the latent.  For two rows of 2,048 at the
+    published widths this is 2.53 GB where the compiler counts 1.09: the sum
+    was fitted to 2.46 GB, which held a second state pool until a launch
+    stopped copying it (``ops/ssm.py``, State layout); what it keeps free of
+    pages is the cache plan's to change."""
     DI, C = cfg.ssm_num_heads * cfg.ssm_head_dim, conv_channels(cfg)
     return tokens * ((DI + C) * jnp.dtype(dtype).itemsize + 4 * (4 * C + 10 * DI)
                      + 4 * cfg.num_experts_per_tok * cfg.moe_latent_size)
@@ -529,12 +532,17 @@ def _prefill(params, cfg, tokens, prefix_lens, t_reals, k_cache, v_cache, page_t
             y, tail = ssm.causal_conv(xbc, tail * keep[:, None, None].astype(tail.dtype),
                                       layer["conv_w"], t_reals, layer["conv_b"])
             x, B, C = split_xbc(y, cfg)
-            S0 = pool_to_heads(read_state(s_pool, li, slots), H) * keep[:, None, None, None]
+            # the scan wants its carry laid out otherwise than the pool lies
+            # (``ops/ssm.py``, State layout): the barriers keep that relayout
+            # on the rows, which cross them in the pool's layout both ways
+            rows = jax.lax.optimization_barrier(read_state(s_pool, li, slots))
+            S0 = pool_to_heads(rows, H) * keep[:, None, None, None]
             y, S = ssm.ssd_chunked(x, jnp.where(real[..., None], dt, 0.0),
                                    jnp.where(real[..., None], g, 0.0), B, C, S0,
                                    cfg.ssm_chunk_size)
             y = y + layer["D"].astype(jnp.float32)[:, None] * x
-            return y, (write_state(s_pool, li, slots, heads_to_pool(S)),
+            rows = jax.lax.optimization_barrier(heads_to_pool(S))
+            return y, (write_state(s_pool, li, slots, rows),
                        write_tail(c_pool, li, slots, tail))
 
         h, (s_pool, c_pool) = mamba_layer(h, layer, cfg, mix)

@@ -34,6 +34,23 @@ and writes the pools with ``ops/linear_attention.py``'s ``read_state``,
 ``write_state``, ``read_tail`` and ``write_tail``, and decode steps the tails
 with them too (``conv_decode_step``).
 
+**Prefill relays a row and never the pool.**  ``ssd_chunked`` carries the state
+as ``[G, R, M, N, P]``, and where a head is narrower than a tile's 128 lanes
+(``P`` 64 at the published widths, ``N`` 128) the TPU's layout assignment lays
+that carry out with ``N`` on the lanes, whatever order the axes are written in
+(the carry restated ``[G, R, M, P, N]`` compiles to the same program).  Left to
+itself it carries the preference back through ``pool_to_heads`` and
+``read_state``'s slice to the pool, whose layout as a parameter is fixed: the
+copy then lands on the pool and not on the row, and every launch copied 1.5 GB
+into the other layout, wrote its rows there and copied the pool home
+(``PERF.md``, Findings, PR 56).  So ``models/nemotron_h._prefill`` puts a
+``lax.optimization_barrier`` round the rows it reads and round the rows it
+writes: a row, ``[N, H * P]`` and 4 MB, crosses it in the layout the pool lies
+in, the transposition the scan wants is a copy of that row, and the pool is
+updated where it lies.  A head of 128 or 192 lanes
+(``models/kimi_linear.py``, ``models/olmo_hybrid.py``) makes the compiler want
+no such thing of a one-row launch.
+
 Everything here computes in float32 at ``highest`` matmul precision: the
 products are under a percent of a layer's arithmetic.
 """

@@ -160,6 +160,37 @@ def test_a_grouped_prefill_is_its_rows_solo_and_a_padded_row_writes_the_garbage_
     assert float(jnp.abs(sp2[:, 1]).max()) > 0.0 and float(jnp.abs(sp2[:, 3]).max()) > 0.0
 
 
+def test_a_grouped_prefill_moves_its_rows_states_alone_and_they_are_the_decode_walks(world):
+    """However a prefill program moves a row between the pool and the scan
+    (``_prefill``'s barriers): of four rows, one cold, one behind a prefix that
+    a solo chunk left in its slot, two padded on the garbage slot, the two
+    named slots come to hold the state and the tail that decode leaves when it
+    walks each whole sequence a token at a time from nothing, and a slot no row
+    names keeps another sequence's state bit for bit, in both pools."""
+    w = world
+    seqs, cut = [w.toks[:40], w.toks[10:60]], 27
+    rng = np.random.default_rng(5)
+    kc, vc, sp, cp = w.empty()
+    sp = sp.at[:, 2].set(rng.normal(size=sp.shape[2:]).astype(np.float32))
+    cp = cp.at[:, 2].set(rng.normal(size=cp.shape[2:]).astype(np.float32))
+    _, kc, vc, sp, cp = w.prefill("xla", seqs[1][:cut], 0, (kc, vc, sp, cp), 3)
+    tokens = np.zeros((4, 64), np.int32)
+    tokens[0, :40], tokens[1, : 50 - cut] = seqs[0], seqs[1][cut:]
+    empty = jnp.zeros_like(w.table)
+    grouped = jax.jit(lambda *a: M.forward_prefill_batched(w.params, w.cfg, None, *a))
+    _, _, _, sp2, cp2 = grouped(
+        jnp.asarray(tokens), jnp.asarray([0, cut, 0, 0], jnp.int32),
+        jnp.asarray([40, 50 - cut, 0, 0], jnp.int32), kc, vc,
+        jnp.stack([w.table + MP, w.table, empty, empty]), sp, cp,
+        jnp.asarray([1, 3, 0, 0], jnp.int32))
+    assert np.array_equal(sp2[:, 2], sp[:, 2]) and np.array_equal(cp2[:, 2], cp[:, 2])
+    for slot, seq in zip((1, 3), seqs):
+        _, (_, _, want_s, want_c) = w.decode("xla", w.empty(), [[t] for t in seq], [0], [slot],
+                                             len(seq))
+        for got, want in ((sp2, want_s), (cp2, want_c)):
+            assert np.abs(got[:, slot] - want[:, slot]).max() < SOUND * np.abs(want[:, slot]).max()
+
+
 def test_a_lane_on_the_garbage_slot_does_not_run_and_picks_no_expert(world):
     """Two lanes of four hold a sequence: the others leave every slot bit for
     bit and add no pick; a column that no lane runs (a discarded lookahead's

@@ -1,6 +1,7 @@
 """What the ``test_tpu_compile*.py`` files share: the described TPU v5e, a
 compile for it, and what they read off the compiled text.  One file a runner
-family, so that ``--dist loadfile`` spreads the compiles over the workers;
+family (the recurrent runner's models in two), so that ``--dist loadfile``
+spreads the compiles over the workers;
 each worker that is given one of them describes the topology itself, inside
 the fixture, and the driver's ``ALLOW_MULTIPLE_LIBTPU_LOAD=1`` lets several do
 so at once (without it all but the first skip their tests, and say so)."""
@@ -81,3 +82,25 @@ def benchmark_cut(name: str):
     with open(path) as f:
         return ModelConfig.from_hf_config(
             {k: v for k, v in json.load(f).items() if k not in own})
+
+
+def assert_pool_updates_in_place(compiled, hlo: str, *pools, workspace: int = 0):
+    """A prefill program still writes its rows into each donated pool where it
+    lies, the tail pool ``cp`` (blocks or flat rows) and the float32 state pool
+    ``sp`` (ISSUE 56) alike: the pool is an aliased output, a row goes in with
+    a ``dynamic-update-slice`` (or is a kernel's aliased result), and nothing
+    copies or transposes an array of the pool's size.  With ``workspace``, what
+    the rows' own operands may take, the program's temporaries also stay under
+    that and half the largest pool: a second state pool does not hide among
+    them."""
+    kind = {"bfloat16": "bf16", "float32": "f32"}
+    for pool in pools:
+        named = f"{kind[str(pool.dtype)]}[{','.join(str(d) for d in pool.shape)}]"
+        assert re.search(rf"= {re.escape(named)}\S* dynamic-update-slice\(", hlo) or re.search(
+            rf"= .*{re.escape(named)}\S* custom-call\(.*output_to_operand_aliasing", hlo)
+        assert [line for line in _relayouts(hlo, pool.size) if named in line] == []
+    memory = compiled.memory_analysis()
+    nbytes = [pool.size * pool.dtype.itemsize for pool in pools]
+    assert memory.alias_size_in_bytes >= sum(nbytes)
+    if workspace:
+        assert memory.temp_size_in_bytes < max(nbytes) // 2 + workspace
